@@ -1,4 +1,3 @@
-from . import jax_compat  # noqa: F401  (must run before any jax.typeof use)
 from .dtype import (float16, bfloat16, float32, float64, int8, int16, int32,
                     int64, uint8, bool_, complex64, complex128, convert_dtype,
                     dtype_name, is_floating_point, is_integer)

@@ -459,7 +459,7 @@ class LLMPredictor:
         """Engine (or fleet) health payload — what /healthz serves."""
         if self.router is not None:
             return self.router.health()
-        return self.engine.health()
+        return self.engine._health()
 
     @property
     def metrics(self):

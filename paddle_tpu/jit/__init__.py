@@ -316,7 +316,10 @@ class InstrumentedStepMixin:
         # then falls back to _cache_size() deltas (same fallback
         # telemetry._InstrumentedJit uses)
         self._monitoring = telemetry.install_compile_tracking()
-        self._peak_flops = fr.device_peak_flops()   # constant per process
+        # constant per process; None off the peaks table (MFU then
+        # reads "not measured")
+        peaks = fr.device_peaks()
+        self._peak_flops = peaks[0] if peaks else None
         self._m_mfu = telemetry.gauge(
             "train_mfu", "Model-FLOPs utilization of the latest step")
         self._m_flops = telemetry.gauge(
@@ -416,8 +419,8 @@ class InstrumentedStepMixin:
             self._m_flops.set(flops)
         if cost.get("bytes_accessed") is not None:
             self._m_bytes.set(cost["bytes_accessed"])
-        mfu = 0.0
-        if flops:
+        mfu = None
+        if flops and self._peak_flops:
             mfu = flops / (max(device_s, 1e-9) * self._peak_flops)
             self._m_mfu.set(mfu)
         data_s, self._pending_data_s = self._pending_data_s, 0.0

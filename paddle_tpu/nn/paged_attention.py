@@ -27,8 +27,13 @@ dispatch point:
                       BlockSpec index_map over a scalar-prefetch table,
                       accumulators in VMEM scratch across the
                       sequential block dimension. `interpret=True` on
-                      CPU so tier-1 exercises the real kernel body.
-  kernel="auto"       "pallas" on TPU, "lax" elsewhere.
+                      CPU so tier-1 exercises the real kernel body; the
+                      body keeps every value 2-D, which is what the TPU
+                      compiler accepts (tests/test_tpu_compile.py
+                      compiles it for v5e at real widths).
+  kernel="auto"       "pallas" on TPU, "lax" elsewhere. An explicit
+                      "pallas" reaches the compiler as is: nothing here
+                      catches a refusal or falls back.
 
 Both serving attention shapes are covered: the decode form (one query
 per lane; replaces gather+`cached_decode_attention` in the decode and
@@ -245,13 +250,21 @@ def _lax_core(q, pk, pv, tables, start, scale, window=None):
 # BlockSpec index_map over the scalar-prefetch block table
 # ---------------------------------------------------------------------------
 
-def _paged_attn_kernel(tables_ref, qpos_ref, q_ref, k_ref, v_ref, o_ref,
-                       m_ref, l_ref, acc_ref, *, scale, window, bs, rep,
+def _paged_attn_kernel(tables_ref, start_ref, off_ref, q_ref, k_ref, v_ref,
+                       o_ref, m_ref, l_ref, acc_ref, *, scale, window, bs,
                        c):
     """One (lane b, kv-head h, block j) grid step. The pipeline already
     gathered this lane's j-th pool block via the index_map — the kernel
     only scores, masks and folds into the VMEM accumulators, which
-    persist across the sequential block dimension."""
+    persist across the sequential block dimension.
+
+    Every value is 2-D (the TPU compiler lays vectors out over sublanes
+    x lanes and refuses 1-D iotas, vector loads from SMEM and the
+    `[:, 0]` / `[:, None]` casts between the two): the running max and
+    denominator stay `[rows, 1]`, key positions come from
+    `broadcasted_iota`, and a row's query position is the lane's scalar
+    `start` plus its `[rows, 1]` offset within the chunk."""
+    import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
 
@@ -268,32 +281,42 @@ def _paged_attn_kernel(tables_ref, qpos_ref, q_ref, k_ref, v_ref, o_ref,
     qf = q_ref[0, 0].astype(jnp.float32)               # [rep*C, D]
     kb = k_ref[0, 0].astype(jnp.float32)               # [BS, D]
     vb = v_ref[0, 0].astype(jnp.float32)
-    s = jnp.dot(qf, kb.T, preferred_element_type=jnp.float32) * scale
-    ks = j * bs + jnp.arange(bs)                       # absolute keys
+    rc = qf.shape[0]
+    start = start_ref[b]                               # SMEM scalar
+    s = jax.lax.dot_general(                           # q @ k.T
+        qf, kb, (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32) * scale    # [rep*C, BS]
     # row i of the [rep*C, D] query tile is (group r, query c) with c
-    # minor — its absolute position is qpos[b, i % C]
-    rowpos = jnp.tile(qpos_ref[b], rep)                # [rep*C]
-    keep = ks[None, :] <= rowpos[:, None]
+    # minor — its absolute position is start + i % C (off_ref)
+    rowpos = start + off_ref[...]                      # [rep*C, 1]
+    ks = j * bs + jax.lax.broadcasted_iota(jnp.int32, (rc, bs), 1)
+    keep = ks <= rowpos
     if window is not None:
-        keep &= ks[None, :] > rowpos[:, None] - window
+        keep &= ks > rowpos - window
     s = jnp.where(keep, s, -jnp.inf)
     # fully-unattended keys get probability 0 but 0 * nan == nan: zero
-    # the V rows no query row keeps so scratch poison cannot leak
-    vb = jnp.where(jnp.any(keep, axis=0)[:, None], vb, 0.0)
-    m_prev = m_ref[:, 0]
-    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1))
+    # the V rows no query row keeps so scratch poison cannot leak. The
+    # rows sit at start .. start+C-1, so the keys some row keeps are
+    # exactly (start - window, start + C - 1]
+    kcol = j * bs + jax.lax.broadcasted_iota(jnp.int32, (bs, 1), 0)
+    attended = kcol <= start + (c - 1)
+    if window is not None:
+        attended &= kcol > start - window
+    vb = jnp.where(attended, vb, 0.0)
+    m_prev = m_ref[...]                                # [rep*C, 1]
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
     shift = jnp.where(jnp.isfinite(m_new), m_new, 0.0)
-    p = jnp.exp(s - shift[:, None])
+    p = jnp.exp(s - shift)
     alpha = jnp.exp(m_prev - shift)
-    l_ref[:, 0] = alpha * l_ref[:, 0] + jnp.sum(p, axis=-1)
-    acc_ref[...] = alpha[:, None] * acc_ref[...] + \
+    l_ref[...] = alpha * l_ref[...] + jnp.sum(p, axis=-1, keepdims=True)
+    acc_ref[...] = alpha * acc_ref[...] + \
         jnp.dot(p, vb, preferred_element_type=jnp.float32)
-    m_ref[:, 0] = m_new
+    m_ref[...] = m_new
 
     @pl.when(j == nblk - 1)
     def _finish():
         # == 0 guard (not > 0): nan denominators must propagate
-        l = l_ref[:, 0][:, None]
+        l = l_ref[...]
         o_ref[0, 0] = jnp.where(l == 0, 0.0, acc_ref[...] / l)
 
 
@@ -301,10 +324,10 @@ def _paged_attn_kernel(tables_ref, qpos_ref, q_ref, k_ref, v_ref, o_ref,
 def _pallas_call(b, h, c, d, hkv, bs, nblk, scale, window, dtype_name,
                  interpret):
     """Build (and cache) the pallas_call for one static shape family.
-    The block table and per-row query positions ride as scalar-prefetch
-    operands so the K/V BlockSpec index_maps can address the pool by
-    table VALUE — the gather happens in the pipeline, block by block,
-    never as a materialised [B, Hkv, nblk*BS, D] array."""
+    The block table and each lane's first query position ride as
+    scalar-prefetch operands so the K/V BlockSpec index_maps can address
+    the pool by table VALUE — the gather happens in the pipeline, block
+    by block, never as a materialised [B, Hkv, nblk*BS, D] array."""
     import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
@@ -313,22 +336,23 @@ def _pallas_call(b, h, c, d, hkv, bs, nblk, scale, window, dtype_name,
     rep = h // hkv
     rc = rep * c
     kernel = functools.partial(_paged_attn_kernel, scale=scale,
-                               window=window, bs=bs, rep=rep, c=c)
+                               window=window, bs=bs, c=c)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(b, hkv, nblk),
         in_specs=[
+            pl.BlockSpec((rc, 1), lambda bb, hh, jj, tab, st: (0, 0)),
             pl.BlockSpec((1, 1, rc, d),
-                         lambda bb, hh, jj, tab, qp: (bb, hh, 0, 0)),
+                         lambda bb, hh, jj, tab, st: (bb, hh, 0, 0)),
             pl.BlockSpec((1, 1, bs, d),
-                         lambda bb, hh, jj, tab, qp: (tab[bb, jj], hh,
+                         lambda bb, hh, jj, tab, st: (tab[bb, jj], hh,
                                                       0, 0)),
             pl.BlockSpec((1, 1, bs, d),
-                         lambda bb, hh, jj, tab, qp: (tab[bb, jj], hh,
+                         lambda bb, hh, jj, tab, st: (tab[bb, jj], hh,
                                                       0, 0)),
         ],
         out_specs=pl.BlockSpec((1, 1, rc, d),
-                               lambda bb, hh, jj, tab, qp: (bb, hh, 0,
+                               lambda bb, hh, jj, tab, st: (bb, hh, 0,
                                                             0)),
         scratch_shapes=[
             pltpu.VMEM((rc, 1), jnp.float32),          # running max m
@@ -353,12 +377,14 @@ def _pallas_core(q, pk, pv, tables, start, scale, window=None):
     hkv, bs = pk.shape[1], pk.shape[2]
     nblk = tables.shape[1]
     rep = h // hkv
-    qpos = _query_positions(start, b, c)
+    start = jnp.broadcast_to(jnp.reshape(jnp.asarray(start, jnp.int32),
+                                         (-1,)), (b,))
     # [B, H, C, D] -> [B, Hkv, rep*C, D]: group-major, query-minor rows
     qr = q.astype(jnp.float32).reshape(b, hkv, rep * c, d)
+    off = (jnp.arange(rep * c, dtype=jnp.int32) % c)[:, None]
     call = _pallas_call(b, h, c, d, hkv, bs, nblk, float(scale),
                         None if window is None else int(window),
                         str(pk.dtype),
                         jax.default_backend() != "tpu")
-    out = call(tables.astype(jnp.int32), qpos, qr, pk, pv)
+    out = call(tables.astype(jnp.int32), start, off, qr, pk, pv)
     return out.reshape(b, h, c, d).astype(pv.dtype)

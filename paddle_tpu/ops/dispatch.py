@@ -112,7 +112,7 @@ def _check_nan_inf(name, outs):
 
 # Ops with no TPU lowering (complex dtypes: the backend returns
 # UNIMPLEMENTED — measured by the on-chip registry sweep,
-# docs/perf/OP_SWEEP_TPU.md). In eager mode these fall back to the host
+# scripts/op_sweep_tpu.py). In eager mode these fall back to the host
 # CPU, the analog of the reference's CPUPlace kernel fallback (ref
 # paddle/fluid/framework/operator.cc ChooseKernel: when no kernel exists
 # for the requested place, the op runs on CPUPlace). Complex outputs

@@ -20,6 +20,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.sharding import PartitionSpec as P
 
 import os
 
@@ -567,6 +568,40 @@ def _kernel_eligible(q, k, mask, dropout_p, bshd=False):
             and sq >= 128 and sk >= 128)
 
 
+def _per_shard_spec(q, bshd):
+    """(mesh, PartitionSpec) when the kernel has to be called per shard,
+    else None. Traced for a program over the installed multi-device mesh
+    (`make_mesh`, as ShardedTrainStep runs under), a Mosaic kernel is
+    refused at lowering — "Mosaic kernels cannot be automatically
+    partitioned. Please wrap the call in a shard_map" — so it is wrapped
+    here: batch over 'dp' and heads over 'mp' where they divide (nothing
+    in the kernel mixes batches or heads), whole otherwise. Interpret
+    mode (CPU) lowers to plain HLO that the partitioner handles itself,
+    and inside someone else's shard_map (ring, pipeline) the call is
+    already per shard."""
+    if jax.default_backend() == "cpu":
+        return None
+    from ...distributed import mesh as mesh_mod
+    mesh = mesh_mod.get_mesh()
+    if mesh is None or mesh.size == 1 or \
+            jax.sharding.get_abstract_mesh().manual_axes:
+        return None
+
+    def divides(name, n):
+        return name in mesh.axis_names and n % int(mesh.shape[name]) == 0
+
+    h = q.shape[2 if bshd else 1]
+    b_ax = mesh_mod.DP_AXIS if divides(mesh_mod.DP_AXIS, q.shape[0]) else None
+    h_ax = mesh_mod.MP_AXIS if divides(mesh_mod.MP_AXIS, h) else None
+    if h_ax and bshd:
+        # BSHD packs heads into 128-lane groups: a shard keeps whole groups
+        local = h // int(mesh.shape[h_ax])
+        if local % _pack(_pad_dim(q.shape[-1]), local)[0]:
+            h_ax = None
+    return mesh, (P(b_ax, None, h_ax, None) if bshd
+                  else P(b_ax, h_ax, None, None))
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
 def _flash_core(q, k, v, causal, scale, bshd=False, window=None):
     out, _ = _flash_fwd_pallas(q, k, v, causal, scale, bshd, window)
@@ -604,7 +639,15 @@ def _flash_array(q, k, v, mask=None, causal=False, dropout_p=0.0, scale=None,
             raise ValueError(f"window must be positive, got {window}")
     bshd = layout == "bshd"
     if _kernel_eligible(q, k, mask, dropout_p, bshd):
-        return _flash_core(q, k, v, causal, scale, bshd, window)
+        sharded = _per_shard_spec(q, bshd)
+        if sharded is None:
+            return _flash_core(q, k, v, causal, scale, bshd, window)
+        mesh, spec = sharded
+        return jax.shard_map(
+            lambda q_, k_, v_: _flash_core(q_, k_, v_, causal, scale, bshd,
+                                           window),
+            mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
+            check_vma=False)(q, k, v)
     if bshd:
         # fallback reference path works in BHSD: transpose around it
         # (ineligible shapes are the rare/small case)

@@ -83,17 +83,18 @@ _HBM_UTIL = telemetry.gauge(
     "bytes-accessed / (wave seconds x device peak HBM bandwidth) — the "
     "roofline axis that actually binds decode")
 
-_DEVICE_PEAKS = []     # [(peak_flops, peak_hbm_bw)] resolved once
+_DEVICE_PEAKS = []     # [(peak_flops, peak_hbm_bw) | None] resolved once
 
 
 def _device_peaks():
     """The roofline denominators, resolved once per process — they are
     device constants, and on_wave sits in the hottest serving loop
-    (sub-millisecond waves), where two env + JAX-client lookups per
-    wave are real overhead."""
+    (sub-millisecond waves), where a JAX-client lookup per wave is real
+    overhead. None when the device is not in the peaks table: the
+    roofline gauges then stay unset and the snapshot's `mfu`/`hbm_util`
+    read None ("not measured")."""
     if not _DEVICE_PEAKS:
-        _DEVICE_PEAKS.append((flight_recorder.device_peak_flops(),
-                              flight_recorder.device_peak_hbm_bw()))
+        _DEVICE_PEAKS.append(flight_recorder.device_peaks())
     return _DEVICE_PEAKS[0]
 # resilience counters (the chaos harness proves each one moves —
 # scripts/chaos_serving.py; kinds are a small closed set)
@@ -267,8 +268,9 @@ class ServingMetrics:
                 self._wave_seconds += float(wave_s)
                 self._wave_flops += float(flops or 0.0)
                 self._wave_bytes += float(bytes_accessed or 0.0)
-        if wave_s is not None and wave_s > 0:
-            peak_flops, peak_bw = _device_peaks()
+        peaks = _device_peaks()
+        if wave_s is not None and wave_s > 0 and peaks:
+            peak_flops, peak_bw = peaks
             if flops:
                 _MFU.set(float(flops) / (wave_s * peak_flops))
             if bytes_accessed:
@@ -374,6 +376,7 @@ class ServingMetrics:
             wave_flops, wave_bytes = self._wave_flops, self._wave_bytes
             spec_p, spec_a = self._spec_proposed, self._spec_accepted
             spec_w = self._spec_waves
+        peaks = _device_peaks()
         return {
             "requests_completed": self._latency.count(),
             "tokens_generated": tokens,
@@ -413,10 +416,10 @@ class ServingMetrics:
             "tpot_p50_s": self._tpot.percentile(50),
             "tpot_p99_s": self._tpot.percentile(99),
             "phase_seconds": phase_seconds,
-            "mfu": (wave_flops / (wave_s * _device_peaks()[0])
-                    if wave_s and wave_flops else None),
-            "hbm_util": (wave_bytes / (wave_s * _device_peaks()[1])
-                         if wave_s and wave_bytes else None),
+            "mfu": (wave_flops / (wave_s * peaks[0])
+                    if wave_s and wave_flops and peaks else None),
+            "hbm_util": (wave_bytes / (wave_s * peaks[1])
+                         if wave_s and wave_bytes and peaks else None),
             # speculative decoding (perf PR): 0/None on engines without
             # a draft model. accepted_per_wave is the headline number —
             # > 0 means each wave nets more than one token per lane

@@ -508,13 +508,14 @@ def read_journal(path):
 
 def rollup(events):
     """Compact summary for bench output: steps, mean MFU over steps that
-    have one, executable (re)compiles, and non-finite incidents."""
+    have one (None when none has: not measured), executable
+    (re)compiles, and non-finite incidents."""
     steps = [e for e in events if e.get("ev") == "step"]
     mfus = [e["mfu"] for e in steps
             if isinstance(e.get("mfu"), (int, float)) and e["mfu"] > 0]
     return {
         "steps": len(steps),
-        "mean_mfu": round(sum(mfus) / len(mfus), 4) if mfus else 0.0,
+        "mean_mfu": round(sum(mfus) / len(mfus), 4) if mfus else None,
         "recompiles": sum(int(e.get("count", 1)) for e in events
                           if e.get("ev") == "compile"),
         "nonfinite": sum(1 for e in events if e.get("ev") == "nonfinite"),
@@ -525,84 +526,43 @@ def rollup(events):
 # cost accounting (MFU)
 # ---------------------------------------------------------------------------
 
-# bf16 peak dense FLOP/s by TPU device kind substring (first match wins);
-# CPU/unknown fall back to a nominal 1 TF/s so MFU stays a defined,
-# comparable-across-runs number even off-chip (flagged by peak source).
-_PEAK_FLOPS_BY_KIND = (
-    ("v5p", 459e12),
-    ("v5e", 197e12),
-    ("v5 lite", 197e12),
-    ("v5litepod", 197e12),
-    ("v6e", 918e12),
-    ("trillium", 918e12),
-    ("v4", 275e12),
-    ("v3", 123e12),
-    ("v2", 45e12),
+# Published peaks by TPU device-kind substring (first match wins): bf16
+# dense FLOP/s and HBM bytes/s, from Google Cloud's per-generation TPU
+# documentation (v5e: 197 TFLOP/s, 819 GB/s). A device that is not in
+# the table has no peak: `device_peaks` returns None and every
+# utilization derived from it reads "not measured" (None) — a CPU run
+# never yields an MFU or an HBM utilization.
+_PEAKS_BY_KIND = (
+    ("v5p", 459e12, 2765e9),
+    ("v5e", 197e12, 819e9),
+    ("v5 lite", 197e12, 819e9),
+    ("v5litepod", 197e12, 819e9),
+    ("v6e", 918e12, 1640e9),
+    ("trillium", 918e12, 1640e9),
+    ("v4", 275e12, 1228e9),
+    ("v3", 123e12, 900e9),
+    ("v2", 45e12, 700e9),
 )
-_DEFAULT_PEAK_FLOPS = 1e12
 
 
-def device_peak_flops(device=None):
-    """Peak FLOP/s of the accelerator MFU is measured against.
-    `PT_PEAK_FLOPS` (float, FLOP/s) overrides the table for parts not
-    listed here."""
-    env = os.environ.get("PT_PEAK_FLOPS")
-    if env:
-        try:
-            return float(env)
-        except ValueError:
-            pass
-    try:
+def device_peaks(device=None):
+    """(peak FLOP/s, peak HBM bytes/s) of `device` (default: the first
+    local device), or None when its kind is not in the table."""
+    if device is None:
         import jax
-        dev = device or jax.local_devices()[0]
-        kind = (getattr(dev, "device_kind", "") or "").lower()
-    except Exception:
-        return _DEFAULT_PEAK_FLOPS
-    for key, peak in _PEAK_FLOPS_BY_KIND:
+        device = jax.local_devices()[0]
+    kind = (getattr(device, "device_kind", "") or "").lower()
+    for key, flops, hbm_bw in _PEAKS_BY_KIND:
         if key in kind:
-            return peak
-    return _DEFAULT_PEAK_FLOPS
+            return flops, hbm_bw
+    return None
 
 
-# peak HBM bandwidth (bytes/s) by TPU device kind substring — the
-# denominator of the serving roofline's bandwidth axis, the way
-# _PEAK_FLOPS_BY_KIND is the compute axis. CPU/unknown fall back to a
-# nominal 100 GB/s so serving_hbm_util stays a defined,
-# comparable-across-runs number off-chip (same policy as MFU).
-_PEAK_HBM_BW_BY_KIND = (
-    ("v5p", 2765e9),
-    ("v5e", 819e9),
-    ("v5 lite", 819e9),
-    ("v5litepod", 819e9),
-    ("v6e", 1640e9),
-    ("trillium", 1640e9),
-    ("v4", 1228e9),
-    ("v3", 900e9),
-    ("v2", 700e9),
-)
-_DEFAULT_PEAK_HBM_BW = 100e9
-
-
-def device_peak_hbm_bw(device=None):
-    """Peak HBM bandwidth (bytes/s) of the accelerator the serving
-    bandwidth-utilization gauge is measured against. `PT_PEAK_HBM_BW`
-    (float, bytes/s) overrides the table for parts not listed."""
-    env = os.environ.get("PT_PEAK_HBM_BW")
-    if env:
-        try:
-            return float(env)
-        except ValueError:
-            pass
-    try:
-        import jax
-        dev = device or jax.local_devices()[0]
-        kind = (getattr(dev, "device_kind", "") or "").lower()
-    except Exception:
-        return _DEFAULT_PEAK_HBM_BW
-    for key, peak in _PEAK_HBM_BW_BY_KIND:
-        if key in kind:
-            return peak
-    return _DEFAULT_PEAK_HBM_BW
+def mfu_text(flops_per_s, device=None):
+    """`flops_per_s` over the device's published peak, for a log line;
+    "not measured" on a device that has none."""
+    peaks = device_peaks(device)
+    return f"{flops_per_s / peaks[0]:.3f}" if peaks else "not measured"
 
 
 def normalize_cost_analysis(ca):
@@ -612,8 +572,8 @@ def normalize_cost_analysis(ca):
     list-of-dicts (one per device/partition — the first carries the
     program totals), or something unusable; keys use XLA's spaced
     spelling ("bytes accessed"). This is THE one place that shape
-    knowledge lives — jit.TrainStep, the xprof audit and
-    scripts/mosaic_check.py all consume this normalized form. Returns
+    knowledge lives — jit.TrainStep and the xprof audit consume this
+    normalized form. Returns
     {"flops": float, "bytes_accessed": float, "transcendentals": float}
     (keys present when the analysis provides a numeric value, never
     NaN), or None when nothing usable came back."""

@@ -24,13 +24,12 @@ sys.path.insert(0, _REPO)
 import jax
 import jax.numpy as jnp
 
-jax.config.update("jax_compilation_cache_dir",
-                  os.path.join(_REPO, ".jax_cache"))
-jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
+from paddle_tpu.utils import compile_cache  # noqa: E402
+compile_cache.enable()
 
 import paddle_tpu as pt
 from paddle_tpu.jit import TrainStep
-from bench import PEAK_TFLOPS
+from paddle_tpu.utils.flight_recorder import mfu_text
 
 t0 = time.time()
 
@@ -68,7 +67,7 @@ def run_combo(fused, layout, batch=8, seq=1024, iters=20):
     n_params = sum(int(np.prod(p.shape)) for p in model.parameters())
     tf = 6 * n_params * batch * seq / dt / 1e12
     log(f"RESULT {tag}: {dt*1e3:.2f} ms/step  {batch*seq/dt:,.0f} tok/s  "
-        f"{tf:.1f} TF/s  MFU={tf/PEAK_TFLOPS:.3f}")
+        f"{tf:.1f} TF/s  MFU={mfu_text(tf * 1e12)}")
     del step, model, opt
     return dt
 

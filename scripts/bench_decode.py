@@ -23,9 +23,8 @@ sys.path.insert(0, _REPO)
 import jax
 import jax.numpy as jnp
 
-jax.config.update("jax_compilation_cache_dir",
-                  os.path.join(_REPO, ".jax_cache"))
-jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
+from paddle_tpu.utils import compile_cache  # noqa: E402
+compile_cache.enable()
 
 import paddle_tpu as pt
 

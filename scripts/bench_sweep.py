@@ -22,10 +22,9 @@ import jax.numpy as jnp
 
 _REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, _REPO)
-from bench import PEAK_TFLOPS
-jax.config.update("jax_compilation_cache_dir",
-                  os.path.join(_REPO, ".jax_cache"))
-jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
+from paddle_tpu.utils import compile_cache  # noqa: E402
+from paddle_tpu.utils.flight_recorder import mfu_text  # noqa: E402
+compile_cache.enable()
 
 import paddle_tpu as pt
 from paddle_tpu.jit import TrainStep
@@ -76,7 +75,7 @@ def _measure_inner(step, inputs, labels, tag, per_step_samples,
     rate = per_step_samples / dt
     tf = flops_per_step / dt / 1e12
     log(f"{tag}: {dt*1e3:.1f} ms/step  {rate:,.0f} {unit}  "
-        f"{tf:.1f} TF/s  MFU={tf/PEAK_TFLOPS:.3f}")
+        f"{tf:.1f} TF/s  MFU={mfu_text(tf * 1e12)}")
     if recorder is not None:
         from paddle_tpu.utils import flight_recorder as fr
         step.attach_flight_recorder(recorder)

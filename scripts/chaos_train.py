@@ -77,9 +77,8 @@ if _FLAG not in os.environ.get("XLA_FLAGS", ""):
 
 import jax
 
-jax.config.update("jax_compilation_cache_dir",
-                  os.path.join(_REPO, ".jax_cache"))
-jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
+from paddle_tpu.utils import compile_cache  # noqa: E402
+compile_cache.enable()
 
 import numpy as np
 

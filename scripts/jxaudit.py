@@ -85,8 +85,8 @@ def run(argv):
         select = {s.strip() for s in args.select.split(",") if s.strip()}
 
     import jax
-    jax.config.update("jax_compilation_cache_dir",
-                      os.path.join(REPO, ".jax_cache"))
+    from paddle_tpu.utils import compile_cache
+    compile_cache.enable()
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
 
     if args.list_programs:

@@ -1,4 +1,4 @@
-"""Regression tests for the round-3 advisor findings (ADVICE.md r3):
+"""Regression tests for the round-3 advisor findings:
 gru_unit packed weight layout, interpolate align_mode=1, shuffle_batch
 seed=0 freshness, max_unpool2d duplicate-index determinism, fluid
 spectral_norm power-iteration state persistence."""

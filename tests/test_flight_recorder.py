@@ -110,8 +110,29 @@ class TestRecorderCore:
 # TrainStep instrumentation
 # ---------------------------------------------------------------------------
 
+V5E_PEAKS = (197e12, 819e9)
+
+
+def test_device_peaks_come_from_the_table_or_not_at_all():
+    """No default peak and no override: a device kind that is not in
+    the table (the CPU here) has no peak, so no utilization."""
+    class Dev:
+        def __init__(self, kind):
+            self.device_kind = kind
+
+    assert fr.device_peaks(Dev("TPU v5 lite")) == V5E_PEAKS
+    assert fr.device_peaks(Dev("TPU v5e")) == V5E_PEAKS
+    assert fr.device_peaks(Dev("cpu")) is None
+    assert fr.device_peaks(Dev("TPU v9 unheard-of")) is None
+    assert fr.device_peaks() is None           # tests run on the CPU
+
+
 class TestTrainStepInstrumentation:
-    def test_step_events_and_cost_accounting(self, tmp_path):
+    @pytest.mark.parametrize("peaks", [V5E_PEAKS, None],
+                             ids=["device-in-table", "device-unknown"])
+    def test_step_events_and_cost_accounting(self, tmp_path, monkeypatch,
+                                             peaks):
+        monkeypatch.setattr(fr, "device_peaks", lambda device=None: peaks)
         path = tmp_path / "run.jsonl"
         step = make_step()
         rec = fr.FlightRecorder(path)
@@ -128,7 +149,10 @@ class TestTrainStepInstrumentation:
             for key in ("data_s", "host_s", "device_s", "mfu", "loss",
                         "grad_norm", "nonfinite"):
                 assert key in e, f"step event missing {key}"
-            assert e["mfu"] > 0 and math.isfinite(e["mfu"])
+            if peaks is None:
+                assert e["mfu"] is None            # "not measured"
+            else:
+                assert e["mfu"] > 0 and math.isfinite(e["mfu"])
             assert e["data_s"] >= 0 and e["host_s"] > 0
         compiles = [e for e in events if e["ev"] == "compile"]
         assert len(compiles) == 1 and compiles[0]["count"] == 1
@@ -136,9 +160,11 @@ class TestTrainStepInstrumentation:
         assert compiles[0]["bytes_accessed"] > 0
         # gauges made it to the registry / exporter
         assert telemetry.value("train_step_flops") == compiles[0]["flops"]
-        assert telemetry.value("train_mfu") > 0
         text = telemetry.render_prometheus()
-        assert "train_mfu" in text and "train_step_flops" in text
+        assert "train_step_flops" in text
+        if peaks is not None:
+            assert telemetry.value("train_mfu") == steps[-1]["mfu"]
+            assert "train_mfu" in text
 
     def test_nonfinite_sentinel_and_counter(self, tmp_path):
         step = make_step()
@@ -185,7 +211,9 @@ class TestTrainStepInstrumentation:
 # ---------------------------------------------------------------------------
 
 class TestFitJournal:
-    def test_two_epoch_fit_journal(self, tmp_path):
+    def test_two_epoch_fit_journal(self, tmp_path, monkeypatch):
+        monkeypatch.setattr(fr, "device_peaks",
+                            lambda device=None: V5E_PEAKS)
         path = tmp_path / "fit.jsonl"
         pt.seed(7)
         net = nn.Linear(4, 3)
@@ -432,3 +460,5 @@ def test_rollup():
     r = fr.rollup(events)
     assert r == {"steps": 3, "mean_mfu": 0.5, "recompiles": 1,
                  "nonfinite": 1}
+    # no step carries an MFU (device off the peaks table): not measured
+    assert fr.rollup([{"ev": "step", "mfu": None}])["mean_mfu"] is None

@@ -339,6 +339,22 @@ def test_front_door_via_inference_config(model):
         Scheduler(ref).generate(prompt, max_tokens=4)
 
 
+def test_front_door_health_names_the_resolved_core(model):
+    """A single-engine predictor's health() is the engine's /healthz
+    payload (it used to raise AttributeError: only the fleet router had
+    a health()); chip_smoke.py reads status and the resolved paged core
+    from it."""
+    from paddle_tpu import inference
+    cfg = inference.Config().enable_llm_engine(
+        paged=True, num_slots=2, max_len=48, prefill_len=16, block_size=8)
+    pred = inference.create_llm_predictor(cfg, model=model)
+    pred.generate(_prompt_tokens(32), max_tokens=2)
+    health = pred.health()
+    assert health["status"] == "ok"
+    assert health["paged_kernel"] == "lax"                 # auto on cpu
+    assert health["decode_compiles"] == 1
+
+
 def _prompt_tokens(seed, n=5):
     return np.random.RandomState(seed).randint(0, VOCAB, (n,)).tolist()
 

@@ -165,7 +165,7 @@ class TestOpCallStack:
 def test_complex_ops_host_fallback(monkeypatch):
     """Reference semantics: ops with no device kernel fall back to
     CPUPlace (ref framework/operator.cc ChooseKernel). Complex dtypes
-    have no TPU lowering (measured: docs/perf/OP_SWEEP_TPU.md, 8
+    have no TPU lowering (measured by scripts/op_sweep_tpu.py: 8
     UNIMPLEMENTED ops), so eager dispatch reroutes them to the host —
     validated here with a patched backend name; on-chip validation is
     the sweep's job."""

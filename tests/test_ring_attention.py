@@ -144,8 +144,7 @@ def test_ring_per_device_sequence_shard():
 def test_ring_memory_advantage_xla_analysis():
     """Per-device compiled memory (XLA memory_analysis, grad included) of
     ring attention over sp=8 must beat the sequence-replicated dense
-    step — the reason sequence parallelism exists (docs/perf/LONGCTX.md
-    carries the full-scale table)."""
+    step — the reason sequence parallelism exists."""
     import numpy as np
     import jax
     import jax.numpy as jnp
@@ -182,8 +181,7 @@ def test_ring_memory_advantage_xla_analysis():
 def test_ring_tiled_block_path_parity():
     """Shard length > _KV_CHUNK exercises the kv-tiling inside each ring
     block (incl. a non-multiple remainder tail): fwd + dq/dk/dv must
-    match dense exactly — the path the LONGCTX linear-memory claim rests
-    on."""
+    match dense exactly — the path the linear-memory claim rests on."""
     import numpy as np
     import jax
     import jax.numpy as jnp

@@ -23,7 +23,14 @@ def _generate_journal(path):
     opt = pt.optimizer.SGD(learning_rate=0.1, parameters=net.parameters())
     step = TrainStep(net, lambda o, y: nn.functional.mse_loss(o, y), opt)
     rec = fr.FlightRecorder(path)
-    step.attach_flight_recorder(rec)
+    # the CPU has no entry in the peaks table, so a real step journals
+    # no MFU; the CLI's MFU line is rendered from a stand-in v5e peak
+    real_peaks = fr.device_peaks
+    fr.device_peaks = lambda device=None: (197e12, 819e9)
+    try:
+        step.attach_flight_recorder(rec)
+    finally:
+        fr.device_peaks = real_peaks
     rng = np.random.RandomState(0)
     x = rng.randn(8, 4).astype("f4")
     y = rng.randn(8, 3).astype("f4")

@@ -33,6 +33,7 @@ import paddle_tpu as pt
 from paddle_tpu.nlp import LlamaConfig, LlamaForCausalLM
 from paddle_tpu.serving import (PagedServingEngine, Scheduler, SLOEngine,
                                 SLOPolicy, fleet)
+from paddle_tpu.serving import metrics as serving_metrics
 from paddle_tpu.utils import chaos, flight_recorder, telemetry
 from paddle_tpu.utils import profiler as prof
 
@@ -102,27 +103,35 @@ def test_paged_program_costs_agree_with_banked_baseline():
                 f"outside tolerance {tol}")
 
 
-def test_wave_roofline_gauges_follow_program_costs(paged):
+@pytest.mark.parametrize("peaks", [(197e12, 819e9), None],
+                         ids=["device-in-table", "device-unknown"])
+def test_wave_roofline_gauges_follow_program_costs(paged, monkeypatch,
+                                                   peaks):
     """serving_mfu / serving_hbm_util are exactly program-cost /
     (measured wave time x device peak), and the snapshot's
-    wave-integral + phase split are populated."""
+    wave-integral + phase split are populated. A device that is not in
+    the peaks table (the CPU these tests run on) has no roofline: the
+    snapshot reads None, never a number against a made-up peak."""
+    monkeypatch.setattr(serving_metrics, "_DEVICE_PEAKS", [peaks])
     sched = Scheduler(paged)
     for p in _prompts(3, seed=40):
         sched.submit(prompt=p, max_tokens=4)
     sched.run()
     costs = paged.program_costs()
     assert costs["decode_wave"] and costs["prefill"]
-    peak_f = flight_recorder.device_peak_flops()
-    peak_b = flight_recorder.device_peak_hbm_bw()
-    # the gauge carries the LAST wave's utilization, computed from the
-    # same cost numbers and the scheduler's measured wave time
-    assert telemetry.value("serving_mfu") == pytest.approx(
-        costs["decode_wave"]["flops"] / (sched.last_wave_s * peak_f))
-    assert telemetry.value("serving_hbm_util") == pytest.approx(
-        costs["decode_wave"]["bytes_accessed"]
-        / (sched.last_wave_s * peak_b))
     snap = sched.metrics.snapshot()
-    assert snap["mfu"] > 0 and snap["hbm_util"] > 0
+    if peaks is None:
+        assert snap["mfu"] is None and snap["hbm_util"] is None
+    else:
+        peak_f, peak_b = peaks
+        # the gauge carries the LAST wave's utilization, computed from
+        # the same cost numbers and the scheduler's measured wave time
+        assert telemetry.value("serving_mfu") == pytest.approx(
+            costs["decode_wave"]["flops"] / (sched.last_wave_s * peak_f))
+        assert telemetry.value("serving_hbm_util") == pytest.approx(
+            costs["decode_wave"]["bytes_accessed"]
+            / (sched.last_wave_s * peak_b))
+        assert snap["mfu"] > 0 and snap["hbm_util"] > 0
     ph = snap["phase_seconds"]
     assert set(ph) >= {"admission", "prefill_chunk", "decode_wave",
                        "host_dispatch"}

@@ -1,0 +1,150 @@
+"""Ask the TPU's compiler, without a TPU: the Pallas kernels of the main
+paths are compiled at real widths for one described v5e chip
+(`jax.experimental.topologies`), so a kernel that only ever ran in
+interpret mode cannot reach the chip unseen. Nothing runs — a pass says
+the compiler accepts the kernel, not that its results are right.
+
+The topology is described inside the module-scoped fixture below and
+nowhere else: only one process may hold libtpu, so it must not load
+while a module is imported (every xdist worker imports every test file)
+and these tests must stay in this one file. Compiles run in the test's
+own process, with the persistent cache off (a described-chip executable
+is written to the cache but cannot be read back without a chip).
+
+The kernels choose `interpret=` from `jax.default_backend()`, which is
+"cpu" here; the tests steer that with monkeypatch, not the program.
+"""
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import (Mesh, NamedSharding, PartitionSpec as P,
+                          SingleDeviceSharding)
+
+from paddle_tpu.distributed import mesh as mesh_mod
+from paddle_tpu.nn import paged_attention as pa
+from paddle_tpu.ops.pallas.flash_attention import _flash_array
+
+
+@pytest.fixture(scope="module")
+def topo():
+    """The described v5e:2x2 host, with the persistent cache off for as
+    long as this file's tests compile for it."""
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        desc = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:   # noqa: BLE001 — whatever libtpu raises here
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", was)
+    cc.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture
+def as_on_tpu(monkeypatch):
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+
+def _kernels_in(fn, *shapes):
+    return jax.jit(fn).lower(*shapes).compile().as_text().count(
+        "tpu_custom_call")
+
+
+def _paged_args(sharding, b, h, hkv, c, d, bs=16, nblk=64):
+    """(q, k pool, v pool, tables, positions) of a paged engine at block
+    16, 64 blocks per lane (max_len 1024), bf16 pools."""
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+    pool = sds((b * nblk + 1, hkv, bs, d), jnp.bfloat16)
+    return (sds((b, h, c, d), jnp.bfloat16), pool, pool,
+            sds((b, nblk), jnp.int32), sds((b,), jnp.int32))
+
+
+@pytest.mark.parametrize("name,layout,b,s,h,d,window", [
+    ("gpt2s-bshd", "bshd", 8, 1024, 12, 64, None),
+    ("gpt2s-bhsd", "bhsd", 8, 1024, 12, 64, None),
+    ("gpt2m-bshd", "bshd", 4, 1024, 16, 64, None),
+    ("8k-window1024", "bshd", 1, 8192, 12, 64, 1024),
+])
+def test_flash_fwd_bwd_compiles_for_v5e(one_chip, as_on_tpu, name, layout,
+                                        b, s, h, d, window):
+    shape = (b, s, h, d) if layout == "bshd" else (b, h, s, d)
+    x = jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
+
+    def loss(q, k, v):
+        return _flash_array(q, k, v, causal=True, layout=layout,
+                            window=window).astype(jnp.float32).sum()
+
+    # forward, dq and dk/dv: three kernels
+    assert _kernels_in(jax.grad(loss, argnums=(0, 1, 2)), x, x, x) == 3
+
+
+def test_flash_under_a_dp2_mp2_mesh_compiles_for_v5e(topo, as_on_tpu,
+                                                     monkeypatch):
+    """ShardedTrainStep traces the model for the installed mesh, all
+    four chips. The TPU compiler cannot partition a Mosaic kernel, so
+    the flash wrapper has to call it per shard (batch over dp, heads
+    over mp); virtual CPU devices never see this, interpret mode being
+    plain HLO."""
+    mesh = Mesh(np.asarray(topo.devices).reshape(2, 2), ("dp", "mp"))
+    monkeypatch.setattr(mesh_mod, "_current_mesh", mesh)
+    x = jax.ShapeDtypeStruct(
+        (8, 1024, 12, 64), jnp.bfloat16,
+        sharding=NamedSharding(mesh, P("dp", None, "mp", None)))
+
+    def loss(q, k, v):
+        return _flash_array(q, k, v, causal=True, layout="bshd").astype(
+            jnp.float32).sum()
+
+    txt = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        x, x, x).compile().as_text()
+    assert txt.count("tpu_custom_call") == 3
+    # each chip runs the kernels on its own [4, 1024, 6, 64] shard:
+    # nothing is gathered to feed them
+    assert "all-gather" not in txt
+
+
+@pytest.mark.parametrize("name,b,h,hkv,c,d,window", [
+    ("gpt2s-decode", 8, 12, 12, 1, 64, None),
+    ("gpt2s-chunk128", 1, 12, 12, 128, 64, None),
+    ("gqa32x8-d128-decode", 8, 32, 8, 1, 128, None),
+    ("gqa32x8-d128-chunk128", 1, 32, 8, 128, 128, None),
+    ("gqa32x8-d128-decode-window256", 8, 32, 8, 1, 128, 256),
+])
+def test_paged_core_server_resolves_on_tpu_compiles_for_v5e(
+        one_chip, as_on_tpu, name, b, h, hkv, c, d, window):
+    """The engine's decode wave (C == 1) and prefill chunk (C == 128)."""
+    kernel = pa.resolve_kernel("auto")
+    assert kernel == "pallas"
+    attend = pa.paged_decode_attention if c == 1 else \
+        pa.paged_chunk_attention
+
+    def fn(q, pk, pv, tables, pos):
+        return attend(q, pk, pv, tables, pos, d ** -0.5, window=window,
+                      kernel=kernel)
+
+    assert _kernels_in(fn, *_paged_args(one_chip, b, h, hkv, c, d)) == 1
+
+
+def test_lax_paged_core_compiles_for_v5e(one_chip):
+    """The portable core is what `paged_kernel="lax"` serves from on a
+    chip; it has no kernel of its own."""
+    def fn(q, pk, pv, tables, pos):
+        return pa.paged_decode_attention(q, pk, pv, tables, pos, 64 ** -0.5,
+                                         kernel="lax")
+
+    assert _kernels_in(fn, *_paged_args(one_chip, 8, 12, 12, 1, 64)) == 0
