@@ -57,6 +57,7 @@ from ..framework import state
 from ..framework.tensor import Tensor
 from ..jit import InstrumentedStepMixin, grad_norm_sentinel
 from ..utils import chaos, telemetry
+from ..utils.profiler import RecordEvent
 from . import mesh as mesh_mod
 
 #: per-device bytes of the dp-sharded optimizer state gathered at the
@@ -460,18 +461,24 @@ class ShardedTrainStep(InstrumentedStepMixin):
         inputs = inputs if isinstance(inputs, (list, tuple)) else (inputs,)
         labels = labels if isinstance(labels, (list, tuple)) else (labels,)
         self._step_i += 1
-        lr = jnp.asarray(self.optimizer.get_lr(), jnp.float32)
-        args = (self.params, self.buffers, self.opt_state, self.grad_acc,
-                state.next_rng_key(), lr,
-                jnp.asarray(self._step_i, jnp.int32),
-                self._shard_batch(inputs), self._shard_batch(labels))
-        with self.mesh:
-            if self._recorder is not None:
-                loss, outs = self._instrumented_call(args)
-            else:
-                (loss, self.params, self.buffers, self.opt_state,
-                 self.grad_acc, outs, self._last_grad_norm,
-                 self._last_nonfinite) = self._compiled(*args)
+        # the same spans as jit.TrainStep: a step annotation, with
+        # staging (lr, key, batch placement) and the dispatch inside
+        with RecordEvent("train", step_num=self._step_i):
+            with RecordEvent("train/stage", step=self._step_i):
+                lr = jnp.asarray(self.optimizer.get_lr(), jnp.float32)
+                args = (self.params, self.buffers, self.opt_state,
+                        self.grad_acc, state.next_rng_key(), lr,
+                        jnp.asarray(self._step_i, jnp.int32),
+                        self._shard_batch(inputs),
+                        self._shard_batch(labels))
+            with RecordEvent("train/dispatch", step=self._step_i), \
+                    self.mesh:
+                if self._recorder is not None:
+                    loss, outs = self._instrumented_call(args)
+                else:
+                    (loss, self.params, self.buffers, self.opt_state,
+                     self.grad_acc, outs, self._last_grad_norm,
+                     self._last_nonfinite) = self._compiled(*args)
         if self.return_outputs:
             return Tensor(loss), _wrap(outs)
         return Tensor(loss)

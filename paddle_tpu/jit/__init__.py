@@ -20,6 +20,7 @@ from ..framework import state
 from ..framework.tensor import Tensor
 from ..nn.layer import Layer
 from ..utils import chaos
+from ..utils.profiler import RecordEvent
 
 
 def _unwrap(x):
@@ -542,17 +543,22 @@ class TrainStep(InstrumentedStepMixin):
         inputs = inputs if isinstance(inputs, (list, tuple)) else (inputs,)
         labels = labels if isinstance(labels, (list, tuple)) else (labels,)
         self._step_i += 1
-        lr = jnp.asarray(self.optimizer.get_lr(), jnp.float32)
-        args = (self.params, self.buffers, self.opt_state, self.grad_acc,
-                state.next_rng_key(),
-                lr, jnp.asarray(self._step_i, jnp.int32),
-                _unwrap(tuple(inputs)), _unwrap(tuple(labels)))
-        if self._recorder is not None:
-            loss, outs = self._instrumented_call(args)
-        else:
-            (loss, self.params, self.buffers, self.opt_state, self.grad_acc,
-             outs, self._last_grad_norm, self._last_nonfinite) = \
-                self._compiled(*args)
+        # a step annotation on the profiler's clock (its step view
+        # groups by it), the host's two parts inside
+        with RecordEvent("train", step_num=self._step_i):
+            with RecordEvent("train/stage", step=self._step_i):
+                lr = jnp.asarray(self.optimizer.get_lr(), jnp.float32)
+                args = (self.params, self.buffers, self.opt_state,
+                        self.grad_acc, state.next_rng_key(),
+                        lr, jnp.asarray(self._step_i, jnp.int32),
+                        _unwrap(tuple(inputs)), _unwrap(tuple(labels)))
+            with RecordEvent("train/dispatch", step=self._step_i):
+                if self._recorder is not None:
+                    loss, outs = self._instrumented_call(args)
+                else:
+                    (loss, self.params, self.buffers, self.opt_state,
+                     self.grad_acc, outs, self._last_grad_norm,
+                     self._last_nonfinite) = self._compiled(*args)
         if self.return_outputs:
             return Tensor(loss), _wrap(outs)
         return Tensor(loss)

@@ -363,7 +363,7 @@ def _pallas_call(b, h, c, d, hkv, bs, nblk, scale, window, dtype_name,
     return pl.pallas_call(
         kernel, grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, hkv, rc, d), jnp.float32),
-        interpret=interpret)
+        interpret=interpret, name="paged_attention")
 
 
 def _pallas_core(q, pk, pv, tables, start, scale, window=None):
@@ -386,5 +386,8 @@ def _pallas_core(q, pk, pv, tables, start, scale, window=None):
                         None if window is None else int(window),
                         str(pk.dtype),
                         jax.default_backend() != "tpu")
-    out = call(tables.astype(jnp.int32), start, off, qr, pk, pv)
+    # the scope, innermost at the call, is what names the instruction
+    # in a device trace ("%paged_attention.1 = ... custom-call")
+    with jax.named_scope("paged_attention"):
+        out = call(tables.astype(jnp.int32), start, off, qr, pk, pv)
     return out.reshape(b, h, c, d).astype(pv.dtype)

@@ -277,20 +277,25 @@ def _flash_fwd_pallas(q, k, v, causal, scale, bshd=False,
     kernel = functools.partial(_fwd_kernel, scale=s, causal=causal,
                                kv_len=sk, q_len=sq, bk=bk_, dp=d_pad,
                                gsz=gsz, window=window)
-    out, lse = pl.pallas_call(
-        kernel,
-        grid=(nprog, sq // bq_),
-        in_specs=[q_spec, kv_spec, kv_spec],
-        out_specs=[
-            q_spec,
-            pl.BlockSpec((1, gsz, sq), lambda bh, i: (bh, 0, 0)),
-        ],
-        out_shape=[
-            o_shape,
-            _sds((nprog, gsz, sq), jnp.float32, qr, kr, vr),
-        ],
-        interpret=interpret,
-    )(qr, kr, vr)
+    # the scope, innermost at the call, names the instruction in a
+    # device trace ("%flash_fwd.1 = ... custom-call"), whatever traced
+    # this function (jvp, transpose, a model's own scopes)
+    with jax.named_scope("flash_fwd"):
+        out, lse = pl.pallas_call(
+            kernel,
+            grid=(nprog, sq // bq_),
+            in_specs=[q_spec, kv_spec, kv_spec],
+            out_specs=[
+                q_spec,
+                pl.BlockSpec((1, gsz, sq), lambda bh, i: (bh, 0, 0)),
+            ],
+            out_shape=[
+                o_shape,
+                _sds((nprog, gsz, sq), jnp.float32, qr, kr, vr),
+            ],
+            interpret=interpret,
+            name="flash_fwd",
+        )(qr, kr, vr)
     if bshd:
         out = out.reshape(b, sq, h, d_pad)
     else:
@@ -506,30 +511,33 @@ def _flash_bwd_pallas(q, k, v, out, lse, g, causal, scale,
     lse_spec = pl.BlockSpec((1, gsz, sq), lambda bh, i: (bh, 0, 0))
     interpret = jax.default_backend() == "cpu"
     bq_, bk_ = _blk(_BQ, sq), _blk(_BK, sk)
-    dkv = pl.pallas_call(
-        functools.partial(_bwd_dkv_kernel, scale=s, causal=causal,
-                          kv_len=sk, q_len=sq, bq=bq_, bk=bk_, dp=d_pad,
-                          gsz=gsz, window=window),
-        grid=(nprog, sk // bk_),
-        in_specs=[fullspec(sq), qspec(bk_), qspec(bk_), fullspec(sq),
-                  lse_spec, lse_spec],
-        out_specs=[qspec(bk_), qspec(bk_)],
-        out_shape=dkv_shape,
-        interpret=interpret,
-    )(qr, kr, vr, dor, lse, dd)
-    dk, dv = dkv
+    with jax.named_scope("flash_bwd_dkv"):
+        dk, dv = pl.pallas_call(
+            functools.partial(_bwd_dkv_kernel, scale=s, causal=causal,
+                              kv_len=sk, q_len=sq, bq=bq_, bk=bk_,
+                              dp=d_pad, gsz=gsz, window=window),
+            grid=(nprog, sk // bk_),
+            in_specs=[fullspec(sq), qspec(bk_), qspec(bk_), fullspec(sq),
+                      lse_spec, lse_spec],
+            out_specs=[qspec(bk_), qspec(bk_)],
+            out_shape=dkv_shape,
+            interpret=interpret,
+            name="flash_bwd_dkv",
+        )(qr, kr, vr, dor, lse, dd)
 
-    dq = pl.pallas_call(
-        functools.partial(_bwd_dq_kernel, scale=s, causal=causal,
-                          kv_len=sk, q_len=sq, bq=bq_, bk=bk_, dp=d_pad,
-                          gsz=gsz, window=window),
-        grid=(nprog, sq // bq_),
-        in_specs=[qspec(bq_), fullspec(sk), fullspec(sk), qspec(bq_),
-                  lse_spec, lse_spec],
-        out_specs=qspec(bq_),
-        out_shape=dq_shape,
-        interpret=interpret,
-    )(qr, kr, vr, dor, lse, dd)
+    with jax.named_scope("flash_bwd_dq"):
+        dq = pl.pallas_call(
+            functools.partial(_bwd_dq_kernel, scale=s, causal=causal,
+                              kv_len=sk, q_len=sq, bq=bq_, bk=bk_,
+                              dp=d_pad, gsz=gsz, window=window),
+            grid=(nprog, sq // bq_),
+            in_specs=[qspec(bq_), fullspec(sk), fullspec(sk), qspec(bq_),
+                      lse_spec, lse_spec],
+            out_specs=qspec(bq_),
+            out_shape=dq_shape,
+            interpret=interpret,
+            name="flash_bwd_dq",
+        )(qr, kr, vr, dor, lse, dd)
 
     if bshd:
         dq = dq.reshape(b, sq, h, d_pad)

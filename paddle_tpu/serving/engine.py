@@ -35,14 +35,9 @@ import numpy as np
 
 from ..framework.tensor import Tensor
 from ..utils import chaos, telemetry
+from ..utils.profiler import RecordEvent
 
 HEALTH_STATES = ("ok", "degraded", "draining")
-
-# program-cost memo keyed by engine shape signature: every fleet
-# replica built from one factory shares a single lowering-level cost
-# analysis instead of paying one per engine (the fleet tests spawn
-# dozens of engines over one model)
-_PROGRAM_COST_CACHE = {}
 
 
 def _infer_cache_dtype(params):
@@ -246,7 +241,21 @@ class ServingEngine:
         # (the paged engine's per-chunk prefill) can emit
         # request-correlated trace events
         self._slot_trace = {}
-        self._program_costs_memo = None
+        # chrome-trace process row of this engine's spans (the
+        # scheduler's `trace_pid` setter keeps it equal to its own)
+        self.trace_pid = 0
+        # seconds of this engine's own phases (`wave.*`, `prefill.*`,
+        # `unfed`: serving/metrics.PHASES) since the scheduler last took
+        # them (take_phase_seconds, once a round). The engine is driven
+        # by one thread at a time, so no lock.
+        self._phase_acc = {}
+        # perf_counter() at which the device last ran out of work: a
+        # blocking read of a program's output returned and no program
+        # has been dispatched since (programs run in order and each
+        # takes the donated cache of the one before, so after such a
+        # read nothing is queued). None while a program is in flight,
+        # before the first one, and after drop_unfed().
+        self._unfed_since = None
 
         self._jit = bool(jit_compile)
         self._metrics_server = None
@@ -377,45 +386,40 @@ class ServingEngine:
         to the request's chrome flow. Cleared at retirement."""
         self._slot_trace[slot] = (int(trace_id), int(trace_pid))
 
-    def program_costs(self):
-        """FLOPs / bytes-accessed per invocation of this engine's two
-        compiled programs, from the xprof registry's specs at THIS
-        engine's real shapes (lowering-level HLO cost analysis — no
-        second backend compile; the same numbers
-        scripts/hlo_baseline.json banks for the canonical shapes).
-        Returns {"decode_wave": {...}|None, "prefill": {...}|None};
-        memoized per engine AND per shape signature process-wide, so a
-        fleet of identical replicas lowers once. {} when the audit
-        registry cannot analyze on this jax build."""
-        if self._program_costs_memo is not None:
-            return self._program_costs_memo
-        # caches are part of the key: they carry the pool/cache dims
-        # (block_size, num_blocks, cache dtype) that change the
-        # program's bytes-accessed even over identical weights
-        sig = (type(self).__name__, self.num_slots, self.max_len,
-               self.prefill_len,
-               tuple((tuple(leaf.shape), str(leaf.dtype))
-                     for leaf in jax.tree_util.tree_leaves(
-                         (self._params, self._buffers, self._caches))))
-        costs = _PROGRAM_COST_CACHE.get(sig)
-        if costs is None:
-            from ..tools.xprof.registry import (engine_program_specs,
-                                                program_cost)
-            costs = {}
-            try:
-                for spec in engine_program_specs(self):
-                    name = spec["name"]
-                    key = ("prefill" if "prefill" in name
-                           else "draft_wave" if "draft" in name
-                           else "verify" if "verify" in name
-                           else "decode_wave")
-                    costs[key] = program_cost(spec)
-            except Exception:   # noqa: BLE001 — cost analysis is
-                costs = {}      # best-effort observability, never a
-                                # reason to fail serving
-            _PROGRAM_COST_CACHE[sig] = costs
-        self._program_costs_memo = costs
-        return costs
+    # ------------------------------------------------- phases and unfed
+    def _acc(self, phase, ev):
+        """Add a finished span's seconds to this round's `phase`."""
+        acc = self._phase_acc
+        acc[phase] = acc.get(phase, 0.0) + ev.elapsed
+
+    def _dispatched(self, phase, ev):
+        """A program was enqueued (`ev`: its dispatch span): the device
+        is fed. The seconds since the read that left it empty are the
+        host's: `unfed`."""
+        self._acc(phase, ev)
+        t = self._unfed_since
+        if t is not None:
+            self._unfed_since = None
+            acc = self._phase_acc
+            acc["unfed"] = acc.get("unfed", 0.0) + ev.end - t
+
+    def _read_back(self, phase, ev):
+        """A blocking read of a program's output returned (`ev`: its
+        span): nothing is queued on the device until the next
+        dispatch."""
+        self._acc(phase, ev)
+        self._unfed_since = ev.end
+
+    def drop_unfed(self):
+        """Forget the open unfed interval: the server is empty, and an
+        empty server is not a slow host."""
+        self._unfed_since = None
+
+    def take_phase_seconds(self):
+        """{phase: seconds} accumulated since the last call (the
+        scheduler folds it into ServingMetrics once a round)."""
+        acc, self._phase_acc = self._phase_acc, {}
+        return acc
 
     def set_health_state(self, state):
         """ok | degraded | draining — the scheduler flips this so
@@ -570,6 +574,11 @@ class ServingEngine:
             top_p=sampling["top_p"], logit_bias=sampling["bias"],
             dynamic_mask=sampling["dynamic_mask"])
 
+    def prefill_chunk_index(self, slot):
+        """Which chunk of the slot's prompt the next prefill_step runs
+        (the `chunk` id of its span); the dense bucket is one chunk."""
+        return 0
+
     def prefill_slot(self, slot, prompt, do_sample=False, temperature=1.0,
                      top_k=0, top_p=1.0, logit_bias=None,
                      dynamic_mask=False):
@@ -593,18 +602,26 @@ class ServingEngine:
             # exactly as it was, so the scheduler can fail JUST this
             # request and keep serving
             chaos.fire(chaos.PREFILL, slot=slot)
-        n = len(prompt)
-        padded = np.zeros((self.prefill_len,), np.int32)
-        padded[:n] = np.asarray(prompt, np.int32)
-        self._key, sub = jax.random.split(self._key)
-        first, self._caches = self._prefill(
-            self._params, self._buffers, self._caches,
-            jnp.asarray(padded), jnp.int32(n), jnp.int32(slot),
-            jnp.asarray(sampling["sample"]),
-            jnp.float32(sampling["temp"]),
-            jnp.int32(sampling["top_k"]), jnp.float32(sampling["top_p"]),
-            jnp.asarray(sampling["bias"]), sub)
-        first = int(np.asarray(first))
+        pid = self.trace_pid
+        with RecordEvent("serving/prefill/stage", pid=pid) as ev:
+            n = len(prompt)
+            padded = np.zeros((self.prefill_len,), np.int32)
+            padded[:n] = np.asarray(prompt, np.int32)
+            self._key, sub = jax.random.split(self._key)
+            args = (self._params, self._buffers, self._caches,
+                    jnp.asarray(padded), jnp.int32(n), jnp.int32(slot),
+                    jnp.asarray(sampling["sample"]),
+                    jnp.float32(sampling["temp"]),
+                    jnp.int32(sampling["top_k"]),
+                    jnp.float32(sampling["top_p"]),
+                    jnp.asarray(sampling["bias"]), sub)
+        self._acc("prefill.stage", ev)
+        with RecordEvent("serving/prefill/dispatch", pid=pid) as ev:
+            first, self._caches = self._prefill(*args)
+        self._dispatched("prefill.dispatch", ev)
+        with RecordEvent("serving/prefill/first_token", pid=pid) as ev:
+            first = int(np.asarray(first))
+        self._read_back("prefill.first_token", ev)
         self._arm_slot(slot, first, n, sampling)
         return first
 
@@ -633,21 +650,25 @@ class ServingEngine:
         # blocks here; a starved lane is excluded from this wave and
         # reported in last_starved_slots for the scheduler to preempt).
         # Idempotent, so a retried wave replays exactly.
-        active_now = self._prepare_wave(active_now)
+        pid = self.trace_pid
+        with RecordEvent("serving/wave/blocks", pid=pid) as ev:
+            active_now = self._prepare_wave(active_now)
+        self._acc("wave.blocks", ev)
         if not any(active_now):
             self.last_nonfinite_slots = []
             return {}
-        poison = np.zeros((self.num_slots,), bool)
-        if chaos.enabled():
-            hit = chaos.value(chaos.DECODE_WAVE_NAN)
-            if hit is not None:
-                for s in np.atleast_1d(hit):
-                    poison[int(s)] = True
-        self._key, sub = jax.random.split(self._key)
-        tok, pos, finite, self._caches = self._decode_wave(
-            *self._wave_args(active_now, poison, sub))
-        tok = np.asarray(tok)
-        finite = np.asarray(finite)
+        with RecordEvent("serving/wave/stage", pid=pid) as ev:
+            poison = self._wave_poison()
+            self._key, sub = jax.random.split(self._key)
+            args = self._wave_args(active_now, poison, sub)
+        self._acc("wave.stage", ev)
+        with RecordEvent("serving/wave/dispatch", pid=pid) as ev:
+            tok, pos, finite, self._caches = self._decode_wave(*args)
+        self._dispatched("wave.dispatch", ev)
+        with RecordEvent("serving/wave/wait", pid=pid) as ev:
+            tok = np.asarray(tok)
+            finite = np.asarray(finite)
+        self._read_back("wave.wait", ev)
         out, bad = {}, []
         for s, was_active in enumerate(active_now):
             if not was_active:
@@ -660,6 +681,17 @@ class ServingEngine:
             out[s] = int(tok[s])
         self.last_nonfinite_slots = bad
         return out
+
+    def _wave_poison(self):
+        """[S] bool of lanes whose logits the chaos harness poisons in
+        this wave (all False in production)."""
+        poison = np.zeros((self.num_slots,), bool)
+        if chaos.enabled():
+            hit = chaos.value(chaos.DECODE_WAVE_NAN)
+            if hit is not None:
+                for s in np.atleast_1d(hit):
+                    poison[int(s)] = True
+        return poison
 
     def _prepare_wave(self, active_now):
         """Hook: ensure each active lane's next cache write has backing

@@ -19,12 +19,20 @@ import threading
 
 from ..utils import flight_recorder, monitor, telemetry
 
-#: scheduler-round phases whose wall time is attributed per round —
+#: the four scheduler-round phases every round is attributed to —
 #: admission (queue pop + block alloc + staging), prefill_chunk (one
 #: prefill program per mid-admission slot), decode_wave (the batched
 #: wave INCLUDING its fused in-program sampling tail), host_dispatch
 #: (token emit + callbacks + retirement). Keys of snapshot()'s
-#: `phase_seconds`.
+#: `phase_seconds`, next to the finer ones the scheduler and the engine
+#: add (docs/observability.md "Spans"): `round` is the total of every
+#: round that had work; a dotted key nests inside its parent
+#: (`wave.blocks` / `wave.stage` / `wave.dispatch` / `wave.wait` inside
+#: decode_wave, `prefill.stage` / `prefill.dispatch` /
+#: `prefill.first_token` inside prefill_chunk); `token_masks` and
+#: `round_tail` are scheduler work outside the four; `unfed` (seconds
+#: from a blocking read of a program's output to the next program
+#: dispatch) overlaps the others.
 PHASES = ("admission", "prefill_chunk", "decode_wave", "host_dispatch")
 
 # legacy stat-registry keys (monitor.stat_get / all_stats)
@@ -69,33 +77,6 @@ _TPOT = telemetry.histogram(
     "Inter-token latency (gap between consecutive streamed tokens of "
     "one request; the first token's latency is TTFT, not TPOT)",
     buckets=TPOT_BUCKETS)
-# serving roofline: the decode wave is memory-bandwidth-bound, so BOTH
-# axes are exported — compute (MFU) and HBM-bandwidth utilization —
-# from the compiled program's own cost analysis (the same flops/bytes
-# scripts/hlo_baseline.json banks) over the measured wave time
-_MFU = telemetry.gauge(
-    "serving_mfu",
-    "Model-FLOPs utilization of the latest decode wave: program FLOPs "
-    "/ (wave seconds x device peak FLOP/s)")
-_HBM_UTIL = telemetry.gauge(
-    "serving_hbm_util",
-    "HBM-bandwidth utilization of the latest decode wave: program "
-    "bytes-accessed / (wave seconds x device peak HBM bandwidth) — the "
-    "roofline axis that actually binds decode")
-
-_DEVICE_PEAKS = []     # [(peak_flops, peak_hbm_bw) | None] resolved once
-
-
-def _device_peaks():
-    """The roofline denominators, resolved once per process — they are
-    device constants, and on_wave sits in the hottest serving loop
-    (sub-millisecond waves), where a JAX-client lookup per wave is real
-    overhead. None when the device is not in the peaks table: the
-    roofline gauges then stay unset and the snapshot's `mfu`/`hbm_util`
-    read None ("not measured")."""
-    if not _DEVICE_PEAKS:
-        _DEVICE_PEAKS.append(flight_recorder.device_peaks())
-    return _DEVICE_PEAKS[0]
 # resilience counters (the chaos harness proves each one moves —
 # scripts/chaos_serving.py; kinds are a small closed set)
 _FAULTS = telemetry.counter(
@@ -212,13 +193,9 @@ class ServingMetrics:
         self._block_total_waves = 0
         self._prefix_base = None
         self._prefix_last = None
-        # per-phase wall time (seconds, accumulated per scheduler
-        # round) and the wave-integral roofline numerators: program
-        # flops/bytes x waves over the summed wave seconds
+        # per-phase wall time (seconds, folded in once per scheduler
+        # round)
         self._phase_seconds = {}
-        self._wave_seconds = 0.0
-        self._wave_flops = 0.0
-        self._wave_bytes = 0.0
         # speculative decoding tallies (0 on non-speculative engines)
         self._spec_proposed = 0
         self._spec_accepted = 0
@@ -250,31 +227,14 @@ class ServingMetrics:
         monitor.stat_add(PREFILLS)
         _PREFILLS.inc()
 
-    def on_wave(self, n_active, wave_s=None, flops=None,
-                bytes_accessed=None):
-        """One dispatched decode wave. `wave_s` is the measured wave
-        wall time and flops/bytes_accessed the compiled program's cost
-        per invocation (engine.program_costs — the numbers the xprof
-        baseline banks); together they produce the serving roofline
-        gauges. Cost-less calls (analysis unavailable) still count the
-        wave."""
+    def on_wave(self, n_active):
+        """One dispatched decode wave over `n_active` lanes."""
         monitor.stat_add(DECODE_WAVES)
         _WAVES.inc()
         _SLOTS_ACTIVE.set(int(n_active))
         with self._lock:
             self._active_slot_waves += int(n_active)
             self._total_slot_waves += self.num_slots
-            if wave_s is not None and wave_s > 0:
-                self._wave_seconds += float(wave_s)
-                self._wave_flops += float(flops or 0.0)
-                self._wave_bytes += float(bytes_accessed or 0.0)
-        peaks = _device_peaks()
-        if wave_s is not None and wave_s > 0 and peaks:
-            peak_flops, peak_bw = peaks
-            if flops:
-                _MFU.set(float(flops) / (wave_s * peak_flops))
-            if bytes_accessed:
-                _HBM_UTIL.set(float(bytes_accessed) / (wave_s * peak_bw))
 
     def on_spec(self, proposed, accepted):
         """One speculative wave's draft economics (scheduler-reported:
@@ -292,14 +252,14 @@ class ServingMetrics:
             if self._spec_proposed:
                 _SPEC_RATE.set(self._spec_accepted / self._spec_proposed)
 
-    def on_phase(self, phase, seconds):
-        """Attribute one scheduler-round phase's wall time (keys in
-        `PHASES`; snapshot() reports the accumulated split)."""
-        if seconds is None:
-            return
+    def on_phases(self, seconds):
+        """Fold one scheduler round's {phase: seconds} (the keys the
+        `PHASES` comment lists) into the accumulated split that
+        snapshot() reports: one lock a round."""
         with self._lock:
-            self._phase_seconds[phase] = (
-                self._phase_seconds.get(phase, 0.0) + float(seconds))
+            acc = self._phase_seconds
+            for phase, s in seconds.items():
+                acc[phase] = acc.get(phase, 0.0) + s
 
     def on_queue_depth(self, depth):
         monitor.stat_max(QUEUE_DEPTH_PEAK, int(depth))  # process-wide peak
@@ -372,11 +332,8 @@ class ServingMetrics:
                 p_hits = self._prefix_last[0] - self._prefix_base[0]
                 p_misses = self._prefix_last[1] - self._prefix_base[1]
             phase_seconds = dict(self._phase_seconds)
-            wave_s = self._wave_seconds
-            wave_flops, wave_bytes = self._wave_flops, self._wave_bytes
             spec_p, spec_a = self._spec_proposed, self._spec_accepted
             spec_w = self._spec_waves
-        peaks = _device_peaks()
         return {
             "requests_completed": self._latency.count(),
             "tokens_generated": tokens,
@@ -409,17 +366,11 @@ class ServingMetrics:
             "first_token_time": first_t,
             "last_token_time": last_t,
             # observability PR: inter-token latency (the second half of
-            # the TTFT/TPOT request-latency decomposition), the per-
-            # round phase split, and the wave-integral roofline —
-            # flops/bytes per wave are the SAME numbers the xprof
-            # baseline banks, so these agree with hlo_baseline.json
+            # the TTFT/TPOT request-latency decomposition) and the
+            # per-round phase split
             "tpot_p50_s": self._tpot.percentile(50),
             "tpot_p99_s": self._tpot.percentile(99),
             "phase_seconds": phase_seconds,
-            "mfu": (wave_flops / (wave_s * peaks[0])
-                    if wave_s and wave_flops and peaks else None),
-            "hbm_util": (wave_bytes / (wave_s * peaks[1])
-                         if wave_s and wave_bytes and peaks else None),
             # speculative decoding (perf PR): 0/None on engines without
             # a draft model. accepted_per_wave is the headline number —
             # > 0 means each wave nets more than one token per lane

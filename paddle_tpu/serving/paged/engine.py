@@ -40,6 +40,7 @@ import hashlib
 
 from ...nn import paged_attention
 from ...utils import chaos, telemetry
+from ...utils.profiler import RecordEvent
 from .. import blackbox
 from ..engine import (ServingEngine, _filter_top_k_top_p, _raw,
                       _select_first_token, _select_wave_tokens)
@@ -271,37 +272,44 @@ class PagedServingEngine(ServingEngine):
         request's first generated token when the final chunk ran, None
         while chunks remain (decode waves continue in between)."""
         st = self._pending_prefill[slot]
-        if chaos.enabled():
-            # host-side, before the donated pool reaches the program — a
-            # fired fault leaves device state untouched; the scheduler
-            # fails just this request and frees its blocks
-            chaos.fire(chaos.PREFILL, slot=slot, chunk_start=st["next"])
-        c0, C, n, bs = st["next"], self.prefill_chunk_len, st["n"], \
-            self.block_size
-        trace = self._slot_trace.get(slot)
-        if trace is not None:
-            # chunk-indexed progress marker inside the request's PREFILL
-            # span: a long chunked admission's folding between decode
-            # waves is visible per chunk in the exported trace
-            telemetry.trace_instant(
-                trace[0], f"PREFILL_CHUNK[{c0 // C}]", pid=trace[1],
-                slot=slot, chunk_start=c0, prompt_len=n)
-        valid = min(C, n - c0)
-        chunk = np.zeros((C,), np.int32)
-        chunk[:valid] = st["prompt"][c0:c0 + valid]
-        last = c0 + C >= n
-        frontier = (n - 1) - c0 if last else 0
-        self._key, sub = jax.random.split(self._key)
-        sampling = st["sampling"]
-        first, self._caches = self._prefill(
-            *self._prefill_chunk_args(slot),
-            jnp.asarray(self._tables[slot]), jnp.asarray(chunk),
-            jnp.int32(c0), jnp.int32(valid), jnp.int32(frontier),
-            jnp.asarray(sampling["sample"]),
-            jnp.float32(sampling["temp"]),
-            jnp.int32(sampling["top_k"]),
-            jnp.float32(sampling["top_p"]),
-            jnp.asarray(sampling["bias"]), sub)
+        pid = self.trace_pid
+        with RecordEvent("serving/prefill/stage", pid=pid) as ev:
+            if chaos.enabled():
+                # host-side, before the donated pool reaches the program
+                # — a fired fault leaves device state untouched; the
+                # scheduler fails just this request and frees its blocks
+                chaos.fire(chaos.PREFILL, slot=slot,
+                           chunk_start=st["next"])
+            c0, C, n, bs = st["next"], self.prefill_chunk_len, st["n"], \
+                self.block_size
+            trace = self._slot_trace.get(slot)
+            if trace is not None:
+                # chunk-indexed progress marker inside the request's
+                # PREFILL span: a long chunked admission's folding
+                # between decode waves is visible per chunk in the
+                # exported trace
+                telemetry.trace_instant(
+                    trace[0], f"PREFILL_CHUNK[{c0 // C}]", pid=trace[1],
+                    slot=slot, chunk_start=c0, prompt_len=n)
+            valid = min(C, n - c0)
+            chunk = np.zeros((C,), np.int32)
+            chunk[:valid] = st["prompt"][c0:c0 + valid]
+            last = c0 + C >= n
+            frontier = (n - 1) - c0 if last else 0
+            self._key, sub = jax.random.split(self._key)
+            sampling = st["sampling"]
+            args = (*self._prefill_chunk_args(slot),
+                    jnp.asarray(self._tables[slot]), jnp.asarray(chunk),
+                    jnp.int32(c0), jnp.int32(valid), jnp.int32(frontier),
+                    jnp.asarray(sampling["sample"]),
+                    jnp.float32(sampling["temp"]),
+                    jnp.int32(sampling["top_k"]),
+                    jnp.float32(sampling["top_p"]),
+                    jnp.asarray(sampling["bias"]), sub)
+        self._acc("prefill.stage", ev)
+        with RecordEvent("serving/prefill/dispatch", pid=pid) as ev:
+            first, self._caches = self._prefill(*args)
+        self._dispatched("prefill.dispatch", ev)
         # full prompt blocks written by this chunk enter the prefix
         # cache — only now, so a concurrent admission can never share a
         # block whose content is not on the device yet
@@ -317,9 +325,15 @@ class PagedServingEngine(ServingEngine):
         if not last:
             return None
         del self._pending_prefill[slot]
-        first = int(np.asarray(first))
+        with RecordEvent("serving/prefill/first_token", pid=pid) as ev:
+            first = int(np.asarray(first))
+        self._read_back("prefill.first_token", ev)
         self._arm_slot(slot, first, n, sampling)
         return first
+
+    def prefill_chunk_index(self, slot):
+        st = self._pending_prefill[slot]
+        return st["next"] // self.prefill_chunk_len
 
     def _prefill_chunk_args(self, slot):
         """Leading argument tuple of the prefill-chunk program (the
@@ -951,43 +965,48 @@ class SpeculativePagedEngine(PagedServingEngine):
                 want = 0 if self.slot_dynamic_mask[s] else self.spec_k
                 spec_len[s] = max(0, min(want, limit))
         self._wave_spec_len = spec_len
-        active_now = self._prepare_wave(active_now)
+        pid = self.trace_pid
+        with RecordEvent("serving/wave/blocks", pid=pid) as ev:
+            active_now = self._prepare_wave(active_now)
+        self._acc("wave.blocks", ev)
         if not any(active_now):
             self.last_nonfinite_slots = []
             return {}
-        poison = np.zeros((self.num_slots,), bool)
-        if chaos.enabled():
-            hit = chaos.value(chaos.DECODE_WAVE_NAN)
-            if hit is not None:
-                for s in np.atleast_1d(hit):
-                    poison[int(s)] = True
-        self._key, dkey = jax.random.split(self._key)
-        self._key, vkey = jax.random.split(self._key)
-        tables = jnp.asarray(
-            np.where(np.asarray(active_now, bool)[:, None], self._tables,
-                     np.int32(BlockPool.SCRATCH)))
-        tok = jnp.asarray(self.slot_tok, jnp.int32)
-        pos = jnp.asarray(self.slot_pos, jnp.int32)
-        act = jnp.asarray(active_now, bool)
-        sampling = self._sampling_args()
-        sl = jnp.asarray(spec_len, jnp.int32)
-        # the draft wave takes no active mask: inactive lanes ride
-        # scratch table rows and their proposals are discarded by
-        # the verify tail's active where — one argument fewer keeps
-        # every draft input live for the donation audit
-        draft_toks, draft_probs, self._caches = self._draft_wave(
-            self._draft_params, self._draft_buffers, self._caches,
-            tables, tok, pos, *sampling, sl, dkey)
-        out_toks, n_emit, nxt, new_pos, finite, self._caches = \
-            self._decode_wave(
-                self._params, self._buffers, self._caches, tables, tok,
-                pos, act, *sampling, sl, draft_toks, draft_probs,
-                jnp.asarray(poison), vkey)
-        out_toks = np.asarray(out_toks)
-        n_emit = np.asarray(n_emit)
-        nxt = np.asarray(nxt)
-        new_pos = np.asarray(new_pos)
-        finite = np.asarray(finite)
+        with RecordEvent("serving/wave/stage", pid=pid) as ev:
+            poison = self._wave_poison()
+            self._key, dkey = jax.random.split(self._key)
+            self._key, vkey = jax.random.split(self._key)
+            tables = jnp.asarray(
+                np.where(np.asarray(active_now, bool)[:, None],
+                         self._tables, np.int32(BlockPool.SCRATCH)))
+            tok = jnp.asarray(self.slot_tok, jnp.int32)
+            pos = jnp.asarray(self.slot_pos, jnp.int32)
+            act = jnp.asarray(active_now, bool)
+            sampling = self._sampling_args()
+            sl = jnp.asarray(spec_len, jnp.int32)
+            poison = jnp.asarray(poison)
+        self._acc("wave.stage", ev)
+        with RecordEvent("serving/wave/dispatch", pid=pid) as ev:
+            # the draft wave takes no active mask: inactive lanes ride
+            # scratch table rows and their proposals are discarded by
+            # the verify tail's active where — one argument fewer keeps
+            # every draft input live for the donation audit
+            draft_toks, draft_probs, self._caches = self._draft_wave(
+                self._draft_params, self._draft_buffers, self._caches,
+                tables, tok, pos, *sampling, sl, dkey)
+            out_toks, n_emit, nxt, new_pos, finite, self._caches = \
+                self._decode_wave(
+                    self._params, self._buffers, self._caches, tables,
+                    tok, pos, act, *sampling, sl, draft_toks,
+                    draft_probs, poison, vkey)
+        self._dispatched("wave.dispatch", ev)
+        with RecordEvent("serving/wave/wait", pid=pid) as ev:
+            out_toks = np.asarray(out_toks)
+            n_emit = np.asarray(n_emit)
+            nxt = np.asarray(nxt)
+            new_pos = np.asarray(new_pos)
+            finite = np.asarray(finite)
+        self._read_back("wave.wait", ev)
         out, bad, waved = {}, [], []
         proposed = accepted = 0
         for s, was_active in enumerate(active_now):

@@ -38,14 +38,15 @@ by injection in scripts/chaos_serving.py):
     with finish_reason "rejected"), `drain()` stops admissions while
     accepted work runs to completion (/healthz: "draining").
 
-Observability (docs/observability.md "SLO engine & fleet tracing"):
-every round is split into admission / prefill_chunk / decode_wave /
-host_dispatch phase spans (traced AND metered — the sampling tail is
-fused inside the wave program, so it deliberately has no host-side
-span), each wave's measured time is divided into the compiled
-program's own cost analysis for the `serving_mfu` /
-`serving_hbm_util` roofline gauges, and an optional `slo=SLOPolicy`
-feeds completions into a burn-rate window served on /healthz.
+Observability (docs/observability.md "Spans"): every round is one
+`serving/round` span holding the admission / prefill / token_masks /
+decode_wave / host_dispatch / round_tail phase spans, and the engine
+nests its own inside prefill and decode_wave (stage, dispatch, wait).
+Every span is traced on the profiler's clock AND metered into
+`phase_seconds`, folded in once a round (the sampling tail is fused
+inside the wave program, so it deliberately has no host-side span).
+An optional `slo=SLOPolicy` feeds completions into a burn-rate window
+served on /healthz.
 
 Thread-model: submit() is safe from any producer thread (the bench
 script's Poisson arrival generator); the wave loop itself runs wherever
@@ -99,10 +100,15 @@ class Scheduler:
         self._handoff_ready = []
         self.engine = engine
         self.max_queue = max_queue
-        # chrome-trace process row for this scheduler's spans/requests
-        # (0 = single-engine; a fleet Replica sets replica_id + 1 so
-        # the router's merged trace shows each replica on its own row)
+        # chrome-trace process row for this scheduler's (and its
+        # engine's) spans/requests (0 = single-engine; a fleet Replica
+        # sets replica_id + 1 so the router's merged trace shows each
+        # replica on its own row)
         self.trace_pid = 0
+        # this round's {phase: seconds}, folded into the metrics (with
+        # the engine's own) when the round ends
+        self._phases = {}
+        self._round_worked = False
         # optional SLO tracking (serving/slo.py): completions feed the
         # sliding window, every round re-evaluates, and the burn-rate
         # verdict rides /healthz next to queue depth
@@ -116,25 +122,6 @@ class Scheduler:
         self._alerts = None
         if self.slo_engine is not None:
             engine.attach_health_probe(self._health_extras)
-        # program flops/bytes per wave for the roofline gauges —
-        # resolved NOW, at construction, not at the first wave: the
-        # lowering-level cost analysis can stall for seconds on a real
-        # model, and a stall between wave and token-emit would be
-        # stamped into every in-flight request's inter-token gap,
-        # spiking the very TPOT/SLO window it feeds. program_costs is
-        # memoized per shape signature, so a fleet pays one lowering.
-        # A speculative engine's wave is TWO programs (draft + verify):
-        # their costs sum into the per-wave roofline numerators.
-        costs = engine.program_costs()
-        self._wave_cost = costs.get("decode_wave") or {}
-        if "verify" in costs or "draft_wave" in costs:
-            merged = {}
-            for part in (costs.get("draft_wave"), costs.get("verify")):
-                for k, v in (part or {}).items():
-                    if isinstance(v, (int, float)):
-                        merged[k] = merged.get(k, 0.0) + v
-            self._wave_cost = merged
-        self.last_wave_s = None
         self.wave_retries = max(0, int(wave_retries))
         self.retry_backoff_s = float(retry_backoff_s)
         # paged engines: a request may be preempted by recompute (its KV
@@ -181,6 +168,19 @@ class Scheduler:
         # the wave counter names waves in `wave` events
         self._round = 0
         self._wave_seq = 0
+
+    @property
+    def trace_pid(self):
+        return self._trace_pid
+
+    @trace_pid.setter
+    def trace_pid(self, pid):
+        self._trace_pid = self.engine.trace_pid = int(pid)
+
+    def _phase(self, phase, ev):
+        """Add a finished span's seconds to this round's `phase`."""
+        if ev.elapsed is not None:
+            self._phases[phase] = self._phases.get(phase, 0.0) + ev.elapsed
 
     def _replica_ord(self):
         """This scheduler's fleet replica id for journal events (the
@@ -515,9 +515,12 @@ class Scheduler:
                 req._finish("timeout")
                 self._complete(req)
                 continue
+            ev = RecordEvent(
+                "serving/prefill", pid=self.trace_pid,
+                request_id=req.request_id, slot=slot,
+                chunk=self.engine.prefill_chunk_index(slot))
             try:
-                with RecordEvent("serving/prefill",
-                                 pid=self.trace_pid) as ev:
+                with ev:
                     first = self.engine.prefill_step(slot)
             except Exception as e:   # noqa: BLE001 — fault barrier
                 self.last_error = e
@@ -525,7 +528,7 @@ class Scheduler:
                     return True
                 continue
             finally:
-                self.metrics.on_phase("prefill_chunk", ev.elapsed)
+                self._phase("prefill_chunk", ev)
             self._prefill_fail_streak = 0
             if first is None:
                 continue             # mid-prefill: decode waves go on
@@ -624,7 +627,7 @@ class Scheduler:
                       slot=slot,
                       error=None if error is None else repr(error))
 
-    def _run_wave_with_retry(self):
+    def _run_wave_with_retry(self, lanes):
         """The decode wave behind a bounded-exponential-backoff retry.
         Returns the wave's {slot: token} dict, or None after degrading
         (budget exhausted). The engine raises BEFORE consuming its key
@@ -636,10 +639,10 @@ class Scheduler:
         for attempt in range(self.wave_retries + 1):
             try:
                 with RecordEvent("serving/decode_wave",
-                                 pid=self.trace_pid) as ev:
+                                 pid=self.trace_pid, round=self._round,
+                                 lanes=lanes) as ev:
                     toks = self.engine.decode_wave()
-                self.last_wave_s = ev.elapsed
-                self.metrics.on_phase("decode_wave", ev.elapsed)
+                self._phase("decode_wave", ev)
                 return toks
             except Exception as e:   # noqa: BLE001 — fault barrier
                 self.last_error = e
@@ -843,27 +846,55 @@ class Scheduler:
         # re-submits and re-faults in the same round order, so the
         # counter must tick before ANY of this round's decisions
         self._round += 1
+        eng = self.engine
+        self._phases = {}
+        self._round_worked = False
+        pending = 0
+        ev = RecordEvent("serving/round", pid=self.trace_pid,
+                         round=self._round, lanes=sum(eng.slot_active),
+                         prefilling=len(eng.prefilling_slots()))
+        try:
+            with ev:
+                pending = self._run_round()
+        finally:
+            # one fold a round: the scheduler's phases, the engine's
+            # (wave.*, prefill.*, unfed), and `round`, the total of a
+            # round that had work
+            phases = self._phases
+            for k, v in eng.take_phase_seconds().items():
+                phases[k] = phases.get(k, 0.0) + v
+            if self._round_worked and ev.elapsed is not None:
+                phases["round"] = ev.elapsed
+            self.metrics.on_phases(phases)
+            if not (self._round_worked and pending):
+                # an empty server is not a slow host: what passes until
+                # the next dispatch is not the host's doing
+                eng.drop_unfed()
+        return pending
+
+    def _run_round(self):
         with RecordEvent("serving/admission", pid=self.trace_pid) as ev:
             self._admit()
-        self.metrics.on_phase("admission", ev.elapsed)
+        self._phase("admission", ev)
         # captured BEFORE the advance: a prefill that admits, emits its
         # first token, and retires within one round still counts as a
         # working round for the pool sample below
         prefilled = bool(self.engine.prefilling_slots())
+        self._round_worked = prefilled
         if self._advance_prefills():
             return 0                         # degraded mid-advance
-        self._refresh_token_masks()
+        with RecordEvent("serving/token_masks", pid=self.trace_pid) as ev:
+            self._refresh_token_masks()
+        self._phase("token_masks", ev)
         active = self.engine.active_slots()
         if active:
-            toks = self._run_wave_with_retry()
+            self._round_worked = True
+            toks = self._run_wave_with_retry(len(active))
             if toks is None:                 # degraded: everything is
                 return 0                     # resolved, nothing pending
             waved = len(active) - len(self.engine.last_starved_slots)
             if waved > 0:     # all-starved rounds dispatch no program —
-                self.metrics.on_wave(  # don't count phantom waves
-                    waved, wave_s=self.last_wave_s,
-                    flops=self._wave_cost.get("flops"),
-                    bytes_accessed=self._wave_cost.get("bytes_accessed"))
+                self.metrics.on_wave(waved)  # don't count phantom waves
                 self._record_spec_wave(waved)
             bb = blackbox.get_recorder()
             if bb is not None and toks:
@@ -919,25 +950,36 @@ class Scheduler:
                             slot, tok, check_length=j == len(emitted) - 1)
                         if self._slot_req[slot] is None:
                             break
-            self.metrics.on_phase("host_dispatch", ev.elapsed)
+            self._phase("host_dispatch", ev)
             # AFTER the dispatch loop: a priority victim was in this
             # wave — evicting it first would drop the token it just
             # produced (starved lanes were never in `toks`, so they
             # don't care about the ordering)
             self._preempt_starved()
+        with RecordEvent("serving/round_tail", pid=self.trace_pid) as ev:
+            pending = self._round_tail()
+        self._phase("round_tail", ev)
+        return pending
+
+    def _round_tail(self):
+        """What a round does after its tokens are out: the pool sample,
+        the SLO verdict, the history sampler, the alert rules, the
+        chrome counter track. Returns the requests still in flight or
+        queued."""
+        worked = self._round_worked
         pool = getattr(self.engine, "block_pool", None)
-        if pool is not None and (active or prefilled):
+        if pool is not None and worked:
             # pool sample per WORKING round (idle spins don't dilute the
             # integral — same cadence discipline as on_wave's slot
             # occupancy): utilization + prefix tallies ride the snapshot
             self.metrics.on_blocks(pool.used, pool.usable)
             self.metrics.on_prefix_totals(pool.prefix_hits,
                                           pool.prefix_misses)
-        if self.slo_engine is not None and (active or prefilled):
+        if self.slo_engine is not None and worked:
             # re-evaluate once per WORKING round: gauges track live,
             # transitions journal, /healthz serves the cached verdict
             self.slo_engine.evaluate()
-        if active or prefilled:
+        if worked:
             # the history sampler and the anomaly detectors run on the
             # same working-round cadence (idle spins sample nothing:
             # they would flood the ladders with flat lines and dilute

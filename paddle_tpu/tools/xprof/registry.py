@@ -91,24 +91,6 @@ TRACKED_PROGRAMS = ("serving_decode_wave", "serving_prefill",
                     "prefill_flash_attention")
 
 
-def program_cost(spec):
-    """Lowering-level cost of ONE tracked-program invocation:
-    {"flops", "bytes_accessed", ...} via the HLO cost analysis (no
-    second backend compile), or None when this jax build can't answer.
-    These are the exact numbers `scripts/hlo_baseline.json` banks per
-    program, which is what lets the serving roofline gauges
-    (`serving_mfu` / `serving_hbm_util`) be checked against the
-    committed baseline."""
-    import jax
-
-    from paddle_tpu.utils import flight_recorder
-
-    jitted = spec.get("jitted")
-    if jitted is None:
-        jitted = jax.jit(spec["fn"], **spec.get("jit_kwargs", {}))
-    return flight_recorder.cost_analysis(jitted, *spec["args"])
-
-
 def engine_program_specs(engine, prefix=None):
     """Audit specs for a LIVE engine's compiled programs, with the
     engine's actual shapes — used on the canonical engines below and by

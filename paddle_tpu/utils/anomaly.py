@@ -294,9 +294,6 @@ def default_serving_rules(detector_kw=None):
         AlertRule("queue_depth_anomaly",
                   ewma_check(_gauge_value("serving_queue_depth"), **up),
                   "queue depth step-change (admission outrunning decode)"),
-        AlertRule("hbm_util_anomaly",
-                  ewma_check(_gauge_value("serving_hbm_util"), **up),
-                  "HBM-roofline utilization shifted regime mid-stream"),
         AlertRule("spec_acceptance_anomaly",
                   ewma_check(
                       _gauge_value("serving_spec_acceptance_rate"),

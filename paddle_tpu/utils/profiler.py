@@ -14,9 +14,11 @@ import json
 import threading
 import time
 
+from jax.profiler import StepTraceAnnotation, TraceAnnotation
+
 _lock = threading.Lock()
 _enabled = False
-_events = []          # (name, start_s, dur_s, thread_id, pid)
+_events = []          # (name, start_s, dur_s, thread_id, pid, ids)
 _raw_events = []      # chrome-format dicts (async spans, flow, counters)
 _trace_gen = 0        # bumped when _raw_events is cleared (new trace)
 _active_trace_dir = None
@@ -57,38 +59,65 @@ def emit_trace_event(event):
 
 
 class RecordEvent:
-    """RAII host event (ref platform/profiler.h:127). Usable as context
-    manager or decorator; nesting is recorded flat like the reference.
+    """RAII host span (ref platform/profiler.h:127): the program's ONE
+    span primitive, with two sinks. Usable as context manager or
+    decorator.
+
+      * the profiler's clock: every span enters a
+        `jax.profiler.TraceAnnotation(name, **ids)`, so it lands in the
+        host plane of whatever profiler session is running (this
+        module's `start_profiler(trace_dir=...)`, an operator's
+        `jax.profiler.start_trace`, a benchmark's traced window) next
+        to the device lines. With no session active the annotation is
+        inert: name and ids are encoded only while one records.
+      * the chrome buffer (`export_chrome_tracing`, the fleet trace
+        merge): appended only after `start_profiler()`; the ids become
+        the event's `args`.
+
+    Nesting gives the parent (a span entered inside another lies inside
+    it on the same thread); the ids (`round`, `request_id`, `slot`,
+    `chunk`, `lanes`, `step`) tie one round's or one request's spans
+    together. `step_num=` makes the annotation a
+    `StepTraceAnnotation`, which the profiler's step view groups by.
 
     `pid` places the slice on a chrome-trace process row (the fleet
     router exports each replica's scheduler activity on its own row —
-    pid = replica_id + 1, pid 0 is the router/host). `elapsed` holds
-    the measured duration in seconds after exit whether or not the
-    profiler was recording, so callers can both trace AND meter one
-    timed region (the scheduler's per-phase attribution)."""
+    pid = replica_id + 1, pid 0 is the router/host). `elapsed` (seconds)
+    and `end` (`time.perf_counter()` at exit) are set after exit whether
+    or not anything was recording, so callers can both trace AND meter
+    one timed region (the scheduler's per-phase attribution)."""
 
-    def __init__(self, name, pid=0):
+    __slots__ = ("name", "pid", "ids", "elapsed", "end", "_t0", "_ann")
+
+    def __init__(self, name, pid=0, **ids):
         self.name = name
         self.pid = int(pid)
-        self.elapsed = None
-        self._t0 = None
+        self.ids = ids
+        self.elapsed = self.end = self._t0 = self._ann = None
 
     def __enter__(self):
+        cls = StepTraceAnnotation if "step_num" in self.ids \
+            else TraceAnnotation
+        self._ann = cls(self.name, **self.ids)
+        self._ann.__enter__()
         self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc):
         if self._t0 is not None:
-            self.elapsed = time.perf_counter() - self._t0
+            self.end = time.perf_counter()
+            self.elapsed = self.end - self._t0
+            self._ann.__exit__(*exc)
             if _enabled:
                 with _lock:
                     _events.append((self.name, self._t0, self.elapsed,
-                                    threading.get_ident(), self.pid))
+                                    threading.get_ident(), self.pid,
+                                    self.ids))
         return False
 
     def __call__(self, fn):
         def wrapped(*a, **k):
-            with RecordEvent(self.name):
+            with RecordEvent(self.name, pid=self.pid, **self.ids):
                 return fn(*a, **k)
         return wrapped
 
@@ -132,7 +161,7 @@ def summary(sorted_key="total"):
     agg = {}
     with _lock:
         evs = list(_events)
-    for name, _t0, dur, _tid, _pid in evs:
+    for name, _t0, dur, _tid, _pid, _ids in evs:
         a = agg.setdefault(name, [0, 0.0, float("inf"), 0.0])
         a[0] += 1
         a[1] += dur
@@ -167,8 +196,9 @@ def export_chrome_tracing(path, extra_events=()):
         raw = [dict(e) for e in _raw_events]
     events = [
         {"name": name, "ph": "X", "ts": t0 * 1e6, "dur": dur * 1e6,
-         "pid": pid, "tid": tid % 10000, "cat": "host"}
-        for name, t0, dur, tid, pid in evs]
+         "pid": pid, "tid": tid % 10000, "cat": "host",
+         **({"args": ids} if ids else {})}
+        for name, t0, dur, tid, pid, ids in evs]
     trace = {"traceEvents": events + raw + [dict(e)
                                             for e in extra_events]}
     with open(path, "w") as f:

@@ -165,10 +165,6 @@ def fleet_snapshot(router, reqs, wall):
         "tpot_p99_s": _agg(snaps, "tpot_p99_s", max),
         "latency_p50_s": _agg(snaps, "latency_p50_s", max),
         "latency_p99_s": _agg(snaps, "latency_p99_s", max),
-        # roofline utilization: mean across replicas (each replica's
-        # waves measure the same compiled program)
-        "mfu": _agg(snaps, "mfu", lambda v: sum(v) / len(v)),
-        "hbm_util": _agg(snaps, "hbm_util", lambda v: sum(v) / len(v)),
         "slot_occupancy": _agg(
             snaps, "slot_occupancy", lambda v: sum(v) / len(v)),
         "queue_depth_peak": _agg(snaps, "queue_depth_peak", max),
@@ -285,8 +281,8 @@ def main():
                          "load point first runs a matched "
                          "kernel=reference baseline row with the same "
                          "arrival seed, and the fused row reports "
-                         "tokens/s, TPOT, serving_hbm_util and "
-                         "program bytes_accessed deltas against it")
+                         "tokens/s, TPOT and program bytes_accessed "
+                         "deltas against it")
     ap.add_argument("--block-size", type=int, default=16,
                     help="paged: tokens per KV block")
     ap.add_argument("--num-blocks", type=int, default=None,
@@ -689,10 +685,6 @@ def main():
                                      3),
                 "tpot_p99_ms": round((snap.get("tpot_p99_s") or 0) * 1e3,
                                      3),
-                "serving_mfu": (None if snap.get("mfu") is None
-                                else round(snap["mfu"], 6)),
-                "serving_hbm_util": (None if snap.get("hbm_util") is None
-                                     else round(snap["hbm_util"], 6)),
                 "slot_occupancy": round(snap["slot_occupancy"], 4),
                 "queue_depth_peak": snap["queue_depth_peak"],
                 # resilience tallies THIS load point: shedding onset vs
@@ -793,7 +785,6 @@ def main():
                 "tokens_per_s_delta": _kdelta("tokens_per_s", nd=1),
                 "tpot_p50_delta_ms": _kdelta("tpot_p50_s", 1e3, 3),
                 "tpot_p99_delta_ms": _kdelta("tpot_p99_s", 1e3, 3),
-                "serving_hbm_util_delta": _kdelta("hbm_util", nd=6),
                 "bytes_accessed": kernel_bytes,
             })
             kern_row = {
@@ -809,9 +800,6 @@ def main():
                         (kern_snap.get("tpot_p50_s") or 0) * 1e3, 3),
                     "tpot_p99_ms": round(
                         (kern_snap.get("tpot_p99_s") or 0) * 1e3, 3),
-                    "serving_hbm_util": (
-                        None if kern_snap.get("hbm_util") is None
-                        else round(kern_snap["hbm_util"], 6)),
                     "offered_load_rps": load,
                     "requests": kern_snap["n_requests"],
                     "wall_s": round(kern_snap["wall_s"], 2),

@@ -7,10 +7,6 @@ The contract under test:
     flow-linked across both replicas (same fleet trace id, a MIGRATE
     flow step joining the halves, each replica on its own named
     process row, per-chunk prefill instants).
-  * **Serving roofline** — `serving_mfu`/`serving_hbm_util` gauges are
-    fed by the compiled programs' own cost analysis; the numbers agree
-    with the committed `scripts/hlo_baseline.json` values for the
-    canonical paged programs within the baseline's own tolerances.
   * **SLO engine** — deterministic burn-rate math over a sliding
     window; under injected latency (chaos delay action) the burn rate
     crosses threshold and the FLEET SCALES UP without dropping
@@ -24,7 +20,6 @@ Canonical tiny LLaMA scale (2 layers, hidden 64 — the shape every
 serving suite compiles) so warm runs hit the persistent cache.
 """
 import json
-import os
 
 import numpy as np
 import pytest
@@ -33,11 +28,9 @@ import paddle_tpu as pt
 from paddle_tpu.nlp import LlamaConfig, LlamaForCausalLM
 from paddle_tpu.serving import (PagedServingEngine, Scheduler, SLOEngine,
                                 SLOPolicy, fleet)
-from paddle_tpu.serving import metrics as serving_metrics
 from paddle_tpu.utils import chaos, flight_recorder, telemetry
 from paddle_tpu.utils import profiler as prof
 
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 VOCAB = 128
 MAX_LEN = 64
 BLOCK = 8
@@ -70,72 +63,6 @@ def paged(factory):
 def _prompts(n, seed=100):
     return [np.random.RandomState(seed + i)
             .randint(0, VOCAB, (4 + i % 3,)).tolist() for i in range(n)]
-
-
-# ---------------------------------------------------------------------------
-# roofline: program costs vs the committed baseline, gauges vs the math
-# ---------------------------------------------------------------------------
-
-def test_paged_program_costs_agree_with_banked_baseline():
-    """The gauges' numerators ARE the xprof numbers: the registry's
-    canonical paged programs cost-analyze to the committed
-    hlo_baseline.json flops/bytes within the baseline's own
-    tolerances (acceptance criterion)."""
-    import jax
-
-    from paddle_tpu.tools.xprof import registry as xreg
-    base = json.load(open(os.path.join(REPO, "scripts",
-                                       "hlo_baseline.json")))
-    if base.get("backend") != jax.default_backend():
-        pytest.skip("baseline banked on a different backend")
-    specs = xreg.tracked_program_specs(["paged_decode_wave",
-                                        "paged_prefill_chunk"])
-    assert len(specs) == 2
-    for spec in specs:
-        cost = xreg.program_cost(spec)
-        assert cost, f"cost analysis unavailable for {spec['name']}"
-        banked = base["programs"][spec["name"]]["metrics"]
-        for metric in ("flops", "bytes_accessed"):
-            tol = base["tolerances"][metric]
-            want, got = banked[metric], cost[metric]
-            assert abs(got - want) <= tol["atol"] + tol["rtol"] * want, (
-                f"{spec['name']}.{metric}: live {got} vs banked {want} "
-                f"outside tolerance {tol}")
-
-
-@pytest.mark.parametrize("peaks", [(197e12, 819e9), None],
-                         ids=["device-in-table", "device-unknown"])
-def test_wave_roofline_gauges_follow_program_costs(paged, monkeypatch,
-                                                   peaks):
-    """serving_mfu / serving_hbm_util are exactly program-cost /
-    (measured wave time x device peak), and the snapshot's
-    wave-integral + phase split are populated. A device that is not in
-    the peaks table (the CPU these tests run on) has no roofline: the
-    snapshot reads None, never a number against a made-up peak."""
-    monkeypatch.setattr(serving_metrics, "_DEVICE_PEAKS", [peaks])
-    sched = Scheduler(paged)
-    for p in _prompts(3, seed=40):
-        sched.submit(prompt=p, max_tokens=4)
-    sched.run()
-    costs = paged.program_costs()
-    assert costs["decode_wave"] and costs["prefill"]
-    snap = sched.metrics.snapshot()
-    if peaks is None:
-        assert snap["mfu"] is None and snap["hbm_util"] is None
-    else:
-        peak_f, peak_b = peaks
-        # the gauge carries the LAST wave's utilization, computed from
-        # the same cost numbers and the scheduler's measured wave time
-        assert telemetry.value("serving_mfu") == pytest.approx(
-            costs["decode_wave"]["flops"] / (sched.last_wave_s * peak_f))
-        assert telemetry.value("serving_hbm_util") == pytest.approx(
-            costs["decode_wave"]["bytes_accessed"]
-            / (sched.last_wave_s * peak_b))
-        assert snap["mfu"] > 0 and snap["hbm_util"] > 0
-    ph = snap["phase_seconds"]
-    assert set(ph) >= {"admission", "prefill_chunk", "decode_wave",
-                       "host_dispatch"}
-    assert ph["decode_wave"] > 0 and ph["prefill_chunk"] > 0
 
 
 def test_tpot_histogram_and_per_request_tpot(paged):

@@ -110,9 +110,9 @@ def test_snapshot_keys_byte_compatible(engine):
         # fleet PR appended the raw span endpoints (rollups across
         # replicas need min(first)/max(last), not per-engine spans)
         "first_token_time", "last_token_time",
-        # observability PR appended TPOT percentiles, the per-round
-        # phase split, and the wave-integral roofline
-        "tpot_p50_s", "tpot_p99_s", "phase_seconds", "mfu", "hbm_util",
+        # observability PR appended TPOT percentiles and the per-round
+        # phase split
+        "tpot_p50_s", "tpot_p99_s", "phase_seconds",
         # speculative-decoding PR appended the draft economics (0/None
         # on engines without a draft model)
         "spec_tokens_proposed", "spec_tokens_accepted",

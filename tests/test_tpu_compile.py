@@ -15,6 +15,7 @@ The kernels choose `interpret=` from `jax.default_backend()`, which is
 "cpu" here; the tests steer that with monkeypatch, not the program.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -56,11 +57,24 @@ def one_chip(topo):
 @pytest.fixture
 def as_on_tpu(monkeypatch):
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    # a test of another file, run earlier by the same xdist worker, may
+    # have left its mesh of CPU devices installed: the flash wrapper
+    # would then shard over it instead of compiling for the one chip
+    monkeypatch.setattr(mesh_mod, "_current_mesh", None)
 
 
 def _kernels_in(fn, *shapes):
     return jax.jit(fn).lower(*shapes).compile().as_text().count(
         "tpu_custom_call")
+
+
+def _kernel_names(fn, *shapes):
+    """Names of the compiled program's Mosaic instructions, without the
+    ".<n>" suffix: what a device trace calls their events."""
+    txt = jax.jit(fn).lower(*shapes).compile().as_text()
+    return sorted(re.match(r"\s*%([^ ]+?)(\.\d+)? = ", line).group(1)
+                  for line in txt.splitlines()
+                  if 'custom_call_target="tpu_custom_call"' in line)
 
 
 def _paged_args(sharding, b, h, hkv, c, d, bs=16, nblk=64):
@@ -148,3 +162,26 @@ def test_lax_paged_core_compiles_for_v5e(one_chip):
                                          kernel="lax")
 
     assert _kernels_in(fn, *_paged_args(one_chip, 8, 12, 12, 1, 64)) == 0
+
+
+def test_kernels_carry_their_names_for_v5e(one_chip, as_on_tpu):
+    """A `jax.named_scope` right at each `pallas_call` names the
+    instruction, under grad and jit alike, so that a device trace can be
+    read by name (`benchmark/trace_reduce.kernel_class` prints the same
+    four)."""
+    x = jax.ShapeDtypeStruct((4, 1024, 12, 64), jnp.bfloat16,
+                             sharding=one_chip)
+
+    def loss(q, k, v):
+        return _flash_array(q, k, v, causal=True, layout="bshd").astype(
+            jnp.float32).sum()
+
+    assert _kernel_names(jax.grad(loss, argnums=(0, 1, 2)), x, x, x) == [
+        "flash_bwd_dkv", "flash_bwd_dq", "flash_fwd"]
+
+    def wave(q, pk, pv, tables, pos):
+        return pa.paged_decode_attention(q, pk, pv, tables, pos, 64 ** -0.5,
+                                         kernel="pallas")
+
+    assert _kernel_names(wave, *_paged_args(one_chip, 8, 12, 12, 1, 64)) \
+        == ["paged_attention"]
