@@ -22,15 +22,28 @@ dispatch point:
   kernel="lax"        a lax.fori_loop over blocks carrying the
                       flash-attention recurrence (running max m, denom
                       l, weighted accumulator); works on every backend;
-  kernel="pallas"     a Pallas TPU kernel — grid over (lanes, kv-heads,
-                      blocks), the block-table gather done by the
+  kernel="pallas"     a Pallas TPU kernel — grid over (lanes, groups of
+                      kv-heads, steps of pages). A step takes whole
+                      pages as the pool stores them (the [heads, BS, D]
+                      slab of every kv-head of the group) through the
                       BlockSpec index_map over a scalar-prefetch table,
-                      accumulators in VMEM scratch across the
-                      sequential block dimension. `interpret=True` on
-                      CPU so tier-1 exercises the real kernel body; the
-                      body keeps every value 2-D, which is what the TPU
-                      compiler accepts (tests/test_tpu_compile.py
-                      compiles it for v5e at real widths).
+                      and only the pages the lane attends
+                      (`attended_pages`, from its position, the chunk
+                      and the window): a step outside them names the
+                      page it already holds, so it moves no bytes, and
+                      skips the arithmetic. Accumulators sit in VMEM
+                      scratch across the sequential step dimension.
+                      How many kv-heads and pages share a step follows
+                      the shapes of the call (`_tile`): all heads and
+                      several pages in the decode form, where a pass
+                      through the recurrence costs the same however
+                      little it scores, so one pass scores them all; a
+                      few heads and one page in a 128-token chunk,
+                      where the query tile fills VMEM. `interpret=True`
+                      on CPU so tier-1 exercises the real kernel body;
+                      the body keeps every value 2-D, which is what the
+                      TPU compiler accepts (tests/test_tpu_compile.py
+                      compiles it for v5e at the cells' real shapes).
   kernel="auto"       "pallas" on TPU, "lax" elsewhere. An explicit
                       "pallas" reaches the compiler as is: nothing here
                       catches a refusal or falls back.
@@ -60,6 +73,8 @@ built with at trace time) > the `PT_PAGED_KERNEL` environment variable
 import contextlib
 import functools
 import os
+
+import numpy as np
 
 KERNELS = ("auto", "reference", "lax", "pallas")
 
@@ -246,31 +261,93 @@ def _lax_core(q, pk, pv, tables, start, scale, window=None):
 
 
 # ---------------------------------------------------------------------------
-# Pallas kernel: grid (lanes, kv-heads, blocks), table gather in the
-# BlockSpec index_map over the scalar-prefetch block table
+# Pallas kernel: grid (lanes, kv-head groups, page steps), table gather
+# in the BlockSpec index_map over the scalar-prefetch block table; only
+# the pages a lane attends are fetched and computed
 # ---------------------------------------------------------------------------
 
-def _paged_attn_kernel(tables_ref, start_ref, off_ref, q_ref, k_ref, v_ref,
-                       o_ref, m_ref, l_ref, acc_ref, *, scale, window, bs,
-                       c):
-    """One (lane b, kv-head h, block j) grid step. The pipeline already
-    gathered this lane's j-th pool block via the index_map — the kernel
-    only scores, masks and folds into the VMEM accumulators, which
-    persist across the sequential block dimension.
+def attended_pages(start, c, bs, nblk, window=None):
+    """(lo, hi): the table entries [lo, hi) of a lane whose `c` queries
+    sit at `start .. start + c - 1`. Between them the rows attend keys
+    in (start - window, start + c - 1], from 0 without a window; a
+    padded chunk tail can reach past the table, so `hi` stops at `nblk`.
+    A lane that attends nothing the table holds (no key yet, or a window
+    wholly past it) gets hi == lo, so `hi - lo` always counts its pages.
 
-    Every value is 2-D (the TPU compiler lays vectors out over sublanes
-    x lanes and refuses 1-D iotas, vector loads from SMEM and the
-    `[:, 0]` / `[:, None]` casts between the two): the running max and
-    denominator stay `[rows, 1]`, key positions come from
-    `broadcasted_iota`, and a row's query position is the lane's scalar
-    `start` plus its `[rows, 1]` offset within the chunk."""
+    Pure integer arithmetic on numpy values (the engine's count of pages
+    a wave visits) and on traced scalars (the kernel's own bounds)
+    alike; a page outside [lo, hi) contributes probability 0 and rescale
+    1 to the recurrence, so skipping it is exact."""
+    if isinstance(start, (int, np.integer, np.ndarray)):
+        xp = np
+    else:
+        import jax.numpy as xp
+    lo = 0 * start if window is None else \
+        xp.maximum(start - (window - 1), 0) // bs
+    hi = xp.maximum(xp.minimum((start + (c - 1)) // bs + 1, nblk), lo)
+    return lo, hi
+
+
+#: rows of the query tile (kv-heads x group x chunk) one step may hold:
+#: float32 q, output and accumulator of 1024 x 128 are 0.5 MB each
+_MAX_ROWS = 1024
+#: pages a step takes at most (each is two more pipelined operands)
+_MAX_PAGES = 8
+
+
+def _tile(c, rep, hkv):
+    """(kv-heads a step, pages a step) from the call's shapes. A step
+    takes whole pages: the [heads, BS, D] slab of as many kv-heads as
+    keep the query tile within `_MAX_ROWS` rows (all of them in the
+    decode form, where a row is one head of one lane), scored in one
+    matmul whose cross-head entries are masked. What a step costs is one
+    pass through the recurrence (matmul, max, exp, matmul: about 1.4 us
+    on a v5e however little it scores), so the fewer rows a page is
+    scored against, the more pages share a pass: as many as keep rows x
+    pages within an eighth of `_MAX_ROWS` (8 pages against a decode
+    wave's 12 rows, 4 against 32, one against a chunk's hundreds; past
+    that the chip's times are flat, PERF.md section 6, PR 26)."""
+    rc = rep * c
+    heads = max(g for g in range(1, hkv + 1)
+                if hkv % g == 0 and (g * rc <= _MAX_ROWS or g == 1))
+    pages = max(1, min(_MAX_PAGES, _MAX_ROWS // 8 // (heads * rc)))
+    return heads, pages
+
+
+def _paged_attn_kernel(tables_ref, start_ref, rows_ref, cols_ref, keys_ref,
+                       q_ref, *refs, scale, window, bs, c, nblk, pages):
+    """One (lane b, kv-head group g, step j) grid step: `pages` table
+    entries of the lane from `j * pages` on, each the [heads, BS, D]
+    slab of the group's kv-heads as the pool stores it. The pipeline
+    gathered them through the index_map; if any lies inside the lane's
+    `attended_pages` the kernel scores them as one tile, masks it and
+    folds it into the VMEM accumulators, which persist across the
+    sequential step dimension; else it does nothing. A key's position
+    follows from where its page stands in the table, so the pages of a
+    visited step that lie outside the bounds (the index_map names a page
+    inside them there) fall to the masks like any other key no row
+    attends: to the window's below `lo`, to the causal one above `hi`,
+    which also stops at the table's end.
+
+    The tile is 2-D (the TPU compiler lays vectors out over sublanes x
+    lanes and refuses 1-D iotas, vector loads from SMEM and the `[:, 0]`
+    / `[:, None]` casts between the two): rows are (kv-head, group
+    member, query) with the query minor, columns are (page, kv-head, key
+    in page); an entry counts where both name one head. The running max
+    and denominator stay `[rows, 1]`; a row's query position is the
+    lane's scalar `start` plus its offset within the chunk, a column's
+    key position the step's first position plus its offset from it
+    (`rows_ref`, `cols_ref`, and `keys_ref` for the rows of V)."""
     import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
 
+    k_refs, v_refs = refs[:pages], refs[pages:2 * pages]
+    o_ref, m_ref, l_ref, acc_ref = refs[2 * pages:]
     b = pl.program_id(0)
     j = pl.program_id(2)
-    nblk = pl.num_programs(2)
+    start = start_ref[b]                               # SMEM scalar
+    lo, hi = attended_pages(start, c, bs, nblk, window)
 
     @pl.when(j == 0)
     def _init():
@@ -278,42 +355,49 @@ def _paged_attn_kernel(tables_ref, start_ref, off_ref, q_ref, k_ref, v_ref,
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    qf = q_ref[0, 0].astype(jnp.float32)               # [rep*C, D]
-    kb = k_ref[0, 0].astype(jnp.float32)               # [BS, D]
-    vb = v_ref[0, 0].astype(jnp.float32)
-    rc = qf.shape[0]
-    start = start_ref[b]                               # SMEM scalar
-    s = jax.lax.dot_general(                           # q @ k.T
-        qf, kb, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32) * scale    # [rep*C, BS]
-    # row i of the [rep*C, D] query tile is (group r, query c) with c
-    # minor — its absolute position is start + i % C (off_ref)
-    rowpos = start + off_ref[...]                      # [rep*C, 1]
-    ks = j * bs + jax.lax.broadcasted_iota(jnp.int32, (rc, bs), 1)
-    keep = ks <= rowpos
-    if window is not None:
-        keep &= ks > rowpos - window
-    s = jnp.where(keep, s, -jnp.inf)
-    # fully-unattended keys get probability 0 but 0 * nan == nan: zero
-    # the V rows no query row keeps so scratch poison cannot leak. The
-    # rows sit at start .. start+C-1, so the keys some row keeps are
-    # exactly (start - window, start + C - 1]
-    kcol = j * bs + jax.lax.broadcasted_iota(jnp.int32, (bs, 1), 0)
-    attended = kcol <= start + (c - 1)
-    if window is not None:
-        attended &= kcol > start - window
-    vb = jnp.where(attended, vb, 0.0)
-    m_prev = m_ref[...]                                # [rep*C, 1]
-    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-    shift = jnp.where(jnp.isfinite(m_new), m_new, 0.0)
-    p = jnp.exp(s - shift)
-    alpha = jnp.exp(m_prev - shift)
-    l_ref[...] = alpha * l_ref[...] + jnp.sum(p, axis=-1, keepdims=True)
-    acc_ref[...] = alpha * acc_ref[...] + \
-        jnp.dot(p, vb, preferred_element_type=jnp.float32)
-    m_ref[...] = m_new
+    def slab(page_refs):
+        """[pages * heads * BS, D] float32 of the step's pages."""
+        heads, _, d = page_refs[0].shape[1:]
+        tiles = [r[0].astype(jnp.float32).reshape(heads * bs, d)
+                 for r in page_refs]
+        return tiles[0] if pages == 1 else jnp.concatenate(tiles, axis=0)
 
-    @pl.when(j == nblk - 1)
+    @pl.when((j * pages < hi) & ((j + 1) * pages > lo))
+    def _visit():
+        qf = q_ref[0, 0]                               # [rows, D]
+        kb, vb = slab(k_refs), slab(v_refs)
+        s = jax.lax.dot_general(                       # q @ k.T
+            qf, kb, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * scale  # [rows, cols]
+        first = j * pages * bs                         # the step's first key
+        rowpos = start + rows_ref[:, 0:1]              # [rows, 1]
+        ks = first + cols_ref[0:1, :]                  # [1, cols]
+        # a padded chunk tail's rows sit past the table: it ends at hi
+        keep = (cols_ref[1:2, :] == rows_ref[:, 1:2]) & \
+            (ks <= jnp.minimum(rowpos, hi * bs - 1))
+        if window is not None:
+            keep &= ks > rowpos - window
+        s = jnp.where(keep, s, -jnp.inf)
+        # fully-unattended keys get probability 0 but 0 * nan == nan:
+        # zero the V rows no query row keeps so scratch poison cannot
+        # leak. The rows sit at start .. start+C-1, so the keys some row
+        # keeps are exactly (start - window, start + C - 1]
+        kcol = first + keys_ref[...]                   # [cols, 1]
+        attended = kcol <= jnp.minimum(start + (c - 1), hi * bs - 1)
+        if window is not None:
+            attended &= kcol > start - window
+        vb = jnp.where(attended, vb, 0.0)
+        m_prev = m_ref[...]                            # [rows, 1]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        shift = jnp.where(jnp.isfinite(m_new), m_new, 0.0)
+        p = jnp.exp(s - shift)
+        alpha = jnp.exp(m_prev - shift)
+        l_ref[...] = alpha * l_ref[...] + jnp.sum(p, axis=-1, keepdims=True)
+        acc_ref[...] = alpha * acc_ref[...] + \
+            jnp.dot(p, vb, preferred_element_type=jnp.float32)
+        m_ref[...] = m_new
+
+    @pl.when(j == pl.num_programs(2) - 1)
     def _finish():
         # == 0 guard (not > 0): nan denominators must propagate
         l = l_ref[...]
@@ -321,48 +405,63 @@ def _paged_attn_kernel(tables_ref, start_ref, off_ref, q_ref, k_ref, v_ref,
 
 
 @functools.lru_cache(maxsize=None)
-def _pallas_call(b, h, c, d, hkv, bs, nblk, scale, window, dtype_name,
-                 interpret):
+def _pallas_call(b, h, c, d, hkv, bs, nblk, heads, pages, scale, window,
+                 dtype_name, interpret):
     """Build (and cache) the pallas_call for one static shape family.
     The block table and each lane's first query position ride as
     scalar-prefetch operands so the K/V BlockSpec index_maps can address
-    the pool by table VALUE — the gather happens in the pipeline, block
-    by block, never as a materialised [B, Hkv, nblk*BS, D] array."""
+    the pool by table VALUE — the gather happens in the pipeline, page
+    by page, never as a materialised [B, Hkv, nblk*BS, D] array. A step
+    outside the lane's `attended_pages` names the nearest page inside
+    them: a block index that does not change is not fetched again, so
+    the pages nobody attends cost neither bytes nor arithmetic."""
     import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    rep = h // hkv
-    rc = rep * c
+    rows, cols = heads * (h // hkv) * c, pages * heads * bs
     kernel = functools.partial(_paged_attn_kernel, scale=scale,
-                               window=window, bs=bs, c=c)
+                               window=window, bs=bs, c=c, nblk=nblk,
+                               pages=pages)
+
+    def page_spec(i):
+        def index_map(bb, gg, jj, tab, st):
+            lo, hi = attended_pages(st[bb], c, bs, nblk, window)
+            page = jnp.minimum(jnp.maximum(jj * pages + i, lo), hi - 1)
+            # a lane that attends nothing has hi == lo, anywhere from 0
+            # to past the table: stay inside it
+            return tab[bb, jnp.clip(page, 0, nblk - 1)], gg, 0, 0
+        return pl.BlockSpec((1, heads, bs, d), index_map)
+
+    def per_group(bb, gg, jj, tab, st):
+        return bb, gg, 0, 0
+
+    def whole(bb, gg, jj, tab, st):
+        return 0, 0
+
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(b, hkv, nblk),
+        grid=(b, hkv // heads, pl.cdiv(nblk, pages)),
         in_specs=[
-            pl.BlockSpec((rc, 1), lambda bb, hh, jj, tab, st: (0, 0)),
-            pl.BlockSpec((1, 1, rc, d),
-                         lambda bb, hh, jj, tab, st: (bb, hh, 0, 0)),
-            pl.BlockSpec((1, 1, bs, d),
-                         lambda bb, hh, jj, tab, st: (tab[bb, jj], hh,
-                                                      0, 0)),
-            pl.BlockSpec((1, 1, bs, d),
-                         lambda bb, hh, jj, tab, st: (tab[bb, jj], hh,
-                                                      0, 0)),
+            pl.BlockSpec((rows, 2), whole),
+            pl.BlockSpec((2, cols), whole),
+            pl.BlockSpec((cols, 1), whole),
+            pl.BlockSpec((1, 1, rows, d), per_group),
+            *[page_spec(i) for i in range(pages)],     # K pages
+            *[page_spec(i) for i in range(pages)],     # V pages
         ],
-        out_specs=pl.BlockSpec((1, 1, rc, d),
-                               lambda bb, hh, jj, tab, st: (bb, hh, 0,
-                                                            0)),
+        out_specs=pl.BlockSpec((1, 1, rows, d), per_group),
         scratch_shapes=[
-            pltpu.VMEM((rc, 1), jnp.float32),          # running max m
-            pltpu.VMEM((rc, 1), jnp.float32),          # running denom l
-            pltpu.VMEM((rc, d), jnp.float32),          # weighted V acc
+            pltpu.VMEM((rows, 1), jnp.float32),        # running max m
+            pltpu.VMEM((rows, 1), jnp.float32),        # running denom l
+            pltpu.VMEM((rows, d), jnp.float32),        # weighted V acc
         ],
     )
     return pl.pallas_call(
         kernel, grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, hkv, rc, d), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((b, hkv // heads, rows, d),
+                                       jnp.float32),
         interpret=interpret, name="paged_attention")
 
 
@@ -377,17 +476,29 @@ def _pallas_core(q, pk, pv, tables, start, scale, window=None):
     hkv, bs = pk.shape[1], pk.shape[2]
     nblk = tables.shape[1]
     rep = h // hkv
+    heads, pages = _tile(c, rep, hkv)
+    rows = heads * rep * c
     start = jnp.broadcast_to(jnp.reshape(jnp.asarray(start, jnp.int32),
                                          (-1,)), (b,))
-    # [B, H, C, D] -> [B, Hkv, rep*C, D]: group-major, query-minor rows
-    qr = q.astype(jnp.float32).reshape(b, hkv, rep * c, d)
-    off = (jnp.arange(rep * c, dtype=jnp.int32) % c)[:, None]
-    call = _pallas_call(b, h, c, d, hkv, bs, nblk, float(scale),
+    # [B, H, C, D] -> [B, Hkv/heads, heads*rep*C, D]: kv-head-major,
+    # then group member, query-minor rows
+    qr = q.astype(jnp.float32).reshape(b, hkv // heads, rows, d)
+    row = np.arange(rows, dtype=np.int32)
+    col = np.arange(pages * heads * bs, dtype=np.int32)
+    # per row: its query's offset in the chunk, its kv-head; per column:
+    # its key's offset from the step's first position, its kv-head
+    rowinfo = np.stack([row % c, row // (rep * c)], axis=1)
+    keyoff = col // (heads * bs) * bs + col % bs
+    colinfo = np.stack([keyoff, col // bs % heads])
+    call = _pallas_call(b, h, c, d, hkv, bs, nblk, heads, pages,
+                        float(scale),
                         None if window is None else int(window),
                         str(pk.dtype),
                         jax.default_backend() != "tpu")
     # the scope, innermost at the call, is what names the instruction
     # in a device trace ("%paged_attention.1 = ... custom-call")
     with jax.named_scope("paged_attention"):
-        out = call(tables.astype(jnp.int32), start, off, qr, pk, pv)
+        out = call(tables.astype(jnp.int32), start, jnp.asarray(rowinfo),
+                   jnp.asarray(colinfo), jnp.asarray(keyoff[:, None]), qr,
+                   *[pk] * pages, *[pv] * pages)
     return out.reshape(b, h, c, d).astype(pv.dtype)

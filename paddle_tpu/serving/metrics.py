@@ -193,6 +193,10 @@ class ServingMetrics:
         self._block_total_waves = 0
         self._prefix_base = None
         self._prefix_last = None
+        # block-table entries the paged-attention core visited in this
+        # instance's decode waves, and the entries their tables held
+        self._pages_visited = 0
+        self._pages_spanned = 0
         # per-phase wall time (seconds, folded in once per scheduler
         # round)
         self._phase_seconds = {}
@@ -260,6 +264,14 @@ class ServingMetrics:
             acc = self._phase_seconds
             for phase, s in seconds.items():
                 acc[phase] = acc.get(phase, 0.0) + s
+
+    def on_pages(self, visited, spanned):
+        """One round's decode waves: table entries inside the lanes'
+        `nn.paged_attention.attended_pages`, and all of them (lanes x
+        blocks per lane)."""
+        with self._lock:
+            self._pages_visited += int(visited)
+            self._pages_spanned += int(spanned)
 
     def on_queue_depth(self, depth):
         monitor.stat_max(QUEUE_DEPTH_PEAK, int(depth))  # process-wide peak
@@ -332,6 +344,7 @@ class ServingMetrics:
                 p_hits = self._prefix_last[0] - self._prefix_base[0]
                 p_misses = self._prefix_last[1] - self._prefix_base[1]
             phase_seconds = dict(self._phase_seconds)
+            pages_v, pages_s = self._pages_visited, self._pages_spanned
             spec_p, spec_a = self._spec_proposed, self._spec_accepted
             spec_w = self._spec_waves
         return {
@@ -380,4 +393,8 @@ class ServingMetrics:
                                      else None),
             "spec_accepted_per_wave": (spec_a / spec_w if spec_w
                                        else None),
+            # how much of its block tables the paged-attention core
+            # walks in the decode waves (0 / 0 on a dense engine)
+            "paged_pages_visited": pages_v,
+            "paged_pages_spanned": pages_s,
         }

@@ -127,6 +127,11 @@ class PagedServingEngine(ServingEngine):
         self._slot_blocks = [[] for _ in range(self.num_slots)]
         self._tables = np.zeros((self.num_slots, self.blocks_per_slot),
                                 np.int32)
+        self._attn_window = getattr(model.cfg, "attn_window", None)
+        # table entries the attention core visits in the decode waves
+        # staged since the scheduler last took them (take_page_counts,
+        # once a round), and the entries those waves' tables hold
+        self._pages_visited = self._pages_spanned = 0
 
     def _make_caches(self):
         return self.model.init_paged_cache(self.block_pool.num_blocks,
@@ -542,6 +547,13 @@ class PagedServingEngine(ServingEngine):
         # for those lanes so the write lands in block 0 by design.
         tables = np.where(np.asarray(active_now, bool)[:, None],
                           self._tables, np.int32(BlockPool.SCRATCH))
+        # every lane rides the wave at its `slot_pos`, the ones not in
+        # it at a stale one over a scratch row: the core walks those too
+        lo, hi = paged_attention.attended_pages(
+            np.asarray(self.slot_pos), 1, self.block_size,
+            self.blocks_per_slot, self._attn_window)
+        self._pages_visited += int(np.sum(hi - lo))
+        self._pages_spanned += tables.size
         return (self._params, self._buffers, self._caches,
                 jnp.asarray(tables),
                 jnp.asarray(self.slot_tok, jnp.int32),
@@ -574,6 +586,14 @@ class PagedServingEngine(ServingEngine):
                 jax.jit(copy_fn, donate_argnums=(0,)), "paged_cow_copy")
                 if self._jit else copy_fn)
         return self._copy_fn(caches, jnp.int32(src), jnp.int32(dst))
+
+    def take_page_counts(self):
+        """(visited, spanned) table entries of the decode waves staged
+        since the last call (the scheduler folds them into
+        ServingMetrics once a round)."""
+        out = self._pages_visited, self._pages_spanned
+        self._pages_visited = self._pages_spanned = 0
+        return out
 
     # ------------------------------------------------------------- slots
     def retire_slot(self, slot):

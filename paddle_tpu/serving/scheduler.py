@@ -866,6 +866,8 @@ class Scheduler:
             if self._round_worked and ev.elapsed is not None:
                 phases["round"] = ev.elapsed
             self.metrics.on_phases(phases)
+            if hasattr(eng, "take_page_counts"):
+                self.metrics.on_pages(*eng.take_page_counts())
             if not (self._round_worked and pending):
                 # an empty server is not a slow host: what passes until
                 # the next dispatch is not the host's doing

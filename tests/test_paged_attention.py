@@ -157,6 +157,143 @@ def test_fully_masked_rows_are_exactly_zero(kernel):
 
 
 # ---------------------------------------------------------------------------
+# the pallas core walks only the pages a lane attends: ragged lanes,
+# poison everywhere else, the bounds against a brute-force count
+# ---------------------------------------------------------------------------
+
+RAGGED_BS, RAGGED_NBLK = 4, 11
+
+
+def _ragged(seed, c, rep, hkv=2, d=8):
+    """Six lanes in one call: start at 0, bs - 1, bs, mid-table, the
+    table's last position (a chunk's tail then reaches past it), and a
+    lane whose table row is all scratch. Every other lane owns its
+    blocks, so a test can poison one lane's page and no other's."""
+    import jax.numpy as jnp
+    bs, nblk = RAGGED_BS, RAGGED_NBLK
+    start = np.asarray([0, bs - 1, bs, 21, nblk * bs - 1, 13], np.int32)
+    b = len(start)
+    rng = np.random.default_rng(seed)
+    nb = b * nblk + 1
+    pk = rng.standard_normal((nb, hkv, bs, d)).astype(np.float32)
+    pv = rng.standard_normal((nb, hkv, bs, d)).astype(np.float32)
+    tables = 1 + rng.permutation(b * nblk).reshape(b, nblk).astype(np.int32)
+    tables[-1] = 0
+    q = jnp.asarray(rng.standard_normal((b, hkv * rep, c, d)), jnp.float32)
+    return q, pk, pv, tables, start
+
+
+def _attend(form):
+    return pa.paged_decode_attention if form == "decode" else \
+        pa.paged_chunk_attention
+
+
+# window 6 starts mid-page, window 3 is shorter than a page of 4
+@pytest.mark.parametrize("window", [None, 6, 3])
+@pytest.mark.parametrize("rep", [1, 4], ids=["mha", "gqa4"])
+@pytest.mark.parametrize("form", ["decode", "chunk"])
+@pytest.mark.parametrize("kernel", FUSED)
+def test_ragged_lanes_parity_vs_reference(kernel, form, rep, window):
+    import jax.numpy as jnp
+    q, pk, pv, tables, start = _ragged(7, 1 if form == "decode" else 5, rep)
+    args = (q, jnp.asarray(pk), jnp.asarray(pv), jnp.asarray(tables),
+            jnp.asarray(start), 0.35)
+    ref = _attend(form)(*args, window=window, kernel="reference")
+    out = _attend(form)(*args, window=window, kernel=kernel)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("window", [None, 6])
+@pytest.mark.parametrize("form", ["decode", "chunk"])
+@pytest.mark.parametrize("kernel", KERNELS)
+def test_poison_outside_the_attended_keys_changes_nothing(kernel, form,
+                                                          window):
+    """NaN in every page outside a lane's `attended_pages`, and in the
+    keys of its first and last visited page that no row attends, leaves
+    the output finite and bit for bit what it was."""
+    import jax.numpy as jnp
+    c = 1 if form == "decode" else 5
+    q, pk, pv, tables, start = _ragged(8, c, rep=2)
+    tables[-1] = tables.max() + 1 + np.arange(RAGGED_NBLK)   # own blocks
+    pk = np.concatenate([pk, pk[:RAGGED_NBLK]])
+    pv = np.concatenate([pv, pv[:RAGGED_NBLK]])
+    clean = _attend(form)(q, jnp.asarray(pk), jnp.asarray(pv),
+                          jnp.asarray(tables), jnp.asarray(start), 0.35,
+                          window=window, kernel=kernel)
+    pk[0] = pv[0] = np.nan
+    for lane, st in enumerate(start):
+        first = 0 if window is None else max(0, st - window + 1)
+        keys = np.arange(RAGGED_NBLK * RAGGED_BS).reshape(RAGGED_NBLK, -1)
+        dead = (keys < first) | (keys > st + c - 1)     # [nblk, bs]
+        for j in range(RAGGED_NBLK):
+            pk[tables[lane, j]][:, dead[j]] = np.nan
+            pv[tables[lane, j]][:, dead[j]] = np.nan
+    out = _attend(form)(q, jnp.asarray(pk), jnp.asarray(pv),
+                        jnp.asarray(tables), jnp.asarray(start), 0.35,
+                        window=window, kernel=kernel)
+    assert np.isfinite(np.asarray(out)).all()
+    np.testing.assert_array_equal(np.asarray(out), np.asarray(clean))
+
+
+@pytest.mark.parametrize("c,window", [(1, None), (1, 6), (1, 3), (5, None),
+                                      (5, 6), (16, 40)])
+def test_attended_pages_match_a_brute_force_count(c, window):
+    """The exported bounds are exactly the pages that hold a key some
+    row of the lane attends, for every start up to past the table."""
+    bs, nblk = RAGGED_BS, RAGGED_NBLK
+    start = np.arange(-1, nblk * bs + 3 * bs, dtype=np.int32)
+    lo, hi = pa.attended_pages(start, c, bs, nblk, window)
+    for st, a, z in zip(start, lo, hi):
+        held = [j for j in range(nblk) for ks in range(j * bs, (j + 1) * bs)
+                if any(ks <= st + i and (window is None
+                                         or ks > st + i - window)
+                       for i in range(c))]
+        want = sorted(set(held))
+        assert list(range(a, z)) == want, (st, a, z, want)
+    # the kernel's own bounds are the same function on traced scalars
+    import jax
+    import jax.numpy as jnp
+    traced = jax.vmap(lambda s: jnp.stack(
+        pa.attended_pages(s, c, bs, nblk, window)))(jnp.asarray(start))
+    np.testing.assert_array_equal(np.asarray(traced), np.stack([lo, hi], 1))
+
+
+@pytest.mark.parametrize("heads,pages", [(1, 1), (2, 1), (1, 3), (2, 3),
+                                         (2, 16)])
+@pytest.mark.parametrize("form", ["decode", "chunk"])
+def test_every_tile_of_the_pallas_core_gives_the_reference(monkeypatch, form,
+                                                           heads, pages):
+    """One recurrence, whatever tile the shapes choose: kv-heads in the
+    grid or folded into a step, one page a step or several, a last step
+    that reaches past the table."""
+    import jax.numpy as jnp
+    monkeypatch.setattr(pa, "_tile", lambda c, rep, hkv: (heads, pages))
+    q, pk, pv, tables, start = _ragged(9, 1 if form == "decode" else 5, 2)
+    args = (q, jnp.asarray(pk), jnp.asarray(pv), jnp.asarray(tables),
+            jnp.asarray(start), 0.35)
+    for window in (None, 6):
+        ref = _attend(form)(*args, window=window, kernel="reference")
+        out = _attend(form)(*args, window=window, kernel="pallas")
+        np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                                   rtol=1e-5, atol=1e-5)
+
+
+def test_tile_follows_the_shapes_of_the_call():
+    # the two cells' decode waves: every kv-head in one step
+    assert pa._tile(1, 1, 12)[0] == 12 and pa._tile(1, 4, 8)[0] == 8
+    # their 128-token chunks: as many heads as keep the tile's rows
+    # within the cap, one page a step
+    assert pa._tile(128, 1, 12) == (6, 1)
+    assert pa._tile(128, 4, 8) == (2, 1)
+    # rows past the cap with one head: heads stay in the grid
+    assert pa._tile(512, 4, 8) == (1, 1)
+    for c, rep, hkv in [(1, 1, 12), (1, 4, 8), (5, 1, 12), (128, 4, 8)]:
+        heads, pages = pa._tile(c, rep, hkv)
+        assert hkv % heads == 0 and 1 <= pages <= pa._MAX_PAGES
+
+
+# ---------------------------------------------------------------------------
 # dispatch front door: resolution order, env override, scopes
 # ---------------------------------------------------------------------------
 
@@ -244,6 +381,38 @@ def test_engine_stream_token_identical_across_kernels(model, kernel):
     assert eng.prefill_compiles == 1
     assert eng.paged_kernel == kernel
     assert eng._health()["paged_kernel"] == kernel
+
+
+@pytest.mark.parametrize("window", [None, 12])
+def test_engine_counts_the_pages_its_waves_visit(window):
+    """`paged_pages_visited` is the sum, over every staged wave's lanes,
+    of the pages holding a key the lane attends at its `slot_pos`
+    (lanes not in the wave ride along at a stale one), counted here key
+    by key; `paged_pages_spanned` is waves x slots x blocks per lane."""
+    pt.seed(7)
+    cfg = LlamaConfig(vocab_size=VOCAB, hidden_size=64, num_layers=1,
+                      num_heads=4, num_kv_heads=2, max_seq_len=MAX_LEN,
+                      attn_window=window)
+    eng = _engine(LlamaForCausalLM(cfg), "lax")
+    waves, stage = [], eng._wave_args
+
+    def spy(*args):
+        waves.append(np.array(eng.slot_pos))
+        return stage(*args)
+
+    eng._wave_args = spy
+    sched = Scheduler(eng)
+    for prompt, m in _jobs(2, n=6):
+        sched.submit(prompt=prompt, max_tokens=m)
+    sched.run()
+    snap = sched.metrics.snapshot()
+    nblk = MAX_LEN // BLOCK
+    assert waves and snap["paged_pages_spanned"] == len(waves) * 4 * nblk
+    want = sum(len({ks // BLOCK for ks in range(int(pos) + 1)
+                    if window is None or ks > pos - window})
+               for wave in waves for pos in wave)
+    assert snap["paged_pages_visited"] == want
+    assert 0 < want < snap["paged_pages_spanned"]
 
 
 @pytest.mark.parametrize("kernel", FUSED)

@@ -116,7 +116,9 @@ def test_snapshot_keys_byte_compatible(engine):
         # speculative-decoding PR appended the draft economics (0/None
         # on engines without a draft model)
         "spec_tokens_proposed", "spec_tokens_accepted",
-        "spec_acceptance_rate", "spec_accepted_per_wave"]
+        "spec_acceptance_rate", "spec_accepted_per_wave",
+        # the paged core's page counters (0 / 0 on a dense engine)
+        "paged_pages_visited", "paged_pages_spanned"]
     # a 3-token request has 2 inter-token gaps — TPOT is real, and the
     # phase split saw every phase of a working round
     assert snap["tpot_p50_s"] is not None
@@ -127,6 +129,7 @@ def test_snapshot_keys_byte_compatible(engine):
     # dense engine: the paged-pool keys are present but empty
     assert snap["block_utilization"] is None
     assert snap["prefix_hits"] == 0 and snap["prefix_hit_rate"] is None
+    assert snap["paged_pages_visited"] == snap["paged_pages_spanned"] == 0
     assert snap["requests_completed"] == 1
     assert snap["ttft_p50_s"] is not None
     assert snap["ttft_p50_s"] <= snap["latency_p50_s"]

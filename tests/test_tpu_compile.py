@@ -154,6 +154,34 @@ def test_paged_core_server_resolves_on_tpu_compiles_for_v5e(
     assert _kernels_in(fn, *_paged_args(one_chip, b, h, hkv, c, d)) == 1
 
 
+@pytest.mark.parametrize("name,b,h,hkv,c,d,nblk,window", [
+    # gpt2s-serve-batch: 128 lanes, max_len 1024
+    ("gpt2s-batch-wave", 128, 12, 12, 1, 64, 64, None),
+    ("gpt2s-batch-chunk128", 1, 12, 12, 128, 64, 64, None),
+    # mistral7b-serve-chat: 64 lanes, max_len 2560, window 4096
+    ("mistral-chat-wave", 64, 32, 8, 1, 128, 160, 4096),
+    ("mistral-chat-chunk128", 1, 32, 8, 128, 128, 160, 4096),
+    # a window inside the table, and the speculative verify wave's form
+    # (C = k + 1 queries at per-lane starts over every lane)
+    ("mistral-wave-window1024", 64, 32, 8, 1, 128, 160, 1024),
+    ("gpt2s-verify-k4", 128, 12, 12, 5, 64, 64, None),
+])
+def test_paged_core_compiles_at_the_cells_shapes_for_v5e(
+        one_chip, as_on_tpu, name, b, h, hkv, c, d, nblk, window):
+    """The two serving cells' real shapes: one kernel, named
+    `paged_attention`, per attention call, whatever tile the shapes
+    choose."""
+    attend = pa.paged_decode_attention if c == 1 else \
+        pa.paged_chunk_attention
+
+    def fn(q, pk, pv, tables, pos):
+        return attend(q, pk, pv, tables, pos, d ** -0.5, window=window,
+                      kernel="pallas")
+
+    assert _kernel_names(fn, *_paged_args(one_chip, b, h, hkv, c, d,
+                                          nblk=nblk)) == ["paged_attention"]
+
+
 def test_lax_paged_core_compiles_for_v5e(one_chip):
     """The portable core is what `paged_kernel="lax"` serves from on a
     chip; it has no kernel of its own."""
