@@ -6,3 +6,4 @@ from .gpt import (GPTModel, GPTForPretraining, GPTConfig, gpt2_small,
 from .bert import BertModel, BertForPretraining, BertConfig, bert_base, bert_large
 from .llama import (LlamaModel, LlamaForCausalLM, LlamaConfig,
                     llama_pretrain_loss)
+from .nemotron_h import NemotronHConfig, NemotronHForCausalLM

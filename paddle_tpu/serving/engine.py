@@ -96,6 +96,16 @@ def _filter_top_k_top_p(lo, top_k, top_p):
     return jnp.where(keep, lo, jnp.float32(-1e9))
 
 
+def _if_any_samples(sample, draw, greedy):
+    """`draw()` where some lane samples, `greedy` as it stands where none
+    does: the filter sorts and gathers the whole `[lanes, vocab]` matrix
+    twice (at 128 x 131072 that was 470 ms of a 500 ms wave on a v5e,
+    PERF.md PR 27), and a wave of greedy lanes has no use for it. Both
+    branches are in the one compiled program; a lane's token does not
+    depend on which ran."""
+    return jax.lax.cond(jnp.any(sample), draw, lambda: greedy)
+
+
 def _select_wave_tokens(lo, tok, pos, active, sample, temps, top_k,
                         top_p, bias, poison, key):
     """The decode wave's token-selection tail, shared by the dense AND
@@ -120,11 +130,15 @@ def _select_wave_tokens(lo, tok, pos, active, sample, temps, top_k,
     lo = jnp.where(poison[:, None], jnp.float32(jnp.nan), lo + bias)
     finite = jnp.all(jnp.isfinite(lo), axis=-1)
     greedy = jnp.argmax(lo, axis=-1).astype(jnp.int32)
-    scaled = lo / jnp.maximum(temps, 1e-6)[:, None]
-    sampled = jax.random.categorical(
-        key, _filter_top_k_top_p(scaled, top_k, top_p),
-        axis=-1).astype(jnp.int32)
-    nxt = jnp.where(sample, sampled, greedy)
+
+    def draw():
+        scaled = lo / jnp.maximum(temps, 1e-6)[:, None]
+        sampled = jax.random.categorical(
+            key, _filter_top_k_top_p(scaled, top_k, top_p),
+            axis=-1).astype(jnp.int32)
+        return jnp.where(sample, sampled, greedy)
+
+    nxt = _if_any_samples(sample, draw, greedy)
     ok = active & finite
     nxt = jnp.where(ok, nxt, tok)
     new_pos = jnp.where(ok, pos + 1, pos)
@@ -140,11 +154,14 @@ def _select_first_token(lo, sample, temp, top_k, top_p, bias, key):
     tail will)."""
     lo = lo + bias
     greedy = jnp.argmax(lo).astype(jnp.int32)
-    scaled = (lo / jnp.maximum(temp, 1e-6))[None, :]
-    sampled = jax.random.categorical(
-        key, _filter_top_k_top_p(scaled, top_k[None], top_p[None])[0]
-    ).astype(jnp.int32)
-    return jnp.where(sample, sampled, greedy)
+
+    def draw():
+        scaled = (lo / jnp.maximum(temp, 1e-6))[None, :]
+        return jax.random.categorical(
+            key, _filter_top_k_top_p(scaled, top_k[None], top_p[None])[0]
+        ).astype(jnp.int32)
+
+    return _if_any_samples(sample, draw, greedy)
 
 
 class ServingEngine:

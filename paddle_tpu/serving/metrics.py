@@ -30,7 +30,9 @@ from ..utils import flight_recorder, monitor, telemetry
 #: (`wave.blocks` / `wave.stage` / `wave.dispatch` / `wave.wait` inside
 #: decode_wave, `prefill.stage` / `prefill.dispatch` /
 #: `prefill.first_token` inside prefill_chunk); `token_masks` and
-#: `round_tail` are scheduler work outside the four; `unfed` (seconds
+#: `round_tail` are scheduler work outside the four; `state.reset` is
+#: the zeroing of a slot's recurrent record inside admission (models
+#: with slot state only); `unfed` (seconds
 #: from a blocking read of a program's output to the next program
 #: dispatch) overlaps the others.
 PHASES = ("admission", "prefill_chunk", "decode_wave", "host_dispatch")
@@ -197,6 +199,10 @@ class ServingMetrics:
         # instance's decode waves, and the entries their tables held
         self._pages_visited = 0
         self._pages_spanned = 0
+        # what a model with slot state or experts was staged (0 for any
+        # other): slot records zeroed at admission, (token, expert)
+        # pairs routed
+        self._model_counts = {"state_resets": 0, "moe_picks": 0}
         # per-phase wall time (seconds, folded in once per scheduler
         # round)
         self._phase_seconds = {}
@@ -273,6 +279,12 @@ class ServingMetrics:
             self._pages_visited += int(visited)
             self._pages_spanned += int(spanned)
 
+    def on_model_counts(self, counts):
+        """One round's `PagedServingEngine.take_model_counts()`."""
+        with self._lock:
+            for k, v in counts.items():
+                self._model_counts[k] += int(v)
+
     def on_queue_depth(self, depth):
         monitor.stat_max(QUEUE_DEPTH_PEAK, int(depth))  # process-wide peak
         _QUEUE_DEPTH.set(int(depth))
@@ -345,6 +357,7 @@ class ServingMetrics:
                 p_misses = self._prefix_last[1] - self._prefix_base[1]
             phase_seconds = dict(self._phase_seconds)
             pages_v, pages_s = self._pages_visited, self._pages_spanned
+            model_counts = dict(self._model_counts)
             spec_p, spec_a = self._spec_proposed, self._spec_accepted
             spec_w = self._spec_waves
         return {
@@ -397,4 +410,7 @@ class ServingMetrics:
             # walks in the decode waves (0 / 0 on a dense engine)
             "paged_pages_visited": pages_v,
             "paged_pages_spanned": pages_s,
+            # slot records zeroed and (token, expert) pairs routed, for
+            # a model that has either (serving/paged/engine.py)
+            **model_counts,
         }

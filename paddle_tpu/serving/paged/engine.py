@@ -75,12 +75,28 @@ def _handoff_digest(layers, n_tokens, block_size):
     return h.hexdigest()
 
 
+#: why a model with slot state is refused what moves, shares or rolls
+#: back K/V pages alone (formatted with the operation's name)
+SLOT_STATE_REFUSAL = (
+    "{what} moves K/V pages only, and this model keeps a recurrent state "
+    "a slot beside them (model.slot_state) that no page holds: a snapshot "
+    "of that state at the boundary is needed first")
+
+
 class PagedServingEngine(ServingEngine):
     """Block-table batched decode executor.
 
     model: a causal LM exposing init_paged_cache / decode_step(...,
         block_tables=) / prefill_chunk (GPTForPretraining,
-        LlamaForCausalLM).
+        LlamaForCausalLM). A model that declares `slot_state`
+        (NemotronHForCausalLM) keeps a fixed record a slot beside the
+        pages: its init_paged_cache takes `num_slots` and returns
+        {"kv": pools, "state": arrays with leading dimension num_slots},
+        its prefill_chunk takes `slot`, its decode_step takes `active`.
+        The engine zeroes a slot's record when the slot begins a prompt,
+        serves such a model without prefix sharing (a shared page holds
+        no state, so a hit would be silently wrong) and refuses it
+        hand-off and speculation.
     max_len: per-request horizon; must be a multiple of block_size
         (table width = max_len // block_size).
     num_blocks: pool size INCLUDING the scratch block (block 0).
@@ -115,7 +131,8 @@ class PagedServingEngine(ServingEngine):
             raise ValueError(
                 f"prefill_chunk_len {self.prefill_chunk_len} > max_len "
                 f"{max_len}")
-        self.prefix_sharing = bool(prefix_sharing)
+        self.slot_state = bool(getattr(model, "slot_state", False))
+        self.prefix_sharing = bool(prefix_sharing) and not self.slot_state
         self.block_pool = BlockPool(num_blocks, self.block_size)
         self._copy_fn = None
         self._handoff_gather_fn = None
@@ -132,25 +149,41 @@ class PagedServingEngine(ServingEngine):
         # staged since the scheduler last took them (take_page_counts,
         # once a round), and the entries those waves' tables hold
         self._pages_visited = self._pages_spanned = 0
+        # what the model's own layers were staged since the scheduler
+        # last took them (take_model_counts, once a round): slot records
+        # zeroed, (token, expert) pairs routed
+        self._picks_per_token = int(getattr(model, "moe_picks_per_token",
+                                            0))
+        self.counts_model_work = bool(self.slot_state
+                                      or self._picks_per_token)
+        self._state_resets = self._moe_picks = 0
 
     def _make_caches(self):
-        return self.model.init_paged_cache(self.block_pool.num_blocks,
-                                           self.block_size, self.max_len,
-                                           dtype=self.cache_dtype)
+        extra = {"num_slots": self.num_slots} if self.slot_state else {}
+        caches = self.model.init_paged_cache(
+            self.block_pool.num_blocks, self.block_size, self.max_len,
+            dtype=self.cache_dtype, **extra)
+        if self.slot_state:
+            # what the slots' records hold on the device (/healthz)
+            self._state_bytes = sum(
+                a.nbytes for a in jax.tree_util.tree_leaves(caches["state"]))
+        return caches
 
     # ---------------------------------------------------------- programs
     def _build_programs(self):
         model, kern = self.model, self.paged_kernel
+        slot_state = self.slot_state
 
         def decode_wave(p, b, caches, tables, tok, pos, active, sample,
                         temps, top_k, top_p, bias, poison, key):
             # the scope pins this engine's kernel at TRACE time — the
             # compiled wave keeps whatever it resolved, regardless of
             # the process default when later engines trace
+            lanes = {"active": active} if slot_state else {}
             with paged_attention.kernel_scope(kern):
                 out, _ = model.functional_call(p, b, tok[:, None], caches,
                                                pos, method="decode_step",
-                                               block_tables=tables)
+                                               block_tables=tables, **lanes)
             logits, new_caches = out
             lo = _raw(logits)[:, 0, :].astype(jnp.float32)
             nxt, new_pos, finite = _select_wave_tokens(
@@ -160,12 +193,15 @@ class PagedServingEngine(ServingEngine):
 
         def prefill_chunk(p, b, caches, table, chunk, chunk_start,
                           valid_len, frontier, sample, temp, top_k,
-                          top_p, bias, key):
+                          top_p, bias, key, *slot):
+            # `slot`: the request's slot, passed for a model with slot
+            # state and for no other (their program keeps its arguments)
             with paged_attention.kernel_scope(kern):
                 out, _ = model.functional_call(
                     p, b, chunk[None, :], caches, method="prefill_chunk",
                     block_tables=table[None, :], chunk_start=chunk_start,
-                    valid_len=valid_len, frontier=frontier)
+                    valid_len=valid_len, frontier=frontier,
+                    **({"slot": slot[0]} if slot else {}))
             logits, new_caches = out
             # frontier logits [1, 1, V]: only the FINAL chunk's value is
             # consumed on host; earlier chunks compute a [V] row that is
@@ -175,9 +211,22 @@ class PagedServingEngine(ServingEngine):
                                         bias, key)
             return first, new_caches
 
+        def state_reset(caches, slot):
+            """Zero one slot's record in every state array (a small
+            program of its own: the pools pass through it aliased)."""
+            state = jax.tree_util.tree_map(
+                lambda a: jax.lax.dynamic_update_slice_in_dim(
+                    a, jnp.zeros((1,) + a.shape[1:], a.dtype), slot, 0),
+                caches["state"])
+            return {**caches, "state": state}
+
         self._decode_wave_fn = decode_wave
         self._prefill_fn = prefill_chunk
         self._program_donate_argnums = (2,)
+        if slot_state:
+            self._state_reset = (telemetry.instrument_jit(
+                jax.jit(state_reset, donate_argnums=(0,)),
+                "paged_state_reset") if self._jit else state_reset)
 
         if self._jit:
             # the block pools are donated exactly like the dense cache:
@@ -203,6 +252,7 @@ class PagedServingEngine(ServingEngine):
                   "num_blocks": self.block_pool.num_blocks,
                   "prefill_chunk_len": self.prefill_chunk_len,
                   "prefix_sharing": self.prefix_sharing,
+                  "slot_state": self.slot_state,
                   "paged_kernel": self.paged_kernel})
         return d
 
@@ -255,6 +305,15 @@ class PagedServingEngine(ServingEngine):
             # retries at the queue head must not inflate the rate
             self.block_pool.count_prefix(len(shared),
                                          n // bs - len(shared))
+        if self.slot_state:
+            # the slot's last request left its record behind: this one
+            # starts from zero, at token 0 (nothing was shared)
+            with RecordEvent("serving/state/reset", pid=self.trace_pid,
+                             slot=slot) as ev:
+                self._caches = self._state_reset(self._caches,
+                                                 np.int32(slot))
+            self._acc("state.reset", ev)
+            self._state_resets += 1
         blocks = shared + fresh
         self._slot_blocks[slot] = blocks
         self._tables[slot, :] = 0
@@ -310,7 +369,9 @@ class PagedServingEngine(ServingEngine):
                     jnp.float32(sampling["temp"]),
                     jnp.int32(sampling["top_k"]),
                     jnp.float32(sampling["top_p"]),
-                    jnp.asarray(sampling["bias"]), sub)
+                    jnp.asarray(sampling["bias"]), sub,
+                    *((jnp.int32(slot),) if self.slot_state else ()))
+            self._moe_picks += valid * self._picks_per_token
         self._acc("prefill.stage", ev)
         with RecordEvent("serving/prefill/dispatch", pid=pid) as ev:
             first, self._caches = self._prefill(*args)
@@ -371,6 +432,9 @@ class PagedServingEngine(ServingEngine):
         The slot itself is left untouched: the caller retires it (which
         frees the blocks but keeps their prefix hashes) only once the
         payload is safely in hand."""
+        if self.slot_state:
+            raise HandoffRefused(SLOT_STATE_REFUSAL.format(
+                what="export_slot_kv (block-level hand-off)"))
         if not self.slot_active[slot]:
             raise RuntimeError(f"slot {slot} is not active "
                                "(handoff export needs a completed prefill)")
@@ -423,6 +487,9 @@ class PagedServingEngine(ServingEngine):
         the single-replica schedule. No prefill-chunk program runs (the
         scatter is a separate lazy jit), which is the whole point:
         a handoff costs bytes on the wire, not recompute."""
+        if self.slot_state:
+            raise HandoffRefused(SLOT_STATE_REFUSAL.format(
+                what="import_handoff (block-level hand-off)"))
         why = self.validate_prompt(prompt)
         if why:
             raise ValueError(why)
@@ -554,6 +621,7 @@ class PagedServingEngine(ServingEngine):
             self.blocks_per_slot, self._attn_window)
         self._pages_visited += int(np.sum(hi - lo))
         self._pages_spanned += tables.size
+        self._moe_picks += sum(active_now) * self._picks_per_token
         return (self._params, self._buffers, self._caches,
                 jnp.asarray(tables),
                 jnp.asarray(self.slot_tok, jnp.int32),
@@ -595,6 +663,16 @@ class PagedServingEngine(ServingEngine):
         self._pages_visited = self._pages_spanned = 0
         return out
 
+    def take_model_counts(self):
+        """{"state_resets", "moe_picks"} since the last call: slot
+        records zeroed at admission, and (token, expert) pairs of the
+        tokens staged into chunks and waves. Taken by the scheduler once
+        a round, from an engine whose `counts_model_work` is set."""
+        out = {"state_resets": self._state_resets,
+               "moe_picks": self._moe_picks}
+        self._state_resets = self._moe_picks = 0
+        return out
+
     # ------------------------------------------------------------- slots
     def retire_slot(self, slot):
         """Free the slot AND its blocks. Freed blocks keep their prefix
@@ -618,7 +696,10 @@ class PagedServingEngine(ServingEngine):
                  cache_blocks_used=self.block_pool.used,
                  cache_blocks_total=self.block_pool.usable,
                  prefix_cache_hits=self.block_pool.prefix_hits,
-                 prefix_cache_misses=self.block_pool.prefix_misses)
+                 prefix_cache_misses=self.block_pool.prefix_misses,
+                 prefix_sharing=self.prefix_sharing)
+        if self.slot_state:
+            h.update(slot_state=True, state_bytes=self._state_bytes)
         return h
 
 
@@ -745,6 +826,11 @@ class SpeculativePagedEngine(PagedServingEngine):
             raise ValueError("SpeculativePagedEngine needs a draft_model")
         if spec_k < 1:
             raise ValueError(f"spec_k must be >= 1, got {spec_k}")
+        if any(getattr(m, "slot_state", False)
+               for m in (model, draft_model)):
+            raise ValueError(SLOT_STATE_REFUSAL.format(
+                what="speculative decoding (the roll-back of a rejected "
+                     "draft)"))
         self.spec_k = int(spec_k)
         draft_model.eval()
         self.draft_model = draft_model

@@ -147,6 +147,10 @@ class Scheduler:
         self._degraded = False
         self.last_error = None
         self.metrics = ServingMetrics(engine.num_slots)
+        # an engine whose model keeps slot state or routes to experts
+        # counts that work; the others are asked nothing a round
+        self._counts_model_work = getattr(engine, "counts_model_work",
+                                          False)
         # /healthz carries this scheduler's queue depth (fleet routers
         # and LBs read load + pool pressure from one endpoint)
         engine.attach_queue_probe(self.queue_depth)
@@ -868,6 +872,8 @@ class Scheduler:
             self.metrics.on_phases(phases)
             if hasattr(eng, "take_page_counts"):
                 self.metrics.on_pages(*eng.take_page_counts())
+            if self._counts_model_work:
+                self.metrics.on_model_counts(eng.take_model_counts())
             if not (self._round_worked and pending):
                 # an empty server is not a slow host: what passes until
                 # the next dispatch is not the host's doing

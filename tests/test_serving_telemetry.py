@@ -118,7 +118,10 @@ def test_snapshot_keys_byte_compatible(engine):
         "spec_tokens_proposed", "spec_tokens_accepted",
         "spec_acceptance_rate", "spec_accepted_per_wave",
         # the paged core's page counters (0 / 0 on a dense engine)
-        "paged_pages_visited", "paged_pages_spanned"]
+        "paged_pages_visited", "paged_pages_spanned",
+        # what a model with slot state or experts was staged (0 / 0 for
+        # any other)
+        "state_resets", "moe_picks"]
     # a 3-token request has 2 inter-token gaps — TPOT is real, and the
     # phase split saw every phase of a working round
     assert snap["tpot_p50_s"] is not None

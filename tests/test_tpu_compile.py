@@ -72,7 +72,7 @@ def _kernel_names(fn, *shapes):
     """Names of the compiled program's Mosaic instructions, without the
     ".<n>" suffix: what a device trace calls their events."""
     txt = jax.jit(fn).lower(*shapes).compile().as_text()
-    return sorted(re.match(r"\s*%([^ ]+?)(\.\d+)? = ", line).group(1)
+    return sorted(re.match(r"\s*(?:ROOT )?%([^ ]+?)(\.\d+)? = ", line).group(1)
                   for line in txt.splitlines()
                   if 'custom_call_target="tpu_custom_call"' in line)
 
@@ -163,6 +163,9 @@ def test_paged_core_server_resolves_on_tpu_compiles_for_v5e(
     ("mistral-chat-chunk128", 1, 32, 8, 128, 128, 160, 4096),
     # a window inside the table, and the speculative verify wave's form
     # (C = k + 1 queries at per-lane starts over every lane)
+    # nemotron3n-serve-reason: 128 lanes, max_len 2048, GQA 32/2
+    ("nemotron-reason-wave", 128, 32, 2, 1, 128, 128, None),
+    ("nemotron-reason-chunk128", 1, 32, 2, 128, 128, 128, None),
     ("mistral-wave-window1024", 64, 32, 8, 1, 128, 160, 1024),
     ("gpt2s-verify-k4", 128, 12, 12, 5, 64, 64, None),
 ])
@@ -180,6 +183,26 @@ def test_paged_core_compiles_at_the_cells_shapes_for_v5e(
 
     assert _kernel_names(fn, *_paged_args(one_chip, b, h, hkv, c, d,
                                           nblk=nblk)) == ["paged_attention"]
+
+
+@pytest.mark.parametrize("name,rows", [("wave-128-lanes", 768),
+                                       ("ragged-rows", 100)])
+def test_grouped_expert_kernel_compiles_for_v5e(one_chip, as_on_tpu, name,
+                                                rows):
+    """`moe_experts` at nemotron3n-serve-reason's size (128 experts of
+    1856 x 2688, 768 picks a wave or a chunk): one kernel, under its
+    name, and neither stack of matrices copied on the way in (both are
+    stored [experts, width, hidden], which the chip tiles exactly)."""
+    from paddle_tpu.ops.pallas.grouped_mlp import grouped_mlp
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    w = sds((128, 1856, 2688), jnp.bfloat16)
+    shapes = (sds((rows, 2688), jnp.bfloat16), w, w, sds((128,), jnp.int32))
+    assert _kernel_names(grouped_mlp, *shapes) == ["moe_experts"]
+    txt = jax.jit(grouped_mlp).lower(*shapes).compile().as_text()
+    assert not re.search(r"bf16\[128,1856,2688\][^ ]* copy\(", txt)
 
 
 def test_lax_paged_core_compiles_for_v5e(one_chip):
