@@ -179,40 +179,41 @@ class GPTAttention(nn.Layer):
         return (jnp.zeros(shape, dtype), jnp.zeros(shape, dtype))
 
     def init_paged_cache(self, num_blocks, block_size, dtype=jnp.float32):
-        """Block-pool KV cache [num_blocks, heads, block_size, head_dim]
-        x2 — requests claim BLOCKS (named by a host-managed table), not
-        dense rows; see serving/paged."""
-        shape = (num_blocks, self.num_heads, block_size, self.head_dim)
-        return (jnp.zeros(shape, dtype), jnp.zeros(shape, dtype))
+        """Block-pool KV cache [num_blocks, heads, block_size,
+        2 * head_dim] (K beside V: nn.transformer's stored form) —
+        requests claim BLOCKS (named by a host-managed table), not dense
+        rows; see serving/paged."""
+        from ..nn.transformer import init_block_kv
+        return init_block_kv(num_blocks, self.num_heads, block_size,
+                             self.head_dim, dtype)
 
     def decode(self, x_t, cache, pos, block_tables=None):
         """One-token step: write K/V at `pos`, attend q over cache[:pos].
         x_t: [B, 1, H] Tensor; pos: traced int — a scalar (lockstep
         batch) or a [B] vector (slot-wise serving decode: per-row cache
         scatter + per-row mask, same shapes, one program). With
-        block_tables [B, nblk], `cache` is the block POOL: K/V scatter
-        through the table and attention reads the gathered per-row
-        view — same fixed shapes, one program for every allocation."""
+        block_tables [B, nblk], `cache` is the block POOL: K/V are
+        written through the table and attention reads it through the
+        table — same fixed shapes, one program for every allocation."""
         b = x_t.shape[0]
         qkv = self.qkv_proj(x_t)
         a = qkv._data if isinstance(qkv, Tensor) else qkv
         a = a.reshape(b, 1, 3, self.num_heads, self.head_dim)
         a = jnp.transpose(a, (2, 0, 3, 1, 4))           # [3, B, nh, 1, D]
         q, k_t, v_t = a[0], a[1], a[2]
-        ck, cv = cache
         from ..nn.paged_attention import paged_decode_attention
         from ..nn.transformer import (cached_decode_attention,
-                                      scatter_block_kv_at, scatter_kv_at)
+                                      scatter_kv_at, write_block_kv)
         if block_tables is not None:
             # fused path: attention reads K/V straight out of the pool
             # through the table (dispatch: reference | lax | pallas) —
             # the [B, Hkv, nblk*BS, D] gathered view never exists
-            ck = scatter_block_kv_at(ck, k_t, block_tables, pos)
-            cv = scatter_block_kv_at(cv, v_t, block_tables, pos)
-            out = paged_decode_attention(q, ck, cv, block_tables, pos,
+            cache = write_block_kv(cache, k_t, v_t, block_tables, pos)
+            out = paged_decode_attention(q, cache, block_tables, pos,
                                          1.0 / math.sqrt(self.head_dim),
                                          window=self.attn_window)
         else:
+            ck, cv = cache
             if jnp.ndim(pos):
                 ck = scatter_kv_at(ck, k_t, pos)
                 cv = scatter_kv_at(cv, v_t, pos)
@@ -224,63 +225,55 @@ class GPTAttention(nn.Layer):
             out = cached_decode_attention(q, ck, cv, pos,
                                           1.0 / math.sqrt(self.head_dim),
                                           window=self.attn_window)
+            cache = (ck, cv)
         out = jnp.transpose(out, (0, 2, 1, 3)).reshape(b, 1, -1)
         out = self.out_proj(Tensor(out.astype(x_t._data.dtype)))
-        return out, (ck, cv)
+        return out, cache
 
     def prefill_chunk(self, x, cache, block_tables, chunk_start, valid_len):
-        """One prompt CHUNK [1, C, H] against the block pool: scatter the
+        """One prompt CHUNK [1, C, H] against the block pool: write the
         chunk's K/V through the table at absolute positions chunk_start +
-        arange(C) (the padded tail past valid_len goes to scratch), then
-        attend the C queries over the gathered view — previous chunks'
-        cached positions plus this chunk's own causal prefix
-        (chunk_attention masks ks <= chunk_start + i)."""
+        arange(C) (the padded tail past valid_len is not written), then
+        attend the C queries over the pool — previous chunks' cached
+        positions plus this chunk's own causal prefix (the cores mask
+        ks <= chunk_start + i)."""
         b, s, h = x.shape
         qkv = self.qkv_proj(x)
         a = qkv._data if isinstance(qkv, Tensor) else qkv
         a = a.reshape(b, s, 3, self.num_heads, self.head_dim)
         a = jnp.transpose(a, (2, 0, 3, 1, 4))           # [3, B, nh, C, D]
         q, k, v = a[0], a[1], a[2]
-        ck, cv = cache
         from ..nn.paged_attention import paged_chunk_attention
-        from ..nn.transformer import scatter_block_kv_chunk
-        positions = chunk_start + jnp.arange(s)
-        ck = scatter_block_kv_chunk(ck, k, block_tables, positions,
-                                    valid_len)
-        cv = scatter_block_kv_chunk(cv, v, block_tables, positions,
-                                    valid_len)
-        out = paged_chunk_attention(q, ck, cv, block_tables,
-                                    chunk_start,
+        from ..nn.transformer import write_block_kv
+        cache = write_block_kv(cache, k, v, block_tables, chunk_start,
+                               valid_len)
+        out = paged_chunk_attention(q, cache, block_tables, chunk_start,
                                     1.0 / math.sqrt(self.head_dim),
                                     window=self.attn_window)
         out = jnp.transpose(out, (0, 2, 1, 3)).reshape(b, s, h)
-        return self.out_proj(Tensor(out.astype(x._data.dtype))), (ck, cv)
+        return self.out_proj(Tensor(out.astype(x._data.dtype))), cache
 
     def decode_chunk(self, x, cache, block_tables, start, valid_len):
         """Speculative verify step: C tokens for EVERY lane at once
         (x: [S, C, H]; start/valid_len: [S]) — the batched, per-lane-
-        offset sibling of prefill_chunk. K/V scatter through every
-        lane's table in one op (writes at i >= valid_len[s] go to
-        scratch: horizon / spec_len clamp) and chunk_attention's
-        vector start gives each query row its own causal frontier."""
+        offset sibling of prefill_chunk. K/V are written through every
+        lane's table in one op (nothing at i >= valid_len[s]: horizon /
+        spec_len clamp) and the cores' vector start gives each query
+        row its own causal frontier."""
         b, s, h = x.shape
         qkv = self.qkv_proj(x)
         a = qkv._data if isinstance(qkv, Tensor) else qkv
         a = a.reshape(b, s, 3, self.num_heads, self.head_dim)
         a = jnp.transpose(a, (2, 0, 3, 1, 4))           # [3, S, nh, C, D]
         q, k, v = a[0], a[1], a[2]
-        ck, cv = cache
         from ..nn.paged_attention import paged_chunk_attention
-        from ..nn.transformer import scatter_block_kv_chunk_batched
-        ck = scatter_block_kv_chunk_batched(ck, k, block_tables, start,
-                                            valid_len)
-        cv = scatter_block_kv_chunk_batched(cv, v, block_tables, start,
-                                            valid_len)
-        out = paged_chunk_attention(q, ck, cv, block_tables, start,
+        from ..nn.transformer import write_block_kv
+        cache = write_block_kv(cache, k, v, block_tables, start, valid_len)
+        out = paged_chunk_attention(q, cache, block_tables, start,
                                     1.0 / math.sqrt(self.head_dim),
                                     window=self.attn_window)
         out = jnp.transpose(out, (0, 2, 1, 3)).reshape(b, s, h)
-        return self.out_proj(Tensor(out.astype(x._data.dtype))), (ck, cv)
+        return self.out_proj(Tensor(out.astype(x._data.dtype))), cache
 
     def prefill(self, x, cache):
         """Prompt-phase step: the forward attention math over x [B, P, H]
@@ -448,7 +441,7 @@ class GPTModel(nn.Layer):
 
     def init_paged_cache(self, num_blocks, block_size, max_len,
                          dtype=jnp.float32):
-        """Per-layer block pools [num_blocks, heads, block_size, hd] x2.
+        """Per-layer block pools [num_blocks, heads, block_size, 2 * hd].
         max_len is the per-request horizon (nblk * block_size) — checked
         against the position-embedding table here because inside the
         decode wave `pos` is traced and the gather would clamp

@@ -300,11 +300,13 @@ class LlamaAttention(nn.Layer):
         return (jnp.zeros(shape, dtype), jnp.zeros(shape, dtype))
 
     def init_paged_cache(self, num_blocks, block_size, dtype=jnp.float32):
-        """Block-pool KV cache [num_blocks, kv_heads, block_size, hd] x2
-        — GQA pools cache only the kv heads, and requests claim blocks
-        through a host-managed table (serving/paged)."""
-        shape = (num_blocks, self.num_kv_heads, block_size, self.head_dim)
-        return (jnp.zeros(shape, dtype), jnp.zeros(shape, dtype))
+        """Block-pool KV cache [num_blocks, kv_heads, block_size, 2 * hd]
+        (K beside V: nn.transformer's stored form) — GQA pools cache only
+        the kv heads, and requests claim blocks through a host-managed
+        table (serving/paged)."""
+        from ..nn.transformer import init_block_kv
+        return init_block_kv(num_blocks, self.num_kv_heads, block_size,
+                             self.head_dim, dtype)
 
     def decode(self, x_t, cache, pos, block_tables=None):
         """One-token step: RoPE at `pos` (traced), write K/V, attend over
@@ -312,9 +314,9 @@ class LlamaAttention(nn.Layer):
         batch) or a [B] vector — slot-wise serving decode where each row
         is at its own depth; the vector path scatters per-row cache
         writes and masks per-row, same fixed shapes, one program. With
-        block_tables [B, nblk] the cache is the block POOL: K/V scatter
-        through the table and attention reads the gathered per-row
-        view."""
+        block_tables [B, nblk] the cache is the block POOL: K/V are
+        written through the table and attention reads it through the
+        table."""
         from ..framework.tensor import Tensor
         nh, nkv, hd = self.num_heads, self.num_kv_heads, self.head_dim
         b = x_t.shape[0]
@@ -324,22 +326,21 @@ class LlamaAttention(nn.Layer):
         q = q.reshape(b, 1, nh, hd).transpose(0, 2, 1, 3)
         k_t = k_t.reshape(b, 1, nkv, hd).transpose(0, 2, 1, 3)
         v_t = v_t.reshape(b, 1, nkv, hd).transpose(0, 2, 1, 3)
-        ck, cv = cache
         from ..nn.paged_attention import paged_decode_attention
         from ..nn.transformer import (cached_decode_attention,
-                                      scatter_block_kv_at, scatter_kv_at)
+                                      scatter_kv_at, write_block_kv)
         if block_tables is not None:
             # fused path: attention reads K/V straight out of the pool
             # through the table (dispatch: reference | lax | pallas) —
             # the [B, Hkv, nblk*BS, D] gathered view never exists
             q = apply_rope_at(q, self._cos, self._sin, pos)
             k_t = apply_rope_at(k_t, self._cos, self._sin, pos)
-            ck = scatter_block_kv_at(ck, k_t, block_tables, pos)
-            cv = scatter_block_kv_at(cv, v_t, block_tables, pos)
-            out = paged_decode_attention(q, ck, cv, block_tables, pos,
+            cache = write_block_kv(cache, k_t, v_t, block_tables, pos)
+            out = paged_decode_attention(q, cache, block_tables, pos,
                                          1.0 / math.sqrt(hd),
                                          window=self.attn_window)
         else:
+            ck, cv = cache
             if jnp.ndim(pos):
                 q = apply_rope_at(q, self._cos, self._sin, pos)
                 k_t = apply_rope_at(k_t, self._cos, self._sin, pos)
@@ -356,17 +357,18 @@ class LlamaAttention(nn.Layer):
             out = cached_decode_attention(q, ck, cv, pos,
                                           1.0 / math.sqrt(hd),
                                           window=self.attn_window)
+            cache = (ck, cv)
         out = jnp.transpose(out, (0, 2, 1, 3)).reshape(b, 1, nh * hd)
         out = self.o_proj(Tensor(out.astype(x_t._data.dtype)))
-        return out, (ck, cv)
+        return out, cache
 
     def prefill_chunk(self, x, cache, block_tables, chunk_start,
                       valid_len):
         """One prompt chunk [1, C, H] against the block pool: RoPE at the
         absolute positions chunk_start + arange(C) (gathered per
         position — a final chunk may overrun the table with pad rows),
-        scatter the chunk's K/V through the table, attend the C queries
-        over the gathered view (previous chunks + own causal prefix)."""
+        write the chunk's K/V through the table, attend the C queries
+        over the pool (previous chunks + own causal prefix)."""
         from ..framework.tensor import Tensor
         nh, nkv, hd = self.num_heads, self.num_kv_heads, self.head_dim
         b, s = x.shape[0], x.shape[1]
@@ -379,28 +381,25 @@ class LlamaAttention(nn.Layer):
         positions = chunk_start + jnp.arange(s)
         q = apply_rope_positions(q, self._cos, self._sin, positions)
         k = apply_rope_positions(k, self._cos, self._sin, positions)
-        ck, cv = cache
         from ..nn.paged_attention import paged_chunk_attention
-        from ..nn.transformer import scatter_block_kv_chunk
-        ck = scatter_block_kv_chunk(ck, k, block_tables, positions,
-                                    valid_len)
-        cv = scatter_block_kv_chunk(cv, v, block_tables, positions,
-                                    valid_len)
-        out = paged_chunk_attention(q, ck, cv, block_tables, chunk_start,
+        from ..nn.transformer import write_block_kv
+        cache = write_block_kv(cache, k, v, block_tables, chunk_start,
+                               valid_len)
+        out = paged_chunk_attention(q, cache, block_tables, chunk_start,
                                     1.0 / math.sqrt(hd),
                                     window=self.attn_window)
         out = jnp.transpose(out, (0, 2, 1, 3)).reshape(b, s, nh * hd)
         out = self.o_proj(Tensor(out.astype(x._data.dtype)))
-        return out, (ck, cv)
+        return out, cache
 
     def decode_chunk(self, x, cache, block_tables, start, valid_len):
         """Speculative verify step: C tokens for EVERY lane at once.
         x: [S, C, H]; block_tables: [S, nblk]; start/valid_len: [S] —
         lane s's tokens sit at absolute positions start[s] + i, with
-        writes at i >= valid_len[s] redirected to the scratch block
-        (horizon / per-request spec_len clamp). RoPE is gathered at the
-        per-lane position matrix, K/V scatter through every lane's
-        table in one op (scatter_block_kv_chunk_batched), and
+        nothing written at i >= valid_len[s] (horizon / per-request
+        spec_len clamp). RoPE is gathered at the per-lane position
+        matrix, K/V are written through every lane's table in one op
+        (write_block_kv), and
         chunk_attention's vector-start mask gives each query row its
         own causal frontier — the C==1 case of this IS the decode wave,
         which is why verify is a third compiled program, not a new
@@ -417,19 +416,15 @@ class LlamaAttention(nn.Layer):
         positions = start[:, None] + jnp.arange(s)[None, :]    # [S, C]
         q = apply_rope_positions(q, self._cos, self._sin, positions)
         k = apply_rope_positions(k, self._cos, self._sin, positions)
-        ck, cv = cache
         from ..nn.paged_attention import paged_chunk_attention
-        from ..nn.transformer import scatter_block_kv_chunk_batched
-        ck = scatter_block_kv_chunk_batched(ck, k, block_tables, start,
-                                            valid_len)
-        cv = scatter_block_kv_chunk_batched(cv, v, block_tables, start,
-                                            valid_len)
-        out = paged_chunk_attention(q, ck, cv, block_tables, start,
+        from ..nn.transformer import write_block_kv
+        cache = write_block_kv(cache, k, v, block_tables, start, valid_len)
+        out = paged_chunk_attention(q, cache, block_tables, start,
                                     1.0 / math.sqrt(hd),
                                     window=self.attn_window)
         out = jnp.transpose(out, (0, 2, 1, 3)).reshape(b, s, nh * hd)
         out = self.o_proj(Tensor(out.astype(x._data.dtype)))
-        return out, (ck, cv)
+        return out, cache
 
     def prefill(self, x, cache):
         """Prompt-phase step: the training forward's attention math over
@@ -556,8 +551,8 @@ class LlamaModel(nn.Layer):
 
     def init_paged_cache(self, num_blocks, block_size, max_len,
                          dtype=jnp.float32):
-        """Per-layer block pools [num_blocks, kv_heads, block_size, hd]
-        x2. max_len (= nblk * block_size, the per-request horizon) is
+        """Per-layer block pools [num_blocks, kv_heads, block_size,
+        2 * hd]. max_len (= nblk * block_size, the per-request horizon) is
         validated against the RoPE table here because positions are
         traced inside the programs (dynamic_slice would clamp
         silently)."""

@@ -283,8 +283,9 @@ class NemotronHAttention(_Block):
         self.o_proj = self._matrix(self.nh * self.hd, cfg.hidden_size)
 
     def init_paged_cache(self, num_blocks, block_size, dtype):
-        shape = (num_blocks, self.nkv, block_size, self.hd)
-        return (jnp.zeros(shape, dtype), jnp.zeros(shape, dtype))
+        from ..nn.transformer import init_block_kv
+        return init_block_kv(num_blocks, self.nkv, block_size, self.hd,
+                             dtype)
 
     def _qkv(self, x):
         """x [B, L, hidden] -> q [B, L, nh, hd], k and v [B, L, nkv, hd]."""
@@ -310,26 +311,21 @@ class NemotronHAttention(_Block):
 
     def decode(self, x, cache, pos, tables):
         from ..nn.paged_attention import paged_decode_attention
-        from ..nn.transformer import scatter_block_kv_at
+        from ..nn.transformer import write_block_kv
         q, k, v = (jnp.swapaxes(t, 1, 2) for t in self._qkv(x))
-        ck = scatter_block_kv_at(cache[0], k, tables, pos)
-        cv = scatter_block_kv_at(cache[1], v, tables, pos)
-        o = paged_decode_attention(q, ck, cv, tables, pos,
+        cache = write_block_kv(cache, k, v, tables, pos)
+        o = paged_decode_attention(q, cache, tables, pos,
                                    1.0 / math.sqrt(self.hd))
-        return self._out(o, x.dtype), (ck, cv)
+        return self._out(o, x.dtype), cache
 
     def prefill_chunk(self, x, cache, tables, chunk_start, valid_len):
         from ..nn.paged_attention import paged_chunk_attention
-        from ..nn.transformer import scatter_block_kv_chunk
+        from ..nn.transformer import write_block_kv
         q, k, v = (jnp.swapaxes(t, 1, 2) for t in self._qkv(x))
-        positions = chunk_start + jnp.arange(x.shape[1])
-        ck = scatter_block_kv_chunk(cache[0], k, tables, positions,
-                                    valid_len)
-        cv = scatter_block_kv_chunk(cache[1], v, tables, positions,
-                                    valid_len)
-        o = paged_chunk_attention(q, ck, cv, tables, chunk_start,
+        cache = write_block_kv(cache, k, v, tables, chunk_start, valid_len)
+        o = paged_chunk_attention(q, cache, tables, chunk_start,
                                   1.0 / math.sqrt(self.hd))
-        return self._out(o, x.dtype), (ck, cv)
+        return self._out(o, x.dtype), cache
 
 
 # ------------------------------------------------------------------ experts
@@ -501,7 +497,7 @@ class NemotronHForCausalLM(nn.Layer):
 
     def init_paged_cache(self, num_blocks, block_size, max_len,
                          dtype=jnp.float32, num_slots=1):
-        """{"kv": a (K, V) block pool an attention layer, "state": a
+        """{"kv": a K/V block pool an attention layer, "state": a
         Mamba record (`ssm`, `conv`; leading dimension `num_slots`) a
         Mamba layer}."""
         mixers = [blk.mixer for blk in self.layers]

@@ -24,7 +24,7 @@ dispatch point:
                       l, weighted accumulator); works on every backend;
   kernel="pallas"     a Pallas TPU kernel — grid over (lanes, groups of
                       kv-heads, steps of pages). A step takes whole
-                      pages as the pool stores them (the [heads, BS, D]
+                      pages as the pool stores them (the [heads, BS, 2D]
                       slab of every kv-head of the group) through the
                       BlockSpec index_map over a scalar-prefetch table,
                       and only the pages the lane attends
@@ -47,6 +47,13 @@ dispatch point:
   kernel="auto"       "pallas" on TPU, "lax" elsewhere. An explicit
                       "pallas" reaches the compiler as is: nothing here
                       catches a refusal or falls back.
+
+The pool's stored form (every core, every model): one array a layer,
+`[NB, Hkv, BS, 2D]`, a position's K row in `[..., :D]` and its V row
+beside it in `[..., D:]` (`nn.transformer.write_block_kv` writes it and
+says why). A core takes that array as it is stored; the Pallas core
+fetches a page's K and V as one slab and never slices it (see
+`_paged_attn_kernel`).
 
 Both serving attention shapes are covered: the decode form (one query
 per lane; replaces gather+`cached_decode_attention` in the decode and
@@ -141,13 +148,14 @@ def resolve_kernel(kernel=None):
 # public entry points
 # ---------------------------------------------------------------------------
 
-def paged_decode_attention(q, pk, pv, tables, pos, scale, window=None,
+def paged_decode_attention(q, pool, tables, pos, scale, window=None,
                            kernel=None):
     """Fused decode attention over the block pool. q: [B, H, 1, D];
-    pk/pv: [NB, Hkv, BS, D] pools; tables: [B, nblk] int32; pos a traced
-    scalar or [B] vector of each lane's current position (the query's
-    own absolute position — keys at ks <= pos are attended, banded to
-    the last `window` when given). Returns [B, H, 1, D] in pv.dtype.
+    pool: [NB, Hkv, BS, 2D] (K beside V, the stored form); tables:
+    [B, nblk] int32; pos a traced scalar or [B] vector of each lane's
+    current position (the query's own absolute position — keys at
+    ks <= pos are attended, banded to the last `window` when given).
+    Returns [B, H, 1, D] in pool.dtype.
 
     Equivalent to gather_block_kv + cached_decode_attention without the
     gathered [B, Hkv, nblk*BS, D] intermediate."""
@@ -156,35 +164,33 @@ def paged_decode_attention(q, pk, pv, tables, pos, scale, window=None,
         from .transformer import cached_decode_attention, gather_block_kv
         # sanitize: the gathered view contains scratch-block positions
         # (masked by construction) whose garbage may be non-finite
-        return cached_decode_attention(q, gather_block_kv(pk, tables),
-                                       gather_block_kv(pv, tables),
-                                       pos, scale, window=window,
+        ck, cv = gather_block_kv(pool, tables)
+        return cached_decode_attention(q, ck, cv, pos, scale, window=window,
                                        sanitize=True)
     if k == "pallas":
-        return _pallas_core(q, pk, pv, tables, pos, scale, window)
-    return _lax_core(q, pk, pv, tables, pos, scale, window)
+        return _pallas_core(q, pool, tables, pos, scale, window)
+    return _lax_core(q, pool, tables, pos, scale, window)
 
 
-def paged_chunk_attention(q, pk, pv, tables, start, scale, window=None,
+def paged_chunk_attention(q, pool, tables, start, scale, window=None,
                           kernel=None):
     """Fused chunk attention over the block pool: C queries per lane at
     absolute positions start + i (start: traced scalar or [B] vector).
-    q: [B, H, C, D]; pools/tables as in paged_decode_attention. Query
+    q: [B, H, C, D]; pool/tables as in paged_decode_attention. Query
     row i masks ks <= start + i (banded to the last `window` keys when
-    given). Returns [B, H, C, D] in pv.dtype.
+    given). Returns [B, H, C, D] in pool.dtype.
 
     Equivalent to gather_block_kv + chunk_attention without the
     gathered intermediate; the decode form is the C == 1 case."""
     k = resolve_kernel(kernel)
     if k == "reference":
         from .transformer import chunk_attention, gather_block_kv
-        return chunk_attention(q, gather_block_kv(pk, tables),
-                               gather_block_kv(pv, tables),
-                               start, scale, window=window,
+        ck, cv = gather_block_kv(pool, tables)
+        return chunk_attention(q, ck, cv, start, scale, window=window,
                                sanitize=True)
     if k == "pallas":
-        return _pallas_core(q, pk, pv, tables, start, scale, window)
-    return _lax_core(q, pk, pv, tables, start, scale, window)
+        return _pallas_core(q, pool, tables, start, scale, window)
+    return _lax_core(q, pool, tables, start, scale, window)
 
 
 def _query_positions(start, b, c):
@@ -199,11 +205,11 @@ def _query_positions(start, b, c):
 # lax fallback: fori_loop over blocks, flash-attention recurrence
 # ---------------------------------------------------------------------------
 
-def _lax_core(q, pk, pv, tables, start, scale, window=None):
+def _lax_core(q, pool, tables, start, scale, window=None):
     """Online-softmax attention streamed block-by-block out of the pool.
 
     Carries (m, l, acc) across the nblk sequential steps: per block j
-    the lane's j-th pool block is fetched ([B, Hkv, BS, D] — the only
+    the lane's j-th pool block is fetched ([B, Hkv, BS, 2D] — the only
     gathered working set that ever exists), scored against the queries,
     masked with -inf at ks > qpos (and outside the window), and folded
     into the running max/denominator/weighted-V with the standard
@@ -214,7 +220,7 @@ def _lax_core(q, pk, pv, tables, start, scale, window=None):
     import jax.numpy as jnp
 
     b, h, c, d = q.shape
-    hkv, bs = pk.shape[1], pk.shape[2]
+    hkv, bs = pool.shape[1], pool.shape[2]
     nblk = tables.shape[1]
     rep = h // hkv
     qf = q.astype(jnp.float32).reshape(b, hkv, rep, c, d)
@@ -224,8 +230,8 @@ def _lax_core(q, pk, pv, tables, start, scale, window=None):
     def body(j, carry):
         m, l, acc = carry
         blk = tables[:, j]                             # [B]
-        kblk = pk[blk].astype(jnp.float32)             # [B, Hkv, BS, D]
-        vblk = pv[blk].astype(jnp.float32)
+        page = pool[blk].astype(jnp.float32)           # [B, Hkv, BS, 2D]
+        kblk, vblk = page[..., :d], page[..., d:]
         s = jnp.einsum("bkrcd,bksd->bkrcs", qf, kblk) * scale
         ks = j * bs + jnp.arange(bs)                   # absolute keys
         keep = ks[None, None, :] <= qpos[:, :, None]   # [B, C, BS]
@@ -257,7 +263,7 @@ def _lax_core(q, pk, pv, tables, start, scale, window=None):
     # guard on == 0, not > 0: a nan denominator (genuine attended
     # fault) must divide through and propagate, not silently zero
     out = jnp.where(l[..., None] == 0, 0.0, acc / l[..., None])
-    return out.reshape(b, h, c, d).astype(pv.dtype)
+    return out.reshape(b, h, c, d).astype(pool.dtype)
 
 
 # ---------------------------------------------------------------------------
@@ -289,15 +295,15 @@ def attended_pages(start, c, bs, nblk, window=None):
 
 
 #: rows of the query tile (kv-heads x group x chunk) one step may hold:
-#: float32 q, output and accumulator of 1024 x 128 are 0.5 MB each
+#: float32 q, output and accumulator of 1024 x 256 are 1 MB each
 _MAX_ROWS = 1024
-#: pages a step takes at most (each is two more pipelined operands)
+#: pages a step takes at most (each is one more pipelined operand)
 _MAX_PAGES = 8
 
 
 def _tile(c, rep, hkv):
     """(kv-heads a step, pages a step) from the call's shapes. A step
-    takes whole pages: the [heads, BS, D] slab of as many kv-heads as
+    takes whole pages: the [heads, BS, 2D] slab of as many kv-heads as
     keep the query tile within `_MAX_ROWS` rows (all of them in the
     decode form, where a row is one head of one lane), scored in one
     matmul whose cross-head entries are masked. What a step costs is one
@@ -317,8 +323,15 @@ def _tile(c, rep, hkv):
 def _paged_attn_kernel(tables_ref, start_ref, rows_ref, cols_ref, keys_ref,
                        q_ref, *refs, scale, window, bs, c, nblk, pages):
     """One (lane b, kv-head group g, step j) grid step: `pages` table
-    entries of the lane from `j * pages` on, each the [heads, BS, D]
-    slab of the group's kv-heads as the pool stores it. The pipeline
+    entries of the lane from `j * pages` on, each the [heads, BS, 2D]
+    slab of the group's kv-heads as the pool stores it, a key's K row
+    and V row side by side. The slab is never cut at D (at head_dim 64
+    that is the middle of a vreg's 128 lanes): the queries arrive
+    zero-extended to 2D, so `q @ slab.T` is `q @ K.T` (at an attended
+    key the V row is finite, or non-finite and due to propagate anyway;
+    anywhere else the score is masked), the accumulator is `p @ slab`,
+    2D wide, and its right half, `p @ V`, is cut out by the caller; both
+    are free at the MXU's width. The pipeline
     gathered them through the index_map; if any lies inside the lane's
     `attended_pages` the kernel scores them as one tile, masks it and
     folds it into the VMEM accumulators, which persist across the
@@ -342,8 +355,8 @@ def _paged_attn_kernel(tables_ref, start_ref, rows_ref, cols_ref, keys_ref,
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
 
-    k_refs, v_refs = refs[:pages], refs[pages:2 * pages]
-    o_ref, m_ref, l_ref, acc_ref = refs[2 * pages:]
+    page_refs = refs[:pages]
+    o_ref, m_ref, l_ref, acc_ref = refs[pages:]
     b = pl.program_id(0)
     j = pl.program_id(2)
     start = start_ref[b]                               # SMEM scalar
@@ -355,19 +368,19 @@ def _paged_attn_kernel(tables_ref, start_ref, rows_ref, cols_ref, keys_ref,
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    def slab(page_refs):
-        """[pages * heads * BS, D] float32 of the step's pages."""
-        heads, _, d = page_refs[0].shape[1:]
-        tiles = [r[0].astype(jnp.float32).reshape(heads * bs, d)
+    def slab():
+        """[pages * heads * BS, 2D] float32 of the step's pages."""
+        heads, _, d2 = page_refs[0].shape[1:]
+        tiles = [r[0].astype(jnp.float32).reshape(heads * bs, d2)
                  for r in page_refs]
         return tiles[0] if pages == 1 else jnp.concatenate(tiles, axis=0)
 
     @pl.when((j * pages < hi) & ((j + 1) * pages > lo))
     def _visit():
-        qf = q_ref[0, 0]                               # [rows, D]
-        kb, vb = slab(k_refs), slab(v_refs)
+        qf = q_ref[0, 0]                               # [rows, 2D]: (q, 0)
+        kv = slab()
         s = jax.lax.dot_general(                       # q @ k.T
-            qf, kb, (((1,), (1,)), ((), ())),
+            qf, kv, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * scale  # [rows, cols]
         first = j * pages * bs                         # the step's first key
         rowpos = start + rows_ref[:, 0:1]              # [rows, 1]
@@ -379,14 +392,14 @@ def _paged_attn_kernel(tables_ref, start_ref, rows_ref, cols_ref, keys_ref,
             keep &= ks > rowpos - window
         s = jnp.where(keep, s, -jnp.inf)
         # fully-unattended keys get probability 0 but 0 * nan == nan:
-        # zero the V rows no query row keeps so scratch poison cannot
+        # zero the rows no query row keeps so scratch poison cannot
         # leak. The rows sit at start .. start+C-1, so the keys some row
         # keeps are exactly (start - window, start + C - 1]
         kcol = first + keys_ref[...]                   # [cols, 1]
         attended = kcol <= jnp.minimum(start + (c - 1), hi * bs - 1)
         if window is not None:
             attended &= kcol > start - window
-        vb = jnp.where(attended, vb, 0.0)
+        kv = jnp.where(attended, kv, 0.0)
         m_prev = m_ref[...]                            # [rows, 1]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
         shift = jnp.where(jnp.isfinite(m_new), m_new, 0.0)
@@ -394,7 +407,7 @@ def _paged_attn_kernel(tables_ref, start_ref, rows_ref, cols_ref, keys_ref,
         alpha = jnp.exp(m_prev - shift)
         l_ref[...] = alpha * l_ref[...] + jnp.sum(p, axis=-1, keepdims=True)
         acc_ref[...] = alpha * acc_ref[...] + \
-            jnp.dot(p, vb, preferred_element_type=jnp.float32)
+            jnp.dot(p, kv, preferred_element_type=jnp.float32)
         m_ref[...] = m_new
 
     @pl.when(j == pl.num_programs(2) - 1)
@@ -405,11 +418,11 @@ def _paged_attn_kernel(tables_ref, start_ref, rows_ref, cols_ref, keys_ref,
 
 
 @functools.lru_cache(maxsize=None)
-def _pallas_call(b, h, c, d, hkv, bs, nblk, heads, pages, scale, window,
+def _pallas_call(b, h, c, d2, hkv, bs, nblk, heads, pages, scale, window,
                  dtype_name, interpret):
     """Build (and cache) the pallas_call for one static shape family.
     The block table and each lane's first query position ride as
-    scalar-prefetch operands so the K/V BlockSpec index_maps can address
+    scalar-prefetch operands so the pages' BlockSpec index_maps can address
     the pool by table VALUE — the gather happens in the pipeline, page
     by page, never as a materialised [B, Hkv, nblk*BS, D] array. A step
     outside the lane's `attended_pages` names the nearest page inside
@@ -432,7 +445,7 @@ def _pallas_call(b, h, c, d, hkv, bs, nblk, heads, pages, scale, window,
             # a lane that attends nothing has hi == lo, anywhere from 0
             # to past the table: stay inside it
             return tab[bb, jnp.clip(page, 0, nblk - 1)], gg, 0, 0
-        return pl.BlockSpec((1, heads, bs, d), index_map)
+        return pl.BlockSpec((1, heads, bs, d2), index_map)
 
     def per_group(bb, gg, jj, tab, st):
         return bb, gg, 0, 0
@@ -447,25 +460,24 @@ def _pallas_call(b, h, c, d, hkv, bs, nblk, heads, pages, scale, window,
             pl.BlockSpec((rows, 2), whole),
             pl.BlockSpec((2, cols), whole),
             pl.BlockSpec((cols, 1), whole),
-            pl.BlockSpec((1, 1, rows, d), per_group),
-            *[page_spec(i) for i in range(pages)],     # K pages
-            *[page_spec(i) for i in range(pages)],     # V pages
+            pl.BlockSpec((1, 1, rows, d2), per_group),
+            *[page_spec(i) for i in range(pages)],
         ],
-        out_specs=pl.BlockSpec((1, 1, rows, d), per_group),
+        out_specs=pl.BlockSpec((1, 1, rows, d2), per_group),
         scratch_shapes=[
             pltpu.VMEM((rows, 1), jnp.float32),        # running max m
             pltpu.VMEM((rows, 1), jnp.float32),        # running denom l
-            pltpu.VMEM((rows, d), jnp.float32),        # weighted V acc
+            pltpu.VMEM((rows, d2), jnp.float32),       # p @ (K, V) acc
         ],
     )
     return pl.pallas_call(
         kernel, grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, hkv // heads, rows, d),
+        out_shape=jax.ShapeDtypeStruct((b, hkv // heads, rows, d2),
                                        jnp.float32),
         interpret=interpret, name="paged_attention")
 
 
-def _pallas_core(q, pk, pv, tables, start, scale, window=None):
+def _pallas_core(q, pool, tables, start, scale, window=None):
     """Pallas path: same recurrence as _lax_core, with the block gather
     folded into the kernel pipeline. interpret=True on CPU so tier-1
     parity tests execute the genuine kernel body."""
@@ -473,16 +485,17 @@ def _pallas_core(q, pk, pv, tables, start, scale, window=None):
     import jax.numpy as jnp
 
     b, h, c, d = q.shape
-    hkv, bs = pk.shape[1], pk.shape[2]
+    hkv, bs = pool.shape[1], pool.shape[2]
     nblk = tables.shape[1]
     rep = h // hkv
     heads, pages = _tile(c, rep, hkv)
     rows = heads * rep * c
     start = jnp.broadcast_to(jnp.reshape(jnp.asarray(start, jnp.int32),
                                          (-1,)), (b,))
-    # [B, H, C, D] -> [B, Hkv/heads, heads*rep*C, D]: kv-head-major,
-    # then group member, query-minor rows
-    qr = q.astype(jnp.float32).reshape(b, hkv // heads, rows, d)
+    # [B, H, C, D] -> [B, Hkv/heads, heads*rep*C, 2D]: kv-head-major,
+    # then group member, query-minor rows, zeros where the slab holds V
+    qr = jnp.pad(q.astype(jnp.float32).reshape(b, hkv // heads, rows, d),
+                 ((0, 0), (0, 0), (0, 0), (0, d)))
     row = np.arange(rows, dtype=np.int32)
     col = np.arange(pages * heads * bs, dtype=np.int32)
     # per row: its query's offset in the chunk, its kv-head; per column:
@@ -490,15 +503,15 @@ def _pallas_core(q, pk, pv, tables, start, scale, window=None):
     rowinfo = np.stack([row % c, row // (rep * c)], axis=1)
     keyoff = col // (heads * bs) * bs + col % bs
     colinfo = np.stack([keyoff, col // bs % heads])
-    call = _pallas_call(b, h, c, d, hkv, bs, nblk, heads, pages,
+    call = _pallas_call(b, h, c, 2 * d, hkv, bs, nblk, heads, pages,
                         float(scale),
                         None if window is None else int(window),
-                        str(pk.dtype),
+                        str(pool.dtype),
                         jax.default_backend() != "tpu")
     # the scope, innermost at the call, is what names the instruction
     # in a device trace ("%paged_attention.1 = ... custom-call")
     with jax.named_scope("paged_attention"):
         out = call(tables.astype(jnp.int32), start, jnp.asarray(rowinfo),
                    jnp.asarray(colinfo), jnp.asarray(keyoff[:, None]), qr,
-                   *[pk] * pages, *[pv] * pages)
-    return out.reshape(b, h, c, d).astype(pv.dtype)
+                   *[pool] * pages)
+    return out[..., d:].reshape(b, h, c, d).astype(pool.dtype)

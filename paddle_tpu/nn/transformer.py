@@ -466,81 +466,114 @@ def scatter_kv_at(cache, kv_t, pos):
 # ---------------------------------------------------------------------------
 # paged KV cache primitives (serving/paged: block-table memory manager)
 # ---------------------------------------------------------------------------
-# The pool is [num_blocks, Hkv, block_size, D]; a request's cache is the
-# ordered sequence of pool blocks named by its block TABLE (int32 block
-# ids, host-managed by serving.paged.BlockPool). All shapes below are
-# static — table entries are VALUES, not shapes — so one compiled
-# program serves every allocation pattern (compile-once). Block 0 is
-# the scratch block: inactive/invalid lanes are redirected there, its
-# contents are garbage by design and never read by a surviving lane
-# (the ks <= pos mask and the active-lane `where` discard them).
+# The pool is ONE array a layer, [num_blocks, Hkv, block_size, 2 * D]: a
+# position's K row in [..., :D] and its V row beside it in [..., D:]. A
+# request's cache is the ordered sequence of pool blocks (pages) named by
+# its block TABLE (int32 block ids, host-managed by
+# serving.paged.BlockPool). All shapes below are static — table entries
+# are VALUES, not shapes — so one compiled program serves every
+# allocation pattern (compile-once). Block 0 is the scratch block:
+# inactive/invalid lanes are redirected there; nothing in it is kept
+# (every write zeroes it) and no surviving lane reads it at a position
+# it attends (the ks <= pos mask and the active-lane `where`).
+#
+# Why this form, and who has to keep it. A serving program is handed the
+# pool donated and hands it back; it stays where it is only if the write,
+# the attention kernel and the program's parameter and result all take
+# one layout. Row-major [.., BS, 2D] is that layout: 2D is 128 lanes at
+# head_dim 64 and 256 at 128, so a page of one kv-head is whole (16, 128)
+# bf16 tiles with nothing padded, which is what the chip picks for the
+# parameter by itself and what the Pallas core's BlockSpec takes; and the
+# write below moves whole pages, which the compiler updates in place in
+# that layout (a scatter of single rows, `pool.at[blk, :, row].set`, is
+# given a layout with the row dimension outermost, and the whole pool is
+# copied there and back: PERF.md, PR 28). Every program that touches the
+# pool (decode wave, prefill chunk, draft and verify waves, copy-on-write,
+# hand-off, state reset) takes and returns this array as it is;
+# tests/test_tpu_compile.py holds the serving programs to no pool-sized
+# copy at the benchmark's shapes.
+
+
+def init_block_kv(num_blocks, hkv, block_size, head_dim, dtype):
+    """A layer's empty pool in the stored form (see above)."""
+    import jax.numpy as jnp
+    return jnp.zeros((num_blocks, hkv, block_size, 2 * head_dim), dtype)
 
 
 def gather_block_kv(pool, tables):
-    """Materialise per-row KV views from the block pool. pool:
-    [NB, Hkv, BS, D]; tables: [B, nblk] int32 → [B, Hkv, nblk*BS, D],
+    """Materialise per-row K and V views from the block pool. pool:
+    [NB, Hkv, BS, 2D]; tables: [B, nblk] int32 → two [B, Hkv, nblk*BS, D],
     position p of row b living at pool[tables[b, p // BS], :, p % BS].
     One gather — the paged analog of reading the dense [B, Hkv, L, D]
     cache (same bytes streamed when nblk*BS == L)."""
     import jax.numpy as jnp
-    g = pool[tables]                           # [B, nblk, Hkv, BS, D]
-    b, nblk, hkv, bs, d = g.shape
-    return jnp.transpose(g, (0, 2, 1, 3, 4)).reshape(b, hkv, nblk * bs, d)
+    g = pool[tables]                           # [B, nblk, Hkv, BS, 2D]
+    b, nblk, hkv, bs, d2 = g.shape
+    g = jnp.transpose(g, (0, 2, 1, 3, 4)).reshape(b, hkv, nblk * bs, d2)
+    return g[..., :d2 // 2], g[..., d2 // 2:]
 
 
-def scatter_block_kv_at(pool, kv_t, tables, pos):
-    """Write one step's K or V [B, Hkv, 1, D] through block tables
-    [B, nblk] at per-row positions pos [B]: row b lands in
-    pool[tables[b, pos[b] // BS], :, pos[b] % BS]. One scatter. Rows
-    whose table entry is the scratch block (retired/starved lanes —
-    the host rewrites their table rows) collide there harmlessly."""
+def write_block_kv(pool, k, v, tables, start, valid_len=None):
+    """Write C new positions a lane into the pool: k, v [S, Hkv, C, D]
+    land at absolute positions start[s] + i, i < valid_len[s], through
+    the block tables [S, nblk]; position p of lane s lives at
+    pool[tables[s, p // BS], :, p % BS] (K in [..., :D], V in [..., D:]).
+    `start` and `valid_len` are traced scalars or [S] vectors;
+    valid_len=None writes all C. The one write of every paged program:
+    the decode wave (C == 1), a prefill chunk (S == 1; the padded tail of
+    the last chunk lies past valid_len), the speculative verify wave
+    (every lane, its own start and span).
+
+    It moves whole pages. The C positions of a lane touch at most
+    ceil((C - 1) / BS) + 1 pages wherever they start; each is gathered,
+    the rows the lane writes are replaced, and the page is scattered
+    back, so a row outside [start, start + valid_len) keeps its bits. A
+    candidate page that holds no written row (a chunk that ends on a page
+    boundary, a lane with valid_len 0, a page past the table's end) is
+    redirected to the scratch block, as is every page of a retired lane
+    (the host points its table row there). Distinct lanes write distinct
+    pages — frontier pages are private by the copy-on-write guard — and
+    a prefill chunk that runs over prefix-shared pages rewrites in them
+    what they hold.
+
+    Every write also zeroes the scratch block. The queries of a padded
+    tail (i >= valid_len) are computed and thrown away, but they attend
+    keys past the lane's last written position, which the table maps to
+    scratch where the lane has no page yet; a non-finite value there
+    would reach the lane's good rows as 0 * nan in `p @ V` (the cores
+    zero only the rows that no query attends). So scratch is finite by
+    the time a program's first layer attends, whatever an earlier fault
+    left in it, and the colliding writes to it all carry the same
+    zeros."""
     import jax.numpy as jnp
-    bs = pool.shape[2]
-    blk = jnp.take_along_axis(tables, (pos // bs)[:, None], axis=1)[:, 0]
-    return pool.at[blk, :, pos % bs, :].set(
-        kv_t[:, :, 0, :].astype(pool.dtype))
-
-
-def scatter_block_kv_chunk(pool, kv_c, table, positions, valid_len):
-    """Write a prefill chunk's K or V [1, Hkv, C, D] through one row's
-    block table [1, nblk] at absolute positions [C] (= chunk_start + i).
-    Positions at or past valid_len (the padded tail of the last chunk)
-    are redirected to the scratch block."""
-    import jax.numpy as jnp
-    nblk, bs = table.shape[1], pool.shape[2]
-    c = positions.shape[0]
-    # clamp BEFORE the table gather (a padded tail can index past the
-    # table); invalid lanes are then redirected to scratch regardless
-    blk = table[0, jnp.minimum(positions // bs, nblk - 1)]
-    blk = jnp.where(jnp.arange(c) < valid_len, blk, 0)
-    kv = jnp.transpose(kv_c[0], (1, 0, 2))     # [C, Hkv, D]
-    return pool.at[blk, :, positions % bs, :].set(kv.astype(pool.dtype))
-
-
-def scatter_block_kv_chunk_batched(pool, kv_c, tables, start, valid_len):
-    """Write a C-token chunk's K or V [S, Hkv, C, D] for EVERY lane
-    through its block table [S, nblk] at absolute positions start[s] + i
-    (start: [S] int). Per-lane positions at or past valid_len[s] ([S])
-    are redirected to the scratch block — the speculative verify wave
-    clamps its k+1-token span per slot this way (horizon, per-request
-    spec_len). The single-lane prefill variant above is the C-chunk/
-    one-slot case of this; here S lanes scatter in ONE op, which is the
-    verify program's write shape (serving/paged speculative decoding).
-    Distinct lanes write distinct blocks (frontier blocks are private by
-    the COW guard), so the only colliding writes are the scratch
-    redirects — garbage by design."""
-    import jax.numpy as jnp
-    nblk, bs = tables.shape[1], pool.shape[2]
-    s, c = kv_c.shape[0], kv_c.shape[2]
-    positions = start[:, None] + jnp.arange(c)[None, :]         # [S, C]
-    # clamp BEFORE the table gather (a clamped span can index past the
-    # table); invalid lanes/positions then redirect to scratch anyway
-    blk = jnp.take_along_axis(tables,
-                              jnp.minimum(positions // bs, nblk - 1),
-                              axis=1)                           # [S, C]
-    blk = jnp.where(jnp.arange(c)[None, :] < valid_len[:, None], blk, 0)
-    kv = jnp.transpose(kv_c, (0, 2, 1, 3))              # [S, C, Hkv, D]
-    return pool.at[blk, :, positions % bs, :].set(kv.astype(pool.dtype))
+    s, hkv, c, _ = k.shape
+    bs, nblk = pool.shape[2], tables.shape[1]
+    kv = jnp.concatenate([k, v], axis=-1).astype(pool.dtype)
+    start = jnp.broadcast_to(jnp.reshape(start, (-1,)), (s,))
+    valid = c if valid_len is None else jnp.minimum(
+        jnp.broadcast_to(jnp.reshape(valid_len, (-1,)), (s,)), c)
+    valid = jnp.reshape(valid, (-1, 1, 1))
+    npages = (c - 1 + bs - 1) // bs + 1
+    first = (start // bs)[:, None] + jnp.arange(npages)        # [S, np]
+    # row r of candidate page i holds the lane's new position number
+    # `src` (negative, or past valid_len: not this write's)
+    src = (first * bs - start[:, None])[:, :, None] + jnp.arange(bs)
+    # a padded tail or a clamped span can reach past the table: nothing
+    # is written there, and the table gather is clamped
+    mine = (src >= 0) & (src < valid) & (first < nblk)[:, :, None]
+    written = jnp.any(mine, axis=-1)                           # [S, np]
+    blk = jnp.take_along_axis(tables, jnp.minimum(first, nblk - 1), axis=1)
+    blk = jnp.where(written, blk, 0).reshape(-1)
+    new = jnp.take_along_axis(
+        kv[:, None], jnp.clip(src, 0, c - 1)[:, :, None, :, None], axis=3)
+    old = pool[blk].reshape(s, npages, hkv, bs, -1)
+    pages = jnp.where(mine[:, :, None, :, None], new, old)
+    # scratch gets zeros: from every page redirected there, from a
+    # retired lane's table row, and once more in case there is neither
+    pages = jnp.where((blk > 0).reshape(s, npages, 1, 1, 1), pages, 0)
+    pages = pages.reshape((s * npages,) + pool.shape[1:])
+    return pool.at[jnp.append(blk, 0)].set(
+        jnp.concatenate([pages, jnp.zeros_like(pages[:1])]))
 
 
 def chunk_attention(q, ck, cv, start, scale, window=None,

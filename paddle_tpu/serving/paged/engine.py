@@ -14,9 +14,10 @@ Still exactly TWO compiled programs, fully static shapes (the
 compile-once discipline — table entries are VALUES, not shapes):
 
   * decode wave — the dense wave plus one traced `[S, nblk]` block
-    table: each lane's K/V scatters through its table row and attention
-    reads the gathered per-row view (`nn/transformer.py
-    gather_block_kv` / `scatter_block_kv_at`).
+    table: each lane's K/V is written through its table row
+    (`nn/transformer.py write_block_kv`, whole pages, the pool in its
+    one stored form) and attention reads the pool through the table
+    (`nn/paged_attention.py`).
   * prefill chunk — ONE fixed-size chunk of one slot's prompt at a
     traced absolute offset. Long prompts run chunk-by-chunk BETWEEN
     decode waves (the scheduler advances one chunk per round), so
@@ -49,7 +50,8 @@ from .block_pool import BlockPool, BlockPoolExhausted
 #: block-level KV handoff payload schema version (export_slot_kv /
 #: import_handoff) — bumped when the payload layout changes so a
 #: mixed-version fleet refuses the transfer instead of mis-scattering
-HANDOFF_VERSION = 1
+#: (2: one [blocks, Hkv, BS, 2D] leaf a layer, K beside V)
+HANDOFF_VERSION = 2
 
 
 class HandoffRefused(RuntimeError):
@@ -646,10 +648,16 @@ class PagedServingEngine(ServingEngine):
         self._tables[slot, bi] = new
 
     def _copy_block(self, caches, src, dst):
+        """Copy-on-write over the whole bundle, every pool in its stored
+        form (the speculative engine's target AND draft pools: one block
+        id names the same token span in both, so a half-copied block
+        would desynchronize the draft cache from the tokens it claims
+        to hold). A model with slot state shares no block, so its
+        records never come here."""
         if self._copy_fn is None:
             def copy_fn(caches, src, dst):
-                return [(ck.at[dst].set(ck[src]), cv.at[dst].set(cv[src]))
-                        for ck, cv in caches]
+                return jax.tree_util.tree_map(
+                    lambda pool: pool.at[dst].set(pool[src]), caches)
             self._copy_fn = (telemetry.instrument_jit(
                 jax.jit(copy_fn, donate_argnums=(0,)), "paged_cow_copy")
                 if self._jit else copy_fn)
@@ -976,24 +984,6 @@ class SpeculativePagedEngine(PagedServingEngine):
     def draft_compiles(self):
         """Compiled draft-wave programs (compile-once: stays 1)."""
         return self._draft_wave._cache_size() if self._jit else 0
-
-    def _copy_block(self, caches, src, dst):
-        """COW over the BUNDLE: a shared block's content must be copied
-        in the target AND draft pools — one block id names the same
-        token span in both, so a half-copied block would desynchronize
-        the draft cache from the tokens it claims to hold."""
-        if self._copy_fn is None:
-            def copy_fn(caches, src, dst):
-                tgt, dr = caches
-
-                def cp(pools):
-                    return [(ck.at[dst].set(ck[src]),
-                             cv.at[dst].set(cv[src])) for ck, cv in pools]
-                return (cp(tgt), cp(dr))
-            self._copy_fn = (telemetry.instrument_jit(
-                jax.jit(copy_fn, donate_argnums=(0,)), "paged_cow_copy")
-                if self._jit else copy_fn)
-        return self._copy_fn(caches, jnp.int32(src), jnp.int32(dst))
 
     def _prefill_chunk_args(self, slot):
         return (self._params, self._buffers, self._caches,

@@ -15,11 +15,11 @@ REAL hot path:
     verifies the block POOL leaves stay donation-aliased at engine
     shapes;
   * `paged_decode_attention` — the block-table decode core
-    (scatter/gather through traced tables + the GQA cached core) — the
+    (page write/gather through traced tables + the GQA cached core) — the
     reference oracle the fused kernels are measured against;
   * `paged_fused_decode_attention` / `paged_fused_chunk_attention` —
     the fused paged-attention cores (nn/paged_attention.py): the same
-    scatter + attend, but reading K/V straight out of the pool through
+    page write + attend, but reading K/V straight out of the pool through
     the table with an online softmax — no gathered
     [B, Hkv, nblk*BS, D] intermediate. Audited with the dispatch's
     backend-auto kernel (lax on CPU — the implementation the banked
@@ -481,9 +481,7 @@ def _attention_specs():
     from paddle_tpu.nn.paged_attention import (paged_chunk_attention,
                                                paged_decode_attention)
     from paddle_tpu.nn.transformer import (cached_decode_attention,
-                                           gather_block_kv,
-                                           scatter_block_kv_at,
-                                           scatter_block_kv_chunk_batched)
+                                           gather_block_kv, write_block_kv)
     from paddle_tpu.ops.pallas.flash_attention import _flash_array
 
     b, h, hkv, L, d = 4, 4, 2, 64, 16
@@ -499,51 +497,46 @@ def _attention_specs():
                    jnp.zeros((b, hkv, L, d), jnp.float32),
                    jnp.zeros((b,), jnp.int32))
 
-    def paged_decode_attn(q, kv_t, pk, pv, tables, pos):
-        # the serving paged decode core: scatter the step's K/V through
-        # the tables, attend over the gathered per-row views; the
-        # updated pools ride out (donated in-place, like the engine's)
-        pk = scatter_block_kv_at(pk, kv_t, tables, pos)
-        pv = scatter_block_kv_at(pv, kv_t, tables, pos)
-        out = cached_decode_attention(
-            q, gather_block_kv(pk, tables), gather_block_kv(pv, tables),
-            pos, scale=1.0 / (d ** 0.5))
-        return out, pk, pv
+    def paged_decode_attn(q, kv_t, pool, tables, pos):
+        # the serving paged decode core: write the step's K/V through
+        # the tables (whole pages, the pool in its stored form), attend
+        # over the gathered per-row views; the updated pool rides out
+        # (donated in-place, like the engine's)
+        pool = write_block_kv(pool, kv_t, kv_t, tables, pos)
+        ck, cv = gather_block_kv(pool, tables)
+        out = cached_decode_attention(q, ck, cv, pos,
+                                      scale=1.0 / (d ** 0.5))
+        return out, pool
 
     paged_args = (jnp.zeros((b, h, 1, d), jnp.float32),
                   jnp.zeros((b, hkv, 1, d), jnp.float32),
-                  jnp.zeros((num_blocks, hkv, bs, d), jnp.float32),
-                  jnp.zeros((num_blocks, hkv, bs, d), jnp.float32),
+                  jnp.zeros((num_blocks, hkv, bs, 2 * d), jnp.float32),
                   jnp.zeros((b, nblk), jnp.int32),
                   jnp.zeros((b,), jnp.int32))
 
-    def fused_decode_attn(q, kv_t, pk, pv, tables, pos):
-        # the fused sibling of paged_decode_attn: same scatter, but the
+    def fused_decode_attn(q, kv_t, pool, tables, pos):
+        # the fused sibling of paged_decode_attn: same write, but the
         # attend reads the pool through the table (online softmax) —
         # the [B, Hkv, nblk*BS, D] gathered view never materialises.
         # kernel=None: the dispatch's backend auto-selection, i.e. the
         # implementation the serving engines actually compile here
-        pk = scatter_block_kv_at(pk, kv_t, tables, pos)
-        pv = scatter_block_kv_at(pv, kv_t, tables, pos)
-        out = paged_decode_attention(q, pk, pv, tables, pos,
+        pool = write_block_kv(pool, kv_t, kv_t, tables, pos)
+        out = paged_decode_attention(q, pool, tables, pos,
                                      scale=1.0 / (d ** 0.5))
-        return out, pk, pv
+        return out, pool
 
-    def fused_chunk_attn(q, kv_c, pk, pv, tables, start, valid_len):
+    def fused_chunk_attn(q, kv_c, pool, tables, start, valid_len):
         # the chunked form (spec verify / prefill chunk): C queries per
-        # lane at per-lane offsets, batched scatter + fused attend
-        pk = scatter_block_kv_chunk_batched(pk, kv_c, tables, start,
-                                            valid_len)
-        pv = scatter_block_kv_chunk_batched(pv, kv_c, tables, start,
-                                            valid_len)
-        out = paged_chunk_attention(q, pk, pv, tables, start,
+        # lane at per-lane offsets, the same write + fused attend
+        pool = write_block_kv(pool, kv_c, kv_c, tables, start, valid_len)
+        out = paged_chunk_attention(q, pool, tables, start,
                                     scale=1.0 / (d ** 0.5))
-        return out, pk, pv
+        return out, pool
 
     fused_chunk_args = (jnp.zeros((b, h, C, d), jnp.float32),
                         jnp.zeros((b, hkv, C, d), jnp.float32),
-                        jnp.zeros((num_blocks, hkv, bs, d), jnp.float32),
-                        jnp.zeros((num_blocks, hkv, bs, d), jnp.float32),
+                        jnp.zeros((num_blocks, hkv, bs, 2 * d),
+                                  jnp.float32),
                         jnp.zeros((b, nblk), jnp.int32),
                         jnp.zeros((b,), jnp.int32),
                         jnp.full((b,), C, jnp.int32))
@@ -561,24 +554,24 @@ def _attention_specs():
                         "position vector"},
         {"name": "paged_decode_attention", "fn": paged_decode_attn,
          "args": paged_args,
-         "jit_kwargs": {"donate_argnums": (2, 3)},
-         "description": "block-table decode attention core: KV "
-                        "scatter/gather through traced tables + the "
+         "jit_kwargs": {"donate_argnums": (2,)},
+         "description": "block-table decode attention core: KV page "
+                        "write/gather through traced tables + the "
                         "GQA cached core (the fused kernels' reference "
                         "oracle)"},
         {"name": "paged_fused_decode_attention", "fn": fused_decode_attn,
          "args": paged_args,
-         "jit_kwargs": {"donate_argnums": (2, 3)},
+         "jit_kwargs": {"donate_argnums": (2,)},
          "description": "fused paged decode core: block-table gather + "
                         "GQA online-softmax attend in one pass, no "
                         "gathered KV intermediate (nn/paged_attention, "
                         "backend-auto kernel)"},
         {"name": "paged_fused_chunk_attention", "fn": fused_chunk_attn,
          "args": fused_chunk_args,
-         "jit_kwargs": {"donate_argnums": (2, 3)},
+         "jit_kwargs": {"donate_argnums": (2,)},
          "description": "fused paged chunk core (spec-verify width "
                         "k+1): per-lane-offset queries, batched KV "
-                        "scatter + fused block-table attend"},
+                        "page write + fused block-table attend"},
         {"name": "prefill_flash_attention", "fn": prefill_attn,
          "args": prefill_args,
          "description": "causal prompt-phase attention array kernel"},

@@ -417,7 +417,7 @@ def test_paged_decode_wave_pool_donation_actually_aliased():
     ctx = ProgramContext(spec)
     assert ctx.donate_argnums == (2,)          # the block pools
     first, n = ctx.leaf_index_ranges()[2]
-    assert n == 4                              # 2 layers x (k, v) pools
+    assert n == 2                              # 2 layers, one K/V pool each
     aliased = ctx.aliased_param_indices
     assert aliased is not None, ctx.unavailable
     missing = [i for i in range(first, first + n) if i not in aliased]
@@ -431,7 +431,7 @@ def test_paged_prefill_chunk_pool_donation_actually_aliased():
     ctx = ProgramContext(spec)
     assert ctx.donate_argnums == (2,)
     first, n = ctx.leaf_index_ranges()[2]
-    assert n == 4
+    assert n == 2
     aliased = ctx.aliased_param_indices
     assert aliased is not None, ctx.unavailable
     missing = [i for i in range(first, first + n) if i not in aliased]
@@ -454,8 +454,8 @@ def test_spec_programs_target_and_draft_pools_actually_aliased():
         ctx = ProgramContext(spec)
         assert ctx.donate_argnums == (2,), spec["name"]
         first, n = ctx.leaf_index_ranges()[2]
-        # 2 target layers x (k, v) + 1 draft layer x (k, v) pools
-        assert n == 6, spec["name"]
+        # 2 target layers + 1 draft layer, one K/V pool each
+        assert n == 3, spec["name"]
         aliased = ctx.aliased_param_indices
         assert aliased is not None, (spec["name"], ctx.unavailable)
         missing = [i for i in range(first, first + n)

@@ -41,6 +41,12 @@ MAX_NEW = 8
 # op-level parity: kernel x form x window on random pools
 # ---------------------------------------------------------------------------
 
+def _fuse(pk, pv):
+    """K and V pools [NB, Hkv, BS, D] -> the stored form [NB, Hkv, BS, 2D]."""
+    import jax.numpy as jnp
+    return jnp.concatenate([jnp.asarray(pk), jnp.asarray(pv)], axis=-1)
+
+
 def _pools(seed, nb=11, hkv=2, bs=4, d=8, poison_scratch=False):
     import jax.numpy as jnp
     rng = np.random.default_rng(seed)
@@ -69,9 +75,9 @@ def test_decode_parity_vs_reference(kernel, window):
     import jax.numpy as jnp
     q, pk, pv, tables = _case(0, c=1)
     pos = jnp.asarray([3, 9, 17], jnp.int32)
-    ref = pa.paged_decode_attention(q, pk, pv, tables, pos, 0.35,
+    ref = pa.paged_decode_attention(q, _fuse(pk, pv), tables, pos, 0.35,
                                     window=window, kernel="reference")
-    out = pa.paged_decode_attention(q, pk, pv, tables, pos, 0.35,
+    out = pa.paged_decode_attention(q, _fuse(pk, pv), tables, pos, 0.35,
                                     window=window, kernel=kernel)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=1e-5, atol=1e-5)
@@ -83,9 +89,9 @@ def test_chunk_parity_vs_reference(kernel, window):
     import jax.numpy as jnp
     q, pk, pv, tables = _case(1)
     start = jnp.asarray([0, 5, 12], jnp.int32)
-    ref = pa.paged_chunk_attention(q, pk, pv, tables, start, 0.35,
+    ref = pa.paged_chunk_attention(q, _fuse(pk, pv), tables, start, 0.35,
                                    window=window, kernel="reference")
-    out = pa.paged_chunk_attention(q, pk, pv, tables, start, 0.35,
+    out = pa.paged_chunk_attention(q, _fuse(pk, pv), tables, start, 0.35,
                                    window=window, kernel=kernel)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=1e-5, atol=1e-5)
@@ -97,10 +103,10 @@ def test_scalar_position_matches_vector(kernel):
     broadcast of the per-lane vector form."""
     import jax.numpy as jnp
     q, pk, pv, tables = _case(2)
-    vec = pa.paged_chunk_attention(q, pk, pv, tables,
+    vec = pa.paged_chunk_attention(q, _fuse(pk, pv), tables,
                                    jnp.asarray([7, 7, 7], jnp.int32),
                                    0.3, kernel=kernel)
-    sca = pa.paged_chunk_attention(q, pk, pv, tables, jnp.int32(7),
+    sca = pa.paged_chunk_attention(q, _fuse(pk, pv), tables, jnp.int32(7),
                                    0.3, kernel=kernel)
     np.testing.assert_array_equal(np.asarray(vec), np.asarray(sca))
 
@@ -118,11 +124,11 @@ def test_poisoned_scratch_block_cannot_leak(kernel):
     q, pk, pv, tables = _case(3, c=1, poison_scratch=True)
     pos = jnp.asarray([3, 9, 17], jnp.int32)
     for window in (None, 6):
-        out = pa.paged_decode_attention(q, pk, pv, tables, pos, 0.35,
+        out = pa.paged_decode_attention(q, _fuse(pk, pv), tables, pos, 0.35,
                                         window=window, kernel=kernel)
         assert np.isfinite(np.asarray(out)).all(), (kernel, window)
     qc, pkc, pvc, tc = _case(4, poison_scratch=True)
-    out = pa.paged_chunk_attention(qc, pkc, pvc, tc,
+    out = pa.paged_chunk_attention(qc, _fuse(pkc, pvc), tc,
                                    jnp.asarray([0, 5, 12], jnp.int32),
                                    0.35, kernel=kernel)
     assert np.isfinite(np.asarray(out)).all()
@@ -137,7 +143,7 @@ def test_attended_nonfinite_still_propagates(kernel):
     q, pk, pv, tables = _case(5, c=1, poison_scratch=True)
     tables = tables.at[1, 0].set(0)            # attended scratch read
     pos = jnp.asarray([3, 9, 17], jnp.int32)
-    out = np.asarray(pa.paged_decode_attention(q, pk, pv, tables, pos,
+    out = np.asarray(pa.paged_decode_attention(q, _fuse(pk, pv), tables, pos,
                                                0.35, kernel=kernel))
     assert not np.isfinite(out[1]).all()
     assert np.isfinite(out[0]).all() and np.isfinite(out[2]).all()
@@ -151,7 +157,7 @@ def test_fully_masked_rows_are_exactly_zero(kernel):
     import jax.numpy as jnp
     q, pk, pv, tables = _case(6, c=1, poison_scratch=True)
     neg = jnp.asarray([-1, -1, -1], jnp.int32)
-    out = np.asarray(pa.paged_decode_attention(q, pk, pv, tables, neg,
+    out = np.asarray(pa.paged_decode_attention(q, _fuse(pk, pv), tables, neg,
                                                0.35, kernel=kernel))
     assert (out == 0).all()
 
@@ -196,7 +202,7 @@ def _attend(form):
 def test_ragged_lanes_parity_vs_reference(kernel, form, rep, window):
     import jax.numpy as jnp
     q, pk, pv, tables, start = _ragged(7, 1 if form == "decode" else 5, rep)
-    args = (q, jnp.asarray(pk), jnp.asarray(pv), jnp.asarray(tables),
+    args = (q, _fuse(pk, pv), jnp.asarray(tables),
             jnp.asarray(start), 0.35)
     ref = _attend(form)(*args, window=window, kernel="reference")
     out = _attend(form)(*args, window=window, kernel=kernel)
@@ -218,7 +224,7 @@ def test_poison_outside_the_attended_keys_changes_nothing(kernel, form,
     tables[-1] = tables.max() + 1 + np.arange(RAGGED_NBLK)   # own blocks
     pk = np.concatenate([pk, pk[:RAGGED_NBLK]])
     pv = np.concatenate([pv, pv[:RAGGED_NBLK]])
-    clean = _attend(form)(q, jnp.asarray(pk), jnp.asarray(pv),
+    clean = _attend(form)(q, _fuse(pk, pv),
                           jnp.asarray(tables), jnp.asarray(start), 0.35,
                           window=window, kernel=kernel)
     pk[0] = pv[0] = np.nan
@@ -229,7 +235,7 @@ def test_poison_outside_the_attended_keys_changes_nothing(kernel, form,
         for j in range(RAGGED_NBLK):
             pk[tables[lane, j]][:, dead[j]] = np.nan
             pv[tables[lane, j]][:, dead[j]] = np.nan
-    out = _attend(form)(q, jnp.asarray(pk), jnp.asarray(pv),
+    out = _attend(form)(q, _fuse(pk, pv),
                         jnp.asarray(tables), jnp.asarray(start), 0.35,
                         window=window, kernel=kernel)
     assert np.isfinite(np.asarray(out)).all()
@@ -270,7 +276,7 @@ def test_every_tile_of_the_pallas_core_gives_the_reference(monkeypatch, form,
     import jax.numpy as jnp
     monkeypatch.setattr(pa, "_tile", lambda c, rep, hkv: (heads, pages))
     q, pk, pv, tables, start = _ragged(9, 1 if form == "decode" else 5, 2)
-    args = (q, jnp.asarray(pk), jnp.asarray(pv), jnp.asarray(tables),
+    args = (q, _fuse(pk, pv), jnp.asarray(tables),
             jnp.asarray(start), 0.35)
     for window in (None, 6):
         ref = _attend(form)(*args, window=window, kernel="reference")
@@ -468,8 +474,7 @@ def test_engine_scratch_poison_regression(model):
     for kernel in KERNELS:
         eng = _engine(model, kernel)
         Scheduler(eng).generate([1, 2, 3], max_tokens=2)   # warm/compile
-        eng._caches = [(k.at[0].set(jnp.nan), v.at[0].set(jnp.nan))
-                       for k, v in eng._caches]
+        eng._caches = [pool.at[0].set(jnp.nan) for pool in eng._caches]
         sched = Scheduler(eng)
         reqs = [sched.submit(prompt=p, max_tokens=m) for p, m in jobs]
         sched.run()

@@ -292,9 +292,9 @@ def test_decode_wave_never_writes_through_midprefill_tables(model):
     engine.begin_prefill(1, _prompt(71, n=2 * CHUNK + 3))   # 3 chunks
     assert engine.prefill_step(1) is None          # chunk 0 written
     blk0 = engine._slot_blocks[1][0]
-    before = np.asarray(engine._caches[0][0])[blk0].copy()
+    before = np.asarray(engine._caches[0])[blk0].copy()
     engine.decode_wave()                           # slot 0 decodes
-    after = np.asarray(engine._caches[0][0])[blk0]
+    after = np.asarray(engine._caches[0])[blk0]
     np.testing.assert_array_equal(before, after)
 
 

@@ -78,13 +78,13 @@ def _kernel_names(fn, *shapes):
 
 
 def _paged_args(sharding, b, h, hkv, c, d, bs=16, nblk=64):
-    """(q, k pool, v pool, tables, positions) of a paged engine at block
-    16, 64 blocks per lane (max_len 1024), bf16 pools."""
+    """(q, pool, tables, positions) of a paged engine at block 16, 64
+    blocks per lane (max_len 1024), a bf16 pool in its stored form."""
     def sds(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
-    pool = sds((b * nblk + 1, hkv, bs, d), jnp.bfloat16)
-    return (sds((b, h, c, d), jnp.bfloat16), pool, pool,
+    return (sds((b, h, c, d), jnp.bfloat16),
+            sds((b * nblk + 1, hkv, bs, 2 * d), jnp.bfloat16),
             sds((b, nblk), jnp.int32), sds((b,), jnp.int32))
 
 
@@ -147,8 +147,8 @@ def test_paged_core_server_resolves_on_tpu_compiles_for_v5e(
     attend = pa.paged_decode_attention if c == 1 else \
         pa.paged_chunk_attention
 
-    def fn(q, pk, pv, tables, pos):
-        return attend(q, pk, pv, tables, pos, d ** -0.5, window=window,
+    def fn(q, pool, tables, pos):
+        return attend(q, pool, tables, pos, d ** -0.5, window=window,
                       kernel=kernel)
 
     assert _kernels_in(fn, *_paged_args(one_chip, b, h, hkv, c, d)) == 1
@@ -177,12 +177,127 @@ def test_paged_core_compiles_at_the_cells_shapes_for_v5e(
     attend = pa.paged_decode_attention if c == 1 else \
         pa.paged_chunk_attention
 
-    def fn(q, pk, pv, tables, pos):
-        return attend(q, pk, pv, tables, pos, d ** -0.5, window=window,
+    def fn(q, pool, tables, pos):
+        return attend(q, pool, tables, pos, d ** -0.5, window=window,
                       kernel="pallas")
 
     assert _kernel_names(fn, *_paged_args(one_chip, b, h, hkv, c, d,
                                           nblk=nblk)) == ["paged_attention"]
+
+
+_HLO_TYPES = {"bfloat16": "bf16", "float32": "f32"}
+
+
+def _pool_stays(compiled, pools):
+    """What has to hold of a compiled serving program for the pool to
+    pass through it in place (PERF.md, PR 28): no instruction whose
+    result is pool-sized is a `copy` or a `transpose`, every pool array
+    is aliased from parameter to result, and nothing is padded (the
+    arguments are the pools' logical bytes, within 2%; the temporaries
+    hold no second pool)."""
+    txt = compiled.as_text()
+    logical = sum(int(np.prod(p.shape)) * p.dtype.itemsize for p in pools)
+    for shape, dtype in {(p.shape, str(p.dtype)) for p in pools}:
+        dims = ",".join(map(str, shape))
+        moved = [line.strip()[:120] for line in txt.splitlines()
+                 if re.search(r"= %s\[%s\]\S* (copy|transpose)\("
+                              % (_HLO_TYPES[dtype], dims), line)]
+        assert not moved, moved
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= logical, (mem.alias_size_in_bytes,
+                                                logical)
+    least = min(int(np.prod(p.shape)) * p.dtype.itemsize for p in pools)
+    assert mem.temp_size_in_bytes < least / 2, mem.temp_size_in_bytes
+    return mem.argument_size_in_bytes / logical
+
+
+@pytest.mark.parametrize("name,blocks,lanes,h,hkv,d,nblk,c,window", [
+    # (blocks, lanes) as the cells reserve them: slots x max_len / 16 + 1
+    ("gpt2s-batch-wave", 8193, 128, 12, 12, 64, 64, 1, None),
+    ("gpt2s-batch-chunk128", 8193, 128, 12, 12, 64, 64, 128, None),
+    ("mistral-chat-wave", 10241, 64, 32, 8, 128, 160, 1, 4096),
+    ("mistral-chat-chunk128", 10241, 64, 32, 8, 128, 160, 128, 4096),
+    ("nemotron-reason-wave", 16385, 128, 32, 2, 128, 128, 1, None),
+    ("nemotron-reason-chunk128", 16385, 128, 32, 2, 128, 128, 128, None),
+])
+def test_pool_passes_through_write_and_attention_in_place_for_v5e(
+        one_chip, as_on_tpu, name, blocks, lanes, h, hkv, d, nblk, c,
+        window):
+    """One layer of a serving program at a cell's real pool: the K/V
+    write, then the paged kernel, the pool donated. The pool is neither
+    copied nor padded on the way (head_dim 64 as 128), and the kernel is
+    the one named `paged_attention`."""
+    from paddle_tpu.nn.transformer import write_block_kv
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    b = lanes if c == 1 else 1
+    attend = pa.paged_decode_attention if c == 1 else \
+        pa.paged_chunk_attention
+
+    def layer(pool, q, k, v, tables, start, valid_len):
+        pool = write_block_kv(pool, k, v, tables, start, valid_len)
+        return pool, attend(q, pool, tables, start, d ** -0.5,
+                            window=window, kernel="pallas")
+
+    pool = sds((blocks, hkv, 16, 2 * d), jnp.bfloat16)
+    kv = sds((b, hkv, c, d), jnp.bfloat16)
+    args = (pool, sds((b, h, c, d), jnp.bfloat16), kv, kv,
+            sds((b, nblk), jnp.int32), sds((b,), jnp.int32),
+            sds((b,), jnp.int32))
+    assert _kernel_names(layer, *args) == ["paged_attention"]
+    compiled = jax.jit(layer, donate_argnums=(0,)).lower(*args).compile()
+    assert _pool_stays(compiled, [pool]) < 1.02
+
+
+@pytest.mark.parametrize("program", ["decode_wave", "prefill_chunk"])
+@pytest.mark.parametrize("family", ["gpt-mha-d64", "llama-gqa-d128"])
+def test_engine_programs_keep_the_pool_in_place_for_v5e(one_chip, as_on_tpu,
+                                                        family, program):
+    """The same, of the programs as the engine builds them (its own
+    closures, its own arguments, the caches donated): a small GPT with
+    heads of 64 and a small GQA Llama with heads of 128."""
+    import paddle_tpu as pt
+    from paddle_tpu.nlp import (GPTConfig, GPTForPretraining, LlamaConfig,
+                                LlamaForCausalLM)
+    from paddle_tpu.serving import PagedServingEngine
+
+    pt.seed(0)
+    if family == "gpt-mha-d64":
+        model = GPTForPretraining(GPTConfig(
+            vocab_size=512, hidden_size=128, num_layers=2, num_heads=2,
+            max_seq_len=512, dropout=0.0, attn_dropout=0.0))
+    else:
+        model = LlamaForCausalLM(LlamaConfig(
+            vocab_size=512, hidden_size=512, num_layers=2, num_heads=4,
+            num_kv_heads=2, max_seq_len=512))
+    slots, chunk = 16, 128
+    eng = PagedServingEngine(model, num_slots=slots, max_len=512,
+                             block_size=16, prefill_chunk_len=chunk,
+                             cache_dtype=jnp.bfloat16,
+                             paged_kernel="pallas")
+    key = jax.random.PRNGKey(0)
+    if program == "decode_wave":
+        fn = eng._decode_wave_fn
+        args = eng._wave_args([True] * slots, np.zeros(slots, bool), key)
+    else:
+        fn = eng._prefill_fn
+        args = (*eng._prefill_chunk_args(0), jnp.asarray(eng._tables[0]),
+                jnp.zeros(chunk, jnp.int32), np.int32(0), np.int32(chunk),
+                np.int32(0), jnp.asarray(False), np.float32(1),
+                np.int32(0), np.float32(1), jnp.zeros(512, jnp.float32),
+                key)
+    pools = jax.tree_util.tree_leaves(eng._caches)
+    assert [p.shape[1:] for p in pools] in (
+        [(2, 16, 128)] * 2, [(2, 16, 256)] * 2)
+    shapes = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(jnp.shape(a), jnp.result_type(a),
+                                       sharding=one_chip), args)
+    compiled = jax.jit(fn, donate_argnums=eng._program_donate_argnums
+                       ).lower(*shapes).compile()
+    _pool_stays(compiled, pools)
+    assert compiled.as_text().count("tpu_custom_call") == 2   # a layer
 
 
 @pytest.mark.parametrize("name,rows", [("wave-128-lanes", 768),
@@ -208,8 +323,8 @@ def test_grouped_expert_kernel_compiles_for_v5e(one_chip, as_on_tpu, name,
 def test_lax_paged_core_compiles_for_v5e(one_chip):
     """The portable core is what `paged_kernel="lax"` serves from on a
     chip; it has no kernel of its own."""
-    def fn(q, pk, pv, tables, pos):
-        return pa.paged_decode_attention(q, pk, pv, tables, pos, 64 ** -0.5,
+    def fn(q, pool, tables, pos):
+        return pa.paged_decode_attention(q, pool, tables, pos, 64 ** -0.5,
                                          kernel="lax")
 
     assert _kernels_in(fn, *_paged_args(one_chip, 8, 12, 12, 1, 64)) == 0
@@ -230,8 +345,8 @@ def test_kernels_carry_their_names_for_v5e(one_chip, as_on_tpu):
     assert _kernel_names(jax.grad(loss, argnums=(0, 1, 2)), x, x, x) == [
         "flash_bwd_dkv", "flash_bwd_dq", "flash_fwd"]
 
-    def wave(q, pk, pv, tables, pos):
-        return pa.paged_decode_attention(q, pk, pv, tables, pos, 64 ** -0.5,
+    def wave(q, pool, tables, pos):
+        return pa.paged_decode_attention(q, pool, tables, pos, 64 ** -0.5,
                                          kernel="pallas")
 
     assert _kernel_names(wave, *_paged_args(one_chip, 8, 12, 12, 1, 64)) \
