@@ -75,6 +75,21 @@ def paged_decode_cost(sh, attended_tokens, itemsize=2):
             sh["layers"] * 2.0 * kv_row * itemsize * attended_tokens)
 
 
+def decode_wave_cost(sh, lanes, attended_tokens, itemsize=2):
+    """(operations, bytes) of one decode wave of a dense decoder over
+    `lanes` decoding lanes that together attend `attended_tokens` cached
+    positions: every matmul weight and the head are read once whatever
+    the lanes and cost 2 operations a lane; the K and V rows the lanes
+    attend are read once (`paged_decode_cost`). Norms, biases, embedding
+    rows, activations and the new K/V rows written are left out (under
+    1% at 64 lanes). The whole step's count: a kernel taken off the path
+    leaves it as it is."""
+    attn_ops, attn_bytes = paged_decode_cost(sh, attended_tokens, itemsize)
+    weights = matmul_params(sh)
+    return (2.0 * lanes * weights + attn_ops,
+            itemsize * weights + attn_bytes)
+
+
 def roofline_seconds(ops, nbytes, peaks):
     """The least time the chip could take, and which of the two bounds
     it: ("compute" | "memory")."""

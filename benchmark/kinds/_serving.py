@@ -101,15 +101,36 @@ def measure(ctx, turn):
 
 
 def snapshot(pred):
-    """The program's own counters, as the scheduler sums them."""
+    """The program's own counters, as the scheduler sums them: every
+    number of its snapshot and the two groups the readers and the verdict
+    take (`phase_seconds`, `faults`). A counter a later program adds
+    reaches its reader with no edit here."""
     snap = pred.metrics.snapshot()
-    return {k: snap[k] for k in ("phase_seconds", "prefix_hits",
-                                 "prefix_misses", "faults", "rejected",
-                                 "tokens_generated", "requests_completed",
-                                 "wave_retries")}
+    out = {k: v for k, v in snap.items()
+           if isinstance(v, (int, float)) and not isinstance(v, bool)}
+    out.update(phase_seconds=dict(snap["phase_seconds"]),
+               faults=dict(snap["faults"]))
+    return out
 
 
-def referee(ctx, weights, records):
+def sampled(ctx, records):
+    """The requests the referee reads: of those that were served
+    `check.min_tokens` tokens or more, the longest (prompt and served
+    tokens) and `check.requests` - 1 others drawn from the seed."""
+    check = ctx.cell["check"]
+    cand = [r for r in records if r.request is not None
+            and len(r.request.output_tokens) >= int(check["min_tokens"])]
+    if not cand:
+        return []
+    longest = max(range(len(cand)), key=lambda i: (
+        len(cand[i].planned.prompt) + len(cand[i].request.output_tokens), -i))
+    rng = np.random.default_rng([ctx.seed, 0xC4EC])
+    order = [longest] + [i for i in rng.permutation(len(cand))
+                         if i != longest]
+    return [cand[i] for i in order[:int(check["requests"])]]
+
+
+def referee(ctx, weights, records, control=None):
     """Served tokens against the float32 reference.
 
     With weights from a seed the largest logit wins by little, and
@@ -122,6 +143,12 @@ def referee(ctx, weights, records):
     float32 one (the cell's file gives the steps measured on the chip), an
     8-bit one tens of steps away.
 
+    With `control` (keywords of the reference's control forward, the
+    cell's `check.control`) the tokens judged are not the served ones but
+    those that forward puts first at the same positions of the same
+    prompts and served histories: the reference in a lower precision in
+    the program's place. The limit has to call it wrong.
+
     Returns (worst gap, tol, share of sampled tokens equal to the
     reference's argmax, tokens sampled).
     """
@@ -129,15 +156,10 @@ def referee(ctx, weights, records):
     ref = harness.reference_for(ctx.config)
     sh = harness.shapes(ctx.config)
     rw = ref.from_state_dict(weights, sh["layers"])
-    cand = [r for r in records if r.request is not None
-            and len(r.request.output_tokens) >= int(check["min_tokens"])]
-    rng = np.random.default_rng([ctx.seed, 0xC4EC])
-    picks = [cand[i] for i in rng.permutation(len(cand))
-             [:int(check["requests"])]]
     pad = int(check["pad_to"])
     worst = tol = 0.0
     same = total = 0
-    for r in picks:
+    for r in sampled(ctx, records):
         out = list(r.request.output_tokens)[:int(check["max_tokens"])]
         prompt = r.planned.prompt
         n = len(prompt)
@@ -151,6 +173,9 @@ def referee(ctx, weights, records):
                                     rows=rows)[0])[:len(out)]
         if not np.isfinite(lo).all():
             return float("inf"), 0.0, 0.0, 0
+        if control:
+            out = np.asarray(ref.forward(rw, ctx_ids, ctx.config, rows=rows,
+                                         **control)[0])[:len(out)].argmax(1)
         gaps = lo.max(axis=1) - lo[np.arange(len(out)), out]
         worst = max(worst, float(gaps.max()))
         tol = max(tol, float(check["logit_tol_bf16_steps"]) * 2.0 ** -8
@@ -168,6 +193,14 @@ def finish(ctx, pred, weights, records, rounds, window, snap0, snap1,
     pred.close(drain=False)
     with ctx.phase("check"):
         worst, tol, same, n = referee(ctx, weights, records)
+        # a traced run also notes what the limit makes of the reference in
+        # a lower precision put in the program's place: not correct
+        if ctx.trace and ctx.cell["check"].get("control"):
+            c_worst, c_tol, c_same, c_n = referee(
+                ctx, weights, records, ctx.cell["check"]["control"])
+            ctx.note("control", forward=ctx.cell["check"]["control"],
+                     worst_logit_gap=c_worst, tol=c_tol, argmax_match=c_same,
+                     tokens_checked=c_n, correct=bool(c_worst <= c_tol))
     faults = {k: snap1["faults"].get(k, 0) - snap0["faults"].get(k, 0)
               for k in snap1["faults"]}
     faults = {k: v for k, v in faults.items() if v}
@@ -186,5 +219,9 @@ def finish(ctx, pred, weights, records, rounds, window, snap0, snap1,
         "attempted": attempted, "failed": failed,
         "correct": (n > 0 and worst <= tol and not faults
                     and rejected == 0),
+        "checks": {"worst_logit_gap": [worst, tol],
+                   "tokens_checked_min": [n, 1],
+                   "faults": [sum(faults.values()), 0],
+                   "rejected": [rejected, 0]},
         "memory_peak_bytes": mem, "obs": obs,
     }

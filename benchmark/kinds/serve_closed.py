@@ -48,6 +48,8 @@ def run(ctx):
     snap0 = _serving.snapshot(pred)
     t0, t1 = _serving.measure(ctx, turn)
     snap1 = _serving.snapshot(pred)
+    ctx.note("schedule", requests=int(traffic["requests"]),
+             sent=len(loop.records), pending=len(loop.pending))
     if not loop.pending:
         raise harness.BenchmarkError(
             f"the schedule of {traffic['requests']} requests ran out "

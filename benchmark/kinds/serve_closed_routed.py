@@ -35,17 +35,6 @@ CONTROLS = {"8bit": {"lower": "float8_e4m3fn"},
             "bf16_state": {"lower": "bfloat16", "state": "bfloat16"}}
 
 
-def sampled(ctx, records):
-    """The requests `_serving.referee` samples, by its rule and its
-    generator: `check.requests` of those that served `min_tokens`."""
-    check = ctx.cell["check"]
-    cand = [r for r in records if r.request is not None
-            and len(r.request.output_tokens) >= int(check["min_tokens"])]
-    rng = np.random.default_rng([ctx.seed, 0xC4EC])
-    return [cand[i] for i in rng.permutation(len(cand))
-            [:int(check["requests"])]]
-
-
 def measure(ctx, weights, picks, control=None):
     """Gaps of the sampled tokens under the float32 reference, each given
     the tokens served before it: {"gaps": the reference's best logit less
@@ -105,9 +94,9 @@ def run(ctx):
     referee was given. One process runs one cell."""
     plain, kept = _serving.referee, {}
 
-    def referee(ctx, weights, records):
+    def referee(ctx, weights, records, control=None):
         kept.update(weights=weights, records=records)
-        return plain(ctx, weights, records)
+        return plain(ctx, weights, records, control)
 
     _serving.referee = referee
     try:
@@ -115,7 +104,7 @@ def run(ctx):
     finally:
         _serving.referee = plain
     with ctx.phase("check"):
-        picks = sampled(ctx, kept["records"])
+        picks = _serving.sampled(ctx, kept["records"])
         ok, read = verdict(ctx.cell["check"],
                            measure(ctx, kept["weights"], picks))
         ctx.note("referee", correct=ok, **read)
@@ -124,4 +113,10 @@ def run(ctx):
                 ctx, kept["weights"], picks, control))
             ctx.note("control", forward=name, correct=c_ok, **c_read)
     result["correct"] = bool(result["correct"] and ok)
+    if "mean_gap_steps" in read:
+        result.setdefault("checks", {}).update(
+            mean_gap_steps=[read["mean_gap_steps"],
+                            read["mean_gap_tol_steps"]],
+            worst_gap_steps=[read["worst_gap_steps"],
+                             read["worst_gap_tol_steps"]])
     return result

@@ -46,6 +46,20 @@ def run(ctx):
     due = [r for r in records if t0 <= r.due_t <= t1]
     waits, missing = loadgen.ttft_sample(records, t0, t1, tail)
     failed = sum(1 for r in due if r.failed) + missing
+    # in every run, traced or not: a starved producer must not read as a
+    # fast server (the per-layer `gen_late_p99_ms` is a traced run's)
+    late = [1e3 * (r.submit_t - r.due_t) for r in due]
+    ctx.note("generator", due=len(due), timed=len(waits),
+             late_ms={p: loadgen.percentile(late, p) for p in (50, 99, 100)},
+             awaiting_first_token={
+                 "mid": loadgen.awaiting_first_token(records, (t0 + t1) / 2),
+                 "end": loadgen.awaiting_first_token(records, t1)})
+    gaps = loadgen.token_gaps(records, t0, t1)
+    ctx.note("tails", gaps=len(gaps),
+             tpot_ms={p: 1e3 * loadgen.percentile(gaps, p)
+                      for p in (50, 90, 95, 99, 99.9)} if gaps else None,
+             ttft_ms={p: 1e3 * loadgen.percentile(waits, p)
+                      for p in (50, 90, 99)} if waits else None)
     return _serving.finish(
         ctx, pred, weights, records, rounds, (t0, t1), snap0, snap1,
         attempted=len(due), failed=failed,

@@ -211,6 +211,13 @@ def run(ctx):
         "attempted": len(losses), "failed": len(bad),
         "correct": (not bad and step1_ok and worst <= tol
                     and bool(losses)),
+        "checks": {"step1_loss_vs_reference": [abs(first - ref_loss),
+                                               loss_tol],
+                   "step1_loss_vs_ln_vocab": [
+                       abs(first - math.log(sh["vocab"])), band],
+                   "worst_logit_diff": [worst, tol],
+                   "nonfinite_losses": [len(bad), 0],
+                   "steps_min": [len(losses), 1]},
         "memory_peak_bytes": mem,
         "obs": {"kind": "train", "window": (t0, t1), "steps": len(losses),
                 "step_done_t": done_t, "tokens_per_step": tokens_per_step,

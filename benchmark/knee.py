@@ -60,6 +60,8 @@ def _one_rate(ctx, model, rate, seconds):
     rs = [r[1] - r[0] for r in rounds.log
           if r[0] >= t0 and r[1] <= t1 and (r[2] or r[4])]
     snap = pred.metrics.snapshot()
+    late = [1e3 * (r.submit_t - r.due_t) for r in records
+            if r.due_t is not None and t0 <= r.due_t <= t1]
     ctx.note(
         "rate", rate=rate, seconds=round(t1 - t0, 1),
         due=sum(1 for r in records if r.due_t and t0 <= r.due_t <= t1),
@@ -70,6 +72,7 @@ def _one_rate(ctx, model, rate, seconds):
         ttft_ms={p: round(1e3 * loadgen.percentile(waits, p), 1)
                  for p in (50, 90)} if waits else None,
         ttft_n=len(waits), ttft_missing=missing,
+        gen_late_ms_p99=loadgen.percentile(late, 99),
         tpot_ms={p: round(1e3 * loadgen.percentile(gaps, p), 1)
                  for p in (50, 99)} if gaps else None,
         life_s_p50=round(loadgen.percentile(life, 50), 1) if life else None,

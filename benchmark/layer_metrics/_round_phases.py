@@ -34,14 +34,20 @@ def ms_per_round(ctx, *phases, waves_only=False):
     return None if s is None or not n else 1e3 * s / n
 
 
-def profiler_gaps(ctx):
-    """Seconds of the window that the benchmark itself took between two
-    rounds to start the profiler, and to stop it and reduce the trace:
-    the gaps between consecutive rounds that hold an end of the traced
-    window. Requests are in flight then, so the program rightly counts
-    them as time it left the device unfed; they are the benchmark's
-    doing (1.5-1.7 s of a traced run on the chip), not the program's."""
+def profiler_gap_intervals(ctx):
+    """[(start, end)] of the gaps between consecutive rounds of the window
+    that hold an end of the traced window: there the benchmark itself
+    starts the profiler, and stops it and reduces the trace."""
     ends = ctx.get("trace_host") or ()
     rounds = readers.rounds_in(ctx, *readers.window(ctx))
-    return sum(b[0] - a[1] for a, b in zip(rounds, rounds[1:])
-               if any(a[1] <= t <= b[0] for t in ends))
+    return [(a[1], b[0]) for a, b in zip(rounds, rounds[1:])
+            if any(a[1] <= t <= b[0] for t in ends)]
+
+
+def profiler_gaps(ctx):
+    """Seconds of the window that the benchmark itself took between two
+    rounds to start the profiler, and to stop it and reduce the trace.
+    Requests are in flight then, so the program rightly counts them as
+    time it left the device unfed; they are the benchmark's doing
+    (1.5-1.7 s of a traced run on the chip), not the program's."""
+    return sum(b - a for a, b in profiler_gap_intervals(ctx))

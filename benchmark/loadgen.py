@@ -266,5 +266,14 @@ def ttft_sample(records, t0, t1, tail_s):
     return waits, missing
 
 
+def awaiting_first_token(records, t):
+    """Requests due by `t` whose first token had not arrived by then:
+    the backlog on the benchmark's own clock. A rate is sustained while
+    it is no larger at a window's end than at its middle."""
+    return sum(1 for r in records
+               if r.due_t is not None and r.due_t <= t
+               and not (r.token_t and r.token_t[0] <= t))
+
+
 def tokens_in(records, t0, t1):
     return sum(1 for r in records for t in r.token_t if t0 <= t <= t1)

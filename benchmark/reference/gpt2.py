@@ -7,6 +7,11 @@ Matmuls run at "highest" precision, because on a TPU a float32 matmul is
 otherwise computed in bfloat16 passes. Departures from the source: none
 (dropout is off, as in the configuration file).
 
+`forward(.., lower=<dtype>)` is the control a referee's limit has to
+call wrong: the same equations with both operands of every matmul rounded
+to `<dtype>` first (an 8-bit type scaled tensor by tensor to its largest
+magnitude, as an 8-bit forward scales them); the arithmetic stays float32.
+
 Weights are given under the names of the program's `state_dict`
 (`from_state_dict` is the one place that knows them).
 """
@@ -15,6 +20,8 @@ import math
 
 import jax
 import jax.numpy as jnp
+
+from ._control import mm as _mm, rounded as _rounded
 
 QUERY_BLOCK = 512
 
@@ -60,10 +67,11 @@ def _gelu_new(x):
         math.sqrt(2.0 / math.pi) * (x + 0.044715 * x ** 3)))
 
 
-def _causal_attention(q, k, v):
+def _causal_attention(q, k, v, lower=None):
     """q, k, v: [B, S, H, D] -> [B, S, H, D]; query blocks bound the
     [H, block, S] score matrix."""
     s, d = q.shape[1], q.shape[-1]
+    q, k, v = (_rounded(t, lower) for t in (q, k, v))
     keys = jnp.arange(s)
     outs = []
     for q0 in range(0, s, QUERY_BLOCK):
@@ -71,43 +79,45 @@ def _causal_attention(q, k, v):
         sc = jnp.einsum("bqhd,bkhd->bhqk", qb, k) / math.sqrt(d)
         qpos = q0 + jnp.arange(qb.shape[1])
         sc = jnp.where(keys[None, :] <= qpos[:, None], sc, -jnp.inf)
-        outs.append(jnp.einsum("bhqk,bkhd->bqhd",
-                               jax.nn.softmax(sc, axis=-1), v))
+        outs.append(jnp.einsum(
+            "bhqk,bkhd->bqhd",
+            _rounded(jax.nn.softmax(sc, axis=-1), lower), v))
     return jnp.concatenate(outs, axis=1)
 
 
-@functools.partial(jax.jit, static_argnames=("n_head", "eps"))
-def _block(x, lw, n_head, eps):
+@functools.partial(jax.jit, static_argnames=("n_head", "eps", "lower"))
+def _block(x, lw, n_head, eps, lower=None):
     with jax.default_matmul_precision("highest"):
         lw = _f32(lw)
         b, s, h = x.shape
         a = _layer_norm(x, lw["ln_1"], eps)
-        qkv = a @ lw["c_attn"][0] + lw["c_attn"][1]
+        qkv = _mm(a, lw["c_attn"][0], lower) + lw["c_attn"][1]
         q, k, v = (t.reshape(b, s, n_head, h // n_head)
                    for t in jnp.split(qkv, 3, axis=-1))
-        o = _causal_attention(q, k, v).reshape(b, s, h)
-        x = x + o @ lw["c_proj"][0] + lw["c_proj"][1]
+        o = _causal_attention(q, k, v, lower).reshape(b, s, h)
+        x = x + _mm(o, lw["c_proj"][0], lower) + lw["c_proj"][1]
         m = _layer_norm(x, lw["ln_2"], eps)
-        m = _gelu_new(m @ lw["c_fc"][0] + lw["c_fc"][1])
-        return x + m @ lw["mlp_proj"][0] + lw["mlp_proj"][1]
+        m = _gelu_new(_mm(m, lw["c_fc"][0], lower) + lw["c_fc"][1])
+        return x + _mm(m, lw["mlp_proj"][0], lower) + lw["mlp_proj"][1]
 
 
-@functools.partial(jax.jit, static_argnames=("eps",))
-def _head(x, ln_f, wte, eps):
+@functools.partial(jax.jit, static_argnames=("eps", "lower"))
+def _head(x, ln_f, wte, eps, lower=None):
     with jax.default_matmul_precision("highest"):
-        return _layer_norm(x, _f32(ln_f), eps) @ wte.astype(jnp.float32).T
+        return _mm(_layer_norm(x, _f32(ln_f), eps), wte.T, lower)
 
 
-def forward(w, ids, cfg, rows=None):
+def forward(w, ids, cfg, rows=None, lower=None):
     """ids [B, S] int -> float32 logits [B, S, V], or [B, len(rows), V]
-    for the sequence positions in `rows`. cfg: the configuration file."""
+    for the sequence positions in `rows`. cfg: the configuration file.
+    `lower`: the control forward (this module's docstring)."""
     ids = jnp.asarray(ids, jnp.int32)
     s = ids.shape[1]
     eps = float(cfg["layer_norm_epsilon"])
     x = (w["wte"][ids].astype(jnp.float32)
          + w["wpe"][:s].astype(jnp.float32)[None])
     for lw in w["layers"]:
-        x = _block(x, lw, int(cfg["n_head"]), eps)
+        x = _block(x, lw, int(cfg["n_head"]), eps, lower)
     if rows is not None:
         x = x[:, jnp.asarray(rows, jnp.int32)]
-    return _head(x, w["ln_f"], w["wte"], eps)
+    return _head(x, w["ln_f"], w["wte"], eps, lower)

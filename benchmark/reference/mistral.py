@@ -17,6 +17,11 @@ Departures from the source, both noted in the configuration file:
   * query i attends keys j with i - window < j <= i (`window` keys, itself
     included). No cell reaches past the window yet.
 
+`forward(.., lower=<dtype>)` is the control a referee's limit has to
+call wrong: the same equations with both operands of every matmul rounded
+to `<dtype>` first (an 8-bit type scaled tensor by tensor to its largest
+magnitude, as an 8-bit forward scales them); the arithmetic stays float32.
+
 Weights are given under the names of the program's `state_dict`
 (`from_state_dict` is the one place that knows them); q, k and v come
 fused as one [hidden, (heads + 2 kv_heads) * head_dim] matrix.
@@ -26,6 +31,8 @@ import math
 
 import jax
 import jax.numpy as jnp
+
+from ._control import mm as _mm, rounded as _rounded
 
 QUERY_BLOCK = 512
 
@@ -66,9 +73,10 @@ def _rope(x, theta):
                      axis=-1).reshape(x.shape)
 
 
-def _window_attention(q, k, v, window):
+def _window_attention(q, k, v, window, lower=None):
     """q [B, S, H, D], k/v [B, S, Hkv, D] -> [B, S, H, D]."""
     b, s, h, d = q.shape
+    q, k, v = (_rounded(t, lower) for t in (q, k, v))
     rep = h // k.shape[2]
     k, v = jnp.repeat(k, rep, axis=2), jnp.repeat(v, rep, axis=2)
     keys = jnp.arange(s)
@@ -81,41 +89,44 @@ def _window_attention(q, k, v, window):
         if window is not None:
             keep &= keys[None, :] > qpos[:, None] - window
         sc = jnp.where(keep, sc, -jnp.inf)
-        outs.append(jnp.einsum("bhqk,bkhd->bqhd",
-                               jax.nn.softmax(sc, axis=-1), v))
+        outs.append(jnp.einsum(
+            "bhqk,bkhd->bqhd",
+            _rounded(jax.nn.softmax(sc, axis=-1), lower), v))
     return jnp.concatenate(outs, axis=1)
 
 
 @functools.partial(jax.jit, static_argnames=("heads", "kv_heads", "theta",
-                                             "eps", "window"))
-def _block(x, lw, heads, kv_heads, theta, eps, window):
+                                             "eps", "window", "lower"))
+def _block(x, lw, heads, kv_heads, theta, eps, window, lower=None):
     with jax.default_matmul_precision("highest"):
         b, s, hid = x.shape
         d = lw["wo"].shape[0] // heads
         a = _rms_norm(x, lw["in_norm"], eps)
-        qkv = a @ lw["wqkv"].astype(jnp.float32)
+        qkv = _mm(a, lw["wqkv"], lower)
         q, k, v = jnp.split(qkv, [heads * d, (heads + kv_heads) * d],
                             axis=-1)
         q = _rope(q.reshape(b, s, heads, d), theta)
         k = _rope(k.reshape(b, s, kv_heads, d), theta)
         v = v.reshape(b, s, kv_heads, d)
-        o = _window_attention(q, k, v, window).reshape(b, s, heads * d)
-        x = x + o @ lw["wo"].astype(jnp.float32)
+        o = _window_attention(q, k, v, window, lower).reshape(
+            b, s, heads * d)
+        x = x + _mm(o, lw["wo"], lower)
         m = _rms_norm(x, lw["post_norm"], eps)
-        m = (jax.nn.silu(m @ lw["gate"].astype(jnp.float32))
-             * (m @ lw["up"].astype(jnp.float32)))
-        return x + m @ lw["down"].astype(jnp.float32)
+        m = (jax.nn.silu(_mm(m, lw["gate"], lower))
+             * _mm(m, lw["up"], lower))
+        return x + _mm(m, lw["down"], lower)
 
 
-@functools.partial(jax.jit, static_argnames=("eps",))
-def _head(x, norm, head, eps):
+@functools.partial(jax.jit, static_argnames=("eps", "lower"))
+def _head(x, norm, head, eps, lower=None):
     with jax.default_matmul_precision("highest"):
-        return _rms_norm(x, norm, eps) @ head.astype(jnp.float32)
+        return _mm(_rms_norm(x, norm, eps), head, lower)
 
 
-def forward(w, ids, cfg, rows=None):
+def forward(w, ids, cfg, rows=None, lower=None):
     """ids [B, S] int -> float32 logits [B, S, V], or [B, len(rows), V]
-    for the sequence positions in `rows`. cfg: the configuration file."""
+    for the sequence positions in `rows`. cfg: the configuration file.
+    `lower`: the control forward (this module's docstring)."""
     ids = jnp.asarray(ids, jnp.int32)
     x = w["embed"][ids].astype(jnp.float32)
     window = cfg.get("sliding_window")
@@ -123,7 +134,8 @@ def forward(w, ids, cfg, rows=None):
         x = _block(x, lw, int(cfg["num_attention_heads"]),
                    int(cfg["num_key_value_heads"]),
                    float(cfg["rope_theta"]), float(cfg["rms_norm_eps"]),
-                   None if window is None else int(window))
+                   None if window is None else int(window), lower)
     if rows is not None:
         x = x[:, jnp.asarray(rows, jnp.int32)]
-    return _head(x, w["norm"], w["head"], float(cfg["rms_norm_eps"]))
+    return _head(x, w["norm"], w["head"], float(cfg["rms_norm_eps"]),
+                 lower)

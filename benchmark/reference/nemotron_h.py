@@ -48,6 +48,8 @@ import math
 import jax
 import jax.numpy as jnp
 
+from ._control import mm as _mm, rounded as _rounded
+
 
 def from_state_dict(state, n_layer):
     def blk(i):
@@ -62,24 +64,6 @@ def from_state_dict(state, n_layer):
 
 def _f32(x):
     return x.astype(jnp.float32)
-
-
-def _rounded(x, dtype):
-    """float32 `x` with its values rounded to `dtype` (None: as it is);
-    a one-byte type is scaled to the tensor's largest magnitude."""
-    x = _f32(x)
-    if dtype is None:
-        return x
-    dtype = jnp.dtype(dtype)
-    if dtype.itemsize > 1:
-        return _f32(x.astype(dtype))
-    scale = jnp.maximum(jnp.max(jnp.abs(x)), 1e-30) \
-        / float(jnp.finfo(dtype).max)
-    return _f32((x / scale).astype(dtype)) * scale
-
-
-def _mm(a, b, lower):
-    return _rounded(a, lower) @ _rounded(b, lower)
 
 
 def _rms_norm(x, w, eps):

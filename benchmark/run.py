@@ -6,7 +6,9 @@ The last line of standard output is the result, one JSON object with the
 keys `correct`, `attempted`, `failed`, `metrics`, `device` (and
 `breakdown` with --trace 1). With --trace 0 the metrics are the cell's
 end-to-end metrics, with --trace 1 its per-layer metrics. Earlier lines
-are notes (JSON objects with a `note` key) for whoever reads a log.
+are notes (JSON objects with a `note` key) for whoever reads a log. The
+result's last key, `check`, and the last lines of standard error give
+every number that decided `correct` beside its limit.
 
 Without a TPU, or with fewer chips than the cell needs, or on a device
 kind that peaks.json does not hold, the exit code is not 0 and no result
@@ -22,6 +24,7 @@ import argparse          # noqa: E402
 import contextlib        # noqa: E402
 import glob              # noqa: E402
 import json              # noqa: E402
+import math              # noqa: E402
 import os                # noqa: E402
 import shutil            # noqa: E402
 import sys               # noqa: E402
@@ -45,6 +48,7 @@ class Context:
         self.setup_s = None
         self.trace_summary = None
         self.trace_host = None
+        self._trace_dir = None
 
     def note(self, what, **fields):
         print(json.dumps({"note": what, **fields}, default=str), flush=True)
@@ -78,16 +82,17 @@ class Context:
 
     @contextlib.contextmanager
     def traced_window(self):
-        """Profile what runs inside, then reduce the trace to a summary.
-        The Python tracer is off: it records every call and slows the
-        host it is meant to observe."""
+        """Profile what runs inside. The Python tracer is off: it records
+        every call and slows the host it is meant to observe. The trace is
+        reduced by `reduce_trace`, once the window has closed: reducing it
+        here held the interpreter for seconds of an open loop's window."""
         import jax
         from . import trace_reduce
-        out = tempfile.mkdtemp(prefix="bench_trace_")
+        self._trace_dir = tempfile.mkdtemp(prefix="bench_trace_")
         opts = jax.profiler.ProfileOptions()
         opts.python_tracer_level = 0
         opts.host_tracer_level = 2
-        jax.profiler.start_trace(out, profiler_options=opts)
+        jax.profiler.start_trace(self._trace_dir, profiler_options=opts)
         ta = time.perf_counter()
         try:
             with self.span(trace_reduce.WINDOW_SPAN):
@@ -96,7 +101,17 @@ class Context:
             tb = time.perf_counter()
             jax.profiler.stop_trace()
         self.trace_host = (ta, tb)
+
+    def reduce_trace(self):
+        """The traced window's summary, if there was one; the profiler's
+        files are removed."""
+        from . import trace_reduce
+        out, self._trace_dir = self._trace_dir, None
+        if not out:
+            return
         try:
+            if not self.trace_host:
+                return
             paths = sorted(glob.glob(os.path.join(
                 out, "plugins", "profile", "*", "*.xplane.pb")))
             if not paths:
@@ -106,7 +121,8 @@ class Context:
                 trace_reduce.load(paths[-1]))
             self.note("trace", bytes=os.path.getsize(paths[-1]),
                       reduce_s=round(time.perf_counter() - t, 2),
-                      host_window_s=round(tb - ta, 3))
+                      host_window_s=round(
+                          self.trace_host[1] - self.trace_host[0], 3))
             if self.keep_trace:
                 os.makedirs(self.keep_trace, exist_ok=True)
                 shutil.copy(paths[-1], os.path.join(
@@ -170,7 +186,10 @@ def main(argv=None):
                  seed=args.seed, seconds=args.seconds, trace=args.trace,
                  compile_cache=cache, rehearse=args.rehearse,
                  t_s=round(time.perf_counter() - _T_PROCESS, 3))
-        result = harness.load_module("kinds", cell["kind"]).run(ctx)
+        try:
+            result = harness.load_module("kinds", cell["kind"]).run(ctx)
+        finally:
+            ctx.reduce_trace()
     except harness.BenchmarkError as e:
         print(f"benchmark: {e}", file=sys.stderr)
         return 2
@@ -220,6 +239,14 @@ def main(argv=None):
             "metrics": metrics, "device": device}
     if args.trace:
         line["breakdown"] = ctx.trace_summary["breakdown"]
+    # every number compared, beside its limit: the result's last key and
+    # the last lines of standard error
+    line["check"] = {
+        name: [v if math.isfinite(v) else repr(v) for v in pair]
+        for name, pair in {**result.get("checks", {}),
+                           "compiles_in_window": [in_window, 0]}.items()}
+    for name, (value, limit) in line["check"].items():
+        print(f"check {name} {value!r} limit {limit!r}", file=sys.stderr)
     print(json.dumps(line), flush=True)
     return 0
 

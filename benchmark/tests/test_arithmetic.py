@@ -186,6 +186,32 @@ def test_paged_decode_cost_by_hand():
     assert ops / nbytes == 4.0                             # GQA 32/8
 
 
+def test_decode_wave_cost_by_hand():
+    """The whole decode step of the two dense configurations at the sizes
+    their cells run: counts only."""
+    peaks = {"bf16_flops_per_s": 197e12, "hbm_bytes_per_s": 819e9}
+    g = _shapes("gpt2-small")
+    # 256 lanes at 200 cached positions each: 123.5 M matmul weights and
+    # the tied head read once (0.247 GB), 51,200 positions of 36,864 bytes
+    ops, nbytes = flops.decode_wave_cost(g, 256, 51_200)
+    assert flops.matmul_params(g) == 123_532_032
+    assert nbytes == 2 * 123_532_032 + 51_200 * 36_864
+    assert ops == 2 * 256 * 123_532_032 + 12 * 4 * 768 * 51_200
+    least, bound = flops.roofline_seconds(ops, nbytes, peaks)
+    assert bound == "memory" and least == pytest.approx(2.606e-3, rel=1e-3)
+    m = _shapes("mistral-7b-d8")
+    # 36 lanes at 500 positions: 3.75 GB of weights, 0.59 GB of K/V
+    ops, nbytes = flops.decode_wave_cost(m, 36, 18_000)
+    assert nbytes == 2 * 1_875_902_464 + 18_000 * 32_768
+    assert ops == 2 * 36 * 1_875_902_464 + 8 * 4 * 32 * 128 * 18_000
+    least, bound = flops.roofline_seconds(ops, nbytes, peaks)
+    assert bound == "memory" and least == pytest.approx(5.301e-3, rel=1e-3)
+    # no lane, nothing attended: the weights alone; and lanes only add
+    # operations, never bytes
+    assert flops.decode_wave_cost(m, 0, 0) == (0.0, 2 * 1_875_902_464)
+    assert flops.decode_wave_cost(m, 64, 18_000)[1] == nbytes
+
+
 def test_flash_train_cost_by_hand():
     g = _shapes("gpt2-small")
     ops, nbytes = flops.flash_train_cost(g, 16, 1024)

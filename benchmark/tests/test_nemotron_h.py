@@ -10,6 +10,7 @@ import numpy as np
 import pytest
 
 from benchmark import flops_hybrid, harness
+from benchmark.kinds import _serving
 from benchmark.layer_metrics import (hybrid_wave_mfu,
                                      moe_experts_device_share,
                                      moe_experts_roofline,
@@ -231,7 +232,7 @@ def test_routed_kind_holds_the_mean_and_the_worst_gap(monkeypatch):
                 "pad_to": 16, "mean_gap_tol_bf16_steps": mean,
                 "logit_tol_bf16_steps": worst}
 
-    picks = kind.sampled(_routed_ctx(check(0, 0)),
+    picks = _serving.sampled(_routed_ctx(check(0, 0)),
                          [_record([5, 6], [0, 1, 0]), _record([1], [])])
     assert [r.planned.prompt for r in picks] == [[5, 6]]
     m = kind.measure(_routed_ctx(check(0, 0)), None, picks)
@@ -332,7 +333,7 @@ def test_an_8bit_forward_is_judged_wrong_where_the_program_is_right():
              "pad_to": 128, "mean_gap_tol_bf16_steps": 1.5,
              "logit_tol_bf16_steps": 160}
     ctx = _routed_ctx(check, config=cfg, seed=5)
-    picks = kind.sampled(ctx, records)
+    picks = _serving.sampled(ctx, records)
     assert len(picks) == 4
     ok, read = kind.verdict(check, kind.measure(ctx, w, picks))
     assert ok and read["tokens"] == 192, read

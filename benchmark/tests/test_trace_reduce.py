@@ -173,3 +173,73 @@ def test_a_loop_is_not_counted_beside_its_body_and_hides_no_collective():
     assert sum(s["op_s"].values()) == pytest.approx(s["busy_s"])
     assert [k for k, _ in s["breakdown"]["device_ops"]] == \
         ["fusion", "all-reduce", "copy"]
+
+
+# ------------------------------------------------- the program's own spans
+SERVING = os.path.join(os.path.dirname(__file__), "data",
+                       "serving_spans_v5e.xplane.pb")
+
+
+def test_idle_is_named_by_the_programs_spans():
+    """A trace recorded on a v5e (data/serving_spans_v5e.xplane.pb, 0.8 MB:
+    three scheduler rounds of a two-layer decoder of gpt2 widths behind
+    the paged engine, 4 slots, each round inside a `bench/step` and
+    followed by a 4 ms `bench/idle_wait`): the program's `serving/...`
+    spans are kept beside the benchmark's, each idle instant goes to the
+    innermost of them, and the two stages lead the list where `bench/step`
+    alone stood before."""
+    trace = tr.load(SERVING)
+    names = [s.name for s in trace.spans]
+    assert len(names) == 44 and names.count("bench/step") == 3
+    assert names.count("serving/round") == 3
+    assert names.count("serving/wave/stage") == 3
+    assert names.count("serving/prefill/stage") == 2
+    assert all(n.startswith(tr.SPAN_PREFIXES) for n in names)
+    s = tr.summarize(trace)
+    assert s["window_s"] == pytest.approx(0.050205, abs=1e-6)
+    assert s["busy_s"] == pytest.approx(0.001272, abs=1e-6)
+    idle = s["idle_by_span"]
+    assert sum(idle.values()) == pytest.approx(s["window_s"] - s["busy_s"],
+                                               rel=1e-9)
+    assert idle["serving/wave/stage"] == pytest.approx(14.50e-3, abs=1e-5)
+    assert idle["serving/prefill/stage"] == pytest.approx(10.53e-3, abs=1e-5)
+    assert idle["serving/wave/wait"] == pytest.approx(5.56e-3, abs=1e-5)
+    assert idle["bench/idle_wait"] == pytest.approx(12.65e-3, abs=1e-5)
+    # what the rounds' own spans do not cover is all that is left to the
+    # benchmark's span around them
+    assert idle["bench/step"] < 0.2e-3 and idle["unattributed"] < 0.1e-3
+    assert [k for k, _ in s["breakdown"]["idle_gaps"][:3]] == [
+        "serving/wave/stage", "bench/idle_wait", "serving/prefill/stage"]
+    # the readers that look programs up by name read as before
+    assert len(s["module_s"]["decode_wave"]) == 3
+    assert len(s["module_s"]["prefill_chunk"]) == 2
+    assert set(s["kernel_by_module"]["decode_wave"]) == {"paged_attention"}
+
+
+def test_span_families_kept_and_the_runtimes_own_events_dropped():
+    keep = ["bench/step", "serving/wave/wait", "train", "train/stage",
+            "collective/all_reduce"]
+    drop = ["PjitFunction(decode_wave)", "TfrtCpuExecutable::Execute",
+            "$core.py:123 bind", "Thread pool"]
+    assert all(n.startswith(tr.SPAN_PREFIXES) for n in keep)
+    assert not any(n.startswith(tr.SPAN_PREFIXES) for n in drop)
+
+
+def test_innermost_span_wins_across_threads_and_ties():
+    """Hand-made: a round of 100 with two children, a span of another
+    thread across the first child's end, and device work under part of
+    it. Every instant goes to the shortest span that covers it."""
+    ev = tr.Event
+    spans = [ev("bench/window", 0, 100), ev("serving/round", 0, 100),
+             ev("serving/wave/stage", 10, 20),      # 10..30
+             ev("serving/wave/wait", 30, 60),       # 30..90
+             ev("bench/poll", 25, 10)]              # 25..35, another thread
+    busy = [(40, 80)]
+    idle = tr.idle_by_span(busy, spans, 0, 100)
+    assert idle == {"serving/round": 20,            # 0..10 and 90..100
+                    "serving/wave/stage": 15,       # 10..25
+                    "bench/poll": 10,               # 25..35: shortest there
+                    "serving/wave/wait": 15}        # 35..40 and 80..90
+    assert sum(idle.values()) == 100 - 40
+    assert tr.idle_by_span([], [], 0, 10) == {"unattributed": 10}
+    assert tr.idle_by_span([], spans, 5, 5) == {}

@@ -11,7 +11,9 @@ What a TPU trace holds (read off a v5e trace by hand, tests/data):
     "Async XLA Ops" the start-to-done span of asynchronous instructions
   plane "/host:CPU", one line per thread; `jax.profiler.TraceAnnotation`
     spans appear there under their own names. The benchmark's spans start
-    with "bench/".
+    with "bench/", the program's with "serving/", "train" or
+    "collective/" (SPAN_PREFIXES); the runtime's own host events are not
+    kept.
 Host and device share one clock, aligned to about a millisecond (in the
 recorded trace a program starts 0.65 ms before the host span that
 launched it); gaps are attributed, not timed, by the host spans.
@@ -22,10 +24,11 @@ seconds.
 import bisect
 import dataclasses
 import functools
+import heapq
 import re
 
 DEVICE_PLANE = re.compile(r"^/device:TPU:(\d+)$")
-SPAN_PREFIX = "bench/"
+SPAN_PREFIXES = ("bench/", "serving/", "train", "collective/")
 WINDOW_SPAN = "bench/window"
 COLLECTIVES = ("all-reduce", "all-gather", "reduce-scatter", "all-to-all",
                "collective-permute", "collective-broadcast")
@@ -60,7 +63,7 @@ class DeviceLines:
 @dataclasses.dataclass
 class Trace:
     devices: dict          # chip number -> DeviceLines
-    spans: list            # host events named "bench/..."
+    spans: list            # host events of the SPAN_PREFIXES families
 
 
 def load(path):
@@ -84,7 +87,7 @@ def load(path):
             for line in plane.lines:
                 spans.extend(Event(e.name, e.start_ns, e.duration_ns)
                              for e in line.events
-                             if e.name.startswith(SPAN_PREFIX))
+                             if e.name.startswith(SPAN_PREFIXES))
     return Trace(devices, sorted(spans, key=lambda e: e.start))
 
 
@@ -205,18 +208,25 @@ def window_of(trace):
 def idle_by_span(busy_iv, spans, t0, t1):
     """{host span name: ns of device idleness inside it}. Each instant of
     the window belongs to the innermost (shortest) span that covers it;
-    what no span covers is "unattributed"."""
-    claimed, mine = [], {}
-    for s in sorted((s for s in spans if s.name != WINDOW_SPAN),
-                    key=lambda s: s.dur):
-        iv = subtract(union(clip([s], t0, t1)), claimed)
-        if iv:
-            mine.setdefault(s.name, []).extend(iv)
-            claimed = union(claimed + iv)
+    what no span covers is "unattributed". One sweep over the spans'
+    ends: a serving trace holds thousands of the program's spans."""
+    clipped = [(max(s.start, t0), min(s.end, t1), s.dur, i, s.name)
+               for i, s in enumerate(spans) if s.name != WINDOW_SPAN]
+    clipped = sorted(c for c in clipped if c[1] > c[0])
+    cuts = sorted({t0, t1} | {c[0] for c in clipped}
+                  | {c[1] for c in clipped}) if t1 > t0 else []
+    mine, active, k = {}, [], 0        # active: heap of (dur, i, end, name)
+    for a, b in zip(cuts, cuts[1:]):
+        while k < len(clipped) and clipped[k][0] <= a:
+            _, end, dur, i, name = clipped[k]
+            heapq.heappush(active, (dur, i, end, name))
+            k += 1
+        while active and active[0][2] <= a:
+            heapq.heappop(active)
+        mine.setdefault(active[0][3] if active else "unattributed",
+                        []).append((a, b))
     out = {name: length(subtract(union(iv), busy_iv))
            for name, iv in mine.items()}
-    out["unattributed"] = length(subtract(
-        subtract([(t0, t1)] if t1 > t0 else [], claimed), busy_iv))
     return {k: v for k, v in out.items() if v > 0}
 
 
