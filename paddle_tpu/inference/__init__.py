@@ -66,11 +66,11 @@ class Config:
         initialized: correctness holds regardless (the verify step
         guarantees the target distribution), but acceptance — the whole
         speedup — needs a draft that actually predicts the target.
-        paged_kernel selects the fused paged-attention implementation
-        the engine compiles with ("reference" | "lax" | "pallas" |
-        "auto"; default None defers to the PT_PAGED_KERNEL env var,
-        then backend auto-selection — nn/paged_attention.py). The
-        engine's /healthz reports the resolved kernel."""
+        paged_kernel pins the paged-attention core the engine compiles
+        with ("reference" | "pallas"); the default None takes what the
+        backend decides, "pallas" on a TPU and "reference" elsewhere
+        (nn/paged_attention.py). The engine's /healthz reports the
+        resolved core."""
         self._llm_opts = {
             "num_slots": int(num_slots),
             "max_len": int(max_len),

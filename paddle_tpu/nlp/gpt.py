@@ -179,113 +179,74 @@ class GPTAttention(nn.Layer):
         return (jnp.zeros(shape, dtype), jnp.zeros(shape, dtype))
 
     def init_paged_cache(self, num_blocks, block_size, dtype=jnp.float32):
-        """Block-pool KV cache [num_blocks, heads, block_size,
-        2 * head_dim] (K beside V: nn.transformer's stored form) —
+        """A layer's block pool (nn.paged_attention owns its form) —
         requests claim BLOCKS (named by a host-managed table), not dense
         rows; see serving/paged."""
-        from ..nn.transformer import init_block_kv
+        from ..nn.paged_attention import init_block_kv
         return init_block_kv(num_blocks, self.num_heads, block_size,
                              self.head_dim, dtype)
 
-    def decode(self, x_t, cache, pos, block_tables=None):
-        """One-token step: write K/V at `pos`, attend q over cache[:pos].
-        x_t: [B, 1, H] Tensor; pos: traced int — a scalar (lockstep
-        batch) or a [B] vector (slot-wise serving decode: per-row cache
-        scatter + per-row mask, same shapes, one program). With
-        block_tables [B, nblk], `cache` is the block POOL: K/V are
-        written through the table and attention reads it through the
-        table — same fixed shapes, one program for every allocation."""
-        b = x_t.shape[0]
-        qkv = self.qkv_proj(x_t)
+    def _qkv_heads(self, x):
+        """x [B, S, H] Tensor -> q, k, v arrays [B, nh, S, D]."""
+        b, s = x.shape[0], x.shape[1]
+        qkv = self.qkv_proj(x)
         a = qkv._data if isinstance(qkv, Tensor) else qkv
-        a = a.reshape(b, 1, 3, self.num_heads, self.head_dim)
-        a = jnp.transpose(a, (2, 0, 3, 1, 4))           # [3, B, nh, 1, D]
-        q, k_t, v_t = a[0], a[1], a[2]
-        from ..nn.paged_attention import paged_decode_attention
-        from ..nn.transformer import (cached_decode_attention,
-                                      scatter_kv_at, write_block_kv)
-        if block_tables is not None:
-            # fused path: attention reads K/V straight out of the pool
-            # through the table (dispatch: reference | lax | pallas) —
-            # the [B, Hkv, nblk*BS, D] gathered view never exists
-            cache = write_block_kv(cache, k_t, v_t, block_tables, pos)
-            out = paged_decode_attention(q, cache, block_tables, pos,
-                                         1.0 / math.sqrt(self.head_dim),
-                                         window=self.attn_window)
+        a = a.reshape(b, s, 3, self.num_heads, self.head_dim)
+        a = jnp.transpose(a, (2, 0, 3, 1, 4))           # [3, B, nh, S, D]
+        return a[0], a[1], a[2]
+
+    def _merge_heads(self, out, x):
+        """out [B, nh, S, D] -> the output projection of [B, S, H], in
+        x's dtype."""
+        b, _, s, _ = out.shape
+        out = jnp.transpose(out, (0, 2, 1, 3)).reshape(b, s, -1)
+        return self.out_proj(Tensor(out.astype(x._data.dtype)))
+
+    def decode(self, x_t, cache, pos):
+        """One-token step on the dense cache: write K/V at `pos`, attend
+        q over cache[:pos]. x_t: [B, 1, H] Tensor; pos: traced int — a
+        scalar (lockstep batch) or a [B] vector (slot-wise serving
+        decode: per-row cache scatter + per-row mask, same shapes, one
+        program)."""
+        q, k_t, v_t = self._qkv_heads(x_t)
+        from ..nn.transformer import cached_decode_attention, scatter_kv_at
+        ck, cv = cache
+        if jnp.ndim(pos):
+            ck = scatter_kv_at(ck, k_t, pos)
+            cv = scatter_kv_at(cv, v_t, pos)
         else:
-            ck, cv = cache
-            if jnp.ndim(pos):
-                ck = scatter_kv_at(ck, k_t, pos)
-                cv = scatter_kv_at(cv, v_t, pos)
-            else:
-                ck = jax.lax.dynamic_update_slice_in_dim(
-                    ck, k_t.astype(ck.dtype), pos, axis=2)
-                cv = jax.lax.dynamic_update_slice_in_dim(
-                    cv, v_t.astype(cv.dtype), pos, axis=2)
-            out = cached_decode_attention(q, ck, cv, pos,
-                                          1.0 / math.sqrt(self.head_dim),
-                                          window=self.attn_window)
-            cache = (ck, cv)
-        out = jnp.transpose(out, (0, 2, 1, 3)).reshape(b, 1, -1)
-        out = self.out_proj(Tensor(out.astype(x_t._data.dtype)))
-        return out, cache
+            ck = jax.lax.dynamic_update_slice_in_dim(
+                ck, k_t.astype(ck.dtype), pos, axis=2)
+            cv = jax.lax.dynamic_update_slice_in_dim(
+                cv, v_t.astype(cv.dtype), pos, axis=2)
+        out = cached_decode_attention(q, ck, cv, pos,
+                                      1.0 / math.sqrt(self.head_dim),
+                                      window=self.attn_window)
+        return self._merge_heads(out, x_t), (ck, cv)
 
-    def prefill_chunk(self, x, cache, block_tables, chunk_start, valid_len):
-        """One prompt CHUNK [1, C, H] against the block pool: write the
-        chunk's K/V through the table at absolute positions chunk_start +
-        arange(C) (the padded tail past valid_len is not written), then
-        attend the C queries over the pool — previous chunks' cached
-        positions plus this chunk's own causal prefix (the cores mask
-        ks <= chunk_start + i)."""
-        b, s, h = x.shape
-        qkv = self.qkv_proj(x)
-        a = qkv._data if isinstance(qkv, Tensor) else qkv
-        a = a.reshape(b, s, 3, self.num_heads, self.head_dim)
-        a = jnp.transpose(a, (2, 0, 3, 1, 4))           # [3, B, nh, C, D]
-        q, k, v = a[0], a[1], a[2]
-        from ..nn.paged_attention import paged_chunk_attention
-        from ..nn.transformer import write_block_kv
-        cache = write_block_kv(cache, k, v, block_tables, chunk_start,
-                               valid_len)
-        out = paged_chunk_attention(q, cache, block_tables, chunk_start,
-                                    1.0 / math.sqrt(self.head_dim),
-                                    window=self.attn_window)
-        out = jnp.transpose(out, (0, 2, 1, 3)).reshape(b, s, h)
-        return self.out_proj(Tensor(out.astype(x._data.dtype))), cache
-
-    def decode_chunk(self, x, cache, block_tables, start, valid_len):
-        """Speculative verify step: C tokens for EVERY lane at once
-        (x: [S, C, H]; start/valid_len: [S]) — the batched, per-lane-
-        offset sibling of prefill_chunk. K/V are written through every
-        lane's table in one op (nothing at i >= valid_len[s]: horizon /
-        spec_len clamp) and the cores' vector start gives each query
-        row its own causal frontier."""
-        b, s, h = x.shape
-        qkv = self.qkv_proj(x)
-        a = qkv._data if isinstance(qkv, Tensor) else qkv
-        a = a.reshape(b, s, 3, self.num_heads, self.head_dim)
-        a = jnp.transpose(a, (2, 0, 3, 1, 4))           # [3, S, nh, C, D]
-        q, k, v = a[0], a[1], a[2]
-        from ..nn.paged_attention import paged_chunk_attention
-        from ..nn.transformer import write_block_kv
-        cache = write_block_kv(cache, k, v, block_tables, start, valid_len)
-        out = paged_chunk_attention(q, cache, block_tables, start,
-                                    1.0 / math.sqrt(self.head_dim),
-                                    window=self.attn_window)
-        out = jnp.transpose(out, (0, 2, 1, 3)).reshape(b, s, h)
-        return self.out_proj(Tensor(out.astype(x._data.dtype))), cache
+    def paged_step(self, x, cache, block_tables, start, valid_len=None):
+        """C positions a lane against the block pool, x: [B, C, H]. Their
+        K/V go through the tables [B, nblk] to the absolute positions
+        start + arange(C) (nothing at i >= valid_len: a padded tail, a
+        horizon or spec_len clamp), then the C queries attend the pool —
+        what the lane cached before plus the span's own causal prefix.
+        `start` and `valid_len` are scalars or [B] vectors. One body at
+        every width: the decode wave is C == 1, the prefill chunk B == 1,
+        the speculative verify wave [S, k + 1]."""
+        q, k, v = self._qkv_heads(x)
+        from ..nn.paged_attention import paged_attend
+        out, cache = paged_attend(q, k, v, cache, block_tables, start,
+                                  valid_len,
+                                  1.0 / math.sqrt(self.head_dim),
+                                  window=self.attn_window)
+        return self._merge_heads(out, x), cache
 
     def prefill(self, x, cache):
         """Prompt-phase step: the forward attention math over x [B, P, H]
         that also writes the prompt's K/V into cache[:, :, :P] so decode
         continues at pos=P (cells past the true prompt length are rewritten
         by the decode frontier before the ks<=pos mask ever exposes them)."""
-        b, s, h = x.shape
-        qkv = self.qkv_proj(x)
-        a = qkv._data if isinstance(qkv, Tensor) else qkv
-        a = a.reshape(b, s, 3, self.num_heads, self.head_dim)
-        a = jnp.transpose(a, (2, 0, 3, 1, 4))           # [3, B, nh, S, D]
-        q, k, v = a[0], a[1], a[2]
+        q, k, v = self._qkv_heads(x)
         ck, cv = cache
         ck = jax.lax.dynamic_update_slice(ck, k.astype(ck.dtype),
                                           (0, 0, 0, 0))
@@ -293,8 +254,7 @@ class GPTAttention(nn.Layer):
                                           (0, 0, 0, 0))
         from ..ops.pallas.flash_attention import _flash_array
         out = _flash_array(q, k, v, causal=True, window=self.attn_window)
-        out = jnp.transpose(out, (0, 2, 1, 3)).reshape(b, s, h)
-        return self.out_proj(Tensor(out.astype(x._data.dtype))), (ck, cv)
+        return self._merge_heads(out, x), (ck, cv)
 
 
 class GPTMLP(nn.Layer):
@@ -341,39 +301,24 @@ class GPTBlock(nn.Layer):
             return x, m[1]
         return x + m
 
-    def decode(self, x, cache, pos, block_tables=None):
-        a, cache = self.attn.decode(self.ln_1(x), cache, pos,
-                                    block_tables=block_tables)
-        x = x + a
-        x = x + self.mlp(self.ln_2(x))
-        return x, cache
-
-    def prefill(self, x, cache):
-        a, cache = self.attn.prefill(self.ln_1(x), cache)
-        x = x + a
+    def _mlp_residual(self, x):
         m = self.mlp(self.ln_2(x))
         if isinstance(m, tuple):         # MoE FFN: (out, aux_loss) — aux
             m = m[0]                     # is a training-only signal
-        return x + m, cache
+        return x + m
 
-    def prefill_chunk(self, x, cache, block_tables, chunk_start, valid_len):
-        a, cache = self.attn.prefill_chunk(self.ln_1(x), cache,
-                                           block_tables, chunk_start,
-                                           valid_len)
-        x = x + a
-        m = self.mlp(self.ln_2(x))
-        if isinstance(m, tuple):         # MoE FFN: aux is training-only
-            m = m[0]
-        return x + m, cache
+    def decode(self, x, cache, pos):
+        a, cache = self.attn.decode(self.ln_1(x), cache, pos)
+        return self._mlp_residual(x + a), cache
 
-    def decode_chunk(self, x, cache, block_tables, start, valid_len):
-        a, cache = self.attn.decode_chunk(self.ln_1(x), cache,
-                                          block_tables, start, valid_len)
-        x = x + a
-        m = self.mlp(self.ln_2(x))
-        if isinstance(m, tuple):         # MoE FFN: aux is training-only
-            m = m[0]
-        return x + m, cache
+    def prefill(self, x, cache):
+        a, cache = self.attn.prefill(self.ln_1(x), cache)
+        return self._mlp_residual(x + a), cache
+
+    def paged_step(self, x, cache, block_tables, start, valid_len=None):
+        a, cache = self.attn.paged_step(self.ln_1(x), cache, block_tables,
+                                        start, valid_len)
+        return self._mlp_residual(x + a), cache
 
 
 class GPTEmbeddings(nn.Layer):
@@ -454,11 +399,10 @@ class GPTModel(nn.Layer):
         return [blk.attn.init_paged_cache(num_blocks, block_size, dtype)
                 for blk in self.blocks]
 
-    def decode_step(self, tok, caches, pos, block_tables=None):
-        """tok: [B, 1] ids; pos: traced position — a scalar, or a [B]
-        vector for slot-wise serving decode. With block_tables [B, nblk]
-        the caches are block POOLS (paged serving engine). Returns
-        (h, caches)."""
+    def decode_step(self, tok, caches, pos):
+        """One token a row on the dense caches. tok: [B, 1] ids; pos:
+        traced position — a scalar, or a [B] vector for slot-wise
+        serving decode. Returns (h, caches)."""
         pos = pos._data if isinstance(pos, Tensor) else pos
         if jnp.ndim(pos):
             pos_ids = jnp.asarray(pos, jnp.int32)[:, None]
@@ -469,49 +413,27 @@ class GPTModel(nn.Layer):
         x = self.embeddings(tok, Tensor(pos_ids))
         new_caches = []
         for blk, cache in zip(self.blocks, caches):
-            x, cache = blk.decode(x, cache, pos,
-                                  block_tables=block_tables)
+            x, cache = blk.decode(x, cache, pos)
             new_caches.append(cache)
         return self.ln_f(x), new_caches
 
-    def prefill_chunk(self, tok_chunk, caches, block_tables, chunk_start,
-                      valid_len):
-        """One prompt chunk [1, C] ids at absolute positions chunk_start
-        + arange(C) against the block pools (chunked prefill: long
-        prompts run C tokens at a time between decode waves, writing K/V
-        through the slot's block table). Returns (h, caches)."""
-        c = tok_chunk.shape[1]
-        pos_ids = (chunk_start + jnp.arange(c, dtype=jnp.int32))[None, :]
-        x = self.embeddings(tok_chunk, Tensor(pos_ids))
-        new_caches = []
-        for blk, cache in zip(self.blocks, caches):
-            x, cache = blk.prefill_chunk(x, cache, block_tables,
-                                         chunk_start, valid_len)
-            new_caches.append(cache)
-        return self.ln_f(x), new_caches
-
-    def decode_chunk(self, tok_chunk, caches, block_tables, start,
-                     valid_len):
-        """Speculative verify: C tokens per lane ([S, C] ids) at
-        per-lane absolute positions start[s] + i against the block
-        pools. Position-embedding rows are gathered at the per-lane
-        position matrix (out-of-table pad positions clamp harmlessly —
-        their K/V is scratch-redirected and their logits masked)."""
-        block_tables = (block_tables._data
-                        if isinstance(block_tables, Tensor)
-                        else block_tables)
+    def paged_step(self, tok, caches, block_tables, start, valid_len=None):
+        """[B, C] ids at absolute positions start + arange(C) against the
+        block pools (see GPTAttention.paged_step for the three widths the
+        paged engines run it at). Position-embedding rows are gathered at
+        the per-lane position matrix; a padded tail's positions past the
+        table clamp harmlessly (their K/V is not written and their logits
+        are not read). Returns (h [B, C, H], caches)."""
         start = start._data if isinstance(start, Tensor) else start
-        valid_len = (valid_len._data if isinstance(valid_len, Tensor)
-                     else valid_len)
-        c = tok_chunk.shape[1]
+        c = tok.shape[1]
         pos_ids = jnp.minimum(
-            start[:, None] + jnp.arange(c, dtype=jnp.int32)[None, :],
+            jnp.reshape(start, (-1, 1)) + jnp.arange(c, dtype=jnp.int32),
             self.cfg.max_seq_len - 1)
-        x = self.embeddings(tok_chunk, Tensor(pos_ids))
+        x = self.embeddings(tok, Tensor(pos_ids))
         new_caches = []
         for blk, cache in zip(self.blocks, caches):
-            x, cache = blk.decode_chunk(x, cache, block_tables, start,
-                                        valid_len)
+            x, cache = blk.paged_step(x, cache, block_tables, start,
+                                      valid_len)
             new_caches.append(cache)
         return self.ln_f(x), new_caches
 
@@ -569,54 +491,46 @@ class GPTForPretraining(nn.Layer):
         return self.gpt.init_paged_cache(num_blocks, block_size, max_len,
                                          dtype)
 
-    def decode_step(self, tok, caches, pos, block_tables=None):
-        h, caches = self.gpt.decode_step(tok, caches, pos,
-                                         block_tables=block_tables)
+    def _logits(self, h, frontier=None):
+        """Tied-head logits of h [B, S, H]; frontier (traced index): of
+        that one position only, [B, 1, V] — the serving engines want ONE
+        next-token row, and indexing before the head keeps the vocab
+        matmul [1, V] instead of [S, V]."""
+        if frontier is not None:
+            hr = h._data if isinstance(h, Tensor) else h
+            h = Tensor(jax.lax.dynamic_slice_in_dim(hr, frontier, 1,
+                                                    axis=1))
         w = self.gpt.embeddings.word_embeddings.weight
         from ..ops.math import matmul
-        return matmul(h, w, transpose_y=True), caches
+        return matmul(h, w, transpose_y=True)
 
-    def decode_chunk(self, tok_chunk, caches, block_tables, start,
-                     valid_len):
-        """Speculative verify: logits for ALL C positions of every lane
-        ([S, C, V] — the k+1-proportional head cost the verify program
-        pays on purpose: one batched forward scores the whole drafted
-        span)."""
-        h, caches = self.gpt.decode_chunk(tok_chunk, caches,
-                                          block_tables, start, valid_len)
-        w = self.gpt.embeddings.word_embeddings.weight
-        from ..ops.math import matmul
-        return matmul(h, w, transpose_y=True), caches
+    def decode_step(self, tok, caches, pos, block_tables=None):
+        """One token a row: tok [B, 1] at pos (a scalar, or [B]). On the
+        dense caches; given block_tables [B, nblk] the caches are block
+        POOLS and the step is a chunk of one (the paged decode wave).
+        The one place that chooses between the two."""
+        if block_tables is not None:
+            return self.prefill_chunk(tok, caches, block_tables, pos, None)
+        h, caches = self.gpt.decode_step(tok, caches, pos)
+        return self._logits(h), caches
 
     def prefill_chunk(self, tok_chunk, caches, block_tables, chunk_start,
                       valid_len, frontier=None):
-        """One prompt chunk against the block pools. frontier (traced
-        index WITHIN the chunk): logits for that one position only —
-        [1, V] instead of [C, V], same trick as prefill; only the final
-        chunk's frontier is consumed by the serving engine."""
-        h, caches = self.gpt.prefill_chunk(tok_chunk, caches, block_tables,
-                                           chunk_start, valid_len)
-        if frontier is not None:
-            hr = h._data if isinstance(h, Tensor) else h
-            h = Tensor(jax.lax.dynamic_slice_in_dim(hr, frontier, 1,
-                                                    axis=1))
-        w = self.gpt.embeddings.word_embeddings.weight
-        from ..ops.math import matmul
-        return matmul(h, w, transpose_y=True), caches
+        """[B, C] ids a lane against the block pools, at absolute
+        positions chunk_start + arange(C) (chunk_start and valid_len:
+        scalars or [B]): a prompt chunk of one lane, or every lane's
+        k + 1 drafted tokens in the speculative verify wave, whose
+        logits for ALL C positions ([S, C, V]) are the cost that program
+        pays on purpose. frontier: see _logits; only the final chunk's
+        frontier is consumed by the serving engine."""
+        h, caches = self.gpt.paged_step(tok_chunk, caches, block_tables,
+                                        chunk_start, valid_len)
+        return self._logits(h, frontier), caches
 
     def prefill(self, input_ids, max_len, dtype=jnp.float32,
                 frontier=None):
-        """frontier (traced index): logits for that one prompt position
-        only — keeps the serving prefill's vocab matmul [1, V] instead
-        of [P, V] over the whole padded bucket."""
         h, caches = self.gpt.prefill(input_ids, max_len, dtype)
-        if frontier is not None:
-            hr = h._data if isinstance(h, Tensor) else h
-            h = Tensor(jax.lax.dynamic_slice_in_dim(hr, frontier, 1,
-                                                    axis=1))
-        w = self.gpt.embeddings.word_embeddings.weight
-        from ..ops.math import matmul
-        return matmul(h, w, transpose_y=True), caches
+        return self._logits(h, frontier), caches
 
 
 # auto threshold for fused_head_loss=None: chunk once the f32 logits

@@ -146,33 +146,19 @@ def apply_rope(x, cos, sin, pos_offset=0):
 
 
 def apply_rope_positions(x, cos, sin, positions):
-    """x: [B, H, C, D] rotated at traced absolute positions — a [C]
-    vector (chunked prefill: one lane, every row at the same offsets)
-    or a [B, C] matrix (the speculative verify wave: every lane's
-    k+1-token span starts at its own depth). GATHERED per element, not
-    dynamic-sliced: a final padded chunk can run past the table end,
-    where a dynamic_slice clamps its START and silently shifts the
-    rotation of VALID rows; the gather clamps only the out-of-range pad
-    rows themselves (whose K/V is redirected to the scratch block and
-    never read)."""
+    """x: [B, H, C, D] rotated at traced absolute positions [B, C] (or
+    [1, C], every row alike): each lane's span starts at its own depth —
+    one row a lane in a decode wave, a chunk of one lane's prompt, every
+    lane's k+1-token span in the speculative verify wave. GATHERED per
+    element, not dynamic-sliced: a final padded chunk can run past the
+    table end, where a dynamic_slice clamps its START and silently
+    shifts the rotation of VALID rows; the gather clamps only the
+    out-of-range pad rows themselves (whose K/V is never written or
+    read). One gather cos[idx] keeps the whole batch one fused
+    program."""
     idx = jnp.minimum(positions, cos.shape[0] - 1)
-    if jnp.ndim(positions) == 2:                    # [B, C] per-lane
-        c = cos[idx][:, None, :, :]                 # [B, 1, C, D/2]
-        sn = sin[idx][:, None, :, :]
-    else:
-        c = cos[idx][None, None, :, :]              # [1, 1, C, D/2]
-        sn = sin[idx][None, None, :, :]
-    return _rotate_pairs(x, c, sn)
-
-
-def apply_rope_at(x, cos, sin, pos):
-    """Single-token RoPE at a per-row position VECTOR. x: [B, H, 1, D];
-    pos: [B] int — each batch row rotated at its own position (slot-wise
-    serving decode, where slots sit at different depths). The table rows
-    come from one gather cos[pos] instead of a dynamic_slice, so the
-    whole batch stays one fused program."""
-    return _rotate_pairs(x, cos[pos][:, None, None, :],
-                         sin[pos][:, None, None, :])
+    return _rotate_pairs(x, cos[idx][:, None, :, :],    # [B, 1, C, D/2]
+                         sin[idx][:, None, :, :])
 
 
 @functools.lru_cache(maxsize=8)
@@ -300,131 +286,81 @@ class LlamaAttention(nn.Layer):
         return (jnp.zeros(shape, dtype), jnp.zeros(shape, dtype))
 
     def init_paged_cache(self, num_blocks, block_size, dtype=jnp.float32):
-        """Block-pool KV cache [num_blocks, kv_heads, block_size, 2 * hd]
-        (K beside V: nn.transformer's stored form) — GQA pools cache only
-        the kv heads, and requests claim blocks through a host-managed
-        table (serving/paged)."""
-        from ..nn.transformer import init_block_kv
+        """A layer's block pool (nn.paged_attention owns its form) — GQA
+        pools cache only the kv heads, and requests claim blocks through
+        a host-managed table (serving/paged)."""
+        from ..nn.paged_attention import init_block_kv
         return init_block_kv(num_blocks, self.num_kv_heads, block_size,
                              self.head_dim, dtype)
 
-    def decode(self, x_t, cache, pos, block_tables=None):
-        """One-token step: RoPE at `pos` (traced), write K/V, attend over
-        cache[:pos]. x_t: [B, 1, H] Tensor. `pos` is a scalar (lockstep
-        batch) or a [B] vector — slot-wise serving decode where each row
-        is at its own depth; the vector path scatters per-row cache
-        writes and masks per-row, same fixed shapes, one program. With
-        block_tables [B, nblk] the cache is the block POOL: K/V are
-        written through the table and attention reads it through the
-        table."""
+    def _qkv_heads(self, x):
+        """x [B, S, H] Tensor -> q [B, nh, S, D], k and v [B, nkv, S, D]
+        arrays, before rotation."""
         from ..framework.tensor import Tensor
         nh, nkv, hd = self.num_heads, self.num_kv_heads, self.head_dim
-        b = x_t.shape[0]
-        qkv = self.qkv_proj(x_t)
+        b, s = x.shape[0], x.shape[1]
+        qkv = self.qkv_proj(x)
         a = qkv._data if isinstance(qkv, Tensor) else qkv
-        q, k_t, v_t = jnp.split(a, [nh * hd, (nh + nkv) * hd], axis=-1)
-        q = q.reshape(b, 1, nh, hd).transpose(0, 2, 1, 3)
-        k_t = k_t.reshape(b, 1, nkv, hd).transpose(0, 2, 1, 3)
-        v_t = v_t.reshape(b, 1, nkv, hd).transpose(0, 2, 1, 3)
-        from ..nn.paged_attention import paged_decode_attention
-        from ..nn.transformer import (cached_decode_attention,
-                                      scatter_kv_at, write_block_kv)
-        if block_tables is not None:
-            # fused path: attention reads K/V straight out of the pool
-            # through the table (dispatch: reference | lax | pallas) —
-            # the [B, Hkv, nblk*BS, D] gathered view never exists
-            q = apply_rope_at(q, self._cos, self._sin, pos)
-            k_t = apply_rope_at(k_t, self._cos, self._sin, pos)
-            cache = write_block_kv(cache, k_t, v_t, block_tables, pos)
-            out = paged_decode_attention(q, cache, block_tables, pos,
-                                         1.0 / math.sqrt(hd),
-                                         window=self.attn_window)
+        q, k, v = jnp.split(a, [nh * hd, (nh + nkv) * hd], axis=-1)
+        return (q.reshape(b, s, nh, hd).transpose(0, 2, 1, 3),
+                k.reshape(b, s, nkv, hd).transpose(0, 2, 1, 3),
+                v.reshape(b, s, nkv, hd).transpose(0, 2, 1, 3))
+
+    def _merge_heads(self, out, x):
+        """out [B, nh, S, D] -> the output projection of [B, S, nh * D],
+        in x's dtype."""
+        from ..framework.tensor import Tensor
+        b, _, s, _ = out.shape
+        out = jnp.transpose(out, (0, 2, 1, 3)).reshape(b, s, -1)
+        return self.o_proj(Tensor(out.astype(x._data.dtype)))
+
+    def decode(self, x_t, cache, pos):
+        """One-token step on the dense cache: RoPE at `pos` (traced),
+        write K/V, attend over cache[:pos]. x_t: [B, 1, H] Tensor. `pos`
+        is a scalar (lockstep batch) or a [B] vector — slot-wise serving
+        decode where each row is at its own depth; the vector path
+        scatters per-row cache writes and masks per-row, same fixed
+        shapes, one program."""
+        q, k_t, v_t = self._qkv_heads(x_t)
+        from ..nn.transformer import cached_decode_attention, scatter_kv_at
+        ck, cv = cache
+        if jnp.ndim(pos):
+            positions = pos[:, None]
+            q = apply_rope_positions(q, self._cos, self._sin, positions)
+            k_t = apply_rope_positions(k_t, self._cos, self._sin, positions)
+            ck = scatter_kv_at(ck, k_t, pos)
+            cv = scatter_kv_at(cv, v_t, pos)
         else:
-            ck, cv = cache
-            if jnp.ndim(pos):
-                q = apply_rope_at(q, self._cos, self._sin, pos)
-                k_t = apply_rope_at(k_t, self._cos, self._sin, pos)
-                ck = scatter_kv_at(ck, k_t, pos)
-                cv = scatter_kv_at(cv, v_t, pos)
-            else:
-                q = apply_rope(q, self._cos, self._sin, pos_offset=pos)
-                k_t = apply_rope(k_t, self._cos, self._sin,
-                                 pos_offset=pos)
-                ck = jax.lax.dynamic_update_slice_in_dim(
-                    ck, k_t.astype(ck.dtype), pos, axis=2)
-                cv = jax.lax.dynamic_update_slice_in_dim(
-                    cv, v_t.astype(cv.dtype), pos, axis=2)
-            out = cached_decode_attention(q, ck, cv, pos,
-                                          1.0 / math.sqrt(hd),
-                                          window=self.attn_window)
-            cache = (ck, cv)
-        out = jnp.transpose(out, (0, 2, 1, 3)).reshape(b, 1, nh * hd)
-        out = self.o_proj(Tensor(out.astype(x_t._data.dtype)))
-        return out, cache
+            q = apply_rope(q, self._cos, self._sin, pos_offset=pos)
+            k_t = apply_rope(k_t, self._cos, self._sin, pos_offset=pos)
+            ck = jax.lax.dynamic_update_slice_in_dim(
+                ck, k_t.astype(ck.dtype), pos, axis=2)
+            cv = jax.lax.dynamic_update_slice_in_dim(
+                cv, v_t.astype(cv.dtype), pos, axis=2)
+        out = cached_decode_attention(q, ck, cv, pos,
+                                      1.0 / math.sqrt(self.head_dim),
+                                      window=self.attn_window)
+        return self._merge_heads(out, x_t), (ck, cv)
 
-    def prefill_chunk(self, x, cache, block_tables, chunk_start,
-                      valid_len):
-        """One prompt chunk [1, C, H] against the block pool: RoPE at the
-        absolute positions chunk_start + arange(C) (gathered per
-        position — a final chunk may overrun the table with pad rows),
-        write the chunk's K/V through the table, attend the C queries
-        over the pool (previous chunks + own causal prefix)."""
-        from ..framework.tensor import Tensor
-        nh, nkv, hd = self.num_heads, self.num_kv_heads, self.head_dim
-        b, s = x.shape[0], x.shape[1]
-        qkv = self.qkv_proj(x)
-        a = qkv._data if isinstance(qkv, Tensor) else qkv
-        q, k, v = jnp.split(a, [nh * hd, (nh + nkv) * hd], axis=-1)
-        q = q.reshape(b, s, nh, hd).transpose(0, 2, 1, 3)
-        k = k.reshape(b, s, nkv, hd).transpose(0, 2, 1, 3)
-        v = v.reshape(b, s, nkv, hd).transpose(0, 2, 1, 3)
-        positions = chunk_start + jnp.arange(s)
+    def paged_step(self, x, cache, block_tables, start, valid_len=None):
+        """C positions a lane against the block pool, x: [B, C, H]: RoPE
+        at the absolute positions start + arange(C), the span's K/V
+        written through the tables [B, nblk] (nothing at i >= valid_len:
+        a padded tail, a horizon or spec_len clamp), then the C queries
+        attend the pool — what the lane cached before plus the span's own
+        causal prefix. `start` and `valid_len` are scalars or [B]
+        vectors. One body at every width: the decode wave is C == 1, the
+        prefill chunk B == 1, the speculative verify wave [S, k + 1]."""
+        q, k, v = self._qkv_heads(x)
+        positions = jnp.reshape(start, (-1, 1)) + jnp.arange(x.shape[1])
         q = apply_rope_positions(q, self._cos, self._sin, positions)
         k = apply_rope_positions(k, self._cos, self._sin, positions)
-        from ..nn.paged_attention import paged_chunk_attention
-        from ..nn.transformer import write_block_kv
-        cache = write_block_kv(cache, k, v, block_tables, chunk_start,
-                               valid_len)
-        out = paged_chunk_attention(q, cache, block_tables, chunk_start,
-                                    1.0 / math.sqrt(hd),
-                                    window=self.attn_window)
-        out = jnp.transpose(out, (0, 2, 1, 3)).reshape(b, s, nh * hd)
-        out = self.o_proj(Tensor(out.astype(x._data.dtype)))
-        return out, cache
-
-    def decode_chunk(self, x, cache, block_tables, start, valid_len):
-        """Speculative verify step: C tokens for EVERY lane at once.
-        x: [S, C, H]; block_tables: [S, nblk]; start/valid_len: [S] —
-        lane s's tokens sit at absolute positions start[s] + i, with
-        nothing written at i >= valid_len[s] (horizon / per-request
-        spec_len clamp). RoPE is gathered at the per-lane position
-        matrix, K/V are written through every lane's table in one op
-        (write_block_kv), and
-        chunk_attention's vector-start mask gives each query row its
-        own causal frontier — the C==1 case of this IS the decode wave,
-        which is why verify is a third compiled program, not a new
-        attention path."""
-        from ..framework.tensor import Tensor
-        nh, nkv, hd = self.num_heads, self.num_kv_heads, self.head_dim
-        b, s = x.shape[0], x.shape[1]
-        qkv = self.qkv_proj(x)
-        a = qkv._data if isinstance(qkv, Tensor) else qkv
-        q, k, v = jnp.split(a, [nh * hd, (nh + nkv) * hd], axis=-1)
-        q = q.reshape(b, s, nh, hd).transpose(0, 2, 1, 3)
-        k = k.reshape(b, s, nkv, hd).transpose(0, 2, 1, 3)
-        v = v.reshape(b, s, nkv, hd).transpose(0, 2, 1, 3)
-        positions = start[:, None] + jnp.arange(s)[None, :]    # [S, C]
-        q = apply_rope_positions(q, self._cos, self._sin, positions)
-        k = apply_rope_positions(k, self._cos, self._sin, positions)
-        from ..nn.paged_attention import paged_chunk_attention
-        from ..nn.transformer import write_block_kv
-        cache = write_block_kv(cache, k, v, block_tables, start, valid_len)
-        out = paged_chunk_attention(q, cache, block_tables, start,
-                                    1.0 / math.sqrt(hd),
-                                    window=self.attn_window)
-        out = jnp.transpose(out, (0, 2, 1, 3)).reshape(b, s, nh * hd)
-        out = self.o_proj(Tensor(out.astype(x._data.dtype)))
-        return out, cache
+        from ..nn.paged_attention import paged_attend
+        out, cache = paged_attend(q, k, v, cache, block_tables, start,
+                                  valid_len,
+                                  1.0 / math.sqrt(self.head_dim),
+                                  window=self.attn_window)
+        return self._merge_heads(out, x), cache
 
     def prefill(self, x, cache):
         """Prompt-phase step: the training forward's attention math over
@@ -490,9 +426,9 @@ class LlamaBlock(nn.Layer):
         x = x + self.mlp(self.post_attention_layernorm(x))
         return x
 
-    def decode(self, x, cache, pos, block_tables=None):
+    def decode(self, x, cache, pos):
         a, cache = self.self_attn.decode(self.input_layernorm(x), cache,
-                                         pos, block_tables=block_tables)
+                                         pos)
         x = x + a
         x = x + self.mlp(self.post_attention_layernorm(x))
         return x, cache
@@ -503,19 +439,9 @@ class LlamaBlock(nn.Layer):
         x = x + self.mlp(self.post_attention_layernorm(x))
         return x, cache
 
-    def prefill_chunk(self, x, cache, block_tables, chunk_start,
-                      valid_len):
-        a, cache = self.self_attn.prefill_chunk(
-            self.input_layernorm(x), cache, block_tables, chunk_start,
-            valid_len)
-        x = x + a
-        x = x + self.mlp(self.post_attention_layernorm(x))
-        return x, cache
-
-    def decode_chunk(self, x, cache, block_tables, start, valid_len):
-        a, cache = self.self_attn.decode_chunk(
-            self.input_layernorm(x), cache, block_tables, start,
-            valid_len)
+    def paged_step(self, x, cache, block_tables, start, valid_len=None):
+        a, cache = self.self_attn.paged_step(
+            self.input_layernorm(x), cache, block_tables, start, valid_len)
         x = x + a
         x = x + self.mlp(self.post_attention_layernorm(x))
         return x, cache
@@ -565,50 +491,30 @@ class LlamaModel(nn.Layer):
                                                dtype)
                 for blk in self.layers]
 
-    def decode_step(self, tok, caches, pos, block_tables=None):
-        """tok: [B, 1] ids; pos: traced position — a scalar, or a [B]
-        vector for slot-wise serving decode. With block_tables [B, nblk]
-        the caches are block POOLS (paged serving engine). Returns
-        (h, caches)."""
+    def decode_step(self, tok, caches, pos):
+        """One token a row on the dense caches. tok: [B, 1] ids; pos:
+        traced position — a scalar, or a [B] vector for slot-wise
+        serving decode. Returns (h, caches)."""
         from ..framework.tensor import Tensor
         pos = pos._data if isinstance(pos, Tensor) else pos
         x = self.embed_tokens(tok)
         new_caches = []
         for blk, cache in zip(self.layers, caches):
-            x, cache = blk.decode(x, cache, pos,
-                                  block_tables=block_tables)
+            x, cache = blk.decode(x, cache, pos)
             new_caches.append(cache)
         return self.norm(x), new_caches
 
-    def prefill_chunk(self, tok_chunk, caches, block_tables, chunk_start,
-                      valid_len):
-        """One prompt chunk [1, C] ids at absolute positions chunk_start
-        + arange(C) against the block pools (chunked prefill)."""
-        x = self.embed_tokens(tok_chunk)
-        new_caches = []
-        for blk, cache in zip(self.layers, caches):
-            x, cache = blk.prefill_chunk(x, cache, block_tables,
-                                         chunk_start, valid_len)
-            new_caches.append(cache)
-        return self.norm(x), new_caches
-
-    def decode_chunk(self, tok_chunk, caches, block_tables, start,
-                     valid_len):
-        """Speculative verify: C tokens per lane ([S, C] ids) at
-        per-lane absolute positions start[s] + i against the block
-        pools. Returns (h [S, C, Hd], caches)."""
+    def paged_step(self, tok, caches, block_tables, start, valid_len=None):
+        """[B, C] ids at absolute positions start + arange(C) against the
+        block pools (see LlamaAttention.paged_step for the three widths
+        the paged engines run it at). Returns (h [B, C, H], caches)."""
         from ..framework.tensor import Tensor
-        block_tables = (block_tables._data
-                        if isinstance(block_tables, Tensor)
-                        else block_tables)
         start = start._data if isinstance(start, Tensor) else start
-        valid_len = (valid_len._data if isinstance(valid_len, Tensor)
-                     else valid_len)
-        x = self.embed_tokens(tok_chunk)
+        x = self.embed_tokens(tok)
         new_caches = []
         for blk, cache in zip(self.layers, caches):
-            x, cache = blk.decode_chunk(x, cache, block_tables, start,
-                                        valid_len)
+            x, cache = blk.paged_step(x, cache, block_tables, start,
+                                      valid_len)
             new_caches.append(cache)
         return self.norm(x), new_caches
 
@@ -637,7 +543,16 @@ class LlamaForCausalLM(nn.Layer):
                     initializer=I.Normal(0.0, cfg.initializer_range)))
             self.lm_head.weight.sharding = P(None, mesh_mod.MP_AXIS)
 
-    def _logits(self, hidden):
+    def _logits(self, hidden, frontier=None):
+        """Logits of hidden [B, S, H]; frontier (traced index): of that
+        one position only, [B, 1, V] — the serving engines want ONE
+        next-token row, and indexing before the LM head keeps the vocab
+        matmul [1, V] instead of [S, V] (S = padded bucket or chunk)."""
+        if frontier is not None:
+            from ..framework.tensor import Tensor
+            hr = hidden._data if isinstance(hidden, Tensor) else hidden
+            hidden = Tensor(jax.lax.dynamic_slice_in_dim(hr, frontier, 1,
+                                                         axis=1))
         if self.cfg.tie_embeddings:
             w = self.model.embed_tokens.weight
             from ..ops.math import matmul
@@ -668,49 +583,32 @@ class LlamaForCausalLM(nn.Layer):
                                            max_len, dtype)
 
     def decode_step(self, tok, caches, pos, block_tables=None):
-        h, caches = self.model.decode_step(tok, caches, pos,
-                                           block_tables=block_tables)
-        return self._logits(h), caches
-
-    def decode_chunk(self, tok_chunk, caches, block_tables, start,
-                     valid_len):
-        """Speculative verify: logits for ALL C positions of every lane
-        ([S, C, V] — the k+1-proportional head cost the verify program
-        pays on purpose: one batched forward scores the whole drafted
-        span)."""
-        h, caches = self.model.decode_chunk(tok_chunk, caches,
-                                            block_tables, start,
-                                            valid_len)
+        """One token a row: tok [B, 1] at pos (a scalar, or [B]). On the
+        dense caches; given block_tables [B, nblk] the caches are block
+        POOLS and the step is a chunk of one (the paged decode wave).
+        The one place that chooses between the two."""
+        if block_tables is not None:
+            return self.prefill_chunk(tok, caches, block_tables, pos, None)
+        h, caches = self.model.decode_step(tok, caches, pos)
         return self._logits(h), caches
 
     def prefill_chunk(self, tok_chunk, caches, block_tables, chunk_start,
                       valid_len, frontier=None):
-        """One prompt chunk against the block pools; frontier (traced
-        index within the chunk) keeps the vocab matmul [1, V] — only the
-        final chunk's frontier row is consumed by the serving engine."""
-        from ..framework.tensor import Tensor
-        h, caches = self.model.prefill_chunk(tok_chunk, caches,
-                                             block_tables, chunk_start,
-                                             valid_len)
-        if frontier is not None:
-            hr = h._data if isinstance(h, Tensor) else h
-            h = Tensor(jax.lax.dynamic_slice_in_dim(hr, frontier, 1,
-                                                    axis=1))
-        return self._logits(h), caches
+        """[B, C] ids a lane against the block pools, at absolute
+        positions chunk_start + arange(C) (chunk_start and valid_len:
+        scalars or [B]): a prompt chunk of one lane, or every lane's
+        k + 1 drafted tokens in the speculative verify wave, whose
+        logits for ALL C positions ([S, C, V]) are the cost that program
+        pays on purpose. frontier: see _logits; only the final chunk's
+        frontier row is consumed by the serving engine."""
+        h, caches = self.model.paged_step(tok_chunk, caches, block_tables,
+                                          chunk_start, valid_len)
+        return self._logits(h, frontier), caches
 
     def prefill(self, input_ids, max_len, dtype=jnp.float32,
                 frontier=None):
-        """frontier (traced index): return logits only for that prompt
-        position — the serving engine wants ONE next-token row, and
-        indexing before the LM head keeps the vocab matmul [1, V]
-        instead of [P, V] (P = padded bucket)."""
-        from ..framework.tensor import Tensor
         h, caches = self.model.prefill(input_ids, max_len, dtype)
-        if frontier is not None:
-            hr = h._data if isinstance(h, Tensor) else h
-            h = Tensor(jax.lax.dynamic_slice_in_dim(hr, frontier, 1,
-                                                    axis=1))
-        return self._logits(h), caches
+        return self._logits(h, frontier), caches
 
 
 def llama_pretrain_loss(logits, labels):

@@ -283,7 +283,7 @@ class NemotronHAttention(_Block):
         self.o_proj = self._matrix(self.nh * self.hd, cfg.hidden_size)
 
     def init_paged_cache(self, num_blocks, block_size, dtype):
-        from ..nn.transformer import init_block_kv
+        from ..nn.paged_attention import init_block_kv
         return init_block_kv(num_blocks, self.nkv, block_size, self.hd,
                              dtype)
 
@@ -309,22 +309,14 @@ class NemotronHAttention(_Block):
         return (o.reshape(*x.shape[:2], -1).astype(x.dtype)
                 @ self.o_proj._data)
 
-    def decode(self, x, cache, pos, tables):
-        from ..nn.paged_attention import paged_decode_attention
-        from ..nn.transformer import write_block_kv
+    def paged_step(self, x, cache, tables, start, valid_len=None):
+        """x [B, C, hidden] at positions start + arange(C) against the
+        block pool: one token a lane in the wave (C == 1), a chunk of
+        one lane's prompt (B == 1; nothing written past valid_len)."""
+        from ..nn.paged_attention import paged_attend
         q, k, v = (jnp.swapaxes(t, 1, 2) for t in self._qkv(x))
-        cache = write_block_kv(cache, k, v, tables, pos)
-        o = paged_decode_attention(q, cache, tables, pos,
-                                   1.0 / math.sqrt(self.hd))
-        return self._out(o, x.dtype), cache
-
-    def prefill_chunk(self, x, cache, tables, chunk_start, valid_len):
-        from ..nn.paged_attention import paged_chunk_attention
-        from ..nn.transformer import write_block_kv
-        q, k, v = (jnp.swapaxes(t, 1, 2) for t in self._qkv(x))
-        cache = write_block_kv(cache, k, v, tables, chunk_start, valid_len)
-        o = paged_chunk_attention(q, cache, tables, chunk_start,
-                                  1.0 / math.sqrt(self.hd))
+        o, cache = paged_attend(q, k, v, cache, tables, start, valid_len,
+                                1.0 / math.sqrt(self.hd))
         return self._out(o, x.dtype), cache
 
 
@@ -519,7 +511,7 @@ class NemotronHForCausalLM(nn.Layer):
                                                 cache["conv"], active)
                 return out, {"ssm": ssm, "conv": conv}
             if blk.kind == "*":
-                return blk.mixer.decode(x, cache, pos, tables)
+                return blk.mixer.paged_step(x, cache, tables, pos)
             return blk.mixer(x), cache
 
         x, caches = self._run(tok, caches, call)
@@ -545,8 +537,8 @@ class NemotronHForCausalLM(nn.Layer):
                         cache["conv"], conv.astype(cache["conv"].dtype),
                         slot, 0)}
             if blk.kind == "*":
-                return blk.mixer.prefill_chunk(x, cache, tables,
-                                               chunk_start, valid_len)
+                return blk.mixer.paged_step(x, cache, tables, chunk_start,
+                                            valid_len)
             return blk.mixer(x), cache
 
         x, caches = self._run(tok_chunk, caches, call)

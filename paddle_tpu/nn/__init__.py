@@ -34,8 +34,7 @@ from .loss import (CTCLoss,
 from .clip import ClipGradByValue, ClipGradByNorm, ClipGradByGlobalNorm
 from . import transformer
 from . import paged_attention
-from .paged_attention import (paged_chunk_attention,
-                              paged_decode_attention, set_paged_kernel)
+from .paged_attention import paged_attend
 from .transformer import (MultiHeadAttention, TransformerEncoderLayer,
                           TransformerEncoder, TransformerDecoderLayer,
                           TransformerDecoder, Transformer)

@@ -1,103 +1,72 @@
-"""Fused paged-attention kernel family: gather + attend over the block
-pools in one pass.
+"""The paged K/V cache: the block pool's stored form, its one write, and
+attention over it through the block tables.
 
-The paged serving paths (decode wave, spec draft wave, spec verify,
-prefill chunk) historically read the KV cache in two steps:
-`gather_block_kv` materialised a `[B, Hkv, nblk*BS, D]` copy of every
-lane's blocks, then `cached_decode_attention`/`chunk_attention`
-consumed it. That intermediate is a full extra HBM round-trip over the
-cache per layer per wave — exactly the memory-intensive op class the
-operator-fusion literature (PAPERS.md: "Operator Fusion in XLA",
-"FusionStitching") shows XLA's default fusion will not stitch away.
+This is the one module that knows how a layer's pool is laid out. A
+model file calls `paged_attend` (write the new positions' K/V through
+the tables, then attend the new queries over the pool) and names no
+shape of the pool; the serving engines allocate it through
+`init_block_kv` and pass it through their programs donated.
 
-This module replaces the pair with kernels that read K/V *directly out
-of the per-layer block pool through the block table* using an online
-(streaming) softmax over blocks — the `[B, Hkv, nblk*BS, D]` gathered
-view never exists. Three interchangeable implementations sit behind one
-dispatch point:
+Two cores attend, behind one dispatch point:
 
-  kernel="reference"  the original gather-then-attend pair, kept as the
-                      selectable parity oracle (bitwise the pre-fusion
-                      program);
-  kernel="lax"        a lax.fori_loop over blocks carrying the
-                      flash-attention recurrence (running max m, denom
-                      l, weighted accumulator); works on every backend;
-  kernel="pallas"     a Pallas TPU kernel — grid over (lanes, groups of
-                      kv-heads, steps of pages). A step takes whole
-                      pages as the pool stores them (the [heads, BS, 2D]
-                      slab of every kv-head of the group) through the
-                      BlockSpec index_map over a scalar-prefetch table,
-                      and only the pages the lane attends
-                      (`attended_pages`, from its position, the chunk
-                      and the window): a step outside them names the
-                      page it already holds, so it moves no bytes, and
-                      skips the arithmetic. Accumulators sit in VMEM
-                      scratch across the sequential step dimension.
-                      How many kv-heads and pages share a step follows
-                      the shapes of the call (`_tile`): all heads and
-                      several pages in the decode form, where a pass
-                      through the recurrence costs the same however
-                      little it scores, so one pass scores them all; a
-                      few heads and one page in a 128-token chunk,
-                      where the query tile fills VMEM. `interpret=True`
-                      on CPU so tier-1 exercises the real kernel body;
-                      the body keeps every value 2-D, which is what the
-                      TPU compiler accepts (tests/test_tpu_compile.py
-                      compiles it for v5e at the cells' real shapes).
-  kernel="auto"       "pallas" on TPU, "lax" elsewhere. An explicit
-                      "pallas" reaches the compiler as is: nothing here
-                      catches a refusal or falls back.
+  kernel="reference"  gather the lanes' pages into a `[B, Hkv, nblk*BS,
+                      D]` view (`gather_block_kv`), then a plain masked
+                      softmax over it: plain XLA, runs anywhere, and the
+                      oracle every test holds the kernel to.
+  kernel="pallas"     a Pallas TPU kernel that reads K/V straight out of
+                      the pool through the table with an online softmax,
+                      so the gathered view never exists. Grid over
+                      (lanes, groups of kv-heads, steps of pages). A step
+                      takes whole pages as the pool stores them (the
+                      [heads, BS, 2D] slab of every kv-head of the group)
+                      through the BlockSpec index_map over a
+                      scalar-prefetch table, and only the pages the lane
+                      attends (`attended_pages`, from its position, the
+                      chunk and the window): a step outside them names
+                      the page it already holds, so it moves no bytes,
+                      and skips the arithmetic. Accumulators sit in VMEM
+                      scratch across the sequential step dimension. How
+                      many kv-heads and pages share a step follows the
+                      shapes of the call (`_tile`): all heads and several
+                      pages in the decode form, where a pass through the
+                      recurrence costs the same however little it scores,
+                      so one pass scores them all; a few heads and one
+                      page in a 128-token chunk, where the query tile
+                      fills VMEM. `interpret=True` off the TPU so tier-1
+                      exercises the real kernel body; the body keeps
+                      every value 2-D, which is what the TPU compiler
+                      accepts (tests/test_tpu_compile.py compiles it for
+                      v5e at the cells' real shapes).
 
-The pool's stored form (every core, every model): one array a layer,
-`[NB, Hkv, BS, 2D]`, a position's K row in `[..., :D]` and its V row
-beside it in `[..., D:]` (`nn.transformer.write_block_kv` writes it and
-says why). A core takes that array as it is stored; the Pallas core
-fetches a page's K and V as one slab and never slices it (see
-`_paged_attn_kernel`).
+Which core a call gets (`resolve_kernel`): an explicit `kernel=`
+argument, else the innermost active `kernel_scope(...)` (how a serving
+engine pins, at trace time, the core it was built with), else what the
+backend decides: "pallas" on a TPU, "reference" anywhere else. No
+environment variable and no process-wide setting choose; an explicit
+"pallas" reaches the compiler as is, and nothing here catches a refusal
+or falls back. What the chip measured of the cores is in PERF.md.
 
-Both serving attention shapes are covered: the decode form (one query
-per lane; replaces gather+`cached_decode_attention` in the decode and
-spec-draft waves) and the chunked form (C queries at per-lane offsets;
-replaces gather+`chunk_attention` in `prefill_chunk` and the spec
-verify wave). Decode is the C == 1 case of the chunk recurrence, but
-keeps its own entry point so the xprof registry can track the two cores
-as distinct programs.
+One attention shape covers every paged program: C queries a lane at
+absolute positions `start + i`. The decode wave is C == 1, the prefill
+chunk one lane of C == chunk, the speculative verify wave every lane at
+C == k + 1.
 
-Masking contract (the `-1e9` wart fixed): masked/out-of-window scores
-are hard-excluded with `-inf` *before* the max/exp, and fully-masked
-rows (all-scratch lanes, padded chunk tails) renormalise through a
-guarded `where(l == 0, 0, acc / l)` instead of softmaxing over a
-uniform `-1e9` row. Scratch-block garbage — which may be non-finite — therefore
-cannot reach the engines' isfinite poison sentinel, while a genuine
-non-finite value at any *attended* position still propagates to the
-logits exactly as before.
-
-Dispatch resolution order for kernel=None: the innermost active
-`kernel_scope(...)` (how the serving engines pin the kernel they were
-built with at trace time) > the `PT_PAGED_KERNEL` environment variable
-> the module default from `set_paged_kernel` > "auto".
+Masking contract: masked/out-of-window scores are hard-excluded with
+`-inf` *before* the max/exp, and fully-masked rows (all-scratch lanes,
+padded chunk tails) renormalise through a guarded `where(l == 0, 0,
+acc / l)` instead of softmaxing over a uniform large-negative row.
+Scratch-block garbage — which may be non-finite — therefore cannot reach
+the engines' isfinite poison sentinel, while a genuine non-finite value
+at any *attended* position still propagates to the logits.
 """
 import contextlib
 import functools
-import os
 
 import numpy as np
 
-KERNELS = ("auto", "reference", "lax", "pallas")
+KERNELS = ("reference", "pallas")
 
-_DEFAULT_KERNEL = "auto"
 _SCOPE_STACK = []           # innermost kernel_scope override, LIFO
-
-
-def set_paged_kernel(kernel):
-    """Set the process-wide default paged-attention kernel."""
-    global _DEFAULT_KERNEL
-    _DEFAULT_KERNEL = _check(kernel)
-
-
-def get_paged_kernel():
-    """The unresolved process default (may be "auto")."""
-    return _DEFAULT_KERNEL
 
 
 def _check(kernel):
@@ -110,10 +79,10 @@ def _check(kernel):
 @contextlib.contextmanager
 def kernel_scope(kernel):
     """Pin the kernel inside a `with` block. The serving engines trace
-    their jitted programs inside this scope, so the engine's configured
-    kernel wins over the process default no matter which thread or
-    engine traced first (tracing runs the Python body; the compiled
-    program keeps whatever the scope resolved)."""
+    their jitted programs inside this scope, so the core an engine was
+    built with is the one its programs keep, whichever thread or engine
+    traced first (tracing runs the Python body; the compiled program
+    keeps whatever the scope resolved)."""
     _SCOPE_STACK.append(_check(kernel))
     try:
         yield
@@ -122,148 +91,195 @@ def kernel_scope(kernel):
 
 
 def resolve_kernel(kernel=None):
-    """Resolve to a concrete implementation name ("reference" | "lax" |
-    "pallas"). Resolution order: explicit argument > innermost
-    kernel_scope > PT_PAGED_KERNEL env > set_paged_kernel default; an
-    "auto" at any level falls through to backend selection (pallas on
-    TPU, lax elsewhere)."""
-    choice = None
+    """The core a call gets: the explicit argument, else the innermost
+    kernel_scope, else "pallas" on a TPU and "reference" elsewhere."""
     if kernel is not None:
-        choice = _check(kernel)
-    elif _SCOPE_STACK:
-        choice = _SCOPE_STACK[-1]
-    else:
-        env = os.environ.get("PT_PAGED_KERNEL", "").strip().lower()
-        if env:
-            choice = _check(env)
-        else:
-            choice = _DEFAULT_KERNEL
-    if choice != "auto":
-        return choice
+        return _check(kernel)
+    if _SCOPE_STACK:
+        return _SCOPE_STACK[-1]
     import jax
-    return "pallas" if jax.default_backend() == "tpu" else "lax"
+    return "pallas" if jax.default_backend() == "tpu" else "reference"
+
+
+# ---------------------------------------------------------------------------
+# the pool's stored form, and its one write
+# ---------------------------------------------------------------------------
+# The pool is ONE array a layer, [num_blocks, Hkv, block_size, 2 * D]: a
+# position's K row in [..., :D] and its V row beside it in [..., D:]. A
+# request's cache is the ordered sequence of pool blocks (pages) named by
+# its block TABLE (int32 block ids, host-managed by
+# serving.paged.BlockPool). All shapes below are static — table entries
+# are VALUES, not shapes — so one compiled program serves every
+# allocation pattern (compile-once). Block 0 is the scratch block:
+# inactive/invalid lanes are redirected there; nothing in it is kept
+# (every write zeroes it) and no surviving lane reads it at a position
+# it attends (the ks <= pos mask and the active-lane `where`).
+#
+# Why this form, and who has to keep it. A serving program is handed the
+# pool donated and hands it back; it stays where it is only if the write,
+# the attention kernel and the program's parameter and result all take
+# one layout. Row-major [.., BS, 2D] is that layout: 2D is 128 lanes at
+# head_dim 64 and 256 at 128, so a page of one kv-head is whole (16, 128)
+# bf16 tiles with nothing padded, which is what the chip picks for the
+# parameter by itself and what the Pallas core's BlockSpec takes; and the
+# write below moves whole pages, which the compiler updates in place in
+# that layout (a scatter of single rows, `pool.at[blk, :, row].set`, is
+# given a layout with the row dimension outermost, and the whole pool is
+# copied there and back: PERF.md, PR 28). Every program that touches the
+# pool (decode wave, prefill chunk, draft and verify waves, copy-on-write,
+# hand-off, state reset) takes and returns this array as it is;
+# tests/test_tpu_compile.py holds the serving programs to no pool-sized
+# copy at the benchmark's shapes.
+
+
+def init_block_kv(num_blocks, hkv, block_size, head_dim, dtype):
+    """A layer's empty pool in the stored form (see above)."""
+    import jax.numpy as jnp
+    return jnp.zeros((num_blocks, hkv, block_size, 2 * head_dim), dtype)
+
+
+def gather_block_kv(pool, tables):
+    """Materialise per-row K and V views from the block pool. pool:
+    [NB, Hkv, BS, 2D]; tables: [B, nblk] int32 → two [B, Hkv, nblk*BS, D],
+    position p of row b living at pool[tables[b, p // BS], :, p % BS].
+    One gather — the paged analog of reading the dense [B, Hkv, L, D]
+    cache (same bytes streamed when nblk*BS == L)."""
+    import jax.numpy as jnp
+    g = pool[tables]                           # [B, nblk, Hkv, BS, 2D]
+    b, nblk, hkv, bs, d2 = g.shape
+    g = jnp.transpose(g, (0, 2, 1, 3, 4)).reshape(b, hkv, nblk * bs, d2)
+    return g[..., :d2 // 2], g[..., d2 // 2:]
+
+
+def write_block_kv(pool, k, v, tables, start, valid_len=None):
+    """Write C new positions a lane into the pool: k, v [S, Hkv, C, D]
+    land at absolute positions start[s] + i, i < valid_len[s], through
+    the block tables [S, nblk]; position p of lane s lives at
+    pool[tables[s, p // BS], :, p % BS] (K in [..., :D], V in [..., D:]).
+    `start` and `valid_len` are traced scalars or [S] vectors;
+    valid_len=None writes all C. The one write of every paged program:
+    the decode wave (C == 1), a prefill chunk (S == 1; the padded tail of
+    the last chunk lies past valid_len), the speculative verify wave
+    (every lane, its own start and span).
+
+    It moves whole pages. The C positions of a lane touch at most
+    ceil((C - 1) / BS) + 1 pages wherever they start; each is gathered,
+    the rows the lane writes are replaced, and the page is scattered
+    back, so a row outside [start, start + valid_len) keeps its bits. A
+    candidate page that holds no written row (a chunk that ends on a page
+    boundary, a lane with valid_len 0, a page past the table's end) is
+    redirected to the scratch block, as is every page of a retired lane
+    (the host points its table row there). Distinct lanes write distinct
+    pages — frontier pages are private by the copy-on-write guard — and
+    a prefill chunk that runs over prefix-shared pages rewrites in them
+    what they hold.
+
+    Every write also zeroes the scratch block. The queries of a padded
+    tail (i >= valid_len) are computed and thrown away, but they attend
+    keys past the lane's last written position, which the table maps to
+    scratch where the lane has no page yet; a non-finite value there
+    would reach the lane's good rows as 0 * nan in `p @ V` (the cores
+    zero only the rows that no query attends). So scratch is finite by
+    the time a program's first layer attends, whatever an earlier fault
+    left in it, and the colliding writes to it all carry the same
+    zeros."""
+    import jax.numpy as jnp
+    s, hkv, c, _ = k.shape
+    bs, nblk = pool.shape[2], tables.shape[1]
+    kv = jnp.concatenate([k, v], axis=-1).astype(pool.dtype)
+    start = jnp.broadcast_to(jnp.reshape(start, (-1,)), (s,))
+    valid = c if valid_len is None else jnp.minimum(
+        jnp.broadcast_to(jnp.reshape(valid_len, (-1,)), (s,)), c)
+    valid = jnp.reshape(valid, (-1, 1, 1))
+    npages = (c - 1 + bs - 1) // bs + 1
+    first = (start // bs)[:, None] + jnp.arange(npages)        # [S, np]
+    # row r of candidate page i holds the lane's new position number
+    # `src` (negative, or past valid_len: not this write's)
+    src = (first * bs - start[:, None])[:, :, None] + jnp.arange(bs)
+    # a padded tail or a clamped span can reach past the table: nothing
+    # is written there, and the table gather is clamped
+    mine = (src >= 0) & (src < valid) & (first < nblk)[:, :, None]
+    written = jnp.any(mine, axis=-1)                           # [S, np]
+    blk = jnp.take_along_axis(tables, jnp.minimum(first, nblk - 1), axis=1)
+    blk = jnp.where(written, blk, 0).reshape(-1)
+    new = jnp.take_along_axis(
+        kv[:, None], jnp.clip(src, 0, c - 1)[:, :, None, :, None], axis=3)
+    old = pool[blk].reshape(s, npages, hkv, bs, -1)
+    pages = jnp.where(mine[:, :, None, :, None], new, old)
+    # scratch gets zeros: from every page redirected there, from a
+    # retired lane's table row, and once more in case there is neither
+    pages = jnp.where((blk > 0).reshape(s, npages, 1, 1, 1), pages, 0)
+    pages = pages.reshape((s * npages,) + pool.shape[1:])
+    return pool.at[jnp.append(blk, 0)].set(
+        jnp.concatenate([pages, jnp.zeros_like(pages[:1])]))
 
 
 # ---------------------------------------------------------------------------
 # public entry points
 # ---------------------------------------------------------------------------
 
-def paged_decode_attention(q, pool, tables, pos, scale, window=None,
-                           kernel=None):
-    """Fused decode attention over the block pool. q: [B, H, 1, D];
-    pool: [NB, Hkv, BS, 2D] (K beside V, the stored form); tables:
-    [B, nblk] int32; pos a traced scalar or [B] vector of each lane's
-    current position (the query's own absolute position — keys at
-    ks <= pos are attended, banded to the last `window` when given).
-    Returns [B, H, 1, D] in pool.dtype.
-
-    Equivalent to gather_block_kv + cached_decode_attention without the
-    gathered [B, Hkv, nblk*BS, D] intermediate."""
-    k = resolve_kernel(kernel)
-    if k == "reference":
-        from .transformer import cached_decode_attention, gather_block_kv
-        # sanitize: the gathered view contains scratch-block positions
-        # (masked by construction) whose garbage may be non-finite
-        ck, cv = gather_block_kv(pool, tables)
-        return cached_decode_attention(q, ck, cv, pos, scale, window=window,
-                                       sanitize=True)
-    if k == "pallas":
-        return _pallas_core(q, pool, tables, pos, scale, window)
-    return _lax_core(q, pool, tables, pos, scale, window)
+def paged_attend(q, k, v, pool, tables, start, valid_len, scale,
+                 window=None, kernel=None):
+    """What a model's attention layer says to the paged cache: write the
+    C new positions' k, v [B, Hkv, C, D] through the tables (nothing at
+    i >= valid_len; None writes all C), then attend q [B, H, C, D] over
+    the pool, each query at its own absolute position start + i.
+    `start` and `valid_len` are traced scalars or [B] vectors. Returns
+    (out [B, H, C, D] in pool.dtype, the pool)."""
+    pool = write_block_kv(pool, k, v, tables, start, valid_len)
+    return attend(q, pool, tables, start, scale, window=window,
+                  kernel=kernel), pool
 
 
-def paged_chunk_attention(q, pool, tables, start, scale, window=None,
-                          kernel=None):
-    """Fused chunk attention over the block pool: C queries per lane at
+def attend(q, pool, tables, start, scale, window=None, kernel=None):
+    """Attention over the block pool as it stands: C queries a lane at
     absolute positions start + i (start: traced scalar or [B] vector).
-    q: [B, H, C, D]; pool/tables as in paged_decode_attention. Query
-    row i masks ks <= start + i (banded to the last `window` keys when
-    given). Returns [B, H, C, D] in pool.dtype.
-
-    Equivalent to gather_block_kv + chunk_attention without the
-    gathered intermediate; the decode form is the C == 1 case."""
-    k = resolve_kernel(kernel)
-    if k == "reference":
-        from .transformer import chunk_attention, gather_block_kv
-        ck, cv = gather_block_kv(pool, tables)
-        return chunk_attention(q, ck, cv, start, scale, window=window,
-                               sanitize=True)
-    if k == "pallas":
+    q: [B, H, C, D]; pool: [NB, Hkv, BS, 2D] (the stored form); tables:
+    [B, nblk] int32. Query row i attends the keys ks <= start + i, banded
+    to the last `window` when given. Returns [B, H, C, D] in pool.dtype."""
+    if resolve_kernel(kernel) == "pallas":
         return _pallas_core(q, pool, tables, start, scale, window)
-    return _lax_core(q, pool, tables, start, scale, window)
-
-
-def _query_positions(start, b, c):
-    """[B, C] int32 absolute position of every query row from a traced
-    scalar or [B] start vector."""
-    import jax.numpy as jnp
-    qpos = jnp.reshape(jnp.asarray(start), (-1, 1)) + jnp.arange(c)
-    return jnp.broadcast_to(qpos, (b, c)).astype(jnp.int32)
+    return _reference_core(q, pool, tables, start, scale, window)
 
 
 # ---------------------------------------------------------------------------
-# lax fallback: fori_loop over blocks, flash-attention recurrence
+# the oracle: gather the lanes' pages, then a plain masked softmax
 # ---------------------------------------------------------------------------
 
-def _lax_core(q, pool, tables, start, scale, window=None):
-    """Online-softmax attention streamed block-by-block out of the pool.
-
-    Carries (m, l, acc) across the nblk sequential steps: per block j
-    the lane's j-th pool block is fetched ([B, Hkv, BS, 2D] — the only
-    gathered working set that ever exists), scored against the queries,
-    masked with -inf at ks > qpos (and outside the window), and folded
-    into the running max/denominator/weighted-V with the standard
-    rescale alpha = exp(m_old - m_new). Fully-masked rows finish with
-    l == 0 and renormalise to exactly 0 via the guarded `where` — never
-    an average over scratch garbage."""
-    import jax
+def _reference_core(q, pool, tables, start, scale, window=None):
+    """Gather-then-attend over an L = nblk*BS position view of each
+    lane's pages (which already holds this call's own K/V). Grouped
+    (GQA) without materialising the repeated cache, exactly like the
+    dense `cached_decode_attention` (C == 1 of this is that function).
+    The gathered view contains scratch-block positions (masked by
+    construction) whose garbage may be non-finite, so the V rows NO query
+    attends are zeroed: a 0-probability key with non-finite garbage
+    would still produce 0 * nan == nan in the probs @ V contraction.
+    Keys attended by at least one query keep their value, so a GENUINE
+    non-finite at an attended position propagates to that lane's logits
+    (the poison sentinel); for finite caches this is bitwise a no-op."""
     import jax.numpy as jnp
+    from .transformer import _masked_softmax
 
+    ck, cv = gather_block_kv(pool, tables)
     b, h, c, d = q.shape
-    hkv, bs = pool.shape[1], pool.shape[2]
-    nblk = tables.shape[1]
+    hkv, L = ck.shape[1], ck.shape[2]
     rep = h // hkv
     qf = q.astype(jnp.float32).reshape(b, hkv, rep, c, d)
-    qpos = _query_positions(start, b, c)               # [B, C]
-    neg_inf = jnp.float32(-jnp.inf)
-
-    def body(j, carry):
-        m, l, acc = carry
-        blk = tables[:, j]                             # [B]
-        page = pool[blk].astype(jnp.float32)           # [B, Hkv, BS, 2D]
-        kblk, vblk = page[..., :d], page[..., d:]
-        s = jnp.einsum("bkrcd,bksd->bkrcs", qf, kblk) * scale
-        ks = j * bs + jnp.arange(bs)                   # absolute keys
-        keep = ks[None, None, :] <= qpos[:, :, None]   # [B, C, BS]
-        if window is not None:
-            keep &= ks[None, None, :] > qpos[:, :, None] - window
-        # keys no query of the lane attends contribute with probability
-        # exactly 0 — but 0 * nan == nan, so zero those V rows outright
-        # (scratch-block poison must not leak; an attended non-finite
-        # still propagates, keeping the engines' isfinite sentinel live)
-        vblk = jnp.where(jnp.any(keep, axis=1)[:, None, :, None],
-                         vblk, 0.0)
-        keep = keep[:, None, None, :, :]               # [B,1,1,C,BS]
-        s = jnp.where(keep, s, neg_inf)
-        m_new = jnp.maximum(m, jnp.max(s, axis=-1))
-        # all-masked-so-far rows carry m == -inf; shifting by 0 keeps
-        # exp(-inf) == 0 without manufacturing inf - inf NaNs
-        shift = jnp.where(jnp.isfinite(m_new), m_new, 0.0)
-        p = jnp.exp(s - shift[..., None])
-        alpha = jnp.exp(m - shift)
-        l_new = alpha * l + jnp.sum(p, axis=-1)
-        acc_new = alpha[..., None] * acc + \
-            jnp.einsum("bkrcs,bksd->bkrcd", p, vblk)
-        return m_new, l_new, acc_new
-
-    m0 = jnp.full((b, hkv, rep, c), neg_inf)
-    l0 = jnp.zeros((b, hkv, rep, c), jnp.float32)
-    acc0 = jnp.zeros((b, hkv, rep, c, d), jnp.float32)
-    m, l, acc = jax.lax.fori_loop(0, nblk, body, (m0, l0, acc0))
-    # guard on == 0, not > 0: a nan denominator (genuine attended
-    # fault) must divide through and propagate, not silently zero
-    out = jnp.where(l[..., None] == 0, 0.0, acc / l[..., None])
-    return out.reshape(b, h, c, d).astype(pool.dtype)
+    scores = jnp.einsum("bkrcd,bkld->bkrcl", qf,
+                        ck.astype(jnp.float32)) * scale
+    if jnp.ndim(start):
+        start = jnp.reshape(start, (b, 1, 1, 1, 1))
+    qpos = start + jnp.arange(c).reshape(1, 1, 1, c, 1)
+    ks = jnp.arange(L).reshape(1, 1, 1, 1, L)
+    mask = ks <= qpos
+    if window is not None:
+        mask = mask & (ks > qpos - window)
+    probs = _masked_softmax(scores, mask).astype(cv.dtype)
+    attended = jnp.any(mask, axis=3)[:, 0, 0, :, None]     # [B, L, 1]
+    cv = jnp.where(attended[:, None], cv, jnp.zeros((), cv.dtype))
+    out = jnp.einsum("bkrcl,bkld->bkrcd", probs, cv)
+    return out.reshape(b, h, c, d)
 
 
 # ---------------------------------------------------------------------------
@@ -478,9 +494,11 @@ def _pallas_call(b, h, c, d2, hkv, bs, nblk, heads, pages, scale, window,
 
 
 def _pallas_core(q, pool, tables, start, scale, window=None):
-    """Pallas path: same recurrence as _lax_core, with the block gather
-    folded into the kernel pipeline. interpret=True on CPU so tier-1
-    parity tests execute the genuine kernel body."""
+    """Pallas path: an online softmax over the lane's pages (running
+    max m, denominator l, weighted accumulator, rescaled by
+    exp(m_old - m_new) a step), the block gather folded into the kernel
+    pipeline. interpret=True off the TPU so tier-1 parity tests execute
+    the genuine kernel body."""
     import jax
     import jax.numpy as jnp
 

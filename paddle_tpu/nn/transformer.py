@@ -376,21 +376,17 @@ def _clone_layer(layer):
     return type(layer)(**layer._config)
 
 
-def cached_decode_attention(q, ck, cv, pos, scale, window=None,
-                            sanitize=False):
+def cached_decode_attention(q, ck, cv, pos, scale, window=None):
     """Single-token cached attention core shared by the GPT and LLaMA
-    decoders. q: [B, H, 1, D]; ck/cv: [B, Hkv, L, D] with H % Hkv == 0 —
-    grouped (GQA) when H > Hkv, WITHOUT materialising the repeated cache:
-    q is reshaped to [B, Hkv, rep, D] and contracted against the
-    un-repeated KV buffers. window=W restricts to the last W cache
-    positions (sliding-window decode matching the training band).
+    dense decoders. q: [B, H, 1, D]; ck/cv: [B, Hkv, L, D] with
+    H % Hkv == 0 — grouped (GQA) when H > Hkv, WITHOUT materialising the
+    repeated cache: q is reshaped to [B, Hkv, rep, D] and contracted
+    against the un-repeated KV buffers. window=W restricts to the last W
+    cache positions (sliding-window decode matching the training band).
     `pos` is a traced scalar (lockstep batch) or a [B] vector — the
     slot-wise serving case where every row sits at its own depth; the
     causal mask broadcasts per-row. Returns [B, H, 1, D] in cv.dtype.
-    sanitize=True additionally zeroes V rows no query attends — needed
-    when the cache view contains scratch-block garbage that may be
-    non-finite (the paged reference path); the dense path skips the
-    extra elementwise pass over the cache."""
+    (The paged cache's cores are in nn/paged_attention.py.)"""
     import jax
     import jax.numpy as jnp
 
@@ -407,8 +403,6 @@ def cached_decode_attention(q, ck, cv, pos, scale, window=None,
     if window is not None:
         mask = mask & (ks > pos - window)
     probs = _masked_softmax(scores, mask).astype(cv.dtype)
-    if sanitize:
-        cv = _sanitize_unattended(cv, mask[:, 0, 0, :, None])
     out = jnp.einsum("bkrl,bkld->bkrd", probs, cv)
     return out.reshape(b, h, 1, d)
 
@@ -436,21 +430,6 @@ def _masked_softmax(scores, mask):
     return jnp.where(denom == 0, 0.0, e / denom)
 
 
-def _sanitize_unattended(cv, attended):
-    """Zero the V rows NO query attends (attended: [B, L] broadcastable
-    against cv [B, Hkv, L, D], any-reduced over the query axes by the
-    caller). A 0-probability key with non-finite garbage would still
-    produce 0 * nan == nan in the probs @ V contraction — scratch-block
-    poison leaking past the mask. Keys attended by at least one query
-    keep their value, so a GENUINE non-finite at an attended position
-    propagates to that lane's logits (the poison sentinel) exactly as
-    before; for finite caches this is bitwise a no-op (0 * v == 0 * 0)."""
-    import jax.numpy as jnp
-    b = attended.shape[0]
-    return jnp.where(jnp.reshape(attended, (b, 1) + attended.shape[1:]),
-                     cv, jnp.zeros((), cv.dtype))
-
-
 def scatter_kv_at(cache, kv_t, pos):
     """Write the step's K or V [B, Hkv, 1, D] into cache [B, Hkv, L, D]
     at a per-row position vector pos [B] (slot-wise decode: each serving
@@ -461,154 +440,3 @@ def scatter_kv_at(cache, kv_t, pos):
     return jax.vmap(
         lambda c, t, p: jax.lax.dynamic_update_slice_in_dim(
             c, t, p, axis=1))(cache, kv_t.astype(cache.dtype), pos)
-
-
-# ---------------------------------------------------------------------------
-# paged KV cache primitives (serving/paged: block-table memory manager)
-# ---------------------------------------------------------------------------
-# The pool is ONE array a layer, [num_blocks, Hkv, block_size, 2 * D]: a
-# position's K row in [..., :D] and its V row beside it in [..., D:]. A
-# request's cache is the ordered sequence of pool blocks (pages) named by
-# its block TABLE (int32 block ids, host-managed by
-# serving.paged.BlockPool). All shapes below are static — table entries
-# are VALUES, not shapes — so one compiled program serves every
-# allocation pattern (compile-once). Block 0 is the scratch block:
-# inactive/invalid lanes are redirected there; nothing in it is kept
-# (every write zeroes it) and no surviving lane reads it at a position
-# it attends (the ks <= pos mask and the active-lane `where`).
-#
-# Why this form, and who has to keep it. A serving program is handed the
-# pool donated and hands it back; it stays where it is only if the write,
-# the attention kernel and the program's parameter and result all take
-# one layout. Row-major [.., BS, 2D] is that layout: 2D is 128 lanes at
-# head_dim 64 and 256 at 128, so a page of one kv-head is whole (16, 128)
-# bf16 tiles with nothing padded, which is what the chip picks for the
-# parameter by itself and what the Pallas core's BlockSpec takes; and the
-# write below moves whole pages, which the compiler updates in place in
-# that layout (a scatter of single rows, `pool.at[blk, :, row].set`, is
-# given a layout with the row dimension outermost, and the whole pool is
-# copied there and back: PERF.md, PR 28). Every program that touches the
-# pool (decode wave, prefill chunk, draft and verify waves, copy-on-write,
-# hand-off, state reset) takes and returns this array as it is;
-# tests/test_tpu_compile.py holds the serving programs to no pool-sized
-# copy at the benchmark's shapes.
-
-
-def init_block_kv(num_blocks, hkv, block_size, head_dim, dtype):
-    """A layer's empty pool in the stored form (see above)."""
-    import jax.numpy as jnp
-    return jnp.zeros((num_blocks, hkv, block_size, 2 * head_dim), dtype)
-
-
-def gather_block_kv(pool, tables):
-    """Materialise per-row K and V views from the block pool. pool:
-    [NB, Hkv, BS, 2D]; tables: [B, nblk] int32 → two [B, Hkv, nblk*BS, D],
-    position p of row b living at pool[tables[b, p // BS], :, p % BS].
-    One gather — the paged analog of reading the dense [B, Hkv, L, D]
-    cache (same bytes streamed when nblk*BS == L)."""
-    import jax.numpy as jnp
-    g = pool[tables]                           # [B, nblk, Hkv, BS, 2D]
-    b, nblk, hkv, bs, d2 = g.shape
-    g = jnp.transpose(g, (0, 2, 1, 3, 4)).reshape(b, hkv, nblk * bs, d2)
-    return g[..., :d2 // 2], g[..., d2 // 2:]
-
-
-def write_block_kv(pool, k, v, tables, start, valid_len=None):
-    """Write C new positions a lane into the pool: k, v [S, Hkv, C, D]
-    land at absolute positions start[s] + i, i < valid_len[s], through
-    the block tables [S, nblk]; position p of lane s lives at
-    pool[tables[s, p // BS], :, p % BS] (K in [..., :D], V in [..., D:]).
-    `start` and `valid_len` are traced scalars or [S] vectors;
-    valid_len=None writes all C. The one write of every paged program:
-    the decode wave (C == 1), a prefill chunk (S == 1; the padded tail of
-    the last chunk lies past valid_len), the speculative verify wave
-    (every lane, its own start and span).
-
-    It moves whole pages. The C positions of a lane touch at most
-    ceil((C - 1) / BS) + 1 pages wherever they start; each is gathered,
-    the rows the lane writes are replaced, and the page is scattered
-    back, so a row outside [start, start + valid_len) keeps its bits. A
-    candidate page that holds no written row (a chunk that ends on a page
-    boundary, a lane with valid_len 0, a page past the table's end) is
-    redirected to the scratch block, as is every page of a retired lane
-    (the host points its table row there). Distinct lanes write distinct
-    pages — frontier pages are private by the copy-on-write guard — and
-    a prefill chunk that runs over prefix-shared pages rewrites in them
-    what they hold.
-
-    Every write also zeroes the scratch block. The queries of a padded
-    tail (i >= valid_len) are computed and thrown away, but they attend
-    keys past the lane's last written position, which the table maps to
-    scratch where the lane has no page yet; a non-finite value there
-    would reach the lane's good rows as 0 * nan in `p @ V` (the cores
-    zero only the rows that no query attends). So scratch is finite by
-    the time a program's first layer attends, whatever an earlier fault
-    left in it, and the colliding writes to it all carry the same
-    zeros."""
-    import jax.numpy as jnp
-    s, hkv, c, _ = k.shape
-    bs, nblk = pool.shape[2], tables.shape[1]
-    kv = jnp.concatenate([k, v], axis=-1).astype(pool.dtype)
-    start = jnp.broadcast_to(jnp.reshape(start, (-1,)), (s,))
-    valid = c if valid_len is None else jnp.minimum(
-        jnp.broadcast_to(jnp.reshape(valid_len, (-1,)), (s,)), c)
-    valid = jnp.reshape(valid, (-1, 1, 1))
-    npages = (c - 1 + bs - 1) // bs + 1
-    first = (start // bs)[:, None] + jnp.arange(npages)        # [S, np]
-    # row r of candidate page i holds the lane's new position number
-    # `src` (negative, or past valid_len: not this write's)
-    src = (first * bs - start[:, None])[:, :, None] + jnp.arange(bs)
-    # a padded tail or a clamped span can reach past the table: nothing
-    # is written there, and the table gather is clamped
-    mine = (src >= 0) & (src < valid) & (first < nblk)[:, :, None]
-    written = jnp.any(mine, axis=-1)                           # [S, np]
-    blk = jnp.take_along_axis(tables, jnp.minimum(first, nblk - 1), axis=1)
-    blk = jnp.where(written, blk, 0).reshape(-1)
-    new = jnp.take_along_axis(
-        kv[:, None], jnp.clip(src, 0, c - 1)[:, :, None, :, None], axis=3)
-    old = pool[blk].reshape(s, npages, hkv, bs, -1)
-    pages = jnp.where(mine[:, :, None, :, None], new, old)
-    # scratch gets zeros: from every page redirected there, from a
-    # retired lane's table row, and once more in case there is neither
-    pages = jnp.where((blk > 0).reshape(s, npages, 1, 1, 1), pages, 0)
-    pages = pages.reshape((s * npages,) + pool.shape[1:])
-    return pool.at[jnp.append(blk, 0)].set(
-        jnp.concatenate([pages, jnp.zeros_like(pages[:1])]))
-
-
-def chunk_attention(q, ck, cv, start, scale, window=None,
-                    sanitize=False):
-    """Prefill-chunk attention core: C queries at absolute positions
-    start + i over an L-position KV view (the gathered paged cache,
-    which already contains this chunk's own K/V). q: [B, H, C, D];
-    ck/cv: [B, Hkv, L, D] with H % Hkv == 0 — grouped (GQA) without
-    materialising the repeated cache, exactly like
-    cached_decode_attention (C == 1 of this is that function). `start`
-    is a traced scalar or a [B] vector; each query row masks
-    ks <= start + i (banded to the last `window` keys when given), so a
-    chunk mid-prefill attends to every previous chunk's cached
-    positions plus its own causal prefix. Returns [B, H, C, D] in
-    cv.dtype. sanitize as in cached_decode_attention (paged gathered
-    views only)."""
-    import jax
-    import jax.numpy as jnp
-
-    b, h, c, d = q.shape
-    hkv, L = ck.shape[1], ck.shape[2]
-    rep = h // hkv
-    qf = q.astype(jnp.float32).reshape(b, hkv, rep, c, d)
-    scores = jnp.einsum("bkrcd,bkld->bkrcl", qf,
-                        ck.astype(jnp.float32)) * scale
-    if jnp.ndim(start):
-        start = jnp.reshape(start, (b, 1, 1, 1, 1))
-    qpos = start + jnp.arange(c).reshape(1, 1, 1, c, 1)
-    ks = jnp.arange(L).reshape(1, 1, 1, 1, L)
-    mask = ks <= qpos
-    if window is not None:
-        mask = mask & (ks > qpos - window)
-    probs = _masked_softmax(scores, mask).astype(cv.dtype)
-    if sanitize:
-        cv = _sanitize_unattended(
-            cv, jnp.any(mask, axis=3)[:, 0, 0, :, None])
-    out = jnp.einsum("bkrcl,bkld->bkrcd", probs, cv)
-    return out.reshape(b, h, c, d)

@@ -4,8 +4,7 @@ restarts.
 Same two-sink discipline as serving/metrics.py: the typed process-wide
 registry (docs/observability.md catalogs the names below) feeds
 /metrics, while a `FleetMetrics` instance aggregates per-router tallies
-for bench rows (`scripts/bench_serving.py --replicas` serializes
-`snapshot()` per load point).
+(`snapshot()`).
 """
 import threading
 

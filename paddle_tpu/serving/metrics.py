@@ -13,7 +13,8 @@ Two sinks, one recording path:
     kept so `monitor.all_stats()` callers see the same counters.
 
 `ServingMetrics.snapshot()` keys are byte-compatible with the PR-1
-shape (`scripts/bench_serving.py` serializes it unchanged).
+shape (`benchmark/kinds/_serving.py` hands every number of it to
+the benchmark's readers).
 """
 import threading
 

@@ -2,10 +2,10 @@
 wave machinery.
 
 The dense engine pays `num_slots * max_len` HBM per layer whatever the
-traffic actually holds; BENCH_serving.json put real occupancy at
-0.26–0.45 — most of that stream is padding. Here the cache is a fixed
-POOL of `[num_blocks, kv_heads, block_size, head_dim]` KV blocks per
-layer and slots reference block TABLES (host-managed int32 id rows,
+traffic actually holds, and most of that stream is padding (the batch
+cell's pool is a fifth live: PERF.md, `pool_live_share`). Here the cache
+is a fixed POOL of KV blocks per layer (`nn/paged_attention.py` owns its
+form) and slots reference block TABLES (host-managed int32 id rows,
 `serving.paged.BlockPool`): HBM scales with the blocks you configure,
 utilisation scales with actual tokens, and identical prompt prefixes
 dedupe onto shared blocks.
@@ -14,10 +14,9 @@ Still exactly TWO compiled programs, fully static shapes (the
 compile-once discipline — table entries are VALUES, not shapes):
 
   * decode wave — the dense wave plus one traced `[S, nblk]` block
-    table: each lane's K/V is written through its table row
-    (`nn/transformer.py write_block_kv`, whole pages, the pool in its
-    one stored form) and attention reads the pool through the table
-    (`nn/paged_attention.py`).
+    table: each lane's K/V is written through its table row and
+    attention reads the pool through the table (one call,
+    `nn/paged_attention.py paged_attend`).
   * prefill chunk — ONE fixed-size chunk of one slot's prompt at a
     traced absolute offset. Long prompts run chunk-by-chunk BETWEEN
     decode waves (the scheduler advances one chunk per round), so
@@ -88,11 +87,13 @@ SLOT_STATE_REFUSAL = (
 class PagedServingEngine(ServingEngine):
     """Block-table batched decode executor.
 
-    model: a causal LM exposing init_paged_cache / decode_step(...,
-        block_tables=) / prefill_chunk (GPTForPretraining,
-        LlamaForCausalLM). A model that declares `slot_state`
-        (NemotronHForCausalLM) keeps a fixed record a slot beside the
-        pages: its init_paged_cache takes `num_slots` and returns
+    model: a causal LM exposing init_paged_cache and the two paged
+        steps, decode_step(tok, caches, pos, block_tables=) and
+        prefill_chunk(tok_chunk, caches, block_tables, chunk_start,
+        valid_len, frontier=) (GPTForPretraining, LlamaForCausalLM). A
+        model that declares `slot_state` (NemotronHForCausalLM) keeps
+        a fixed record a slot beside the pages: its init_paged_cache
+        takes `num_slots` and returns
         {"kv": pools, "state": arrays with leading dimension num_slots},
         its prefill_chunk takes `slot`, its decode_step takes `active`.
         The engine zeroes a slot's record when the slot begins a prompt,
@@ -108,11 +109,11 @@ class PagedServingEngine(ServingEngine):
     prefill_chunk_len: prompt chunk size (default min(64, max_len)).
     prefix_sharing: hash full prompt blocks and dedupe identical
         prefixes (copy-on-write guarded; see BlockPool).
-    paged_kernel: which fused paged-attention implementation the
-        engine's programs trace ("reference" | "lax" | "pallas" |
-        "auto"; None defers to PT_PAGED_KERNEL / the process default —
-        see nn/paged_attention.py). Resolved at construction and pinned
-        for every program this engine compiles; reported in /healthz.
+    paged_kernel: which paged-attention core the engine's programs
+        trace: "reference" | "pallas", or None for what the backend
+        decides ("pallas" on a TPU, "reference" elsewhere; see
+        nn/paged_attention.py). Resolved at construction and pinned for
+        every program this engine compiles; reported in /healthz.
     """
 
     def __init__(self, model, num_slots=4, max_len=256, block_size=16,
@@ -178,9 +179,8 @@ class PagedServingEngine(ServingEngine):
 
         def decode_wave(p, b, caches, tables, tok, pos, active, sample,
                         temps, top_k, top_p, bias, poison, key):
-            # the scope pins this engine's kernel at TRACE time — the
-            # compiled wave keeps whatever it resolved, regardless of
-            # the process default when later engines trace
+            # the scope pins this engine's kernel at TRACE time: the
+            # compiled wave keeps the core the engine was built with
             lanes = {"active": active} if slot_state else {}
             with paged_attention.kernel_scope(kern):
                 out, _ = model.functional_call(p, b, tok[:, None], caches,
@@ -803,10 +803,11 @@ class SpeculativePagedEngine(PagedServingEngine):
 
     A small DRAFT model proposes up to k tokens per slot per wave; the
     target model scores all k + 1 positions in ONE batched forward built
-    on `chunk_attention` over the SAME block tables (C == k + 1 — the
-    C == 1 case of the verify kernel IS the plain decode wave, so this
-    is a third compiled program, not a new attention path). Exact
-    acceptance–rejection (see `_spec_verify_tail`) keeps outputs
+    on the model's one paged step over the SAME block tables (its
+    `prefill_chunk` at [S, k + 1] with per-lane starts — the C == 1 case
+    IS the plain decode wave, so this is a third compiled program, not a
+    new attention path). Exact acceptance–rejection (see
+    `_spec_verify_tail`) keeps outputs
     distribution-identical to the target model — bitwise-identical under
     greedy — while a wave advances each lane by 1..k+1 tokens: decode
     rounds per generated token drop by the acceptance rate.
@@ -913,14 +914,16 @@ class SpeculativePagedEngine(PagedServingEngine):
                         temps, top_k, top_p, bias, spec_len, draft_toks,
                         draft_probs, poison, key):
             """Verify-once: ONE target forward scores all k+1 positions
-            of every lane (decode_chunk == chunk_attention over the
-            block tables), then the exact acceptance-rejection tail."""
+            of every lane (the chunk program's own model call, at
+            [S, k + 1] with every lane's start and span and no frontier),
+            then the exact acceptance-rejection tail."""
             tgt_caches, dr_caches = caches
             chunk = jnp.concatenate([tok[:, None], draft_toks], axis=1)
             with paged_attention.kernel_scope(kern):
                 out, _ = model.functional_call(
-                    p, b, chunk, tgt_caches, tables, pos, spec_len + 1,
-                    method="decode_chunk")
+                    p, b, chunk, tgt_caches, method="prefill_chunk",
+                    block_tables=tables, chunk_start=pos,
+                    valid_len=spec_len + 1)
             logits, tgt_caches = out
             lo = _raw(logits).astype(jnp.float32)       # [S, k+1, V]
             out_toks, n_emit, nxt, new_pos, finite = _spec_verify_tail(

@@ -16,14 +16,8 @@ REAL hot path:
     shapes;
   * `paged_decode_attention` — the block-table decode core
     (page write/gather through traced tables + the GQA cached core) — the
-    reference oracle the fused kernels are measured against;
-  * `paged_fused_decode_attention` / `paged_fused_chunk_attention` —
-    the fused paged-attention cores (nn/paged_attention.py): the same
-    page write + attend, but reading K/V straight out of the pool through
-    the table with an online softmax — no gathered
-    [B, Hkv, nblk*BS, D] intermediate. Audited with the dispatch's
-    backend-auto kernel (lax on CPU — the implementation the banked
-    CPU baselines gate; pallas on TPU);
+    reference oracle the Pallas core is held to (the kernel itself is
+    compiled for a described v5e in tests/test_tpu_compile.py);
   * `train_step` — `jit.TrainStep` (forward + backward + AdamW, donated
     state) on the canonical 2-layer GPT config — the same topology
     bench.py's CPU smoke compiles, so the persistent compile cache is
@@ -86,18 +80,16 @@ TRACKED_PROGRAMS = ("serving_decode_wave", "serving_prefill",
                     "sharded_train_step_z3", "sharded_decode_wave",
                     "cached_decode_attention",
                     "paged_decode_attention",
-                    "paged_fused_decode_attention",
-                    "paged_fused_chunk_attention",
                     "prefill_flash_attention")
 
 
 def engine_program_specs(engine, prefix=None):
     """Audit specs for a LIVE engine's compiled programs, with the
-    engine's actual shapes — used on the canonical engines below and by
-    bench_serving.py on the engine it just measured. Dispatches on the
-    engine flavour: a paged engine (block_pool) audits its
-    decode-wave-with-tables and prefill-chunk programs; a speculative
-    engine (draft_model) audits its draft/verify/prefill trio."""
+    engine's actual shapes — used on the canonical engines below.
+    Dispatches on the engine flavour: a paged engine (block_pool)
+    audits its decode-wave-with-tables and prefill-chunk programs; a
+    speculative engine (draft_model) audits its draft/verify/prefill
+    trio."""
     if hasattr(engine, "draft_model"):
         return _spec_engine_specs(engine, prefix or "paged_spec")
     if hasattr(engine, "block_pool"):
@@ -478,15 +470,13 @@ def _sharded_decode_wave_spec():
 
 def _attention_specs():
     import jax.numpy as jnp
-    from paddle_tpu.nn.paged_attention import (paged_chunk_attention,
-                                               paged_decode_attention)
-    from paddle_tpu.nn.transformer import (cached_decode_attention,
-                                           gather_block_kv, write_block_kv)
+    from paddle_tpu.nn.paged_attention import (gather_block_kv,
+                                               write_block_kv)
+    from paddle_tpu.nn.transformer import cached_decode_attention
     from paddle_tpu.ops.pallas.flash_attention import _flash_array
 
     b, h, hkv, L, d = 4, 4, 2, 64, 16
     bs, nblk, num_blocks = 8, 8, 17        # nblk * bs == L
-    C = SPEC["spec_k"] + 1                 # the verify chunk width
 
     def decode_attn(q, ck, cv, pos):
         return cached_decode_attention(q, ck, cv, pos,
@@ -514,33 +504,6 @@ def _attention_specs():
                   jnp.zeros((b, nblk), jnp.int32),
                   jnp.zeros((b,), jnp.int32))
 
-    def fused_decode_attn(q, kv_t, pool, tables, pos):
-        # the fused sibling of paged_decode_attn: same write, but the
-        # attend reads the pool through the table (online softmax) —
-        # the [B, Hkv, nblk*BS, D] gathered view never materialises.
-        # kernel=None: the dispatch's backend auto-selection, i.e. the
-        # implementation the serving engines actually compile here
-        pool = write_block_kv(pool, kv_t, kv_t, tables, pos)
-        out = paged_decode_attention(q, pool, tables, pos,
-                                     scale=1.0 / (d ** 0.5))
-        return out, pool
-
-    def fused_chunk_attn(q, kv_c, pool, tables, start, valid_len):
-        # the chunked form (spec verify / prefill chunk): C queries per
-        # lane at per-lane offsets, the same write + fused attend
-        pool = write_block_kv(pool, kv_c, kv_c, tables, start, valid_len)
-        out = paged_chunk_attention(q, pool, tables, start,
-                                    scale=1.0 / (d ** 0.5))
-        return out, pool
-
-    fused_chunk_args = (jnp.zeros((b, h, C, d), jnp.float32),
-                        jnp.zeros((b, hkv, C, d), jnp.float32),
-                        jnp.zeros((num_blocks, hkv, bs, 2 * d),
-                                  jnp.float32),
-                        jnp.zeros((b, nblk), jnp.int32),
-                        jnp.zeros((b,), jnp.int32),
-                        jnp.full((b,), C, jnp.int32))
-
     def prefill_attn(q, k, v):
         return _flash_array(q, k, v, causal=True)
 
@@ -557,21 +520,8 @@ def _attention_specs():
          "jit_kwargs": {"donate_argnums": (2,)},
          "description": "block-table decode attention core: KV page "
                         "write/gather through traced tables + the "
-                        "GQA cached core (the fused kernels' reference "
+                        "GQA cached core (the Pallas core's reference "
                         "oracle)"},
-        {"name": "paged_fused_decode_attention", "fn": fused_decode_attn,
-         "args": paged_args,
-         "jit_kwargs": {"donate_argnums": (2,)},
-         "description": "fused paged decode core: block-table gather + "
-                        "GQA online-softmax attend in one pass, no "
-                        "gathered KV intermediate (nn/paged_attention, "
-                        "backend-auto kernel)"},
-        {"name": "paged_fused_chunk_attention", "fn": fused_chunk_attn,
-         "args": fused_chunk_args,
-         "jit_kwargs": {"donate_argnums": (2,)},
-         "description": "fused paged chunk core (spec-verify width "
-                        "k+1): per-lane-offset queries, batched KV "
-                        "page write + fused block-table attend"},
         {"name": "prefill_flash_attention", "fn": prefill_attn,
          "args": prefill_args,
          "description": "causal prompt-phase attention array kernel"},
@@ -603,7 +553,6 @@ def tracked_program_specs(names=None):
     if "sharded_decode_wave" in want:
         specs.append(_sharded_decode_wave_spec())
     if want & {"cached_decode_attention", "paged_decode_attention",
-               "paged_fused_decode_attention",
-               "paged_fused_chunk_attention", "prefill_flash_attention"}:
+               "prefill_flash_attention"}:
         specs += [s for s in _attention_specs() if s["name"] in want]
     return specs

@@ -21,7 +21,7 @@ engine (serving/slo.py): state changes bump `alerts_fired_total{rule}`,
 move `alerts_active{rule}`, and journal an `alert` flight-recorder
 event — steady state journals nothing.  `health()` merges into
 /healthz and `FleetRouter.health()`; `summary()` is the rollup
-bench.py / bench_serving.py embed in their BENCH JSON.
+bench.py embeds in its JSON line.
 
 Every `AlertRule` id constructed in code must be documented in the
 alert table of docs/observability.md — the `alert-rule-documented`

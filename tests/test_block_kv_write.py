@@ -1,7 +1,7 @@
 """The block pool's stored form and its one write.
 
-`nn.transformer.write_block_kv` moves whole pages where the parent
-scattered single rows (`scatter_block_kv_at`, `_chunk`,
+`nn.paged_attention.write_block_kv` moves whole pages where PR 28's
+parent scattered single rows (`scatter_block_kv_at`, `_chunk`,
 `_chunk_batched`); what a program may observe of it has to be what the
 row scatters did. The oracle below is those scatters in plain numpy, a
 position at a time, on the stored form `[NB, Hkv, BS, 2D]`. Two things
@@ -10,7 +10,7 @@ after every write (the scatters left the padded tail's rows in it), and a
 position past the table's end is not written (the scatters wrapped it
 onto the table's last page; the engine never asks for one).
 
-Then the three attention cores on the stored form at the head shapes the
+Then the two attention cores on the stored form at the head shapes the
 benchmark serves, and the paged engine's greedy tokens against the dense
 engine's (GPT, Llama) and the model's own forward (Nemotron-H).
 """
@@ -19,8 +19,8 @@ import pytest
 
 import paddle_tpu as pt
 from paddle_tpu.nn import paged_attention as pa
-from paddle_tpu.nn.transformer import (gather_block_kv, init_block_kv,
-                                       write_block_kv)
+from paddle_tpu.nn.paged_attention import (gather_block_kv, init_block_kv,
+                                           write_block_kv)
 
 HKV, D = 2, 4
 
@@ -171,20 +171,22 @@ def test_gather_reads_back_what_write_stored():
 
 
 # ---------------------------------------------------------------------------
-# the three cores on the stored form, at the benchmark's head shapes
+# the two cores on the stored form, at the benchmark's head shapes
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("kernel", ["lax", "pallas"])
+@pytest.mark.parametrize("bs", [16, 8])
 @pytest.mark.parametrize("form", ["decode", "chunk"])
 @pytest.mark.parametrize("h,hkv,d", [(12, 12, 64), (32, 8, 128),
                                      (32, 2, 128)],
                          ids=["mha12x64", "gqa32-8x128", "gqa32-2x128"])
-def test_cores_agree_on_the_stored_form(h, hkv, d, form, kernel):
-    """Written by `write_block_kv` into a bfloat16 pool of pages of 16,
-    attended by each core: `lax` and `pallas` against `reference`."""
+def test_cores_agree_on_the_stored_form(h, hkv, d, form, bs):
+    """Written by `write_block_kv` into a bfloat16 pool of pages of 16
+    (whole (16, 128) tiles) and of 8 (half a tile a page, which only the
+    CPU can check cheaply), attended by each core: `pallas` against
+    `reference`."""
     import jax.numpy as jnp
     rng = np.random.default_rng(6)
-    lanes, nblk, bs, c = 3, 4, 16, (1 if form == "decode" else 8)
+    lanes, nblk, c = 3, 4, (1 if form == "decode" else 8)
     tables = jnp.asarray(
         1 + rng.permutation(lanes * nblk).reshape(lanes, nblk), jnp.int32)
     pool = init_block_kv(lanes * nblk + 1, hkv, bs, d, jnp.bfloat16)
@@ -193,10 +195,8 @@ def test_cores_agree_on_the_stored_form(h, hkv, d, form, kernel):
     pool = write_block_kv(pool, *hist, tables, jnp.zeros(lanes, jnp.int32))
     start = jnp.asarray([0, 17, nblk * bs - c], jnp.int32)
     q = jnp.asarray(rng.standard_normal((lanes, h, c, d)), jnp.bfloat16)
-    attend = pa.paged_decode_attention if c == 1 else \
-        pa.paged_chunk_attention
-    ref = attend(q, pool, tables, start, d ** -0.5, kernel="reference")
-    out = attend(q, pool, tables, start, d ** -0.5, kernel=kernel)
+    ref = pa.attend(q, pool, tables, start, d ** -0.5, kernel="reference")
+    out = pa.attend(q, pool, tables, start, d ** -0.5, kernel="pallas")
     assert out.shape == (lanes, h, c, d) and out.dtype == jnp.bfloat16
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(ref, np.float32),
@@ -238,7 +238,7 @@ def _llama():
         num_kv_heads=2, max_seq_len=MAX_LEN))
 
 
-@pytest.mark.parametrize("kernel", ["reference", "lax", "pallas"])
+@pytest.mark.parametrize("kernel", pa.KERNELS)
 @pytest.mark.parametrize("family", ["gpt", "llama"])
 def test_paged_greedy_tokens_equal_the_dense_engines(family, kernel):
     """Prompts of under one chunk to several (with ragged tails), three
@@ -258,7 +258,7 @@ def test_paged_greedy_tokens_equal_the_dense_engines(family, kernel):
     assert _tokens(paged, jobs) == _tokens(dense, jobs)
 
 
-@pytest.mark.parametrize("kernel", ["lax", "pallas"])
+@pytest.mark.parametrize("kernel", pa.KERNELS)
 def test_nemotron_paged_greedy_tokens_equal_its_forward(kernel):
     """The hybrid model has no dense engine: its served tokens against
     the argmax of its own full forward over prompt + tokens."""

@@ -1,12 +1,11 @@
-"""paddle_tpu.nn.paged_attention — the fused gather+attend kernel
-family and its dispatch front door.
+"""paddle_tpu.nn.paged_attention — the two paged-attention cores and
+their dispatch front door.
 
-The acceptance contract for the fused kernels is PARITY, not
-approximation: every kernel ("reference" — the original
-gather_block_kv + attend pair, "lax" — the fori_loop online-softmax
-fallback, "pallas" — the TPU kernel run in interpret mode on CPU so
-tier-1 executes the genuine kernel body) must produce the SAME TOKENS
-through the serving engines, greedy and sampled, single request and
+The acceptance contract for the kernel is PARITY, not approximation:
+both cores ("reference" — gather_block_kv + a plain masked softmax, the
+oracle; "pallas" — the TPU kernel run in interpret mode on CPU so tier-1
+executes the genuine kernel body) must produce the SAME TOKENS through
+the serving engines, greedy and sampled, single request and
 mixed-length multi-wave streams, plain and speculative — while the
 compile-once program counts and the isfinite poison sentinel hold.
 
@@ -15,9 +14,9 @@ fully-masked rows renormalise to exactly 0, and non-finite garbage in
 a scratch block — which the engines read at MASKED positions by design
 — cannot leak into any lane's output, while a genuine non-finite at an
 ATTENDED position still propagates to the logits (the poison
-sentinel's signal). The gather-free claim is asserted compile-level:
-the fused decode core touches strictly fewer HBM bytes than the
-reference gather-then-attend core.
+sentinel's signal). That the chip's programs hold no gathered view and
+no pool-sized copy is tests/test_tpu_compile.py's, for a described v5e
+at the benchmark's shapes.
 """
 import numpy as np
 import pytest
@@ -27,8 +26,8 @@ from paddle_tpu.nn import paged_attention as pa
 from paddle_tpu.nlp import LlamaConfig, LlamaForCausalLM
 from paddle_tpu.serving import PagedServingEngine, Scheduler
 
-KERNELS = ("reference", "lax", "pallas")
-FUSED = ("lax", "pallas")
+KERNELS = pa.KERNELS
+FUSED = ("pallas",)
 
 VOCAB = 128
 MAX_LEN = 64
@@ -75,10 +74,10 @@ def test_decode_parity_vs_reference(kernel, window):
     import jax.numpy as jnp
     q, pk, pv, tables = _case(0, c=1)
     pos = jnp.asarray([3, 9, 17], jnp.int32)
-    ref = pa.paged_decode_attention(q, _fuse(pk, pv), tables, pos, 0.35,
-                                    window=window, kernel="reference")
-    out = pa.paged_decode_attention(q, _fuse(pk, pv), tables, pos, 0.35,
-                                    window=window, kernel=kernel)
+    ref = pa.attend(q, _fuse(pk, pv), tables, pos, 0.35, window=window,
+                    kernel="reference")
+    out = pa.attend(q, _fuse(pk, pv), tables, pos, 0.35, window=window,
+                    kernel=kernel)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=1e-5, atol=1e-5)
 
@@ -89,10 +88,10 @@ def test_chunk_parity_vs_reference(kernel, window):
     import jax.numpy as jnp
     q, pk, pv, tables = _case(1)
     start = jnp.asarray([0, 5, 12], jnp.int32)
-    ref = pa.paged_chunk_attention(q, _fuse(pk, pv), tables, start, 0.35,
-                                   window=window, kernel="reference")
-    out = pa.paged_chunk_attention(q, _fuse(pk, pv), tables, start, 0.35,
-                                   window=window, kernel=kernel)
+    ref = pa.attend(q, _fuse(pk, pv), tables, start, 0.35, window=window,
+                    kernel="reference")
+    out = pa.attend(q, _fuse(pk, pv), tables, start, 0.35, window=window,
+                    kernel=kernel)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=1e-5, atol=1e-5)
 
@@ -103,11 +102,10 @@ def test_scalar_position_matches_vector(kernel):
     broadcast of the per-lane vector form."""
     import jax.numpy as jnp
     q, pk, pv, tables = _case(2)
-    vec = pa.paged_chunk_attention(q, _fuse(pk, pv), tables,
-                                   jnp.asarray([7, 7, 7], jnp.int32),
-                                   0.3, kernel=kernel)
-    sca = pa.paged_chunk_attention(q, _fuse(pk, pv), tables, jnp.int32(7),
-                                   0.3, kernel=kernel)
+    vec = pa.attend(q, _fuse(pk, pv), tables,
+                    jnp.asarray([7, 7, 7], jnp.int32), 0.3, kernel=kernel)
+    sca = pa.attend(q, _fuse(pk, pv), tables, jnp.int32(7), 0.3,
+                    kernel=kernel)
     np.testing.assert_array_equal(np.asarray(vec), np.asarray(sca))
 
 
@@ -124,13 +122,12 @@ def test_poisoned_scratch_block_cannot_leak(kernel):
     q, pk, pv, tables = _case(3, c=1, poison_scratch=True)
     pos = jnp.asarray([3, 9, 17], jnp.int32)
     for window in (None, 6):
-        out = pa.paged_decode_attention(q, _fuse(pk, pv), tables, pos, 0.35,
-                                        window=window, kernel=kernel)
+        out = pa.attend(q, _fuse(pk, pv), tables, pos, 0.35, window=window,
+                        kernel=kernel)
         assert np.isfinite(np.asarray(out)).all(), (kernel, window)
     qc, pkc, pvc, tc = _case(4, poison_scratch=True)
-    out = pa.paged_chunk_attention(qc, _fuse(pkc, pvc), tc,
-                                   jnp.asarray([0, 5, 12], jnp.int32),
-                                   0.35, kernel=kernel)
+    out = pa.attend(qc, _fuse(pkc, pvc), tc,
+                    jnp.asarray([0, 5, 12], jnp.int32), 0.35, kernel=kernel)
     assert np.isfinite(np.asarray(out)).all()
 
 
@@ -143,8 +140,8 @@ def test_attended_nonfinite_still_propagates(kernel):
     q, pk, pv, tables = _case(5, c=1, poison_scratch=True)
     tables = tables.at[1, 0].set(0)            # attended scratch read
     pos = jnp.asarray([3, 9, 17], jnp.int32)
-    out = np.asarray(pa.paged_decode_attention(q, _fuse(pk, pv), tables, pos,
-                                               0.35, kernel=kernel))
+    out = np.asarray(pa.attend(q, _fuse(pk, pv), tables, pos, 0.35,
+                               kernel=kernel))
     assert not np.isfinite(out[1]).all()
     assert np.isfinite(out[0]).all() and np.isfinite(out[2]).all()
 
@@ -157,8 +154,8 @@ def test_fully_masked_rows_are_exactly_zero(kernel):
     import jax.numpy as jnp
     q, pk, pv, tables = _case(6, c=1, poison_scratch=True)
     neg = jnp.asarray([-1, -1, -1], jnp.int32)
-    out = np.asarray(pa.paged_decode_attention(q, _fuse(pk, pv), tables, neg,
-                                               0.35, kernel=kernel))
+    out = np.asarray(pa.attend(q, _fuse(pk, pv), tables, neg, 0.35,
+                               kernel=kernel))
     assert (out == 0).all()
 
 
@@ -189,14 +186,10 @@ def _ragged(seed, c, rep, hkv=2, d=8):
     return q, pk, pv, tables, start
 
 
-def _attend(form):
-    return pa.paged_decode_attention if form == "decode" else \
-        pa.paged_chunk_attention
-
-
-# window 6 starts mid-page, window 3 is shorter than a page of 4
+# window 6 starts mid-page, window 3 is shorter than a page of 4;
+# 16 query heads a kv-head is the hybrid cell's GQA 32 / 2
 @pytest.mark.parametrize("window", [None, 6, 3])
-@pytest.mark.parametrize("rep", [1, 4], ids=["mha", "gqa4"])
+@pytest.mark.parametrize("rep", [1, 4, 16], ids=["mha", "gqa4", "gqa16"])
 @pytest.mark.parametrize("form", ["decode", "chunk"])
 @pytest.mark.parametrize("kernel", FUSED)
 def test_ragged_lanes_parity_vs_reference(kernel, form, rep, window):
@@ -204,8 +197,8 @@ def test_ragged_lanes_parity_vs_reference(kernel, form, rep, window):
     q, pk, pv, tables, start = _ragged(7, 1 if form == "decode" else 5, rep)
     args = (q, _fuse(pk, pv), jnp.asarray(tables),
             jnp.asarray(start), 0.35)
-    ref = _attend(form)(*args, window=window, kernel="reference")
-    out = _attend(form)(*args, window=window, kernel=kernel)
+    ref = pa.attend(*args, window=window, kernel="reference")
+    out = pa.attend(*args, window=window, kernel=kernel)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=1e-5, atol=1e-5)
 
@@ -224,9 +217,8 @@ def test_poison_outside_the_attended_keys_changes_nothing(kernel, form,
     tables[-1] = tables.max() + 1 + np.arange(RAGGED_NBLK)   # own blocks
     pk = np.concatenate([pk, pk[:RAGGED_NBLK]])
     pv = np.concatenate([pv, pv[:RAGGED_NBLK]])
-    clean = _attend(form)(q, _fuse(pk, pv),
-                          jnp.asarray(tables), jnp.asarray(start), 0.35,
-                          window=window, kernel=kernel)
+    clean = pa.attend(q, _fuse(pk, pv), jnp.asarray(tables),
+                      jnp.asarray(start), 0.35, window=window, kernel=kernel)
     pk[0] = pv[0] = np.nan
     for lane, st in enumerate(start):
         first = 0 if window is None else max(0, st - window + 1)
@@ -235,9 +227,8 @@ def test_poison_outside_the_attended_keys_changes_nothing(kernel, form,
         for j in range(RAGGED_NBLK):
             pk[tables[lane, j]][:, dead[j]] = np.nan
             pv[tables[lane, j]][:, dead[j]] = np.nan
-    out = _attend(form)(q, _fuse(pk, pv),
-                        jnp.asarray(tables), jnp.asarray(start), 0.35,
-                        window=window, kernel=kernel)
+    out = pa.attend(q, _fuse(pk, pv), jnp.asarray(tables),
+                    jnp.asarray(start), 0.35, window=window, kernel=kernel)
     assert np.isfinite(np.asarray(out)).all()
     np.testing.assert_array_equal(np.asarray(out), np.asarray(clean))
 
@@ -265,11 +256,15 @@ def test_attended_pages_match_a_brute_force_count(c, window):
     np.testing.assert_array_equal(np.asarray(traced), np.stack([lo, hi], 1))
 
 
+# window 3 is shorter than a page: its `lo` falls inside a step of
+# several pages
+@pytest.mark.parametrize("window", [None, 6, 3])
 @pytest.mark.parametrize("heads,pages", [(1, 1), (2, 1), (1, 3), (2, 3),
                                          (2, 16)])
 @pytest.mark.parametrize("form", ["decode", "chunk"])
 def test_every_tile_of_the_pallas_core_gives_the_reference(monkeypatch, form,
-                                                           heads, pages):
+                                                           heads, pages,
+                                                           window):
     """One recurrence, whatever tile the shapes choose: kv-heads in the
     grid or folded into a step, one page a step or several, a last step
     that reaches past the table."""
@@ -278,11 +273,10 @@ def test_every_tile_of_the_pallas_core_gives_the_reference(monkeypatch, form,
     q, pk, pv, tables, start = _ragged(9, 1 if form == "decode" else 5, 2)
     args = (q, _fuse(pk, pv), jnp.asarray(tables),
             jnp.asarray(start), 0.35)
-    for window in (None, 6):
-        ref = _attend(form)(*args, window=window, kernel="reference")
-        out = _attend(form)(*args, window=window, kernel="pallas")
-        np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                                   rtol=1e-5, atol=1e-5)
+    ref = pa.attend(*args, window=window, kernel="reference")
+    out = pa.attend(*args, window=window, kernel="pallas")
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               rtol=1e-5, atol=1e-5)
 
 
 def test_tile_follows_the_shapes_of_the_call():
@@ -300,41 +294,36 @@ def test_tile_follows_the_shapes_of_the_call():
 
 
 # ---------------------------------------------------------------------------
-# dispatch front door: resolution order, env override, scopes
+# dispatch front door: two names, one rule
 # ---------------------------------------------------------------------------
 
 def test_kernel_resolution_order(monkeypatch):
-    monkeypatch.delenv("PT_PAGED_KERNEL", raising=False)
-    assert pa.resolve_kernel("lax") == "lax"
-    # auto on the CPU backend is the lax fallback
-    assert pa.resolve_kernel() == "lax"
-    assert pa.resolve_kernel("auto") == "lax"
-    monkeypatch.setenv("PT_PAGED_KERNEL", "reference")
+    import jax
+    assert pa.KERNELS == ("reference", "pallas")
+    # nothing said: what the backend decides
     assert pa.resolve_kernel() == "reference"
-    # scope beats env; inner scope beats outer; explicit beats scope
-    with pa.kernel_scope("pallas"):
-        assert pa.resolve_kernel() == "pallas"
-        with pa.kernel_scope("lax"):
-            assert pa.resolve_kernel() == "lax"
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert pa.resolve_kernel() == "pallas"
+    # a scope beats the backend; inner scope beats outer; explicit
+    # beats scope
+    with pa.kernel_scope("reference"):
+        assert pa.resolve_kernel() == "reference"
+        with pa.kernel_scope("pallas"):
+            assert pa.resolve_kernel() == "pallas"
             assert pa.resolve_kernel("reference") == "reference"
-        assert pa.resolve_kernel() == "pallas"
-    assert pa.resolve_kernel() == "reference"
-    monkeypatch.delenv("PT_PAGED_KERNEL")
-    pa.set_paged_kernel("pallas")
-    try:
-        assert pa.resolve_kernel() == "pallas"
-    finally:
-        pa.set_paged_kernel("auto")
+        assert pa.resolve_kernel() == "reference"
+    assert pa.resolve_kernel() == "pallas"
 
 
-def test_unknown_kernel_rejected(monkeypatch):
-    with pytest.raises(ValueError, match="unknown paged kernel"):
-        pa.resolve_kernel("flash")
-    with pytest.raises(ValueError, match="unknown paged kernel"):
-        pa.set_paged_kernel("nope")
-    monkeypatch.setenv("PT_PAGED_KERNEL", "bogus")
-    with pytest.raises(ValueError, match="unknown paged kernel"):
-        pa.resolve_kernel()
+def test_unknown_kernel_rejected(model):
+    for name in ("flash", "lax", "auto", ""):
+        with pytest.raises(ValueError, match="unknown paged kernel"):
+            pa.resolve_kernel(name)
+        with pytest.raises(ValueError, match="unknown paged kernel"):
+            with pa.kernel_scope(name):
+                pass
+        with pytest.raises(ValueError, match="unknown paged kernel"):
+            _engine(model, name)
 
 
 # ---------------------------------------------------------------------------
@@ -399,7 +388,7 @@ def test_engine_counts_the_pages_its_waves_visit(window):
     cfg = LlamaConfig(vocab_size=VOCAB, hidden_size=64, num_layers=1,
                       num_heads=4, num_kv_heads=2, max_seq_len=MAX_LEN,
                       attn_window=window)
-    eng = _engine(LlamaForCausalLM(cfg), "lax")
+    eng = _engine(LlamaForCausalLM(cfg), None)
     waves, stage = [], eng._wave_args
 
     def spy(*args):
@@ -482,18 +471,6 @@ def test_engine_scratch_poison_regression(model):
         assert sched.metrics.snapshot()["faults"] == {}, kernel
 
 
-def test_env_override_reaches_engine(model, monkeypatch):
-    """PT_PAGED_KERNEL steers engines built without an explicit choice
-    (the no-code-change escape hatch), and an explicit constructor
-    argument still wins over it."""
-    monkeypatch.setenv("PT_PAGED_KERNEL", "reference")
-    eng = _engine(model, None)
-    assert eng.paged_kernel == "reference"
-    assert _engine(model, "lax").paged_kernel == "lax"
-    monkeypatch.delenv("PT_PAGED_KERNEL")
-    assert _engine(model, None).paged_kernel == "lax"      # auto on cpu
-
-
 def test_front_door_via_inference_config(model):
     """inference.Config.enable_llm_engine(paged_kernel=...) reaches the
     engine through create_llm_predictor, token-compatible with a
@@ -502,9 +479,9 @@ def test_front_door_via_inference_config(model):
     cfg = inference.Config()
     cfg.enable_llm_engine(paged=True, num_slots=2, max_len=48,
                           prefill_len=16, block_size=8,
-                          paged_kernel="lax")
+                          paged_kernel="pallas")
     pred = inference.create_llm_predictor(cfg, model=model)
-    assert pred.engine.paged_kernel == "lax"
+    assert pred.engine.paged_kernel == "pallas"
     prompt = _prompt_tokens(31)
     ref = PagedServingEngine(model, num_slots=2, max_len=48,
                              block_size=8, prefill_chunk_len=16,
@@ -525,39 +502,9 @@ def test_front_door_health_names_the_resolved_core(model):
     pred.generate(_prompt_tokens(32), max_tokens=2)
     health = pred.health()
     assert health["status"] == "ok"
-    assert health["paged_kernel"] == "lax"                 # auto on cpu
+    assert health["paged_kernel"] == "reference"           # off the TPU
     assert health["decode_compiles"] == 1
 
 
 def _prompt_tokens(seed, n=5):
     return np.random.RandomState(seed).randint(0, VOCAB, (n,)).tolist()
-
-
-# ---------------------------------------------------------------------------
-# the gather-free claim, compile-level
-# ---------------------------------------------------------------------------
-
-def test_fused_core_accesses_fewer_bytes_than_reference():
-    """The xprof-tracked fused decode core must touch strictly fewer
-    HBM bytes than the reference gather-then-attend core on the same
-    canonical shapes — the [B, Hkv, nblk*BS, D] gathered intermediate
-    is gone, not merely renamed."""
-    from paddle_tpu.tools import xprof
-    specs = xprof.tracked_program_specs(
-        ["paged_decode_attention", "paged_fused_decode_attention",
-         "paged_fused_chunk_attention"])
-    assert len(specs) == 3, [s["name"] for s in specs]
-    snap = xprof.snapshot_programs(specs)["programs"]
-    ref = snap["paged_decode_attention"]["cost"]["bytes_accessed"]
-    fused = snap["paged_fused_decode_attention"]["cost"]["bytes_accessed"]
-    assert fused < ref, (fused, ref)
-    assert snap["paged_fused_chunk_attention"]["cost"][
-        "bytes_accessed"] > 0
-    # and the memory analysis agrees: the fused program's temp
-    # allocation is smaller than even ONE gathered [B, Hkv, nblk*BS, D]
-    # f32 copy at the registry's canonical attention shapes
-    # (b=4, hkv=2, L=nblk*bs=64, d=16 — _attention_specs) — there is
-    # nowhere a gathered view could be hiding
-    gathered = 4 * 2 * 64 * 16 * 4
-    temp = snap["paged_fused_decode_attention"]["memory"]["temp_bytes"]
-    assert temp < gathered, (temp, gathered)

@@ -142,14 +142,10 @@ def test_flash_under_a_dp2_mp2_mesh_compiles_for_v5e(topo, as_on_tpu,
 def test_paged_core_server_resolves_on_tpu_compiles_for_v5e(
         one_chip, as_on_tpu, name, b, h, hkv, c, d, window):
     """The engine's decode wave (C == 1) and prefill chunk (C == 128)."""
-    kernel = pa.resolve_kernel("auto")
-    assert kernel == "pallas"
-    attend = pa.paged_decode_attention if c == 1 else \
-        pa.paged_chunk_attention
+    assert pa.resolve_kernel() == "pallas"
 
     def fn(q, pool, tables, pos):
-        return attend(q, pool, tables, pos, d ** -0.5, window=window,
-                      kernel=kernel)
+        return pa.attend(q, pool, tables, pos, d ** -0.5, window=window)
 
     assert _kernels_in(fn, *_paged_args(one_chip, b, h, hkv, c, d)) == 1
 
@@ -174,12 +170,9 @@ def test_paged_core_compiles_at_the_cells_shapes_for_v5e(
     """The two serving cells' real shapes: one kernel, named
     `paged_attention`, per attention call, whatever tile the shapes
     choose."""
-    attend = pa.paged_decode_attention if c == 1 else \
-        pa.paged_chunk_attention
-
     def fn(q, pool, tables, pos):
-        return attend(q, pool, tables, pos, d ** -0.5, window=window,
-                      kernel="pallas")
+        return pa.attend(q, pool, tables, pos, d ** -0.5, window=window,
+                         kernel="pallas")
 
     assert _kernel_names(fn, *_paged_args(one_chip, b, h, hkv, c, d,
                                           nblk=nblk)) == ["paged_attention"]
@@ -223,23 +216,21 @@ def _pool_stays(compiled, pools):
 def test_pool_passes_through_write_and_attention_in_place_for_v5e(
         one_chip, as_on_tpu, name, blocks, lanes, h, hkv, d, nblk, c,
         window):
-    """One layer of a serving program at a cell's real pool: the K/V
-    write, then the paged kernel, the pool donated. The pool is neither
-    copied nor padded on the way (head_dim 64 as 128), and the kernel is
-    the one named `paged_attention`."""
-    from paddle_tpu.nn.transformer import write_block_kv
-
+    """One layer of a serving program at a cell's real pool: the call a
+    model's attention makes (the K/V write, then the paged kernel), the
+    pool donated. The pool is neither copied nor padded on the way
+    (head_dim 64 as 128), and the kernel is the one named
+    `paged_attention`."""
     def sds(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
     b = lanes if c == 1 else 1
-    attend = pa.paged_decode_attention if c == 1 else \
-        pa.paged_chunk_attention
 
     def layer(pool, q, k, v, tables, start, valid_len):
-        pool = write_block_kv(pool, k, v, tables, start, valid_len)
-        return pool, attend(q, pool, tables, start, d ** -0.5,
-                            window=window, kernel="pallas")
+        out, pool = pa.paged_attend(q, k, v, pool, tables, start, valid_len,
+                                    d ** -0.5, window=window,
+                                    kernel="pallas")
+        return pool, out
 
     pool = sds((blocks, hkv, 16, 2 * d), jnp.bfloat16)
     kv = sds((b, hkv, c, d), jnp.bfloat16)
@@ -320,16 +311,6 @@ def test_grouped_expert_kernel_compiles_for_v5e(one_chip, as_on_tpu, name,
     assert not re.search(r"bf16\[128,1856,2688\][^ ]* copy\(", txt)
 
 
-def test_lax_paged_core_compiles_for_v5e(one_chip):
-    """The portable core is what `paged_kernel="lax"` serves from on a
-    chip; it has no kernel of its own."""
-    def fn(q, pool, tables, pos):
-        return pa.paged_decode_attention(q, pool, tables, pos, 64 ** -0.5,
-                                         kernel="lax")
-
-    assert _kernels_in(fn, *_paged_args(one_chip, 8, 12, 12, 1, 64)) == 0
-
-
 def test_kernels_carry_their_names_for_v5e(one_chip, as_on_tpu):
     """A `jax.named_scope` right at each `pallas_call` names the
     instruction, under grad and jit alike, so that a device trace can be
@@ -346,8 +327,7 @@ def test_kernels_carry_their_names_for_v5e(one_chip, as_on_tpu):
         "flash_bwd_dkv", "flash_bwd_dq", "flash_fwd"]
 
     def wave(q, pool, tables, pos):
-        return pa.paged_decode_attention(q, pool, tables, pos, 64 ** -0.5,
-                                         kernel="pallas")
+        return pa.attend(q, pool, tables, pos, 64 ** -0.5, kernel="pallas")
 
     assert _kernel_names(wave, *_paged_args(one_chip, 8, 12, 12, 1, 64)) \
         == ["paged_attention"]
