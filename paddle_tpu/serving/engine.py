@@ -25,9 +25,22 @@ Retire-and-refill happens BETWEEN waves by rewriting the per-slot
 vectors — in-flight decodes never stall and never recompile.
 
 Slot bookkeeping (positions, tokens, flags) is host-authoritative:
-five tiny [S] uploads per wave instead of device round-trips, and the
+numpy arrays of the programs' own dtypes, written in place between
+waves, so a wave costs one small upload and no device round-trip; the
 next-token pull each wave is the one unavoidable sync (the tokens are
 the product being streamed).
+
+A stage (`serving/prefill/stage`, `serving/wave/stage`) puts nothing on
+the device's queue: nothing it builds is a `jnp` value (each
+`jnp.int32(..)` or `jnp.asarray(<list>)` is a tiny device program of its
+own, run while the device waits), and the PRNG key is split INSIDE the
+program, which returns the next key beside its outputs. A program's
+small arguments travel as ONE numpy array, int32, packed afresh by the
+stage (`pack_lanes` for a wave, `pack_prompt` for a prefill; the float32
+knobs go by their bits): every host argument of a jitted call is a
+transfer of its own, 0.16 ms of the call on a v5e whatever its size
+(PERF.md, PR 32), and a chunk had nine of them. What does not change
+stays on the device: the bias row and matrix of a server without biases.
 """
 import jax
 import jax.numpy as jnp
@@ -62,6 +75,82 @@ def _infer_cache_dtype(params):
 
 def _raw(x):
     return x._data if isinstance(x, Tensor) else x
+
+
+#: a wave program's small arguments, one int32 row a lane: the lane's
+#: block-table row (paged engines; no column on a dense one), then these
+#: columns. `spec_len` is the speculative engine's (0 elsewhere);
+#: `temp` and `top_p` are float32, carried by their bits.
+LANE_FIELDS = ("tok", "pos", "active", "sample", "top_k", "poison",
+               "spec_len", "temp", "top_p")
+#: a prefill program's small arguments, one int32 vector: the slot's
+#: table row (paged engines), the chunk's tokens, then these. `start` is
+#: the chunk's offset in the prompt, `valid` its real tokens (the dense
+#: bucket's prompt length), `frontier` the position whose logits pick
+#: the first token.
+PROMPT_FIELDS = ("start", "valid", "frontier", "slot", "sample", "top_k",
+                 "temp", "top_p")
+_FLOAT_FIELDS = ("temp", "top_p")
+_FLAG_FIELDS = ("active", "sample", "poison")
+
+
+def _as_bits(x):
+    """float32 value(s) as the int32 of the same bits (host side)."""
+    return np.asarray(x, np.float32).view(np.int32)
+
+
+def pack_lanes(tables=None, **columns):
+    """One wave's small arguments as one fresh int32 array
+    [S, nblk + len(LANE_FIELDS)]: `tables` [S, nblk] or None, and a
+    vector [S] for every name in LANE_FIELDS."""
+    vectors = [_as_bits(columns[f]) if f in _FLOAT_FIELDS else columns[f]
+               for f in LANE_FIELDS]
+    nblk = 0 if tables is None else tables.shape[1]
+    block = np.empty((len(vectors[0]), nblk + len(LANE_FIELDS)), np.int32)
+    if nblk:
+        block[:, :nblk] = tables
+    for i, vec in enumerate(vectors):
+        block[:, nblk + i] = vec
+    return block
+
+
+def pack_prompt(tokens, table=None, **scalars):
+    """One prefill call's small arguments as one fresh int32 vector
+    [nblk + C + len(PROMPT_FIELDS)]: the slot's `table` row or None, the
+    chunk's `tokens` [C], and a value for every name in PROMPT_FIELDS."""
+    tail = [_as_bits(scalars[f]) if f in _FLOAT_FIELDS else scalars[f]
+            for f in PROMPT_FIELDS]
+    lead = () if table is None else (table,)
+    return np.concatenate([*lead, tokens, np.asarray(tail, np.int32)],
+                          dtype=np.int32)
+
+
+def _unpack(fields, columns):
+    """{name: value} of a packed argument's named columns, inside a
+    program: flags as bool, the float32 knobs back from their bits."""
+    out = dict(zip(fields, columns))
+    for f in _FLOAT_FIELDS:
+        out[f] = jax.lax.bitcast_convert_type(out[f], jnp.float32)
+    for f in _FLAG_FIELDS:
+        if f in out:
+            out[f] = out[f] != 0
+    return out
+
+
+def unpack_lanes(block):
+    """(tables [S, nblk], {name: [S] vector}) of a `pack_lanes` array."""
+    nblk = block.shape[1] - len(LANE_FIELDS)
+    return block[:, :nblk], _unpack(
+        LANE_FIELDS, [block[:, nblk + i] for i in range(len(LANE_FIELDS))])
+
+
+def unpack_prompt(block, chunk_len):
+    """(table row [nblk], tokens [chunk_len], {name: scalar}) of a
+    `pack_prompt` vector."""
+    nblk = block.shape[0] - chunk_len - len(PROMPT_FIELDS)
+    tail = block[nblk + chunk_len:]
+    return block[:nblk], block[nblk:nblk + chunk_len], _unpack(
+        PROMPT_FIELDS, [tail[i] for i in range(len(PROMPT_FIELDS))])
 
 
 def _filter_top_k_top_p(lo, top_k, top_p):
@@ -202,17 +291,23 @@ class ServingEngine:
         # seed provenance) so a fresh engine built with the same seed
         # replays sampled streams token-exact
         self.seed = int(seed)
+        # every program that draws takes this key, splits it as its first
+        # instruction (next key, subkey: the chain an eager
+        # `key, sub = jax.random.split(key)` a dispatch would walk) and
+        # returns the next key, which is kept here as it comes back
         self._key = jax.random.PRNGKey(seed)
 
-        # host-authoritative per-slot state
+        # host-authoritative per-slot state: numpy arrays of the wave
+        # program's dtypes, written in place (_arm_slot, retire_slot,
+        # the wave's read-back); a wave stage packs them (pack_lanes)
         S = self.num_slots
         # vocab width: the logit-bias / token-mask rows are [V] uploads
         self.vocab_size = int(model.cfg.vocab_size)
-        self.slot_active = [False] * S
-        self.slot_pos = [0] * S        # next cache write position
-        self.slot_tok = [0] * S        # token fed to the next wave
-        self.slot_sample = [False] * S
-        self.slot_temp = [1.0] * S
+        self.slot_active = np.zeros((S,), bool)
+        self.slot_pos = np.zeros((S,), np.int32)   # next cache write position
+        self.slot_tok = np.zeros((S,), np.int32)   # token fed to the next wave
+        self.slot_sample = np.zeros((S,), bool)
+        self.slot_temp = np.ones((S,), np.float32)
         # per-request scenario surface (all flow through the one shared
         # sampling tail, _select_wave_tokens): top-k / nucleus knobs and
         # a [S, V] additive logit-bias/token-mask matrix (0 = untouched,
@@ -221,17 +316,25 @@ class ServingEngine:
         # speculative engine clamps its draft span to 0 for that lane —
         # drafting ahead of a mask that depends on emitted tokens would
         # break exactness.
-        self.slot_top_k = [0] * S
-        self.slot_top_p = [1.0] * S
-        self.slot_dynamic_mask = [False] * S
+        self.slot_top_k = np.zeros((S,), np.int32)
+        self.slot_top_p = np.ones((S,), np.float32)
+        self.slot_dynamic_mask = np.zeros((S,), bool)
         self._slot_bias = np.zeros((S, self.vocab_size), np.float32)
         # device-resident copy of the bias matrix, re-uploaded only
         # when a row actually changes: the [S, V] upload would
         # otherwise ride EVERY wave of every engine (V can be 50k+),
         # and the common case is all-zeros. The wave programs never
-        # donate it, so the same device array serves every wave.
-        self._slot_bias_dev = None
+        # donate it, so the same device array serves every wave. The
+        # prefill programs' [V] row likewise: one resident zero row
+        # serves every request whose row is all zeros.
+        self._slot_bias_dev = jax.device_put(self._slot_bias)
         self._slot_bias_nonzero = [False] * S
+        self._zero_bias_row = jax.device_put(
+            np.zeros((self.vocab_size,), np.float32))
+        # rows and matrices of bias sent to the device since the
+        # scheduler last took the count (take_bias_uploads, once a
+        # round): 0 for as long as no request brings a bias
+        self._bias_uploads = 0
 
         # admissions mid-prefill (slot -> engine-specific state): the
         # scheduler admits via begin_prefill and advances one
@@ -287,29 +390,34 @@ class ServingEngine:
         model, L = self.model, self.max_len
         cache_dtype = self.cache_dtype
 
-        def decode_wave(p, b, caches, tok, pos, active, sample, temps,
-                        top_k, top_p, bias, poison, key):
-            out, _ = model.functional_call(p, b, tok[:, None], caches,
-                                           pos, method="decode_step")
+        bucket = self.prefill_len
+
+        def decode_wave(p, b, caches, lanes, bias, key):
+            key, sub = jax.random.split(key)
+            _, a = unpack_lanes(lanes)
+            out, _ = model.functional_call(p, b, a["tok"][:, None], caches,
+                                           a["pos"], method="decode_step")
             logits, new_caches = out
             lo = _raw(logits)[:, 0, :].astype(jnp.float32)
             nxt, new_pos, finite = _select_wave_tokens(
-                lo, tok, pos, active, sample, temps, top_k, top_p, bias,
-                poison, key)
-            return nxt, new_pos, finite, new_caches
+                lo, a["tok"], a["pos"], a["active"], a["sample"],
+                a["temp"], a["top_k"], a["top_p"], bias, a["poison"], sub)
+            return nxt, new_pos, finite, new_caches, key
 
-        def prefill(p, b, caches, prompt, prompt_len, slot, sample, temp,
-                    top_k, top_p, bias, key):
-            # frontier=prompt_len-1: the model applies its LM head to
-            # that ONE position, not the whole padded bucket
-            out, _ = model.functional_call(p, b, prompt[None, :],
+        def prefill(p, b, caches, prompt, bias, key):
+            key, sub = jax.random.split(key)
+            _, tokens, a = unpack_prompt(prompt, bucket)
+            # the model applies its LM head to the frontier position
+            # alone (the prompt's last), not the whole padded bucket
+            out, _ = model.functional_call(p, b, tokens[None, :],
                                            method="prefill", max_len=L,
                                            dtype=cache_dtype,
-                                           frontier=prompt_len - 1)
+                                           frontier=a["frontier"])
             logits, slot_caches = out
             lo = _raw(logits)[0, 0].astype(jnp.float32)    # [V]
-            first = _select_first_token(lo, sample, temp, top_k, top_p,
-                                        bias, key)
+            first = _select_first_token(lo, a["sample"], a["temp"],
+                                        a["top_k"], a["top_p"], bias, sub)
+            slot = a["slot"]
             new_caches = []
             for (ck, cv), (sck, scv) in zip(caches, slot_caches):
                 ck = jax.lax.dynamic_update_slice(
@@ -317,7 +425,7 @@ class ServingEngine:
                 cv = jax.lax.dynamic_update_slice(
                     cv, _raw(scv).astype(cv.dtype), (slot, 0, 0, 0))
                 new_caches.append((ck, cv))
-            return first, new_caches
+            return first, new_caches, key
 
         # raw closures + jit spec, kept for the compile-level audit
         # (tools/xprof lowers THE functions the engine serves — and can
@@ -466,11 +574,11 @@ class ServingEngine:
 
     # ------------------------------------------------------------- slots
     def free_slots(self):
-        return [i for i, a in enumerate(self.slot_active)
-                if not a and i not in self._pending_prefill]
+        return [i for i in np.flatnonzero(~self.slot_active).tolist()
+                if i not in self._pending_prefill]
 
     def active_slots(self):
-        return [i for i, a in enumerate(self.slot_active) if a]
+        return np.flatnonzero(self.slot_active).tolist()
 
     def prefilling_slots(self):
         """Slots admitted but still mid-prefill (paged chunked prefill;
@@ -528,14 +636,17 @@ class ServingEngine:
         self._set_bias_row(slot, self._normalize_bias(bias))
         self.slot_dynamic_mask[slot] = bool(dynamic)
 
-    def _set_bias_row(self, slot, row):
-        """Write one slot's bias row, invalidating the device copy only
-        when the row's content actually changes zero-ness — a stream of
-        bias-free requests uploads the [S, V] matrix exactly once."""
-        nonzero = bool(np.any(row))
+    def _set_bias_row(self, slot, row, nonzero=None):
+        """Write one slot's bias row (None: all zeros), invalidating the
+        device copy only when the row's content actually changes
+        zero-ness — a stream of bias-free requests never uploads the
+        [S, V] matrix. `nonzero`: what `np.any(row)` is, where the
+        caller knows."""
+        if nonzero is None:
+            nonzero = row is not None and bool(np.any(row))
         if nonzero or self._slot_bias_nonzero[slot]:
             self._slot_bias_dev = None
-        self._slot_bias[slot] = row
+            self._slot_bias[slot] = row if nonzero else 0.0
         self._slot_bias_nonzero[slot] = nonzero
 
     def _arm_slot(self, slot, first, n, sampling):
@@ -545,19 +656,52 @@ class ServingEngine:
         self.slot_active[slot] = True
         self.slot_pos[slot] = n
         self.slot_tok[slot] = first
-        self.slot_sample[slot] = bool(sampling["sample"])
-        self.slot_temp[slot] = float(sampling["temp"])
-        self.slot_top_k[slot] = int(sampling["top_k"])
-        self.slot_top_p[slot] = float(sampling["top_p"])
-        self._set_bias_row(slot, sampling["bias"])
-        self.slot_dynamic_mask[slot] = bool(sampling["dynamic_mask"])
+        self.slot_sample[slot] = sampling["sample"]
+        self.slot_temp[slot] = sampling["temp"]
+        self.slot_top_k[slot] = sampling["top_k"]
+        self.slot_top_p[slot] = sampling["top_p"]
+        self._set_bias_row(slot, sampling["bias"],
+                           sampling["bias_nonzero"])
+        self.slot_dynamic_mask[slot] = sampling["dynamic_mask"]
 
     def _sampling_state(self, do_sample, temperature, top_k, top_p,
                         logit_bias, dynamic_mask):
+        """One request's sampling surface. `bias` is the [V] row, or
+        None where the request brings none; `bias_nonzero` is computed
+        here, once: a request whose row is all zeros is served by the
+        resident zero row."""
+        bias = (None if logit_bias is None
+                else self._normalize_bias(logit_bias))
         return {"sample": bool(do_sample), "temp": float(temperature),
                 "top_k": int(top_k), "top_p": float(top_p),
-                "bias": self._normalize_bias(logit_bias),
+                "bias": bias,
+                "bias_nonzero": bias is not None and bool(np.any(bias)),
                 "dynamic_mask": bool(dynamic_mask)}
+
+    def _prompt_args(self, slot, tokens, start, valid, frontier, sampling,
+                     table=None):
+        """A prefill program's arguments after the donated caches: the
+        packed vector (pack_prompt), the [V] bias row, the key. The row
+        is uploaded once a request, and only if it holds a bias."""
+        if not sampling["bias_nonzero"]:
+            bias = self._zero_bias_row
+        elif "bias_dev" in sampling:
+            bias = sampling["bias_dev"]
+        else:
+            bias = sampling["bias_dev"] = jax.device_put(sampling["bias"])
+            self._bias_uploads += 1
+        prompt = pack_prompt(
+            tokens, table, start=start, valid=valid, frontier=frontier,
+            slot=slot, sample=sampling["sample"], top_k=sampling["top_k"],
+            temp=sampling["temp"], top_p=sampling["top_p"])
+        return prompt, bias, self._key
+
+    def take_bias_uploads(self):
+        """Rows and matrices of bias sent to the device since the last
+        call (the scheduler folds it into ServingMetrics once a
+        round)."""
+        n, self._bias_uploads = self._bias_uploads, 0
+        return n
 
     def begin_prefill(self, slot, prompt, do_sample=False,
                       temperature=1.0, top_k=0, top_p=1.0,
@@ -623,18 +767,13 @@ class ServingEngine:
         with RecordEvent("serving/prefill/stage", pid=pid) as ev:
             n = len(prompt)
             padded = np.zeros((self.prefill_len,), np.int32)
-            padded[:n] = np.asarray(prompt, np.int32)
-            self._key, sub = jax.random.split(self._key)
+            padded[:n] = prompt
             args = (self._params, self._buffers, self._caches,
-                    jnp.asarray(padded), jnp.int32(n), jnp.int32(slot),
-                    jnp.asarray(sampling["sample"]),
-                    jnp.float32(sampling["temp"]),
-                    jnp.int32(sampling["top_k"]),
-                    jnp.float32(sampling["top_p"]),
-                    jnp.asarray(sampling["bias"]), sub)
+                    *self._prompt_args(slot, padded, 0, n, n - 1,
+                                       sampling))
         self._acc("prefill.stage", ev)
         with RecordEvent("serving/prefill/dispatch", pid=pid) as ev:
-            first, self._caches = self._prefill(*args)
+            first, self._caches, self._key = self._prefill(*args)
         self._dispatched("prefill.dispatch", ev)
         with RecordEvent("serving/prefill/first_token", pid=pid) as ev:
             first = int(np.asarray(first))
@@ -651,18 +790,20 @@ class ServingEngine:
         ride along frozen.
 
         Raise-type faults (chaos, or a real host-side error) fire
-        BEFORE the key splits or the donated cache reaches the program,
-        so a failed wave mutates nothing and a retry replays exactly.
+        BEFORE the donated cache reaches the program, and the key
+        advances only when the program returns the next one, so a
+        failed wave mutates nothing and a retry replays exactly.
         An error from inside the compiled call itself may have consumed
         the donated cache — the retry then fails too and the scheduler
         degrades gracefully instead of looping."""
-        active_now = list(self.slot_active)
-        if not any(active_now):
+        active_now = self.slot_active.copy()
+        if not active_now.any():
             self.last_nonfinite_slots = []
             self.last_starved_slots = []
             return {}
         if chaos.enabled():
-            chaos.fire(chaos.DECODE_WAVE, active=sum(active_now))
+            chaos.fire(chaos.DECODE_WAVE,
+                       active=int(np.count_nonzero(active_now)))
         # back each lane's next cache write (paged engines allocate
         # blocks here; a starved lane is excluded from this wave and
         # reported in last_starved_slots for the scheduler to preempt).
@@ -671,33 +812,30 @@ class ServingEngine:
         with RecordEvent("serving/wave/blocks", pid=pid) as ev:
             active_now = self._prepare_wave(active_now)
         self._acc("wave.blocks", ev)
-        if not any(active_now):
+        if not active_now.any():
             self.last_nonfinite_slots = []
             return {}
         with RecordEvent("serving/wave/stage", pid=pid) as ev:
-            poison = self._wave_poison()
-            self._key, sub = jax.random.split(self._key)
-            args = self._wave_args(active_now, poison, sub)
+            args = self._wave_args(active_now, self._wave_poison(),
+                                   self._key)
         self._acc("wave.stage", ev)
         with RecordEvent("serving/wave/dispatch", pid=pid) as ev:
-            tok, pos, finite, self._caches = self._decode_wave(*args)
+            tok, pos, finite, self._caches, self._key = \
+                self._decode_wave(*args)
         self._dispatched("wave.dispatch", ev)
         with RecordEvent("serving/wave/wait", pid=pid) as ev:
             tok = np.asarray(tok)
             finite = np.asarray(finite)
         self._read_back("wave.wait", ev)
-        out, bad = {}, []
-        for s, was_active in enumerate(active_now):
-            if not was_active:
-                continue
-            if not bool(finite[s]):
-                bad.append(s)       # lane frozen in-program; caller
-                continue            # must retire it before the next wave
-            self.slot_pos[s] += 1
-            self.slot_tok[s] = int(tok[s])
-            out[s] = int(tok[s])
-        self.last_nonfinite_slots = bad
-        return out
+        # a lane whose logits went non-finite is frozen in-program; the
+        # caller must retire it before the next wave
+        ok = active_now & finite
+        self.slot_pos[ok] += 1
+        self.slot_tok[ok] = tok[ok]
+        self.last_nonfinite_slots = np.flatnonzero(
+            active_now & ~finite).tolist()
+        lanes = np.flatnonzero(ok)
+        return dict(zip(lanes.tolist(), tok[lanes].tolist()))
 
     def _wave_poison(self):
         """[S] bool of lanes whose logits the chaos harness poisons in
@@ -717,33 +855,33 @@ class ServingEngine:
         self.last_starved_slots = []
         return active_now
 
-    def _sampling_args(self):
-        """The sampling-scenario vectors every wave uploads (per-slot
-        knobs + the [S, V] bias/mask matrix) — one place, so the dense,
-        paged and speculative wave argument tuples cannot drift."""
+    def _lane_args(self, active_now, poison, tables=None, spec_len=0):
+        """A wave program's arguments after the donated caches, before
+        the key: the packed per-lane state (pack_lanes: one place, so
+        the dense, paged and speculative waves cannot drift) and the
+        [S, V] bias/mask matrix, resident until a row changes."""
         if self._slot_bias_dev is None:
-            self._slot_bias_dev = jnp.asarray(self._slot_bias)
-        return (jnp.asarray(self.slot_sample, bool),
-                jnp.asarray(self.slot_temp, jnp.float32),
-                jnp.asarray(self.slot_top_k, jnp.int32),
-                jnp.asarray(self.slot_top_p, jnp.float32),
-                self._slot_bias_dev)
+            self._slot_bias_dev = jax.device_put(self._slot_bias)
+            self._bias_uploads += 1
+        lanes = pack_lanes(
+            tables, tok=self.slot_tok, pos=self.slot_pos,
+            active=active_now, sample=self.slot_sample,
+            top_k=self.slot_top_k, poison=poison, spec_len=spec_len,
+            temp=self.slot_temp, top_p=self.slot_top_p)
+        return lanes, self._slot_bias_dev
 
     def _wave_args(self, active_now, poison, key):
         """The decode-wave program's argument tuple (the paged engine
-        inserts its block tables after the donated caches)."""
+        packs its block tables in with the lanes). `key` is the
+        engine's: the program splits it."""
         return (self._params, self._buffers, self._caches,
-                jnp.asarray(self.slot_tok, jnp.int32),
-                jnp.asarray(self.slot_pos, jnp.int32),
-                jnp.asarray(active_now, bool),
-                *self._sampling_args(),
-                jnp.asarray(poison), key)
+                *self._lane_args(active_now, poison), key)
 
     def slot_full(self, slot):
         """True when the slot's next write would fall past the cache
         horizon (max_len - 1 is the last legal write) — the scheduler
         must retire it (finish_reason 'length') before the next wave."""
-        return self.slot_pos[slot] >= self.max_len
+        return bool(self.slot_pos[slot] >= self.max_len)
 
     def retire_slot(self, slot):
         """Free a slot between waves. The cache region is left as-is:
@@ -756,6 +894,6 @@ class ServingEngine:
         self.slot_top_k[slot] = 0
         self.slot_top_p[slot] = 1.0
         self.slot_dynamic_mask[slot] = False
-        self._set_bias_row(slot, np.zeros((self.vocab_size,), np.float32))
+        self._set_bias_row(slot, None)
         self._pending_prefill.pop(slot, None)
         self._slot_trace.pop(slot, None)

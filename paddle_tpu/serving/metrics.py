@@ -204,6 +204,10 @@ class ServingMetrics:
         # other): slot records zeroed at admission, (token, expert)
         # pairs routed
         self._model_counts = {"state_resets": 0, "moe_picks": 0}
+        # [V] rows and [S, V] matrices of logit bias the engine sent to
+        # the device (0 while no request brings a bias: the engine
+        # keeps a zero row and a zero matrix there)
+        self._bias_uploads = 0
         # per-phase wall time (seconds, folded in once per scheduler
         # round)
         self._phase_seconds = {}
@@ -286,6 +290,11 @@ class ServingMetrics:
             for k, v in counts.items():
                 self._model_counts[k] += int(v)
 
+    def on_bias_uploads(self, n):
+        """One round's `ServingEngine.take_bias_uploads()`."""
+        with self._lock:
+            self._bias_uploads += int(n)
+
     def on_queue_depth(self, depth):
         monitor.stat_max(QUEUE_DEPTH_PEAK, int(depth))  # process-wide peak
         _QUEUE_DEPTH.set(int(depth))
@@ -359,6 +368,7 @@ class ServingMetrics:
             phase_seconds = dict(self._phase_seconds)
             pages_v, pages_s = self._pages_visited, self._pages_spanned
             model_counts = dict(self._model_counts)
+            bias_uploads = self._bias_uploads
             spec_p, spec_a = self._spec_proposed, self._spec_accepted
             spec_w = self._spec_waves
         return {
@@ -414,4 +424,6 @@ class ServingMetrics:
             # slot records zeroed and (token, expert) pairs routed, for
             # a model that has either (serving/paged/engine.py)
             **model_counts,
+            # bias rows and matrices uploaded (serving/engine.py)
+            "bias_uploads": bias_uploads,
         }

@@ -25,7 +25,9 @@ compile-once discipline — table entries are VALUES, not shapes):
     are skipped outright.
 
 Block bookkeeping is host-authoritative like the rest of the slot
-state: the table upload is `S * nblk` int32 per wave. Allocation happens
+state (numpy, packed with the lanes into a wave's one small argument:
+serving/engine.py says what a stage may and may not do): the table
+upload is `S * nblk` int32 per wave. Allocation happens
 between waves; a wave whose lane cannot get a block (pool exhausted) is
 excluded from that wave and reported in `last_starved_slots` — the
 scheduler preempts it by recompute (requeue with prompt + generated
@@ -43,7 +45,8 @@ from ...utils import chaos, telemetry
 from ...utils.profiler import RecordEvent
 from .. import blackbox
 from ..engine import (ServingEngine, _filter_top_k_top_p, _raw,
-                      _select_first_token, _select_wave_tokens)
+                      _select_first_token, _select_wave_tokens,
+                      unpack_lanes, unpack_prompt)
 from .block_pool import BlockPool, BlockPoolExhausted
 
 #: block-level KV handoff payload schema version (export_slot_kv /
@@ -177,41 +180,44 @@ class PagedServingEngine(ServingEngine):
         model, kern = self.model, self.paged_kernel
         slot_state = self.slot_state
 
-        def decode_wave(p, b, caches, tables, tok, pos, active, sample,
-                        temps, top_k, top_p, bias, poison, key):
+        chunk_len = self.prefill_chunk_len
+
+        def decode_wave(p, b, caches, lanes, bias, key):
+            key, sub = jax.random.split(key)
+            tables, a = unpack_lanes(lanes)
             # the scope pins this engine's kernel at TRACE time: the
             # compiled wave keeps the core the engine was built with
-            lanes = {"active": active} if slot_state else {}
+            live = {"active": a["active"]} if slot_state else {}
             with paged_attention.kernel_scope(kern):
-                out, _ = model.functional_call(p, b, tok[:, None], caches,
-                                               pos, method="decode_step",
-                                               block_tables=tables, **lanes)
+                out, _ = model.functional_call(
+                    p, b, a["tok"][:, None], caches, a["pos"],
+                    method="decode_step", block_tables=tables, **live)
             logits, new_caches = out
             lo = _raw(logits)[:, 0, :].astype(jnp.float32)
             nxt, new_pos, finite = _select_wave_tokens(
-                lo, tok, pos, active, sample, temps, top_k, top_p, bias,
-                poison, key)
-            return nxt, new_pos, finite, new_caches
+                lo, a["tok"], a["pos"], a["active"], a["sample"],
+                a["temp"], a["top_k"], a["top_p"], bias, a["poison"], sub)
+            return nxt, new_pos, finite, new_caches, key
 
-        def prefill_chunk(p, b, caches, table, chunk, chunk_start,
-                          valid_len, frontier, sample, temp, top_k,
-                          top_p, bias, key, *slot):
-            # `slot`: the request's slot, passed for a model with slot
-            # state and for no other (their program keeps its arguments)
+        def prefill_chunk(p, b, caches, prompt, bias, key):
+            key, sub = jax.random.split(key)
+            table, chunk, a = unpack_prompt(prompt, chunk_len)
+            # the request's slot goes to a model with slot state and to
+            # no other
+            where = {"slot": a["slot"]} if slot_state else {}
             with paged_attention.kernel_scope(kern):
                 out, _ = model.functional_call(
                     p, b, chunk[None, :], caches, method="prefill_chunk",
-                    block_tables=table[None, :], chunk_start=chunk_start,
-                    valid_len=valid_len, frontier=frontier,
-                    **({"slot": slot[0]} if slot else {}))
+                    block_tables=table[None, :], chunk_start=a["start"],
+                    valid_len=a["valid"], frontier=a["frontier"], **where)
             logits, new_caches = out
             # frontier logits [1, 1, V]: only the FINAL chunk's value is
             # consumed on host; earlier chunks compute a [V] row that is
             # simply ignored (static shapes beat a conditional head)
             lo = _raw(logits)[0, 0].astype(jnp.float32)
-            first = _select_first_token(lo, sample, temp, top_k, top_p,
-                                        bias, key)
-            return first, new_caches
+            first = _select_first_token(lo, a["sample"], a["temp"],
+                                        a["top_k"], a["top_p"], bias, sub)
+            return first, new_caches, key
 
         def state_reset(caches, slot):
             """Zero one slot's record in every state array (a small
@@ -362,21 +368,14 @@ class PagedServingEngine(ServingEngine):
             chunk[:valid] = st["prompt"][c0:c0 + valid]
             last = c0 + C >= n
             frontier = (n - 1) - c0 if last else 0
-            self._key, sub = jax.random.split(self._key)
             sampling = st["sampling"]
             args = (*self._prefill_chunk_args(slot),
-                    jnp.asarray(self._tables[slot]), jnp.asarray(chunk),
-                    jnp.int32(c0), jnp.int32(valid), jnp.int32(frontier),
-                    jnp.asarray(sampling["sample"]),
-                    jnp.float32(sampling["temp"]),
-                    jnp.int32(sampling["top_k"]),
-                    jnp.float32(sampling["top_p"]),
-                    jnp.asarray(sampling["bias"]), sub,
-                    *((jnp.int32(slot),) if self.slot_state else ()))
+                    *self._prompt_args(slot, chunk, c0, valid, frontier,
+                                       sampling, self._tables[slot]))
             self._moe_picks += valid * self._picks_per_token
         self._acc("prefill.stage", ev)
         with RecordEvent("serving/prefill/dispatch", pid=pid) as ev:
-            first, self._caches = self._prefill(*args)
+            first, self._caches, self._key = self._prefill(*args)
         self._dispatched("prefill.dispatch", ev)
         # full prompt blocks written by this chunk enter the prefix
         # cache — only now, so a concurrent admission can never share a
@@ -589,10 +588,9 @@ class PagedServingEngine(ServingEngine):
         sharing keeps the frontier private by construction) is
         copy-on-write'd first."""
         starved = []
-        for s, live in enumerate(active_now):
-            if not live:
-                continue
-            bi = self.slot_pos[s] // self.block_size
+        block_of = (self.slot_pos // self.block_size).tolist()
+        for s in np.flatnonzero(active_now).tolist():
+            bi = block_of[s]
             blocks = self._slot_blocks[s]
             try:
                 if bi >= len(blocks):
@@ -607,30 +605,30 @@ class PagedServingEngine(ServingEngine):
         self.last_starved_slots = starved
         return active_now
 
+    def _wave_tables(self, active_now):
+        """The tables a wave takes. The program scatters EVERY lane's
+        K/V unconditionally (fixed shapes); a lane not in THIS wave
+        (free, mid-prefill, starved) would write its stale token through
+        its table row into a live block — a mid-chunked-prefill slot's
+        table is already populated, possibly with SHARED blocks. Those
+        lanes get scratch rows, so the write lands in block 0 by
+        design."""
+        return np.where(np.asarray(active_now, bool)[:, None],
+                        self._tables, np.int32(BlockPool.SCRATCH))
+
     def _wave_args(self, active_now, poison, key):
-        # the program scatters EVERY lane's K/V unconditionally (fixed
-        # shapes); a lane not in THIS wave (free, mid-prefill, starved)
-        # would write its stale token through its table row into a live
-        # block — a mid-chunked-prefill slot's table is already
-        # populated, possibly with SHARED blocks. Upload scratch rows
-        # for those lanes so the write lands in block 0 by design.
-        tables = np.where(np.asarray(active_now, bool)[:, None],
-                          self._tables, np.int32(BlockPool.SCRATCH))
+        tables = self._wave_tables(active_now)
         # every lane rides the wave at its `slot_pos`, the ones not in
         # it at a stale one over a scratch row: the core walks those too
         lo, hi = paged_attention.attended_pages(
-            np.asarray(self.slot_pos), 1, self.block_size,
+            self.slot_pos, 1, self.block_size,
             self.blocks_per_slot, self._attn_window)
         self._pages_visited += int(np.sum(hi - lo))
         self._pages_spanned += tables.size
-        self._moe_picks += sum(active_now) * self._picks_per_token
+        self._moe_picks += (int(np.count_nonzero(active_now))
+                            * self._picks_per_token)
         return (self._params, self._buffers, self._caches,
-                jnp.asarray(tables),
-                jnp.asarray(self.slot_tok, jnp.int32),
-                jnp.asarray(self.slot_pos, jnp.int32),
-                jnp.asarray(active_now, bool),
-                *self._sampling_args(),
-                jnp.asarray(poison), key)
+                *self._lane_args(active_now, poison, tables), key)
 
     # ----------------------------------------------------- copy-on-write
     def _ensure_private(self, slot, bi):
@@ -661,7 +659,7 @@ class PagedServingEngine(ServingEngine):
             self._copy_fn = (telemetry.instrument_jit(
                 jax.jit(copy_fn, donate_argnums=(0,)), "paged_cow_copy")
                 if self._jit else copy_fn)
-        return self._copy_fn(caches, jnp.int32(src), jnp.int32(dst))
+        return self._copy_fn(caches, np.int32(src), np.int32(dst))
 
     def take_page_counts(self):
         """(visited, spanned) table entries of the decode waves staged
@@ -874,18 +872,22 @@ class SpeculativePagedEngine(PagedServingEngine):
     # -------------------------------------------------------- programs
     def _build_programs(self):
         model, draft, k = self.model, self.draft_model, self.spec_k
-        kern = self.paged_kernel
+        kern, chunk_len = self.paged_kernel, self.prefill_chunk_len
 
-        def draft_wave(dp, db, caches, tables, tok, pos, sample,
-                       temps, top_k, top_p, bias, spec_len, key):
+        def draft_wave(dp, db, caches, lanes, bias, key):
             """k+1 draft decode steps in ONE executable: step j writes
             the fed token's K/V at pos+j and proposes the next; the
             final step is write-only (it commits d_k's K/V so a fully
             accepted span leaves the draft cache synchronized). Writes
             past a lane's spec_len land in the scratch block via a
-            scratch table row — per-step, per-lane, still one program."""
+            scratch table row — per-step, per-lane, still one program.
+            Takes the engine's key and returns the next one; the steps
+            draw from a chain of the subkey's own."""
+            engine_key, key = jax.random.split(key)
+            tables, a = unpack_lanes(lanes)
+            pos, spec_len, sample = a["pos"], a["spec_len"], a["sample"]
             tgt_caches, dr_caches = caches
-            cur = tok
+            cur = a["tok"]
             toks, probs = [], []
             for j in range(k + 1):
                 tab_j = jnp.where((j <= spec_len)[:, None], tables,
@@ -899,8 +901,8 @@ class SpeculativePagedEngine(PagedServingEngine):
                     break               # write-only step: no proposal
                 lo = _raw(logits)[:, 0, :].astype(jnp.float32) + bias
                 greedy = jnp.argmax(lo, axis=-1).astype(jnp.int32)
-                scaled = lo / jnp.maximum(temps, 1e-6)[:, None]
-                filt = _filter_top_k_top_p(scaled, top_k, top_p)
+                scaled = lo / jnp.maximum(a["temp"], 1e-6)[:, None]
+                filt = _filter_top_k_top_p(scaled, a["top_k"], a["top_p"])
                 key, sub = jax.random.split(key)
                 sampled = jax.random.categorical(
                     sub, filt, axis=-1).astype(jnp.int32)
@@ -908,57 +910,57 @@ class SpeculativePagedEngine(PagedServingEngine):
                 toks.append(cur)
                 probs.append(jax.nn.softmax(filt, axis=-1))
             return (jnp.stack(toks, axis=1), jnp.stack(probs, axis=1),
-                    (tgt_caches, dr_caches))
+                    (tgt_caches, dr_caches), engine_key)
 
-        def spec_verify(p, b, caches, tables, tok, pos, active, sample,
-                        temps, top_k, top_p, bias, spec_len, draft_toks,
-                        draft_probs, poison, key):
+        def spec_verify(p, b, caches, lanes, bias, draft_toks,
+                        draft_probs, key):
             """Verify-once: ONE target forward scores all k+1 positions
             of every lane (the chunk program's own model call, at
             [S, k + 1] with every lane's start and span and no frontier),
             then the exact acceptance-rejection tail."""
+            key, sub = jax.random.split(key)
+            tables, a = unpack_lanes(lanes)
             tgt_caches, dr_caches = caches
-            chunk = jnp.concatenate([tok[:, None], draft_toks], axis=1)
+            chunk = jnp.concatenate([a["tok"][:, None], draft_toks],
+                                    axis=1)
             with paged_attention.kernel_scope(kern):
                 out, _ = model.functional_call(
                     p, b, chunk, tgt_caches, method="prefill_chunk",
-                    block_tables=tables, chunk_start=pos,
-                    valid_len=spec_len + 1)
+                    block_tables=tables, chunk_start=a["pos"],
+                    valid_len=a["spec_len"] + 1)
             logits, tgt_caches = out
             lo = _raw(logits).astype(jnp.float32)       # [S, k+1, V]
             out_toks, n_emit, nxt, new_pos, finite = _spec_verify_tail(
-                lo, tok, pos, active, sample, temps, top_k, top_p, bias,
-                spec_len, draft_toks, draft_probs, poison, key)
+                lo, a["tok"], a["pos"], a["active"], a["sample"],
+                a["temp"], a["top_k"], a["top_p"], bias, a["spec_len"],
+                draft_toks, draft_probs, a["poison"], sub)
             return (out_toks, n_emit, nxt, new_pos, finite,
-                    (tgt_caches, dr_caches))
+                    (tgt_caches, dr_caches), key)
 
-        def prefill_chunk(p, b, caches, dp, db, table, chunk,
-                          chunk_start, valid_len, frontier, sample, temp,
-                          top_k, top_p, bias, key):
+        def prefill_chunk(p, b, caches, dp, db, prompt, bias, key):
             """The spec configuration's ONE prefill program: the chunk
             writes the TARGET pools (frontier logits select the first
             token, exactly the non-spec chunk) AND the DRAFT pools — a
             draft cache synchronized at admission is what lets the
             first decode wave start drafting immediately, and a
             prefix-cache hit skips the chunk for both models at once."""
+            key, sub = jax.random.split(key)
+            table, chunk, a = unpack_prompt(prompt, chunk_len)
+            step = dict(method="prefill_chunk", block_tables=table[None, :],
+                        chunk_start=a["start"], valid_len=a["valid"],
+                        frontier=a["frontier"])
             tgt_caches, dr_caches = caches
             with paged_attention.kernel_scope(kern):
-                out, _ = model.functional_call(
-                    p, b, chunk[None, :], tgt_caches,
-                    method="prefill_chunk", block_tables=table[None, :],
-                    chunk_start=chunk_start, valid_len=valid_len,
-                    frontier=frontier)
+                out, _ = model.functional_call(p, b, chunk[None, :],
+                                               tgt_caches, **step)
                 logits, tgt_caches = out
-                dout, _ = draft.functional_call(
-                    dp, db, chunk[None, :], dr_caches,
-                    method="prefill_chunk", block_tables=table[None, :],
-                    chunk_start=chunk_start, valid_len=valid_len,
-                    frontier=frontier)
+                dout, _ = draft.functional_call(dp, db, chunk[None, :],
+                                                dr_caches, **step)
             _, dr_caches = dout         # draft frontier logits unused
             lo = _raw(logits)[0, 0].astype(jnp.float32)
-            first = _select_first_token(lo, sample, temp, top_k, top_p,
-                                        bias, key)
-            return first, (tgt_caches, dr_caches)
+            first = _select_first_token(lo, a["sample"], a["temp"],
+                                        a["top_k"], a["top_p"], bias, sub)
+            return first, (tgt_caches, dr_caches), key
 
         self._draft_wave_fn = draft_wave
         self._decode_wave_fn = spec_verify
@@ -1001,10 +1003,10 @@ class SpeculativePagedEngine(PagedServingEngine):
         the wave and preempted by recompute, exactly like the
         single-token engine."""
         starved, bs = [], self.block_size
-        for s, live in enumerate(active_now):
-            if not live:
-                continue
-            last_bi = (self.slot_pos[s] + self._wave_spec_len[s]) // bs
+        first_of = (self.slot_pos // bs).tolist()
+        last_of = ((self.slot_pos + self._wave_spec_len) // bs).tolist()
+        for s in np.flatnonzero(active_now).tolist():
+            last_bi = last_of[s]
             blocks = self._slot_blocks[s]
             try:
                 missing = last_bi + 1 - len(blocks)
@@ -1012,7 +1014,7 @@ class SpeculativePagedEngine(PagedServingEngine):
                     for blk in self.block_pool.alloc(missing):
                         blocks.append(blk)
                         self._tables[s, len(blocks) - 1] = blk
-                for bi in range(self.slot_pos[s] // bs, last_bi + 1):
+                for bi in range(first_of[s], last_bi + 1):
                     if self.block_pool.refcount(blocks[bi]) > 1:
                         self._ensure_private(s, bi)
             except BlockPoolExhausted:
@@ -1032,7 +1034,7 @@ class SpeculativePagedEngine(PagedServingEngine):
         bs = self.block_size
         for s in wave_slots:
             blocks = self._slot_blocks[s]
-            needed = max(1, (self.slot_pos[s] + bs - 1) // bs)
+            needed = max(1, (int(self.slot_pos[s]) + bs - 1) // bs)
             if len(blocks) > needed:
                 extra = blocks[needed:]
                 del blocks[needed:]
@@ -1046,58 +1048,52 @@ class SpeculativePagedEngine(PagedServingEngine):
         eos/budget/stop). Poisoned/non-finite lanes emit nothing, are
         listed in `last_nonfinite_slots`, and their speculation is
         rolled back with the rest."""
-        active_now = list(self.slot_active)
-        if not any(active_now):
+        active_now = self.slot_active.copy()
+        if not active_now.any():
             self.last_nonfinite_slots = []
             self.last_starved_slots = []
             return {}
         if chaos.enabled():
-            chaos.fire(chaos.DECODE_WAVE, active=sum(active_now))
+            chaos.fire(chaos.DECODE_WAVE,
+                       active=int(np.count_nonzero(active_now)))
         # per-lane draft span: the horizon clamps it (writes stop at
         # max_len - 1), a dynamic token-mask lane runs at 0 — the
         # verify chunk then degenerates to the plain single-token wave
         # for that lane, mask applied, same program
-        spec_len = [0] * self.num_slots
-        for s, live in enumerate(active_now):
-            if live:
-                limit = self.max_len - 1 - self.slot_pos[s]
-                want = 0 if self.slot_dynamic_mask[s] else self.spec_k
-                spec_len[s] = max(0, min(want, limit))
+        limit = np.maximum(self.max_len - 1 - self.slot_pos, 0)
+        spec_len = np.where(active_now & ~self.slot_dynamic_mask,
+                            np.minimum(self.spec_k, limit),
+                            0).astype(np.int32)
         self._wave_spec_len = spec_len
         pid = self.trace_pid
         with RecordEvent("serving/wave/blocks", pid=pid) as ev:
             active_now = self._prepare_wave(active_now)
         self._acc("wave.blocks", ev)
-        if not any(active_now):
+        if not active_now.any():
             self.last_nonfinite_slots = []
             return {}
         with RecordEvent("serving/wave/stage", pid=pid) as ev:
-            poison = self._wave_poison()
-            self._key, dkey = jax.random.split(self._key)
-            self._key, vkey = jax.random.split(self._key)
-            tables = jnp.asarray(
-                np.where(np.asarray(active_now, bool)[:, None],
-                         self._tables, np.int32(BlockPool.SCRATCH)))
-            tok = jnp.asarray(self.slot_tok, jnp.int32)
-            pos = jnp.asarray(self.slot_pos, jnp.int32)
-            act = jnp.asarray(active_now, bool)
-            sampling = self._sampling_args()
-            sl = jnp.asarray(spec_len, jnp.int32)
-            poison = jnp.asarray(poison)
+            lanes, bias = self._lane_args(
+                active_now, self._wave_poison(),
+                self._wave_tables(active_now), spec_len)
         self._acc("wave.stage", ev)
         with RecordEvent("serving/wave/dispatch", pid=pid) as ev:
-            # the draft wave takes no active mask: inactive lanes ride
-            # scratch table rows and their proposals are discarded by
-            # the verify tail's active where — one argument fewer keeps
-            # every draft input live for the donation audit
-            draft_toks, draft_probs, self._caches = self._draft_wave(
-                self._draft_params, self._draft_buffers, self._caches,
-                tables, tok, pos, *sampling, sl, dkey)
-            out_toks, n_emit, nxt, new_pos, finite, self._caches = \
-                self._decode_wave(
-                    self._params, self._buffers, self._caches, tables,
-                    tok, pos, act, *sampling, sl, draft_toks,
-                    draft_probs, poison, vkey)
+            # both programs take the one packed argument, sent once. The
+            # draft wave reads no active mask out of it: inactive lanes
+            # ride scratch table rows and their proposals are discarded
+            # by the verify tail's active where. Each program splits the
+            # key it is given and hands the next on: the draft's subkey,
+            # then the verify's, as two eager splits in a row would draw
+            # them
+            lanes = jax.device_put(lanes)
+            draft_toks, draft_probs, self._caches, self._key = \
+                self._draft_wave(
+                    self._draft_params, self._draft_buffers, self._caches,
+                    lanes, bias, self._key)
+            out_toks, n_emit, nxt, new_pos, finite, self._caches, \
+                self._key = self._decode_wave(
+                    self._params, self._buffers, self._caches, lanes,
+                    bias, draft_toks, draft_probs, self._key)
         self._dispatched("wave.dispatch", ev)
         with RecordEvent("serving/wave/wait", pid=pid) as ev:
             out_toks = np.asarray(out_toks)
@@ -1106,24 +1102,20 @@ class SpeculativePagedEngine(PagedServingEngine):
             new_pos = np.asarray(new_pos)
             finite = np.asarray(finite)
         self._read_back("wave.wait", ev)
-        out, bad, waved = {}, [], []
-        proposed = accepted = 0
-        for s, was_active in enumerate(active_now):
-            if not was_active:
-                continue
-            waved.append(s)
-            if not bool(finite[s]):
-                bad.append(s)       # lane frozen in-program; caller
-                continue            # must retire it before the next wave
-            n = int(n_emit[s])
-            proposed += spec_len[s]
-            accepted += n - 1       # the extra token is never a draft's
-            self.slot_pos[s] = int(new_pos[s])
-            self.slot_tok[s] = int(nxt[s])
-            out[s] = [int(t) for t in out_toks[s, :n]]
-        self.last_nonfinite_slots = bad
-        self.last_spec_proposed = proposed
-        self.last_spec_accepted = accepted
+        # a lane whose logits went non-finite is frozen in-program; the
+        # caller must retire it before the next wave
+        ok = active_now & finite
+        self.slot_pos[ok] = new_pos[ok]
+        self.slot_tok[ok] = nxt[ok]
+        waved = np.flatnonzero(active_now).tolist()
+        emitted = np.flatnonzero(ok).tolist()
+        out = {s: out_toks[s, :n].tolist()
+               for s, n in zip(emitted, n_emit[ok].tolist())}
+        self.last_nonfinite_slots = np.flatnonzero(
+            active_now & ~finite).tolist()
+        self.last_spec_proposed = int(spec_len[ok].sum())
+        # the extra token is never a draft's
+        self.last_spec_accepted = int(n_emit[ok].sum()) - len(emitted)
         # rejected-token blocks go back NOW, poisoned lanes included —
         # the pool must never hold blocks for tokens that don't exist
         self._rollback_spec_blocks(waved)

@@ -855,7 +855,7 @@ class Scheduler:
         self._round_worked = False
         pending = 0
         ev = RecordEvent("serving/round", pid=self.trace_pid,
-                         round=self._round, lanes=sum(eng.slot_active),
+                         round=self._round, lanes=int(eng.slot_active.sum()),
                          prefilling=len(eng.prefilling_slots()))
         try:
             with ev:
@@ -874,6 +874,9 @@ class Scheduler:
                 self.metrics.on_pages(*eng.take_page_counts())
             if self._counts_model_work:
                 self.metrics.on_model_counts(eng.take_model_counts())
+            uploads = eng.take_bias_uploads()
+            if uploads:
+                self.metrics.on_bias_uploads(uploads)
             if not (self._round_worked and pending):
                 # an empty server is not a slow host: what passes until
                 # the next dispatch is not the host's doing

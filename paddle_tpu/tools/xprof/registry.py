@@ -97,45 +97,31 @@ def engine_program_specs(engine, prefix=None):
     return _dense_engine_specs(engine, prefix or "serving")
 
 
-def _sampling_vec_args(engine):
-    """The shared sampling-scenario vectors every wave program takes
-    (sample flag, temperature, top-k, top-p, [S, V] bias/mask) — the
-    audit specs mirror engine._sampling_args so signatures can't
-    drift."""
-    import jax.numpy as jnp
+def _greedy(engine):
+    """A request's sampling surface with every knob at rest."""
+    return engine._sampling_state(False, 1.0, 0, 1.0, None, False)
+
+
+def _all_lanes(engine):
+    """(active, poison) of a wave with every lane decoding."""
+    import numpy as np
     S = engine.num_slots
-    return (jnp.zeros((S,), bool), jnp.ones((S,), jnp.float32),
-            jnp.zeros((S,), jnp.int32), jnp.ones((S,), jnp.float32),
-            jnp.zeros((S, engine.vocab_size), jnp.float32))
-
-
-def _prefill_sampling_args(engine):
-    """The prefill programs' per-request sampling scalars + bias row."""
-    import jax.numpy as jnp
-    return (jnp.asarray(False), jnp.float32(1.0), jnp.int32(0),
-            jnp.float32(1.0),
-            jnp.zeros((engine.vocab_size,), jnp.float32))
+    return np.ones((S,), bool), np.zeros((S,), bool)
 
 
 def _dense_engine_specs(engine, prefix):
-    import jax
-    import jax.numpy as jnp
+    """The engine's own closures with the engine's own argument tuples
+    (`_wave_args`, `_prompt_args`): what an audit lowers is what a
+    dispatch sends, so the two cannot drift."""
     import numpy as np
 
     S = engine.num_slots
-    key = jax.random.PRNGKey(0)
     jit_kwargs = {"donate_argnums": engine._program_donate_argnums}
-    decode_args = (
-        engine._params, engine._buffers, engine._caches,
-        jnp.zeros((S,), jnp.int32), jnp.zeros((S,), jnp.int32),
-        jnp.ones((S,), bool), *_sampling_vec_args(engine),
-        jnp.zeros((S,), bool),          # poison (chaos NaN injection)
-        key)
+    decode_args = engine._wave_args(*_all_lanes(engine), engine._key)
     prefill_args = (
         engine._params, engine._buffers, engine._caches,
-        jnp.asarray(np.zeros((engine.prefill_len,), np.int32)),
-        jnp.int32(1), jnp.int32(0), *_prefill_sampling_args(engine),
-        key)
+        *engine._prompt_args(0, np.zeros((engine.prefill_len,), np.int32),
+                             0, 1, 0, _greedy(engine)))
     return [
         {"name": f"{prefix}_decode_wave", "fn": engine._decode_wave_fn,
          "args": decode_args, "jit_kwargs": jit_kwargs,
@@ -148,28 +134,21 @@ def _dense_engine_specs(engine, prefix):
     ]
 
 
-def _paged_engine_specs(engine, prefix):
-    import jax
-    import jax.numpy as jnp
+def _chunk_args(engine):
+    """The prefill-chunk program's arguments for one chunk of slot 0."""
     import numpy as np
-
-    S, nblk = engine.num_slots, engine.blocks_per_slot
     C = engine.prefill_chunk_len
-    key = jax.random.PRNGKey(0)
+    return (*engine._prefill_chunk_args(0),
+            *engine._prompt_args(0, np.zeros((C,), np.int32), 0, 1, 0,
+                                 _greedy(engine), engine._tables[0]))
+
+
+def _paged_engine_specs(engine, prefix):
+    S = engine.num_slots
+    C = engine.prefill_chunk_len
     jit_kwargs = {"donate_argnums": engine._program_donate_argnums}
-    decode_args = (
-        engine._params, engine._buffers, engine._caches,
-        jnp.zeros((S, nblk), jnp.int32),     # block tables (traced!)
-        jnp.zeros((S,), jnp.int32), jnp.zeros((S,), jnp.int32),
-        jnp.ones((S,), bool), *_sampling_vec_args(engine),
-        jnp.zeros((S,), bool),               # poison
-        key)
-    prefill_args = (
-        engine._params, engine._buffers, engine._caches,
-        jnp.zeros((nblk,), jnp.int32),       # one slot's table row
-        jnp.asarray(np.zeros((C,), np.int32)),
-        jnp.int32(0), jnp.int32(1), jnp.int32(0),
-        *_prefill_sampling_args(engine), key)
+    # the block tables ride in the packed lanes: traced values
+    decode_args = engine._wave_args(*_all_lanes(engine), engine._key)
     return [
         {"name": f"{prefix}_decode_wave", "fn": engine._decode_wave_fn,
          "args": decode_args, "jit_kwargs": jit_kwargs,
@@ -178,7 +157,7 @@ def _paged_engine_specs(engine, prefix):
                         f"blocks={engine.block_pool.num_blocks}x"
                         f"{engine.block_size})"},
         {"name": f"{prefix}_prefill_chunk", "fn": engine._prefill_fn,
-         "args": prefill_args, "jit_kwargs": jit_kwargs,
+         "args": _chunk_args(engine), "jit_kwargs": jit_kwargs,
          "description": f"one prompt chunk admission through a block "
                         f"table (chunk={C})"},
     ]
@@ -192,38 +171,21 @@ def _spec_engine_specs(engine, prefix):
     donation rule runs over these to prove BOTH the target and draft
     KV-pool leaves stay aliased; hlo_audit banks the verify program's
     bytes-accessed so a k+1-disproportionate regression gates."""
-    import jax
-    import jax.numpy as jnp
     import numpy as np
 
-    S, nblk, k = engine.num_slots, engine.blocks_per_slot, engine.spec_k
+    S, k, V = engine.num_slots, engine.spec_k, engine.vocab_size
     C = engine.prefill_chunk_len
-    V = engine.vocab_size
-    key = jax.random.PRNGKey(0)
     jit_kwargs = {"donate_argnums": engine._program_donate_argnums}
-    tables = jnp.zeros((S, nblk), jnp.int32)        # traced tables
-    tok = jnp.zeros((S,), jnp.int32)
-    pos = jnp.zeros((S,), jnp.int32)
-    spec_len = jnp.ones((S,), jnp.int32)
-    # the draft wave has no active mask (inactive lanes ride scratch
-    # table rows; the verify tail discards their proposals)
+    # both waves take the one packed argument (tables, lanes, spec_len)
+    lanes, bias = engine._lane_args(
+        *_all_lanes(engine), engine._tables, np.ones((S,), np.int32))
     draft_args = (engine._draft_params, engine._draft_buffers,
-                  engine._caches, tables, tok, pos,
-                  *_sampling_vec_args(engine), spec_len, key)
+                  engine._caches, lanes, bias, engine._key)
     verify_args = (
-        engine._params, engine._buffers, engine._caches, tables, tok,
-        pos, jnp.ones((S,), bool), *_sampling_vec_args(engine), spec_len,
-        jnp.zeros((S, k), jnp.int32),               # draft tokens
-        jnp.zeros((S, k, V), jnp.float32),          # draft probs
-        jnp.zeros((S,), bool),                      # poison
-        key)
-    prefill_args = (
-        engine._params, engine._buffers, engine._caches,
-        engine._draft_params, engine._draft_buffers,
-        jnp.zeros((nblk,), jnp.int32),
-        jnp.asarray(np.zeros((C,), np.int32)),
-        jnp.int32(0), jnp.int32(1), jnp.int32(0),
-        *_prefill_sampling_args(engine), key)
+        engine._params, engine._buffers, engine._caches, lanes, bias,
+        np.zeros((S, k), np.int32),                 # draft tokens
+        np.zeros((S, k, V), np.float32),            # draft probs
+        engine._key)
     return [
         {"name": f"{prefix}_draft_wave", "fn": engine._draft_wave_fn,
          "args": draft_args, "jit_kwargs": jit_kwargs,
@@ -235,7 +197,7 @@ def _spec_engine_specs(engine, prefix):
                         f"over C=k+1={engine.spec_k + 1} positions + "
                         "exact acceptance-rejection"},
         {"name": f"{prefix}_prefill_chunk", "fn": engine._prefill_fn,
-         "args": prefill_args, "jit_kwargs": jit_kwargs,
+         "args": _chunk_args(engine), "jit_kwargs": jit_kwargs,
          "description": f"dual-model prompt chunk admission (target + "
                         f"draft K/V, chunk={C})"},
     ]

@@ -440,12 +440,11 @@ def test_device_work_carries_its_scope_names(model, engine):
     for scope in ("ssm_step", "moe_route", "moe_experts", "moe_shared"):
         assert scope in wave, scope
     assert "ssm_scan" not in wave
+    greedy = engine._sampling_state(False, 1.0, 0, 1.0, None, False)
     chunk = jax.jit(engine._prefill_fn).lower(
         engine._params, engine._buffers, engine._caches,
-        jnp.zeros(MAX_LEN // BLOCK, jnp.int32), jnp.zeros(CHUNK, jnp.int32),
-        np.int32(0), np.int32(CHUNK), np.int32(0), jnp.asarray(False),
-        np.float32(1), np.int32(0), np.float32(1),
-        jnp.zeros(VOCAB, jnp.float32), key, np.int32(0)
+        *engine._prompt_args(0, np.zeros(CHUNK, np.int32), 0, CHUNK, 0,
+                             greedy, engine._tables[0])
     ).as_text(debug_info=True)
     assert "ssm_scan" in chunk and "moe_experts" in chunk
 
@@ -503,8 +502,13 @@ def test_programs_of_models_without_slot_state_take_no_new_argument(model):
         eng = PagedServingEngine(plain, **kw)
         assert not eng.slot_state and not eng.counts_model_work
         assert "state_bytes" not in eng._health()
-        assert _program_arity(eng) == (14, 14)
-    assert _program_arity(PagedServingEngine(model, **kw)) == (15, 14)
+        # parameters, buffers, caches, the packed small arguments, the
+        # bias, the key: the slot and the active mask ride in the packed
+        # one for every model, and reach only a model with slot state
+        # (these two's `prefill_chunk` / `decode_step` have no such
+        # parameter and would refuse it)
+        assert _program_arity(eng) == (6, 6)
+    assert _program_arity(PagedServingEngine(model, **kw)) == (6, 6)
 
 
 def test_the_front_door_serves_it(model, engine):

@@ -521,16 +521,21 @@ def test_bias_matrix_uploaded_once_for_bias_free_streams(paged):
     reqs = [sched.submit(prompt=_prompt(109 + i), max_tokens=4)
             for i in range(2)]
     sched.step()
-    dev1 = paged._sampling_args()[-1]
+    def bias_matrix():
+        """The device array a wave staged now would be handed."""
+        live = paged.slot_active.copy()
+        return paged._lane_args(live, np.zeros_like(live))[-1]
+
+    dev1 = bias_matrix()
     sched.step()
-    dev2 = paged._sampling_args()[-1]
+    dev2 = bias_matrix()
     assert dev1 is dev2, "bias-free waves re-uploaded the bias matrix"
     paged.set_slot_bias(reqs[0].slot, {3: -1e9})
-    dev3 = paged._sampling_args()[-1]
+    dev3 = bias_matrix()
     assert dev3 is not dev2
     assert float(dev3[reqs[0].slot, 3]) == -1e9
     sched.run()
-    assert float(np.asarray(paged._sampling_args()[-1]).sum()) == 0.0
+    assert float(np.asarray(bias_matrix()).sum()) == 0.0
 
 
 def test_raising_token_mask_fails_only_its_request(paged):

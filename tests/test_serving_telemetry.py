@@ -121,7 +121,11 @@ def test_snapshot_keys_byte_compatible(engine):
         "paged_pages_visited", "paged_pages_spanned",
         # what a model with slot state or experts was staged (0 / 0 for
         # any other)
-        "state_resets", "moe_picks"]
+        "state_resets", "moe_picks",
+        # bias rows and matrices sent to the device (0 while no request
+        # brings a bias)
+        "bias_uploads"]
+    assert snap["bias_uploads"] == 0
     # a 3-token request has 2 inter-token gaps — TPOT is real, and the
     # phase split saw every phase of a working round
     assert snap["tpot_p50_s"] is not None

@@ -274,11 +274,12 @@ def test_engine_programs_keep_the_pool_in_place_for_v5e(one_chip, as_on_tpu,
         args = eng._wave_args([True] * slots, np.zeros(slots, bool), key)
     else:
         fn = eng._prefill_fn
-        args = (*eng._prefill_chunk_args(0), jnp.asarray(eng._tables[0]),
-                jnp.zeros(chunk, jnp.int32), np.int32(0), np.int32(chunk),
-                np.int32(0), jnp.asarray(False), np.float32(1),
-                np.int32(0), np.float32(1), jnp.zeros(512, jnp.float32),
-                key)
+        # the tuple `prefill_step` stages: the packed chunk, the
+        # resident zero bias row, the engine's key
+        greedy = eng._sampling_state(False, 1.0, 0, 1.0, None, False)
+        args = (*eng._prefill_chunk_args(0),
+                *eng._prompt_args(0, np.zeros(chunk, np.int32), 0, chunk, 0,
+                                  greedy, eng._tables[0]))
     pools = jax.tree_util.tree_leaves(eng._caches)
     assert [p.shape[1:] for p in pools] in (
         [(2, 16, 128)] * 2, [(2, 16, 256)] * 2)
