@@ -21,8 +21,8 @@ import jax.numpy as jnp
 from .. import nn
 from ..framework.tensor import Tensor
 from ..nn import initializer as I
-from ..ops.pallas.grouped_mlp import grouped_mlp
 from .llama import _gqa_flash_bshd, _rms_norm_raw
+from .moe import ParamBlock as _Block, RoutedExperts, route  # noqa: F401
 
 _HIGHEST = jax.lax.Precision.HIGHEST
 
@@ -90,28 +90,6 @@ class NemotronHConfig:
 
 def _raw(x):
     return x._data if isinstance(x, Tensor) else x
-
-
-class _Block(nn.Layer):
-    """What the three mixers share: parameter creation in the config's
-    dtype, matrices normal(0, initializer_range) or, with
-    `init_weights=False`, zeros."""
-
-    def __init__(self, cfg):
-        super().__init__()
-        self.cfg = cfg
-
-    def _matrix(self, *shape):
-        cfg = self.cfg
-        init = (I.Normal(0.0, cfg.initializer_range) if cfg.init_weights
-                else I.Constant(0.0))
-        return self.create_parameter(list(shape), dtype=cfg.param_dtype,
-                                     default_initializer=init)
-
-    def _vector(self, n, value):
-        return self.create_parameter(
-            [n], dtype=self.cfg.param_dtype,
-            default_initializer=I.Constant(value))
 
 
 # ------------------------------------------------------------------ Mamba-2
@@ -321,89 +299,14 @@ class NemotronHAttention(_Block):
 
 
 # ------------------------------------------------------------------ experts
-def route(scores_bias, logits, k, scale):
-    """(experts [T, k], weights [T, k] float32) from router logits
-    [T, E]: s = sigmoid(logits); the k largest of s + bias are chosen;
-    their weights are the chosen s, without the bias, over their sum,
-    times `scale`."""
-    s = jax.nn.sigmoid(logits.astype(jnp.float32))
-    _, idx = jax.lax.top_k(s + scores_bias.astype(jnp.float32), k)
-    w = jnp.take_along_axis(s, idx, axis=-1)
-    return idx, w / jnp.sum(w, axis=-1, keepdims=True) * scale
-
-
-class NemotronHMoE(_Block):
+class NemotronHMoE(RoutedExperts):
     """Dropless routed experts `down(relu(up(x))^2)` and one shared
-    expert of the same form. Picks are sorted by expert and one kernel
-    walks the experts, each over its own rows
-    (`ops/pallas/grouped_mlp.py`): no capacity, nothing dropped, no
-    per-token copy of an expert's weights."""
-
-    #: picks one call of the kernel keeps resident; more tokens than
-    #: this many picks go through it a segment at a time
-    MAX_ROWS = 1024
+    expert of the same form: the ungated form of the experts that the
+    routed-expert models share (`nlp/moe.py`: router, sort by expert,
+    grouped kernel)."""
 
     def __init__(self, cfg):
-        super().__init__(cfg)
-        h, e, m = (cfg.hidden_size, cfg.n_routed_experts,
-                   cfg.moe_intermediate_size)
-        self.router_weight = self._matrix(h, e)
-        self.e_score_correction_bias = self._vector(e, 0.0)
-        # both [experts, width, hidden]: `up` as [out, in], `down` as
-        # [in, out], the layout the kernel reads without a copy
-        self.experts_up = self._matrix(e, m, h)
-        self.experts_down = self._matrix(e, m, h)
-        ms = cfg.moe_shared_expert_intermediate_size
-        self.shared_up = self._matrix(h, ms)
-        self.shared_down = self._matrix(ms, h)
-
-    def route(self, x):
-        logits = jnp.matmul(x.astype(jnp.float32),
-                            self.router_weight._data.astype(jnp.float32),
-                            precision=_HIGHEST)
-        return route(self.e_score_correction_bias._data, logits,
-                     self.cfg.num_experts_per_tok,
-                     self.cfg.routed_scaling_factor)
-
-    def _segment(self, x, idx, weights):
-        """x [T, hidden], idx and weights [T, k] -> [T, hidden] float32,
-        T k <= MAX_ROWS."""
-        k = idx.shape[1]
-        flat = idx.reshape(-1)
-        order = jnp.argsort(flat)                   # stable: by expert
-        sizes = jnp.bincount(flat, length=self.cfg.n_routed_experts)
-        out = grouped_mlp(x[order // k], self.experts_up._data,
-                          self.experts_down._data, sizes)
-        out = out * weights.reshape(-1)[order][:, None]
-        # back to token order: pick j of token t sits at row inv[t k + j]
-        inv = jnp.argsort(order)
-        return out[inv].reshape(-1, k, out.shape[-1]).sum(axis=1)
-
-    def experts(self, x, idx, weights):
-        """x [T, hidden], idx and weights [T, k] -> [T, hidden] float32."""
-        t, k = idx.shape
-        seg = max(1, self.MAX_ROWS // k)
-        if t <= seg:
-            return self._segment(x, idx, weights)
-        pad = -t % seg              # padded tokens: expert 0, weight 0
-        parts = [jnp.pad(a, ((0, pad), (0, 0))).reshape(-1, seg, a.shape[1])
-                 for a in (x, idx, weights)]
-        out = jax.lax.map(lambda a: self._segment(*a), tuple(parts))
-        return out.reshape(-1, out.shape[-1])[:t]
-
-    def shared(self, x):
-        hid = jnp.square(jax.nn.relu(x @ self.shared_up._data))
-        return hid @ self.shared_down._data
-
-    def forward(self, x):
-        lead = x.shape[:-1]
-        x = x.reshape(-1, x.shape[-1])
-        with jax.named_scope("moe_route"):
-            idx, weights = self.route(x)
-        y = self.experts(x, idx, weights)
-        with jax.named_scope("moe_shared"):
-            y = y + self.shared(x).astype(jnp.float32)
-        return y.astype(x.dtype).reshape(*lead, -1)
+        super().__init__(cfg, cfg.moe_shared_expert_intermediate_size)
 
 
 # -------------------------------------------------------------------- stack

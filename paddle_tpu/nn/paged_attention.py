@@ -1,11 +1,14 @@
-"""The paged K/V cache: the block pool's stored form, its one write, and
-attention over it through the block tables.
+"""The paged cache: the block pool's stored forms, their one write, and
+attention over them through the block tables.
 
 This is the one module that knows how a layer's pool is laid out. A
 model file calls `paged_attend` (write the new positions' K/V through
 the tables, then attend the new queries over the pool) and names no
 shape of the pool; the serving engines allocate it through
-`init_block_kv` and pass it through their programs donated.
+`init_block_kv` and pass it through their programs donated. A model
+with latent attention keeps one row a position that all heads share: the
+second stored form, with its own entry point `paged_attend_latent` and
+its two ways through the pool, at the end of this file.
 
 Two cores attend, behind one dispatch point:
 
@@ -157,10 +160,19 @@ def write_block_kv(pool, k, v, tables, start, valid_len=None):
     the block tables [S, nblk]; position p of lane s lives at
     pool[tables[s, p // BS], :, p % BS] (K in [..., :D], V in [..., D:]).
     `start` and `valid_len` are traced scalars or [S] vectors;
-    valid_len=None writes all C. The one write of every paged program:
-    the decode wave (C == 1), a prefill chunk (S == 1; the padded tail of
-    the last chunk lies past valid_len), the speculative verify wave
-    (every lane, its own start and span).
+    valid_len=None writes all C. The one write of every paged program
+    that keeps K/V: the decode wave (C == 1), a prefill chunk (S == 1;
+    the padded tail of the last chunk lies past valid_len), the
+    speculative verify wave (every lane, its own start and span). How it
+    moves pages: `_write_pages`."""
+    import jax.numpy as jnp
+    return _write_pages(pool, jnp.concatenate([k, v], axis=-1), tables,
+                        start, valid_len)
+
+
+def _write_pages(pool, new, tables, start, valid_len=None):
+    """The write of both stored forms: new [S, Hkv, C, W] into pool
+    [NB, Hkv, BS, W] (the latent form comes with Hkv == 1, a view).
 
     It moves whole pages. The C positions of a lane touch at most
     ceil((C - 1) / BS) + 1 pages wherever they start; each is gathered,
@@ -184,9 +196,9 @@ def write_block_kv(pool, k, v, tables, start, valid_len=None):
     left in it, and the colliding writes to it all carry the same
     zeros."""
     import jax.numpy as jnp
-    s, hkv, c, _ = k.shape
+    s, hkv, c, _ = new.shape
     bs, nblk = pool.shape[2], tables.shape[1]
-    kv = jnp.concatenate([k, v], axis=-1).astype(pool.dtype)
+    kv = new.astype(pool.dtype)
     start = jnp.broadcast_to(jnp.reshape(start, (-1,)), (s,))
     valid = c if valid_len is None else jnp.minimum(
         jnp.broadcast_to(jnp.reshape(valid_len, (-1,)), (s,)), c)
@@ -378,11 +390,7 @@ def _paged_attn_kernel(tables_ref, start_ref, rows_ref, cols_ref, keys_ref,
     start = start_ref[b]                               # SMEM scalar
     lo, hi = attended_pages(start, c, bs, nblk, window)
 
-    @pl.when(j == 0)
-    def _init():
-        m_ref[...] = jnp.full_like(m_ref, -jnp.inf)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
+    pl.when(j == 0)(lambda: _softmax_init(m_ref, l_ref, acc_ref))
 
     def slab():
         """[pages * heads * BS, 2D] float32 of the step's pages."""
@@ -416,21 +424,48 @@ def _paged_attn_kernel(tables_ref, start_ref, rows_ref, cols_ref, keys_ref,
         if window is not None:
             attended &= kcol > start - window
         kv = jnp.where(attended, kv, 0.0)
-        m_prev = m_ref[...]                            # [rows, 1]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        shift = jnp.where(jnp.isfinite(m_new), m_new, 0.0)
-        p = jnp.exp(s - shift)
-        alpha = jnp.exp(m_prev - shift)
-        l_ref[...] = alpha * l_ref[...] + jnp.sum(p, axis=-1, keepdims=True)
-        acc_ref[...] = alpha * acc_ref[...] + \
-            jnp.dot(p, kv, preferred_element_type=jnp.float32)
-        m_ref[...] = m_new
+        _softmax_fold(s, kv, m_ref, l_ref, acc_ref)
 
-    @pl.when(j == pl.num_programs(2) - 1)
-    def _finish():
-        # == 0 guard (not > 0): nan denominators must propagate
-        l = l_ref[...]
-        o_ref[0, 0] = jnp.where(l == 0, 0.0, acc_ref[...] / l)
+    pl.when(j == pl.num_programs(2) - 1)(
+        lambda: _softmax_finish(o_ref, l_ref, acc_ref))
+
+
+def _softmax_init(m_ref, l_ref, acc_ref):
+    """The online softmax's accumulators before a lane's first step: what
+    both kernels (K/V and latent) keep in VMEM scratch across the
+    sequential step dimension."""
+    import jax.numpy as jnp
+    m_ref[...] = jnp.full_like(m_ref, -jnp.inf)
+    l_ref[...] = jnp.zeros_like(l_ref)
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+
+
+def _softmax_fold(s, kv, m_ref, l_ref, acc_ref):
+    """Fold one step's masked scores s [rows, cols] (-inf where masked)
+    and its value rows kv [cols, width] into the accumulators: running
+    max m, denominator l, weighted sum, rescaled by exp(m_old - m_new).
+    A row that has seen only -inf keeps m = -inf and shifts by 0. The
+    probabilities meet the MXU in the rows' dtype, the sums are
+    float32."""
+    import jax.numpy as jnp
+    m_prev = m_ref[...]                                # [rows, 1]
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+    shift = jnp.where(jnp.isfinite(m_new), m_new, 0.0)
+    p = jnp.exp(s - shift)
+    alpha = jnp.exp(m_prev - shift)
+    l_ref[...] = alpha * l_ref[...] + jnp.sum(p, axis=-1, keepdims=True)
+    acc_ref[...] = alpha * acc_ref[...] + jnp.dot(
+        p.astype(kv.dtype), kv, preferred_element_type=jnp.float32)
+    m_ref[...] = m_new
+
+
+def _softmax_finish(o_ref, l_ref, acc_ref):
+    """The lane's output after its last step; a row that attended
+    nothing gives 0. == 0 guard (not > 0): nan denominators must
+    propagate."""
+    import jax.numpy as jnp
+    l = l_ref[...]
+    o_ref[0, 0] = jnp.where(l == 0, 0.0, acc_ref[...] / l)
 
 
 @functools.lru_cache(maxsize=None)
@@ -533,3 +568,329 @@ def _pallas_core(q, pool, tables, start, scale, window=None):
                    jnp.asarray(colinfo), jnp.asarray(keyoff[:, None]), qr,
                    *[pool] * pages)
     return out[..., d:].reshape(b, h, c, d).astype(pool.dtype)
+
+
+# ---------------------------------------------------------------------------
+# the latent form: one row a position, shared by every head (MLA)
+# ---------------------------------------------------------------------------
+# A layer with latent attention caches, for a position, the row
+# [c | k_rope]: the `rank` values every head's K and V are expanded from
+# (K_nope, V = c W_kv_b) and the one rotary key all heads share. Its pool
+# is ONE array a layer, [num_blocks, block_size, W]: no K-beside-V halves
+# and no kv-head dimension. W is rank + rope rounded up to whole vregs of
+# 128 lanes (576 -> 640; the tail is zeros): the device would pad the
+# minor dimension to that anyway, so the stored bytes are counted as
+# they are, the parameter keeps its layout through the programs, and the
+# kernel's matmuls take whole tiles. Tables, scratch block, page write
+# and masking contract are the K/V form's (`_write_pages`, the pool seen
+# as [NB, 1, BS, W]).
+#
+# Two ways to attend it, one rule (`latent_path`), by the queries a lane
+# brings. ABSORBED: K and V are never made; a head's query is carried
+# into the latent space (q_abs = q_nope W_UK^T), every head scores the
+# same row, the output is carried back (o = (P c) W_UV). A (query, row,
+# head) costs 2 (rank + rope) + 2 rank operations: 2,176 at 512 + 64.
+# EXPANDED: the attended rows are put through W_kv_b once, 2 rank (nope +
+# v) operations a (row, head) = 262,144 at 128 + 128, and a (query, row,
+# head) then costs 2 (nope + rope) + 2 v = 640. One query a lane (the
+# decode wave) is absorbed; a chunk of hundreds is expanded; the two
+# cross at rank (nope + v) / (2 rank - nope - v) queries: 170.
+
+_LANES = 128
+
+
+def latent_width(rank, rope):
+    """Stored width of a latent row: rank + rope in whole 128-lane
+    vregs."""
+    return -(-(rank + rope) // _LANES) * _LANES
+
+
+def init_block_latent(num_blocks, block_size, rank, rope, dtype):
+    """A layer's empty pool in the latent form (see above)."""
+    import jax.numpy as jnp
+    return jnp.zeros((num_blocks, block_size, latent_width(rank, rope)),
+                     dtype)
+
+
+def write_block_latent(pool, row, tables, start, valid_len=None):
+    """Write C new positions' rows [S, C, rank + rope] into the latent
+    pool [NB, BS, W], zero-extended to W: `write_block_kv`'s contract,
+    whole pages moved, scratch zeroed."""
+    import jax.numpy as jnp
+    row = jnp.pad(row, ((0, 0), (0, 0), (0, pool.shape[-1] - row.shape[-1])))
+    return _write_pages(pool[:, None], row[:, None], tables, start,
+                        valid_len)[:, 0]
+
+
+def latent_path(c, rank, rope, nope, v):
+    """"absorbed" or "expanded" for a call of `c` queries a lane: the
+    one place the choice is made (arithmetic above). Absorbed while its
+    extra cost a query is under one expansion of the row."""
+    extra = 2 * (2 * rank + rope) - 2 * (nope + rope + v)
+    return "absorbed" if c * extra < 2 * rank * (nope + v) else "expanded"
+
+
+#: pages the expanded path gathers, expands and scores at a time
+_EXPAND_PAGES = 32
+
+
+def expanded_rows(start, c, bs, nblk):
+    """Rows one lane's chunk of `c` queries at `start` puts through
+    W_kv_b on the expanded path: its attended pages, in whole tiles of
+    `_EXPAND_PAGES`. numpy or traced, like `attended_pages`."""
+    _, hi = attended_pages(start, c, bs, nblk)
+    t = min(_EXPAND_PAGES, nblk)
+    return (hi + t - 1) // t * t * bs
+
+
+def paged_attend_latent(q_nope, q_rope, row, w_kv_b, pool, tables, start,
+                        valid_len, scale, kernel=None):
+    """What a latent-attention layer says to the paged cache: write the
+    C new positions' rows [B, C, rank + rope] (`[c | k_rope]`, the key
+    already rotated) through the tables, then attend q_nope [B, H, C,
+    nope] and q_rope [B, H, C, rope] (rotated) over the pool, each query
+    at its own absolute position start + i. w_kv_b [rank, H, nope + v]
+    expands a row into every head's K_nope and V. Returns (out [B, H, C,
+    v] in pool.dtype, the pool). Absorbed or expanded: `latent_path`."""
+    import jax
+    import jax.numpy as jnp
+    pool = write_block_latent(pool, row, tables, start, valid_len)
+    rank, rope, nope = w_kv_b.shape[0], q_rope.shape[-1], q_nope.shape[-1]
+    c, v = q_nope.shape[2], w_kv_b.shape[-1] - nope
+    if latent_path(c, rank, rope, nope, v) == "expanded":
+        with jax.named_scope("mla_expand"):
+            out = _expanded_core(q_nope, q_rope, w_kv_b, pool, tables,
+                                 start, scale)
+        return out.astype(pool.dtype), pool
+    with jax.named_scope("mla_absorb"):
+        q_abs = jnp.einsum("bhcn,rhn->bhcr", q_nope, w_kv_b[..., :nope],
+                           preferred_element_type=jnp.float32)
+        q = jnp.concatenate([q_abs.astype(pool.dtype),
+                             q_rope.astype(pool.dtype)], axis=-1)
+        q = jnp.pad(q, ((0, 0),) * 3 + ((0, pool.shape[-1] - rank - rope),))
+    o_lat = attend_latent(q, pool, tables, start, scale, kernel=kernel)
+    with jax.named_scope("mla_absorb"):
+        out = jnp.einsum("bhcr,rhv->bhcv",
+                         o_lat[..., :rank].astype(pool.dtype),
+                         w_kv_b[..., nope:],
+                         preferred_element_type=jnp.float32)
+    return out.astype(pool.dtype), pool
+
+
+def attend_latent(q, pool, tables, start, scale, kernel=None):
+    """Absorbed attention over the latent pool as it stands: q [B, H, C,
+    W] (a head's query in the row's own layout, zeros in the tail), pool
+    [NB, BS, W], tables [B, nblk]. Every head scores the same rows, and
+    the output is the probabilities' mix of the rows themselves, W wide
+    (the caller keeps [..., :rank]). Returns float32 [B, H, C, W]."""
+    if resolve_kernel(kernel) == "pallas":
+        return _latent_pallas_core(q, pool, tables, start, scale)
+    return _latent_reference_core(q, pool, tables, start, scale)
+
+
+def _latent_reference_core(q, pool, tables, start, scale):
+    """The oracle of the latent form: gather the lanes' pages into a
+    [B, nblk*BS, W] view, then a plain masked softmax (the K/V oracle's
+    contract: rows no query attends are zeroed, so scratch garbage
+    cannot reach a good row as 0 * nan)."""
+    import jax.numpy as jnp
+    from .transformer import _masked_softmax
+    b, h, c, w = q.shape
+    rows = pool[tables].reshape(b, -1, w)                  # [B, L, W]
+    length = rows.shape[1]
+    scores = jnp.einsum("bhcw,blw->bhcl", q.astype(jnp.float32),
+                        rows.astype(jnp.float32)) * scale
+    start = jnp.broadcast_to(jnp.reshape(start, (-1,)), (b,))
+    qpos = start[:, None, None, None] + jnp.arange(c).reshape(1, 1, c, 1)
+    mask = jnp.arange(length).reshape(1, 1, 1, length) <= qpos
+    probs = _masked_softmax(scores, mask).astype(rows.dtype)
+    attended = jnp.any(mask, axis=2)[:, 0, :, None]        # [B, L, 1]
+    rows = jnp.where(attended, rows, jnp.zeros((), rows.dtype))
+    return jnp.einsum("bhcl,blw->bhcw", probs, rows,
+                      preferred_element_type=jnp.float32)
+
+
+def _expanded_core(q_nope, q_rope, w_kv_b, pool, tables, start, scale):
+    """The expanded path, plain XLA: a loop over tiles of `_EXPAND_PAGES`
+    table entries, as far as the furthest lane attends. A tile's rows
+    are read through the table, expanded by W_kv_b into every head's
+    K_nope and V, scored (nope and rope parts), masked, and folded into
+    an online softmax, so neither the expanded prefix nor the scores of
+    a whole chunk over a whole context ever exist at once."""
+    import jax
+    import jax.numpy as jnp
+    b, h, c, nope = q_nope.shape
+    rank, rope = w_kv_b.shape[0], q_rope.shape[-1]
+    v = w_kv_b.shape[-1] - nope
+    bs, nblk = pool.shape[1], tables.shape[1]
+    t = min(_EXPAND_PAGES, nblk)
+    tables = jnp.pad(tables, ((0, 0), (0, -nblk % t)))     # scratch
+    start = jnp.broadcast_to(jnp.reshape(jnp.asarray(start, jnp.int32),
+                                         (-1,)), (b,))
+    _, hi = attended_pages(start, c, bs, nblk)
+    # a padded chunk tail's rows sit past the table: it ends at hi
+    last = hi * bs - 1
+    qpos = jnp.minimum(start[:, None] + jnp.arange(c), last[:, None])
+    seen = jnp.minimum(start + (c - 1), last)              # [B]
+    f32 = jnp.float32
+
+    def tile(j, carry):
+        m, l, acc = carry
+        pages = jax.lax.dynamic_slice_in_dim(tables, j * t, t, axis=1)
+        rows = pool[pages].reshape(b, t * bs, -1)          # [B, L, W]
+        ks = j * (t * bs) + jnp.arange(t * bs)
+        # rows no query attends are zeroed (0 * nan, as in the cores)
+        rows = jnp.where((ks[None, :] <= seen[:, None])[..., None], rows,
+                         jnp.zeros((), rows.dtype))
+        kv = jnp.einsum("blr,rhd->bhld", rows[..., :rank], w_kv_b)
+        s = (jnp.einsum("bhcn,bhln->bhcl", q_nope.astype(kv.dtype),
+                        kv[..., :nope], preferred_element_type=f32)
+             + jnp.einsum("bhcr,blr->bhcl", q_rope.astype(rows.dtype),
+                          rows[..., rank:rank + rope],
+                          preferred_element_type=f32)) * scale
+        s = jnp.where(ks[None, None, None, :] <= qpos[:, None, :, None],
+                      s, -jnp.inf)
+        m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
+        shift = jnp.where(jnp.isfinite(m_new), m_new, 0.0)
+        p, alpha = jnp.exp(s - shift), jnp.exp(m - shift)
+        l = alpha * l + jnp.sum(p, axis=-1, keepdims=True)
+        acc = alpha * acc + jnp.einsum(
+            "bhcl,bhlv->bhcv", p.astype(kv.dtype), kv[..., nope:],
+            preferred_element_type=f32)
+        return m_new, l, acc
+
+    init = (jnp.full((b, h, c, 1), -jnp.inf, f32),
+            jnp.zeros((b, h, c, 1), f32), jnp.zeros((b, h, c, v), f32))
+    _, l, acc = jax.lax.fori_loop(0, (jnp.max(hi) + t - 1) // t, tile, init)
+    # == 0 guard (not > 0): nan denominators must propagate
+    return jnp.where(l == 0, 0.0, acc / l)
+
+
+#: pages a step of the latent kernel takes (each is one more pipelined
+#: operand: a page is BS rows of W, 20 KB at 16 x 640 bfloat16)
+_LATENT_PAGES = 32
+
+
+def _latent_kernel(tables_ref, start_ref, rows_ref, q_ref, *refs, scale,
+                   bs, c, nblk, pages):
+    """One (lane b, head group g, step j) grid step of the absorbed
+    core: `pages` table entries of the lane from `j * pages` on, each a
+    [BS, W] page of latent rows that every head of the group scores
+    (`q @ rows.T`) and mixes (`p @ rows`): key and value are the same
+    bytes, read once. Operands go to the MXU in the pool's dtype, sums
+    and the softmax are float32. The recurrence, the bounds and the
+    masking contract are `_paged_attn_kernel`'s; with no kv-head
+    dimension a column IS a key, so its position is the step's first
+    plus an iota."""
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+
+    page_refs = refs[:pages]
+    o_ref, m_ref, l_ref, acc_ref = refs[pages:]
+    b = pl.program_id(0)
+    j = pl.program_id(2)
+    start = start_ref[b]                               # SMEM scalar
+    _, hi = attended_pages(start, c, bs, nblk)
+    cols = pages * bs
+
+    pl.when(j == 0)(lambda: _softmax_init(m_ref, l_ref, acc_ref))
+
+    @pl.when(j * pages < hi)
+    def _visit():
+        q = q_ref[0, 0]                                # [rows, W]
+        tiles = [r[0] for r in page_refs]
+        kv = tiles[0] if pages == 1 else jnp.concatenate(tiles, axis=0)
+        first = j * cols                               # the step's first key
+        last = hi * bs - 1       # a padded tail's rows end with the table
+        # rows no query keeps are zeroed: 0 * nan == nan otherwise
+        kcol = first + jax.lax.broadcasted_iota(jnp.int32, (cols, 1), 0)
+        kv = jnp.where(kcol <= jnp.minimum(start + (c - 1), last), kv,
+                       jnp.zeros((), kv.dtype))
+        s = jax.lax.dot_general(                       # q @ rows.T
+            q, kv, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * scale  # [rows, cols]
+        ks = first + jax.lax.broadcasted_iota(jnp.int32, (1, cols), 1)
+        rowpos = jnp.minimum(start + rows_ref[...], last)    # [rows, 1]
+        _softmax_fold(jnp.where(ks <= rowpos, s, -jnp.inf), kv, m_ref,
+                      l_ref, acc_ref)
+
+    pl.when(j == pl.num_programs(2) - 1)(
+        lambda: _softmax_finish(o_ref, l_ref, acc_ref))
+
+
+@functools.lru_cache(maxsize=None)
+def _latent_call(b, h, c, w, bs, nblk, heads, pages, scale, dtype_name,
+                 interpret):
+    """The pallas_call of the absorbed core for one static shape family:
+    grid (lanes, head groups, steps of pages), the table and the lanes'
+    positions as scalar-prefetch operands, the pages gathered by the
+    BlockSpec index_maps as in `_pallas_call` (a step past the lane's
+    last attended page names that page again and moves no bytes)."""
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    rows = heads * c
+    kernel = functools.partial(_latent_kernel, scale=scale, bs=bs, c=c,
+                               nblk=nblk, pages=pages)
+
+    def page_spec(i):
+        def index_map(bb, gg, jj, tab, st):
+            _, hi = attended_pages(st[bb], c, bs, nblk)
+            page = jnp.minimum(jj * pages + i, hi - 1)
+            return tab[bb, jnp.clip(page, 0, nblk - 1)], 0, 0
+        return pl.BlockSpec((1, bs, w), index_map)
+
+    def per_group(bb, gg, jj, tab, st):
+        return bb, gg, 0, 0
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(b, h // heads, pl.cdiv(nblk, pages)),
+        in_specs=[
+            pl.BlockSpec((rows, 1), lambda bb, gg, jj, tab, st: (0, 0)),
+            pl.BlockSpec((1, 1, rows, w), per_group),
+            *[page_spec(i) for i in range(pages)],
+        ],
+        out_specs=pl.BlockSpec((1, 1, rows, w), per_group),
+        scratch_shapes=[
+            pltpu.VMEM((rows, 1), jnp.float32),        # running max m
+            pltpu.VMEM((rows, 1), jnp.float32),        # running denom l
+            pltpu.VMEM((rows, w), jnp.float32),        # p @ rows acc
+        ],
+    )
+    return pl.pallas_call(
+        kernel, grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((b, h // heads, rows, w),
+                                       jnp.float32),
+        interpret=interpret, name="paged_latent_attention")
+
+
+def _latent_pallas_core(q, pool, tables, start, scale):
+    """Pallas path of the absorbed core. Rows of the query tile are
+    (head, query) with the query minor; as many heads share a step as
+    keep the tile within `_MAX_ROWS` rows (all of them in the decode
+    form). interpret=True off the TPU, as `_pallas_core`."""
+    import jax
+    import jax.numpy as jnp
+
+    b, h, c, w = q.shape
+    bs, nblk = pool.shape[1], tables.shape[1]
+    heads = max(g for g in range(1, h + 1)
+                if h % g == 0 and (g * c <= _MAX_ROWS or g == 1))
+    pages = min(_LATENT_PAGES, nblk)
+    rows = heads * c
+    start = jnp.broadcast_to(jnp.reshape(jnp.asarray(start, jnp.int32),
+                                         (-1,)), (b,))
+    call = _latent_call(b, h, c, w, bs, nblk, heads, pages, float(scale),
+                        str(pool.dtype), jax.default_backend() != "tpu")
+    qoff = np.arange(rows, dtype=np.int32)[:, None] % c
+    # the scope, innermost at the call, names the instruction in a
+    # device trace ("%paged_latent_attention.1 = ... custom-call")
+    with jax.named_scope("paged_latent_attention"):
+        out = call(tables.astype(jnp.int32), start, jnp.asarray(qoff),
+                   q.astype(pool.dtype).reshape(b, h // heads, rows, w),
+                   *[pool] * pages)
+    return out.reshape(b, h, c, w)

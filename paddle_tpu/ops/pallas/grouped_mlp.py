@@ -1,18 +1,20 @@
-"""Grouped expert MLP `down(relu(up(x))^2)` over rows sorted by expert:
-one Pallas kernel, one expert a grid step.
+"""Grouped expert MLP over rows sorted by expert: one Pallas kernel, one
+expert a grid step, in two forms of one body. Without a gate an expert
+is `down(relu(up(x))^2)`, two matrices; with `gate=` it is
+`down(silu(gate(x)) * up(x))`, three.
 
 Rows [offsets[e], offsets[e + 1]) of `x` belong to expert e. A step
-fetches that expert's two matrices whole (each is read from memory once
+fetches that expert's matrices whole (each is read from memory once
 a call, in one long transfer) and walks the expert's rows in blocks of
 `ROWS`, straight out of the resident `x`; a block that straddles two
 experts is visited by both, each keeping its own rows. Nothing is
 padded to a capacity and no row is dropped: an expert with no rows costs
 its fetch and no arithmetic.
 
-Both matrices are stored [experts, width, hidden] (`up` as [out, in],
-`down` as [in, out]): the device lays a matrix out in tiles of 128
-columns and would store [hidden, 1856] transposed; `hidden` tiles
-exactly, so neither is copied on the way into the kernel.
+Every matrix is stored [experts, width, hidden] (`up` and `gate` as
+[out, in], `down` as [in, out]): the device lays a matrix out in tiles
+of 128 columns and would store [hidden, 1856] transposed; `hidden` tiles
+exactly, so none is copied on the way into the kernel.
 
 Why not `jax.lax.ragged_dot`: on a TPU it is a kernel tiled (256, 128,
 128), about 40,000 grid steps a matmul at 128 experts x 2688 x 1856
@@ -26,8 +28,10 @@ import jax.numpy as jnp
 ROWS = 16       # rows a block: one packed bfloat16 tile of sublanes
 
 
-def _kernel(x_ref, up_ref, down_ref, offs_ref, o_ref):
+def _kernel(x_ref, up_ref, *refs, gated):
     from jax.experimental import pallas as pl
+    gate_ref = refs[0] if gated else None
+    down_ref, offs_ref, o_ref = refs[-3:]
     e = pl.program_id(0)
 
     @pl.when(e == 0)
@@ -41,9 +45,14 @@ def _kernel(x_ref, up_ref, down_ref, offs_ref, o_ref):
     def block(i, carry):
         start = pl.multiple_of((first + i) * ROWS, ROWS)
         xb = x_ref[pl.ds(start, ROWS), :]
-        h = jax.lax.dot_general(xb, up_ref[0], (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32)
-        h = jnp.square(jnp.maximum(h, 0.0)).astype(xb.dtype)
+        def into(w_ref):       # x @ w.T, w stored [width, hidden]
+            return jax.lax.dot_general(
+                xb, w_ref[0], (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32)
+
+        h = into(up_ref)
+        h = (jax.nn.silu(into(gate_ref)) * h if gated
+             else jnp.square(jnp.maximum(h, 0.0))).astype(xb.dtype)
         y = jnp.dot(h, down_ref[0], preferred_element_type=jnp.float32)
         rows = start + jax.lax.broadcasted_iota(jnp.int32, (ROWS, 1), 0)
         mine = (rows >= r0) & (rows < r1)
@@ -55,19 +64,20 @@ def _kernel(x_ref, up_ref, down_ref, offs_ref, o_ref):
 
 
 @functools.lru_cache(maxsize=None)
-def _call(rows, hidden, width, experts, dtype_name, interpret):
+def _call(rows, hidden, width, experts, dtype_name, interpret, gated=False):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
     item = jnp.dtype(dtype_name).itemsize
-    # two matrices, double-buffered, beside the resident rows and result
-    vmem = (4 * width * hidden * item + 2 * rows * hidden * (item + 4)
-            + (8 << 20))
+    mats = 3 if gated else 2
+    # the matrices, double-buffered, beside the resident rows and result
+    vmem = (2 * mats * width * hidden * item
+            + 2 * rows * hidden * (item + 4) + (8 << 20))
+    matrix = pl.BlockSpec((1, width, hidden), lambda e: (e, 0, 0))
     return pl.pallas_call(
-        _kernel, grid=(experts,),
+        functools.partial(_kernel, gated=gated), grid=(experts,),
         in_specs=[
             pl.BlockSpec((rows, hidden), lambda e: (0, 0)),
-            pl.BlockSpec((1, width, hidden), lambda e: (e, 0, 0)),
-            pl.BlockSpec((1, width, hidden), lambda e: (e, 0, 0)),
+            *[matrix] * mats,
             pl.BlockSpec(memory_space=pltpu.SMEM),
         ],
         out_specs=pl.BlockSpec((rows, hidden), lambda e: (0, 0)),
@@ -77,9 +87,10 @@ def _call(rows, hidden, width, experts, dtype_name, interpret):
         interpret=interpret, name="moe_experts")
 
 
-def grouped_mlp(x, up, down, group_sizes):
-    """x [rows, hidden] sorted by expert, up and down [experts, width,
-    hidden], group_sizes [experts] int -> float32 [rows, hidden]."""
+def grouped_mlp(x, up, down, group_sizes, gate=None):
+    """x [rows, hidden] sorted by expert, up and down (and gate, for the
+    gated form) [experts, width, hidden], group_sizes [experts] int ->
+    float32 [rows, hidden]."""
     rows, hidden = x.shape
     experts, width, _ = up.shape
     pad = -rows % ROWS
@@ -88,9 +99,10 @@ def grouped_mlp(x, up, down, group_sizes):
     offsets = jnp.concatenate([jnp.zeros((1,), jnp.int32),
                                jnp.cumsum(group_sizes).astype(jnp.int32)])
     call = _call(rows + pad, hidden, width, experts, str(x.dtype),
-                 jax.default_backend() != "tpu")
+                 jax.default_backend() != "tpu", gate is not None)
+    mats = (up, down) if gate is None else (up, gate, down)
     # the scope, innermost at the call, names the instruction in a
     # device trace ("%moe_experts.1 = ... custom-call")
     with jax.named_scope("moe_experts"):
-        out = call(x, up, down, offsets)
+        out = call(x, *mats, offsets)
     return out[:rows]

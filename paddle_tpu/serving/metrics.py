@@ -161,6 +161,12 @@ def record_callback_error(request, error):
                   request_id=request.request_id, error=repr(error))
 
 
+#: what `PagedServingEngine.take_model_counts` counts, and the snapshot
+#: carries under the same names (0 for a model that has none of it)
+MODEL_COUNTS = ("state_resets", "moe_picks", "mla_rows_attended",
+                "mla_rows_expanded", "prefill_tokens", "prefill_chunks")
+
+
 class ServingMetrics:
     """Per-engine aggregation on top of the process-wide sinks: bounded
     TTFT/latency histograms (for this instance's p50/p99) and the
@@ -200,10 +206,12 @@ class ServingMetrics:
         # instance's decode waves, and the entries their tables held
         self._pages_visited = 0
         self._pages_spanned = 0
-        # what a model with slot state or experts was staged (0 for any
-        # other): slot records zeroed at admission, (token, expert)
-        # pairs routed
-        self._model_counts = {"state_resets": 0, "moe_picks": 0}
+        # what a model with slot state, experts or a latent cache was
+        # staged (0 for any other): slot records zeroed at admission,
+        # (token, expert) pairs routed, latent rows attended by the
+        # waves and expanded by the chunks, those chunks and their
+        # prompt tokens
+        self._model_counts = dict.fromkeys(MODEL_COUNTS, 0)
         # [V] rows and [S, V] matrices of logit bias the engine sent to
         # the device (0 while no request brings a bias: the engine
         # keeps a zero row and a zero matrix there)

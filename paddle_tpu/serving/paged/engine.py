@@ -44,6 +44,7 @@ from ...nn import paged_attention
 from ...utils import chaos, telemetry
 from ...utils.profiler import RecordEvent
 from .. import blackbox
+from ..metrics import MODEL_COUNTS
 from ..engine import (ServingEngine, _filter_top_k_top_p, _raw,
                       _select_first_token, _select_wave_tokens,
                       unpack_lanes, unpack_prompt)
@@ -87,6 +88,16 @@ SLOT_STATE_REFUSAL = (
     "of that state at the boundary is needed first")
 
 
+#: why a model whose pages hold latent rows is refused the programs
+#: that were written for K/V pages (formatted with the operation's name)
+LATENT_REFUSAL = (
+    "{what} was written for pools of K/V pages ([blocks, kv_heads, "
+    "block, 2 x head_dim]); this model's pages hold latent rows "
+    "(model.latent_cache: [blocks, block, width], one row a position "
+    "shared by every head) and the program has not been taken through "
+    "that form yet")
+
+
 class PagedServingEngine(ServingEngine):
     """Block-table batched decode executor.
 
@@ -102,7 +113,11 @@ class PagedServingEngine(ServingEngine):
         The engine zeroes a slot's record when the slot begins a prompt,
         serves such a model without prefix sharing (a shared page holds
         no state, so a hit would be silently wrong) and refuses it
-        hand-off and speculation.
+        hand-off and speculation. A model that declares `latent_cache`
+        (DeepseekV3ForCausalLM) keeps one latent row a position in its
+        pages (nn.paged_attention's latent form): a page of rows is a
+        page, so tables, prefix sharing, copy-on-write and eviction are
+        the same; hand-off and speculation refuse it by name.
     max_len: per-request horizon; must be a multiple of block_size
         (table width = max_len // block_size).
     num_blocks: pool size INCLUDING the scratch block (block 0).
@@ -138,6 +153,7 @@ class PagedServingEngine(ServingEngine):
                 f"prefill_chunk_len {self.prefill_chunk_len} > max_len "
                 f"{max_len}")
         self.slot_state = bool(getattr(model, "slot_state", False))
+        self.latent_cache = bool(getattr(model, "latent_cache", False))
         self.prefix_sharing = bool(prefix_sharing) and not self.slot_state
         self.block_pool = BlockPool(num_blocks, self.block_size)
         self._copy_fn = None
@@ -160,9 +176,9 @@ class PagedServingEngine(ServingEngine):
         # zeroed, (token, expert) pairs routed
         self._picks_per_token = int(getattr(model, "moe_picks_per_token",
                                             0))
-        self.counts_model_work = bool(self.slot_state
+        self.counts_model_work = bool(self.slot_state or self.latent_cache
                                       or self._picks_per_token)
-        self._state_resets = self._moe_picks = 0
+        self._model_counts = dict.fromkeys(MODEL_COUNTS, 0)
 
     def _make_caches(self):
         extra = {"num_slots": self.num_slots} if self.slot_state else {}
@@ -321,7 +337,7 @@ class PagedServingEngine(ServingEngine):
                 self._caches = self._state_reset(self._caches,
                                                  np.int32(slot))
             self._acc("state.reset", ev)
-            self._state_resets += 1
+            self._model_counts["state_resets"] += 1
         blocks = shared + fresh
         self._slot_blocks[slot] = blocks
         self._tables[slot, :] = 0
@@ -372,7 +388,14 @@ class PagedServingEngine(ServingEngine):
             args = (*self._prefill_chunk_args(slot),
                     *self._prompt_args(slot, chunk, c0, valid, frontier,
                                        sampling, self._tables[slot]))
-            self._moe_picks += valid * self._picks_per_token
+            counts = self._model_counts
+            counts["moe_picks"] += valid * self._picks_per_token
+            if self.latent_cache:
+                counts["prefill_tokens"] += valid
+                counts["prefill_chunks"] += 1
+                counts["mla_rows_expanded"] += int(
+                    paged_attention.expanded_rows(c0, C, bs,
+                                                  self.blocks_per_slot))
         self._acc("prefill.stage", ev)
         with RecordEvent("serving/prefill/dispatch", pid=pid) as ev:
             first, self._caches, self._key = self._prefill(*args)
@@ -433,9 +456,7 @@ class PagedServingEngine(ServingEngine):
         The slot itself is left untouched: the caller retires it (which
         frees the blocks but keeps their prefix hashes) only once the
         payload is safely in hand."""
-        if self.slot_state:
-            raise HandoffRefused(SLOT_STATE_REFUSAL.format(
-                what="export_slot_kv (block-level hand-off)"))
+        self._refuse_handoff("export_slot_kv")
         if not self.slot_active[slot]:
             raise RuntimeError(f"slot {slot} is not active "
                                "(handoff export needs a completed prefill)")
@@ -472,6 +493,15 @@ class PagedServingEngine(ServingEngine):
                    n_tokens=n)
         return payload
 
+    def _refuse_handoff(self, name):
+        """Hand-off moves K/V pages alone: not a slot's record, and not
+        (yet) pages of latent rows."""
+        for flag, why in ((self.slot_state, SLOT_STATE_REFUSAL),
+                          (self.latent_cache, LATENT_REFUSAL)):
+            if flag:
+                raise HandoffRefused(why.format(
+                    what=f"{name} (block-level hand-off)"))
+
     def import_handoff(self, slot, prompt, payload, do_sample=False,
                        temperature=1.0, top_k=0, top_p=1.0,
                        logit_bias=None, dynamic_mask=False):
@@ -488,9 +518,7 @@ class PagedServingEngine(ServingEngine):
         the single-replica schedule. No prefill-chunk program runs (the
         scatter is a separate lazy jit), which is the whole point:
         a handoff costs bytes on the wire, not recompute."""
-        if self.slot_state:
-            raise HandoffRefused(SLOT_STATE_REFUSAL.format(
-                what="import_handoff (block-level hand-off)"))
+        self._refuse_handoff("import_handoff")
         why = self.validate_prompt(prompt)
         if why:
             raise ValueError(why)
@@ -625,8 +653,11 @@ class PagedServingEngine(ServingEngine):
             self.blocks_per_slot, self._attn_window)
         self._pages_visited += int(np.sum(hi - lo))
         self._pages_spanned += tables.size
-        self._moe_picks += (int(np.count_nonzero(active_now))
-                            * self._picks_per_token)
+        self._model_counts["moe_picks"] += (
+            int(np.count_nonzero(active_now)) * self._picks_per_token)
+        if self.latent_cache:
+            self._model_counts["mla_rows_attended"] += int(
+                np.sum(self.slot_pos[np.asarray(active_now, bool)] + 1))
         return (self._params, self._buffers, self._caches,
                 *self._lane_args(active_now, poison, tables), key)
 
@@ -670,13 +701,15 @@ class PagedServingEngine(ServingEngine):
         return out
 
     def take_model_counts(self):
-        """{"state_resets", "moe_picks"} since the last call: slot
-        records zeroed at admission, and (token, expert) pairs of the
-        tokens staged into chunks and waves. Taken by the scheduler once
-        a round, from an engine whose `counts_model_work` is set."""
-        out = {"state_resets": self._state_resets,
-               "moe_picks": self._moe_picks}
-        self._state_resets = self._moe_picks = 0
+        """What the model's own layers were staged since the last call:
+        slot records zeroed at admission; (token, expert) pairs of the
+        tokens staged into chunks and waves; of a latent cache, the rows
+        the waves' lanes attend and the rows the chunks expand (each a
+        layer), the chunks and the prompt tokens they carried. Taken by the
+        scheduler once a round, from an engine whose `counts_model_work`
+        is set."""
+        out = self._model_counts
+        self._model_counts = dict.fromkeys(MODEL_COUNTS, 0)
         return out
 
     # ------------------------------------------------------------- slots
@@ -706,6 +739,8 @@ class PagedServingEngine(ServingEngine):
                  prefix_sharing=self.prefix_sharing)
         if self.slot_state:
             h.update(slot_state=True, state_bytes=self._state_bytes)
+        if self.latent_cache:
+            h.update(latent_cache=True)
         return h
 
 
@@ -838,6 +873,10 @@ class SpeculativePagedEngine(PagedServingEngine):
             raise ValueError(SLOT_STATE_REFUSAL.format(
                 what="speculative decoding (the roll-back of a rejected "
                      "draft)"))
+        if any(getattr(m, "latent_cache", False)
+               for m in (model, draft_model)):
+            raise ValueError(LATENT_REFUSAL.format(
+                what="speculative decoding (the draft and verify waves)"))
         self.spec_k = int(spec_k)
         draft_model.eval()
         self.draft_model = draft_model

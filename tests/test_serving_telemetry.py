@@ -119,9 +119,10 @@ def test_snapshot_keys_byte_compatible(engine):
         "spec_acceptance_rate", "spec_accepted_per_wave",
         # the paged core's page counters (0 / 0 on a dense engine)
         "paged_pages_visited", "paged_pages_spanned",
-        # what a model with slot state or experts was staged (0 / 0 for
-        # any other)
-        "state_resets", "moe_picks",
+        # what a model with slot state, experts or a latent cache was
+        # staged (0 for any other)
+        "state_resets", "moe_picks", "mla_rows_attended",
+        "mla_rows_expanded", "prefill_tokens", "prefill_chunks",
         # bias rows and matrices sent to the device (0 while no request
         # brings a bias)
         "bias_uploads"]

@@ -242,20 +242,72 @@ def test_pool_passes_through_write_and_attention_in_place_for_v5e(
     assert _pool_stays(compiled, [pool]) < 1.02
 
 
+@pytest.mark.parametrize("name,lanes,c,kernels", [
+    # kanana2-serve-doc: 32 slots x 10240 positions / 16 + 1 pages of
+    # 16 rows x 640 (576 stored in whole vregs), 32 heads, MLA 512 + 64
+    ("kanana-doc-wave", 32, 1, ["paged_latent_attention"]),
+    ("kanana-doc-chunk512", 1, 512, []),
+])
+def test_latent_pool_passes_through_write_and_attention_in_place_for_v5e(
+        one_chip, as_on_tpu, name, lanes, c, kernels):
+    """One latent-attention layer of a serving program at the cell's
+    real pool: the call the model's attention makes (the rows' write,
+    then the absorbed kernel in a wave, the expanded loop in a chunk),
+    the pool donated: neither copied nor padded on the way."""
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    heads, rank, rope, nope, v, nblk = 32, 512, 64, 128, 128, 640
+    assert pa.latent_path(c, rank, rope, nope, v) == \
+        ("absorbed" if kernels else "expanded")
+
+    def layer(pool, q_nope, q_rope, rows, w, tables, start, valid_len):
+        out, pool = pa.paged_attend_latent(
+            q_nope, q_rope, rows, w, pool, tables, start, valid_len,
+            (nope + rope) ** -0.5, kernel="pallas")
+        return pool, out
+
+    pool = sds((20481, 16, pa.latent_width(rank, rope)), jnp.bfloat16)
+    args = (pool, sds((lanes, heads, c, nope), jnp.bfloat16),
+            sds((lanes, heads, c, rope), jnp.bfloat16),
+            sds((lanes, c, rank + rope), jnp.bfloat16),
+            sds((rank, heads, nope + v), jnp.bfloat16),
+            sds((lanes, nblk), jnp.int32), sds((lanes,), jnp.int32),
+            sds((lanes,), jnp.int32))
+    assert _kernel_names(layer, *args) == kernels
+    compiled = jax.jit(layer, donate_argnums=(0,)).lower(*args).compile()
+    # the other arguments (W_kv_b is 8.4 MB, a chunk's queries 6) are 2
+    # to 3.5% of the pool's 419 MB: nothing is padded
+    assert _pool_stays(compiled, [pool]) < 1.04
+
+
 @pytest.mark.parametrize("program", ["decode_wave", "prefill_chunk"])
-@pytest.mark.parametrize("family", ["gpt-mha-d64", "llama-gqa-d128"])
+@pytest.mark.parametrize("family", ["gpt-mha-d64", "llama-gqa-d128",
+                                    "deepseek-mla"])
 def test_engine_programs_keep_the_pool_in_place_for_v5e(one_chip, as_on_tpu,
                                                         family, program):
     """The same, of the programs as the engine builds them (its own
     closures, its own arguments, the caches donated): a small GPT with
     heads of 64 and a small GQA Llama with heads of 128."""
     import paddle_tpu as pt
-    from paddle_tpu.nlp import (GPTConfig, GPTForPretraining, LlamaConfig,
+    from paddle_tpu.nlp import (DeepseekV3Config, DeepseekV3ForCausalLM,
+                                GPTConfig, GPTForPretraining, LlamaConfig,
                                 LlamaForCausalLM)
     from paddle_tpu.serving import PagedServingEngine
 
     pt.seed(0)
-    if family == "gpt-mha-d64":
+    slots, chunk, max_len, blocks = 16, 128, 512, None
+    if family == "deepseek-mla":
+        # kanana2-serve-doc's programs: its widths, heads, lanes, table
+        # and chunk; two layers (one dense, one of 8 experts), a small
+        # vocabulary and a tenth of the pages, so that the sandbox holds
+        # the model
+        model = DeepseekV3ForCausalLM(DeepseekV3Config(
+            vocab_size=512, num_hidden_layers=2, n_routed_experts=8,
+            max_position_embeddings=10240, param_dtype="bfloat16",
+            init_weights=False))
+        slots, chunk, max_len, blocks = 32, 512, 10240, 2049
+    elif family == "gpt-mha-d64":
         model = GPTForPretraining(GPTConfig(
             vocab_size=512, hidden_size=128, num_layers=2, num_heads=2,
             max_seq_len=512, dropout=0.0, attn_dropout=0.0))
@@ -263,9 +315,9 @@ def test_engine_programs_keep_the_pool_in_place_for_v5e(one_chip, as_on_tpu,
         model = LlamaForCausalLM(LlamaConfig(
             vocab_size=512, hidden_size=512, num_layers=2, num_heads=4,
             num_kv_heads=2, max_seq_len=512))
-    slots, chunk = 16, 128
-    eng = PagedServingEngine(model, num_slots=slots, max_len=512,
-                             block_size=16, prefill_chunk_len=chunk,
+    eng = PagedServingEngine(model, num_slots=slots, max_len=max_len,
+                             block_size=16, num_blocks=blocks,
+                             prefill_chunk_len=chunk,
                              cache_dtype=jnp.bfloat16,
                              paged_kernel="pallas")
     key = jax.random.PRNGKey(0)
@@ -282,14 +334,19 @@ def test_engine_programs_keep_the_pool_in_place_for_v5e(one_chip, as_on_tpu,
                                   greedy, eng._tables[0]))
     pools = jax.tree_util.tree_leaves(eng._caches)
     assert [p.shape[1:] for p in pools] in (
-        [(2, 16, 128)] * 2, [(2, 16, 256)] * 2)
+        [(2, 16, 128)] * 2, [(2, 16, 256)] * 2, [(16, 640)] * 2)
     shapes = jax.tree_util.tree_map(
         lambda a: jax.ShapeDtypeStruct(jnp.shape(a), jnp.result_type(a),
                                        sharding=one_chip), args)
     compiled = jax.jit(fn, donate_argnums=eng._program_donate_argnums
                        ).lower(*shapes).compile()
     _pool_stays(compiled, pools)
-    assert compiled.as_text().count("tpu_custom_call") == 2   # a layer
+    # a layer: the paged kernel; of the latent model the absorbed kernel
+    # in each layer of a wave, none in a chunk (expanded), and the
+    # expert kernel of its one expert layer in both
+    assert compiled.as_text().count("tpu_custom_call") == {
+        ("deepseek-mla", "decode_wave"): 3,
+        ("deepseek-mla", "prefill_chunk"): 1}.get((family, program), 2)
 
 
 @pytest.mark.parametrize("name,rows", [("wave-128-lanes", 768),
@@ -310,6 +367,45 @@ def test_grouped_expert_kernel_compiles_for_v5e(one_chip, as_on_tpu, name,
     assert _kernel_names(grouped_mlp, *shapes) == ["moe_experts"]
     txt = jax.jit(grouped_mlp).lower(*shapes).compile().as_text()
     assert not re.search(r"bf16\[128,1856,2688\][^ ]* copy\(", txt)
+
+
+@pytest.mark.parametrize("name,rows", [("wave-32-lanes", 192),
+                                       ("chunk-segment", 2048)])
+def test_gated_expert_kernel_compiles_for_v5e(one_chip, as_on_tpu, name,
+                                              rows):
+    """`moe_experts` in its gated form at kanana2-serve-doc's size (128
+    experts of three 768 x 2048 matrices; 192 picks a wave, 2,048 a
+    segment of a chunk): one kernel, under its name, no stack of
+    matrices copied on the way in."""
+    from paddle_tpu.ops.pallas.grouped_mlp import grouped_mlp
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def gated(x, up, gate, down, sizes):
+        return grouped_mlp(x, up, down, sizes, gate=gate)
+
+    w = sds((128, 768, 2048), jnp.bfloat16)
+    shapes = (sds((rows, 2048), jnp.bfloat16), w, w, w,
+              sds((128,), jnp.int32))
+    assert _kernel_names(gated, *shapes) == ["moe_experts"]
+    txt = jax.jit(gated).lower(*shapes).compile().as_text()
+    assert not re.search(r"bf16\[128,768,2048\][^ ]* copy\(", txt)
+
+
+def test_latent_attention_forward_compiles_round_flash_for_v5e(one_chip,
+                                                               as_on_tpu):
+    """The sequence forward of a latent-attention layer at the published
+    head sizes: keys of 192, values of 128 zero-extended to the keys'
+    width, one flash kernel."""
+    q = jax.ShapeDtypeStruct((2, 1024, 32, 192), jnp.bfloat16,
+                             sharding=one_chip)
+
+    def attend(q, k, v):
+        return _flash_array(q, k, v, causal=True, layout="bshd",
+                            scale=192 ** -0.5)[..., :128]
+
+    assert _kernel_names(attend, q, q, q) == ["flash_fwd"]
 
 
 def test_kernels_carry_their_names_for_v5e(one_chip, as_on_tpu):
