@@ -116,6 +116,11 @@ _PREFIX_MISSES = telemetry.counter(
     "serving_prefix_cache_misses_total",
     "Full prompt blocks that had to be computed by prefill (no cached "
     "block with a matching chain hash)")
+_PREFIX_EVICTIONS = telemetry.counter(
+    "serving_prefix_cache_evictions_total",
+    "Blocks the pool handed out by dropping a cached prefix hash (no "
+    "free block without one was left): 0 while plain blocks last, one "
+    "per allocated block once every free block is cached")
 # speculative decoding (serving/paged SpeculativePagedEngine): the
 # draft-k/verify-once wave's economics — acceptance rate IS the
 # speedup knob (mean accepted/wave > 0 means decode rounds per
@@ -148,6 +153,11 @@ def record_prefix_lookup(hits, misses):
         _PREFIX_HITS.inc(int(hits))
     if misses:
         _PREFIX_MISSES.inc(int(misses))
+
+
+def record_prefix_evictions(n):
+    """Count the cached blocks one allocation evicted."""
+    _PREFIX_EVICTIONS.inc(int(n))
 
 
 def record_callback_error(request, error):
@@ -315,14 +325,15 @@ class ServingMetrics:
             self._block_used_waves += int(used)
             self._block_total_waves += int(total)
 
-    def on_prefix_totals(self, hits, misses):
+    def on_prefix_totals(self, hits, misses, evictions):
         """Track the pool's monotonic prefix counters; snapshot reports
         the delta across this metrics instance (per-load-point rates in
         the bench, which builds a fresh Scheduler per point)."""
+        totals = (int(hits), int(misses), int(evictions))
         with self._lock:
             if self._prefix_base is None:
-                self._prefix_base = (int(hits), int(misses))
-            self._prefix_last = (int(hits), int(misses))
+                self._prefix_base = totals
+            self._prefix_last = totals
 
     def on_token(self, t_now, prev_t=None):
         """One streamed token; `prev_t` is the SAME request's previous
@@ -369,10 +380,11 @@ class ServingMetrics:
             blk_used, blk_total = (self._block_used_waves,
                                    self._block_total_waves)
             if self._prefix_base is None:
-                p_hits = p_misses = 0
+                p_hits = p_misses = p_evictions = 0
             else:
-                p_hits = self._prefix_last[0] - self._prefix_base[0]
-                p_misses = self._prefix_last[1] - self._prefix_base[1]
+                p_hits, p_misses, p_evictions = (
+                    last - base for last, base in
+                    zip(self._prefix_last, self._prefix_base))
             phase_seconds = dict(self._phase_seconds)
             pages_v, pages_s = self._pages_visited, self._pages_spanned
             model_counts = dict(self._model_counts)
@@ -404,6 +416,8 @@ class ServingMetrics:
             "prefix_misses": p_misses,
             "prefix_hit_rate": (p_hits / (p_hits + p_misses)
                                 if p_hits + p_misses else None),
+            # blocks the pool handed out by dropping a cached hash
+            "prefix_evictions": p_evictions,
             # fleet PR: raw span endpoints (monotonic clock), so a
             # multi-replica rollup can compute the FLEET's first-to-
             # last-token span (max(last) - min(first)) and keep its

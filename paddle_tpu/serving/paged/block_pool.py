@@ -3,10 +3,11 @@
 The device side is a fixed pool of KV blocks per layer —
 `[num_blocks, kv_heads, block_size, head_dim]` x2, allocated once at
 engine construction (serving pays HBM for the blocks it CONFIGURES, not
-`num_slots * max_len`). This class owns the block ids: a free list with
-refcounts, per-request allocation, and a hash-based prefix cache so
-identical prompt prefixes (the shared-system-prompt pattern that
-dominates at millions-of-users scale) map to the SAME physical blocks.
+`num_slots * max_len`). This class owns the block ids: two free lists,
+plain before cached, each oldest-freed first, with refcounts,
+per-request allocation, and a hash-based prefix cache so identical
+prompt prefixes (the shared-system-prompt pattern that dominates at
+millions-of-users scale) map to the SAME physical blocks.
 
 Invariants the engine relies on:
 
@@ -19,9 +20,13 @@ Invariants the engine relies on:
     concurrent admission can never share a block whose content is not
     on the device yet;
   * a freed block (refcount 0) keeps its hash and stays reusable from
-    the free list — the prefix cache survives request churn and is
-    evicted lazily, oldest-freed first, only when allocation needs the
-    block back;
+    the cached free list — the prefix cache survives request churn and
+    is evicted lazily, oldest-freed first, only when the plain free
+    list (blocks that carry no hash) is empty and allocation needs the
+    block back. A block's kind cannot change while it is free (a hash
+    is registered on a live block and leaves only by eviction), so
+    `release` picks the list once and `alloc` pops a head: constant
+    time a block, and no method ever iterates a free list;
   * `cow()` is the copy-on-write guard: writing through a block with
     refcount > 1 must first move the writer onto a private copy. With
     full-block-only sharing the decode frontier always lands in a
@@ -67,15 +72,23 @@ class BlockPool:
             raise ValueError(f"block_size must be >= 1, got {block_size}")
         self.num_blocks = int(num_blocks)
         self.block_size = int(block_size)
-        # free list in eviction order (oldest-freed first); block 0 is
-        # the scratch block and never enters it
-        self._free = collections.OrderedDict(
+        # two free lists, each oldest-freed first: blocks with no
+        # prefix hash, handed out first, and blocks whose cached hash
+        # alloc drops only when the plain list is empty. OrderedDict
+        # because both ends of what is asked are constant time there:
+        # pop-oldest (alloc) and remove-by-id (a reviving match_prefix).
+        # Block 0 is the scratch block and never enters either.
+        self._free_plain = collections.OrderedDict(
             (b, None) for b in range(1, self.num_blocks))
+        self._free_cached = collections.OrderedDict()
         self._ref = [0] * self.num_blocks
         self._hash_to_block = {}
         self._block_hash = {}
         self.prefix_hits = 0
         self.prefix_misses = 0
+        # blocks handed out by dropping a cached hash: 0 while plain
+        # blocks last, one a block once every free block is cached
+        self.evictions = 0
         self._publish()
 
     # ------------------------------------------------------------- state
@@ -87,7 +100,10 @@ class BlockPool:
     @property
     def used(self):
         """Blocks currently referenced by at least one request."""
-        return self.usable - len(self._free)
+        return self.usable - self._nfree()
+
+    def _nfree(self):
+        return len(self._free_plain) + len(self._free_cached)
 
     def refcount(self, block):
         return self._ref[block]
@@ -110,30 +126,32 @@ class BlockPool:
         only when it must. Raises BlockPoolExhausted when fewer than `n`
         blocks are free — atomically: either all `n` or none."""
         n = int(n)
+        free = self._nfree()
         if chaos.enabled():
             # payload (truthy) = simulated exhaustion; raise-action =
             # simulated allocator crash (must surface as a fault, not
             # be absorbed as capacity)
-            if chaos.value(chaos.CACHE_ALLOC, need=n,
-                           free=len(self._free)):
+            if chaos.value(chaos.CACHE_ALLOC, need=n, free=free):
                 raise BlockPoolExhausted(
                     f"injected exhaustion: need {n} block(s)")
-        if n > len(self._free):
+        if n > free:
             raise BlockPoolExhausted(
-                f"need {n} block(s), {len(self._free)} free of "
+                f"need {n} block(s), {free} free of "
                 f"{self.usable} usable")
         out = []
+        evicted = 0
         for _ in range(n):
-            blk = next((b for b in self._free
-                        if b not in self._block_hash), None)
-            if blk is None:
-                blk = next(iter(self._free))       # evict oldest cached
-            del self._free[blk]
-            h = self._block_hash.pop(blk, None)
-            if h is not None and self._hash_to_block.get(h) == blk:
-                del self._hash_to_block[h]
+            if self._free_plain:
+                blk, _ = self._free_plain.popitem(last=False)
+            else:                                  # evict oldest cached
+                blk, _ = self._free_cached.popitem(last=False)
+                del self._hash_to_block[self._block_hash.pop(blk)]
+                evicted += 1
             self._ref[blk] = 1
             out.append(blk)
+        if evicted:
+            self.evictions += evicted
+            serving_metrics.record_prefix_evictions(evicted)
         self._publish()
         return out
 
@@ -145,14 +163,18 @@ class BlockPool:
 
     def release(self, blocks):
         """Drop one reference per block; refcount 0 returns the block to
-        the free list (keeping its prefix-cache hash, if any — the
-        cached content stays matchable until evicted by alloc)."""
+        the end of its free list: the cached one if it carries a
+        prefix-cache hash (the content stays matchable until evicted
+        by alloc), the plain one if not."""
         for blk in blocks:
             if self._ref[blk] < 1:
                 raise ValueError(f"double free of block {blk}")
             self._ref[blk] -= 1
             if self._ref[blk] == 0:
-                self._free[blk] = None
+                if blk in self._block_hash:
+                    self._free_cached[blk] = None
+                else:
+                    self._free_plain[blk] = None
         self._publish()
 
     def cow(self, block):
@@ -197,7 +219,7 @@ class BlockPool:
             if blk is None:
                 break
             if self._ref[blk] == 0:            # revive off the free list
-                del self._free[blk]
+                del self._free_cached[blk]
             self._ref[blk] += 1
             blocks.append(blk)
             hashes.append(h)
@@ -281,4 +303,5 @@ class BlockPool:
             "cached_hashes": len(self._hash_to_block),
             "prefix_hits": self.prefix_hits,
             "prefix_misses": self.prefix_misses,
+            "evictions": self.evictions,
         }

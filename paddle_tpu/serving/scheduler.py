@@ -160,7 +160,8 @@ class Scheduler:
             # BEFORE any round of ours — the snapshot then reports
             # exactly this scheduler's lookups, first round included
             self.metrics.on_prefix_totals(pool.prefix_hits,
-                                          pool.prefix_misses)
+                                          pool.prefix_misses,
+                                          pool.evictions)
         # bounded: callers hold their own Request handles (submit returns
         # them); this ring is a debugging/inspection tail, and unbounded
         # growth would leak every prompt ever served on a long-running
@@ -985,7 +986,8 @@ class Scheduler:
             # occupancy): utilization + prefix tallies ride the snapshot
             self.metrics.on_blocks(pool.used, pool.usable)
             self.metrics.on_prefix_totals(pool.prefix_hits,
-                                          pool.prefix_misses)
+                                          pool.prefix_misses,
+                                          pool.evictions)
         if self.slo_engine is not None and worked:
             # re-evaluate once per WORKING round: gauges track live,
             # transitions journal, /healthz serves the cached verdict

@@ -18,6 +18,8 @@ scripts/chaos_serving.py) and prove the acceptance contract:
     mid-decode starvation preempts by recompute and the resumed request
     still produces the same tokens.
 """
+import collections
+
 import numpy as np
 import pytest
 
@@ -26,7 +28,7 @@ from paddle_tpu.nlp import LlamaConfig, LlamaForCausalLM
 from paddle_tpu.serving import (BlockPool, BlockPoolExhausted,
                                 PagedServingEngine, Scheduler,
                                 ServingEngine)
-from paddle_tpu.utils import chaos
+from paddle_tpu.utils import chaos, telemetry
 
 VOCAB = 128
 MAX_LEN = 64
@@ -200,6 +202,270 @@ def test_block_pool_refcount_and_cow_units():
     assert pool.match_prefix(toks)[0] == []    # the hash is gone
     with pytest.raises(ValueError, match="double free"):
         pool.release([a[0], a[0]])
+
+
+class _OneListPool(BlockPool):
+    """The allocator as it stood before the two free lists: ONE list in
+    freeing order, scanned from its oldest entry for a block without a
+    hash, the oldest evicted when there is none. Linear in the free
+    list for every block, which is why it went; kept here as the oracle
+    for the block ids the two-list pool has to hand out."""
+
+    def __init__(self, num_blocks, block_size):
+        self._free = collections.OrderedDict(
+            (b, None) for b in range(1, num_blocks))
+        super().__init__(num_blocks, block_size)
+        del self._free_plain, self._free_cached
+
+    @property
+    def used(self):
+        return self.usable - len(self._free)
+
+    def alloc(self, n):
+        if n > len(self._free):
+            raise BlockPoolExhausted(f"need {n}, {len(self._free)} free")
+        out = []
+        for _ in range(n):
+            blk = next((b for b in self._free
+                        if b not in self._block_hash), None)
+            if blk is None:
+                blk = next(iter(self._free))       # evict oldest cached
+            del self._free[blk]
+            h = self._block_hash.pop(blk, None)
+            if h is not None:
+                del self._hash_to_block[h]
+                self.evictions += 1
+            self._ref[blk] = 1
+            out.append(blk)
+        return out
+
+    def release(self, blocks):
+        for blk in blocks:
+            if self._ref[blk] < 1:
+                raise ValueError(f"double free of block {blk}")
+            self._ref[blk] -= 1
+            if self._ref[blk] == 0:
+                self._free[blk] = None
+
+    def match_prefix(self, tokens):
+        blocks, hashes = [], []
+        for h in self.prompt_hashes(tokens):
+            blk = self._hash_to_block.get(h)
+            if blk is None:
+                break
+            if self._ref[blk] == 0:
+                del self._free[blk]
+            self._ref[blk] += 1
+            blocks.append(blk)
+            hashes.append(h)
+        return blocks, hashes
+
+
+_POOL_OPS = ("admit", "release", "grow", "acquire", "cow", "import",
+             "exhaust", "drain")
+_POOL_OP_P = (0.30, 0.27, 0.15, 0.06, 0.06, 0.06, 0.06, 0.04)
+
+
+def _drive_pool(pool, seed, density, steps):
+    """One random life of a pool: admissions that share prefixes,
+    decode growth, releases, extra references, copy-on-write, block
+    hand-off and exhaustion. `density` is the share of admitted prompts
+    whose full blocks are hashed. Returns the log of everything the
+    pool answered; what a step does follows from the answers before
+    it, so two pools that answer alike are driven alike."""
+    rng = np.random.RandomState(seed)
+    bs = pool.block_size
+    longest = max(1, min(pool.usable // 3, 160))        # blocks a prompt
+    docs = [rng.randint(0, 50, (rng.randint(1, longest + 1) * bs,)).tolist()
+            for _ in range(4)]
+    held, log = [], []
+
+    def take(fn, arg):
+        """What an allocating call answered: (its result, the cached
+        blocks it evicted with their hashes), or "exhausted" with
+        nothing taken."""
+        cached, before = dict(pool._block_hash), pool.outstanding()
+        try:
+            got = fn(arg)
+        except BlockPoolExhausted:
+            assert pool.outstanding() == before
+            assert pool._block_hash == cached
+            return "exhausted"
+        gone = sorted(set(cached.items()) - set(pool._block_hash.items()))
+        return got, gone
+
+    for step in range(steps):
+        op = _POOL_OPS[rng.choice(len(_POOL_OPS), p=_POOL_OP_P)]
+        if not held and op in ("release", "grow", "acquire", "cow",
+                               "import"):
+            op = "admit"
+        pick = rng.randint(len(held)) if held else 0
+        if op == "admit":
+            if rng.rand() < 0.7:                # begins with a document
+                toks = docs[rng.randint(len(docs))] + rng.randint(
+                    0, 50, (rng.randint(0, 2 * bs),)).tolist()
+            else:
+                toks = rng.randint(50, 99, (rng.randint(
+                    1, longest * bs + 1),)).tolist()
+            hashed = rng.rand() < density
+            shared, _ = pool.match_prefix(toks)
+            got = take(pool.alloc, -(-len(toks) // bs) - len(shared))
+            if got == "exhausted":
+                pool.release(shared)
+            else:
+                held.append(shared + got[0])
+                if hashed:
+                    for blk, h in zip(held[-1], pool.prompt_hashes(toks)):
+                        pool.register_hash(blk, h)
+            log.append((shared, got))
+        elif op == "release":
+            pool.release(held.pop(pick))
+        elif op == "grow":
+            got = take(pool.alloc, 1)
+            if got != "exhausted":
+                held[pick].extend(got[0])
+            log.append(got)
+        elif op == "acquire":
+            blk = held[pick][rng.randint(len(held[pick]))]
+            pool.acquire(blk)
+            held.append([blk])
+        elif op == "cow":
+            i = rng.randint(len(held[pick]))
+            got = take(pool.cow, held[pick][i])
+            if got != "exhausted":
+                held[pick][i] = got[0]
+            log.append(got)
+        elif op == "import":
+            manifest = pool.export_blocks(held[pick])
+            got = take(pool.import_blocks, manifest)
+            if got != "exhausted":
+                for blk, entry in zip(got[0], manifest):
+                    if entry["hash"] is not None:
+                        pool.register_hash(blk, entry["hash"])
+                held.append(got[0])
+            log.append(got)
+        elif op == "exhaust":
+            log.append(take(pool.alloc, pool.usable - pool.used + 1))
+        else:
+            while held:
+                pool.release(held.pop())
+        log.append((step, op, pool.stats()))
+        if step % 20 == 0:
+            log.append((pool.outstanding(),
+                        sorted(pool._block_hash.items())))
+    while held:
+        pool.release(held.pop())
+    log.append((pool.outstanding(), pool.stats(),
+                sorted(pool._block_hash.items())))
+    return log
+
+
+@pytest.mark.parametrize("density", [0.0, 0.5, 1.0],
+                         ids=["none", "half", "all"])
+@pytest.mark.parametrize("num_blocks", [5, 64, 4097])
+@pytest.mark.parametrize("seed", [0, 1, 2])
+def test_two_free_lists_hand_out_the_one_list_pools_ids(seed, num_blocks,
+                                                        density):
+    """The two-list pool against the one-list oracle over one random
+    life each: the same block ids in the same order, the same
+    refcounts, `stats()`, eviction victims, and BlockPoolExhausted at
+    the same calls with nothing taken."""
+    steps = 800 if num_blocks > 64 else 400
+    want = _drive_pool(_OneListPool(num_blocks, 4), seed, density, steps)
+    got = _drive_pool(BlockPool(num_blocks, 4), seed, density, steps)
+    for i, (g, w) in enumerate(zip(got, want)):
+        assert g == w, f"log entry {i}"
+    assert len(got) == len(want)
+    final = got[-1][1]
+    assert got[-1][0] == {}                     # every reference returned
+    # the life reached both regimes it is there for
+    assert (final["evictions"] > 0) == (density > 0)
+    assert any(e == "exhausted" for e in got)
+
+
+class _CountedFreeList(collections.OrderedDict):
+    """A free list that refuses to be walked and counts the calls it
+    serves: each of them touches one entry."""
+    calls = 0
+
+    def _walked(self, *args, **kwargs):
+        raise AssertionError("a BlockPool method iterated a free list")
+
+    __iter__ = __reversed__ = keys = values = items = _walked
+
+    def _counted(name):
+        def method(self, *args, **kwargs):
+            type(self).calls += 1
+            return getattr(collections.OrderedDict, name)(
+                self, *args, **kwargs)
+        return method
+
+    popitem = _counted("popitem")
+    __getitem__ = _counted("__getitem__")
+    __setitem__ = _counted("__setitem__")
+    __delitem__ = _counted("__delitem__")
+    del _counted
+
+
+def _free_list_calls(num_blocks):
+    """Calls on the free lists for 200 x `alloc(1)`, their release and
+    a reviving `match_prefix` over 100 blocks, on a pool whose every
+    free block carries a hash."""
+    pool = BlockPool(num_blocks, 4)
+    toks = list(range(100 * 4))
+    blocks = pool.alloc(pool.usable)
+    for blk, h in zip(blocks, pool.prompt_hashes(toks)):
+        pool.register_hash(blk, h)
+    for blk in blocks[100:]:
+        pool.register_hash(blk, blk)            # any hashable will do
+    pool.release(blocks[100:] + blocks[:100])   # the prompt's: newest
+    pool._free_plain = _CountedFreeList(pool._free_plain)
+    pool._free_cached = _CountedFreeList(pool._free_cached)
+    _CountedFreeList.calls = 0
+    taken = [pool.alloc(1)[0] for _ in range(200)]
+    assert taken == blocks[100:300] and pool.evictions == 200
+    pool.release(taken)
+    revived, _ = pool.match_prefix(toks)
+    assert revived == blocks[:100]
+    assert pool.used == 100
+    return _CountedFreeList.calls
+
+
+def test_block_pool_never_walks_a_free_list():
+    """The cost that went does not come back: with 50,000 free hashed
+    blocks `alloc(1)`, `release` and a reviving `match_prefix` touch a
+    constant number of free-list entries a block (the one-list scan
+    visited all 50,000 for every block), and iterate neither list."""
+    calls = _free_list_calls(50_001)
+    assert calls == _free_list_calls(501)       # whatever the pool's size
+    assert calls <= 4 * (200 + 200 + 100)
+
+
+def test_evictions_counted_once_plain_blocks_are_gone():
+    """`evictions`: 0 while plain blocks remain, one a block once they
+    are gone, unchanged by a revival; in `stats()` and as a telemetry
+    counter."""
+    name = "serving_prefix_cache_evictions_total"
+    base = telemetry.value(name, default=0)
+    pool = BlockPool(num_blocks=6, block_size=4)        # 5 usable
+    toks = list(range(8))
+    a = pool.alloc(5)
+    for blk, h in zip(a, pool.prompt_hashes(toks)):
+        pool.register_hash(blk, h)                      # a[0], a[1] cached
+    pool.release(a)
+    assert pool.alloc(3) == a[2:]                       # plain ones first
+    assert pool.evictions == 0
+    revived, _ = pool.match_prefix(toks)                # off the cached list
+    assert revived == a[:2] and pool.evictions == 0
+    pool.release(revived)
+    assert pool.alloc(1) == a[:1] and pool.evictions == 1
+    assert pool.alloc(1) == a[1:2] and pool.evictions == 2
+    assert pool.stats()["evictions"] == 2
+    assert pool.stats()["cached_hashes"] == 0
+    assert telemetry.value(name, default=0) - base == 2
+    with pytest.raises(BlockPoolExhausted):
+        pool.alloc(1)
+    assert pool.evictions == 2
 
 
 def test_cow_under_forced_sharing_keeps_tokens(model, paged):
@@ -440,6 +706,27 @@ def test_prefix_hits_sampled_on_immediate_retire(model):
     snap = sched.metrics.snapshot()
     assert snap["prefix_hits"] >= 2                 # both full blocks re-hit
     assert snap["block_utilization"] is not None
+
+
+def test_prefix_evictions_reach_the_snapshot(model):
+    """A pool that distinct prompts have filled with cached blocks hands
+    the next ones out by eviction, and the scheduler's snapshot reports
+    the pool's count over its own lifetime."""
+    eng = PagedServingEngine(model, num_slots=2, max_len=MAX_LEN,
+                             block_size=BLOCK, num_blocks=9,
+                             prefill_chunk_len=CHUNK)   # 8 usable
+    pool = eng.block_pool
+    sched = Scheduler(eng)
+    for seed in range(80, 83):                  # 3 blocks each, 2 hashed
+        sched.generate(_prompt(seed, n=2 * BLOCK), max_tokens=2)
+    assert pool.evictions == 0                  # plain blocks lasted
+    assert sched.metrics.snapshot()["prefix_evictions"] == 0
+    later = Scheduler(eng)
+    for seed in range(83, 86):
+        later.generate(_prompt(seed, n=2 * BLOCK), max_tokens=2)
+    assert pool.evictions > 0
+    assert later.metrics.snapshot()["prefix_evictions"] == pool.evictions
+    assert pool.stats()["evictions"] == pool.evictions
 
 
 def test_timeout_mid_chunked_prefill_retires_without_tokens(model):

@@ -106,7 +106,7 @@ def test_snapshot_keys_byte_compatible(engine):
         "slot_occupancy", "queue_depth_peak",
         "faults", "rejected", "wave_retries",
         "block_utilization", "prefix_hits", "prefix_misses",
-        "prefix_hit_rate",
+        "prefix_hit_rate", "prefix_evictions",
         # fleet PR appended the raw span endpoints (rollups across
         # replicas need min(first)/max(last), not per-engine spans)
         "first_token_time", "last_token_time",
@@ -137,6 +137,7 @@ def test_snapshot_keys_byte_compatible(engine):
     # dense engine: the paged-pool keys are present but empty
     assert snap["block_utilization"] is None
     assert snap["prefix_hits"] == 0 and snap["prefix_hit_rate"] is None
+    assert snap["prefix_evictions"] == 0
     assert snap["paged_pages_visited"] == snap["paged_pages_spanned"] == 0
     assert snap["requests_completed"] == 1
     assert snap["ttft_p50_s"] is not None
