@@ -7,4 +7,5 @@ from .bert import BertModel, BertForPretraining, BertConfig, bert_base, bert_lar
 from .llama import (LlamaModel, LlamaForCausalLM, LlamaConfig,
                     llama_pretrain_loss)
 from .nemotron_h import NemotronHConfig, NemotronHForCausalLM
+from .granite_hybrid import GraniteHybridConfig, GraniteHybridForCausalLM
 from .deepseek_v3 import DeepseekV3Config, DeepseekV3ForCausalLM

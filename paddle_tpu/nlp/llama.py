@@ -185,15 +185,17 @@ def _split_rope_bshd(a, cos, sin, nh, nkv, hd):
     return q, k, v.reshape(b, s, nkv, hd)
 
 
-def _gqa_flash_bshd(q, k, v, nh, nkv, window):
+def _gqa_flash_bshd(q, k, v, nh, nkv, window, scale=None):
     """GQA kv-head repeat (free reshape-broadcast under XLA) + causal
-    flash attention, bshd layout."""
+    flash attention, bshd layout; `scale` multiplies q k^T
+    (None: 1/sqrt(head_dim))."""
     if nkv != nh:
         rep = nh // nkv
         k = jnp.repeat(k, rep, axis=2)
         v = jnp.repeat(v, rep, axis=2)
     from ..ops.pallas.flash_attention import _flash_array
-    return _flash_array(q, k, v, causal=True, layout="bshd", window=window)
+    return _flash_array(q, k, v, causal=True, layout="bshd", window=window,
+                        scale=scale)
 
 
 def _llama_attention_raw(x, wqkv, cos, sin, num_heads=1, num_kv_heads=1,

@@ -250,12 +250,16 @@ class Mamba2Mixer(_Block):
 # ---------------------------------------------------------------- attention
 class NemotronHAttention(_Block):
     """Causal grouped-query attention, no bias, no window, no rotary
-    embedding (the config's `rope_theta` is unused by this model)."""
+    embedding (the config's `rope_theta` is unused by this model).
+    `scale` multiplies q k^T: 1/sqrt(head_dim) unless the model states
+    its own (`nlp/granite_hybrid.py`: `attention_multiplier`)."""
 
-    def __init__(self, cfg):
+    def __init__(self, cfg, scale=None):
         super().__init__(cfg)
         self.nh, self.nkv = cfg.num_attention_heads, cfg.num_key_value_heads
         self.hd = cfg.head_dim
+        self.scale = (1.0 / math.sqrt(self.hd) if scale is None
+                      else float(scale))
         self.qkv_proj = self._matrix(cfg.hidden_size,
                                      (self.nh + 2 * self.nkv) * self.hd)
         self.o_proj = self._matrix(self.nh * self.hd, cfg.hidden_size)
@@ -283,7 +287,8 @@ class NemotronHAttention(_Block):
 
     def forward(self, x):
         q, k, v = self._qkv(x)
-        o = _gqa_flash_bshd(q, k, v, self.nh, self.nkv, None)
+        o = _gqa_flash_bshd(q, k, v, self.nh, self.nkv, None,
+                            scale=self.scale)
         return (o.reshape(*x.shape[:2], -1).astype(x.dtype)
                 @ self.o_proj._data)
 
@@ -294,7 +299,7 @@ class NemotronHAttention(_Block):
         from ..nn.paged_attention import paged_attend
         q, k, v = (jnp.swapaxes(t, 1, 2) for t in self._qkv(x))
         o, cache = paged_attend(q, k, v, cache, tables, start, valid_len,
-                                1.0 / math.sqrt(self.hd))
+                                self.scale)
         return self._out(o, x.dtype), cache
 
 
@@ -307,6 +312,87 @@ class NemotronHMoE(RoutedExperts):
 
     def __init__(self, cfg):
         super().__init__(cfg, cfg.moe_shared_expert_intermediate_size)
+
+
+# ------------------------------------------------------ ways down a stack
+# What a hybrid stack's three entry points say to a layer's mixer:
+# `call(mixer, cache, x) -> (mixer output, the layer's cache after it)`.
+# A Mamba mixer's cache is its slots' records, an attention layer's its
+# block pool, any other mixer's None. One set for every stack whose
+# mixers are these classes (this file's, `nlp/granite_hybrid.py`'s).
+def cache_group(mixer):
+    """"state", "kv" or None: the list of the paged cache that holds
+    this mixer's entry."""
+    if isinstance(mixer, Mamba2Mixer):
+        return "state"
+    return "kv" if isinstance(mixer, NemotronHAttention) else None
+
+
+def init_paged_caches(mixers, num_blocks, block_size, dtype, num_slots):
+    """{"kv": a K/V block pool an attention layer, "state": a Mamba
+    record (`ssm`, `conv`; leading dimension `num_slots`) a Mamba
+    layer}, each list in the layers' order."""
+    return {"kv": [m.init_paged_cache(num_blocks, block_size, dtype)
+                   for m in mixers if cache_group(m) == "kv"],
+            "state": [m.init_state(num_slots, dtype) for m in mixers
+                      if cache_group(m) == "state"]}
+
+
+def through(call, mixer, caches, i, x):
+    """`call` on one layer's mixer with entry `i` of the list that holds
+    its cache (if it keeps one), which the new entry replaces in
+    `caches`; returns the mixer's output."""
+    store = caches.get(cache_group(mixer))
+    out, new = call(mixer, None if store is None else store[i], x)
+    if store is not None:
+        store[i] = new
+    return out
+
+
+def sequence_call(batch):
+    """A whole sequence: attention over the sequence itself, the scan
+    from a zero state; nothing is cached."""
+    def call(mixer, cache, x):
+        if isinstance(mixer, Mamba2Mixer):
+            fresh = mixer.init_state(batch, x.dtype)
+            return mixer.scan(x, fresh["ssm"], fresh["conv"])[0], cache
+        return mixer(x), cache
+    return call
+
+
+def wave_call(pos, tables, active):
+    """One token a lane (lane b is slot b) at positions `pos` [B];
+    lanes where `active` is false keep their records."""
+    def call(mixer, cache, x):
+        if isinstance(mixer, Mamba2Mixer):
+            out, ssm, conv = mixer.step(x, cache["ssm"], cache["conv"],
+                                        active)
+            return out, {"ssm": ssm, "conv": conv}
+        if isinstance(mixer, NemotronHAttention):
+            return mixer.paged_step(x, cache, tables, pos)
+        return mixer(x), cache
+    return call
+
+
+def chunk_call(tables, chunk_start, valid_len, slot):
+    """One prompt chunk [1, C] of the request in `slot` at absolute
+    positions chunk_start + arange(C), `valid_len` of them real."""
+    def call(mixer, cache, x):
+        if isinstance(mixer, Mamba2Mixer):
+            ssm = jax.lax.dynamic_slice_in_dim(cache["ssm"], slot, 1)
+            conv = jax.lax.dynamic_slice_in_dim(cache["conv"], slot, 1)
+            out, ssm, conv = mixer.scan(x, ssm, conv, valid_len)
+            return out, {
+                "ssm": jax.lax.dynamic_update_slice_in_dim(
+                    cache["ssm"], ssm, slot, 0),
+                "conv": jax.lax.dynamic_update_slice_in_dim(
+                    cache["conv"], conv.astype(cache["conv"].dtype),
+                    slot, 0)}
+        if isinstance(mixer, NemotronHAttention):
+            return mixer.paged_step(x, cache, tables, chunk_start,
+                                    valid_len)
+        return mixer(x), cache
+    return call
 
 
 # -------------------------------------------------------------------- stack
@@ -355,69 +441,37 @@ class NemotronHForCausalLM(nn.Layer):
         #: (token, expert) pairs one token makes on its way down the stack
         self.moe_picks_per_token = kinds.count("E") * cfg.num_experts_per_tok
 
-    def _run(self, ids, caches, mixer_call):
-        """The residual stack over ids [B, L]; `mixer_call(block, cache,
-        x)` -> (mixer output, new cache). Returns (final hidden, caches)."""
+    def _run(self, ids, caches, call):
+        """The residual stack over ids [B, L]; `call` is one of the ways
+        down a stack above. Returns (final hidden, caches)."""
         x = self.embeddings._data[_raw(ids)]
-        kv, state = list(caches["kv"]), list(caches["state"])
+        caches = {k: list(v) for k, v in caches.items()}
         for blk, i in zip(self.layers, self._cache_index):
-            store = {"M": state, "*": kv}.get(blk.kind)
-            out, new = mixer_call(
-                blk, None if store is None else store[i],
-                _rms_norm_raw(x, blk.norm_weight._data, blk.eps))
-            if store is not None:
-                store[i] = new
-            x = x + out
+            x = x + through(call, blk.mixer, caches, i, _rms_norm_raw(
+                x, blk.norm_weight._data, blk.eps))
         x = _rms_norm_raw(x, self.norm_f_weight._data,
                           self.cfg.layer_norm_epsilon)
-        return x, {"kv": kv, "state": state}
+        return x, caches
 
     def forward(self, input_ids):
         """Logits [B, L, V] of a whole sequence: attention over the
         sequence itself, the scan from a zero state."""
-        batch = _raw(input_ids).shape[0]
-
-        def call(blk, cache, x):
-            if blk.kind == "M":
-                fresh = blk.mixer.init_state(batch, x.dtype)
-                return blk.mixer.scan(x, fresh["ssm"], fresh["conv"])[0], \
-                    cache
-            return blk.mixer(x), cache
-
-        kinds = self.cfg.hybrid_override_pattern
-        empty = {"kv": [None] * kinds.count("*"),
-                 "state": [None] * kinds.count("M")}
-        x, _ = self._run(input_ids, empty, call)
+        x, _ = self._run(input_ids, {},
+                         sequence_call(_raw(input_ids).shape[0]))
         return Tensor(x @ self.lm_head._data)
 
     def init_paged_cache(self, num_blocks, block_size, max_len,
                          dtype=jnp.float32, num_slots=1):
-        """{"kv": a K/V block pool an attention layer, "state": a
-        Mamba record (`ssm`, `conv`; leading dimension `num_slots`) a
-        Mamba layer}."""
-        mixers = [blk.mixer for blk in self.layers]
-        return {"kv": [m.init_paged_cache(num_blocks, block_size, dtype)
-                       for m in mixers
-                       if isinstance(m, NemotronHAttention)],
-                "state": [m.init_state(num_slots, dtype) for m in mixers
-                          if isinstance(m, Mamba2Mixer)]}
+        """`init_paged_caches` of this stack's mixers."""
+        return init_paged_caches([blk.mixer for blk in self.layers],
+                                 num_blocks, block_size, dtype, num_slots)
 
     def decode_step(self, tok, caches, pos, block_tables, active):
         """One token a lane: tok [B, 1], pos [B], tables [B, nblk],
         active [B] bool (lane b is slot b). Returns (logits [B, 1, V],
         caches)."""
-        pos, tables, active = _raw(pos), _raw(block_tables), _raw(active)
-
-        def call(blk, cache, x):
-            if blk.kind == "M":
-                out, ssm, conv = blk.mixer.step(x, cache["ssm"],
-                                                cache["conv"], active)
-                return out, {"ssm": ssm, "conv": conv}
-            if blk.kind == "*":
-                return blk.mixer.paged_step(x, cache, tables, pos)
-            return blk.mixer(x), cache
-
-        x, caches = self._run(tok, caches, call)
+        x, caches = self._run(tok, caches, wave_call(
+            _raw(pos), _raw(block_tables), _raw(active)))
         return x @ self.lm_head._data, caches
 
     def prefill_chunk(self, tok_chunk, caches, block_tables, chunk_start,
@@ -425,25 +479,8 @@ class NemotronHForCausalLM(nn.Layer):
         """One prompt chunk [1, C] of the request in `slot` at absolute
         positions chunk_start + arange(C). Returns (logits [1, 1, V] at
         the chunk's `frontier` row, caches)."""
-        tables, slot = _raw(block_tables), _raw(slot)
-        chunk_start, valid_len = _raw(chunk_start), _raw(valid_len)
-
-        def call(blk, cache, x):
-            if blk.kind == "M":
-                ssm = jax.lax.dynamic_slice_in_dim(cache["ssm"], slot, 1)
-                conv = jax.lax.dynamic_slice_in_dim(cache["conv"], slot, 1)
-                out, ssm, conv = blk.mixer.scan(x, ssm, conv, valid_len)
-                return out, {
-                    "ssm": jax.lax.dynamic_update_slice_in_dim(
-                        cache["ssm"], ssm, slot, 0),
-                    "conv": jax.lax.dynamic_update_slice_in_dim(
-                        cache["conv"], conv.astype(cache["conv"].dtype),
-                        slot, 0)}
-            if blk.kind == "*":
-                return blk.mixer.paged_step(x, cache, tables, chunk_start,
-                                            valid_len)
-            return blk.mixer(x), cache
-
-        x, caches = self._run(tok_chunk, caches, call)
+        x, caches = self._run(tok_chunk, caches, chunk_call(
+            _raw(block_tables), _raw(chunk_start), _raw(valid_len),
+            _raw(slot)))
         x = jax.lax.dynamic_slice_in_dim(x, frontier, 1, axis=1)
         return x @ self.lm_head._data, caches

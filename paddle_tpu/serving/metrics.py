@@ -174,7 +174,8 @@ def record_callback_error(request, error):
 #: what `PagedServingEngine.take_model_counts` counts, and the snapshot
 #: carries under the same names (0 for a model that has none of it)
 MODEL_COUNTS = ("state_resets", "moe_picks", "mla_rows_attended",
-                "mla_rows_expanded", "prefill_tokens", "prefill_chunks")
+                "mla_rows_expanded", "prefill_tokens", "prefill_chunks",
+                "ssm_records_stepped", "ssm_lanes_stepped")
 
 
 class ServingMetrics:
@@ -218,8 +219,9 @@ class ServingMetrics:
         self._pages_spanned = 0
         # what a model with slot state, experts or a latent cache was
         # staged (0 for any other): slot records zeroed at admission,
+        # records the waves stepped and lanes that decoded in them,
         # (token, expert) pairs routed, latent rows attended by the
-        # waves and expanded by the chunks, those chunks and their
+        # waves and expanded by the chunks, the chunks and their
         # prompt tokens
         self._model_counts = dict.fromkeys(MODEL_COUNTS, 0)
         # [V] rows and [S, V] matrices of logit bias the engine sent to

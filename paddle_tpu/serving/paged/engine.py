@@ -390,9 +390,9 @@ class PagedServingEngine(ServingEngine):
                                        sampling, self._tables[slot]))
             counts = self._model_counts
             counts["moe_picks"] += valid * self._picks_per_token
+            counts["prefill_tokens"] += valid
+            counts["prefill_chunks"] += 1
             if self.latent_cache:
-                counts["prefill_tokens"] += valid
-                counts["prefill_chunks"] += 1
                 counts["mla_rows_expanded"] += int(
                     paged_attention.expanded_rows(c0, C, bs,
                                                   self.blocks_per_slot))
@@ -653,8 +653,13 @@ class PagedServingEngine(ServingEngine):
             self.blocks_per_slot, self._attn_window)
         self._pages_visited += int(np.sum(hi - lo))
         self._pages_spanned += tables.size
-        self._model_counts["moe_picks"] += (
-            int(np.count_nonzero(active_now)) * self._picks_per_token)
+        lanes = int(np.count_nonzero(active_now))
+        self._model_counts["moe_picks"] += lanes * self._picks_per_token
+        if self.slot_state:
+            # the wave's program steps every slot's record, whether its
+            # lane decodes or keeps what it had
+            self._model_counts["ssm_records_stepped"] += self.num_slots
+            self._model_counts["ssm_lanes_stepped"] += lanes
         if self.latent_cache:
             self._model_counts["mla_rows_attended"] += int(
                 np.sum(self.slot_pos[np.asarray(active_now, bool)] + 1))
@@ -702,12 +707,13 @@ class PagedServingEngine(ServingEngine):
 
     def take_model_counts(self):
         """What the model's own layers were staged since the last call:
-        slot records zeroed at admission; (token, expert) pairs of the
-        tokens staged into chunks and waves; of a latent cache, the rows
-        the waves' lanes attend and the rows the chunks expand (each a
-        layer), the chunks and the prompt tokens they carried. Taken by the
-        scheduler once a round, from an engine whose `counts_model_work`
-        is set."""
+        slot records zeroed at admission, records the waves stepped
+        (every slot's) and the lanes that decoded in them; (token,
+        expert) pairs of the tokens staged into chunks and waves; the
+        chunks and the prompt tokens they carried; of a latent cache,
+        the rows the waves' lanes attend and the rows the chunks expand
+        (each a layer). Taken by the scheduler once a round, from an
+        engine whose `counts_model_work` is set."""
         out = self._model_counts
         self._model_counts = dict.fromkeys(MODEL_COUNTS, 0)
         return out

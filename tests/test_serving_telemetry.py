@@ -123,6 +123,7 @@ def test_snapshot_keys_byte_compatible(engine):
         # staged (0 for any other)
         "state_resets", "moe_picks", "mla_rows_attended",
         "mla_rows_expanded", "prefill_tokens", "prefill_chunks",
+        "ssm_records_stepped", "ssm_lanes_stepped",
         # bias rows and matrices sent to the device (0 while no request
         # brings a bias)
         "bias_uploads"]
@@ -144,6 +145,61 @@ def test_snapshot_keys_byte_compatible(engine):
     assert snap["ttft_p50_s"] <= snap["latency_p50_s"]
     assert snap["faults"] == {} and snap["rejected"] == 0
     assert json.dumps(snap)                       # still serializable
+
+
+@pytest.mark.parametrize("family", ["granite-hybrid", "nemotron-h",
+                                    "llama"])
+def test_slot_state_waves_count_their_records_and_lanes(family):
+    """A wave of a model with slot state reads and writes every slot's
+    record (`ssm_records_stepped`: the slots, each wave) whichever
+    lanes decode in it (`ssm_lanes_stepped`); chunks and their prompt
+    tokens are counted for it too. A model without slot state counts
+    no records."""
+    from paddle_tpu.nlp import (GraniteHybridConfig,
+                                GraniteHybridForCausalLM, NemotronHConfig,
+                                NemotronHForCausalLM)
+    from paddle_tpu.serving import PagedServingEngine
+    pt.seed(3)
+    if family == "granite-hybrid":
+        model = GraniteHybridForCausalLM(GraniteHybridConfig(
+            vocab_size=VOCAB, hidden_size=64,
+            layer_types=("mamba", "attention"), num_attention_heads=4,
+            num_key_value_heads=2, shared_intermediate_size=96,
+            mamba_n_heads=8, mamba_d_head=16, mamba_d_state=16,
+            mamba_chunk_size=8))
+    elif family == "nemotron-h":
+        model = NemotronHForCausalLM(NemotronHConfig(
+            vocab_size=VOCAB, hidden_size=64, hybrid_override_pattern="M*",
+            num_attention_heads=4, num_key_value_heads=2, head_dim=16,
+            mamba_num_heads=8, mamba_head_dim=8, n_groups=2,
+            ssm_state_size=16, chunk_size=8))
+    else:
+        model = LlamaForCausalLM(LlamaConfig(
+            vocab_size=VOCAB, hidden_size=64, num_layers=2, num_heads=4,
+            num_kv_heads=2, max_seq_len=64))
+    eng = PagedServingEngine(model.eval(), num_slots=3, max_len=64,
+                             block_size=8, prefill_chunk_len=16)
+    sched = Scheduler(eng)
+    # 20 prompt tokens in two chunks, then 4 waves of one lane of three
+    out = sched.generate(list(range(1, 21)), max_tokens=5)
+    snap = sched.metrics.snapshot()
+    assert len(out) == 5
+    if family == "llama":
+        assert snap["ssm_records_stepped"] == snap["ssm_lanes_stepped"] == 0
+        return
+    assert snap["ssm_records_stepped"] == 4 * 3
+    assert snap["ssm_lanes_stepped"] == 4
+    assert snap["prefill_chunks"] == 2 and snap["prefill_tokens"] == 20
+    assert snap["state_resets"] == 1
+    # two requests at once: both lanes decode in the waves they share
+    reqs = [sched.submit(prompt=list(range(1, 6)), max_tokens=4)
+            for _ in range(2)]
+    sched.run()
+    assert all(len(r.output_tokens) == 4 for r in reqs)
+    after = sched.metrics.snapshot()
+    waves = (after["ssm_records_stepped"] - snap["ssm_records_stepped"]) // 3
+    assert 3 <= waves <= 4
+    assert after["ssm_lanes_stepped"] - snap["ssm_lanes_stepped"] == 6
 
 
 def test_engine_metrics_server_and_healthz(engine):

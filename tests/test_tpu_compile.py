@@ -281,6 +281,30 @@ def test_latent_pool_passes_through_write_and_attention_in_place_for_v5e(
     assert _pool_stays(compiled, [pool]) < 1.04
 
 
+def _compile_engine_program(eng, program, sharding):
+    """A paged engine's decode wave or prefill chunk as the engine builds
+    it (its own closure, the arguments a round stages, the caches
+    donated), compiled for the described chip."""
+    slots, chunk = eng.num_slots, eng.prefill_chunk_len
+    if program == "decode_wave":
+        fn = eng._decode_wave_fn
+        args = eng._wave_args([True] * slots, np.zeros(slots, bool),
+                              jax.random.PRNGKey(0))
+    else:
+        fn = eng._prefill_fn
+        # the tuple `prefill_step` stages: the packed chunk, the
+        # resident zero bias row, the engine's key
+        greedy = eng._sampling_state(False, 1.0, 0, 1.0, None, False)
+        args = (*eng._prefill_chunk_args(0),
+                *eng._prompt_args(0, np.zeros(chunk, np.int32), 0, chunk, 0,
+                                  greedy, eng._tables[0]))
+    shapes = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(jnp.shape(a), jnp.result_type(a),
+                                       sharding=sharding), args)
+    return jax.jit(fn, donate_argnums=eng._program_donate_argnums
+                   ).lower(*shapes).compile()
+
+
 @pytest.mark.parametrize("program", ["decode_wave", "prefill_chunk"])
 @pytest.mark.parametrize("family", ["gpt-mha-d64", "llama-gqa-d128",
                                     "deepseek-mla"])
@@ -320,26 +344,10 @@ def test_engine_programs_keep_the_pool_in_place_for_v5e(one_chip, as_on_tpu,
                              prefill_chunk_len=chunk,
                              cache_dtype=jnp.bfloat16,
                              paged_kernel="pallas")
-    key = jax.random.PRNGKey(0)
-    if program == "decode_wave":
-        fn = eng._decode_wave_fn
-        args = eng._wave_args([True] * slots, np.zeros(slots, bool), key)
-    else:
-        fn = eng._prefill_fn
-        # the tuple `prefill_step` stages: the packed chunk, the
-        # resident zero bias row, the engine's key
-        greedy = eng._sampling_state(False, 1.0, 0, 1.0, None, False)
-        args = (*eng._prefill_chunk_args(0),
-                *eng._prompt_args(0, np.zeros(chunk, np.int32), 0, chunk, 0,
-                                  greedy, eng._tables[0]))
     pools = jax.tree_util.tree_leaves(eng._caches)
     assert [p.shape[1:] for p in pools] in (
         [(2, 16, 128)] * 2, [(2, 16, 256)] * 2, [(16, 640)] * 2)
-    shapes = jax.tree_util.tree_map(
-        lambda a: jax.ShapeDtypeStruct(jnp.shape(a), jnp.result_type(a),
-                                       sharding=one_chip), args)
-    compiled = jax.jit(fn, donate_argnums=eng._program_donate_argnums
-                       ).lower(*shapes).compile()
+    compiled = _compile_engine_program(eng, program, one_chip)
     _pool_stays(compiled, pools)
     # a layer: the paged kernel; of the latent model the absorbed kernel
     # in each layer of a wave, none in a chunk (expanded), and the
@@ -347,6 +355,40 @@ def test_engine_programs_keep_the_pool_in_place_for_v5e(one_chip, as_on_tpu,
     assert compiled.as_text().count("tpu_custom_call") == {
         ("deepseek-mla", "decode_wave"): 3,
         ("deepseek-mla", "prefill_chunk"): 1}.get((family, program), 2)
+
+
+@pytest.mark.parametrize("program", ["decode_wave", "prefill_chunk"])
+def test_dense_hybrid_engine_programs_keep_pool_and_state_in_place_for_v5e(
+        one_chip, as_on_tpu, program):
+    """granite4hm-serve-longdoc's programs as the engine builds them: the
+    published widths, 32 slots, chunks of 512 (two scan chunks of 256),
+    tables of 1,088 pages; one Mamba layer and one attention layer, a
+    small vocabulary and an eighth of the pages, so that the sandbox
+    holds the model. Neither the K/V pool ([blocks, 8, 16, 128]: 32
+    query heads in groups of 4, head size 64) nor the slots' Mamba
+    state (67 MB a layer) is copied, transposed or padded on its way
+    through; one paged kernel."""
+    import paddle_tpu as pt
+    from paddle_tpu.nlp import GraniteHybridConfig, GraniteHybridForCausalLM
+    from paddle_tpu.serving import PagedServingEngine
+
+    pt.seed(0)
+    slots, chunk, max_len = 32, 512, 17408
+    model = GraniteHybridForCausalLM(GraniteHybridConfig(
+        vocab_size=512, layer_types=("mamba", "attention"),
+        param_dtype="bfloat16", init_weights=False))
+    eng = PagedServingEngine(model, num_slots=slots, max_len=max_len,
+                             block_size=16, num_blocks=4353,
+                             prefill_chunk_len=chunk,
+                             cache_dtype=jnp.bfloat16,
+                             paged_kernel="pallas")
+    pool, = eng._caches["kv"]
+    ssm = eng._caches["state"][0]["ssm"]
+    assert pool.shape == (4353, 8, 16, 128) and pool.dtype == jnp.bfloat16
+    assert ssm.shape == (32, 64, 64, 128) and ssm.dtype == jnp.float32
+    compiled = _compile_engine_program(eng, program, one_chip)
+    _pool_stays(compiled, [pool, ssm])
+    assert compiled.as_text().count("tpu_custom_call") == 1
 
 
 @pytest.mark.parametrize("name,rows", [("wave-128-lanes", 768),
