@@ -19,27 +19,33 @@ Two cores attend, behind one dispatch point:
   kernel="pallas"     a Pallas TPU kernel that reads K/V straight out of
                       the pool through the table with an online softmax,
                       so the gathered view never exists. Grid over
-                      (lanes, groups of kv-heads, steps of pages). A step
-                      takes whole pages as the pool stores them (the
-                      [heads, BS, 2D] slab of every kv-head of the group)
-                      through the BlockSpec index_map over a
-                      scalar-prefetch table, and only the pages the lane
-                      attends (`attended_pages`, from its position, the
-                      chunk and the window): a step outside them names
-                      the page it already holds, so it moves no bytes,
-                      and skips the arithmetic. Accumulators sit in VMEM
-                      scratch across the sequential step dimension. How
-                      many kv-heads and pages share a step follows the
-                      shapes of the call (`_tile`): all heads and several
-                      pages in the decode form, where a pass through the
-                      recurrence costs the same however little it scores,
-                      so one pass scores them all; a few heads and one
-                      page in a 128-token chunk, where the query tile
-                      fills VMEM. `interpret=True` off the TPU so tier-1
-                      exercises the real kernel body; the body keeps
-                      every value 2-D, which is what the TPU compiler
-                      accepts (tests/test_tpu_compile.py compiles it for
-                      v5e at the cells' real shapes).
+                      (lanes, groups of kv-heads, query tiles, steps of
+                      pages). A step takes whole pages as the pool stores
+                      them (the [heads, BS, 2D] slab of every kv-head of
+                      the group) through the BlockSpec index_map over a
+                      scalar-prefetch walk of the table, and only the
+                      pages its query tile attends (`attended_pages`,
+                      from the tile's position, its queries and the
+                      window): a step outside them names the page it
+                      already holds, so it moves no bytes, and skips the
+                      arithmetic, and the step dimension ends where the
+                      call's furthest lane does (a traced grid bound).
+                      Accumulators sit in VMEM scratch across the
+                      sequential step dimension. What a step scores
+                      follows the keys' side (`_tile`): up to 32 pages,
+                      hundreds of keys, in both forms. A wave (one
+                      query a lane, or the verify wave's k + 1) takes
+                      them for every kv-head at once, because a pass
+                      through the recurrence costs its latency however
+                      little it scores; a chunk takes them for one
+                      kv-head against all of the chunk's queries, cut
+                      into query tiles (each with its own bounds) only
+                      where VMEM would not hold them. `interpret=True`
+                      off the TPU so tier-1 exercises the real kernel
+                      body; the body keeps every value 2-D, which is
+                      what the TPU compiler accepts
+                      (tests/test_tpu_compile.py compiles it for v5e at
+                      the cells' real shapes).
 
 Which core a call gets (`resolve_kernel`): an explicit `kernel=`
 argument, else the innermost active `kernel_scope(...)` (how a serving
@@ -295,9 +301,10 @@ def _reference_core(q, pool, tables, start, scale, window=None):
 
 
 # ---------------------------------------------------------------------------
-# Pallas kernel: grid (lanes, kv-head groups, page steps), table gather
-# in the BlockSpec index_map over the scalar-prefetch block table; only
-# the pages a lane attends are fetched and computed
+# Pallas kernel: grid (kv-head groups, the query tiles' steps of pages
+# end to end), table gather in the BlockSpec index_map over a
+# scalar-prefetch walk of the block table; only the pages a tile attends
+# are stepped over, fetched and computed
 # ---------------------------------------------------------------------------
 
 def attended_pages(start, c, bs, nblk, window=None):
@@ -322,112 +329,233 @@ def attended_pages(start, c, bs, nblk, window=None):
     return lo, hi
 
 
-#: rows of the query tile (kv-heads x group x chunk) one step may hold:
-#: float32 q, output and accumulator of 1024 x 256 are 1 MB each
-_MAX_ROWS = 1024
-#: pages a step takes at most (each is one more pipelined operand)
-_MAX_PAGES = 8
+#: VMEM a call asks the compiler for (a v5e core has 128 MiB, the
+#: compiler's own default is 16), and what the rule lets a step plan of it
+_VMEM_LIMIT = 64 << 20
+_VMEM_PLAN = 40 << 20
+#: pages a step takes at least and at most (each is one more pipelined
+#: operand; 64 of them compile and run on a v5e)
+_MIN_PAGES, _MAX_PAGES = 8, 64
+#: rows (group members x queries) of one kv-head a query tile holds
+_MAX_ROWS = 2048
+#: query rows of ALL kv-heads under which a call is a wave (one query a
+#: lane, or the verify wave's k + 1): every kv-head shares a step
+_WAVE_ROWS = 512
+
+_LANES = 128
 
 
-def _tile(c, rep, hkv):
-    """(kv-heads a step, pages a step) from the call's shapes. A step
-    takes whole pages: the [heads, BS, 2D] slab of as many kv-heads as
-    keep the query tile within `_MAX_ROWS` rows (all of them in the
-    decode form, where a row is one head of one lane), scored in one
-    matmul whose cross-head entries are masked. What a step costs is one
-    pass through the recurrence (matmul, max, exp, matmul: about 1.4 us
-    on a v5e however little it scores), so the fewer rows a page is
-    scored against, the more pages share a pass: as many as keep rows x
-    pages within an eighth of `_MAX_ROWS` (8 pages against a decode
-    wave's 12 rows, 4 against 32, one against a chunk's hundreds; past
-    that the chip's times are flat, PERF.md section 6, PR 26)."""
-    rc = rep * c
-    heads = max(g for g in range(1, hkv + 1)
-                if hkv % g == 0 and (g * rc <= _MAX_ROWS or g == 1))
-    pages = max(1, min(_MAX_PAGES, _MAX_ROWS // 8 // (heads * rc)))
-    return heads, pages
+def _width(d):
+    """Width of the kernel's query, key and value operands at head size
+    `d`: D where the stored [K | V] slab can be cut at D (a multiple of
+    a vreg's 128 lanes), else the whole slab's 2D."""
+    return d if d % _LANES == 0 else 2 * d
 
 
-def _paged_attn_kernel(tables_ref, start_ref, rows_ref, cols_ref, keys_ref,
-                       q_ref, *refs, scale, window, bs, c, nblk, pages):
-    """One (lane b, kv-head group g, step j) grid step: `pages` table
-    entries of the lane from `j * pages` on, each the [heads, BS, 2D]
-    slab of the group's kv-heads as the pool stores it, a key's K row
-    and V row side by side. The slab is never cut at D (at head_dim 64
-    that is the middle of a vreg's 128 lanes): the queries arrive
-    zero-extended to 2D, so `q @ slab.T` is `q @ K.T` (at an attended
-    key the V row is finite, or non-finite and due to propagate anyway;
-    anywhere else the score is masked), the accumulator is `p @ slab`,
-    2D wide, and its right half, `p @ V`, is cut out by the caller; both
-    are free at the MXU's width. The pipeline
-    gathered them through the index_map; if any lies inside the lane's
-    `attended_pages` the kernel scores them as one tile, masks it and
-    folds it into the VMEM accumulators, which persist across the
-    sequential step dimension; else it does nothing. A key's position
-    follows from where its page stands in the table, so the pages of a
-    visited step that lie outside the bounds (the index_map names a page
-    inside them there) fall to the masks like any other key no row
-    attends: to the window's below `lo`, to the causal one above `hi`,
-    which also stops at the table's end.
+def _step_vmem(heads, pages, rows, d, bs, itemsize):
+    """Bytes of VMEM one step of the kernel plans for `rows` query rows
+    against `pages` pages of `heads` kv-heads: the pipelined operands
+    twice (queries, pages, output), the accumulators (a `[rows, 1]`
+    float32 column is stored a whole vreg of lanes wide), the pages as
+    one slab in the pool's dtype and in float32, and four tiles the
+    size of the scores (scores, probabilities, masks)."""
+    w = _width(d)
+    cols = pages * heads * bs
+    return (2 * rows * w * itemsize + 2 * cols * 2 * d * itemsize
+            + 3 * rows * w * 4 + 2 * rows * _LANES * 4
+            + cols * 2 * d * (itemsize + 4) + 4 * rows * cols * 4)
 
-    The tile is 2-D (the TPU compiler lays vectors out over sublanes x
-    lanes and refuses 1-D iotas, vector loads from SMEM and the `[:, 0]`
-    / `[:, None]` casts between the two): rows are (kv-head, group
-    member, query) with the query minor, columns are (page, kv-head, key
-    in page); an entry counts where both name one head. The running max
-    and denominator stay `[rows, 1]`; a row's query position is the
-    lane's scalar `start` plus its offset within the chunk, a column's
-    key position the step's first position plus its offset from it
-    (`rows_ref`, `cols_ref`, and `keys_ref` for the rows of V)."""
+
+@functools.lru_cache(maxsize=None)
+def _tile(c, rep, hkv, d, bs, nblk, itemsize):
+    """(kv-heads a step, pages a step, queries a tile) from the call's
+    shapes: what one pass of the recurrence scores. Written from the
+    kernel timed alone on a v5e at the four served configurations' wave
+    and chunk shapes (scripts/paged_kernel_sweep.py; PERF.md section 6,
+    PR 36); every term below is what that table said.
+
+    Pages: a quarter of the table, as a power of two, within
+    `_MIN_PAGES` .. `_MAX_PAGES` (16 of gpt2-small's 64, 64 of
+    Mistral's 160, 32 of Nemotron's 128, 64 of Granite's 1,088) and
+    within the table. A pass costs its latency whatever it scores: a
+    visited step of a wave takes about 0.35 us plus 0.04-0.09 us a page
+    (Mistral's 64 KB pages arrive at 750 GB/s once a step holds 16),
+    so a lane's context in one or two passes beats eight (Granite's
+    wave 2.65 ms a layer at 4 pages a step, 1.36 at 32, 1.30 at 64; a
+    chunk's layer over 6k keys 3.25 ms at 4, 0.72 at 32, 0.50 at 64).
+    Past the contexts the table is there for, a step scores columns no
+    lane attends (gpt2-small's wave 0.76 ms at 16 pages, 0.99 at 32,
+    1.71 at 64: its lanes hold 14 pages in the mean), and the table's
+    length is what a call's shapes say of them.
+
+    Heads and queries: a wave (all kv-heads' rows within `_WAVE_ROWS`)
+    takes every kv-head in one step, scored in one product whose
+    cross-head entries are masked, because one kv-head a step runs the
+    pass once a head (gpt2-small's wave 2.4-2.9 ms a layer with a loop
+    over the heads inside the step, 12.6 with the heads in the grid,
+    0.76 in one product). A chunk takes one kv-head a step, so nothing
+    is masked away, against all its queries and group members up to
+    `_MAX_ROWS` rows (Granite's 2,048: 0.50 ms a layer at 64 pages,
+    0.63-0.67 in tiles of 1,024 rows, 0.54 in tiles of 512); a longer
+    chunk is cut into query tiles that divide it, each with its own
+    `attended_pages`. Then pages are halved until `_step_vmem` fits
+    `_VMEM_PLAN`."""
+    if hkv * rep * c <= _WAVE_ROWS:
+        heads, cq = hkv, c
+    else:
+        heads = 1
+        cq = max(t for t in range(1, c + 1)
+                 if c % t == 0 and (rep * t <= _MAX_ROWS or t == 1))
+    quarter = 1 << max(nblk // 4 - 1, 0).bit_length()
+    pages = min(max(quarter, _MIN_PAGES), _MAX_PAGES, nblk)
+    while pages > 1 and _step_vmem(heads, pages, heads * rep * cq, d, bs,
+                                   itemsize) > _VMEM_PLAN:
+        pages //= 2
+    return heads, pages, cq
+
+
+def _step_plan(start, c, bs, nblk, window, pages, cq):
+    """The steps of a call whose lanes' first queries sit at `start`
+    [B], in query tiles of `cq` and steps of `pages` table entries:
+    (first, count, visits, lo, hi), each [B * C // cq], one entry a
+    (lane, query tile) in that order, `lo` and `hi` the tile's
+    `attended_pages`. A tile's steps are `first .. first + count - 1`
+    of its lane's table, from the step that holds the first page it
+    attends to the one that holds the last, and nothing else: the grid
+    is the tiles' steps laid end to end, so no step walks a page only
+    another lane or a later tile attends. A tile that attends nothing
+    still gets one step (`count` 1, `visits` 0), so that its output is
+    written; `visits` are the steps that fetch and score. numpy or
+    traced, like `attended_pages`: the kernel's grid and the engine's
+    count of it are this one function."""
+    xp = np
+    if not isinstance(start, np.ndarray):
+        import jax.numpy as xp
+    tiles = (start[:, None]
+             + cq * np.arange(c // cq, dtype=np.int32)).reshape(-1)
+    lo, hi = attended_pages(tiles, cq, bs, nblk, window)
+    first = xp.where(hi > lo, lo // pages, 0)
+    visits = xp.where(hi > lo, (hi + pages - 1) // pages - first, 0)
+    return first, xp.maximum(visits, 1), visits, lo, hi
+
+
+def count_steps(start, c, rep, hkv, d, bs, nblk, itemsize, window=None):
+    """(run, visited): the grid steps one attention layer's kernel runs
+    for a call of `c` queries a lane at `start` [B] (numpy), and those
+    of them that fetch and score pages (all but the one step of a tile
+    that attends nothing). What the engine counts as `paged_steps_run` /
+    `paged_steps_visited`."""
+    heads, pages, cq = _tile(c, rep, hkv, d, bs, nblk, itemsize)
+    start = np.asarray(start, np.int32).reshape(-1)
+    _, count, visits, _, _ = _step_plan(start, c, bs, nblk, window, pages,
+                                        cq)
+    return (int(hkv // heads * count.sum()),
+            int(hkv // heads * visits.sum()))
+
+
+def _paged_attn_kernel(walk_ref, start_ref, tile_ref, step_ref, edge_ref,
+                       rows_ref, cols_ref, keys_ref, q_ref, *refs, scale,
+                       window, bs, cq, nq, nblk, pages, d):
+    """One (kv-head group g, step s) grid step. The steps are those of
+    every (lane, query tile) laid end to end (`_step_plan`); step s
+    belongs to tile `tile_ref[s]` and is step j = `step_ref[s]` of its
+    lane's table: `pages` table entries from `j * pages` on, each the
+    [heads, BS, 2D] slab of the group's kv-heads as the pool
+    stores it, a key's K row and V row side by side, scored as ONE tile
+    against the tile's `cq` queries of every group member of every
+    kv-head of the group: rows are (kv-head, group member, query) with
+    the query minor, columns (page, kv-head, key in page); an entry
+    counts where both name one head (`rows_ref`, `cols_ref`; with one
+    kv-head a step, every chunk's form, all do). One product, one max,
+    one exp, one product a step: what a step costs is that chain's
+    latency, so it is not run once a head.
+
+    At head_dim 128 the slab is cut at D, which is a vreg boundary. At
+    64 it is not cut (D is the middle of a vreg's 128 lanes): the
+    queries arrive zero-extended to 2D, so `q @ slab.T` is `q @ K.T`,
+    the accumulator is `p @ slab`, 2D wide, and its right half, `p @ V`,
+    is cut out by the caller; both are free at the MXU's width.
+
+    The pipeline gathered the pages through the index_map, and the
+    kernel folds the step into the VMEM accumulators, which persist
+    across a tile's steps (`edge_ref` marks its first, which resets
+    them, and its last, which writes the output). A step whose every key every row of
+    the tile attends (all of a long context but its last step) needs no
+    mask by position. In any other visited step a key's position
+    follows from where its page stands in the table, so the pages that
+    lie outside the bounds (the walk names a page inside them there)
+    fall to the masks like any other key no row attends: to the
+    window's below `lo`, to the causal one above `hi`, which also stops
+    at the table's end; the rows of K and V no row attends are zeroed
+    first, so nothing non-finite in them reaches a product.
+
+    Operands meet the MXU in the dtype the queries came in when that is
+    the pool's (bfloat16 products summed in float32 are the float32
+    products of the same values), in float32 otherwise. The
+    probabilities stay float32 into `p @ V`. Every value is 2-D (the
+    TPU compiler refuses 1-D iotas and vector loads from SMEM); the
+    running max and denominator stay `[rows, 1]`."""
     import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
 
     page_refs = refs[:pages]
     o_ref, m_ref, l_ref, acc_ref = refs[pages:]
-    b = pl.program_id(0)
-    j = pl.program_id(2)
-    start = start_ref[b]                               # SMEM scalar
-    lo, hi = attended_pages(start, c, bs, nblk, window)
+    heads, d2 = page_refs[0].shape[1], 2 * d
+    kw = _width(d)
+    step = pl.program_id(1)
+    tile, j, edge = tile_ref[step], step_ref[step], edge_ref[step]
+    start = start_ref[tile // nq] + tile % nq * cq     # SMEM scalars
+    lo, hi = attended_pages(start, cq, bs, nblk, window)
+    k0 = j * (pages * bs)                              # the step's first key
+    # the last key a row may attend: a padded chunk tail's rows sit past
+    # the table, which ends at hi
+    end = hi * bs - 1
 
-    pl.when(j == 0)(lambda: _softmax_init(m_ref, l_ref, acc_ref))
+    pl.when(edge % 2 == 1)(lambda: _softmax_init(m_ref, l_ref, acc_ref))
 
-    def slab():
-        """[pages * heads * BS, 2D] float32 of the step's pages."""
-        heads, _, d2 = page_refs[0].shape[1:]
-        tiles = [r[0].astype(jnp.float32).reshape(heads * bs, d2)
+    def fold(masked):
+        q = q_ref[0, 0]                                # [rows, kw]
+        tiles = [r[0].reshape(heads * bs, d2).astype(q.dtype)
                  for r in page_refs]
-        return tiles[0] if pages == 1 else jnp.concatenate(tiles, axis=0)
-
-    @pl.when((j * pages < hi) & ((j + 1) * pages > lo))
-    def _visit():
-        qf = q_ref[0, 0]                               # [rows, 2D]: (q, 0)
-        kv = slab()
+        keep = None if heads == 1 else \
+            cols_ref[1:2, :] == rows_ref[:, 1:2]       # [rows, cols]
+        if masked:
+            rowpos = start + rows_ref[:, 0:1]          # [rows, 1]
+            ks = k0 + cols_ref[0:1, :]                 # [1, cols]
+            near = ks <= jnp.minimum(rowpos, end)
+            if window is not None:
+                near &= ks > rowpos - window
+            keep = near if keep is None else keep & near
+            # 0 * nan == nan: the rows of K and V no query row keeps are
+            # zeroed, so scratch poison cannot reach a product. The rows
+            # sit at start .. start + cq - 1, so the keys some row keeps
+            # are exactly (start - window, start + cq - 1]
+            last = jnp.minimum(start + (cq - 1), end)
+            for i, tile in enumerate(tiles):
+                kpos = k0 + i * bs + keys_ref[...]     # [heads * bs, 1]
+                live = kpos <= last
+                if window is not None:
+                    live &= kpos > start - window
+                tiles[i] = jnp.where(live, tile, jnp.zeros((), tile.dtype))
+        kv = tiles[0] if pages == 1 else jnp.concatenate(tiles, axis=0)
         s = jax.lax.dot_general(                       # q @ k.T
-            qf, kv, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale  # [rows, cols]
-        first = j * pages * bs                         # the step's first key
-        rowpos = start + rows_ref[:, 0:1]              # [rows, 1]
-        ks = first + cols_ref[0:1, :]                  # [1, cols]
-        # a padded chunk tail's rows sit past the table: it ends at hi
-        keep = (cols_ref[1:2, :] == rows_ref[:, 1:2]) & \
-            (ks <= jnp.minimum(rowpos, hi * bs - 1))
-        if window is not None:
-            keep &= ks > rowpos - window
-        s = jnp.where(keep, s, -jnp.inf)
-        # fully-unattended keys get probability 0 but 0 * nan == nan:
-        # zero the rows no query row keeps so scratch poison cannot
-        # leak. The rows sit at start .. start+C-1, so the keys some row
-        # keeps are exactly (start - window, start + C - 1]
-        kcol = first + keys_ref[...]                   # [cols, 1]
-        attended = kcol <= jnp.minimum(start + (c - 1), hi * bs - 1)
-        if window is not None:
-            attended &= kcol > start - window
-        kv = jnp.where(attended, kv, 0.0)
-        _softmax_fold(s, kv, m_ref, l_ref, acc_ref)
+            q, kv[:, :kw], (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * scale
+        if keep is not None:
+            s = jnp.where(keep, s, -jnp.inf)
+        _softmax_fold(s, kv[:, d2 - kw:].astype(jnp.float32), m_ref, l_ref,
+                      acc_ref)
 
-    pl.when(j == pl.num_programs(2) - 1)(
-        lambda: _softmax_finish(o_ref, l_ref, acc_ref))
+    whole = k0 + pages * bs - 1 <= jnp.minimum(start, end)
+    if window is not None:
+        whole &= k0 > start + (cq - 1) - window
+    # hi == lo: the one step of a tile that attends nothing
+    pl.when((hi > lo) & whole)(lambda: fold(False))
+    pl.when((hi > lo) & jnp.logical_not(whole))(lambda: fold(True))
+
+    pl.when(edge >= 2)(
+        lambda: _softmax_finish(o_ref.at[0, 0], l_ref, acc_ref))
 
 
 def _softmax_init(m_ref, l_ref, acc_ref):
@@ -460,71 +588,75 @@ def _softmax_fold(s, kv, m_ref, l_ref, acc_ref):
 
 
 def _softmax_finish(o_ref, l_ref, acc_ref):
-    """The lane's output after its last step; a row that attended
+    """The rows' output after their last step; a row that attended
     nothing gives 0. == 0 guard (not > 0): nan denominators must
     propagate."""
     import jax.numpy as jnp
     l = l_ref[...]
-    o_ref[0, 0] = jnp.where(l == 0, 0.0, acc_ref[...] / l)
+    o_ref[...] = jnp.where(l == 0, 0.0, acc_ref[...] / l)
 
 
-@functools.lru_cache(maxsize=None)
-def _pallas_call(b, h, c, d2, hkv, bs, nblk, heads, pages, scale, window,
-                 dtype_name, interpret):
-    """Build (and cache) the pallas_call for one static shape family.
-    The block table and each lane's first query position ride as
-    scalar-prefetch operands so the pages' BlockSpec index_maps can address
-    the pool by table VALUE — the gather happens in the pipeline, page
-    by page, never as a materialised [B, Hkv, nblk*BS, D] array. A step
-    outside the lane's `attended_pages` names the nearest page inside
-    them: a block index that does not change is not fetched again, so
-    the pages nobody attends cost neither bytes nor arithmetic."""
+def _pallas_call(tiles, hkv, bs, d, nq, tile, rows, total, scale, window,
+                 nblk, interpret):
+    """Build the pallas_call for one shape family and one `total` of
+    steps (a traced scalar: the grid ends with the last tile's last
+    step). The pages' BlockSpec index_maps address the pool by table
+    VALUE, so the gather happens in the pipeline, page by page, never as
+    a materialised [B, Hkv, nblk*BS, D] array. What they read is the
+    call's `walk` (`_pallas_core`), a scalar-prefetch operand like the
+    lanes' first query positions and the steps' tiles: the page each
+    operand of each step takes, so an index_map is a multiplication and
+    a load (with 32 operands a step the scalar core runs 32 of them a
+    step). A page of a step that lies outside the tile's
+    `attended_pages` names the nearest page inside them, which the
+    neighbouring operand or step already names: a block index that does
+    not change is not fetched again."""
     import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    rows, cols = heads * (h // hkv) * c, pages * heads * bs
+    heads, pages, cq = tile
+    groups = hkv // heads
+    w = _width(d)
+    cols = pages * heads * bs
     kernel = functools.partial(_paged_attn_kernel, scale=scale,
-                               window=window, bs=bs, c=c, nblk=nblk,
-                               pages=pages)
+                               window=window, bs=bs, cq=cq, nq=nq,
+                               nblk=nblk, pages=pages, d=d)
 
     def page_spec(i):
-        def index_map(bb, gg, jj, tab, st):
-            lo, hi = attended_pages(st[bb], c, bs, nblk, window)
-            page = jnp.minimum(jnp.maximum(jj * pages + i, lo), hi - 1)
-            # a lane that attends nothing has hi == lo, anywhere from 0
-            # to past the table: stay inside it
-            return tab[bb, jnp.clip(page, 0, nblk - 1)], gg, 0, 0
-        return pl.BlockSpec((1, heads, bs, d2), index_map)
+        def index_map(gg, ss, walk, *_):
+            return walk[ss * pages + i], gg, 0, 0
+        return pl.BlockSpec((1, heads, bs, 2 * d), index_map)
 
-    def per_group(bb, gg, jj, tab, st):
-        return bb, gg, 0, 0
+    def per_tile(gg, ss, walk, st, tile_of, *_):
+        return tile_of[ss], gg, 0, 0
 
-    def whole(bb, gg, jj, tab, st):
+    def whole(gg, ss, *_):
         return 0, 0
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(b, hkv // heads, pl.cdiv(nblk, pages)),
+        num_scalar_prefetch=5,
+        grid=(groups, total),
         in_specs=[
             pl.BlockSpec((rows, 2), whole),
             pl.BlockSpec((2, cols), whole),
-            pl.BlockSpec((cols, 1), whole),
-            pl.BlockSpec((1, 1, rows, d2), per_group),
+            pl.BlockSpec((heads * bs, 1), whole),
+            pl.BlockSpec((1, 1, rows, w), per_tile),
             *[page_spec(i) for i in range(pages)],
         ],
-        out_specs=pl.BlockSpec((1, 1, rows, d2), per_group),
+        out_specs=pl.BlockSpec((1, 1, rows, w), per_tile),
         scratch_shapes=[
             pltpu.VMEM((rows, 1), jnp.float32),        # running max m
             pltpu.VMEM((rows, 1), jnp.float32),        # running denom l
-            pltpu.VMEM((rows, d2), jnp.float32),       # p @ (K, V) acc
+            pltpu.VMEM((rows, w), jnp.float32),        # p @ v acc
         ],
     )
     return pl.pallas_call(
         kernel, grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, hkv // heads, rows, d2),
+        out_shape=jax.ShapeDtypeStruct((tiles, groups, rows, w),
                                        jnp.float32),
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=_VMEM_LIMIT),
         interpret=interpret, name="paged_attention")
 
 
@@ -533,7 +665,30 @@ def _pallas_core(q, pool, tables, start, scale, window=None):
     max m, denominator l, weighted accumulator, rescaled by
     exp(m_old - m_new) a step), the block gather folded into the kernel
     pipeline. interpret=True off the TPU so tier-1 parity tests execute
-    the genuine kernel body."""
+    the genuine kernel body. The call goes through one jitted function a
+    shape family (`_tiled_core`), so a program that attends in every
+    layer traces and lowers the kernel once, not once a layer (half a
+    second each at 64 pages a step)."""
+    import jax
+    (_, h, c, d), hkv = q.shape, pool.shape[1]
+    tile = _tile(c, h // hkv, hkv, d, pool.shape[2], tables.shape[1],
+                 pool.dtype.itemsize)
+    return _tiled_core(tile, float(scale),
+                       None if window is None else int(window),
+                       jax.default_backend() != "tpu")(q, pool, tables,
+                                                       start)
+
+
+@functools.lru_cache(maxsize=None)
+def _tiled_core(tile, scale, window, interpret):
+    import jax
+    return jax.jit(functools.partial(_attend_tiled, tile=tile, scale=scale,
+                                     window=window, interpret=interpret))
+
+
+def _attend_tiled(q, pool, tables, start, *, tile, scale, window, interpret):
+    """`_pallas_core` for one `_tile`: the queries' re-layout, the
+    schedule of steps, the kernel's call, the output's re-layout."""
     import jax
     import jax.numpy as jnp
 
@@ -541,33 +696,63 @@ def _pallas_core(q, pool, tables, start, scale, window=None):
     hkv, bs = pool.shape[1], pool.shape[2]
     nblk = tables.shape[1]
     rep = h // hkv
-    heads, pages = _tile(c, rep, hkv)
-    rows = heads * rep * c
+    heads, pages, cq = tile
+    groups, nq = hkv // heads, c // cq
+    # operands in the dtype both already have, float32 where they differ
+    op = pool.dtype if q.dtype == pool.dtype else jnp.dtype(jnp.float32)
+    rows = heads * rep * cq
+    w = _width(d)
     start = jnp.broadcast_to(jnp.reshape(jnp.asarray(start, jnp.int32),
                                          (-1,)), (b,))
-    # [B, H, C, D] -> [B, Hkv/heads, heads*rep*C, 2D]: kv-head-major,
-    # then group member, query-minor rows, zeros where the slab holds V
-    qr = jnp.pad(q.astype(jnp.float32).reshape(b, hkv // heads, rows, d),
-                 ((0, 0), (0, 0), (0, 0), (0, d)))
+    # [B, H, C, D] -> [B * C/cq, Hkv/heads, heads * rep * cq, w]: a
+    # tile's rows are kv-head, then group member, then query; zeros
+    # where the uncut slab holds V
+    qr = q.astype(op).reshape(b, groups, heads * rep, nq, cq, d)
+    qr = jnp.transpose(qr, (0, 3, 1, 2, 4, 5)).reshape(
+        b * nq, groups, rows, d)
+    if w > d:
+        qr = jnp.pad(qr, ((0, 0),) * 3 + ((0, w - d),))
     row = np.arange(rows, dtype=np.int32)
     col = np.arange(pages * heads * bs, dtype=np.int32)
-    # per row: its query's offset in the chunk, its kv-head; per column:
+    # per row: its query's offset in the tile, its kv-head; per column:
     # its key's offset from the step's first position, its kv-head
-    rowinfo = np.stack([row % c, row // (rep * c)], axis=1)
-    keyoff = col // (heads * bs) * bs + col % bs
-    colinfo = np.stack([keyoff, col // bs % heads])
-    call = _pallas_call(b, h, c, 2 * d, hkv, bs, nblk, heads, pages,
-                        float(scale),
-                        None if window is None else int(window),
-                        str(pool.dtype),
-                        jax.default_backend() != "tpu")
+    rowinfo = np.stack([row % cq, row // (rep * cq)], axis=1)
+    colinfo = np.stack([col // (heads * bs) * bs + col % bs,
+                        col // bs % heads])
+    # the schedule: the tiles' steps end to end, and for every step the
+    # tile it belongs to, which step of the lane's table it is, whether
+    # it is the tile's first (1) and last (2), and the table entry each
+    # of its page operands takes (the walk)
+    first, count, _, lo, hi = _step_plan(start, c, bs, nblk, window, pages,
+                                         cq)
+    tiles = b * nq
+    ends = jnp.cumsum(count)
+    step = np.arange(tiles * -(-nblk // pages), dtype=np.int32)
+    of = jnp.minimum(jnp.sum(ends[None, :] <= step[:, None], axis=1,
+                             dtype=jnp.int32), tiles - 1)
+    base = np.arange(tiles, dtype=np.int32) // nq * nblk
+    begins, count, first, lo, hi, base = jnp.stack(
+        [ends - count, count, first, lo, hi, base], axis=1)[of].T
+    since = step - begins
+    edge = (since == 0) + 2 * (since == count - 1)
+    walk = jnp.clip((first + since)[:, None] * pages
+                    + np.arange(pages, dtype=np.int32),
+                    lo[:, None], hi[:, None] - 1)
+    walk = tables.astype(jnp.int32).reshape(-1)[
+        base[:, None] + jnp.clip(walk, 0, nblk - 1)]
+    call = _pallas_call(tiles, hkv, bs, d, nq, tile, rows, ends[-1], scale,
+                        window, nblk, interpret)
     # the scope, innermost at the call, is what names the instruction
     # in a device trace ("%paged_attention.1 = ... custom-call")
     with jax.named_scope("paged_attention"):
-        out = call(tables.astype(jnp.int32), start, jnp.asarray(rowinfo),
-                   jnp.asarray(colinfo), jnp.asarray(keyoff[:, None]), qr,
+        out = call(walk.reshape(-1), start, of, first + since,
+                   edge.astype(jnp.int32), jnp.asarray(rowinfo),
+                   jnp.asarray(colinfo), jnp.asarray(col[:heads * bs, None]
+                                                     % bs), qr,
                    *[pool] * pages)
-    return out[..., d:].reshape(b, h, c, d).astype(pool.dtype)
+    out = out[..., w - d:].reshape(b, nq, groups, heads * rep, cq, d)
+    return jnp.transpose(out, (0, 2, 3, 1, 4, 5)).reshape(
+        b, h, c, d).astype(pool.dtype)
 
 
 # ---------------------------------------------------------------------------
@@ -595,8 +780,6 @@ def _pallas_core(q, pool, tables, start, scale, window=None):
 # head) then costs 2 (nope + rope) + 2 v = 640. One query a lane (the
 # decode wave) is absorbed; a chunk of hundreds is expanded; the two
 # cross at rank (nope + v) / (2 rank - nope - v) queries: 170.
-
-_LANES = 128
 
 
 def latent_width(rank, rope):
@@ -816,7 +999,7 @@ def _latent_kernel(tables_ref, start_ref, rows_ref, q_ref, *refs, scale,
                       l_ref, acc_ref)
 
     pl.when(j == pl.num_programs(2) - 1)(
-        lambda: _softmax_finish(o_ref, l_ref, acc_ref))
+        lambda: _softmax_finish(o_ref.at[0, 0], l_ref, acc_ref))
 
 
 @functools.lru_cache(maxsize=None)

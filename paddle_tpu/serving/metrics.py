@@ -217,6 +217,10 @@ class ServingMetrics:
         # instance's decode waves, and the entries their tables held
         self._pages_visited = 0
         self._pages_spanned = 0
+        # grid steps its kernel ran in the waves and the prefill chunks,
+        # and those of them that scored pages (a layer)
+        self._steps_run = 0
+        self._steps_visited = 0
         # what a model with slot state, experts or a latent cache was
         # staged (0 for any other): slot records zeroed at admission,
         # records the waves stepped and lanes that decoded in them,
@@ -296,13 +300,17 @@ class ServingMetrics:
             for phase, s in seconds.items():
                 acc[phase] = acc.get(phase, 0.0) + s
 
-    def on_pages(self, visited, spanned):
+    def on_pages(self, visited, spanned, steps_run, steps_visited):
         """One round's decode waves: table entries inside the lanes'
         `nn.paged_attention.attended_pages`, and all of them (lanes x
-        blocks per lane)."""
+        blocks per lane); the grid steps the attention kernel ran in
+        the round's waves and chunks, and those that scored pages
+        (`nn.paged_attention.count_steps`, a layer)."""
         with self._lock:
             self._pages_visited += int(visited)
             self._pages_spanned += int(spanned)
+            self._steps_run += int(steps_run)
+            self._steps_visited += int(steps_visited)
 
     def on_model_counts(self, counts):
         """One round's `PagedServingEngine.take_model_counts()`."""
@@ -389,6 +397,7 @@ class ServingMetrics:
                     zip(self._prefix_last, self._prefix_base))
             phase_seconds = dict(self._phase_seconds)
             pages_v, pages_s = self._pages_visited, self._pages_spanned
+            steps_r, steps_v = self._steps_run, self._steps_visited
             model_counts = dict(self._model_counts)
             bias_uploads = self._bias_uploads
             spec_p, spec_a = self._spec_proposed, self._spec_accepted
@@ -445,6 +454,10 @@ class ServingMetrics:
             # walks in the decode waves (0 / 0 on a dense engine)
             "paged_pages_visited": pages_v,
             "paged_pages_spanned": pages_s,
+            # the grid steps its kernel ran in waves and chunks, and
+            # those of them that fetched and scored pages (a layer)
+            "paged_steps_run": steps_r,
+            "paged_steps_visited": steps_v,
             # slot records zeroed and (token, expert) pairs routed, for
             # a model that has either (serving/paged/engine.py)
             **model_counts,

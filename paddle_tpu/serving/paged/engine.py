@@ -169,8 +169,12 @@ class PagedServingEngine(ServingEngine):
         self._attn_window = getattr(model.cfg, "attn_window", None)
         # table entries the attention core visits in the decode waves
         # staged since the scheduler last took them (take_page_counts,
-        # once a round), and the entries those waves' tables hold
+        # once a round), and the entries those waves' tables hold; the
+        # grid steps its kernel runs, and those that fetch and score
+        # pages, in those waves and the prefill chunks, a layer
         self._pages_visited = self._pages_spanned = 0
+        self._steps_run = self._steps_visited = 0
+        self._kv_call = self._kv_call_shapes()
         # what the model's own layers were staged since the scheduler
         # last took them (take_model_counts, once a round): slot records
         # zeroed, (token, expert) pairs routed
@@ -179,6 +183,33 @@ class PagedServingEngine(ServingEngine):
         self.counts_model_work = bool(self.slot_state or self.latent_cache
                                       or self._picks_per_token)
         self._model_counts = dict.fromkeys(MODEL_COUNTS, 0)
+
+    def _kv_call_shapes(self):
+        """(query heads a kv-head, kv-heads, head size, bytes a value) of
+        the calls the K/V attention core gets from this engine's
+        programs, read off the model's sizes and the pool as it stands;
+        None where no layer attends a K/V pool (a latent cache)."""
+        if self.latent_cache:
+            return None
+        cfg = self.model.cfg
+        heads = getattr(cfg, "num_attention_heads", None) or cfg.num_heads
+        pool = next(a for a in jax.tree_util.tree_leaves(self._caches)
+                    if a.ndim == 4 and a.shape[0] == self.block_pool.num_blocks
+                    and a.shape[2] == self.block_size)
+        return (heads // pool.shape[1], pool.shape[1], pool.shape[3] // 2,
+                pool.dtype.itemsize)
+
+    def _count_steps(self, start, c):
+        """Count the kernel's grid for one staged call of `c` queries a
+        lane at `start`: the function the kernel builds its grid from."""
+        if self._kv_call is None:
+            return
+        rep, hkv, d, itemsize = self._kv_call
+        run, visited = paged_attention.count_steps(
+            start, c, rep, hkv, d, self.block_size, self.blocks_per_slot,
+            itemsize, self._attn_window)
+        self._steps_run += run
+        self._steps_visited += visited
 
     def _make_caches(self):
         extra = {"num_slots": self.num_slots} if self.slot_state else {}
@@ -392,6 +423,7 @@ class PagedServingEngine(ServingEngine):
             counts["moe_picks"] += valid * self._picks_per_token
             counts["prefill_tokens"] += valid
             counts["prefill_chunks"] += 1
+            self._count_steps(np.int32([c0]), C)
             if self.latent_cache:
                 counts["mla_rows_expanded"] += int(
                     paged_attention.expanded_rows(c0, C, bs,
@@ -653,6 +685,7 @@ class PagedServingEngine(ServingEngine):
             self.blocks_per_slot, self._attn_window)
         self._pages_visited += int(np.sum(hi - lo))
         self._pages_spanned += tables.size
+        self._count_steps(self.slot_pos, 1)
         lanes = int(np.count_nonzero(active_now))
         self._model_counts["moe_picks"] += lanes * self._picks_per_token
         if self.slot_state:
@@ -699,10 +732,13 @@ class PagedServingEngine(ServingEngine):
 
     def take_page_counts(self):
         """(visited, spanned) table entries of the decode waves staged
-        since the last call (the scheduler folds them into
-        ServingMetrics once a round)."""
-        out = self._pages_visited, self._pages_spanned
+        since the last call, and the (run, visited) grid steps of the
+        attention kernel in those waves and the prefill chunks, a layer
+        (the scheduler folds them into ServingMetrics once a round)."""
+        out = (self._pages_visited, self._pages_spanned, self._steps_run,
+               self._steps_visited)
         self._pages_visited = self._pages_spanned = 0
+        self._steps_run = self._steps_visited = 0
         return out
 
     def take_model_counts(self):

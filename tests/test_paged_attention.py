@@ -257,20 +257,28 @@ def test_attended_pages_match_a_brute_force_count(c, window):
 
 
 # window 3 is shorter than a page: its `lo` falls inside a step of
-# several pages
+# several pages. Tiles: kv-heads in the grid or sharing a step; one page
+# a step, three (the table's 11 do not divide), 32 (more than the table
+# holds: one step, the rest of it past the table's end); the chunk's six
+# queries whole, or in query tiles of three and of two, each with its
+# own bounds
 @pytest.mark.parametrize("window", [None, 6, 3])
-@pytest.mark.parametrize("heads,pages", [(1, 1), (2, 1), (1, 3), (2, 3),
-                                         (2, 16)])
+@pytest.mark.parametrize("heads,pages,cq", [
+    (1, 1, 0), (2, 1, 0), (1, 3, 0), (2, 3, 0), (2, 32, 0), (1, 3, 3),
+    (2, 2, 2)])
 @pytest.mark.parametrize("form", ["decode", "chunk"])
 def test_every_tile_of_the_pallas_core_gives_the_reference(monkeypatch, form,
-                                                           heads, pages,
+                                                           heads, pages, cq,
                                                            window):
     """One recurrence, whatever tile the shapes choose: kv-heads in the
     grid or folded into a step, one page a step or several, a last step
-    that reaches past the table."""
+    that reaches past the table, a chunk's queries in one tile or in
+    several."""
     import jax.numpy as jnp
-    monkeypatch.setattr(pa, "_tile", lambda c, rep, hkv: (heads, pages))
-    q, pk, pv, tables, start = _ragged(9, 1 if form == "decode" else 5, 2)
+    c = 1 if form == "decode" else 6
+    monkeypatch.setattr(pa, "_tile",
+                        lambda *_: (heads, pages, min(cq or c, c)))
+    q, pk, pv, tables, start = _ragged(9, c, 2)
     args = (q, _fuse(pk, pv), jnp.asarray(tables),
             jnp.asarray(start), 0.35)
     ref = pa.attend(*args, window=window, kernel="reference")
@@ -279,18 +287,77 @@ def test_every_tile_of_the_pallas_core_gives_the_reference(monkeypatch, form,
                                rtol=1e-5, atol=1e-5)
 
 
-def test_tile_follows_the_shapes_of_the_call():
-    # the two cells' decode waves: every kv-head in one step
-    assert pa._tile(1, 1, 12)[0] == 12 and pa._tile(1, 4, 8)[0] == 8
-    # their 128-token chunks: as many heads as keep the tile's rows
-    # within the cap, one page a step
-    assert pa._tile(128, 1, 12) == (6, 1)
-    assert pa._tile(128, 4, 8) == (2, 1)
-    # rows past the cap with one head: heads stay in the grid
-    assert pa._tile(512, 4, 8) == (1, 1)
-    for c, rep, hkv in [(1, 1, 12), (1, 4, 8), (5, 1, 12), (128, 4, 8)]:
-        heads, pages = pa._tile(c, rep, hkv)
-        assert hkv % heads == 0 and 1 <= pages <= pa._MAX_PAGES
+@pytest.mark.parametrize("window", [None, 24])
+@pytest.mark.parametrize("form", ["decode", "chunk"])
+def test_bfloat16_operands_give_the_float32_products(form, window):
+    """Queries and pool both bfloat16: the kernel hands the MXU those
+    values as they are (their products summed in float32) and keeps the
+    probabilities float32, so what comes out is the float32 arithmetic
+    on the same values (the reference's, up to the order of the sums)."""
+    import jax.numpy as jnp
+    q, pk, pv, tables, start = _ragged(11, 1 if form == "decode" else 8, 2)
+    pool = _fuse(pk, pv).astype(jnp.bfloat16)
+    q = q.astype(jnp.bfloat16)
+    args = (jnp.asarray(tables), jnp.asarray(start), 0.35)
+    out = pa.attend(q, pool, *args, window=window, kernel="pallas")
+    ref = pa.attend(q.astype(jnp.float32), pool.astype(jnp.float32), *args,
+                    window=window, kernel="reference")
+    assert out.dtype == jnp.bfloat16
+    # one rounding of the output to bfloat16 (2^-9 of values up to ~3)
+    np.testing.assert_allclose(np.asarray(out, np.float32), np.asarray(ref),
+                               rtol=0, atol=2e-2)
+
+
+#: the four K/V configurations the benchmark serves: query heads a
+#: kv-head, kv-heads, head size, pages a lane's table holds, chunk
+SERVED = {"gpt2-small": (1, 12, 64, 64, 128),
+          "mistral-7b": (4, 8, 128, 160, 128),
+          "nemotron3-nano": (16, 2, 128, 128, 128),
+          "granite-4.0-h-micro": (4, 8, 64, 1088, 512)}
+
+
+@pytest.mark.parametrize("form", ["wave", "chunk", "verify-k4"])
+@pytest.mark.parametrize("config", sorted(SERVED))
+def test_tile_follows_the_shapes_of_the_call(config, form):
+    """The rule by the keys' side, at the served configurations' wave,
+    chunk and verify-wave shapes (block 16, a bfloat16 pool)."""
+    rep, hkv, d, nblk, chunk = SERVED[config]
+    c = {"wave": 1, "chunk": chunk, "verify-k4": 5}[form]
+    bs, itemsize = 16, 2
+    heads, pages, cq = pa._tile(c, rep, hkv, d, bs, nblk, itemsize)
+    assert hkv % heads == 0 and c % cq == 0 and 1 <= pages <= nblk
+    # what the call asks the compiler for covers what a step plans
+    assert pa._step_vmem(heads, pages, heads * rep * cq, d, bs,
+                         itemsize) <= pa._VMEM_PLAN < pa._VMEM_LIMIT
+    if form == "chunk":
+        # one kv-head's queries against at least a whole vreg of keys,
+        # hundreds where VMEM holds them beside the query tile
+        assert heads == 1 and pages * bs >= 256
+        assert rep * cq <= pa._MAX_ROWS
+    else:
+        # every kv-head shares a step, and a step carries what the
+        # chip's table says: a quarter of the table's pages as a power
+        # of two, 8 to 64 of them
+        assert heads == hkv and cq == c
+        quarter = {64: 16, 160: 64, 128: 32, 1088: 64}[nblk]
+        assert pages == quarter or (
+            pages == quarter // 2 and pa._step_vmem(
+                heads, quarter, heads * rep * cq, d, bs,
+                itemsize) > pa._VMEM_PLAN)
+        assert form == "verify-k4" or pages == quarter
+        assert pages * hkv * bs * 2 * d * itemsize >= 3 << 17
+
+
+@pytest.mark.parametrize("c", [1, 128])
+def test_a_step_never_takes_more_pages_than_the_table_holds(c):
+    """A table of 4 pages is one step of 4, in both forms (of 11, one
+    of 8 and a rest), and a chunk too long for one query tile is cut
+    where the tiles divide it."""
+    assert pa._tile(c, 4, 8, 128, 16, 4, 2)[1] == 4
+    assert pa._tile(c, 4, 8, 128, 16, 11, 2)[1] == 8
+    heads, pages, cq = pa._tile(4096, 4, 8, 64, 16, 1088, 2)
+    assert heads == 1 and 4096 % cq == 0 and 4 * cq <= pa._MAX_ROWS
+    assert pages * 16 >= 128
 
 
 # ---------------------------------------------------------------------------
@@ -408,6 +475,57 @@ def test_engine_counts_the_pages_its_waves_visit(window):
                for wave in waves for pos in wave)
     assert snap["paged_pages_visited"] == want
     assert 0 < want < snap["paged_pages_spanned"]
+
+
+@pytest.mark.parametrize("window", [None, 12])
+def test_engine_counts_the_steps_its_kernel_runs(window):
+    """`paged_steps_run` / `paged_steps_visited` are, summed over every
+    staged wave (all lanes at their `slot_pos`) and every staged prefill
+    chunk (one lane at the chunk's start), the grid the kernel's call
+    runs a layer (every query tile's steps from its first attended page
+    to its last; one step for a tile that attends nothing) and the steps
+    of it that score pages: here counted one by one against each query
+    tile's `attended_pages`."""
+    pt.seed(7)
+    cfg = LlamaConfig(vocab_size=VOCAB, hidden_size=64, num_layers=1,
+                      num_heads=4, num_kv_heads=2, max_seq_len=MAX_LEN,
+                      attn_window=window)
+    eng = _engine(LlamaForCausalLM(cfg), None)
+    calls, wave, prompt = [], eng._wave_args, eng._prompt_args
+
+    def spy_wave(*args):
+        calls.append((np.array(eng.slot_pos, np.int32), 1))
+        return wave(*args)
+
+    def spy_prompt(slot, chunk, c0, *rest):
+        calls.append((np.asarray([c0], np.int32), CHUNK))
+        return prompt(slot, chunk, c0, *rest)
+
+    eng._wave_args, eng._prompt_args = spy_wave, spy_prompt
+    sched = Scheduler(eng)
+    rng = np.random.RandomState(3)
+    for n in (5, 23, 40, 17, 33, 9):
+        sched.submit(prompt=rng.randint(0, VOCAB, (n,)).tolist(),
+                     max_tokens=int(rng.randint(2, 10)))
+    sched.run()
+    snap = sched.metrics.snapshot()
+    nblk, hkv, rep, d = MAX_LEN // BLOCK, 2, 2, 16
+    assert {c for _, c in calls} == {1, CHUNK}
+    assert sum(c == CHUNK for _, c in calls) > 6     # chunks past the first
+    run = visited = 0
+    for start, c in calls:
+        heads, pages, cq = pa._tile(c, rep, hkv, d, BLOCK, nblk, 4)
+        for st in start:
+            for t in range(c // cq):
+                lo, hi = pa.attended_pages(int(st) + t * cq, cq, BLOCK,
+                                           nblk, window)
+                steps = sum(j * pages < hi and (j + 1) * pages > lo
+                            for j in range(-(-nblk // pages)))
+                run += hkv // heads * max(int(steps), 1)
+                visited += hkv // heads * int(steps)
+    assert snap["paged_steps_run"] == run
+    assert snap["paged_steps_visited"] == visited
+    assert 0 < visited <= run
 
 
 @pytest.mark.parametrize("kernel", FUSED)

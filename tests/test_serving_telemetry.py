@@ -119,6 +119,8 @@ def test_snapshot_keys_byte_compatible(engine):
         "spec_acceptance_rate", "spec_accepted_per_wave",
         # the paged core's page counters (0 / 0 on a dense engine)
         "paged_pages_visited", "paged_pages_spanned",
+        # the grid steps its kernel ran, and those that scored pages
+        "paged_steps_run", "paged_steps_visited",
         # what a model with slot state, experts or a latent cache was
         # staged (0 for any other)
         "state_resets", "moe_picks", "mla_rows_attended",
@@ -140,6 +142,7 @@ def test_snapshot_keys_byte_compatible(engine):
     assert snap["prefix_hits"] == 0 and snap["prefix_hit_rate"] is None
     assert snap["prefix_evictions"] == 0
     assert snap["paged_pages_visited"] == snap["paged_pages_spanned"] == 0
+    assert snap["paged_steps_run"] == snap["paged_steps_visited"] == 0
     assert snap["requests_completed"] == 1
     assert snap["ttft_p50_s"] is not None
     assert snap["ttft_p50_s"] <= snap["latency_p50_s"]

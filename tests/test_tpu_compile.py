@@ -164,12 +164,22 @@ def test_paged_core_server_resolves_on_tpu_compiles_for_v5e(
     ("nemotron-reason-chunk128", 1, 32, 2, 128, 128, 128, None),
     ("mistral-wave-window1024", 64, 32, 8, 1, 128, 160, 1024),
     ("gpt2s-verify-k4", 128, 12, 12, 5, 64, 64, None),
+    # granite4hm-serve-longdoc: 32 lanes, max_len 17408, GQA 32/8 x 64;
+    # its chunk is 512 queries x 4 a kv-head over 1,088 pages
+    ("granite-longdoc-wave", 32, 32, 8, 1, 64, 1088, None),
+    ("granite-longdoc-chunk512", 1, 32, 8, 512, 64, 1088, None),
+    # gpt2s-serve-batch at its 256 lanes
+    ("gpt2s-batch-wave-256", 256, 12, 12, 1, 64, 64, None),
+    # a table shorter than the pages a step would take
+    ("short-table-wave", 8, 32, 8, 1, 128, 8, None),
+    ("short-table-chunk", 1, 32, 8, 128, 128, 8, None),
 ])
 def test_paged_core_compiles_at_the_cells_shapes_for_v5e(
         one_chip, as_on_tpu, name, b, h, hkv, c, d, nblk, window):
-    """The two serving cells' real shapes: one kernel, named
+    """The serving cells' real shapes: one kernel, named
     `paged_attention`, per attention call, whatever tile the shapes
-    choose."""
+    choose (`_tile`: every kv-head and up to 32 pages a step in a wave,
+    one kv-head's whole chunk against up to 32 pages in a chunk)."""
     def fn(q, pool, tables, pos):
         return pa.attend(q, pool, tables, pos, d ** -0.5, window=window,
                          kernel="pallas")
@@ -212,6 +222,8 @@ def _pool_stays(compiled, pools):
     ("mistral-chat-chunk128", 10241, 64, 32, 8, 128, 160, 128, 4096),
     ("nemotron-reason-wave", 16385, 128, 32, 2, 128, 128, 1, None),
     ("nemotron-reason-chunk128", 16385, 128, 32, 2, 128, 128, 128, None),
+    ("granite-longdoc-wave", 34817, 32, 32, 8, 64, 1088, 1, None),
+    ("granite-longdoc-chunk512", 34817, 32, 32, 8, 64, 1088, 512, None),
 ])
 def test_pool_passes_through_write_and_attention_in_place_for_v5e(
         one_chip, as_on_tpu, name, blocks, lanes, h, hkv, d, nblk, c,
@@ -320,7 +332,9 @@ def test_engine_programs_keep_the_pool_in_place_for_v5e(one_chip, as_on_tpu,
     from paddle_tpu.serving import PagedServingEngine
 
     pt.seed(0)
-    slots, chunk, max_len, blocks = 16, 128, 512, None
+    # 2,049 pages: a pool of 17 MB and more, which the compiler cannot
+    # decide to hold in VMEM whole, as it cannot hold any cell's
+    slots, chunk, max_len, blocks = 16, 128, 512, 2049
     if family == "deepseek-mla":
         # kanana2-serve-doc's programs: its widths, heads, lanes, table
         # and chunk; two layers (one dense, one of 8 experts), a small
