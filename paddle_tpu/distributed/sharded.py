@@ -142,6 +142,7 @@ class ShardedTrainStep(InstrumentedStepMixin):
         loss = step(batch_inputs, batch_labels)   # global batch arrays
     """
 
+    @telemetry.startup_span("step_build")
     def __init__(self, model, loss_fn, optimizer, mesh=None, dp_axis=None,
                  zero_stage=0, donate=True, remat=False, shard_seq=True,
                  return_outputs=False, exact_reshard=False):
@@ -179,6 +180,18 @@ class ShardedTrainStep(InstrumentedStepMixin):
             self.param_specs[n] = _valid_spec(hint, self.mesh, arr.shape)
         self.buffer_specs = {n: P() for n in buffers}
 
+        def shard(x, spec):
+            # jnp.copy BEFORE the placement: a restored/set_value'd leaf
+            # can be a ZERO-COPY view of host numpy memory (jax 0.4.37's
+            # CPU client aliases aligned numpy buffers), and the
+            # compiled step DONATES these — XLA freeing memory numpy
+            # owns corrupts the heap ("double free"/"corrupted
+            # double-linked list" on the first post-restore step). The
+            # copy materializes an XLA-owned buffer first, exactly what
+            # jit.TrainStep.__init__ does for the same reason;
+            # construction-time-only cost.
+            return jax.device_put(jnp.copy(x), NamedSharding(self.mesh, spec))
+
         # ---- optimizer state shardings (follow param; + dp for ZeRO>=1)
         # ZeRO stages under GSPMD (ref fleet sharding_optimizer.py stages;
         # PAPERS.md arXiv:2004.13336):
@@ -202,40 +215,31 @@ class ShardedTrainStep(InstrumentedStepMixin):
         # path). Without it a rebuilt sharded step would zero the
         # moments on every resume, exactly the TrainStep bug PR 10
         # fixed on the single-chip path.
-        opt_state = optimizer.init_opt_state(
-            params, parameters=named_params)
-        self.opt_specs = {}
-        for n, slots in opt_state.items():
-            base = self.param_specs[n]
-            spec = base
-            if zero_stage >= 1:
-                spec = _zero_spec(params[n].shape, self.mesh, self.dp_axis,
-                                  base)
-            self.opt_specs[n] = {sn: spec for sn in slots}
+        # the moments made, then placed. Neither span waits for its
+        # arrays: the first step's trace runs while they travel
+        with telemetry.startup_span("opt_state", zero_stage=zero_stage):
+            opt_state = optimizer.init_opt_state(
+                params, parameters=named_params)
+            self.opt_specs = {}
+            for n, slots in opt_state.items():
+                base = self.param_specs[n]
+                spec = base
+                if zero_stage >= 1:
+                    spec = _zero_spec(params[n].shape, self.mesh,
+                                      self.dp_axis, base)
+                self.opt_specs[n] = {sn: spec for sn in slots}
+            self.opt_state = jax.tree_util.tree_map_with_path(
+                lambda kp, a: shard(a, self.opt_specs[kp[0].key][kp[1].key]),
+                opt_state)
         if zero_stage >= 3:
             for n, arr in params.items():
                 self.param_specs[n] = _zero_spec(arr.shape, self.mesh,
                                                  self.dp_axis,
                                                  self.param_specs[n])
-
-        def shard(x, spec):
-            # jnp.copy BEFORE the placement: a restored/set_value'd leaf
-            # can be a ZERO-COPY view of host numpy memory (jax 0.4.37's
-            # CPU client aliases aligned numpy buffers), and the
-            # compiled step DONATES these — XLA freeing memory numpy
-            # owns corrupts the heap ("double free"/"corrupted
-            # double-linked list" on the first post-restore step). The
-            # copy materializes an XLA-owned buffer first, exactly what
-            # jit.TrainStep.__init__ does for the same reason;
-            # construction-time-only cost.
-            return jax.device_put(jnp.copy(x), NamedSharding(self.mesh, spec))
-
-        self.params = {n: shard(a, self.param_specs[n])
-                       for n, a in params.items()}
-        self.buffers = {n: shard(a, P()) for n, a in buffers.items()}
-        self.opt_state = jax.tree_util.tree_map_with_path(
-            lambda kp, a: shard(a, self.opt_specs[kp[0].key][kp[1].key]),
-            opt_state)
+        with telemetry.startup_span("shard"):
+            self.params = {n: shard(a, self.param_specs[n])
+                           for n, a in params.items()}
+            self.buffers = {n: shard(a, P()) for n, a in buffers.items()}
         self._step_i = optimizer._global_step
         apply_fn = optimizer.apply_gradients_fn()
         dp_axis_name = self.dp_axis
@@ -473,12 +477,9 @@ class ShardedTrainStep(InstrumentedStepMixin):
                         self._shard_batch(labels))
             with RecordEvent("train/dispatch", step=self._step_i), \
                     self.mesh:
-                if self._recorder is not None:
-                    loss, outs = self._instrumented_call(args)
-                else:
-                    (loss, self.params, self.buffers, self.opt_state,
-                     self.grad_acc, outs, self._last_grad_norm,
-                     self._last_nonfinite) = self._compiled(*args)
+                loss, outs = (self._instrumented_call(args)
+                              if self._recorder is not None
+                              else self._dispatch(args))
         if self.return_outputs:
             return Tensor(loss), _wrap(outs)
         return Tensor(loss)

@@ -19,6 +19,8 @@ import warnings
 
 import numpy as np
 
+from ..utils import telemetry
+
 
 def _inert(knob, why):
     warnings.warn(
@@ -385,6 +387,7 @@ class LLMPredictor:
         from ..serving.fleet import DisaggFleetRouter, FleetRouter
         opts = config._llm_opts or {}
         self._eos_token_id = opts.get("eos_token_id")
+        telemetry.install_compile_tracking()
         factory = _engine_factory(config, opts, model, draft_model)
         self.router = None
         fleet_opts = config._fleet_opts
@@ -486,6 +489,17 @@ def _engine_factory(config, opts, model, draft_model):
         draft_model = type(model)(draft_cfg)
 
     def factory():
+        """The engine, as a `startup/engine` span (one a replica)."""
+        with telemetry.startup_span(
+                "engine", num_slots=opts.get("num_slots", 4)) as span:
+            engine = build()
+            pool = getattr(engine, "block_pool", None)
+            span.ids.update(
+                blocks=pool.num_blocks if pool is not None else 0,
+                pool_bytes=engine.pool_bytes)
+        return engine
+
+    def build():
         if opts.get("speculative"):
             return SpeculativePagedEngine(
                 model, draft_model,

@@ -19,7 +19,7 @@ import jax.numpy as jnp
 from ..framework import state
 from ..framework.tensor import Tensor
 from ..nn.layer import Layer
-from ..utils import chaos
+from ..utils import chaos, telemetry
 from ..utils.profiler import RecordEvent
 
 
@@ -283,6 +283,11 @@ class InstrumentedStepMixin:
     and `_step_i`. `_init_instrumentation()` must run in `__init__`."""
 
     def _init_instrumentation(self, label="train_step"):
+        # the process's first program may be this step: its trace, lower
+        # and compile (or load) are heard, under this label, with no
+        # recorder attached; the collector's pauses are `train/gc` spans
+        telemetry.install_compile_tracking()
+        telemetry.install_gc_tracking("train")
         self._recorder = None
         self._label = label
         self._fail_fast = False
@@ -306,17 +311,13 @@ class InstrumentedStepMixin:
         `utils.resume.TrainWatchdog`) is fed one `beat()` per completed
         step, so a step that never completes becomes a journaled `hang`
         event instead of a silent stall."""
-        from ..utils import telemetry, flight_recorder as fr
+        from ..utils import flight_recorder as fr
         self._recorder = recorder
         if label is not None:
             self._label = label
         self._watchdog = watchdog
         self._fail_fast = recorder.fail_fast if fail_fast is None \
             else bool(fail_fast)
-        # False on jax builds without jax.monitoring: compile detection
-        # then falls back to _cache_size() deltas (same fallback
-        # telemetry._InstrumentedJit uses)
-        self._monitoring = telemetry.install_compile_tracking()
         # constant per process; None off the peaks table (MFU then
         # reads "not measured")
         peaks = fr.device_peaks()
@@ -364,12 +365,6 @@ class InstrumentedStepMixin:
         return None if self._last_grad_norm is None \
             else float(self._last_grad_norm)
 
-    def _safe_cache_size(self):
-        try:
-            return self._compiled._cache_size()
-        except Exception:
-            return 0
-
     def _signature(self, args):
         # dtype via attribute, NOT jnp.asarray: these are the raw batch
         # leaves and asarray would device-transfer numpy batches once
@@ -379,9 +374,18 @@ class InstrumentedStepMixin:
             (jnp.shape(a), str(getattr(a, "dtype", type(a).__name__)))
             for a in leaves)
 
+    def _dispatch(self, args):
+        """The compiled step, its state rebound from what it returns; what
+        it traces, lowers, compiles or loads carries the step's label."""
+        with telemetry.track_compiles(self._label):
+            (loss, self.params, self.buffers, self.opt_state, self.grad_acc,
+             outs, self._last_grad_norm, self._last_nonfinite) = \
+                self._compiled(*args)
+        return loss, outs
+
     def _instrumented_call(self, args):
         import time as _time
-        from ..utils import telemetry, flight_recorder as fr
+        from ..utils import flight_recorder as fr
         rec = self._recorder
         sig = self._signature(args)
         if sig not in self._cost_cache:
@@ -389,25 +393,14 @@ class InstrumentedStepMixin:
             # lowering-level HLO cost analysis, no second backend compile
             self._cost_cache[sig] = fr.cost_analysis(self._compiled, *args)
         cost = self._cost_cache[sig] or {}
-        before = telemetry.compile_count(self._label) if self._monitoring \
-            else self._safe_cache_size()
+        before = telemetry.compile_count(self._label)
         t0 = _time.perf_counter()
-        with telemetry.track_compiles(self._label):
-            (loss, self.params, self.buffers, self.opt_state, self.grad_acc,
-             outs, self._last_grad_norm, self._last_nonfinite) = \
-                self._compiled(*args)
+        loss, outs = self._dispatch(args)
         t1 = _time.perf_counter()
         loss.block_until_ready()
         t2 = _time.perf_counter()
         host_s, device_s = t1 - t0, t2 - t1
-        if self._monitoring:
-            compiled = telemetry.compile_count(self._label) - before
-        else:
-            compiled = max(0, self._safe_cache_size() - before)
-            if compiled:
-                telemetry.counter(
-                    "xla_compiles_total", labelnames=("function",)
-                ).labels(self._label).inc(compiled)
+        compiled = telemetry.compile_count(self._label) - before
         flops = cost.get("flops")
         if compiled:
             rec.compile_event(self._label, count=compiled, compile_s=host_s,
@@ -458,6 +451,7 @@ class TrainStep(InstrumentedStepMixin):
         step.sync()                # write state back into model/opt
     """
 
+    @telemetry.startup_span("step_build")
     def __init__(self, model, loss_fn, optimizer, donate=True,
                  return_outputs=False):
         from . import transforms as tfm
@@ -553,12 +547,9 @@ class TrainStep(InstrumentedStepMixin):
                         lr, jnp.asarray(self._step_i, jnp.int32),
                         _unwrap(tuple(inputs)), _unwrap(tuple(labels)))
             with RecordEvent("train/dispatch", step=self._step_i):
-                if self._recorder is not None:
-                    loss, outs = self._instrumented_call(args)
-                else:
-                    (loss, self.params, self.buffers, self.opt_state,
-                     self.grad_acc, outs, self._last_grad_norm,
-                     self._last_nonfinite) = self._compiled(*args)
+                loss, outs = (self._instrumented_call(args)
+                              if self._recorder is not None
+                              else self._dispatch(args))
         if self.return_outputs:
             return Tensor(loss), _wrap(outs)
         return Tensor(loss)

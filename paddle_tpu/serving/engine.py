@@ -285,7 +285,16 @@ class ServingEngine:
         self._params, self._buffers = model.functional_state()
         self.cache_dtype = (cache_dtype if cache_dtype is not None
                             else _infer_cache_dtype(self._params))
-        self._caches = self._make_caches()
+        # the pool (or the dense cache) and a model's slot state, waited
+        # for: what the engine reserves on the device, and how long
+        # reserving it takes
+        pool = getattr(self, "block_pool", None)
+        with telemetry.startup_span(
+                "pool", num_slots=self.num_slots,
+                blocks=pool.num_blocks if pool is not None else 0) as span:
+            self._caches = jax.block_until_ready(self._make_caches())
+            self.pool_bytes = span.ids["pool_bytes"] = sum(
+                a.nbytes for a in jax.tree_util.tree_leaves(self._caches))
         # the one PRNG chain every sampled request on this engine draws
         # from — recorded (blackbox `run_start` harness / per-request
         # seed provenance) so a fresh engine built with the same seed

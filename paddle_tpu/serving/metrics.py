@@ -463,4 +463,7 @@ class ServingMetrics:
             **model_counts,
             # bias rows and matrices uploaded (serving/engine.py)
             "bias_uploads": bias_uploads,
+            # the interpreter's collector: seconds it held the process and
+            # its collections (process totals; a reader takes the delta)
+            **telemetry.gc_totals(),
         }

@@ -89,6 +89,9 @@ class Scheduler:
                 "handoff surface (export_slot_kv / import_handoff — "
                 "serving/paged)")
         self.role = role
+        # the collector's pauses, wherever in a round they fall, are
+        # `serving/gc` spans and the process's gc_* counters
+        telemetry.install_gc_tracking("serving")
         # optional multi-tenant QoS manager (serving/fleet/qos.py),
         # duck-typed: under_pressure(pool) gates weighted-fair admission
         # (pick_admission over the queue) — with qos=None the queue is

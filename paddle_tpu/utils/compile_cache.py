@@ -13,7 +13,12 @@ _CHECKOUT = os.path.dirname(os.path.dirname(os.path.dirname(
 
 
 def enable():
-    """Turn the persistent cache on; returns the directory in use."""
+    """Turn the persistent cache on; returns the directory in use. The
+    compile listener goes in with it (`telemetry.process_summary` then
+    says what was compiled and what the cache held), so that a process
+    hears its first program."""
+    from . import telemetry
+    telemetry.install_compile_tracking()
     env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if env:
         return env

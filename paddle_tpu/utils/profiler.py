@@ -16,7 +16,9 @@ import time
 
 from jax.profiler import StepTraceAnnotation, TraceAnnotation
 
-_lock = threading.Lock()
+# re-entrant: the collector's `<family>/gc` span (telemetry._on_gc) exits
+# from whatever bytecode its thread was at, which may hold this lock
+_lock = threading.RLock()
 _enabled = False
 _events = []          # (name, start_s, dur_s, thread_id, pid, ids)
 _raw_events = []      # chrome-format dicts (async spans, flow, counters)
@@ -334,9 +336,3 @@ class Profiler:
     def __exit__(self, *exc):
         self.stop()
         return False
-
-
-def export_protobuf(path):
-    """XPlane protobufs are written by jax.profiler into trace_dir; this
-    helper names the convention for API parity (ref profiler export)."""
-    return path

@@ -15,14 +15,18 @@ tools/timeline.py, rebuilt as one subsystem:
     `/healthz`, `/metrics/history` (the utils/timeseries ring-buffer
     history, `snapshot_history()`) and `/dashboard` (self-contained
     sparkline page);
-  * XLA compile-event tracking: a `jax.monitoring` duration-listener
-    counts backend compilations (persistent-cache loads included — a new
-    executable entered this process either way) attributed to the
+  * XLA compile-event tracking: a `jax.monitoring` listener counts
+    backend compilations (persistent-cache loads included — a new
+    executable entered this process either way) and the seconds of
+    every stage (trace, lower, compile, cache load), attributed to the
     function label on the `track_compiles` thread-local stack, so the
-    serving engine's compile-once invariant is a live metric.  On jax
-    builds without `jax.monitoring`, `instrument_jit` falls back to
-    counting `_cache_size()` growth around each call (the wrap-jit
-    fallback for old containers);
+    serving engine's compile-once invariant is a live metric;
+  * the process journal: a bounded, always-on record of what the
+    process did outside its steady state — the compile stages by
+    program, the `startup/<phase>` spans of the constructors that do
+    start-up work, the collector's pauses — on `time.perf_counter()`,
+    read by `process_events` / `process_summary` and served under
+    `process` in `/metrics.json`;
   * `trace_request`: chrome-trace async spans + flow events for the
     serving Request lifecycle (QUEUED → PREFILL → DECODE → DONE) emitted
     into `utils.profiler`'s event sink, so one exported trace shows host
@@ -33,7 +37,8 @@ docs/observability.md; the `metric-name` rule of scripts/ptlint.py
 lints call sites against that catalog.
 """
 import bisect
-import contextlib
+import functools
+import gc
 import http.server
 import io
 import json
@@ -209,7 +214,10 @@ class _Metric:
         self.name = name
         self.help = help
         self.labelnames = tuple(_check_name(n) for n in labelnames)
-        self._lock = threading.Lock()
+        # re-entrant: the collector's hook (`_on_gc`) counts into its
+        # metrics from whatever bytecode the thread was at, which may
+        # be a reader of those metrics holding this lock
+        self._lock = threading.RLock()
         self._children = {}
 
     def _normalize(self, values, kv):
@@ -547,11 +555,186 @@ def value(name, labels=None, default=None):
 
 
 # ---------------------------------------------------------------------------
+# the process journal
+# ---------------------------------------------------------------------------
+
+#: what an entry can be: a compile stage of a program (`trace`, `lower`,
+#: `compile`, `cache_load`), a `startup/<phase>` span, a pause of the
+#: collector (`gc`), or one of the two counts (seconds 0)
+PROCESS_KINDS = ("trace", "lower", "compile", "cache_load", "startup", "gc",
+                 "cache_hit", "cache_miss")
+JOURNAL_MAX = 4096
+_STAGES = PROCESS_KINDS[:4]
+
+_tl = threading.local()               # label stack, pending cache events,
+                                      # the thread's last `startup` row
+_journal_lock = threading.RLock()     # re-entrant: `_on_gc` may interrupt
+_journal = []                         # oldest first; rows end with a thread id
+_journal_state = {"dropped": 0}
+
+_STARTUP_SECONDS = counter(
+    "startup_seconds_total",
+    "Self seconds of the process's start-up phases (startup/<phase> spans)",
+    labelnames=("phase",))
+
+
+def _inside(start):
+    # index of the first row that ended after `start`; caller holds the lock
+    i = len(_journal)
+    while i and _journal[i - 1][0] > start:
+        i -= 1
+    return i
+
+
+def record_process_event(kind, label, seconds=0.0, t_end=None):
+    """Add `(t_end, kind, label, seconds)` to the process journal and
+    return the entry's own seconds (what a registry counter its children
+    counted into before it gets). `t_end` is on `time.perf_counter()` (now if
+    not given). For an entry that spans time, `seconds` is what the caller
+    measured from its start to `t_end`; what is kept is its SELF time:
+    less the seconds of every entry this thread journaled inside that
+    interval (a child span, a compile stage, a pause of the collector).
+    So the journal's seconds add up to wall time at most, and a sum by
+    kind counts nothing twice. Two rules keep a model's start-up from
+    filling the journal. A compile stage takes in the stages that ended
+    inside it (the helpers a program's trace traces on its way, the
+    kernels its lowering traces): they leave the journal and their
+    seconds stay the program's (its entry holds them, the value returned
+    does not). And a `startup` entry whose thread's last
+    `startup` entry has the same label is added to that one (a
+    constructor's parameters, one initialiser after another, are one
+    entry; the replicas of a fleet are not, a `pool` lies between their
+    `engine`s)."""
+    t_end = time.perf_counter() if t_end is None else t_end
+    label, tid = str(label), threading.get_ident()
+    with _journal_lock:
+        own = seconds
+        if seconds > 0.0:
+            i = _inside(t_end - seconds)
+            inside = [row for row in _journal[i:] if row[4] == tid]
+            own = max(0.0, seconds - sum(row[3] for row in inside))
+            if kind in _STAGES and inside:
+                inside = [row for row in inside if row[1] not in _STAGES]
+                _journal[i:] = [row for row in _journal[i:] if row[4] != tid
+                                or row[1] not in _STAGES]
+                seconds = max(0.0, seconds - sum(row[3] for row in inside))
+            else:
+                seconds = own
+        if kind == "startup":
+            last = getattr(_tl, "startup_row", None)
+            if last is not None and last[2] == label:
+                last[0], last[3] = t_end, last[3] + seconds
+                return own
+        row = [t_end, kind, label, seconds, tid]
+        if kind == "startup":
+            _tl.startup_row = row
+        _journal.append(row)
+        if len(_journal) > JOURNAL_MAX and not _compact_tail(row):
+            del _journal[0]
+            _journal_state["dropped"] += 1
+    return own
+
+
+def _compact_tail(row):
+    """Room in a full journal without dropping its oldest entry: a large
+    program's trace journals thousands of stages under its label before
+    the enclosing one arrives and takes them in, so this thread's run of
+    such rows at the tail is summed into `row` (the enclosing stage's
+    seconds cover them all the same). True if that made room."""
+    kind, label, tid = row[1], row[2], row[4]
+    if kind not in _STAGES:
+        return False
+    with _journal_lock:
+        i = len(_journal) - 1
+        while i and (_journal[i - 1][4] != tid or (
+                _journal[i - 1][1] in _STAGES
+                and _journal[i - 1][2] == label)):
+            i -= 1
+        run = [r for r in _journal[i:-1] if r[4] == tid]
+        if not run:
+            return False
+        row[3] += sum(r[3] for r in run)
+        _journal[i:] = [r for r in _journal[i:-1] if r[4] != tid] + [row]
+    return True
+
+
+def process_events(since=None, until=None):
+    """The journal's entries `(t_end, kind, label, seconds)` with
+    `since <= t_end <= until` (either may be None), oldest first.
+    `seconds` is self time (`record_process_event`)."""
+    with _journal_lock:
+        rows = list(_journal)
+    return [tuple(row[:4]) for row in rows
+            if (since is None or row[0] >= since)
+            and (until is None or row[0] <= until)]
+
+
+def process_summary(until=None):
+    """The journal summed: `{"entries", "dropped", "kinds": {kind:
+    {"seconds", "count", "labels": {label: {"seconds", "count"}}}}}` over
+    the entries that ended by `until`. Seconds are self time, so a span
+    nested in another is counted once, under its own label. An operator
+    reads a replica's time to ready by phase and its cache state here
+    (`/metrics.json`, key `process`); a benchmark cuts at its window's
+    start."""
+    kinds = {}
+    events = process_events(until=until)
+    for _, kind, label, seconds in events:
+        k = kinds.setdefault(kind, {"seconds": 0.0, "count": 0, "labels": {}})
+        by = k["labels"].setdefault(label, {"seconds": 0.0, "count": 0})
+        for acc in (k, by):
+            acc["seconds"] += seconds
+            acc["count"] += 1
+    with _journal_lock:
+        dropped = _journal_state["dropped"]
+    return {"entries": len(events), "dropped": dropped, "kinds": kinds}
+
+
+def clear_process_journal():
+    """Empty the journal and its drop count (tests isolate themselves
+    with this, as with `Registry.reset`)."""
+    with _journal_lock:
+        _journal.clear()
+        _journal_state["dropped"] = 0
+    _tl.startup_row = None
+
+
+class startup_span(profiler.RecordEvent):
+    """`RecordEvent("startup/<phase>", **ids)` whose self time is also
+    journaled (kind `startup`, label `<phase>`) and counted in
+    `startup_seconds_total{phase=}`: the constructors that do start-up
+    work wrap it in one. Context manager or decorator."""
+
+    __slots__ = ("phase",)
+
+    def __init__(self, phase, **ids):
+        super().__init__("startup/" + phase, **ids)
+        self.phase = phase
+
+    def __exit__(self, *exc):
+        super().__exit__(*exc)
+        if self.elapsed is not None:
+            _STARTUP_SECONDS.labels(self.phase).inc(record_process_event(
+                "startup", self.phase, self.elapsed, t_end=self.end))
+        return False
+
+    def __call__(self, fn):
+        @functools.wraps(fn)
+        def wrapped(*a, **k):
+            with startup_span(self.phase, **self.ids):
+                return fn(*a, **k)
+        return wrapped
+
+
+# ---------------------------------------------------------------------------
 # XLA compile-event tracking
 # ---------------------------------------------------------------------------
 
+XLA_TRACE_EVENT = "/jax/core/compile/jaxpr_trace_duration"
+XLA_LOWER_EVENT = "/jax/core/compile/jaxpr_to_mlir_module_duration"
 XLA_BACKEND_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 XLA_CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
+XLA_CACHE_MISS_EVENT = "/jax/compilation_cache/cache_misses"
 
 _XLA_COMPILES = counter(
     "xla_compiles_total",
@@ -564,93 +747,143 @@ _XLA_COMPILE_SECONDS = histogram(
 _XLA_CACHE_HITS = counter(
     "xla_persistent_cache_hits_total",
     "Compiled executables loaded from the persistent compilation cache")
+_XLA_CACHE_MISSES = counter(
+    "xla_persistent_cache_misses_total",
+    "Executables compiled and written to the persistent compilation "
+    "cache, per attributed function", labelnames=("function",))
+_XLA_STAGE_SECONDS = counter(
+    "xla_stage_seconds_total",
+    "Self seconds of bringing programs into the process, by stage "
+    "(trace, lower, compile, cache_load) and attributed function",
+    labelnames=("stage", "function"))
 
-_tl = threading.local()
 _install_lock = threading.Lock()
-_install_state = {"installed": None}
+_install_state = {"installed": False}
+_known_labels = set()
+
+
+def _tl_list(name):
+    got = getattr(_tl, name, None)
+    if got is None:
+        got = []
+        setattr(_tl, name, got)
+    return got
 
 
 def _compile_label(metadata_name=None):
     stack = getattr(_tl, "stack", None)
     if stack:
         return stack[-1]
-    return metadata_name or "unattributed"
+    # a trace names its function `f`, its lowering and compile `jit(f)`
+    name = metadata_name or "unattributed"
+    return name[4:-1] if name.startswith("jit(") and name[-1] == ")" \
+        else name
 
 
-def _on_compile_duration(event, duration, **kw):
-    if event != XLA_BACKEND_COMPILE_EVENT:
-        return
-    label = _compile_label(kw.get("fun_name"))
-    _XLA_COMPILES.labels(label).inc()
-    _XLA_COMPILE_SECONDS.observe(duration)
+def _stage(kind, label, seconds, t_end=None):
+    _XLA_STAGE_SECONDS.labels(kind, label).inc(
+        record_process_event(kind, label, seconds, t_end))
+
+
+def _on_duration(event, duration, **kw):
+    """Every stage of bringing a program into the process, by program.
+
+    A request for an executable is one `backend_compile_duration` event
+    whether XLA compiled it or the persistent cache held it, so the two
+    are told apart by what JAX reports from inside it, on the same
+    thread, before it ends: a load reports `cache_hits`, a compile whose
+    result is written reports `cache_misses`, a compile the cache was
+    never asked for reports neither. Those inner events carry no name, so
+    they wait (`_tl.pending`) for the enclosing event's `fun_name`. With
+    a hit pending the request was a load, and all of it (the cache key,
+    then what `cache_retrieval_time_sec` times) is journaled
+    `cache_load` and nothing as `compile`; otherwise it is a `compile`."""
+    if event == XLA_TRACE_EVENT:
+        _stage("trace", _compile_label(kw.get("fun_name")), duration)
+    elif event == XLA_LOWER_EVENT:
+        _stage("lower", _compile_label(kw.get("fun_name")), duration)
+    elif event == XLA_BACKEND_COMPILE_EVENT:
+        label = _compile_label(kw.get("fun_name"))
+        _XLA_COMPILES.labels(label).inc()
+        _XLA_COMPILE_SECONDS.observe(duration)
+        pending, _tl.pending = _tl_list("pending"), []
+        for t_end, kind in pending:
+            record_process_event(kind, label, t_end=t_end)
+            if kind == "cache_miss":
+                _XLA_CACHE_MISSES.labels(label).inc()
+        hit = any(kind == "cache_hit" for _, kind in pending)
+        _stage("cache_load" if hit else "compile", label, duration)
 
 
 def _on_event(event, **kw):
     if event == XLA_CACHE_HIT_EVENT:
         _XLA_CACHE_HITS.inc()
+        _tl_list("pending").append((time.perf_counter(), "cache_hit"))
+    elif event == XLA_CACHE_MISS_EVENT:
+        _tl_list("pending").append((time.perf_counter(), "cache_miss"))
 
 
 def install_compile_tracking():
-    """Register the jax.monitoring listeners (idempotent). Returns True
-    when live; False on jax builds without jax.monitoring — callers fall
-    back to _cache_size() deltas (instrument_jit does automatically)."""
-    with _install_lock:
-        if _install_state["installed"] is None:
-            try:
+    """Register the jax.monitoring listeners, once a process
+    (idempotent). `utils.compile_cache.enable()`, the
+    serving front door and the train steps' constructors call it, so a
+    process's first program is heard."""
+    if not _install_state["installed"]:
+        with _install_lock:
+            if not _install_state["installed"]:
                 import jax.monitoring as jmon
-                jmon.register_event_duration_secs_listener(
-                    _on_compile_duration)
+                jmon.register_event_duration_secs_listener(_on_duration)
                 jmon.register_event_listener(_on_event)
                 _install_state["installed"] = True
-            except Exception:        # pragma: no cover - old jax fallback
-                _install_state["installed"] = False
-        return _install_state["installed"]
 
 
-@contextlib.contextmanager
-def track_compiles(label):
-    """Attribute every XLA compile event fired inside the block (from
-    this thread) to `label` in xla_compiles_total{function=label}."""
+def _register_label(label):
     _check_name(label)
     install_compile_tracking()
-    stack = getattr(_tl, "stack", None)
-    if stack is None:
-        stack = _tl.stack = []
-    stack.append(label)
-    try:
-        yield
-    finally:
-        stack.pop()
+    with _install_lock:
+        _known_labels.add(label)
+    return label
+
+
+class track_compiles:
+    """Attribute every XLA compile-stage event fired inside the block
+    (from this thread) to `label`: `xla_compiles_total{function=label}`,
+    `xla_stage_seconds_total{function=label}` and the journal's label.
+    A label is checked, and the listener installed, the first time the
+    label is seen; a later call pushes and pops a list."""
+
+    __slots__ = ("label",)
+
+    def __init__(self, label):
+        if label not in _known_labels:
+            _register_label(label)
+        self.label = label
+
+    def __enter__(self):
+        _tl_list("stack").append(self.label)
+        return self
+
+    def __exit__(self, *exc):
+        _tl.stack.pop()
+        return False
 
 
 class _InstrumentedJit:
-    """Proxy over a jitted callable: calls run under
-    track_compiles(label); without jax.monitoring it counts
-    `_cache_size()` growth instead (the wrap-jit fallback). Attribute
-    access (lower, _cache_size, ...) passes through."""
+    """Proxy over a jitted callable: calls run under its label as under
+    `track_compiles(label)`. Attribute access (lower, _cache_size, ...)
+    passes through."""
 
     def __init__(self, fn, label):
-        _check_name(label)
         self._fn = fn
-        self.label = label
-        self._monitoring = install_compile_tracking()
+        self.label = _register_label(label)
 
     def __call__(self, *args, **kw):
-        if self._monitoring:
-            with track_compiles(self.label):
-                return self._fn(*args, **kw)
-        before = self._safe_cache_size()
-        out = self._fn(*args, **kw)
-        grew = self._safe_cache_size() - before
-        if grew > 0:
-            _XLA_COMPILES.labels(self.label).inc(grew)
-        return out
-
-    def _safe_cache_size(self):
+        stack = _tl_list("stack")
+        stack.append(self.label)
         try:
-            return self._fn._cache_size()
-        except Exception:
-            return 0
+            return self._fn(*args, **kw)
+        finally:
+            stack.pop()
 
     def __getattr__(self, name):
         return getattr(self._fn, name)
@@ -669,6 +902,71 @@ def instrument_jit(fn, label):
 def compile_count(function):
     """Live compile count for an attributed function label."""
     return int(value("xla_compiles_total", {"function": function}, 0) or 0)
+
+
+# ---------------------------------------------------------------------------
+# the collector's pauses
+# ---------------------------------------------------------------------------
+
+GC_JOURNAL_MIN_S = 1e-3
+
+_GC_PAUSE = counter(
+    "gc_pause_seconds_total",
+    "Seconds the interpreter's collector held the process")
+_GC_COLLECTIONS = counter(
+    "gc_collections_total", "Collections of the interpreter's collector",
+    labelnames=("generation",))
+
+
+class _GcWatch:
+    """The one hook's state. Attributes, written by the hook alone: the
+    collector does not run inside itself, so nothing needs a lock."""
+    family = None       # span family of the pauses: `serving` or `train`
+    span = None         # the open `<family>/gc` span, between the phases
+
+
+_gc_watch = _GcWatch()
+
+
+def _on_gc(phase, info):
+    watch = _gc_watch
+    if phase == "start":
+        watch.span = profiler.RecordEvent(
+            watch.family + "/gc", generation=info["generation"]).__enter__()
+        return
+    span, watch.span = watch.span, None
+    if span is None:            # installed while a collection ran
+        return
+    span.__exit__(None, None, None)
+    _GC_PAUSE.inc(span.elapsed)
+    _GC_COLLECTIONS.labels(info["generation"]).inc()
+    if span.elapsed >= GC_JOURNAL_MIN_S:
+        record_process_event("gc", info["generation"], span.elapsed,
+                             t_end=span.end)
+
+
+def install_gc_tracking(family):
+    """Hook `gc.callbacks`, once a process (idempotent): every collection
+    is a `<family>/gc` span (ids: `generation`) and counts into
+    `gc_pause_seconds_total` and `gc_collections_total{generation=}`; a
+    pause of a millisecond or more is journaled (kind `gc`, label the
+    generation). `family` is `serving` (`Scheduler`) or `train` (the
+    train steps); the last caller's names the spans. Measures only: no
+    threshold, freeze or disable."""
+    with _install_lock:
+        _gc_watch.family = family
+        if _on_gc not in gc.callbacks:
+            gc.callbacks.append(_on_gc)
+
+
+def gc_totals():
+    """The process's collector totals, the three numbers
+    `ServingMetrics.snapshot()` hands on."""
+    by_generation = [int(value("gc_collections_total", {"generation": g}, 0))
+                     for g in range(3)]
+    return {"gc_pause_seconds": _GC_PAUSE.value(),
+            "gc_collections": sum(by_generation),
+            "gc_gen2_collections": by_generation[2]}
 
 
 # ---------------------------------------------------------------------------
@@ -804,7 +1102,8 @@ def make_metrics_handler(registry=None, health_fn=None, sampler=None):
                 ctype = "text/plain; version=0.0.4; charset=utf-8"
                 code = 200
             elif path == "/metrics.json":
-                body = json.dumps(reg.snapshot()).encode()
+                body = json.dumps({**reg.snapshot(),
+                                   "process": process_summary()}).encode()
                 ctype = "application/json"
                 code = 200
             elif path == "/metrics/history":

@@ -366,8 +366,8 @@ def scenario_kill_resume(name, kill_step, cfg, inject=None, journal=None):
 
         # ---- compile-once in the resumed process ----------------------
         step_obj = model2._train_step
-        cache_size = step_obj._safe_cache_size() if step_obj is not None \
-            else None
+        cache_size = step_obj._compiled._cache_size() \
+            if step_obj is not None else None
         _check(v, cache_size == 1,
                f"resumed train step compiled {cache_size} executables, "
                "expected exactly 1 (resume changed traced shapes/dtypes?)")

@@ -316,3 +316,66 @@ def test_unfed_ignores_an_empty_server(model):
             time.sleep(0.2)
         sched.generate(_prompt(5), max_tokens=3)
     assert _unfed(sched) - before < 0.2
+
+
+# ---------------------------------------------------------------------------
+# the collector's pauses, and the train step's label
+# ---------------------------------------------------------------------------
+
+def test_one_gc_hook_however_many_schedulers_are_built(paged):
+    import gc
+    from paddle_tpu.utils import telemetry
+    for _ in range(3):
+        Scheduler(paged)
+    assert gc.callbacks.count(telemetry._on_gc) == 1
+
+
+def test_a_collection_inside_a_round_is_one_serving_gc_span(paged):
+    import gc
+    from paddle_tpu.utils import telemetry
+    sched = Scheduler(paged)                 # names the family `serving`
+    before = sched.metrics.snapshot()
+    prof.start_profiler()
+    try:
+        with RecordEvent("serving/round") as round_:
+            gc.collect()
+    finally:
+        prof.stop_profiler()
+    after = sched.metrics.snapshot()
+    spans = [e for e in prof._events if e[0] == "serving/gc"
+             and e[5]["generation"] == 2]
+    assert len(spans) == 1                   # entered once, left once
+    name, t0, dur, *_ = spans[0]
+    assert round_.end - round_.elapsed <= t0 and t0 + dur <= round_.end
+    assert after["gc_gen2_collections"] == before["gc_gen2_collections"] + 1
+    assert after["gc_collections"] > before["gc_collections"]
+    assert after["gc_pause_seconds"] >= before["gc_pause_seconds"] + dur
+    assert telemetry.gc_totals() == {
+        k: after[k] for k in ("gc_pause_seconds", "gc_collections",
+                              "gc_gen2_collections")}
+
+
+def test_a_plain_train_step_is_heard_under_its_label():
+    """No recorder attached: the constructor installs the listener, and
+    the first call's trace, lowering and compile (or load) are journaled
+    as `train_step`; the build is a `startup/step_build` entry."""
+    from paddle_tpu import nn, optimizer
+    from paddle_tpu.jit import TrainStep
+    from paddle_tpu.utils import telemetry
+    telemetry.clear_process_journal()
+    net = nn.Linear(8, 4)
+    step = TrainStep(net, lambda out, y: ((out - y) ** 2).mean(),
+                     optimizer.SGD(learning_rate=0.1,
+                                   parameters=net.parameters()))
+    assert step._recorder is None
+    assert telemetry._install_state["installed"]
+    before = telemetry.compile_count("train_step")
+    step(pt.to_tensor(np.ones((2, 8), np.float32)),
+         pt.to_tensor(np.zeros((2, 4), np.float32)))
+    assert telemetry.compile_count("train_step") == before + 1
+    kinds = telemetry.process_summary()["kinds"]
+    assert "step_build" in kinds["startup"]["labels"]
+    for stage in ("trace", "lower"):
+        assert kinds[stage]["labels"]["train_step"]["seconds"] > 0
+    assert any("train_step" in kinds.get(k, {"labels": ()})["labels"]
+               for k in ("compile", "cache_load"))

@@ -128,7 +128,9 @@ def test_snapshot_keys_byte_compatible(engine):
         "ssm_records_stepped", "ssm_lanes_stepped",
         # bias rows and matrices sent to the device (0 while no request
         # brings a bias)
-        "bias_uploads"]
+        "bias_uploads",
+        # the collector's pauses and collections (process totals)
+        "gc_pause_seconds", "gc_collections", "gc_gen2_collections"]
     assert snap["bias_uploads"] == 0
     # a 3-token request has 2 inter-token gaps — TPOT is real, and the
     # phase split saw every phase of a working round

@@ -6,6 +6,7 @@ Tests use PRIVATE Registry instances wherever possible so they don't
 disturb the process-wide default registry other suites accumulate into.
 """
 import json
+import time
 
 import pytest
 
@@ -328,3 +329,189 @@ def test_telemetry_callback_records_step_loss_and_memory():
     # published as a misleading zero; on accelerators it's >= 0
     mem = telemetry.value("device_bytes_in_use")
     assert mem is None or mem >= 0
+
+
+# ----------------------------------------------------- the process journal
+_JOURNAL_PROBE = """
+import json, jax, jax.numpy as jnp
+from paddle_tpu.utils import compile_cache, telemetry
+compile_cache.enable()                    # installs the listener
+jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+x = jnp.arange(8.0)
+fn = telemetry.instrument_jit(jax.jit(lambda x: (x * 3 + 1).sum()),
+                              "journal_probe")
+out = {}
+for phase in ("cold", "warm"):
+    telemetry.clear_process_journal()
+    fn(x)
+    out[phase] = telemetry.process_summary()["kinds"]
+    jax.clear_caches()                    # the directory is kept
+print(json.dumps(out))
+"""
+
+
+@pytest.fixture(scope="module")
+def journal_probe(tmp_path_factory):
+    """One process, an empty cache directory: the labelled function's
+    first call, then (executables dropped, directory kept) its second."""
+    import os
+    import subprocess
+    import sys
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=root,
+               JAX_COMPILATION_CACHE_DIR=str(
+                   tmp_path_factory.mktemp("jax_cache")))
+    out = subprocess.run([sys.executable, "-c", _JOURNAL_PROBE], env=env,
+                         capture_output=True, text=True, timeout=300)
+    assert out.returncode == 0, out.stderr[-2000:]
+    return json.loads(out.stdout.splitlines()[-1])
+
+
+@pytest.mark.parametrize("phase,journaled,absent", [
+    ("cold", ("trace", "lower", "compile", "cache_miss"),
+     ("cache_load", "cache_hit")),
+    ("warm", ("trace", "lower", "cache_load", "cache_hit"),
+     ("compile", "cache_miss")),
+])
+def test_compile_stages_are_journaled_by_program_and_a_load_is_no_compile(
+        journal_probe, phase, journaled, absent):
+    kinds = journal_probe[phase]
+    for kind in journaled:
+        assert kinds[kind]["labels"]["journal_probe"]["count"] == 1, kind
+    for kind in absent:
+        assert "journal_probe" not in kinds.get(kind, {"labels": {}})[
+            "labels"], kind
+    timed = [k for k in journaled if not k.startswith("cache_")
+             or k == "cache_load"]
+    assert all(kinds[k]["labels"]["journal_probe"]["seconds"] > 0
+               for k in timed)
+
+
+@pytest.fixture
+def journal():
+    """An empty journal, and no collection while the test runs: a hook an
+    earlier test's scheduler installed would journal a long pause."""
+    import gc
+    gc.disable()
+    telemetry.clear_process_journal()
+    yield telemetry
+    telemetry.clear_process_journal()
+    gc.enable()
+
+
+def test_process_events_cut_at_since_and_until(journal):
+    for t in (10.0, 11.0, 12.0):
+        journal.record_process_event("gc", 0, 0.002, t_end=t)
+    assert [e[0] for e in journal.process_events()] == [10.0, 11.0, 12.0]
+    assert [e[0] for e in journal.process_events(until=11.0)] == [10.0, 11.0]
+    assert [e[0] for e in journal.process_events(since=11.5)] == [12.0]
+    assert journal.process_events(since=10.5, until=11.5) == [
+        (11.0, "gc", "0", 0.002)]
+    assert journal.process_summary(until=10.5)["kinds"]["gc"]["count"] == 1
+
+
+def test_the_journal_is_bounded_and_counts_what_it_dropped(journal):
+    n = journal.JOURNAL_MAX + 7
+    for i in range(n):
+        journal.record_process_event("cache_hit", "f", t_end=float(i))
+    events = journal.process_events()
+    assert len(events) == journal.JOURNAL_MAX
+    assert events[0][0] == 7.0 and events[-1][0] == float(n - 1)
+    assert journal.process_summary()["dropped"] == 7
+
+
+def test_a_nested_startup_span_and_what_it_compiled_count_once(journal):
+    # engine [0, 10] holds pool [2, 5], which holds a 1 s compile; and a
+    # program's trace [6, 9] that traced a helper [7, 8] on its way
+    journal.record_process_event("compile", "zeros", 1.0, t_end=4.0)
+    journal.record_process_event("startup", "pool", 3.0, t_end=5.0)
+    journal.record_process_event("trace", "helper", 1.0, t_end=8.0)
+    journal.record_process_event("trace", "wave", 3.0, t_end=9.0)
+    journal.record_process_event("startup", "engine", 10.0, t_end=10.0)
+    kinds = journal.process_summary()["kinds"]
+    startup = kinds["startup"]["labels"]
+    assert startup["pool"]["seconds"] == pytest.approx(2.0)
+    assert startup["engine"]["seconds"] == pytest.approx(10.0 - 3.0 - 3.0)
+    assert kinds["compile"]["seconds"] == pytest.approx(1.0)
+    # the helper's second stays the program's, in one entry
+    assert kinds["trace"]["labels"] == {"wave": {"seconds": 3.0, "count": 1}}
+    total = sum(k["seconds"] for k in kinds.values())
+    assert total == pytest.approx(10.0)      # wall time, nothing twice
+
+
+def test_startup_span_journals_its_self_time_and_counts_it(journal):
+    before = telemetry.value("startup_seconds_total",
+                             {"phase": "probe_outer"}, 0.0)
+    with journal.startup_span("probe_outer", slots=2) as outer:
+        with journal.startup_span("probe_inner"):
+            time.sleep(0.01)
+    labels = journal.process_summary()["kinds"]["startup"]["labels"]
+    inner = labels["probe_inner"]["seconds"]
+    assert inner >= 0.01 and outer.name == "startup/probe_outer"
+    assert labels["probe_outer"]["seconds"] == pytest.approx(
+        outer.elapsed - inner)
+    assert telemetry.value("startup_seconds_total",
+                           {"phase": "probe_outer"}) == pytest.approx(
+        before + outer.elapsed - inner)
+
+
+def test_a_model_constructor_journals_its_initialisers_once(journal):
+    from paddle_tpu import nn
+
+    class Two(nn.Layer):
+        def __init__(self):
+            super().__init__()
+            self.a, self.b = nn.Linear(16, 32), nn.Linear(32, 8)
+
+    net = Two()
+    net.to(dtype="bfloat16")
+    labels = journal.process_summary()["kinds"]["startup"]["labels"]
+    assert labels["param_init"]["count"] == 1      # four parameters, one entry
+    assert labels["param_init"]["seconds"] > 0
+    assert labels["cast"]["count"] == 1
+    assert str(net.a.weight.dtype).endswith("bfloat16")
+
+
+def test_metrics_json_serves_the_process_summary(journal):
+    journal.record_process_event("startup", "engine", 2.5, t_end=3.0)
+    code, _, body = telemetry.http_get_inline("/metrics.json")
+    process = json.loads(body)["process"]
+    assert code == 200 and process["dropped"] == 0
+    assert process["kinds"]["startup"]["labels"]["engine"] == {
+        "seconds": 2.5, "count": 1}
+
+
+def test_a_large_trace_does_not_push_the_start_up_out_of_the_journal(journal):
+    """More stages under one label than the journal holds, before the
+    enclosing stage arrives: the run is summed, nothing older is dropped,
+    and the enclosing stage still takes it all in."""
+    journal.record_process_event("startup", "param_init", 5.0, t_end=5.0)
+    n = 3 * journal.JOURNAL_MAX
+    for i in range(n):
+        journal.record_process_event("trace", "big_step", 5e-4,
+                                     t_end=10.0 + i * 1e-3)
+    mid = journal.process_summary()
+    assert mid["dropped"] == 0 and mid["entries"] <= journal.JOURNAL_MAX
+    assert mid["kinds"]["trace"]["seconds"] == pytest.approx(n * 5e-4)
+    journal.record_process_event("trace", "big_step", 20.0, t_end=25.0)
+    kinds = journal.process_summary()["kinds"]
+    assert kinds["trace"]["labels"]["big_step"] == {
+        "seconds": pytest.approx(20.0), "count": 1}
+    assert kinds["startup"]["labels"]["param_init"]["seconds"] == 5.0
+
+
+def test_the_registry_counts_a_nested_stage_once(journal):
+    """The journal folds a helper's trace into its program's entry; the
+    counter by stage had the helper's second already and gets only the
+    program's own."""
+    def total():
+        return sum(telemetry.value("xla_stage_seconds_total",
+                                   {"stage": "trace", "function": f}, 0.0)
+                   for f in ("fold_helper", "fold_program"))
+    before = total()
+    telemetry._stage("trace", "fold_helper", 1.0, t_end=8.0)
+    telemetry._stage("trace", "fold_program", 3.0, t_end=9.0)
+    assert total() - before == pytest.approx(3.0)
+    assert journal.process_summary()["kinds"]["trace"]["labels"] == {
+        "fold_program": {"seconds": 3.0, "count": 1}}
