@@ -24,11 +24,21 @@ request stream reuses the same executable):
 Retire-and-refill happens BETWEEN waves by rewriting the per-slot
 vectors — in-flight decodes never stall and never recompile.
 
-Slot bookkeeping (positions, tokens, flags) is host-authoritative:
-numpy arrays of the programs' own dtypes, written in place between
-waves, so a wave costs one small upload and no device round-trip; the
-next-token pull each wave is the one unavoidable sync (the tokens are
-the product being streamed).
+Slot bookkeeping is split by who decides it. What the host decides
+(active, the sampling knobs, block tables) is host-authoritative: numpy
+arrays of the programs' own dtypes, written in place between waves and
+packed into a wave's one small upload. What the programs decide, each
+lane's last token and next position, lives ON THE DEVICE: two int32[S]
+arrays carried from program to program, donated, as the caches and the
+key are. A wave reads and returns them; a prompt's last chunk writes
+the first token it selected and the prompt's length into its slot's
+row. So the host is not on the token's path: `dispatch_wave` enqueues a
+wave and returns a ticket, `collect_wave` reads that wave's tokens, and
+a scheduler may enqueue the next wave (and the next chunks) before it
+collects the last one. A token is therefore read one program after it
+is made. The host keeps `slot_pos` as a mirror by counting (a lane in a
+wave advances by one; a position never depends on a token's value) and
+`slot_tok` as what it last read.
 
 A stage (`serving/prefill/stage`, `serving/wave/stage`) puts nothing on
 the device's queue: nothing it builds is a `jnp` value (each
@@ -42,6 +52,8 @@ transfer of its own, 0.16 ms of the call on a v5e whatever its size
 (PERF.md, PR 32), and a chunk had nine of them. What does not change
 stays on the device: the bias row and matrix of a server without biases.
 """
+import time
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -79,19 +91,22 @@ def _raw(x):
 
 #: a wave program's small arguments, one int32 row a lane: the lane's
 #: block-table row (paged engines; no column on a dense one), then these
-#: columns. `spec_len` is the speculative engine's (0 elsewhere);
-#: `temp` and `top_p` are float32, carried by their bits.
-LANE_FIELDS = ("tok", "pos", "active", "sample", "top_k", "poison",
-               "spec_len", "temp", "top_p")
+#: columns: what the host decides. A lane's token and position are not
+#: here: they stay on the device (the module comment). `spec_len` is the
+#: speculative engine's (0 elsewhere); `temp` and `top_p` are float32,
+#: carried by their bits.
+LANE_FIELDS = ("active", "sample", "top_k", "poison", "spec_len", "temp",
+               "top_p")
 #: a prefill program's small arguments, one int32 vector: the slot's
 #: table row (paged engines), the chunk's tokens, then these. `start` is
 #: the chunk's offset in the prompt, `valid` its real tokens (the dense
 #: bucket's prompt length), `frontier` the position whose logits pick
-#: the first token.
-PROMPT_FIELDS = ("start", "valid", "frontier", "slot", "sample", "top_k",
-                 "temp", "top_p")
+#: the first token, `last` whether this is the prompt's last chunk (the
+#: program then arms the slot's lane: `arm_lane`).
+PROMPT_FIELDS = ("start", "valid", "frontier", "slot", "last", "sample",
+                 "top_k", "temp", "top_p")
 _FLOAT_FIELDS = ("temp", "top_p")
-_FLAG_FIELDS = ("active", "sample", "poison")
+_FLAG_FIELDS = ("active", "sample", "poison", "last")
 
 
 def _as_bits(x):
@@ -253,9 +268,47 @@ def _select_first_token(lo, sample, temp, top_k, top_p, bias, key):
     return _if_any_samples(sample, draw, greedy)
 
 
+def arm_lane(lane_tok, lane_pos, a, first):
+    """A prefill program's last step, shared by every engine's: on a
+    prompt's last chunk the slot's lane takes the first token and the
+    prompt's length (the position the next wave writes), on any other
+    chunk it keeps what it had."""
+    slot, n = a["slot"], a["start"] + a["frontier"] + 1
+    return (lane_tok.at[slot].set(jnp.where(a["last"], first,
+                                            lane_tok[slot])),
+            lane_pos.at[slot].set(jnp.where(a["last"], n, lane_pos[slot])))
+
+
+def wave_read(nxt, finite):
+    """What the host reads of a wave, one int32[2, S] array of its own
+    (the lane state beside it is donated to the next program before the
+    host gets to it): the tokens, and whether each lane's logits were
+    finite."""
+    return jnp.stack([nxt, finite.astype(jnp.int32)])
+
+
+class WaveTicket:
+    """A dispatched wave that the host has not read yet.
+
+    lanes: the slots in the wave (int array); epochs: each slot's epoch
+    at dispatch (a slot retired, or armed for another request, since then
+    has another, and its entry is dropped at collect); full: the slots
+    whose position after this wave is at the cache horizon; read: the
+    device array `wave_read` built; tokens: the result, for a wave that
+    was read when it was dispatched (the speculative engine's)."""
+    __slots__ = ("lanes", "epochs", "full", "read", "tokens")
+
+    def __init__(self, lanes, epochs, full, read=None, tokens=None):
+        self.lanes, self.epochs, self.full = lanes, epochs, full
+        self.read, self.tokens = read, tokens
+
+
 class ServingEngine:
     """Fixed-shape batched decode executor. The Scheduler decides WHICH
     request occupies which slot and when; the engine only knows slots.
+
+    `pipelined`: a wave's tokens can be read after the next programs
+    were dispatched (`dispatch_wave` returns before the wave has run).
 
     model: a causal LM exposing prefill / decode_step / init_cache
         (GPTForPretraining, LlamaForCausalLM).
@@ -267,6 +320,8 @@ class ServingEngine:
         inference Config's ir_optim=False analog) — for debugging;
         decode_compiles stays 0 on that path.
     """
+
+    pipelined = True
 
     def __init__(self, model, num_slots=4, max_len=256, prefill_len=None,
                  cache_dtype=None, jit_compile=True, seed=0):
@@ -307,14 +362,29 @@ class ServingEngine:
         self._key = jax.random.PRNGKey(seed)
 
         # host-authoritative per-slot state: numpy arrays of the wave
-        # program's dtypes, written in place (_arm_slot, retire_slot,
-        # the wave's read-back); a wave stage packs them (pack_lanes)
+        # program's dtypes, written in place (_arm_slot, retire_slot);
+        # a wave stage packs them (pack_lanes)
         S = self.num_slots
         # vocab width: the logit-bias / token-mask rows are [V] uploads
         self.vocab_size = int(model.cfg.vocab_size)
         self.slot_active = np.zeros((S,), bool)
-        self.slot_pos = np.zeros((S,), np.int32)   # next cache write position
-        self.slot_tok = np.zeros((S,), np.int32)   # token fed to the next wave
+        # each lane's last token and next cache write position, on the
+        # device: every program takes them donated and returns them
+        self._lane_tok = jax.device_put(np.zeros((S,), np.int32))
+        self._lane_pos = jax.device_put(np.zeros((S,), np.int32))
+        # the host's mirror of the positions, by counting (set when a
+        # slot is armed, +1 when its lane goes into a wave): what block
+        # allocation, page counts and the horizon read
+        self.slot_pos = np.zeros((S,), np.int32)
+        # the token the host last READ of each lane (hand-off export)
+        self.slot_tok = np.zeros((S,), np.int32)
+        # bumped when a slot is armed and when it is retired: a wave or
+        # a first token dispatched under another epoch than the slot
+        # has at collect served a request that is gone
+        self._slot_epoch = np.zeros((S,), np.int64)
+        # (slot, epoch, device scalar) of the prompts whose last chunk
+        # went out and whose first token is unread
+        self._first_pending = []
         self.slot_sample = np.zeros((S,), bool)
         self.slot_temp = np.ones((S,), np.float32)
         # per-request scenario surface (all flow through the one shared
@@ -378,13 +448,18 @@ class ServingEngine:
         # them (take_phase_seconds, once a round). The engine is driven
         # by one thread at a time, so no lock.
         self._phase_acc = {}
-        # perf_counter() at which the device last ran out of work: a
-        # blocking read of a program's output returned and no program
-        # has been dispatched since (programs run in order and each
-        # takes the donated cache of the one before, so after such a
-        # read nothing is queued). None while a program is in flight,
-        # before the first one, and after drop_unfed().
+        # perf_counter() at which the host saw the device out of work: a
+        # blocking read returned, or the scheduler asked between reads
+        # (poll_unfed), with the NEWEST program finished and none
+        # dispatched since (programs run in order and each takes the
+        # donated cache of the one before, so then nothing is queued; a
+        # read of an older program may return with later ones still
+        # running). None while a program is in flight, before the first
+        # one, and after drop_unfed().
         self._unfed_since = None
+        # an output of the newest program dispatched: ready when the
+        # device has run everything it was given
+        self._newest_out = None
 
         self._jit = bool(jit_compile)
         self._metrics_server = None
@@ -401,19 +476,19 @@ class ServingEngine:
 
         bucket = self.prefill_len
 
-        def decode_wave(p, b, caches, lanes, bias, key):
+        def decode_wave(p, b, caches, lane_tok, lane_pos, lanes, bias, key):
             key, sub = jax.random.split(key)
             _, a = unpack_lanes(lanes)
-            out, _ = model.functional_call(p, b, a["tok"][:, None], caches,
-                                           a["pos"], method="decode_step")
+            out, _ = model.functional_call(p, b, lane_tok[:, None], caches,
+                                           lane_pos, method="decode_step")
             logits, new_caches = out
             lo = _raw(logits)[:, 0, :].astype(jnp.float32)
             nxt, new_pos, finite = _select_wave_tokens(
-                lo, a["tok"], a["pos"], a["active"], a["sample"],
+                lo, lane_tok, lane_pos, a["active"], a["sample"],
                 a["temp"], a["top_k"], a["top_p"], bias, a["poison"], sub)
-            return nxt, new_pos, finite, new_caches, key
+            return wave_read(nxt, finite), new_caches, nxt, new_pos, key
 
-        def prefill(p, b, caches, prompt, bias, key):
+        def prefill(p, b, caches, lane_tok, lane_pos, prompt, bias, key):
             key, sub = jax.random.split(key)
             _, tokens, a = unpack_prompt(prompt, bucket)
             # the model applies its LM head to the frontier position
@@ -434,7 +509,8 @@ class ServingEngine:
                 cv = jax.lax.dynamic_update_slice(
                     cv, _raw(scv).astype(cv.dtype), (slot, 0, 0, 0))
                 new_caches.append((ck, cv))
-            return first, new_caches, key
+            return (first, new_caches,
+                    *arm_lane(lane_tok, lane_pos, a, first), key)
 
         # raw closures + jit spec, kept for the compile-level audit
         # (tools/xprof lowers THE functions the engine serves — and can
@@ -442,13 +518,15 @@ class ServingEngine:
         # rather than a drifting reimplementation)
         self._decode_wave_fn = decode_wave
         self._prefill_fn = prefill
-        self._program_donate_argnums = (2,)
+        self._program_donate_argnums = (2, 3, 4)
+        self._prefill_donate_argnums = self._program_donate_argnums
 
         if self._jit:
             # donate the batched cache: the engine always replaces its
             # cache reference with the program output, so XLA may update
             # it in place — without this every wave would transiently
-            # hold 2x the [S, Hkv, L, D] pair in HBM.
+            # hold 2x the [S, Hkv, L, D] pair in HBM. The two lane-state
+            # vectors beside it are replaced the same way.
             # instrument_jit attributes XLA compile events to these
             # labels (xla_compiles_total{function=...}) — the
             # compile-once invariant as a live metric, not just the
@@ -459,7 +537,7 @@ class ServingEngine:
                 "serving_decode_wave")
             self._prefill = telemetry.instrument_jit(
                 jax.jit(prefill,
-                        donate_argnums=self._program_donate_argnums),
+                        donate_argnums=self._prefill_donate_argnums),
                 "serving_prefill")
         else:
             self._decode_wave = decode_wave
@@ -526,11 +604,12 @@ class ServingEngine:
         acc = self._phase_acc
         acc[phase] = acc.get(phase, 0.0) + ev.elapsed
 
-    def _dispatched(self, phase, ev):
-        """A program was enqueued (`ev`: its dispatch span): the device
-        is fed. The seconds since the read that left it empty are the
-        host's: `unfed`."""
+    def _dispatched(self, phase, ev, out):
+        """A program was enqueued (`ev`: its dispatch span; `out`: one of
+        its outputs): the device is fed. The seconds since the host saw
+        it empty are the host's: `unfed`."""
         self._acc(phase, ev)
+        self._newest_out = out
         t = self._unfed_since
         if t is not None:
             self._unfed_since = None
@@ -539,15 +618,31 @@ class ServingEngine:
 
     def _read_back(self, phase, ev):
         """A blocking read of a program's output returned (`ev`: its
-        span): nothing is queued on the device until the next
-        dispatch."""
+        span). If the newest program dispatched has finished (it was the
+        one read, or it ran while the host read an older one), nothing
+        is queued on the device until the next dispatch; if it has not,
+        the device still works and no unfed interval opens."""
         self._acc(phase, ev)
-        self._unfed_since = ev.end
+        self.poll_unfed(ev.end)
+
+    def poll_unfed(self, since=None):
+        """Open the unfed interval if the device has finished everything
+        it was given and the host has not seen that yet: at `since`, or
+        now. The scheduler asks at a round's two ends, where no read
+        tells: at its end, and at its start with `since` the end of the
+        round before (the last moment it saw work queued: what its
+        caller did in between, the device had nothing)."""
+        out = self._newest_out
+        if self._unfed_since is None and out is not None \
+                and out.is_ready():
+            self._unfed_since = (time.perf_counter() if since is None
+                                 else since)
 
     def drop_unfed(self):
-        """Forget the open unfed interval: the server is empty, and an
-        empty server is not a slow host."""
-        self._unfed_since = None
+        """Forget the open unfed interval, and the program that could
+        open one: the server is empty, and an empty server is not a slow
+        host."""
+        self._unfed_since = self._newest_out = None
 
     def take_phase_seconds(self):
         """{phase: seconds} accumulated since the last call (the
@@ -658,13 +753,15 @@ class ServingEngine:
             self._slot_bias[slot] = row if nonzero else 0.0
         self._slot_bias_nonzero[slot] = nonzero
 
-    def _arm_slot(self, slot, first, n, sampling):
-        """Post-prefill slot arming shared by the dense and paged
-        admission paths: the request's whole sampling surface becomes
-        per-slot vectors for the next wave."""
+    def _arm_slot(self, slot, n, sampling):
+        """Slot arming shared by the dense and paged admission paths,
+        when the prompt's last chunk has been DISPATCHED (the program
+        arms the lane on the device: `arm_lane`): the request's whole
+        sampling surface becomes per-slot vectors for the next wave, the
+        position mirror starts at the prompt's length."""
         self.slot_active[slot] = True
         self.slot_pos[slot] = n
-        self.slot_tok[slot] = first
+        self._slot_epoch[slot] += 1
         self.slot_sample[slot] = sampling["sample"]
         self.slot_temp[slot] = sampling["temp"]
         self.slot_top_k[slot] = sampling["top_k"]
@@ -688,10 +785,11 @@ class ServingEngine:
                 "dynamic_mask": bool(dynamic_mask)}
 
     def _prompt_args(self, slot, tokens, start, valid, frontier, sampling,
-                     table=None):
+                     table=None, last=True):
         """A prefill program's arguments after the donated caches: the
-        packed vector (pack_prompt), the [V] bias row, the key. The row
-        is uploaded once a request, and only if it holds a bias."""
+        two lane-state vectors (donated too), the packed vector
+        (pack_prompt), the [V] bias row, the key. The row is uploaded
+        once a request, and only if it holds a bias."""
         if not sampling["bias_nonzero"]:
             bias = self._zero_bias_row
         elif "bias_dev" in sampling:
@@ -701,9 +799,10 @@ class ServingEngine:
             self._bias_uploads += 1
         prompt = pack_prompt(
             tokens, table, start=start, valid=valid, frontier=frontier,
-            slot=slot, sample=sampling["sample"], top_k=sampling["top_k"],
-            temp=sampling["temp"], top_p=sampling["top_p"])
-        return prompt, bias, self._key
+            slot=slot, last=last, sample=sampling["sample"],
+            top_k=sampling["top_k"], temp=sampling["temp"],
+            top_p=sampling["top_p"])
+        return self._lane_tok, self._lane_pos, prompt, bias, self._key
 
     def take_bias_uploads(self):
         """Rows and matrices of bias sent to the device since the last
@@ -732,17 +831,21 @@ class ServingEngine:
                                  logit_bias, dynamic_mask))
 
     def prefill_step(self, slot):
-        """Advance the slot's admission one step. Returns the request's
-        FIRST generated token (host int) when the prefill completed,
-        None while more steps remain (the dense bucket prefill always
-        completes here). Routed through prefill_slot so engine users
-        (and test seams) that override it see every admission."""
+        """Advance the slot's admission one step: DISPATCH one prefill
+        program and return, without reading anything. True when that was
+        the prompt's last (the slot is armed and rides the next wave; its
+        first token is read by `collect_first_tokens`, one program or
+        more after it was made), False while more steps remain (the dense
+        bucket prefill always completes here). Routed through
+        prefill_slot so engine users (and test seams) that override it
+        see every admission."""
         prompt, sampling = self._pending_prefill.pop(slot)
-        return self.prefill_slot(
+        self.prefill_slot(
             slot, prompt, do_sample=sampling["sample"],
             temperature=sampling["temp"], top_k=sampling["top_k"],
             top_p=sampling["top_p"], logit_bias=sampling["bias"],
-            dynamic_mask=sampling["dynamic_mask"])
+            dynamic_mask=sampling["dynamic_mask"], wait=False)
+        return True
 
     def prefill_chunk_index(self, slot):
         """Which chunk of the slot's prompt the next prefill_step runs
@@ -751,19 +854,22 @@ class ServingEngine:
 
     def prefill_slot(self, slot, prompt, do_sample=False, temperature=1.0,
                      top_k=0, top_p=1.0, logit_bias=None,
-                     dynamic_mask=False):
+                     dynamic_mask=False, wait=True):
         """Admit a prompt into a free slot: run the prefill program,
         splice the slot's cache region, arm the slot for the next wave.
-        Returns the request's FIRST generated token (host int)."""
+        Returns the request's FIRST generated token (host int); with
+        `wait=False` nothing is read and None is returned (the token
+        comes with the next `collect_first_tokens`)."""
         why = self.validate_prompt(prompt)
         if why:
             raise ValueError(why)
-        return self._prefill_slot_armed(
+        self._dispatch_prefill(
             slot, list(prompt),
             self._sampling_state(do_sample, temperature, top_k, top_p,
                                  logit_bias, dynamic_mask))
+        return self.collect_first_tokens()[slot] if wait else None
 
-    def _prefill_slot_armed(self, slot, prompt, sampling):
+    def _dispatch_prefill(self, slot, prompt, sampling):
         if self.slot_active[slot]:
             raise RuntimeError(f"slot {slot} is busy")
         if chaos.enabled():
@@ -782,34 +888,74 @@ class ServingEngine:
                                        sampling))
         self._acc("prefill.stage", ev)
         with RecordEvent("serving/prefill/dispatch", pid=pid) as ev:
-            first, self._caches, self._key = self._prefill(*args)
-        self._dispatched("prefill.dispatch", ev)
-        with RecordEvent("serving/prefill/first_token", pid=pid) as ev:
-            first = int(np.asarray(first))
+            first, self._caches, self._lane_tok, self._lane_pos, \
+                self._key = self._prefill(*args)
+        self._dispatched("prefill.dispatch", ev, first)
+        self._armed(slot, first, n, sampling)
+
+    def _armed(self, slot, first, n, sampling):
+        """The prompt's last chunk is on the device's queue: arm the
+        slot and park its first token (a device scalar) for
+        `collect_first_tokens`."""
+        self._arm_slot(slot, n, sampling)
+        self._first_pending.append((slot, int(self._slot_epoch[slot]),
+                                    first))
+
+    @property
+    def first_tokens_pending(self):
+        """Prompts whose last chunk was dispatched and whose first token
+        has not been read."""
+        return len(self._first_pending)
+
+    def collect_first_tokens(self):
+        """{slot: first token} of the prompts whose last chunk was
+        dispatched since the last call: one blocking read of their
+        scalars (`serving/prefill/first_token`). A slot retired since its
+        chunk went out has no entry."""
+        pending, self._first_pending = self._first_pending, []
+        if not pending:
+            return {}
+        with RecordEvent("serving/prefill/first_token",
+                         pid=self.trace_pid) as ev:
+            firsts = jax.device_get([p[2] for p in pending])
         self._read_back("prefill.first_token", ev)
-        self._arm_slot(slot, first, n, sampling)
-        return first
+        out = {}
+        for (slot, epoch, _), first in zip(pending, firsts):
+            if self._slot_epoch[slot] == epoch:
+                out[slot] = self.slot_tok[slot] = int(first)
+        return out
 
     def decode_wave(self):
-        """One batched decode step over all slots. Returns {slot: token}
-        for the slots that were active this wave AND produced finite
-        logits; slots whose logits went non-finite are excluded, frozen
-        in-program, and listed in `last_nonfinite_slots` for the
-        scheduler to retire (finish_reason "error"). Inactive lanes
-        ride along frozen.
+        """One batched decode step over all slots, dispatched and read:
+        `dispatch_wave` then `collect_wave`. Returns {slot: token} for
+        the slots that were active this wave AND produced finite logits
+        (see `collect_wave`)."""
+        ticket = self.dispatch_wave()
+        if ticket is None:
+            self.last_nonfinite_slots = []
+            return {}
+        return self.collect_wave(ticket)
+
+    def dispatch_wave(self, skip=()):
+        """Enqueue one batched decode step over the active slots less
+        `skip` (the lanes the caller knows have no token left: their
+        budget or the horizon is met by a token still in flight) and
+        return its WaveTicket without reading anything; None when no
+        lane is left to decode. Inactive lanes ride along frozen. The
+        position mirror of the wave's lanes advances here.
 
         Raise-type faults (chaos, or a real host-side error) fire
         BEFORE the donated cache reaches the program, and the key
         advances only when the program returns the next one, so a
-        failed wave mutates nothing and a retry replays exactly.
+        failed dispatch mutates nothing and a retry replays exactly.
         An error from inside the compiled call itself may have consumed
         the donated cache — the retry then fails too and the scheduler
         degrades gracefully instead of looping."""
         active_now = self.slot_active.copy()
+        active_now[np.asarray(skip, np.intp)] = False
         if not active_now.any():
-            self.last_nonfinite_slots = []
             self.last_starved_slots = []
-            return {}
+            return None
         if chaos.enabled():
             chaos.fire(chaos.DECODE_WAVE,
                        active=int(np.count_nonzero(active_now)))
@@ -822,29 +968,50 @@ class ServingEngine:
             active_now = self._prepare_wave(active_now)
         self._acc("wave.blocks", ev)
         if not active_now.any():
-            self.last_nonfinite_slots = []
-            return {}
+            return None
         with RecordEvent("serving/wave/stage", pid=pid) as ev:
             args = self._wave_args(active_now, self._wave_poison(),
                                    self._key)
         self._acc("wave.stage", ev)
         with RecordEvent("serving/wave/dispatch", pid=pid) as ev:
-            tok, pos, finite, self._caches, self._key = \
-                self._decode_wave(*args)
-        self._dispatched("wave.dispatch", ev)
-        with RecordEvent("serving/wave/wait", pid=pid) as ev:
-            tok = np.asarray(tok)
-            finite = np.asarray(finite)
+            read, self._caches, self._lane_tok, self._lane_pos, \
+                self._key = self._decode_wave(*args)
+        self._dispatched("wave.dispatch", ev, read)
+        return self._ticket(np.flatnonzero(active_now), read=read)
+
+    def _ticket(self, lanes, advance=1, **kw):
+        """The ticket of a wave over `lanes` that was just dispatched;
+        their mirrored positions move on by `advance`."""
+        self.slot_pos[lanes] += advance
+        full = lanes[self.slot_pos[lanes] >= self.max_len]
+        return WaveTicket(lanes, self._slot_epoch[lanes].copy(),
+                          frozenset(full.tolist()), **kw)
+
+    def collect_wave(self, ticket):
+        """Read a dispatched wave (`serving/wave/wait`: the one blocking
+        read). Returns {slot: token} for the wave's lanes that produced
+        finite logits and whose slot still serves the request it served
+        at dispatch: a slot retired, or armed for another request, since
+        then drops its entry (a lane that ended on a token's VALUE has
+        by then run one step more than its request had use for). Lanes
+        whose logits went non-finite are excluded, frozen in-program,
+        and listed in `last_nonfinite_slots` for the scheduler to retire
+        (finish_reason "error")."""
+        if ticket.tokens is not None:
+            return ticket.tokens
+        with RecordEvent("serving/wave/wait", pid=self.trace_pid) as ev:
+            tok, finite = jax.device_get(ticket.read)
         self._read_back("wave.wait", ev)
-        # a lane whose logits went non-finite is frozen in-program; the
-        # caller must retire it before the next wave
-        ok = active_now & finite
-        self.slot_pos[ok] += 1
+        lanes = ticket.lanes[self._slot_epoch[ticket.lanes]
+                             == ticket.epochs]
+        finite = finite[lanes] != 0
+        ok, bad = lanes[finite], lanes[~finite]
+        # a lane whose logits went non-finite is frozen in-program (it
+        # did not advance); the caller must retire it
+        self.slot_pos[bad] -= 1
         self.slot_tok[ok] = tok[ok]
-        self.last_nonfinite_slots = np.flatnonzero(
-            active_now & ~finite).tolist()
-        lanes = np.flatnonzero(ok)
-        return dict(zip(lanes.tolist(), tok[lanes].tolist()))
+        self.last_nonfinite_slots = bad.tolist()
+        return dict(zip(ok.tolist(), tok[ok].tolist()))
 
     def _wave_poison(self):
         """[S] bool of lanes whose logits the chaos harness poisons in
@@ -873,8 +1040,7 @@ class ServingEngine:
             self._slot_bias_dev = jax.device_put(self._slot_bias)
             self._bias_uploads += 1
         lanes = pack_lanes(
-            tables, tok=self.slot_tok, pos=self.slot_pos,
-            active=active_now, sample=self.slot_sample,
+            tables, active=active_now, sample=self.slot_sample,
             top_k=self.slot_top_k, poison=poison, spec_len=spec_len,
             temp=self.slot_temp, top_p=self.slot_top_p)
         return lanes, self._slot_bias_dev
@@ -884,13 +1050,8 @@ class ServingEngine:
         packs its block tables in with the lanes). `key` is the
         engine's: the program splits it."""
         return (self._params, self._buffers, self._caches,
+                self._lane_tok, self._lane_pos,
                 *self._lane_args(active_now, poison), key)
-
-    def slot_full(self, slot):
-        """True when the slot's next write would fall past the cache
-        horizon (max_len - 1 is the last legal write) — the scheduler
-        must retire it (finish_reason 'length') before the next wave."""
-        return bool(self.slot_pos[slot] >= self.max_len)
 
     def retire_slot(self, slot):
         """Free a slot between waves. The cache region is left as-is:
@@ -898,6 +1059,7 @@ class ServingEngine:
         rewrites every position before the ks<=pos mask exposes it.
         Also aborts a mid-prefill admission parked on the slot."""
         self.slot_active[slot] = False
+        self._slot_epoch[slot] += 1
         self.slot_sample[slot] = False
         self.slot_temp[slot] = 1.0
         self.slot_top_k[slot] = 0

@@ -33,9 +33,13 @@ from ..utils import flight_recorder, monitor, telemetry
 #: `prefill.first_token` inside prefill_chunk); `token_masks` and
 #: `round_tail` are scheduler work outside the four; `state.reset` is
 #: the zeroing of a slot's recurrent record inside admission (models
-#: with slot state only); `unfed` (seconds
-#: from a blocking read of a program's output to the next program
-#: dispatch) overlaps the others.
+#: with slot state only); `unfed` (seconds from the host's seeing the
+#: device with nothing queued, at a read or a round's end that finds the
+#: newest program finished, to the next program dispatch) overlaps the
+#: others. A
+#: round reads the wave BEFORE the one it dispatched, so `wave.wait` and
+#: `prefill.first_token` are seconds the host waited with work queued
+#: behind what it read, except in a round that holds the pipeline empty.
 PHASES = ("admission", "prefill_chunk", "decode_wave", "host_dispatch")
 
 # legacy stat-registry keys (monitor.stat_get / all_stats)
@@ -198,6 +202,11 @@ class ServingMetrics:
             "serving_tpot_seconds", buckets=TPOT_BUCKETS)
         self._active_slot_waves = 0
         self._total_slot_waves = 0
+        # waves put on the device's queue, and those of them that went
+        # out while the wave before was still unread (the scheduler's
+        # one-deep pipeline was full)
+        self._waves_dispatched = 0
+        self._waves_dispatched_ahead = 0
         self._tokens = 0
         self._queue_peak = 0
         self._first_token_time = None
@@ -266,14 +275,17 @@ class ServingMetrics:
         monitor.stat_add(PREFILLS)
         _PREFILLS.inc()
 
-    def on_wave(self, n_active):
-        """One dispatched decode wave over `n_active` lanes."""
+    def on_wave(self, n_active, ahead=False):
+        """One dispatched decode wave over `n_active` lanes; `ahead`:
+        it went out before the previous wave's tokens were read."""
         monitor.stat_add(DECODE_WAVES)
         _WAVES.inc()
         _SLOTS_ACTIVE.set(int(n_active))
         with self._lock:
             self._active_slot_waves += int(n_active)
             self._total_slot_waves += self.num_slots
+            self._waves_dispatched += 1
+            self._waves_dispatched_ahead += bool(ahead)
 
     def on_spec(self, proposed, accepted):
         """One speculative wave's draft economics (scheduler-reported:
@@ -402,6 +414,8 @@ class ServingMetrics:
             bias_uploads = self._bias_uploads
             spec_p, spec_a = self._spec_proposed, self._spec_accepted
             spec_w = self._spec_waves
+            waves, waves_ahead = (self._waves_dispatched,
+                                  self._waves_dispatched_ahead)
         return {
             "requests_completed": self._latency.count(),
             "tokens_generated": tokens,
@@ -466,4 +480,9 @@ class ServingMetrics:
             # the interpreter's collector: seconds it held the process and
             # its collections (process totals; a reader takes the delta)
             **telemetry.gc_totals(),
+            # decode waves dispatched, and those dispatched before the
+            # wave before them was read (serving/scheduler.py: a round
+            # dispatches first and reads the last wave afterwards)
+            "waves_dispatched": waves,
+            "waves_dispatched_ahead": waves_ahead,
         }

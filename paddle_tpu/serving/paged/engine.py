@@ -24,11 +24,14 @@ compile-once discipline — table entries are VALUES, not shapes):
     complete in one step, and chunks fully covered by prefix-cache hits
     are skipped outright.
 
-Block bookkeeping is host-authoritative like the rest of the slot
-state (numpy, packed with the lanes into a wave's one small argument:
-serving/engine.py says what a stage may and may not do): the table
-upload is `S * nblk` int32 per wave. Allocation happens
-between waves; a wave whose lane cannot get a block (pool exhausted) is
+Block bookkeeping is host-authoritative like the rest of what the host
+decides (numpy, packed with the lanes into a wave's one small argument:
+serving/engine.py says what a stage may and may not do, and that a
+lane's token and position stay on the device): the table upload is
+`S * nblk` int32 per wave. Allocation happens before a wave is
+dispatched, by the host's mirror of the positions, which does not wait
+for the last wave's tokens; a wave whose lane cannot get a block (pool
+exhausted) is
 excluded from that wave and reported in `last_starved_slots` — the
 scheduler preempts it by recompute (requeue with prompt + generated
 tokens; the freed blocks' prefix hashes make the re-prefill mostly
@@ -45,9 +48,9 @@ from ...utils import chaos, telemetry
 from ...utils.profiler import RecordEvent
 from .. import blackbox
 from ..metrics import MODEL_COUNTS
-from ..engine import (ServingEngine, _filter_top_k_top_p, _raw,
-                      _select_first_token, _select_wave_tokens,
-                      unpack_lanes, unpack_prompt)
+from ..engine import (ServingEngine, WaveTicket, _filter_top_k_top_p, _raw,
+                      _select_first_token, _select_wave_tokens, arm_lane,
+                      unpack_lanes, unpack_prompt, wave_read)
 from .block_pool import BlockPool, BlockPoolExhausted
 
 #: block-level KV handoff payload schema version (export_slot_kv /
@@ -157,6 +160,7 @@ class PagedServingEngine(ServingEngine):
         self.prefix_sharing = bool(prefix_sharing) and not self.slot_state
         self.block_pool = BlockPool(num_blocks, self.block_size)
         self._copy_fn = None
+        self._set_lane_fn = None
         self._handoff_gather_fn = None
         self._handoff_scatter_fn = None
         super().__init__(model, num_slots=num_slots, max_len=max_len,
@@ -229,7 +233,7 @@ class PagedServingEngine(ServingEngine):
 
         chunk_len = self.prefill_chunk_len
 
-        def decode_wave(p, b, caches, lanes, bias, key):
+        def decode_wave(p, b, caches, lane_tok, lane_pos, lanes, bias, key):
             key, sub = jax.random.split(key)
             tables, a = unpack_lanes(lanes)
             # the scope pins this engine's kernel at TRACE time: the
@@ -237,16 +241,17 @@ class PagedServingEngine(ServingEngine):
             live = {"active": a["active"]} if slot_state else {}
             with paged_attention.kernel_scope(kern):
                 out, _ = model.functional_call(
-                    p, b, a["tok"][:, None], caches, a["pos"],
+                    p, b, lane_tok[:, None], caches, lane_pos,
                     method="decode_step", block_tables=tables, **live)
             logits, new_caches = out
             lo = _raw(logits)[:, 0, :].astype(jnp.float32)
             nxt, new_pos, finite = _select_wave_tokens(
-                lo, a["tok"], a["pos"], a["active"], a["sample"],
+                lo, lane_tok, lane_pos, a["active"], a["sample"],
                 a["temp"], a["top_k"], a["top_p"], bias, a["poison"], sub)
-            return nxt, new_pos, finite, new_caches, key
+            return wave_read(nxt, finite), new_caches, nxt, new_pos, key
 
-        def prefill_chunk(p, b, caches, prompt, bias, key):
+        def prefill_chunk(p, b, caches, lane_tok, lane_pos, prompt, bias,
+                          key):
             key, sub = jax.random.split(key)
             table, chunk, a = unpack_prompt(prompt, chunk_len)
             # the request's slot goes to a model with slot state and to
@@ -259,12 +264,14 @@ class PagedServingEngine(ServingEngine):
                     valid_len=a["valid"], frontier=a["frontier"], **where)
             logits, new_caches = out
             # frontier logits [1, 1, V]: only the FINAL chunk's value is
-            # consumed on host; earlier chunks compute a [V] row that is
-            # simply ignored (static shapes beat a conditional head)
+            # consumed (it arms the slot's lane, and the host reads it);
+            # earlier chunks compute a [V] row that is simply ignored
+            # (static shapes beat a conditional head)
             lo = _raw(logits)[0, 0].astype(jnp.float32)
             first = _select_first_token(lo, a["sample"], a["temp"],
                                         a["top_k"], a["top_p"], bias, sub)
-            return first, new_caches, key
+            return (first, new_caches,
+                    *arm_lane(lane_tok, lane_pos, a, first), key)
 
         def state_reset(caches, slot):
             """Zero one slot's record in every state array (a small
@@ -277,23 +284,25 @@ class PagedServingEngine(ServingEngine):
 
         self._decode_wave_fn = decode_wave
         self._prefill_fn = prefill_chunk
-        self._program_donate_argnums = (2,)
+        self._program_donate_argnums = (2, 3, 4)
+        self._prefill_donate_argnums = self._program_donate_argnums
         if slot_state:
             self._state_reset = (telemetry.instrument_jit(
                 jax.jit(state_reset, donate_argnums=(0,)),
                 "paged_state_reset") if self._jit else state_reset)
 
         if self._jit:
-            # the block pools are donated exactly like the dense cache:
-            # the engine always replaces its cache reference with the
-            # program output, so XLA updates the pool in place
+            # the block pools (and the lane state) are donated exactly
+            # like the dense cache: the engine always replaces its
+            # reference with the program output, so XLA updates the pool
+            # in place
             self._decode_wave = telemetry.instrument_jit(
                 jax.jit(decode_wave,
                         donate_argnums=self._program_donate_argnums),
                 "paged_decode_wave")
             self._prefill = telemetry.instrument_jit(
                 jax.jit(prefill_chunk,
-                        donate_argnums=self._program_donate_argnums),
+                        donate_argnums=self._prefill_donate_argnums),
                 "paged_prefill_chunk")
         else:
             self._decode_wave = decode_wave
@@ -387,9 +396,12 @@ class PagedServingEngine(ServingEngine):
         }
 
     def prefill_step(self, slot):
-        """Run ONE chunk of the slot's staged prompt. Returns the
-        request's first generated token when the final chunk ran, None
-        while chunks remain (decode waves continue in between)."""
+        """DISPATCH one chunk of the slot's staged prompt and return,
+        without reading anything. True when that was the final chunk
+        (the slot is armed and rides the next wave; its first token is
+        read by `collect_first_tokens`, one program or more after it was
+        made), False while chunks remain (decode waves continue in
+        between)."""
         st = self._pending_prefill[slot]
         pid = self.trace_pid
         with RecordEvent("serving/prefill/stage", pid=pid) as ev:
@@ -418,7 +430,7 @@ class PagedServingEngine(ServingEngine):
             sampling = st["sampling"]
             args = (*self._prefill_chunk_args(slot),
                     *self._prompt_args(slot, chunk, c0, valid, frontier,
-                                       sampling, self._tables[slot]))
+                                       sampling, self._tables[slot], last))
             counts = self._model_counts
             counts["moe_picks"] += valid * self._picks_per_token
             counts["prefill_tokens"] += valid
@@ -430,8 +442,9 @@ class PagedServingEngine(ServingEngine):
                                                   self.blocks_per_slot))
         self._acc("prefill.stage", ev)
         with RecordEvent("serving/prefill/dispatch", pid=pid) as ev:
-            first, self._caches, self._key = self._prefill(*args)
-        self._dispatched("prefill.dispatch", ev)
+            first, self._caches, self._lane_tok, self._lane_pos, \
+                self._key = self._prefill(*args)
+        self._dispatched("prefill.dispatch", ev, first)
         # full prompt blocks written by this chunk enter the prefix
         # cache — only now, so a concurrent admission can never share a
         # block whose content is not on the device yet
@@ -445,13 +458,10 @@ class PagedServingEngine(ServingEngine):
                 st["next_hash"] += 1
         st["next"] = c0 + C
         if not last:
-            return None
+            return False
         del self._pending_prefill[slot]
-        with RecordEvent("serving/prefill/first_token", pid=pid) as ev:
-            first = int(np.asarray(first))
-        self._read_back("prefill.first_token", ev)
-        self._arm_slot(slot, first, n, sampling)
-        return first
+        self._armed(slot, first, n, sampling)
+        return True
 
     def prefill_chunk_index(self, slot):
         st = self._pending_prefill[slot]
@@ -470,10 +480,9 @@ class PagedServingEngine(ServingEngine):
         waves. Accepts the full per-request sampling surface
         (do_sample, temperature, top_k, top_p, logit_bias)."""
         self.begin_prefill(slot, prompt, **kw)
-        while True:
-            first = self.prefill_step(slot)
-            if first is not None:
-                return first
+        while not self.prefill_step(slot):
+            pass
+        return self.collect_first_tokens()[slot]
 
     # -------------------------------------------------- block-level handoff
     def export_slot_kv(self, slot):
@@ -628,15 +637,31 @@ class PagedServingEngine(ServingEngine):
             self._tables[slot, :] = 0
             raise
         first = prompt[-1]
-        self._arm_slot(slot, first, n,
+        self._arm_slot(slot, n,
                        self._sampling_state(do_sample, temperature, top_k,
                                             top_p, logit_bias,
                                             dynamic_mask))
+        self._set_lane(slot, first, n)
         bb = blackbox.get_recorder()
         if bb is not None:
             bb.hop(kind="kv_import", slot=slot, digest=payload["digest"],
                    blocks=nblk, nbytes=payload.get("nbytes"), n_tokens=n)
         return first
+
+    def _set_lane(self, slot, tok, pos):
+        """Arm one lane on the device where no prefill chunk did (a
+        hand-off import): a tiny program of its own, compiled lazily
+        like the COW copy."""
+        if self._set_lane_fn is None:
+            def set_lane(lane_tok, lane_pos, row):
+                return (lane_tok.at[row[0]].set(row[1]),
+                        lane_pos.at[row[0]].set(row[2]))
+            self._set_lane_fn = (telemetry.instrument_jit(
+                jax.jit(set_lane, donate_argnums=(0, 1)), "paged_set_lane")
+                if self._jit else set_lane)
+        self._lane_tok, self._lane_pos = self._set_lane_fn(
+            self._lane_tok, self._lane_pos, np.int32([slot, tok, pos]))
+        self.slot_tok[slot] = tok
 
     # ------------------------------------------------------------- waves
     def _prepare_wave(self, active_now):
@@ -697,6 +722,7 @@ class PagedServingEngine(ServingEngine):
             self._model_counts["mla_rows_attended"] += int(
                 np.sum(self.slot_pos[np.asarray(active_now, bool)] + 1))
         return (self._params, self._buffers, self._caches,
+                self._lane_tok, self._lane_pos,
                 *self._lane_args(active_now, poison, tables), key)
 
     # ----------------------------------------------------- copy-on-write
@@ -903,7 +929,14 @@ class SpeculativePagedEngine(PagedServingEngine):
     tail), and `paged_spec_prefill_chunk` (target + draft chunk
     prefill). Per-lane spec_len (horizon clamp, dynamic token-mask
     lanes) is a traced VALUE, not a shape.
+
+    Not `pipelined`: the accepted lengths have to reach the host before
+    the next wave (they roll blocks back and set its spans), so
+    `dispatch_wave` reads its wave and returns a ticket that holds the
+    result. The lane state is on the device all the same: the draft wave
+    reads it, the verify wave reads and returns it, the chunk arms it.
     """
+    pipelined = False
 
     def __init__(self, model, draft_model, spec_k=4, **kw):
         if draft_model is None:
@@ -955,7 +988,7 @@ class SpeculativePagedEngine(PagedServingEngine):
         model, draft, k = self.model, self.draft_model, self.spec_k
         kern, chunk_len = self.paged_kernel, self.prefill_chunk_len
 
-        def draft_wave(dp, db, caches, lanes, bias, key):
+        def draft_wave(dp, db, caches, lane_tok, lane_pos, lanes, bias, key):
             """k+1 draft decode steps in ONE executable: step j writes
             the fed token's K/V at pos+j and proposes the next; the
             final step is write-only (it commits d_k's K/V so a fully
@@ -966,9 +999,9 @@ class SpeculativePagedEngine(PagedServingEngine):
             draw from a chain of the subkey's own."""
             engine_key, key = jax.random.split(key)
             tables, a = unpack_lanes(lanes)
-            pos, spec_len, sample = a["pos"], a["spec_len"], a["sample"]
+            pos, spec_len, sample = lane_pos, a["spec_len"], a["sample"]
             tgt_caches, dr_caches = caches
-            cur = a["tok"]
+            cur = lane_tok
             toks, probs = [], []
             for j in range(k + 1):
                 tab_j = jnp.where((j <= spec_len)[:, None], tables,
@@ -993,8 +1026,8 @@ class SpeculativePagedEngine(PagedServingEngine):
             return (jnp.stack(toks, axis=1), jnp.stack(probs, axis=1),
                     (tgt_caches, dr_caches), engine_key)
 
-        def spec_verify(p, b, caches, lanes, bias, draft_toks,
-                        draft_probs, key):
+        def spec_verify(p, b, caches, lane_tok, lane_pos, lanes, bias,
+                        draft_toks, draft_probs, key):
             """Verify-once: ONE target forward scores all k+1 positions
             of every lane (the chunk program's own model call, at
             [S, k + 1] with every lane's start and span and no frontier),
@@ -1002,23 +1035,24 @@ class SpeculativePagedEngine(PagedServingEngine):
             key, sub = jax.random.split(key)
             tables, a = unpack_lanes(lanes)
             tgt_caches, dr_caches = caches
-            chunk = jnp.concatenate([a["tok"][:, None], draft_toks],
+            chunk = jnp.concatenate([lane_tok[:, None], draft_toks],
                                     axis=1)
             with paged_attention.kernel_scope(kern):
                 out, _ = model.functional_call(
                     p, b, chunk, tgt_caches, method="prefill_chunk",
-                    block_tables=tables, chunk_start=a["pos"],
+                    block_tables=tables, chunk_start=lane_pos,
                     valid_len=a["spec_len"] + 1)
             logits, tgt_caches = out
             lo = _raw(logits).astype(jnp.float32)       # [S, k+1, V]
             out_toks, n_emit, nxt, new_pos, finite = _spec_verify_tail(
-                lo, a["tok"], a["pos"], a["active"], a["sample"],
+                lo, lane_tok, lane_pos, a["active"], a["sample"],
                 a["temp"], a["top_k"], a["top_p"], bias, a["spec_len"],
                 draft_toks, draft_probs, a["poison"], sub)
-            return (out_toks, n_emit, nxt, new_pos, finite,
-                    (tgt_caches, dr_caches), key)
+            return (out_toks, n_emit, finite, (tgt_caches, dr_caches),
+                    nxt, new_pos, key)
 
-        def prefill_chunk(p, b, caches, dp, db, prompt, bias, key):
+        def prefill_chunk(p, b, caches, dp, db, lane_tok, lane_pos, prompt,
+                          bias, key):
             """The spec configuration's ONE prefill program: the chunk
             writes the TARGET pools (frontier logits select the first
             token, exactly the non-spec chunk) AND the DRAFT pools — a
@@ -1041,17 +1075,23 @@ class SpeculativePagedEngine(PagedServingEngine):
             lo = _raw(logits)[0, 0].astype(jnp.float32)
             first = _select_first_token(lo, a["sample"], a["temp"],
                                         a["top_k"], a["top_p"], bias, sub)
-            return first, (tgt_caches, dr_caches), key
+            return (first, (tgt_caches, dr_caches),
+                    *arm_lane(lane_tok, lane_pos, a, first), key)
 
         self._draft_wave_fn = draft_wave
         self._decode_wave_fn = spec_verify
         self._prefill_fn = prefill_chunk
-        self._program_donate_argnums = (2,)
+        # the verify wave's, as the plain wave's; the draft wave reads
+        # the lane state and leaves it to the verify, and the chunk takes
+        # the draft's weights between the caches and the lane state
+        self._program_donate_argnums = (2, 3, 4)
+        self._draft_donate_argnums = (2,)
+        self._prefill_donate_argnums = (2, 5, 6)
 
         if self._jit:
             self._draft_wave = telemetry.instrument_jit(
                 jax.jit(draft_wave,
-                        donate_argnums=self._program_donate_argnums),
+                        donate_argnums=self._draft_donate_argnums),
                 "paged_spec_draft_wave")
             self._decode_wave = telemetry.instrument_jit(
                 jax.jit(spec_verify,
@@ -1059,7 +1099,7 @@ class SpeculativePagedEngine(PagedServingEngine):
                 "paged_spec_verify")
             self._prefill = telemetry.instrument_jit(
                 jax.jit(prefill_chunk,
-                        donate_argnums=self._program_donate_argnums),
+                        donate_argnums=self._prefill_donate_argnums),
                 "paged_spec_prefill_chunk")
         else:
             self._draft_wave = draft_wave
@@ -1122,18 +1162,22 @@ class SpeculativePagedEngine(PagedServingEngine):
                 self._tables[s, needed:] = 0
                 self.block_pool.release(extra)
 
-    def decode_wave(self):
-        """One speculative wave: draft k, verify once, accept exactly.
-        Returns {slot: [tokens]} — 1..k+1 tokens per healthy lane (the
+    def dispatch_wave(self, skip=()):
+        """One speculative wave: draft k, verify once, accept exactly,
+        and READ (this engine is not `pipelined`). The ticket holds
+        {slot: [tokens]} — 1..k+1 tokens per healthy lane (the
         scheduler streams them in order and retires mid-batch on
         eos/budget/stop). Poisoned/non-finite lanes emit nothing, are
         listed in `last_nonfinite_slots`, and their speculation is
         rolled back with the rest."""
+        if len(skip):
+            raise ValueError("a speculative wave is read before the next "
+                             "is dispatched: no lane has a token in flight")
+        self.last_nonfinite_slots = []
         active_now = self.slot_active.copy()
         if not active_now.any():
-            self.last_nonfinite_slots = []
             self.last_starved_slots = []
-            return {}
+            return None
         if chaos.enabled():
             chaos.fire(chaos.DECODE_WAVE,
                        active=int(np.count_nonzero(active_now)))
@@ -1151,8 +1195,7 @@ class SpeculativePagedEngine(PagedServingEngine):
             active_now = self._prepare_wave(active_now)
         self._acc("wave.blocks", ev)
         if not active_now.any():
-            self.last_nonfinite_slots = []
-            return {}
+            return None
         with RecordEvent("serving/wave/stage", pid=pid) as ev:
             lanes, bias = self._lane_args(
                 active_now, self._wave_poison(),
@@ -1170,18 +1213,17 @@ class SpeculativePagedEngine(PagedServingEngine):
             draft_toks, draft_probs, self._caches, self._key = \
                 self._draft_wave(
                     self._draft_params, self._draft_buffers, self._caches,
-                    lanes, bias, self._key)
-            out_toks, n_emit, nxt, new_pos, finite, self._caches, \
-                self._key = self._decode_wave(
-                    self._params, self._buffers, self._caches, lanes,
-                    bias, draft_toks, draft_probs, self._key)
-        self._dispatched("wave.dispatch", ev)
+                    self._lane_tok, self._lane_pos, lanes, bias, self._key)
+            out_toks, n_emit, finite, self._caches, self._lane_tok, \
+                self._lane_pos, self._key = self._decode_wave(
+                    self._params, self._buffers, self._caches,
+                    self._lane_tok, self._lane_pos, lanes, bias,
+                    draft_toks, draft_probs, self._key)
+        self._dispatched("wave.dispatch", ev, finite)
         with RecordEvent("serving/wave/wait", pid=pid) as ev:
-            out_toks = np.asarray(out_toks)
-            n_emit = np.asarray(n_emit)
-            nxt = np.asarray(nxt)
-            new_pos = np.asarray(new_pos)
-            finite = np.asarray(finite)
+            # the lane state is read before the next program takes it
+            out_toks, n_emit, finite, nxt, new_pos = jax.device_get(
+                (out_toks, n_emit, finite, self._lane_tok, self._lane_pos))
         self._read_back("wave.wait", ev)
         # a lane whose logits went non-finite is frozen in-program; the
         # caller must retire it before the next wave
@@ -1200,7 +1242,8 @@ class SpeculativePagedEngine(PagedServingEngine):
         # rejected-token blocks go back NOW, poisoned lanes included —
         # the pool must never hold blocks for tokens that don't exist
         self._rollback_spec_blocks(waved)
-        return out
+        return self._ticket(np.flatnonzero(active_now), advance=0,
+                            tokens=out)
 
     def _health(self):
         h = super()._health()

@@ -9,7 +9,24 @@ paged engine runs one CHUNK per round, so a long prompt's admission is
 folded between decode waves and never stalls the other lanes (same
 compiled programs throughout). Retirement (EOS / max_tokens / cache
 horizon / timeout) frees slots between waves and the freed slot is
-refilled in the same step() — a slot never idles while work is queued.
+refilled by the next round's admission.
+
+A round is a ONE-DEEP PIPELINE of device programs: it puts its prefill
+chunks and its wave on the device's queue first and reads the PREVIOUS
+wave's tokens afterwards, so the host's whole share of a round (emit,
+retire, journal, the tail, the next admission and staging) runs while
+the device works. A token is therefore read one program after it is
+made. Who rides wave k is decided without wave k-1's tokens: a lane
+whose budget (`max_tokens`) or cache horizon is met by a token still in
+flight is left out (the host counts), and a lane that ends on a token's
+VALUE (eos, a stop sequence, non-finite logits) is found one wave late:
+the step it ran too many wrote one K/V row into a block it still owned
+and produced a token that is dropped. The pipeline is held empty (the
+round reads each program before it dispatches the next: `_may_dispatch_
+ahead`) where the next wave depends on the last one's tokens: a dynamic
+token mask, the speculative engine, a prefill-role replica (it exports
+what it just read), a draining server; and it is emptied before a
+preemption and before a failed dispatch is retried.
 
 Paged-engine capacity (serving/paged) is handled here too: an exhausted
 block pool at admission queues the head request behind the blocks it is
@@ -57,6 +74,8 @@ import collections
 import threading
 import time
 
+import numpy as np
+
 from ..utils import flight_recorder, profiler, telemetry
 from ..utils.profiler import RecordEvent
 from . import blackbox
@@ -74,6 +93,15 @@ from .slo import as_engine as _slo_as_engine
 #: blocks, zero prefill-chunk programs run); "unified" is the classic
 #: do-both replica.
 ROLES = ("prefill", "decode", "unified")
+
+
+#: A wave the scheduler dispatched and has not collected: the engine's
+#: ticket, the request each of its lanes served at dispatch ({slot:
+#: request}; None with no journal to name them in), and what the
+#: journal's `wave` event says of the dispatch (its round, the lanes it
+#: starved, a speculative wave's counts).
+_Wave = collections.namedtuple(
+    "_Wave", "ticket members round starved spec_proposed spec_accepted")
 
 
 class Scheduler:
@@ -146,6 +174,14 @@ class Scheduler:
         self._lock = threading.Lock()        # queue + lifecycle flags
         self._wave_lock = threading.Lock()   # one step() at a time
         self._slot_req = [None] * engine.num_slots
+        # tokens the slot's request may still have made: its `max_tokens`
+        # less those it had at admission, less one for every program
+        # dispatched since that makes it one (its last chunk, a wave it
+        # rides), read or not
+        self._budget = np.zeros((engine.num_slots,), np.int64)
+        # waves dispatched and not collected, oldest first: at most one
+        # between rounds (`_Wave`)
+        self._waves = collections.deque()
         self._draining = False
         self._degraded = False
         self.last_error = None
@@ -176,6 +212,8 @@ class Scheduler:
         # the wave counter names waves in `wave` events
         self._round = 0
         self._wave_seq = 0
+        # perf_counter() when the last round ended
+        self._round_end = None
 
     @property
     def trace_pid(self):
@@ -361,8 +399,7 @@ class Scheduler:
                 self.engine.set_slot_bias(slot, self._combined_bias(req))
             except Exception as e:   # noqa: BLE001 — client code
                 self.last_error = e
-                self.engine.retire_slot(slot)
-                self._slot_req[slot] = None
+                self._free_slot(slot)
                 self._fault("token_mask_error", action="request_failed",
                             request=req, slot=slot, error=e)
                 req._fail(e)
@@ -485,14 +522,21 @@ class Scheduler:
             self.engine.set_slot_trace(slot, req.trace_id,
                                        self.trace_pid)
             self._slot_req[slot] = req
+            self._budget[slot] = req.max_tokens - len(req.output_tokens)
+
+    def _free_slot(self, slot):
+        """The slot's request is leaving it (finished, failed, evicted):
+        free the engine's slot, with its blocks. Whatever is still in
+        flight for it is dropped when it is read."""
+        self.engine.retire_slot(slot)
+        self._slot_req[slot] = None
 
     def _prefill_fault(self, req, slot):
         """Shared admission/chunk fault barrier: fail ONLY this request,
         free the slot, and escalate to degradation after
         `prefill_fail_limit` consecutive distinct-request failures.
         Returns True when the engine degraded (stop the round)."""
-        self.engine.retire_slot(slot)      # frees pending state + blocks
-        self._slot_req[slot] = None
+        self._free_slot(slot)              # frees pending state + blocks
         self._prefill_fail_streak += 1
         escalate = self._prefill_fail_streak >= self.prefill_fail_limit
         self._fault("prefill_error",
@@ -506,10 +550,11 @@ class Scheduler:
         return False
 
     def _advance_prefills(self):
-        """Run one prefill step per mid-admission slot (ONE chunk on a
-        paged engine; the whole bucket on the dense engine). Slots whose
-        prefill completed get their first token and become active for
-        this round's decode wave. Returns True when a fault escalated to
+        """Dispatch one prefill step per mid-admission slot (ONE chunk
+        on a paged engine; the whole bucket on the dense engine), back to
+        back, reading nothing. Slots whose prefill completed are armed
+        and ride this round's decode wave; their first tokens are read by
+        `_emit_first_tokens`. Returns True when a fault escalated to
         degradation."""
         for slot in self.engine.prefilling_slots():
             req = self._slot_req[slot]
@@ -518,8 +563,7 @@ class Scheduler:
                 # burning chunk programs (and finally emit a token) on a
                 # request that already expired; same semantics as the
                 # queue-pop timeout check
-                self.engine.retire_slot(slot)
-                self._slot_req[slot] = None
+                self._free_slot(slot)
                 req._finish("timeout")
                 self._complete(req)
                 continue
@@ -529,7 +573,7 @@ class Scheduler:
                 chunk=self.engine.prefill_chunk_index(slot))
             try:
                 with ev:
-                    first = self.engine.prefill_step(slot)
+                    done = self.engine.prefill_step(slot)
             except Exception as e:   # noqa: BLE001 — fault barrier
                 self.last_error = e
                 if self._prefill_fault(req, slot):
@@ -538,21 +582,42 @@ class Scheduler:
             finally:
                 self._phase("prefill_chunk", ev)
             self._prefill_fail_streak = 0
-            if first is None:
-                continue             # mid-prefill: decode waves go on
+            if done:                 # else mid-prefill: decode waves go on
+                self._budget[slot] -= 1
+        return False
+
+    def _emit_first_tokens(self):
+        """Read the first tokens of the prompts whose last chunk was
+        dispatched (one blocking read: with a wave queued behind the
+        chunks the device works on through it) and stream them. Each was
+        made one program or more ago; a request that ends on it (eos,
+        `max_tokens` 1) retires here, and was left out of the wave
+        dispatched meanwhile only if the host could count that."""
+        if not self.engine.first_tokens_pending:
+            return
+        self._round_worked = True
+        with RecordEvent("serving/prefill", pid=self.trace_pid,
+                         first_tokens=self.engine.first_tokens_pending
+                         ) as ev:
+            firsts = self.engine.collect_first_tokens()
+        self._phase("prefill_chunk", ev)
+        now = time.monotonic()
+        for slot, first in firsts.items():
+            req = self._slot_req[slot]
             self.metrics.on_prefill()
             # prev_t is non-None only for a preempted-then-resumed
             # request: its re-prefill token IS an inter-token gap (the
             # preemption stall is real TPOT the client observed)
             prev_t = req.last_token_time
             req._emit(first)
-            self.metrics.on_token(time.monotonic(), prev_t=prev_t)
-            self._maybe_retire(slot, first)
+            self.metrics.on_token(now, prev_t=prev_t)
+            # a prompt leaves room to decode (validate_prompt): the
+            # horizon is never met by a first token
+            self._maybe_retire(slot, first, full=False)
             if self.role == "prefill" and self._slot_req[slot] is not None:
                 # prefill-role epilogue: this replica never decodes —
                 # package the populated KV blocks for a decode replica
                 self._export_handoff(slot)
-        return False
 
     def _export_handoff(self, slot):
         """Export the slot's populated KV blocks (the prefill just
@@ -570,8 +635,7 @@ class Scheduler:
             self.last_error = e
             self._fault("handoff_error", action="export_failed",
                         request=req, slot=slot, error=e)
-        self.engine.retire_slot(slot)
-        self._slot_req[slot] = None
+        self._free_slot(slot)
         with self._lock:
             self._handoff_ready.append((req, payload))
 
@@ -585,15 +649,17 @@ class Scheduler:
         return out
 
     # ---------------------------------------------------------- wave loop
-    def _maybe_retire(self, slot, last_token, check_length=True):
+    def _maybe_retire(self, slot, last_token, full):
         """Retire the slot if its request just finished: EOS (even on the
         very first prefill-produced token), a stop sequence, token
-        budget, cache horizon, or wall-clock timeout. check_length=False
-        suppresses the horizon check for the NON-final tokens of a
-        speculative batch: slot_pos is already advanced for the whole
-        batch, and only its last token is the one written at the
-        horizon — retiring on an earlier one would drop tokens the
-        plain engine delivers."""
+        budget, cache horizon, or wall-clock timeout. `full`: whether
+        this token is the one that filled the slot to the horizon, by
+        the wave that made it (`WaveTicket.full`: the engine's mirror may
+        have counted a later wave since). False for the NON-final tokens
+        of a speculative batch: the position is already advanced for the
+        whole batch, and only its last token is the one written at the
+        horizon — retiring on an earlier one would drop tokens the plain
+        engine delivers."""
         req = self._slot_req[slot]
         reason = None
         if req.eos_token_id is not None and last_token == req.eos_token_id:
@@ -602,13 +668,12 @@ class Scheduler:
             reason = "stop"
         elif len(req.output_tokens) >= req.max_tokens:
             reason = "max_tokens"
-        elif check_length and self.engine.slot_full(slot):
+        elif full:
             reason = "length"
         elif req._timed_out():
             reason = "timeout"
         if reason is not None:
-            self.engine.retire_slot(slot)
-            self._slot_req[slot] = None
+            self._free_slot(slot)
             req._finish(reason)
             self._complete(req)
 
@@ -635,23 +700,40 @@ class Scheduler:
                       slot=slot,
                       error=None if error is None else repr(error))
 
-    def _run_wave_with_retry(self, lanes):
-        """The decode wave behind a bounded-exponential-backoff retry.
-        Returns the wave's {slot: token} dict, or None after degrading
-        (budget exhausted). The engine raises BEFORE consuming its key
-        or the donated cache, so a retried wave replays exactly; an
-        error from inside the compiled call may have invalidated the
-        donated cache, in which case the retry fails too and the budget
-        runs out — degradation, not an infinite loop."""
+    def _spent_lanes(self):
+        """The active slots with no token left to make: the programs
+        dispatched for the request so far meet its `max_tokens`, or fill
+        its slot to the horizon. The host knows both by counting, without
+        the tokens' values. Empty when nothing is in flight: such a slot
+        has been retired."""
+        eng = self.engine
+        return np.flatnonzero(eng.slot_active & (
+            (self._budget <= 0) | (eng.slot_pos >= eng.max_len)))
+
+    def _dispatch_wave_with_retry(self):
+        """The decode wave's dispatch behind a bounded-exponential-
+        backoff retry. Returns None after degrading (budget exhausted),
+        else the lanes the wave starved of a block (the wave, if any
+        lane was left to decode, is the newest of `_waves`; no program
+        goes out when every active lane's last token is in flight). The
+        engine raises BEFORE consuming its key or the donated cache, so
+        a retried dispatch replays exactly, and it goes out with nothing
+        in flight: what was is read first. An error from inside the
+        compiled call may have invalidated the donated cache, in which
+        case the retry fails too and the budget runs out — degradation,
+        not an infinite loop."""
         delay = self.retry_backoff_s
         for attempt in range(self.wave_retries + 1):
+            spent = self._spent_lanes()
+            lanes = len(self.engine.active_slots()) - len(spent)
+            if not lanes:
+                return []
             try:
                 with RecordEvent("serving/decode_wave",
                                  pid=self.trace_pid, round=self._round,
                                  lanes=lanes) as ev:
-                    toks = self.engine.decode_wave()
+                    ticket = self.engine.dispatch_wave(spent)
                 self._phase("decode_wave", ev)
-                return toks
             except Exception as e:   # noqa: BLE001 — fault barrier
                 self.last_error = e
                 self._fault("wave_error",
@@ -661,10 +743,114 @@ class Scheduler:
                 if attempt >= self.wave_retries:
                     break
                 self.metrics.on_wave_retry()
+                if not self._collect_waves():
+                    return None
+                self._emit_first_tokens()
                 time.sleep(delay)
                 delay *= 2
+                continue
+            if ticket is not None:
+                self._wave_dispatched(ticket)
+            return self.engine.last_starved_slots
         self._degrade()
         return None
+
+    def _wave_dispatched(self, ticket):
+        """Count a wave that went out and keep what its collect needs:
+        the request each lane serves now, not then."""
+        waved = len(ticket.lanes)
+        self.metrics.on_wave(waved, ahead=bool(self._waves))
+        self._record_spec_wave(waved)
+        self._budget[ticket.lanes] -= 1
+        # the journal names requests: taken now, while the slots hold them
+        members = (None if blackbox.get_recorder() is None else
+                   {s: self._slot_req[s] for s in ticket.lanes.tolist()})
+        self._waves.append(_Wave(
+            ticket, members, self._round,
+            sorted(self.engine.last_starved_slots) or None,
+            getattr(self.engine, "last_spec_proposed", None),
+            getattr(self.engine, "last_spec_accepted", None)))
+
+    def _collect_waves(self, keep=0):
+        """Read the dispatched waves, oldest first, down to the newest
+        `keep`, and stream their tokens. False when a read failed and
+        the engine degraded."""
+        while len(self._waves) > keep:
+            if not self._collect_wave(self._waves.popleft()):
+                return False
+        return True
+
+    def _collect_wave(self, wave):
+        """One wave's tokens, read (`serving/wave/wait`) and handed out:
+        the journal's `wave` event with the membership taken at
+        dispatch, the poisoned lanes retired, every other lane's token
+        emitted and its request retired if that token ended it. A lane
+        whose slot was retired (or re-admitted) since the dispatch has no
+        entry: its request ended on an earlier token."""
+        self._round_worked = True
+        try:
+            with RecordEvent("serving/decode_wave", pid=self.trace_pid,
+                             round=wave.round, collect=True) as ev:
+                toks = self.engine.collect_wave(wave.ticket)
+        except Exception as e:   # noqa: BLE001 — fault barrier: the
+            # program consumed its donated inputs, so nothing can be
+            # dispatched again
+            self.last_error = e
+            self._fault("wave_error", action="degrade", error=e)
+            self._degrade()
+            return False
+        self._phase("decode_wave", ev)
+        bb = blackbox.get_recorder()
+        if bb is not None and toks and wave.members is not None:
+            # membership BEFORE the dispatch loop below retires finished
+            # slots (after it, the slot->request map may be cleared)
+            self._wave_seq += 1
+            bb.wave(
+                self._wave_seq,
+                members=[{"slot": s,
+                          "request_id": wave.members[s].request_id,
+                          "tokens": (len(t) if isinstance(t, list)
+                                     else 1)}
+                         for s, t in sorted(toks.items())],
+                starved=wave.starved,
+                nonfinite=sorted(self.engine.last_nonfinite_slots)
+                or None,
+                spec_proposed=wave.spec_proposed,
+                spec_accepted=wave.spec_accepted,
+                round=wave.round, replica=self._replica_ord())
+        # fused-sentinel fallout: retire ONLY the poisoned lanes —
+        # their requests resolve with "error", healthy neighbours
+        # stream on token-identically (proven in chaos_serving)
+        for slot in self.engine.last_nonfinite_slots:
+            req = self._slot_req[slot]
+            self._free_slot(slot)
+            self._fault("nonfinite", action="slot_retired",
+                        request=req, slot=slot)
+            req._fail("non-finite logits in decode wave")
+            self._complete(req)
+        now = time.monotonic()
+        with RecordEvent("serving/host_dispatch",
+                         pid=self.trace_pid) as ev:
+            for slot, emitted in toks.items():
+                req = self._slot_req[slot]
+                full = slot in wave.ticket.full
+                # a speculative wave emits a BATCH per lane; stream
+                # it in order and stop at the first retirement
+                # (eos/stop/budget/horizon) — the batch's rejected
+                # tail past that point is dropped, exactly what the
+                # non-speculative wave would never have generated
+                if not isinstance(emitted, list):
+                    emitted = [emitted]
+                for j, tok in enumerate(emitted):
+                    prev_t = req.last_token_time
+                    req._emit(tok)
+                    self.metrics.on_token(now, prev_t=prev_t)
+                    self._maybe_retire(
+                        slot, tok, full and j == len(emitted) - 1)
+                    if self._slot_req[slot] is None:
+                        break
+        self._phase("host_dispatch", ev)
+        return True
 
     def _degrade(self):
         """Graceful degradation: the wave loop cannot make progress, so
@@ -680,11 +866,13 @@ class Scheduler:
             self.engine.set_health_state("degraded")
         self._fault("degraded", action="drain_and_reject",
                     error=self.last_error)
+        # what is in flight is never read: every request it served
+        # resolves here
+        self._waves.clear()
         for slot, req in enumerate(self._slot_req):
             if req is None:
                 continue
-            self.engine.retire_slot(slot)
-            self._slot_req[slot] = None
+            self._free_slot(slot)
             req._fail(f"engine degraded: {self.last_error!r}")
             self._complete(req)
         with self._lock:
@@ -709,7 +897,9 @@ class Scheduler:
         """Pull every accepted-but-unresolved request out of this
         scheduler WITHOUT resolving it, and stop accepting work. The
         fleet failover path calls this on a replica presumed DEAD, so
-        no engine call is made here. The router migrates from its OWN
+        no engine call is made here (a wave in flight is never read: its
+        tokens were handed to no one, and the migrated request makes them
+        again). The router migrates from its OWN
         live-request registry (serving/fleet/router.py scans _live —
         it must not trust a dead replica's bookkeeping); the returned
         list (in-slot first, then queued) is informational: operators
@@ -727,15 +917,21 @@ class Scheduler:
                 self._handoff_ready = []
             out = [req for req in self._slot_req if req is not None]
             self._slot_req = [None] * self.engine.num_slots
+            self._waves.clear()
             out.extend(parked)
             out.extend(queued)
         self.metrics.on_queue_depth(0)
         return out
 
     def step(self):
-        """One scheduling round: refill free slots from the queue, run
-        one batched decode wave, stream the tokens, retire finished
-        slots. Returns the number of requests still in flight or queued.
+        """One scheduling round: refill free slots from the queue,
+        dispatch the prefill chunks and one batched decode wave, THEN
+        read the previous round's wave, stream its tokens and retire the
+        slots they finished (the module comment: a token is read one
+        program after it is made, so a slot freed by a token's value is
+        refilled one round later than the token was made, and the round
+        after the last wave only reads). Returns the number of requests
+        still in flight or queued.
 
         Serialized by `_wave_lock`, so concurrent drivers (a run() loop
         in one thread, shutdown() in another) interleave whole rounds
@@ -801,8 +997,7 @@ class Scheduler:
         eviction unblocks (priority preemption) for the journal."""
         req = self._slot_req[slot]
         bb = blackbox.get_recorder()
-        self.engine.retire_slot(slot)          # frees the blocks
-        self._slot_req[slot] = None
+        self._free_slot(slot)                  # frees the blocks
         req.preemptions += 1
         cont = self._continuation(req)
         why = self.engine.validate_prompt(cont)
@@ -828,14 +1023,14 @@ class Scheduler:
                        replica=self._replica_ord())
         self._requeue_front(req)
 
-    def _preempt_starved(self):
+    def _preempt_starved(self, starved):
         """Pool-exhausted lanes (the wave excluded them): evict a lane
         by recompute so blocks free up. Which lane is a QoS decision —
         a lower-priority lane below the starved request goes first
         (_preemption_victim); otherwise the starved lane evicts itself
         (and the victim path leaves it armed to retry allocation at the
         next wave against the freed blocks)."""
-        for slot in self.engine.last_starved_slots:
+        for slot in starved:
             if self._slot_req[slot] is None:
                 continue     # already evicted as another lane's victim
                              # (or finished during this round's dispatch)
@@ -855,6 +1050,9 @@ class Scheduler:
         # counter must tick before ANY of this round's decisions
         self._round += 1
         eng = self.engine
+        # the device may have run dry since the last round ended, while
+        # this scheduler's caller had the thread
+        eng.poll_unfed(self._round_end)
         self._phases = {}
         self._round_worked = False
         pending = 0
@@ -885,7 +1083,27 @@ class Scheduler:
                 # an empty server is not a slow host: what passes until
                 # the next dispatch is not the host's doing
                 eng.drop_unfed()
+            else:
+                eng.poll_unfed()
+            self._round_end = time.perf_counter()
         return pending
+
+    def _may_dispatch_ahead(self):
+        """Whether this round may put its programs on the device's queue
+        before it reads the last wave's tokens. Not where the next wave
+        is a function of those tokens: a dynamic token mask on any
+        request in a slot (the next mask is computed from the token not
+        read yet), an engine that has to read each wave before the next
+        (speculative: the accepted lengths roll blocks back); not on a
+        prefill-role replica (it exports the slot it just read); not
+        while the server drains. The round then reads every program
+        before it dispatches the next: the same code, nothing in
+        flight."""
+        if not self.engine.pipelined or self._draining or \
+                self.role == "prefill":
+            return False
+        return not any(r is not None and r.token_mask is not None
+                       for r in self._slot_req)
 
     def _run_round(self):
         with RecordEvent("serving/admission", pid=self.trace_pid) as ev:
@@ -894,83 +1112,41 @@ class Scheduler:
         # captured BEFORE the advance: a prefill that admits, emits its
         # first token, and retires within one round still counts as a
         # working round for the pool sample below
-        prefilled = bool(self.engine.prefilling_slots())
-        self._round_worked = prefilled
+        self._round_worked = bool(self.engine.prefilling_slots())
+        ahead = self._may_dispatch_ahead()
+        if not ahead and not self._collect_waves():
+            return 0                         # degraded on a read
         if self._advance_prefills():
             return 0                         # degraded mid-advance
+        if not ahead:
+            self._emit_first_tokens()
         with RecordEvent("serving/token_masks", pid=self.trace_pid) as ev:
             self._refresh_token_masks()
         self._phase("token_masks", ev)
-        active = self.engine.active_slots()
-        if active:
+        waves_before = len(self._waves)
+        starved = []
+        if self.engine.active_slots():
             self._round_worked = True
-            toks = self._run_wave_with_retry(len(active))
-            if toks is None:                 # degraded: everything is
-                return 0                     # resolved, nothing pending
-            waved = len(active) - len(self.engine.last_starved_slots)
-            if waved > 0:     # all-starved rounds dispatch no program —
-                self.metrics.on_wave(waved)  # don't count phantom waves
-                self._record_spec_wave(waved)
-            bb = blackbox.get_recorder()
-            if bb is not None and toks:
-                # membership captured from `toks` BEFORE the dispatch
-                # loop below retires finished slots (after it, the
-                # slot->request map may already be cleared)
-                self._wave_seq += 1
-                bb.wave(
-                    self._wave_seq,
-                    members=[{"slot": s,
-                              "request_id": self._slot_req[s].request_id,
-                              "tokens": (len(t) if isinstance(t, list)
-                                         else 1)}
-                             for s, t in sorted(toks.items())
-                             if self._slot_req[s] is not None],
-                    starved=sorted(self.engine.last_starved_slots)
-                    or None,
-                    nonfinite=sorted(self.engine.last_nonfinite_slots)
-                    or None,
-                    spec_proposed=getattr(self.engine,
-                                          "last_spec_proposed", None),
-                    spec_accepted=getattr(self.engine,
-                                          "last_spec_accepted", None),
-                    round=self._round, replica=self._replica_ord())
-            # fused-sentinel fallout: retire ONLY the poisoned lanes —
-            # their requests resolve with "error", healthy neighbours
-            # stream on token-identically (proven in chaos_serving)
-            for slot in self.engine.last_nonfinite_slots:
-                req = self._slot_req[slot]
-                self.engine.retire_slot(slot)
-                self._slot_req[slot] = None
-                self._fault("nonfinite", action="slot_retired",
-                            request=req, slot=slot)
-                req._fail("non-finite logits in decode wave")
-                self._complete(req)
-            now = time.monotonic()
-            with RecordEvent("serving/host_dispatch",
-                             pid=self.trace_pid) as ev:
-                for slot, emitted in toks.items():
-                    req = self._slot_req[slot]
-                    # a speculative wave emits a BATCH per lane; stream
-                    # it in order and stop at the first retirement
-                    # (eos/stop/budget/horizon) — the batch's rejected
-                    # tail past that point is dropped, exactly what the
-                    # non-speculative wave would never have generated
-                    if not isinstance(emitted, list):
-                        emitted = [emitted]
-                    for j, tok in enumerate(emitted):
-                        prev_t = req.last_token_time
-                        req._emit(tok)
-                        self.metrics.on_token(now, prev_t=prev_t)
-                        self._maybe_retire(
-                            slot, tok, check_length=j == len(emitted) - 1)
-                        if self._slot_req[slot] is None:
-                            break
-            self._phase("host_dispatch", ev)
-            # AFTER the dispatch loop: a priority victim was in this
-            # wave — evicting it first would drop the token it just
-            # produced (starved lanes were never in `toks`, so they
-            # don't care about the ordering)
-            self._preempt_starved()
+            starved = self._dispatch_wave_with_retry()
+            if starved is None:
+                return 0          # degraded: everything is resolved
+        # this round's wave stays in flight while the host does its
+        # share; the one before it is read now. With no wave dispatched
+        # (no lane left to decode) whatever is in flight is read.
+        keep = int(ahead and len(self._waves) > waves_before)
+        if not self._collect_waves(keep):
+            return 0
+        self._emit_first_tokens()
+        if starved:
+            # an eviction requeues a request with the tokens it was
+            # handed: everything in flight is read first. AFTER the
+            # dispatch loop: a priority victim was in this wave —
+            # evicting it first would drop the token it just produced
+            # (starved lanes were never in it, so they don't care about
+            # the ordering)
+            if not self._collect_waves():
+                return 0
+            self._preempt_starved(starved)
         with RecordEvent("serving/round_tail", pid=self.trace_pid) as ev:
             pending = self._round_tail()
         self._phase("round_tail", ev)

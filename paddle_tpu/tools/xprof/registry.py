@@ -176,19 +176,24 @@ def _spec_engine_specs(engine, prefix):
     S, k, V = engine.num_slots, engine.spec_k, engine.vocab_size
     C = engine.prefill_chunk_len
     jit_kwargs = {"donate_argnums": engine._program_donate_argnums}
-    # both waves take the one packed argument (tables, lanes, spec_len)
+    # both waves take the lane state (the draft reads it, the verify
+    # takes it donated) and the one packed argument (tables, lanes,
+    # spec_len)
     lanes, bias = engine._lane_args(
         *_all_lanes(engine), engine._tables, np.ones((S,), np.int32))
     draft_args = (engine._draft_params, engine._draft_buffers,
-                  engine._caches, lanes, bias, engine._key)
+                  engine._caches, engine._lane_tok, engine._lane_pos,
+                  lanes, bias, engine._key)
     verify_args = (
-        engine._params, engine._buffers, engine._caches, lanes, bias,
+        engine._params, engine._buffers, engine._caches,
+        engine._lane_tok, engine._lane_pos, lanes, bias,
         np.zeros((S, k), np.int32),                 # draft tokens
         np.zeros((S, k, V), np.float32),            # draft probs
         engine._key)
     return [
         {"name": f"{prefix}_draft_wave", "fn": engine._draft_wave_fn,
-         "args": draft_args, "jit_kwargs": jit_kwargs,
+         "args": draft_args,
+         "jit_kwargs": {"donate_argnums": engine._draft_donate_argnums},
          "description": f"k+1={engine.spec_k + 1} draft decode steps "
                         f"in one executable (slots={S})"},
         {"name": f"{prefix}_verify", "fn": engine._decode_wave_fn,
@@ -197,7 +202,8 @@ def _spec_engine_specs(engine, prefix):
                         f"over C=k+1={engine.spec_k + 1} positions + "
                         "exact acceptance-rejection"},
         {"name": f"{prefix}_prefill_chunk", "fn": engine._prefill_fn,
-         "args": _chunk_args(engine), "jit_kwargs": jit_kwargs,
+         "args": _chunk_args(engine),
+         "jit_kwargs": {"donate_argnums": engine._prefill_donate_argnums},
          "description": f"dual-model prompt chunk admission (target + "
                         f"draft K/V, chunk={C})"},
     ]

@@ -384,6 +384,15 @@ def decode_wave_ctx():
     return ProgramContext(spec)
 
 
+def _lane_state_leaves(ctx, argnum, after):
+    """How many leaves the two lane-state vectors are (one each), which
+    follow the caches at `after`."""
+    ranges = ctx.leaf_index_ranges()
+    assert ranges[argnum] == (after, 1) and ranges[argnum + 1] == (
+        after + 1, 1)
+    return 2
+
+
 def test_decode_wave_kv_donation_actually_aliased(decode_wave_ctx):
     """The engine's donated batched KV cache must be aliased by XLA at
     the engine's real shapes — every cache leaf, not just 'no findings'.
@@ -391,11 +400,13 @@ def test_decode_wave_kv_donation_actually_aliased(decode_wave_ctx):
     the donation and transiently doubling the cache in HBM every wave)
     fails HERE."""
     ctx = decode_wave_ctx
-    assert ctx.donate_argnums == (2,)          # the batched KV cache
+    # the batched KV cache, and the lanes' tokens and positions
+    assert ctx.donate_argnums == (2, 3, 4)
     first, n = ctx.leaf_index_ranges()[2]
     assert n == 4                              # 2 layers x (k, v)
     aliased = ctx.aliased_param_indices
     assert aliased is not None, ctx.unavailable
+    n += _lane_state_leaves(ctx, 3, first + n)
     missing = [i for i in range(first, first + n) if i not in aliased]
     assert missing == [], \
         f"decode-wave KV cache leaves {missing} lost donation aliasing"
@@ -415,11 +426,12 @@ def test_paged_decode_wave_pool_donation_actually_aliased():
     input (never donated, never a baked constant)."""
     (spec,) = jxaudit.tracked_specs(["paged_decode_wave"])
     ctx = ProgramContext(spec)
-    assert ctx.donate_argnums == (2,)          # the block pools
+    assert ctx.donate_argnums == (2, 3, 4)     # the block pools, the lanes
     first, n = ctx.leaf_index_ranges()[2]
     assert n == 2                              # 2 layers, one K/V pool each
     aliased = ctx.aliased_param_indices
     assert aliased is not None, ctx.unavailable
+    n += _lane_state_leaves(ctx, 3, first + n)
     missing = [i for i in range(first, first + n) if i not in aliased]
     assert missing == [], \
         f"paged decode-wave pool leaves {missing} lost donation aliasing"
@@ -429,11 +441,12 @@ def test_paged_decode_wave_pool_donation_actually_aliased():
 def test_paged_prefill_chunk_pool_donation_actually_aliased():
     (spec,) = jxaudit.tracked_specs(["paged_prefill_chunk"])
     ctx = ProgramContext(spec)
-    assert ctx.donate_argnums == (2,)
+    assert ctx.donate_argnums == (2, 3, 4)
     first, n = ctx.leaf_index_ranges()[2]
     assert n == 2
     aliased = ctx.aliased_param_indices
     assert aliased is not None, ctx.unavailable
+    n += _lane_state_leaves(ctx, 3, first + n)
     missing = [i for i in range(first, first + n) if i not in aliased]
     assert missing == [], \
         f"paged prefill-chunk pool leaves {missing} lost donation " \
@@ -452,12 +465,18 @@ def test_spec_programs_target_and_draft_pools_actually_aliased():
     assert len(specs) == 2
     for spec in specs:
         ctx = ProgramContext(spec)
-        assert ctx.donate_argnums == (2,), spec["name"]
+        # the draft wave reads the lane state and leaves it to the
+        # verify wave, which takes it donated beside the bundle
+        verify = spec["name"] == "paged_spec_verify"
+        assert ctx.donate_argnums == ((2, 3, 4) if verify else (2,)), \
+            spec["name"]
         first, n = ctx.leaf_index_ranges()[2]
         # 2 target layers + 1 draft layer, one K/V pool each
         assert n == 3, spec["name"]
         aliased = ctx.aliased_param_indices
         assert aliased is not None, (spec["name"], ctx.unavailable)
+        if verify:
+            n += _lane_state_leaves(ctx, 3, first + n)
         missing = [i for i in range(first, first + n)
                    if i not in aliased]
         assert missing == [], \

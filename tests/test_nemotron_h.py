@@ -502,13 +502,13 @@ def test_programs_of_models_without_slot_state_take_no_new_argument(model):
         eng = PagedServingEngine(plain, **kw)
         assert not eng.slot_state and not eng.counts_model_work
         assert "state_bytes" not in eng._health()
-        # parameters, buffers, caches, the packed small arguments, the
-        # bias, the key: the slot and the active mask ride in the packed
-        # one for every model, and reach only a model with slot state
-        # (these two's `prefill_chunk` / `decode_step` have no such
-        # parameter and would refuse it)
-        assert _program_arity(eng) == (6, 6)
-    assert _program_arity(PagedServingEngine(model, **kw)) == (6, 6)
+        # parameters, buffers, caches, the lanes' tokens and positions,
+        # the packed small arguments, the bias, the key: the slot and the
+        # active mask ride in the packed one for every model, and reach
+        # only a model with slot state (these two's `prefill_chunk` /
+        # `decode_step` have no such parameter and would refuse it)
+        assert _program_arity(eng) == (8, 8)
+    assert _program_arity(PagedServingEngine(model, **kw)) == (8, 8)
 
 
 def test_the_front_door_serves_it(model, engine):

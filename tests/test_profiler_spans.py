@@ -154,8 +154,9 @@ def test_chrome_sink_keeps_ids_as_args_and_the_decorator_keeps_pid(tmp_path):
 
 def test_spans_reach_a_jax_profiler_trace_nested_with_ids(paged, tmp_path):
     """An operator's `jax.profiler.start_trace` (not this module's
-    start_profiler) sees the round, the wave inside it and the device
-    wait inside that, on one thread, with the round's number on them."""
+    start_profiler) sees the round, the wave's dispatch inside it and, a
+    round later, its read with the device wait inside that, on one
+    thread, with the number of the round that dispatched on both."""
     sched = Scheduler(paged)
     _serve(sched, n=1, max_tokens=2)            # compile outside the trace
     jax.profiler.start_trace(str(tmp_path))
@@ -175,13 +176,27 @@ def test_spans_reach_a_jax_profiler_trace_nested_with_ids(paged, tmp_path):
     rounds = {dict(e.stats)["round"]: e for e in named["serving/round"]}
     waits = named["serving/wave/wait"]
     assert waits
+    dispatched, collected = [], []
     for wave in named["serving/decode_wave"]:
         ids = dict(wave.stats)
-        assert ids["lanes"] == 1
-        assert _inside(wave, rounds[ids["round"]])
-        assert sum(1 for w in waits if _inside(w, wave)) == 1
-    # a two-chunk prompt: two prefill spans of this request, chunk 0 and 1
+        inside = [sum(1 for e in named[name] if _inside(e, wave))
+                  for name in ("serving/wave/dispatch", "serving/wave/wait")]
+        if "collect" in ids:
+            # read in the round after the one that dispatched it
+            assert inside == [0, 1]
+            assert _inside(wave, rounds[ids["round"] + 1])
+            collected.append(ids["round"])
+        else:
+            assert ids["lanes"] == 1 and inside == [1, 0]
+            assert _inside(wave, rounds[ids["round"]])
+            dispatched.append(ids["round"])
+    assert dispatched == collected and len(waits) == len(collected) == 2
+    # a two-chunk prompt: two prefill spans of this request, chunk 0 and
+    # 1, and the read of its first token, a span of its own behind the
+    # wave's dispatch
     chunks = [dict(e.stats) for e in named["serving/prefill"]]
+    assert [c["first_tokens"] for c in chunks if "chunk" not in c] == [1]
+    chunks = [c for c in chunks if "chunk" in c]
     assert [c["chunk"] for c in chunks] == [0, 1]
     assert {c["request_id"] for c in chunks} == {req.request_id}
     assert all("slot" in c for c in chunks)
@@ -235,7 +250,11 @@ def test_every_phase_is_its_spans_total(kind, model):
         ph = _serve(sched)
     finally:
         rows = {r["name"]: r for r in prof.stop_profiler()}
-    assert set(ph) == set(SPAN_PHASE.values()) | {"unfed"}
+    # `unfed` is there when the host saw the device run dry with a
+    # request waiting: always where every wave is read before the next
+    # is dispatched
+    assert set(ph) - {"unfed"} == set(SPAN_PHASE.values())
+    assert "unfed" in ph or kind != "speculative"
     for span, phase in SPAN_PHASE.items():
         if span == "serving/round":
             continue            # idle rounds have the span, not the phase
@@ -281,16 +300,35 @@ def _unfed(sched):
     return sched.metrics.snapshot()["phase_seconds"].get("unfed", 0.0)
 
 
-def test_unfed_is_zero_while_programs_are_queued_back_to_back(model):
-    """A four-chunk prompt alone: chunk after chunk is dispatched and
-    nothing is read back until the last one, whatever the host does in
-    between."""
+class _StillRunning:
+    """A program's output that the device has not produced yet."""
+
+    def is_ready(self):
+        return False
+
+
+@pytest.mark.parametrize("prompt_len, max_tokens",
+                         [(3 * CHUNK + 5, 2), (5, 6)],
+                         ids=["chunks-alone", "waves-read-one-late"])
+def test_unfed_is_zero_while_the_newest_program_still_runs(
+        model, prompt_len, max_tokens):
+    """A four-chunk prompt alone (chunk after chunk is dispatched and
+    nothing is read back until the last one), and a decoding lane whose
+    every read is of the wave BEFORE the newest: while the newest program
+    dispatched has not finished (here: its output never reads as ready),
+    no read and no end of a round opens an unfed interval, whatever the
+    host does in between."""
     sched = Scheduler(_paged(model))
-    sched.submit(prompt=_prompt(3 * CHUNK + 5), max_tokens=2)
+    eng = sched.engine
+    plain = eng._dispatched
+    eng._dispatched = lambda phase, ev, out: plain(phase, ev,
+                                                   _StillRunning())
+    sched.submit(prompt=_prompt(prompt_len), max_tokens=max_tokens)
     for _ in range(3):
         assert sched.step() == 1
         time.sleep(0.02)
-    assert sched.engine.prefilling_slots() == [0]
+    assert eng.prefilling_slots() == ([0] if max_tokens == 2 else [])
+    sched.run()
     assert _unfed(sched) == 0.0
 
 

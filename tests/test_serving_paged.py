@@ -556,7 +556,7 @@ def test_decode_wave_never_writes_through_midprefill_tables(model):
                                 prefill_chunk_len=CHUNK)
     engine.prefill_slot(0, _prompt(70))            # active decoder
     engine.begin_prefill(1, _prompt(71, n=2 * CHUNK + 3))   # 3 chunks
-    assert engine.prefill_step(1) is None          # chunk 0 written
+    assert not engine.prefill_step(1)              # chunk 0 written
     blk0 = engine._slot_blocks[1][0]
     before = np.asarray(engine._caches[0])[blk0].copy()
     engine.decode_wave()                           # slot 0 decodes
@@ -772,3 +772,54 @@ def test_all_starved_wave_not_counted_in_occupancy(model):
     decode_tokens = sum(len(r.output_tokens) for r in reqs) - admissions
     assert sum(waves) == decode_tokens
     assert all(n >= 1 for n in waves)
+
+
+# ---------------------------------------------------------------------------
+# the step a lane runs too many (serving/scheduler.py: a token is read
+# one program after it is made)
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("n", [3 * BLOCK, 3 * BLOCK + 5, BLOCK - 1],
+                         ids=["full-blocks", "part-block", "no-full-block"])
+def test_the_step_run_too_many_writes_no_block_a_later_owner_reads(model, n):
+    """A request that ends on a stop sequence has ridden one wave more
+    when the scheduler finds out, and that wave's K/V row went through
+    the lane's table: into a block the lane still owned, at a position
+    past the prompt. The prompt's hashed blocks hold bit for bit what the
+    chunks wrote, the next request with the same prompt shares them and
+    streams what a fresh engine streams, and the pool gets every block
+    back."""
+    def fresh():
+        return PagedServingEngine(model, num_slots=2, max_len=MAX_LEN,
+                                  block_size=BLOCK, num_blocks=17,
+                                  prefill_chunk_len=CHUNK)
+
+    prompt = _prompt(130 + n, n=n)
+    ref = Scheduler(fresh()).generate(prompt, max_tokens=MAX_NEW)
+    # ends where these three tokens first stand at the stream's end (a
+    # model from a seed repeats itself: wherever that is, the host finds
+    # out by reading them)
+    gram = ref[1:4]
+    cut = next(i for i in range(2, MAX_NEW) if ref[i - 2:i + 1] == gram)
+    eng = fresh()
+    sched = Scheduler(eng)
+    req = sched.submit(prompt=prompt, max_tokens=MAX_NEW,
+                       stop_sequences=[gram])
+    hashed = before = None
+    while not req.done:
+        sched.step()
+        if before is None and req.output_tokens:
+            hashed = list(eng._slot_blocks[req.slot][:n // BLOCK])
+            before = [np.asarray(pool)[hashed].copy()
+                      for pool in eng._caches]
+    assert req.finish_reason == "stop"
+    assert req.output_tokens == ref[:cut + 1]
+    # the wave that carried the lane once more is still unread
+    assert len(sched._waves) == 1
+    assert eng.block_pool.used == 0
+    for pool, kept in zip(eng._caches, before):
+        np.testing.assert_array_equal(np.asarray(pool)[hashed], kept)
+    again = sched.generate(prompt, max_tokens=MAX_NEW)
+    assert again == ref
+    assert sched.metrics.snapshot()["prefix_hits"] == n // BLOCK
+    assert not sched._waves and eng.block_pool.used == 0

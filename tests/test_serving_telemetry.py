@@ -130,8 +130,13 @@ def test_snapshot_keys_byte_compatible(engine):
         # brings a bias)
         "bias_uploads",
         # the collector's pauses and collections (process totals)
-        "gc_pause_seconds", "gc_collections", "gc_gen2_collections"]
+        "gc_pause_seconds", "gc_collections", "gc_gen2_collections",
+        # waves put on the device's queue, and those that went out
+        # before the wave before them was read
+        "waves_dispatched", "waves_dispatched_ahead"]
     assert snap["bias_uploads"] == 0
+    assert snap["waves_dispatched"] == 2          # 3 tokens: 1 + 2 waves
+    assert snap["waves_dispatched_ahead"] == 1
     # a 3-token request has 2 inter-token gaps — TPOT is real, and the
     # phase split saw every phase of a working round
     assert snap["tpot_p50_s"] is not None

@@ -279,9 +279,14 @@ def test_e2e_spike_and_recompile_fire_once_and_clear(paged, model):
             _run_stream(sched, _prompts(seed=500 + 10 * r))
         assert am.summary()["fired_total"] == 0
 
+        # waves 2-4, not the first: a round reads its prompts' first
+        # tokens behind its wave's dispatch, so a stall in the FIRST
+        # wave's dispatch is a TTFT step of its own (the first
+        # admissions'), before the queued requests' larger one: two
+        # firings of the TTFT rule, and both rightly
         monkey = chaos.ChaosMonkey([chaos.Fault(
             chaos.DECODE_WAVE, action="delay", delay_s=0.25,
-            times=(1, 2, 3))])
+            times=(2, 3, 4))])
         with chaos.active(monkey):
             _run_stream(sched, _prompts(seed=520))
         assert len(monkey.fired) == 3, "latency injection never fired"

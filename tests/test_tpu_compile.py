@@ -293,28 +293,57 @@ def test_latent_pool_passes_through_write_and_attention_in_place_for_v5e(
     assert _pool_stays(compiled, [pool]) < 1.04
 
 
-def _compile_engine_program(eng, program, sharding):
-    """A paged engine's decode wave or prefill chunk as the engine builds
-    it (its own closure, the arguments a round stages, the caches
-    donated), compiled for the described chip."""
+def _engine_program_args(eng, program):
+    """(the engine's own closure, the arguments a round stages for it)
+    of a paged engine's decode wave or prefill chunk."""
     slots, chunk = eng.num_slots, eng.prefill_chunk_len
     if program == "decode_wave":
-        fn = eng._decode_wave_fn
-        args = eng._wave_args([True] * slots, np.zeros(slots, bool),
-                              jax.random.PRNGKey(0))
-    else:
-        fn = eng._prefill_fn
-        # the tuple `prefill_step` stages: the packed chunk, the
-        # resident zero bias row, the engine's key
-        greedy = eng._sampling_state(False, 1.0, 0, 1.0, None, False)
-        args = (*eng._prefill_chunk_args(0),
-                *eng._prompt_args(0, np.zeros(chunk, np.int32), 0, chunk, 0,
-                                  greedy, eng._tables[0]))
+        return eng._decode_wave_fn, eng._wave_args(
+            [True] * slots, np.zeros(slots, bool), jax.random.PRNGKey(0))
+    # the tuple `prefill_step` stages: the lane state, the packed chunk,
+    # the resident zero bias row, the engine's key
+    greedy = eng._sampling_state(False, 1.0, 0, 1.0, None, False)
+    return eng._prefill_fn, (
+        *eng._prefill_chunk_args(0),
+        *eng._prompt_args(0, np.zeros(chunk, np.int32), 0, chunk, 0,
+                          greedy, eng._tables[0]))
+
+
+def _compile_engine_program(eng, program, sharding):
+    """A paged engine's decode wave or prefill chunk as the engine builds
+    it (its own closure, the arguments a round stages, the caches and
+    the lane state donated), compiled for the described chip."""
+    fn, args = _engine_program_args(eng, program)
     shapes = jax.tree_util.tree_map(
         lambda a: jax.ShapeDtypeStruct(jnp.shape(a), jnp.result_type(a),
                                        sharding=sharding), args)
     return jax.jit(fn, donate_argnums=eng._program_donate_argnums
                    ).lower(*shapes).compile()
+
+
+def _lane_state_stays(compiled, eng, program, carried):
+    """The lanes' tokens and positions go through a serving program as
+    the pools do: two int32[slots] device arrays the engine holds,
+    donated, each aliased from parameter to result beside the `carried`
+    arrays (the header's alias list holds them all and the aliased bytes
+    cover them), so the host is not on a token's way from one program to
+    the next; and the program takes ONE argument from the host, the
+    packed numpy array, as since PR 32."""
+    _, args = _engine_program_args(eng, program)
+    assert args[3] is eng._lane_tok and args[4] is eng._lane_pos
+    assert eng._program_donate_argnums == (2, 3, 4)
+    for lane in args[3:5]:
+        assert isinstance(lane, jax.Array)
+        assert (lane.shape, lane.dtype) == ((eng.num_slots,), jnp.int32)
+    leaves = jax.tree_util.tree_leaves(args)
+    assert [type(a) for a in leaves].count(np.ndarray) == 1
+    assert all(isinstance(a, (np.ndarray, jax.Array)) for a in leaves)
+    header = compiled.as_text().split("\n", 1)[0]
+    aliases = re.findall(r"\(\d+, \{\}, (?:may|must)-alias\)", header)
+    assert len(aliases) == len(carried) + 2, header[:400]
+    logical = sum(int(np.prod(p.shape)) * p.dtype.itemsize for p in carried)
+    assert compiled.memory_analysis().alias_size_in_bytes >= \
+        logical + 2 * 4 * eng.num_slots
 
 
 @pytest.mark.parametrize("program", ["decode_wave", "prefill_chunk"])
@@ -363,6 +392,7 @@ def test_engine_programs_keep_the_pool_in_place_for_v5e(one_chip, as_on_tpu,
         [(2, 16, 128)] * 2, [(2, 16, 256)] * 2, [(16, 640)] * 2)
     compiled = _compile_engine_program(eng, program, one_chip)
     _pool_stays(compiled, pools)
+    _lane_state_stays(compiled, eng, program, pools)
     # a layer: the paged kernel; of the latent model the absorbed kernel
     # in each layer of a wave, none in a chunk (expanded), and the
     # expert kernel of its one expert layer in both
@@ -402,6 +432,8 @@ def test_dense_hybrid_engine_programs_keep_pool_and_state_in_place_for_v5e(
     assert ssm.shape == (32, 64, 64, 128) and ssm.dtype == jnp.float32
     compiled = _compile_engine_program(eng, program, one_chip)
     _pool_stays(compiled, [pool, ssm])
+    _lane_state_stays(compiled, eng, program,
+                      jax.tree_util.tree_leaves(eng._caches))
     assert compiled.as_text().count("tpu_custom_call") == 1
 
 
