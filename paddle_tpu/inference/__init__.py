@@ -428,8 +428,9 @@ class LLMPredictor:
         """Graceful shutdown: drain the scheduler (accepted requests
         complete, new submits are shed with finish_reason "rejected")
         and stop the background metrics exporter. drain=False skips the
-        wave loop for a hard stop. The engine's compiled programs need
-        no teardown."""
+        wave loop for a hard stop and gives the engine's pool back to
+        the device at once (`ServingEngine.release`): nothing more can
+        be served. The engine's compiled programs need no teardown."""
         if self.router is not None:
             if drain:
                 self.router.shutdown()
@@ -439,6 +440,7 @@ class LLMPredictor:
             self.scheduler.shutdown()
         else:
             self.engine.stop_metrics_server()
+            self.engine.release()
         self.metrics_server = None
 
     def generate(self, prompt, **kw):

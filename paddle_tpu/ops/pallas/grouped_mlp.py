@@ -11,6 +11,14 @@ experts is visited by both, each keeping its own rows. Nothing is
 padded to a capacity and no row is dropped: an expert with no rows costs
 its fetch and no arithmetic.
 
+An expert whose matrices, double-buffered, do not fit the chip's fast
+memory beside the resident rows is walked in `tiles` slices of its
+width (`_tiles`): a slice of `up` (and `gate`) gives a slice of the
+hidden activation, which its slice of `down` adds to the rows' result,
+so the grid is (experts, tiles) and every byte is still read once. At
+one tile, which is every shape that fitted before, the kernel is the
+one it was.
+
 Every matrix is stored [experts, width, hidden] (`up` and `gate` as
 [out, in], `down` as [in, out]): the device lays a matrix out in tiles
 of 128 columns and would store [hidden, 1856] transposed; `hidden` tiles
@@ -26,15 +34,35 @@ import jax
 import jax.numpy as jnp
 
 ROWS = 16       # rows a block: one packed bfloat16 tile of sublanes
+#: what one call may plan of a v5e's 128 MiB of VMEM
+VMEM_BUDGET = 120 << 20
 
 
-def _kernel(x_ref, up_ref, *refs, gated):
+def _vmem(rows, hidden, width, item, mats):
+    """The matrices, double-buffered, beside the resident rows and
+    result, and a margin."""
+    return (2 * mats * width * hidden * item
+            + 2 * rows * hidden * (item + 4) + (8 << 20))
+
+
+def _tiles(rows, hidden, width, item, mats):
+    """Slices of an expert's width a call walks: the fewest (a power of
+    two, each slice whole 128-column tiles) whose plan fits the budget."""
+    tiles = 1
+    while (_vmem(rows, hidden, width // tiles, item, mats) > VMEM_BUDGET
+           and width % (256 * tiles) == 0):
+        tiles *= 2
+    return tiles
+
+
+def _kernel(x_ref, up_ref, *refs, gated, tiled):
     from jax.experimental import pallas as pl
     gate_ref = refs[0] if gated else None
     down_ref, offs_ref, o_ref = refs[-3:]
     e = pl.program_id(0)
+    first_step = (e == 0) & (pl.program_id(1) == 0) if tiled else e == 0
 
-    @pl.when(e == 0)
+    @pl.when(first_step)
     def _():
         o_ref[...] = jnp.zeros_like(o_ref)
 
@@ -56,8 +84,12 @@ def _kernel(x_ref, up_ref, *refs, gated):
         y = jnp.dot(h, down_ref[0], preferred_element_type=jnp.float32)
         rows = start + jax.lax.broadcasted_iota(jnp.int32, (ROWS, 1), 0)
         mine = (rows >= r0) & (rows < r1)
-        o_ref[pl.ds(start, ROWS), :] = jnp.where(
-            mine, y, o_ref[pl.ds(start, ROWS), :])
+        was = o_ref[pl.ds(start, ROWS), :]
+        # a slice of the width adds its part to what the slices before
+        # it left (zeros at first); a whole expert's rows are its own
+        o_ref[pl.ds(start, ROWS), :] = (
+            was + jnp.where(mine, y, 0.0) if tiled
+            else jnp.where(mine, y, was))
         return carry
 
     jax.lax.fori_loop(0, blocks, block, 0)
@@ -69,21 +101,25 @@ def _call(rows, hidden, width, experts, dtype_name, interpret, gated=False):
     from jax.experimental.pallas import tpu as pltpu
     item = jnp.dtype(dtype_name).itemsize
     mats = 3 if gated else 2
-    # the matrices, double-buffered, beside the resident rows and result
-    vmem = (2 * mats * width * hidden * item
-            + 2 * rows * hidden * (item + 4) + (8 << 20))
-    matrix = pl.BlockSpec((1, width, hidden), lambda e: (e, 0, 0))
+    tiles = _tiles(rows, hidden, width, item, mats)
+    tiled = tiles > 1
+    grid = (experts, tiles) if tiled else (experts,)
+    matrix = pl.BlockSpec((1, width // tiles, hidden),
+                          lambda e, t=0: (e, t, 0))
+    resident = pl.BlockSpec((rows, hidden), lambda *_: (0, 0))
     return pl.pallas_call(
-        functools.partial(_kernel, gated=gated), grid=(experts,),
+        functools.partial(_kernel, gated=gated, tiled=tiled), grid=grid,
         in_specs=[
-            pl.BlockSpec((rows, hidden), lambda e: (0, 0)),
+            resident,
             *[matrix] * mats,
             pl.BlockSpec(memory_space=pltpu.SMEM),
         ],
-        out_specs=pl.BlockSpec((rows, hidden), lambda e: (0, 0)),
+        out_specs=resident,
         out_shape=jax.ShapeDtypeStruct((rows, hidden), jnp.float32),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary",), vmem_limit_bytes=vmem),
+            dimension_semantics=("arbitrary",) * len(grid),
+            vmem_limit_bytes=_vmem(rows, hidden, width // tiles, item,
+                                   mats)),
         interpret=interpret, name="moe_experts")
 
 

@@ -577,6 +577,18 @@ class ServingEngine:
             self._metrics_server.stop()
             self._metrics_server = None
 
+    def release(self):
+        """Give the caches' device memory back (the K/V or latent pool,
+        slot state): the end of the engine's life, for a caller that
+        keeps the process and the weights (the benchmark's referee runs
+        its float32 forward beside them). Waits for the programs in
+        flight, whose results the caches are."""
+        leaves = [a for a in jax.tree_util.tree_leaves(self._caches)
+                  if isinstance(a, jax.Array) and not a.is_deleted()]
+        jax.block_until_ready(leaves)
+        for a in leaves:
+            a.delete()
+
     def attach_queue_probe(self, fn):
         """Register a zero-arg queue-depth callable (the Scheduler's) —
         folded into /healthz so load balancers and the fleet router get

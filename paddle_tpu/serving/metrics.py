@@ -125,6 +125,12 @@ _PREFIX_EVICTIONS = telemetry.counter(
     "Blocks the pool handed out by dropping a cached prefix hash (no "
     "free block without one was left): 0 while plain blocks last, one "
     "per allocated block once every free block is cached")
+_MHC_ROWS = telemetry.counter(
+    "serving_mhc_rows_mixed_total",
+    "Token rows put through the residual-stream maps of a model with "
+    "more than one residual stream (hc_mult > 1): tokens staged into "
+    "chunks and waves times the sub-layers each passes (0 for a model "
+    "with one stream)")
 # speculative decoding (serving/paged SpeculativePagedEngine): the
 # draft-k/verify-once wave's economics — acceptance rate IS the
 # speedup knob (mean accepted/wave > 0 means decode rounds per
@@ -179,7 +185,8 @@ def record_callback_error(request, error):
 #: carries under the same names (0 for a model that has none of it)
 MODEL_COUNTS = ("state_resets", "moe_picks", "mla_rows_attended",
                 "mla_rows_expanded", "prefill_tokens", "prefill_chunks",
-                "ssm_records_stepped", "ssm_lanes_stepped")
+                "ssm_records_stepped", "ssm_lanes_stepped",
+                "mhc_rows_mixed")
 
 
 class ServingMetrics:
@@ -235,7 +242,8 @@ class ServingMetrics:
         # records the waves stepped and lanes that decoded in them,
         # (token, expert) pairs routed, latent rows attended by the
         # waves and expanded by the chunks, the chunks and their
-        # prompt tokens
+        # prompt tokens, token rows put through the maps of a
+        # multi-stream residual path
         self._model_counts = dict.fromkeys(MODEL_COUNTS, 0)
         # [V] rows and [S, V] matrices of logit bias the engine sent to
         # the device (0 while no request brings a bias: the engine
@@ -329,6 +337,8 @@ class ServingMetrics:
         with self._lock:
             for k, v in counts.items():
                 self._model_counts[k] += int(v)
+        if counts.get("mhc_rows_mixed"):
+            _MHC_ROWS.inc(int(counts["mhc_rows_mixed"]))
 
     def on_bias_uploads(self, n):
         """One round's `ServingEngine.take_bias_uploads()`."""

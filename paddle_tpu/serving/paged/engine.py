@@ -184,8 +184,11 @@ class PagedServingEngine(ServingEngine):
         # zeroed, (token, expert) pairs routed
         self._picks_per_token = int(getattr(model, "moe_picks_per_token",
                                             0))
+        self._mixes_per_token = int(getattr(model, "mhc_mixes_per_token",
+                                            0))
         self.counts_model_work = bool(self.slot_state or self.latent_cache
-                                      or self._picks_per_token)
+                                      or self._picks_per_token
+                                      or self._mixes_per_token)
         self._model_counts = dict.fromkeys(MODEL_COUNTS, 0)
 
     def _kv_call_shapes(self):
@@ -433,6 +436,7 @@ class PagedServingEngine(ServingEngine):
                                        sampling, self._tables[slot], last))
             counts = self._model_counts
             counts["moe_picks"] += valid * self._picks_per_token
+            counts["mhc_rows_mixed"] += valid * self._mixes_per_token
             counts["prefill_tokens"] += valid
             counts["prefill_chunks"] += 1
             self._count_steps(np.int32([c0]), C)
@@ -713,6 +717,7 @@ class PagedServingEngine(ServingEngine):
         self._count_steps(self.slot_pos, 1)
         lanes = int(np.count_nonzero(active_now))
         self._model_counts["moe_picks"] += lanes * self._picks_per_token
+        self._model_counts["mhc_rows_mixed"] += lanes * self._mixes_per_token
         if self.slot_state:
             # the wave's program steps every slot's record, whether its
             # lane decodes or keeps what it had
@@ -774,8 +779,10 @@ class PagedServingEngine(ServingEngine):
         expert) pairs of the tokens staged into chunks and waves; the
         chunks and the prompt tokens they carried; of a latent cache,
         the rows the waves' lanes attend and the rows the chunks expand
-        (each a layer). Taken by the scheduler once a round, from an
-        engine whose `counts_model_work` is set."""
+        (each a layer); of a residual path with several streams, those
+        tokens times the sub-layers whose maps each passes
+        (`model.mhc_mixes_per_token`). Taken by the scheduler once a
+        round, from an engine whose `counts_model_work` is set."""
         out = self._model_counts
         self._model_counts = dict.fromkeys(MODEL_COUNTS, 0)
         return out

@@ -129,9 +129,10 @@ def test_parameters_are_created_in_the_named_dtype_and_kept():
 
 
 @pytest.mark.parametrize("kw,what", [
-    ({"q_lora_rank": 1536}, "q_lora_rank"),
+    ({"rope_scaling": {"type": "linear", "factor": 4.0}},
+     "rope_scaling.type='linear'"),
     ({"n_group": 8}, "n_group"),
-    ({"rope_scaling": {"type": "yarn"}}, "rope_scaling"),
+    ({"hc_mult": 0}, "hc_mult=0"),
     ({"num_experts_per_tok": 9}, "num_experts_per_tok 9 > "),
     ({"qk_rope_head_dim": 7}, "even")])
 def test_config_refuses_what_is_not_computed(kw, what):

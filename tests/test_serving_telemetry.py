@@ -126,6 +126,9 @@ def test_snapshot_keys_byte_compatible(engine):
         "state_resets", "moe_picks", "mla_rows_attended",
         "mla_rows_expanded", "prefill_tokens", "prefill_chunks",
         "ssm_records_stepped", "ssm_lanes_stepped",
+        # token rows put through the maps of a multi-stream residual
+        # path (0 with one stream)
+        "mhc_rows_mixed",
         # bias rows and matrices sent to the device (0 while no request
         # brings a bias)
         "bias_uploads",

@@ -254,14 +254,20 @@ def test_pool_passes_through_write_and_attention_in_place_for_v5e(
     assert _pool_stays(compiled, [pool]) < 1.02
 
 
-@pytest.mark.parametrize("name,lanes,c,kernels", [
+@pytest.mark.parametrize("name,lanes,c,nblk,blocks,scale,kernels", [
     # kanana2-serve-doc: 32 slots x 10240 positions / 16 + 1 pages of
     # 16 rows x 640 (576 stored in whole vregs), 32 heads, MLA 512 + 64
-    ("kanana-doc-wave", 32, 1, ["paged_latent_attention"]),
-    ("kanana-doc-chunk512", 1, 512, []),
+    ("kanana-doc-wave", 32, 1, 640, 20481, 192 ** -0.5,
+     ["paged_latent_attention"]),
+    ("kanana-doc-chunk512", 1, 512, 640, 20481, 192 ** -0.5, []),
+    # xing4-serve-repo-reason: 16 slots x 24,576 positions / 16 + 1
+    # pages, tables of 1,536 pages, the YaRN-corrected softmax scale
+    ("xing-reason-wave", 16, 1, 1536, 24577, 0.1447,
+     ["paged_latent_attention"]),
+    ("xing-reason-chunk512", 1, 512, 1536, 24577, 0.1447, []),
 ])
 def test_latent_pool_passes_through_write_and_attention_in_place_for_v5e(
-        one_chip, as_on_tpu, name, lanes, c, kernels):
+        one_chip, as_on_tpu, name, lanes, c, nblk, blocks, scale, kernels):
     """One latent-attention layer of a serving program at the cell's
     real pool: the call the model's attention makes (the rows' write,
     then the absorbed kernel in a wave, the expanded loop in a chunk),
@@ -269,17 +275,17 @@ def test_latent_pool_passes_through_write_and_attention_in_place_for_v5e(
     def sds(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
-    heads, rank, rope, nope, v, nblk = 32, 512, 64, 128, 128, 640
+    heads, rank, rope, nope, v = 32, 512, 64, 128, 128
     assert pa.latent_path(c, rank, rope, nope, v) == \
         ("absorbed" if kernels else "expanded")
 
     def layer(pool, q_nope, q_rope, rows, w, tables, start, valid_len):
         out, pool = pa.paged_attend_latent(
             q_nope, q_rope, rows, w, pool, tables, start, valid_len,
-            (nope + rope) ** -0.5, kernel="pallas")
+            scale, kernel="pallas")
         return pool, out
 
-    pool = sds((20481, 16, pa.latent_width(rank, rope)), jnp.bfloat16)
+    pool = sds((blocks, 16, pa.latent_width(rank, rope)), jnp.bfloat16)
     args = (pool, sds((lanes, heads, c, nope), jnp.bfloat16),
             sds((lanes, heads, c, rope), jnp.bfloat16),
             sds((lanes, c, rank + rope), jnp.bfloat16),
@@ -289,7 +295,7 @@ def test_latent_pool_passes_through_write_and_attention_in_place_for_v5e(
     assert _kernel_names(layer, *args) == kernels
     compiled = jax.jit(layer, donate_argnums=(0,)).lower(*args).compile()
     # the other arguments (W_kv_b is 8.4 MB, a chunk's queries 6) are 2
-    # to 3.5% of the pool's 419 MB: nothing is padded
+    # to 3.5% of the pool's 419 MB (503 MB): nothing is padded
     assert _pool_stays(compiled, [pool]) < 1.04
 
 
@@ -348,7 +354,7 @@ def _lane_state_stays(compiled, eng, program, carried):
 
 @pytest.mark.parametrize("program", ["decode_wave", "prefill_chunk"])
 @pytest.mark.parametrize("family", ["gpt-mha-d64", "llama-gqa-d128",
-                                    "deepseek-mla"])
+                                    "deepseek-mla", "deepseek-mla-mhc"])
 def test_engine_programs_keep_the_pool_in_place_for_v5e(one_chip, as_on_tpu,
                                                         family, program):
     """The same, of the programs as the engine builds them (its own
@@ -374,6 +380,24 @@ def test_engine_programs_keep_the_pool_in_place_for_v5e(one_chip, as_on_tpu,
             max_position_embeddings=10240, param_dtype="bfloat16",
             init_weights=False))
         slots, chunk, max_len, blocks = 32, 512, 10240, 2049
+    elif family == "deepseek-mla-mhc":
+        # xing4-serve-repo-reason's programs: its widths, four residual
+        # streams, the low-rank query, the YaRN tables to 262,144
+        # positions, its lanes, table and chunk; two layers (one dense,
+        # one of 8 experts), a small vocabulary and half of the pages
+        # (a chunk's temporaries, 86 MB of four streams 3584 wide, have
+        # to stay under half a layer's pool for `_pool_stays`)
+        model = DeepseekV3ForCausalLM(DeepseekV3Config(
+            vocab_size=512, hidden_size=3584, intermediate_size=9216,
+            moe_intermediate_size=1024, num_hidden_layers=2,
+            n_routed_experts=8, n_shared_experts=1, num_experts_per_tok=4,
+            q_lora_rank=768, routed_scaling_factor=2.0, rope_theta=1e4,
+            rope_scaling={"type": "yarn", "factor": 64, "beta_fast": 32,
+                          "beta_slow": 1, "mscale": 1, "mscale_all_dim": 1,
+                          "original_max_position_embeddings": 4096},
+            max_position_embeddings=262144, hc_mult=4,
+            param_dtype="bfloat16", init_weights=False))
+        slots, chunk, max_len, blocks = 16, 512, 24576, 12289
     elif family == "gpt-mha-d64":
         model = GPTForPretraining(GPTConfig(
             vocab_size=512, hidden_size=128, num_layers=2, num_heads=2,
@@ -396,9 +420,19 @@ def test_engine_programs_keep_the_pool_in_place_for_v5e(one_chip, as_on_tpu,
     # a layer: the paged kernel; of the latent model the absorbed kernel
     # in each layer of a wave, none in a chunk (expanded), and the
     # expert kernel of its one expert layer in both
-    assert compiled.as_text().count("tpu_custom_call") == {
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == {
         ("deepseek-mla", "decode_wave"): 3,
-        ("deepseek-mla", "prefill_chunk"): 1}.get((family, program), 2)
+        ("deepseek-mla", "prefill_chunk"): 1,
+        ("deepseek-mla-mhc", "decode_wave"): 3,
+        ("deepseek-mla-mhc", "prefill_chunk"): 1}.get((family, program), 2)
+    # the maps and mixes of the four streams are in the one model's
+    # programs and in no other's, and a wave's 20 Sinkhorn iterations
+    # are not a loop on the device
+    assert ("mhc_map" in text and "mhc_mix" in text) == \
+        (family == "deepseek-mla-mhc")
+    if (family, program) == ("deepseek-mla-mhc", "decode_wave"):
+        assert " while(" not in text
 
 
 @pytest.mark.parametrize("program", ["decode_wave", "prefill_chunk"])
@@ -479,6 +513,68 @@ def test_gated_expert_kernel_compiles_for_v5e(one_chip, as_on_tpu, name,
     assert _kernel_names(gated, *shapes) == ["moe_experts"]
     txt = jax.jit(gated).lower(*shapes).compile().as_text()
     assert not re.search(r"bf16\[128,768,2048\][^ ]* copy\(", txt)
+
+
+@pytest.mark.parametrize("name,rows,tiles", [("wave-16-lanes", 64, 1),
+                                             ("chunk-512", 2048, 2)])
+def test_wide_gated_expert_kernel_compiles_inside_vmem_for_v5e(
+        one_chip, as_on_tpu, name, rows, tiles):
+    """`moe_experts` in its gated form at xing4-serve-repo-reason's size
+    (64 experts of three 1024 x 3584 matrices; 64 picks a wave, 2,048 a
+    chunk of 512 tokens): whole, an expert's matrices double-buffered
+    (44 MB) beside 2,048 resident rows (88 MB) pass a v5e's 128 MiB of
+    VMEM, so the chunk's call walks an expert in two slices of its
+    width; one kernel, under its name, no stack of matrices copied on
+    the way in. The shapes that fitted before keep their one tile."""
+    from paddle_tpu.ops.pallas import grouped_mlp as gm
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def gated(x, up, gate, down, sizes):
+        return gm.grouped_mlp(x, up, down, sizes, gate=gate)
+
+    assert gm._tiles(rows, 3584, 1024, 2, 3) == tiles
+    assert gm._vmem(rows, 3584, 1024 // tiles, 2, 3) <= gm.VMEM_BUDGET \
+        < 128 << 20
+    # kanana2-serve-doc's and nemotron3n-serve-reason's calls
+    assert gm._tiles(2048, 2048, 768, 2, 3) == 1
+    assert gm._tiles(1024, 2688, 1856, 2, 2) == 1
+    w = sds((64, 1024, 3584), jnp.bfloat16)
+    shapes = (sds((rows, 3584), jnp.bfloat16), w, w, w,
+              sds((64,), jnp.int32))
+    assert _kernel_names(gated, *shapes) == ["moe_experts"]
+    txt = jax.jit(gated).lower(*shapes).compile().as_text()
+    assert not re.search(r"bf16\[64,1024,3584\][^ ]* copy\(", txt)
+
+
+def test_a_one_stream_model_lowers_to_a_plain_residual():
+    """`hc_mult` 1 (every model but one): the serving programs hold no
+    `mhc_*` or `mla_q_lora` scope and the residual is `[B, L, hidden]`:
+    two adds of that shape a layer (`x + attention`, `x + MLP`) and no
+    other, so nothing was added for the streams."""
+    import paddle_tpu as pt
+    from paddle_tpu.nlp import DeepseekV3Config, DeepseekV3ForCausalLM
+    from paddle_tpu.serving import PagedServingEngine
+
+    pt.seed(0)
+    layers, hidden, slots, chunk = 3, 64, 4, 16
+    model = DeepseekV3ForCausalLM(DeepseekV3Config(
+        vocab_size=96, hidden_size=hidden, intermediate_size=96,
+        moe_intermediate_size=32, num_hidden_layers=layers,
+        num_attention_heads=4, kv_lora_rank=32, qk_nope_head_dim=16,
+        qk_rope_head_dim=8, v_head_dim=16, n_routed_experts=8,
+        num_experts_per_tok=3, max_position_embeddings=128))
+    eng = PagedServingEngine(model, num_slots=slots, max_len=64,
+                             block_size=8, prefill_chunk_len=chunk)
+    for program, lead in (("decode_wave", f"{slots}x1"),
+                          ("prefill_chunk", f"1x{chunk}")):
+        fn, args = _engine_program_args(eng, program)
+        text = jax.jit(fn).lower(*args).as_text(debug_info=True)
+        assert "mhc_" not in text and "mla_q_lora" not in text
+        adds = re.findall(r"stablehlo\.add %\w+, %\w+ : tensor<"
+                          + f"{lead}x{hidden}xf32>", text)
+        assert len(adds) == 2 * layers
 
 
 def test_latent_attention_forward_compiles_round_flash_for_v5e(one_chip,
