@@ -9,3 +9,4 @@ from .llama import (LlamaModel, LlamaForCausalLM, LlamaConfig,
 from .nemotron_h import NemotronHConfig, NemotronHForCausalLM
 from .granite_hybrid import GraniteHybridConfig, GraniteHybridForCausalLM
 from .deepseek_v3 import DeepseekV3Config, DeepseekV3ForCausalLM
+from .laguna import LagunaConfig, LagunaForCausalLM

@@ -8,7 +8,10 @@ the segments and the shared expert are one body.
 
 A config gives `hidden_size`, `n_routed_experts`, `num_experts_per_tok`,
 `moe_intermediate_size`, `routed_scaling_factor`, `initializer_range`,
-`param_dtype` and `init_weights`. The router's arithmetic is float32.
+`param_dtype` and `init_weights`. The router's arithmetic is float32, and
+it scores in one of two ways: a sigmoid a logit with a correction bias
+that only chooses (`route`), or a softmax over the logits (`route_softmax`,
+`nlp/laguna.py`).
 """
 import jax
 import jax.numpy as jnp
@@ -52,24 +55,39 @@ def route(scores_bias, logits, k, scale):
     return idx, w / jnp.sum(w, axis=-1, keepdims=True) * scale
 
 
+def route_softmax(logits, k, scale):
+    """(experts [T, k], weights [T, k] float32) from router logits
+    [T, E]: p = softmax(logits); the k largest of p are chosen; their
+    weights are the chosen p over their sum, times `scale`."""
+    w, idx = jax.lax.top_k(jax.nn.softmax(logits.astype(jnp.float32),
+                                          axis=-1), k)
+    return idx, w / jnp.sum(w, axis=-1, keepdims=True) * scale
+
+
 class RoutedExperts(ParamBlock):
     """Dropless routed experts and one shared expert of the same form
     (`gated`: see the module's docstring), `shared_width` wide. Picks
     are sorted by expert and one kernel walks the experts, each over its
     own rows: no capacity, nothing dropped, no per-token copy of an
-    expert's weights."""
+    expert's weights. `scoring` is "sigmoid" (`route`, with its
+    correction bias) or "softmax" (`route_softmax`, no bias)."""
 
     #: picks one call of the kernel keeps resident; more tokens than
     #: this many picks go through it a segment at a time
     MAX_ROWS = 1024
 
-    def __init__(self, cfg, shared_width, gated=False):
+    def __init__(self, cfg, shared_width, gated=False, scoring="sigmoid"):
         super().__init__(cfg)
+        if scoring not in ("sigmoid", "softmax"):
+            raise ValueError(f"router scoring {scoring!r} is not computed "
+                             "(only 'sigmoid' or 'softmax')")
         h, e, m = (cfg.hidden_size, cfg.n_routed_experts,
                    cfg.moe_intermediate_size)
         self.gated = bool(gated)
+        self.scoring = scoring
         self.router_weight = self._matrix(h, e)
-        self.e_score_correction_bias = self._vector(e, 0.0)
+        if scoring == "sigmoid":
+            self.e_score_correction_bias = self._vector(e, 0.0)
         # all [experts, width, hidden]: `up` (and `gate`) as [out, in],
         # `down` as [in, out], the layout the kernel reads without a copy
         self.experts_up = self._matrix(e, m, h)
@@ -85,6 +103,9 @@ class RoutedExperts(ParamBlock):
         logits = jnp.matmul(x.astype(jnp.float32),
                             self.router_weight._data.astype(jnp.float32),
                             precision=_HIGHEST)
+        if self.scoring == "softmax":
+            return route_softmax(logits, self.cfg.num_experts_per_tok,
+                                 self.cfg.routed_scaling_factor)
         return route(self.e_score_correction_bias._data, logits,
                      self.cfg.num_experts_per_tok,
                      self.cfg.routed_scaling_factor)

@@ -33,7 +33,9 @@ from ..utils import flight_recorder, monitor, telemetry
 #: `prefill.first_token` inside prefill_chunk); `token_masks` and
 #: `round_tail` are scheduler work outside the four; `state.reset` is
 #: the zeroing of a slot's recurrent record inside admission (models
-#: with slot state only); `unfed` (seconds from the host's seeing the
+#: with slot state only); `window.release` is the return of a window
+#: pool's pages behind the window, inside `wave.stage` and
+#: `prefill.stage` (models with a window pool only); `unfed` (seconds from the host's seeing the
 #: device with nothing queued, at a read or a round's end that finds the
 #: newest program finished, to the next program dispatch) overlaps the
 #: others. A
@@ -186,7 +188,9 @@ def record_callback_error(request, error):
 MODEL_COUNTS = ("state_resets", "moe_picks", "mla_rows_attended",
                 "mla_rows_expanded", "prefill_tokens", "prefill_chunks",
                 "ssm_records_stepped", "ssm_lanes_stepped",
-                "mhc_rows_mixed")
+                "mhc_rows_mixed", "prefill_keys",
+                "window_pages_released", "window_pages_held",
+                "window_lanes_held")
 
 
 class ServingMetrics:

@@ -305,3 +305,13 @@ class BlockPool:
             "prefix_misses": self.prefix_misses,
             "evictions": self.evictions,
         }
+
+
+class WindowBlockPool(BlockPool):
+    """The pool of a model's sliding-window layers, beside its full pool
+    (PagedServingEngine): its occupancy is the engine's own count
+    (`window_blocks_used`), and the process's `serving_cache_blocks_*`
+    gauges stay the full pool's."""
+
+    def _publish(self):
+        pass

@@ -37,11 +37,12 @@ scheduler preempts it by recompute (requeue with prompt + generated
 tokens; the freed blocks' prefix hashes make the re-prefill mostly
 cache hits).
 """
+import collections
+import hashlib
+
 import jax
 import jax.numpy as jnp
 import numpy as np
-
-import hashlib
 
 from ...nn import paged_attention
 from ...utils import chaos, telemetry
@@ -51,7 +52,7 @@ from ..metrics import MODEL_COUNTS
 from ..engine import (ServingEngine, WaveTicket, _filter_top_k_top_p, _raw,
                       _select_first_token, _select_wave_tokens, arm_lane,
                       unpack_lanes, unpack_prompt, wave_read)
-from .block_pool import BlockPool, BlockPoolExhausted
+from .block_pool import BlockPool, BlockPoolExhausted, WindowBlockPool
 
 #: block-level KV handoff payload schema version (export_slot_kv /
 #: import_handoff) — bumped when the payload layout changes so a
@@ -101,6 +102,31 @@ LATENT_REFUSAL = (
     "that form yet")
 
 
+#: why a model whose window layers keep a pool of their own is refused
+#: what moves, shares or rolls back the pages of one pool (formatted with
+#: the operation's name)
+WINDOW_REFUSAL = (
+    "{what} moves the pages of one pool; this model's window layers keep "
+    "theirs in a second pool (model.layer_windows) that gives back the "
+    "pages behind the window as a lane advances, and the program has not "
+    "been taken through two pools yet")
+
+
+def pool_window(model):
+    """The window of a model that declares window layers of one size
+    beside full ones (`model.layer_windows`, None for a full layer): its
+    window layers get a pool and tables of their own. None for any other
+    model."""
+    windows = tuple(getattr(model, "layer_windows", None) or ())
+    sizes = {int(w) for w in windows if w}
+    if not sizes or None not in windows:
+        return None
+    if len(sizes) > 1:
+        raise ValueError(f"window layers of sizes {sorted(sizes)}: one "
+                         "window pool serves one window")
+    return sizes.pop()
+
+
 class PagedServingEngine(ServingEngine):
     """Block-table batched decode executor.
 
@@ -120,7 +146,16 @@ class PagedServingEngine(ServingEngine):
         (DeepseekV3ForCausalLM) keeps one latent row a position in its
         pages (nn.paged_attention's latent form): a page of rows is a
         page, so tables, prefix sharing, copy-on-write and eviction are
-        the same; hand-off and speculation refuse it by name.
+        the same; hand-off and speculation refuse it by name. A model
+        that declares window layers beside full ones (`layer_windows`,
+        LagunaForCausalLM) gets a second pool for them (`window_pool`)
+        and a second table a slot, as wide as the first: an entry below
+        what the lane attends points to the scratch block, and a page
+        wholly behind the window goes back to the pool as the lane
+        advances, so a lane holds at most `window_pages` of them. Its
+        init_paged_cache takes `window_blocks`, its two steps
+        `window_tables`. Such a model is served without prefix sharing;
+        hand-off and speculation refuse it by name.
     max_len: per-request horizon; must be a multiple of block_size
         (table width = max_len // block_size).
     num_blocks: pool size INCLUDING the scratch block (block 0).
@@ -157,8 +192,17 @@ class PagedServingEngine(ServingEngine):
                 f"{max_len}")
         self.slot_state = bool(getattr(model, "slot_state", False))
         self.latent_cache = bool(getattr(model, "latent_cache", False))
-        self.prefix_sharing = bool(prefix_sharing) and not self.slot_state
+        self.window = pool_window(model)
+        self.prefix_sharing = (bool(prefix_sharing) and not self.slot_state
+                               and not self.window)
         self.block_pool = BlockPool(num_blocks, self.block_size)
+        if self.window:
+            # what a lane attends at most: the window behind a chunk's
+            # first query and the chunk, on pages that need not align
+            self.window_pages = -(-(self.window - 1 + self.prefill_chunk_len)
+                                  // self.block_size) + 1
+            self.window_pool = WindowBlockPool(
+                int(num_slots) * self.window_pages + 1, self.block_size)
         self._copy_fn = None
         self._set_lane_fn = None
         self._handoff_gather_fn = None
@@ -171,14 +215,22 @@ class PagedServingEngine(ServingEngine):
         self._tables = np.zeros((self.num_slots, self.blocks_per_slot),
                                 np.int32)
         self._attn_window = getattr(model.cfg, "attn_window", None)
+        if self.window:
+            # a slot's pages of the window pool: table entries
+            # `_win_first[slot]` onwards, in order
+            self._win_tables = np.zeros_like(self._tables)
+            self._win_blocks = [[] for _ in range(self.num_slots)]
+            self._win_first = [0] * self.num_slots
         # table entries the attention core visits in the decode waves
         # staged since the scheduler last took them (take_page_counts,
         # once a round), and the entries those waves' tables hold; the
         # grid steps its kernel runs, and those that fetch and score
-        # pages, in those waves and the prefill chunks, a layer
+        # pages, in those waves and the prefill chunks, a layer (a model
+        # with a window pool: each kind of layer times its layers)
         self._pages_visited = self._pages_spanned = 0
         self._steps_run = self._steps_visited = 0
-        self._attn_call = self._attn_call_shapes()
+        self._attn_call = None if self.window else self._attn_call_shapes()
+        self._attn_kinds = self._attention_kinds()
         # what the model's own layers were staged since the scheduler
         # last took them (take_model_counts, once a round): slot records
         # zeroed, (token, expert) pairs routed
@@ -188,7 +240,8 @@ class PagedServingEngine(ServingEngine):
                                             0))
         self.counts_model_work = bool(self.slot_state or self.latent_cache
                                       or self._picks_per_token
-                                      or self._mixes_per_token)
+                                      or self._mixes_per_token
+                                      or self.window)
         self._model_counts = dict.fromkeys(MODEL_COUNTS, 0)
 
     def _attn_call_shapes(self):
@@ -211,6 +264,23 @@ class PagedServingEngine(ServingEngine):
         return (heads // pool.shape[1], pool.shape[1], pool.shape[3] // 2,
                 pool.dtype.itemsize)
 
+    def _attention_kinds(self):
+        """(layers, (query heads a kv-head, kv-heads, head size, bytes a
+        value), window) of each kind of layer whose pages and kernel grid
+        the engine counts: one kind, counted once, for a model with one
+        head count and window; for a model with a window pool each
+        distinct (heads, window) of `model.layer_heads` and
+        `layer_windows`, with the number of its layers."""
+        if not self.window:
+            return [(1, self._attn_call, self._attn_window)]
+        pool = next(a for a in jax.tree_util.tree_leaves(self._caches)
+                    if a.ndim == 4)
+        hkv, d, item = pool.shape[1], pool.shape[3] // 2, pool.dtype.itemsize
+        kinds = collections.Counter(zip(self.model.layer_heads,
+                                        self.model.layer_windows))
+        return [(n, (h // hkv, hkv, d, item), w)
+                for (h, w), n in sorted(kinds.items(), key=str)]
+
     def _count_steps(self, start, c):
         """Count the kernel's grid for one staged call of `c` queries a
         lane at `start`: the function the kernel builds its grid from
@@ -222,16 +292,20 @@ class PagedServingEngine(ServingEngine):
             run, visited = paged_attention.count_latent_steps(
                 start, c, heads, width, self.block_size,
                 self.blocks_per_slot, itemsize)
-        else:
-            rep, hkv, d, itemsize = self._attn_call
+            self._steps_run += run
+            self._steps_visited += visited
+            return
+        for layers, (rep, hkv, d, itemsize), window in self._attn_kinds:
             run, visited = paged_attention.count_steps(
                 start, c, rep, hkv, d, self.block_size, self.blocks_per_slot,
-                itemsize, self._attn_window)
-        self._steps_run += run
-        self._steps_visited += visited
+                itemsize, window)
+            self._steps_run += layers * run
+            self._steps_visited += layers * visited
 
     def _make_caches(self):
         extra = {"num_slots": self.num_slots} if self.slot_state else {}
+        if self.window:
+            extra["window_blocks"] = self.window_pool.num_blocks
         caches = self.model.init_paged_cache(
             self.block_pool.num_blocks, self.block_size, self.max_len,
             dtype=self.cache_dtype, **extra)
@@ -247,6 +321,16 @@ class PagedServingEngine(ServingEngine):
         slot_state = self.slot_state
 
         chunk_len = self.prefill_chunk_len
+        nblk, window = self.blocks_per_slot, self.window
+
+        def tables_of(t):
+            """The model's table keywords of packed table rows: with a
+            window pool, the full pool's entries and then the window
+            pool's."""
+            if not window:
+                return {"block_tables": t}
+            return {"block_tables": t[..., :nblk],
+                    "window_tables": t[..., nblk:]}
 
         def decode_wave(p, b, caches, lane_tok, lane_pos, lanes, bias, key):
             key, sub = jax.random.split(key)
@@ -257,7 +341,7 @@ class PagedServingEngine(ServingEngine):
             with paged_attention.kernel_scope(kern):
                 out, _ = model.functional_call(
                     p, b, lane_tok[:, None], caches, lane_pos,
-                    method="decode_step", block_tables=tables, **live)
+                    method="decode_step", **tables_of(tables), **live)
             logits, new_caches = out
             lo = _raw(logits)[:, 0, :].astype(jnp.float32)
             nxt, new_pos, finite = _select_wave_tokens(
@@ -275,7 +359,7 @@ class PagedServingEngine(ServingEngine):
             with paged_attention.kernel_scope(kern):
                 out, _ = model.functional_call(
                     p, b, chunk[None, :], caches, method="prefill_chunk",
-                    block_tables=table[None, :], chunk_start=a["start"],
+                    **tables_of(table[None, :]), chunk_start=a["start"],
                     valid_len=a["valid"], frontier=a["frontier"], **where)
             logits, new_caches = out
             # frontier logits [1, 1, V]: only the FINAL chunk's value is
@@ -443,13 +527,18 @@ class PagedServingEngine(ServingEngine):
             last = c0 + C >= n
             frontier = (n - 1) - c0 if last else 0
             sampling = st["sampling"]
+            if self.window:
+                self._release_window([slot], [c0])
+                self._back_window(slot, (c0 + valid - 1) // bs)
             args = (*self._prefill_chunk_args(slot),
                     *self._prompt_args(slot, chunk, c0, valid, frontier,
-                                       sampling, self._tables[slot], last))
+                                       sampling, self._slot_table(slot),
+                                       last))
             counts = self._model_counts
             counts["moe_picks"] += valid * self._picks_per_token
             counts["mhc_rows_mixed"] += valid * self._mixes_per_token
             counts["prefill_tokens"] += valid
+            counts["prefill_keys"] += valid * c0 + valid * (valid + 1) // 2
             counts["prefill_chunks"] += 1
             self._count_steps(np.int32([c0]), C)
             if self.latent_cache:
@@ -552,9 +641,10 @@ class PagedServingEngine(ServingEngine):
 
     def _refuse_handoff(self, name):
         """Hand-off moves K/V pages alone: not a slot's record, and not
-        (yet) pages of latent rows."""
+        (yet) pages of latent rows or a second pool's."""
         for flag, why in ((self.slot_state, SLOT_STATE_REFUSAL),
-                          (self.latent_cache, LATENT_REFUSAL)):
+                          (self.latent_cache, LATENT_REFUSAL),
+                          (self.window, WINDOW_REFUSAL)):
             if flag:
                 raise HandoffRefused(why.format(
                     what=f"{name} (block-level hand-off)"))
@@ -700,6 +790,8 @@ class PagedServingEngine(ServingEngine):
                     self._tables[s, bi] = blk
                 elif self.block_pool.refcount(blocks[bi]) > 1:
                     self._ensure_private(s, bi)
+                if self.window:
+                    self._back_window(s, bi)
             except BlockPoolExhausted:
                 starved.append(s)
                 active_now[s] = False
@@ -713,19 +805,27 @@ class PagedServingEngine(ServingEngine):
         its table row into a live block — a mid-chunked-prefill slot's
         table is already populated, possibly with SHARED blocks. Those
         lanes get scratch rows, so the write lands in block 0 by
-        design."""
+        design. With a window pool a row is the full table and then the
+        window table."""
+        tables = self._tables
+        if self.window:
+            tables = np.concatenate([tables, self._win_tables], axis=1)
         return np.where(np.asarray(active_now, bool)[:, None],
-                        self._tables, np.int32(BlockPool.SCRATCH))
+                        tables, np.int32(BlockPool.SCRATCH))
 
     def _wave_args(self, active_now, poison, key):
+        if self.window:
+            lanes = np.flatnonzero(active_now)
+            self._release_window(lanes, self.slot_pos[lanes])
         tables = self._wave_tables(active_now)
         # every lane rides the wave at its `slot_pos`, the ones not in
         # it at a stale one over a scratch row: the core walks those too
-        lo, hi = paged_attention.attended_pages(
-            self.slot_pos, 1, self.block_size,
-            self.blocks_per_slot, self._attn_window)
-        self._pages_visited += int(np.sum(hi - lo))
-        self._pages_spanned += tables.size
+        for layers, _, window in self._attn_kinds:
+            lo, hi = paged_attention.attended_pages(
+                self.slot_pos, 1, self.block_size, self.blocks_per_slot,
+                window)
+            self._pages_visited += layers * int(np.sum(hi - lo))
+            self._pages_spanned += layers * self._tables.size
         self._count_steps(self.slot_pos, 1)
         lanes = int(np.count_nonzero(active_now))
         self._model_counts["moe_picks"] += lanes * self._picks_per_token
@@ -741,6 +841,48 @@ class PagedServingEngine(ServingEngine):
         return (self._params, self._buffers, self._caches,
                 self._lane_tok, self._lane_pos,
                 *self._lane_args(active_now, poison, tables), key)
+
+    # -------------------------------------------------------- window pool
+    def _slot_table(self, slot):
+        """The table row a prefill chunk of `slot` takes: the full pool's
+        entries, and with a window pool the window pool's after them."""
+        if not self.window:
+            return self._tables[slot]
+        return np.concatenate([self._tables[slot], self._win_tables[slot]])
+
+    def _back_window(self, slot, last):
+        """Back the slot's window table up to entry `last` with pages of
+        the window pool (BlockPoolExhausted as `alloc` raises it)."""
+        blocks = self._win_blocks[slot]
+        have = self._win_first[slot] + len(blocks)
+        if last >= have:
+            fresh = self.window_pool.alloc(last + 1 - have)
+            self._win_tables[slot, have:last + 1] = fresh
+            blocks.extend(fresh)
+
+    def _release_window(self, slots, starts):
+        """Give back the window pages of `slots` that lie wholly below what
+        their next call, whose first query sits at `starts`, attends: the
+        entries under `(start - window + 1) // block_size`. Their table
+        entries point to the scratch block again. A page given back here
+        may be written by a later program for another lane, never before
+        the programs already dispatched have read it: the device runs
+        them in order."""
+        cuts = (np.maximum(np.asarray(starts, np.int64) - self.window + 1, 0)
+                // self.block_size).tolist()
+        n = [min(max(cut - self._win_first[s], 0), len(self._win_blocks[s]))
+             for s, cut in zip(slots, cuts)]
+        with RecordEvent("serving/window/release", pid=self.trace_pid,
+                         pages=sum(n)) as ev:
+            for s, k in zip(slots, n):
+                if k:
+                    first, blocks = self._win_first[s], self._win_blocks[s]
+                    self.window_pool.release(blocks[:k])
+                    del blocks[:k]
+                    self._win_tables[s, first:first + k] = 0
+                    self._win_first[s] = first + k
+        self._acc("window.release", ev)
+        self._model_counts["window_pages_released"] += sum(n)
 
     # ----------------------------------------------------- copy-on-write
     def _ensure_private(self, slot, bi):
@@ -789,14 +931,21 @@ class PagedServingEngine(ServingEngine):
         slot records zeroed at admission, records the waves stepped
         (every slot's) and the lanes that decoded in them; (token,
         expert) pairs of the tokens staged into chunks and waves; the
-        chunks and the prompt tokens they carried; of a latent cache,
+        chunks, the prompt tokens they carried and the keys those tokens
+        attend in a full layer (position + 1 each); of a latent cache,
         the rows the waves' lanes attend and the rows the chunks expand
         (each a layer); of a residual path with several streams, those
         tokens times the sub-layers whose maps each passes
-        (`model.mhc_mixes_per_token`). Taken by the scheduler once a
-        round, from an engine whose `counts_model_work` is set."""
+        (`model.mhc_mixes_per_token`); of a window pool, the pages given
+        back behind the window, and the pages in use and the lanes
+        holding any as the call finds them (once a round: summed, the
+        pool's occupancy a round). Taken by the scheduler once a round,
+        from an engine whose `counts_model_work` is set."""
         out = self._model_counts
         self._model_counts = dict.fromkeys(MODEL_COUNTS, 0)
+        if self.window:
+            out["window_pages_held"] += self.window_pool.used
+            out["window_lanes_held"] += sum(map(bool, self._win_blocks))
         return out
 
     # ------------------------------------------------------------- slots
@@ -811,6 +960,11 @@ class PagedServingEngine(ServingEngine):
             self.block_pool.release(blocks)
         self._slot_blocks[slot] = []
         self._tables[slot, :] = 0
+        if self.window:
+            self.window_pool.release(self._win_blocks[slot])
+            self._win_blocks[slot] = []
+            self._win_first[slot] = 0
+            self._win_tables[slot, :] = 0
 
     def _health(self):
         # cache_blocks_used/total mirror the gauges of the same name:
@@ -828,6 +982,10 @@ class PagedServingEngine(ServingEngine):
             h.update(slot_state=True, state_bytes=self._state_bytes)
         if self.latent_cache:
             h.update(latent_cache=True)
+        if self.window:
+            h.update(window=self.window,
+                     window_blocks_used=self.window_pool.used,
+                     window_blocks_total=self.window_pool.usable)
         return h
 
 
@@ -971,6 +1129,10 @@ class SpeculativePagedEngine(PagedServingEngine):
                for m in (model, draft_model)):
             raise ValueError(LATENT_REFUSAL.format(
                 what="speculative decoding (the draft and verify waves)"))
+        if any(pool_window(m) for m in (model, draft_model)):
+            raise ValueError(WINDOW_REFUSAL.format(
+                what="speculative decoding (the roll-back of a rejected "
+                     "draft)"))
         self.spec_k = int(spec_k)
         draft_model.eval()
         self.draft_model = draft_model

@@ -823,3 +823,112 @@ def test_the_step_run_too_many_writes_no_block_a_later_owner_reads(model, n):
     assert again == ref
     assert sched.metrics.snapshot()["prefix_hits"] == n // BLOCK
     assert not sched._waves and eng.block_pool.used == 0
+
+
+# ---------------------------------------------------------------------------
+# a model with window layers beside full ones: the window pool
+# ---------------------------------------------------------------------------
+
+WIN, WIN_BLOCK, WIN_CHUNK, WIN_LEN = 8, 4, 16, 160
+
+
+@pytest.fixture(scope="module")
+def window_model():
+    """A tiny Laguna: full + dense, three sliding layers of window 8,
+    full; 6 and 8 heads over one kv-head."""
+    from paddle_tpu.nlp import LagunaConfig, LagunaForCausalLM
+    pt.seed(7)
+    full, sliding = "full_attention", "sliding_attention"
+    return LagunaForCausalLM(LagunaConfig(
+        vocab_size=VOCAB, hidden_size=64, intermediate_size=96,
+        num_hidden_layers=5, num_key_value_heads=1, head_dim=16,
+        num_attention_heads_per_layer=[6, 8, 8, 8, 6],
+        layer_types=[full, sliding, sliding, sliding, full],
+        mlp_layer_types=["dense"] + ["sparse"] * 4, sliding_window=WIN,
+        rope_parameters={sliding: {"rope_theta": 10000}},
+        num_experts=16, num_experts_per_tok=4, moe_intermediate_size=16,
+        shared_expert_intermediate_size=16, max_position_embeddings=256,
+        initializer_range=0.2)).eval()
+
+
+def _window_engine(model, **kw):
+    return PagedServingEngine(model, **{
+        "num_slots": 2, "max_len": WIN_LEN, "block_size": WIN_BLOCK,
+        "prefill_chunk_len": WIN_CHUNK, **kw})
+
+
+def test_window_pool_holds_its_bound_and_reuses_what_it_gives_back(
+        window_model):
+    """Each slot holds at most ceil((W - 1 + C) / BS) + 1 window pages at
+    every round (7 here: a chunk of 16 and the 7 keys before it, on pages
+    of 4), the table entries below what the lane attends point to the
+    scratch block, and the pool's 14 usable pages serve requests that
+    need far more pages than that in all: what goes back is handed out
+    again."""
+    eng = _window_engine(window_model)
+    assert eng.window_pages == -(-(WIN - 1 + WIN_CHUNK) // WIN_BLOCK) + 1
+    assert eng.window_pool.usable == 2 * eng.window_pages
+    assert eng.prefix_sharing is False
+    sched = Scheduler(eng)
+    jobs = [(_prompt(90 + i, n=40 + 17 * i), 12) for i in range(4)]
+    reqs = [sched.submit(prompt=p, max_tokens=m) for p, m in jobs]
+    handed = set()
+    while sched.step():
+        for s in range(eng.num_slots):
+            blocks = eng._win_blocks[s]
+            assert len(blocks) <= eng.window_pages
+            first = eng._win_first[s]
+            row = eng._win_tables[s]
+            assert not row[:first].any() and not row[first + len(blocks):].any()
+            assert row[first:first + len(blocks)].tolist() == blocks
+            handed.update(blocks)
+        assert eng.window_pool.used == sum(map(len, eng._win_blocks))
+    assert all(r.finish_reason == "max_tokens" for r in reqs)
+    need = sum(-(-(len(p) + m) // WIN_BLOCK) for p, m in jobs)
+    assert need > 3 * eng.window_pool.usable
+    assert handed == set(range(1, eng.window_pool.num_blocks))
+    assert eng.window_pool.used == 0 and eng.window_pool.outstanding() == {}
+    snap = sched.metrics.snapshot()
+    assert snap["window_pages_released"] > eng.window_pool.usable
+
+
+def test_window_model_preempted_by_recompute_frees_both_pools(window_model):
+    """A full pool too small for two long requests: a starved lane is
+    preempted (both tables freed), requeued, and completes with the
+    tokens of a solo run; at the end neither pool holds a page."""
+    small = _window_engine(window_model, num_blocks=17)     # 16 usable
+    jobs = [(_prompt(95 + i, n=30), 20) for i in range(2)]
+    sched, reqs = _stream(small, jobs)
+    assert all(r.finish_reason == "max_tokens" for r in reqs)
+    assert sum(r.preemptions for r in reqs) >= 1
+    assert small.block_pool.outstanding() == {}
+    assert small.window_pool.outstanding() == {}
+    for (p, m), r in zip(jobs, reqs):
+        assert Scheduler(_window_engine(window_model)).generate(
+            p, max_tokens=m) == r.output_tokens
+
+
+def test_window_model_refuses_speculation_and_handoff_by_name(window_model):
+    """What moves or rolls back the pages of one pool is refused for a
+    model whose window layers keep a second one, naming why; prefix
+    sharing is off whatever the option says."""
+    from paddle_tpu.serving import HandoffRefused, SpeculativePagedEngine
+    with pytest.raises(ValueError, match="speculative decoding.*second pool"):
+        SpeculativePagedEngine(window_model, window_model, num_slots=2,
+                               max_len=WIN_LEN, block_size=WIN_BLOCK)
+    eng = _window_engine(window_model, prefix_sharing=True)
+    assert eng.prefix_sharing is False
+    Scheduler(eng).generate(_prompt(97, n=20), max_tokens=2)
+    with pytest.raises(HandoffRefused, match="export_slot_kv.*second pool"):
+        eng.export_slot_kv(0)
+    with pytest.raises(HandoffRefused, match="import_handoff.*second pool"):
+        eng.import_handoff(0, [1, 2, 3], {})
+    # a window pool serves window layers of one size beside full ones
+    import types
+    from paddle_tpu.serving.paged.engine import pool_window
+    for windows, want in (((None, 8, 8), 8), ((8, 8), None),
+                          ((None, None), None), ((), None)):
+        assert pool_window(types.SimpleNamespace(
+            layer_windows=windows)) == want
+    with pytest.raises(ValueError, match="one window pool"):
+        pool_window(types.SimpleNamespace(layer_windows=(None, 8, 16)))

@@ -129,6 +129,11 @@ def test_snapshot_keys_byte_compatible(engine):
         # token rows put through the maps of a multi-stream residual
         # path (0 with one stream)
         "mhc_rows_mixed",
+        # keys the chunks' tokens attend in a full layer (position + 1)
+        "prefill_keys",
+        # a window pool's pages given back behind the window, and its
+        # pages and lanes holding any, summed a round (0 without one)
+        "window_pages_released", "window_pages_held", "window_lanes_held",
         # bias rows and matrices sent to the device (0 while no request
         # brings a bias)
         "bias_uploads",

@@ -173,6 +173,13 @@ def test_paged_core_server_resolves_on_tpu_compiles_for_v5e(
     # a table shorter than the pages a step would take
     ("short-table-wave", 8, 32, 8, 1, 128, 8, None),
     ("short-table-chunk", 1, 32, 8, 128, 128, 8, None),
+    # laguna-xs2-serve-agent-mix: 16 lanes, max_len 32,768 (tables of
+    # 2,048 pages), 8 kv-heads of 128; full layers 48 heads (6 a
+    # kv-head), window layers 64 (8 a kv-head) banded to 512 keys
+    ("laguna-full-wave", 16, 48, 8, 1, 128, 2048, None),
+    ("laguna-full-chunk512", 1, 48, 8, 512, 128, 2048, None),
+    ("laguna-window-wave", 16, 64, 8, 1, 128, 2048, 512),
+    ("laguna-window-chunk512", 1, 64, 8, 512, 128, 2048, 512),
 ])
 def test_paged_core_compiles_at_the_cells_shapes_for_v5e(
         one_chip, as_on_tpu, name, b, h, hkv, c, d, nblk, window):
@@ -352,7 +359,7 @@ def _engine_program_args(eng, program):
     return eng._prefill_fn, (
         *eng._prefill_chunk_args(0),
         *eng._prompt_args(0, np.zeros(chunk, np.int32), 0, chunk, 0,
-                          greedy, eng._tables[0]))
+                          greedy, eng._slot_table(0)))
 
 
 def _compile_engine_program(eng, program, sharding):
@@ -588,6 +595,85 @@ def test_wide_gated_expert_kernel_compiles_inside_vmem_for_v5e(
     assert _kernel_names(gated, *shapes) == ["moe_experts"]
     txt = jax.jit(gated).lower(*shapes).compile().as_text()
     assert not re.search(r"bf16\[64,1024,3584\][^ ]* copy\(", txt)
+
+
+@pytest.mark.parametrize("name,rows", [("wave-16-lanes", 128),
+                                       ("chunk-512", 4096)])
+def test_small_gated_experts_compile_in_one_call_for_v5e(one_chip, as_on_tpu,
+                                                         name, rows):
+    """`moe_experts` in its gated form at laguna-xs2-serve-agent-mix's
+    size (256 experts of three 512 x 2048 matrices, top-8: 128 picks a
+    wave of 16 lanes, 4,096 a chunk of 512 tokens, `LagunaMoE.MAX_ROWS`):
+    a chunk's picks are one call, whose 4,096 resident rows (101 MB)
+    beside an expert's matrices double-buffered (12.6 MB) fit the budget
+    with the expert whole (116 MiB of 120); one kernel, under its name,
+    no stack of matrices copied on the way in."""
+    from paddle_tpu.nlp.laguna import LagunaMoE
+    from paddle_tpu.ops.pallas import grouped_mlp as gm
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def gated(x, up, gate, down, sizes):
+        return gm.grouped_mlp(x, up, down, sizes, gate=gate)
+
+    assert LagunaMoE.MAX_ROWS == 512 * 8
+    assert gm._tiles(rows, 2048, 512, 2, 3) == 1
+    assert gm._vmem(rows, 2048, 512, 2, 3) <= gm.VMEM_BUDGET
+    w = sds((256, 512, 2048), jnp.bfloat16)
+    shapes = (sds((rows, 2048), jnp.bfloat16), w, w, w,
+              sds((256,), jnp.int32))
+    assert _kernel_names(gated, *shapes) == ["moe_experts"]
+    txt = jax.jit(gated).lower(*shapes).compile().as_text()
+    assert not re.search(r"bf16\[256,512,2048\][^ ]* copy\(", txt)
+
+
+@pytest.mark.parametrize("program", ["decode_wave", "prefill_chunk"])
+def test_window_pool_engine_programs_keep_both_pools_in_place_for_v5e(
+        one_chip, as_on_tpu, program):
+    """laguna-xs2-serve-agent-mix's programs as the engine builds them:
+    the published widths and heads (48 on the full layer, 64 on the
+    window layer, 8 kv-heads of 128), the YaRN and plain tables to
+    262,144 positions, 16 slots, tables of 2,048 pages, chunks of 512;
+    one full layer with the dense SwiGLU and one window layer with 8
+    experts, a small vocabulary and a sixteenth of the full pool's pages,
+    so that the host that compiles it holds the model. The full pool and the window
+    pool (16 x 65 + 1 pages) are each aliased through, neither copied nor
+    padded; a paged kernel a layer and the expert kernel."""
+    import paddle_tpu as pt
+    from paddle_tpu.nlp import LagunaConfig, LagunaForCausalLM
+    from paddle_tpu.serving import PagedServingEngine
+
+    pt.seed(0)
+    full, sliding = "full_attention", "sliding_attention"
+    model = LagunaForCausalLM(LagunaConfig(
+        vocab_size=512, num_hidden_layers=2, layer_types=[full, sliding],
+        mlp_layer_types=["dense", "sparse"],
+        num_attention_heads_per_layer=[48, 64], num_experts=8,
+        rope_parameters={
+            full: {"rope_theta": 500000, "rope_type": "yarn", "factor": 64,
+                   "original_max_position_embeddings": 4096,
+                   "beta_slow": 1, "beta_fast": 64,
+                   "attention_factor": 1.4158883083359672,
+                   "partial_rotary_factor": 0.5},
+            sliding: {"rope_type": "default", "rope_theta": 10000}},
+        param_dtype="bfloat16", init_weights=False))
+    eng = PagedServingEngine(model, num_slots=16, max_len=32768,
+                             block_size=16, num_blocks=2049,
+                             prefill_chunk_len=512,
+                             cache_dtype=jnp.bfloat16,
+                             paged_kernel="pallas")
+    assert (eng.window, eng.window_pages) == (512, 65)
+    pools = jax.tree_util.tree_leaves(eng._caches)
+    assert [p.shape for p in pools] == [(2049, 8, 16, 256),
+                                        (16 * 65 + 1, 8, 16, 256)]
+    compiled = _compile_engine_program(eng, program, one_chip)
+    _pool_stays(compiled, pools[:1])
+    _lane_state_stays(compiled, eng, program, pools)
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 3
+    assert not re.search(r"bf16\[1041,8,16,256\]\S* (copy|transpose)\(",
+                         text)
 
 
 def test_a_one_stream_model_lowers_to_a_plain_residual():
