@@ -18,7 +18,7 @@ import jax.numpy as jnp
 
 from .. import nn
 from ..nn import initializer as I
-from ..ops.pallas.grouped_mlp import grouped_mlp
+from ..ops.pallas.grouped_mlp import grouped_mlp, note_fetched, walk
 
 _HIGHEST = jax.lax.Precision.HIGHEST
 
@@ -111,8 +111,8 @@ class RoutedExperts(ParamBlock):
                      self.cfg.routed_scaling_factor)
 
     def _segment(self, x, idx, weights):
-        """x [T, hidden], idx and weights [T, k] -> [T, hidden] float32,
-        T k <= MAX_ROWS."""
+        """x [T, hidden], idx and weights [T, k] -> ([T, hidden] float32,
+        the experts the kernel's call fetched), T k <= MAX_ROWS."""
         k = idx.shape[1]
         flat = idx.reshape(-1)
         order = jnp.argsort(flat)                   # stable: by expert
@@ -123,18 +123,24 @@ class RoutedExperts(ParamBlock):
         out = out * weights.reshape(-1)[order][:, None]
         # back to token order: pick j of token t sits at row inv[t k + j]
         inv = jnp.argsort(order)
-        return out[inv].reshape(-1, k, out.shape[-1]).sum(axis=1)
+        return (out[inv].reshape(-1, k, out.shape[-1]).sum(axis=1),
+                walk(sizes)[1])
 
     def experts(self, x, idx, weights):
-        """x [T, hidden], idx and weights [T, k] -> [T, hidden] float32."""
+        """x [T, hidden], idx and weights [T, k] -> [T, hidden] float32.
+        The experts its kernel calls fetched go to the program's
+        `fetch_tally`, if it keeps one."""
         t, k = idx.shape
         seg = max(1, self.MAX_ROWS // k)
         if t <= seg:
-            return self._segment(x, idx, weights)
+            out, fetched = self._segment(x, idx, weights)
+            note_fetched(fetched)
+            return out
         pad = -t % seg              # padded tokens: expert 0, weight 0
         parts = [jnp.pad(a, ((0, pad), (0, 0))).reshape(-1, seg, a.shape[1])
                  for a in (x, idx, weights)]
-        out = jax.lax.map(lambda a: self._segment(*a), tuple(parts))
+        out, fetched = jax.lax.map(lambda a: self._segment(*a), tuple(parts))
+        note_fetched(jnp.sum(fetched))
         return out.reshape(-1, out.shape[-1])[:t]
 
     def shared(self, x):

@@ -279,12 +279,17 @@ def arm_lane(lane_tok, lane_pos, a, first):
             lane_pos.at[slot].set(jnp.where(a["last"], n, lane_pos[slot])))
 
 
-def wave_read(nxt, finite):
+def wave_read(nxt, finite, fetched=()):
     """What the host reads of a wave, one int32[2, S] array of its own
     (the lane state beside it is donated to the next program before the
     host gets to it): the tokens, and whether each lane's logits were
-    finite."""
-    return jnp.stack([nxt, finite.astype(jnp.int32)])
+    finite. A wave whose model noted the experts its kernel calls
+    fetched (`ops.pallas.grouped_mlp.fetch_tally`) gives their sum at
+    the head of a third row."""
+    rows = [nxt, finite.astype(jnp.int32)]
+    if fetched:
+        rows.append(jnp.zeros_like(nxt).at[0].set(sum(fetched)))
+    return jnp.stack(rows)
 
 
 class WaveTicket:
@@ -1012,8 +1017,10 @@ class ServingEngine:
         if ticket.tokens is not None:
             return ticket.tokens
         with RecordEvent("serving/wave/wait", pid=self.trace_pid) as ev:
-            tok, finite = jax.device_get(ticket.read)
+            tok, finite, *counted = jax.device_get(ticket.read)
         self._read_back("wave.wait", ev)
+        if counted:
+            self._wave_counted(counted[0])
         lanes = ticket.lanes[self._slot_epoch[ticket.lanes]
                              == ticket.epochs]
         finite = finite[lanes] != 0
@@ -1024,6 +1031,10 @@ class ServingEngine:
         self.slot_tok[ok] = tok[ok]
         self.last_nonfinite_slots = bad.tolist()
         return dict(zip(ok.tolist(), tok[ok].tolist()))
+
+    def _wave_counted(self, row):
+        """Hook: the third row of a wave's read, what its model counted
+        on the device (`wave_read`)."""
 
     def _wave_poison(self):
         """[S] bool of lanes whose logits the chaos harness poisons in

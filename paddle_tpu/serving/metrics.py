@@ -192,6 +192,12 @@ MODEL_COUNTS = ("state_resets", "moe_picks", "mla_rows_attended",
                 "window_pages_released", "window_pages_held",
                 "window_lanes_held")
 
+#: what it counts beside them for a model with routed experts alone, and
+#: the snapshot carries only once such an engine has reported them: the
+#: experts the decode waves' kernel calls fetched (counted on the device,
+#: read with the wave's tokens) and those the waves' sparse layers store
+EXPERT_COUNTS = ("moe_experts_fetched", "moe_experts_stored")
+
 
 class ServingMetrics:
     """Per-engine aggregation on top of the process-wide sinks: bounded
@@ -340,7 +346,7 @@ class ServingMetrics:
         """One round's `PagedServingEngine.take_model_counts()`."""
         with self._lock:
             for k, v in counts.items():
-                self._model_counts[k] += int(v)
+                self._model_counts[k] = self._model_counts.get(k, 0) + int(v)
         if counts.get("mhc_rows_mixed"):
             _MHC_ROWS.inc(int(counts["mhc_rows_mixed"]))
 

@@ -45,10 +45,11 @@ import jax.numpy as jnp
 import numpy as np
 
 from ...nn import paged_attention
+from ...ops.pallas import grouped_mlp
 from ...utils import chaos, telemetry
 from ...utils.profiler import RecordEvent
 from .. import blackbox
-from ..metrics import MODEL_COUNTS
+from ..metrics import EXPERT_COUNTS, MODEL_COUNTS
 from ..engine import (ServingEngine, WaveTicket, _filter_top_k_top_p, _raw,
                       _select_first_token, _select_wave_tokens, arm_lane,
                       unpack_lanes, unpack_prompt, wave_read)
@@ -243,6 +244,14 @@ class PagedServingEngine(ServingEngine):
                                       or self._mixes_per_token
                                       or self.window)
         self._model_counts = dict.fromkeys(MODEL_COUNTS, 0)
+        # of a model with routed experts, the experts its sparse layers
+        # store, which every wave's kernel calls could fetch; the waves
+        # read since the last take report those they did fetch
+        cfg = model.cfg
+        self._experts_stored = (
+            self._picks_per_token // cfg.num_experts_per_tok
+            * cfg.n_routed_experts if self._picks_per_token else 0)
+        self._expert_counts = dict.fromkeys(EXPERT_COUNTS, 0)
 
     def _attn_call_shapes(self):
         """What the engine's programs hand the attention core, read off
@@ -338,7 +347,10 @@ class PagedServingEngine(ServingEngine):
             # the scope pins this engine's kernel at TRACE time: the
             # compiled wave keeps the core the engine was built with
             live = {"active": a["active"]} if slot_state else {}
-            with paged_attention.kernel_scope(kern):
+            # a routed model's layers note the experts their kernel calls
+            # fetch, which the wave's read carries (none for any other)
+            with paged_attention.kernel_scope(kern), \
+                    grouped_mlp.fetch_tally() as fetched:
                 out, _ = model.functional_call(
                     p, b, lane_tok[:, None], caches, lane_pos,
                     method="decode_step", **tables_of(tables), **live)
@@ -347,7 +359,8 @@ class PagedServingEngine(ServingEngine):
             nxt, new_pos, finite = _select_wave_tokens(
                 lo, lane_tok, lane_pos, a["active"], a["sample"],
                 a["temp"], a["top_k"], a["top_p"], bias, a["poison"], sub)
-            return wave_read(nxt, finite), new_caches, nxt, new_pos, key
+            return (wave_read(nxt, finite, fetched), new_caches, nxt,
+                    new_pos, key)
 
         def prefill_chunk(p, b, caches, lane_tok, lane_pos, prompt, bias,
                           key):
@@ -830,6 +843,7 @@ class PagedServingEngine(ServingEngine):
         lanes = int(np.count_nonzero(active_now))
         self._model_counts["moe_picks"] += lanes * self._picks_per_token
         self._model_counts["mhc_rows_mixed"] += lanes * self._mixes_per_token
+        self._expert_counts["moe_experts_stored"] += self._experts_stored
         if self.slot_state:
             # the wave's program steps every slot's record, whether its
             # lane decodes or keeps what it had
@@ -939,14 +953,22 @@ class PagedServingEngine(ServingEngine):
         (`model.mhc_mixes_per_token`); of a window pool, the pages given
         back behind the window, and the pages in use and the lanes
         holding any as the call finds them (once a round: summed, the
-        pool's occupancy a round). Taken by the scheduler once a round,
-        from an engine whose `counts_model_work` is set."""
+        pool's occupancy a round); of a model with routed experts, the
+        experts the waves read since fetched and those they store
+        (`EXPERT_COUNTS`). Taken by the scheduler once a round, from an
+        engine whose `counts_model_work` is set."""
         out = self._model_counts
         self._model_counts = dict.fromkeys(MODEL_COUNTS, 0)
         if self.window:
             out["window_pages_held"] += self.window_pool.used
             out["window_lanes_held"] += sum(map(bool, self._win_blocks))
+        if self._experts_stored:
+            out.update(self._expert_counts)
+            self._expert_counts = dict.fromkeys(EXPERT_COUNTS, 0)
         return out
+
+    def _wave_counted(self, row):
+        self._expert_counts["moe_experts_fetched"] += int(row[0])
 
     # ------------------------------------------------------------- slots
     def retire_slot(self, slot):

@@ -305,6 +305,69 @@ def test_the_counters_of_a_window_pool(model):
     assert snap["paged_steps_visited"] > 0
 
 
+class _Routes:
+    """Records the experts every sparse layer's router chooses in the
+    decode waves of an uncompiled engine (the waves' calls route
+    `num_slots` tokens, the chunks' `CHUNK`)."""
+
+    def __init__(self, model, lanes):
+        self.moes = [blk.mlp for blk in model.layers if not blk.dense]
+        self.picks = []
+        for moe in self.moes:
+            inner = moe.route
+
+            def spy(x, _inner=inner):
+                idx, w = _inner(x)
+                if x.shape[0] == lanes:
+                    self.picks.append(np.asarray(idx))
+                return idx, w
+            moe.route = spy
+
+    def restore(self):
+        for moe in self.moes:
+            del moe.route
+
+
+def test_the_experts_a_wave_fetches_are_counted_on_the_device(model):
+    """`moe_experts_fetched`: the experts the waves' kernel calls walked,
+    summed over the sparse layers in the program and read with the wave's
+    tokens, is the distinct experts each sparse layer's router chose for
+    the wave's lanes; `moe_experts_stored` is waves x 16 experts x 4
+    sparse layers. Uncompiled, where the router's choices can be read,
+    and compiled, which counts the same."""
+    jobs = [(_prompt(60 + i, 20 + 7 * i), 6) for i in range(5)]
+
+    def serve(eng):
+        sched = Scheduler(eng)
+        for prompt, m in jobs:
+            sched.submit(prompt=prompt, max_tokens=m)
+        while sched.step():
+            pass
+        return sched.metrics.snapshot()
+
+    eng = PagedServingEngine(model, num_slots=3, max_len=MAX_LEN,
+                             block_size=BLOCK, prefill_chunk_len=CHUNK,
+                             jit_compile=False)
+    routes = _Routes(model, eng.num_slots)
+    try:
+        snap = serve(eng)
+    finally:
+        routes.restore()
+    waves = len(routes.picks) // 4
+    assert waves == snap["waves_dispatched"] > 5
+    distinct = sum(len(np.unique(idx)) for idx in routes.picks)
+    assert snap["moe_experts_fetched"] == distinct
+    assert snap["moe_experts_stored"] == waves * 16 * 4
+    # three lanes of top-4 over 16 experts leave some of them unchosen
+    assert distinct < snap["moe_experts_stored"]
+    compiled = serve(PagedServingEngine(model, num_slots=3, max_len=MAX_LEN,
+                                        block_size=BLOCK,
+                                        prefill_chunk_len=CHUNK))
+    for key in ("waves_dispatched", "moe_experts_fetched",
+                "moe_experts_stored"):
+        assert compiled[key] == snap[key], key
+
+
 def test_the_front_door_serves_it_and_a_hard_close_gives_both_pools_back(
         model):
     """`create_llm_predictor` -> the paged engine with a window pool and no

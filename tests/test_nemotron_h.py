@@ -220,6 +220,61 @@ def test_grouped_kernel_keeps_each_experts_rows_apart():
     _close(got, want)
 
 
+@pytest.fixture
+def small_budget(monkeypatch):
+    """A VMEM budget that makes the kernel walk an expert of width 512 in
+    four slices of its width (the calls are built afresh around it)."""
+    from paddle_tpu.ops.pallas import grouped_mlp as gm
+    monkeypatch.setattr(gm, "VMEM_BUDGET", gm._vmem(64, 32, 128, 4, 3))
+    gm._call.cache_clear()
+    yield
+    gm._call.cache_clear()
+
+
+@pytest.mark.parametrize("sizes", [
+    [0, 5, 16, 3, 9, 0],                  # the first and last experts empty
+    [4, 0, 0, 0, 18, 0, 0, 0, 0, 2, 0, 0],  # runs of empty experts
+    [0, 0, 0, 21, 0, 0],                  # a single expert touched
+    [2, 17, 1, 16, 5, 3],                 # every expert touched
+    [7, 0, 9, 25, 1, 0, 14],              # groups across 16-row blocks
+], ids=["ends-empty", "runs-empty", "one-expert", "every-expert",
+        "straddling"])
+@pytest.mark.parametrize("form", ["plain", "gated", "tiled"])
+def test_grouped_kernel_walks_only_the_experts_that_hold_rows(
+        sizes, form, request):
+    """The kernel walks the experts that hold rows, in ascending order
+    (`walk`), and no other; every row it serves equals its expert's MLP
+    in numpy, in both forms and in the tiled one (an expert walked in
+    slices of its width, forced by a small VMEM budget)."""
+    from paddle_tpu.ops.pallas import grouped_mlp as gm
+    if form == "tiled":
+        request.getfixturevalue("small_budget")
+    gated = form != "plain"
+    width = 512 if form == "tiled" else 24
+    sizes = np.asarray(sizes, np.int32)
+    e, hidden = len(sizes), 32
+    order, steps = gm.walk(jnp.asarray(sizes))
+    held = np.flatnonzero(sizes)
+    assert int(steps) == len(held)
+    assert np.asarray(order)[:len(held)].tolist() == held.tolist()
+    assert sorted(np.asarray(order).tolist()) == list(range(e))
+    rows = -(-int(sizes.sum()) // gm.ROWS) * gm.ROWS
+    assert gm._tiles(rows, hidden, width, 4, 3) == (4 if form == "tiled"
+                                                    else 1)
+    rng = np.random.default_rng(int(sizes.sum()))
+    x = rng.normal(0, 1, (int(sizes.sum()), hidden)).astype(np.float32)
+    up, gate, down = (rng.normal(0, 0.3, (e, width, hidden)).astype(
+        np.float32) for _ in range(3))
+    got = gm.grouped_mlp(jnp.asarray(x), jnp.asarray(up), jnp.asarray(down),
+                         jnp.asarray(sizes),
+                         gate=jnp.asarray(gate) if gated else None)
+    owner = np.repeat(np.arange(e), sizes)
+    hid, g = (np.einsum("rh,rwh->rw", x, w[owner]) for w in (up, gate))
+    hid = (hid * g / (1 + np.exp(-g)) if gated    # up(x) * silu(gate(x))
+           else np.square(np.maximum(hid, 0)))
+    _close(got, np.einsum("rw,rwh->rh", hid, down[owner]))
+
+
 def test_choice_by_biased_scores_weights_from_unbiased_ones():
     logits = jnp.asarray([[2.0, 1.0, 0.5, -1.0], [0.0, 0.1, 0.2, 0.3]])
     s = np.asarray(jax.nn.sigmoid(logits))

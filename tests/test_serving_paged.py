@@ -20,6 +20,8 @@ scripts/chaos_serving.py) and prove the acceptance contract:
 """
 import collections
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -107,6 +109,22 @@ def test_block_utilization_reported(paged):
     assert snap["block_utilization"] is not None
     assert 0 < snap["block_utilization"] < 1
     assert paged.block_pool.used == 0          # all blocks back home
+
+
+def test_a_dense_models_wave_counts_no_experts(model, paged):
+    """A model without routed experts notes nothing while its wave is
+    traced: the wave's read is the int32[2, S] of tokens and finite flags
+    it was, and the snapshot carries no expert counter."""
+    sched, _ = _stream(paged, [(_prompt(4, 6), 3)])
+    assert not {"moe_experts_fetched", "moe_experts_stored"} & set(
+        sched.metrics.snapshot())
+    eng = PagedServingEngine(model, num_slots=4, max_len=MAX_LEN,
+                             block_size=BLOCK, num_blocks=33,
+                             prefill_chunk_len=CHUNK)
+    args = eng._wave_args(np.ones(4, bool), np.zeros(4, bool),
+                          jax.random.PRNGKey(0))
+    read = jax.eval_shape(eng._decode_wave_fn, *args)[0]
+    assert read.shape == (2, 4) and read.dtype == jnp.int32
 
 
 # ---------------------------------------------------------------------------

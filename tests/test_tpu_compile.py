@@ -520,6 +520,25 @@ def test_dense_hybrid_engine_programs_keep_pool_and_state_in_place_for_v5e(
     assert compiled.as_text().count("tpu_custom_call") == 1
 
 
+def _expert_operands(txt):
+    """The operand types of the one `moe_experts` call in a compiled
+    program's text, in order."""
+    line, = (ln for ln in txt.splitlines() if "%moe_experts" in ln
+             and 'custom_call_target="tpu_custom_call"' in ln)
+    kinds = line.split("operand_layout_constraints={", 1)[1].split("}}")[0]
+    return re.findall(r"([a-z0-9]+\[[0-9,]*\])\{", kinds + "}")
+
+
+def _walks_the_experts(txt, rows, experts, width, hidden, mats):
+    """The call takes the resident rows first (a device trace's reader
+    takes a Mosaic call whose first operand is an s32 array for paged
+    attention), the stacks of matrices as they are stored, and then the
+    walk (s32[experts]), the steps it runs and the groups' offsets."""
+    assert _expert_operands(txt) == [
+        f"bf16[{rows},{hidden}]", *[f"bf16[{experts},{width},{hidden}]"]
+        * mats, f"s32[{experts}]", "s32[1]", f"s32[{experts + 1}]"]
+
+
 @pytest.mark.parametrize("name,rows", [("wave-128-lanes", 768),
                                        ("ragged-rows", 100)])
 def test_grouped_expert_kernel_compiles_for_v5e(one_chip, as_on_tpu, name,
@@ -538,6 +557,7 @@ def test_grouped_expert_kernel_compiles_for_v5e(one_chip, as_on_tpu, name,
     assert _kernel_names(grouped_mlp, *shapes) == ["moe_experts"]
     txt = jax.jit(grouped_mlp).lower(*shapes).compile().as_text()
     assert not re.search(r"bf16\[128,1856,2688\][^ ]* copy\(", txt)
+    _walks_the_experts(txt, -(-rows // 16) * 16, 128, 1856, 2688, 2)
 
 
 @pytest.mark.parametrize("name,rows", [("wave-32-lanes", 192),
@@ -562,6 +582,7 @@ def test_gated_expert_kernel_compiles_for_v5e(one_chip, as_on_tpu, name,
     assert _kernel_names(gated, *shapes) == ["moe_experts"]
     txt = jax.jit(gated).lower(*shapes).compile().as_text()
     assert not re.search(r"bf16\[128,768,2048\][^ ]* copy\(", txt)
+    _walks_the_experts(txt, rows, 128, 768, 2048, 3)
 
 
 @pytest.mark.parametrize("name,rows,tiles", [("wave-16-lanes", 64, 1),
@@ -595,6 +616,7 @@ def test_wide_gated_expert_kernel_compiles_inside_vmem_for_v5e(
     assert _kernel_names(gated, *shapes) == ["moe_experts"]
     txt = jax.jit(gated).lower(*shapes).compile().as_text()
     assert not re.search(r"bf16\[64,1024,3584\][^ ]* copy\(", txt)
+    _walks_the_experts(txt, rows, 64, 1024, 3584, 3)
 
 
 @pytest.mark.parametrize("name,rows", [("wave-16-lanes", 128),
@@ -626,6 +648,7 @@ def test_small_gated_experts_compile_in_one_call_for_v5e(one_chip, as_on_tpu,
     assert _kernel_names(gated, *shapes) == ["moe_experts"]
     txt = jax.jit(gated).lower(*shapes).compile().as_text()
     assert not re.search(r"bf16\[256,512,2048\][^ ]* copy\(", txt)
+    _walks_the_experts(txt, rows, 256, 512, 2048, 3)
 
 
 @pytest.mark.parametrize("program", ["decode_wave", "prefill_chunk"])
