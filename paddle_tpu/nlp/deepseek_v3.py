@@ -322,12 +322,6 @@ class DeepseekV3MoE(RoutedExperts):
     """Gated routed experts `down(silu(gate x) * up x)` and the shared
     experts as one SwiGLU of `n_shared_experts` times their width."""
 
-    #: three matrices of 768 x 2048 are 19 MB double-buffered where the
-    #: ungated model's two of 1856 x 2688 are 40: the kernel's VMEM holds
-    #: twice the rows, and a chunk of 512 tokens (3,072 picks) reads the
-    #: experts in two segments and not three
-    MAX_ROWS = 2048
-
     def __init__(self, cfg):
         super().__init__(cfg,
                          cfg.n_shared_experts * cfg.moe_intermediate_size,

@@ -242,12 +242,6 @@ class LagunaAttention(ParamBlock):
 class LagunaMoE(RoutedExperts):
     """Softmax-routed gated experts and one gated shared expert."""
 
-    #: a chunk of 512 tokens makes 4,096 picks: one call of the kernel,
-    #: which fetches every expert once (4,096 resident rows and an expert
-    #: of three 512 x 2048 matrices double-buffered plan 116 MiB of VMEM,
-    #: inside ops/pallas/grouped_mlp.py's budget with the expert whole)
-    MAX_ROWS = 4096
-
     def __init__(self, cfg):
         super().__init__(cfg, cfg.shared_expert_intermediate_size,
                          gated=True, scoring="softmax")

@@ -3,8 +3,12 @@ config's dtype, the router, and the dropless dispatch of sorted picks
 through the grouped kernel (`ops/pallas/grouped_mlp.py`), in the two
 forms an expert takes: `down(relu(up(x))^2)` (two matrices an expert)
 and, gated, `down(silu(gate(x)) * up(x))` (three). `nlp/nemotron_h.py`
-uses the first, `nlp/deepseek_v3.py` the second; the router, the sort,
-the segments and the shared expert are one body.
+uses the first, `nlp/deepseek_v3.py` and `nlp/laguna.py` the second; the
+router, the sort, the segments and the shared expert are one body. A
+forward's picks go through the kernel in one call wherever the kernel's
+VMEM plan holds them (`grouped_mlp.max_rows`, from the layer's own
+widths, dtype and matrices), and a segment at a time, each as large as
+fits, only past that.
 
 A config gives `hidden_size`, `n_routed_experts`, `num_experts_per_tok`,
 `moe_intermediate_size`, `routed_scaling_factor`, `initializer_range`,
@@ -18,7 +22,8 @@ import jax.numpy as jnp
 
 from .. import nn
 from ..nn import initializer as I
-from ..ops.pallas.grouped_mlp import grouped_mlp, note_fetched, walk
+from ..ops.pallas.grouped_mlp import (grouped_mlp, max_rows, note_fetched,
+                                     walk)
 
 _HIGHEST = jax.lax.Precision.HIGHEST
 
@@ -72,10 +77,6 @@ class RoutedExperts(ParamBlock):
     expert's weights. `scoring` is "sigmoid" (`route`, with its
     correction bias) or "softmax" (`route_softmax`, no bias)."""
 
-    #: picks one call of the kernel keeps resident; more tokens than
-    #: this many picks go through it a segment at a time
-    MAX_ROWS = 1024
-
     def __init__(self, cfg, shared_width, gated=False, scoring="sigmoid"):
         super().__init__(cfg)
         if scoring not in ("sigmoid", "softmax"):
@@ -110,9 +111,22 @@ class RoutedExperts(ParamBlock):
                      self.cfg.num_experts_per_tok,
                      self.cfg.routed_scaling_factor)
 
+    def tokens_a_call(self):
+        """Tokens whose picks one kernel call takes: the rows the
+        kernel's plan holds at this layer's widths, dtype and matrices
+        (`grouped_mlp.max_rows`) over top-k, at least one."""
+        _, width, hidden = self.experts_up.shape
+        rows = max_rows(hidden, width, self.experts_up._data.dtype.itemsize,
+                        3 if self.gated else 2)
+        return max(1, rows // self.cfg.num_experts_per_tok)
+
+    def kernel_calls(self, tokens):
+        """The kernel calls `experts` makes for `tokens` tokens."""
+        return -(-tokens // self.tokens_a_call())
+
     def _segment(self, x, idx, weights):
         """x [T, hidden], idx and weights [T, k] -> ([T, hidden] float32,
-        the experts the kernel's call fetched), T k <= MAX_ROWS."""
+        the experts the kernel's call fetched), T <= `tokens_a_call()`."""
         k = idx.shape[1]
         flat = idx.reshape(-1)
         order = jnp.argsort(flat)                   # stable: by expert
@@ -127,11 +141,13 @@ class RoutedExperts(ParamBlock):
                 walk(sizes)[1])
 
     def experts(self, x, idx, weights):
-        """x [T, hidden], idx and weights [T, k] -> [T, hidden] float32.
-        The experts its kernel calls fetched go to the program's
-        `fetch_tally`, if it keeps one."""
-        t, k = idx.shape
-        seg = max(1, self.MAX_ROWS // k)
+        """x [T, hidden], idx and weights [T, k] -> [T, hidden] float32:
+        the picks in one kernel call where it holds them, else a segment
+        of `tokens_a_call()` tokens a call (`kernel_calls`). The experts
+        its calls fetched go to the program's `fetch_tally`, if it keeps
+        one."""
+        t = idx.shape[0]
+        seg = self.tokens_a_call()
         if t <= seg:
             out, fetched = self._segment(x, idx, weights)
             note_fetched(fetched)

@@ -70,6 +70,17 @@ def _tiles(rows, hidden, width, item, mats):
     return tiles
 
 
+def max_rows(hidden, width, item, mats):
+    """The most rows, a multiple of `ROWS`, one call plans inside the
+    budget: resident beside an expert cut in as many slices as `_tiles`
+    may cut it (at least one block of rows)."""
+    tiles = 1
+    while width % (256 * tiles) == 0:
+        tiles *= 2
+    free = VMEM_BUDGET - _vmem(0, hidden, width // tiles, item, mats)
+    return max(ROWS, free // (2 * hidden * (item + 4)) // ROWS * ROWS)
+
+
 def walk(group_sizes):
     """(walk int32[experts], n): the experts a call steps through, those
     that hold rows first in ascending order and then the rest, and the

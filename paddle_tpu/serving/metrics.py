@@ -195,8 +195,12 @@ MODEL_COUNTS = ("state_resets", "moe_picks", "mla_rows_attended",
 #: what it counts beside them for a model with routed experts alone, and
 #: the snapshot carries only once such an engine has reported them: the
 #: experts the decode waves' kernel calls fetched (counted on the device,
-#: read with the wave's tokens) and those the waves' sparse layers store
-EXPERT_COUNTS = ("moe_experts_fetched", "moe_experts_stored")
+#: read with the wave's tokens) and those the waves' sparse layers store;
+#: the expert kernel's calls the prefill chunks' sparse layers made
+#: (`RoutedExperts.kernel_calls`, counted where a chunk is staged) and
+#: those layers, a chunk's each time
+EXPERT_COUNTS = ("moe_experts_fetched", "moe_experts_stored",
+                 "moe_chunk_calls", "moe_chunk_layers")
 
 
 class ServingMetrics:
@@ -493,7 +497,10 @@ class ServingMetrics:
             "paged_steps_run": steps_r,
             "paged_steps_visited": steps_v,
             # slot records zeroed and (token, expert) pairs routed, for
-            # a model that has either (serving/paged/engine.py)
+            # a model that has either (serving/paged/engine.py); of a
+            # model with routed experts, `EXPERT_COUNTS` besides: the
+            # experts the waves fetched and stored, and the kernel calls
+            # the chunks' sparse layers made and those layers
             **model_counts,
             # bias rows and matrices uploaded (serving/engine.py)
             "bias_uploads": bias_uploads,

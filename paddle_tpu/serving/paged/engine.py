@@ -44,6 +44,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ...nlp.moe import RoutedExperts
 from ...nn import paged_attention
 from ...ops.pallas import grouped_mlp
 from ...utils import chaos, telemetry
@@ -246,11 +247,18 @@ class PagedServingEngine(ServingEngine):
         self._model_counts = dict.fromkeys(MODEL_COUNTS, 0)
         # of a model with routed experts, the experts its sparse layers
         # store, which every wave's kernel calls could fetch; the waves
-        # read since the last take report those they did fetch
+        # read since the last take report those they did fetch. A chunk
+        # runs its sparse layers, each in the kernel calls its
+        # `kernel_calls` cuts the chunk's tokens into
         cfg = model.cfg
         self._experts_stored = (
             self._picks_per_token // cfg.num_experts_per_tok
             * cfg.n_routed_experts if self._picks_per_token else 0)
+        routed = [m for m in model.sublayers()
+                  if isinstance(m, RoutedExperts)]
+        self._chunk_layers = len(routed)
+        self._chunk_calls = sum(m.kernel_calls(self.prefill_chunk_len)
+                                for m in routed)
         self._expert_counts = dict.fromkeys(EXPERT_COUNTS, 0)
 
     def _attn_call_shapes(self):
@@ -558,6 +566,10 @@ class PagedServingEngine(ServingEngine):
                 counts["mla_rows_expanded"] += int(
                     paged_attention.expanded_rows(c0, C, bs,
                                                   self.blocks_per_slot))
+            if self._experts_stored:
+                self._expert_counts["moe_chunk_calls"] += self._chunk_calls
+                self._expert_counts["moe_chunk_layers"] += \
+                    self._chunk_layers
         self._acc("prefill.stage", ev)
         with RecordEvent("serving/prefill/dispatch", pid=pid) as ev:
             first, self._caches, self._lane_tok, self._lane_pos, \
@@ -954,7 +966,8 @@ class PagedServingEngine(ServingEngine):
         back behind the window, and the pages in use and the lanes
         holding any as the call finds them (once a round: summed, the
         pool's occupancy a round); of a model with routed experts, the
-        experts the waves read since fetched and those they store
+        experts the waves read since fetched and those they store, and
+        the kernel calls the chunks' sparse layers made and those layers
         (`EXPERT_COUNTS`). Taken by the scheduler once a round, from an
         engine whose `counts_model_work` is set."""
         out = self._model_counts

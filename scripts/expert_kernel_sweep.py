@@ -6,16 +6,19 @@ shapes.
 
 For each routed configuration the benchmark serves (experts, width,
 hidden, gated or not) it times one `grouped_mlp` call at the cell's wave
-shape (its lanes x top-k picks) and at its chunk's picks (a segment of
-`RoutedExperts.MAX_ROWS` where a chunk takes several), with picks drawn
-as a cell's routing draws them when the lanes choose independently: each
-token picks k distinct experts at random. It prints one JSON line a
-call: the experts that hold rows, the bytes of their matrices, the
-seconds a call and the rate those bytes arrive at. A call's time is the
-difference of two loops of the same jitted program (10 and 2 calls), so
-dispatch and read-back cancel. `--old PATH` also times the module at
-PATH (the parent's `ops/pallas/grouped_mlp.py`) on the same inputs, and
-prints the largest difference between the two outputs.
+shape (its lanes x top-k picks) and at its whole chunk's picks, with
+picks drawn as a cell's routing draws them when the lanes choose
+independently: each token picks k distinct experts at random. It prints
+one JSON line a shape: the experts that hold rows, the bytes of their
+matrices, the seconds and the rate those bytes arrive at. A call's time
+is the difference of two loops of the same jitted program (10 and 2
+calls), so dispatch and read-back cancel. `--old PATH` also times the
+module at PATH (the parent's `ops/pallas/grouped_mlp.py`) on the same
+picks, and prints the largest difference between the two outputs, pick
+by pick. Where the parent cut a cell's chunk into segments (kanana2's
+512 tokens: two of 341, the second padded with 170 tokens whose picks
+are all expert 0's, as `nlp/moe.py` padded them), its form is those
+calls, timed one after the other and summed.
 
 Needs a TPU (`--rehearse`: tiny shapes on any backend, for the plumbing
 alone).
@@ -37,12 +40,13 @@ import jax.numpy as jnp  # noqa: E402
 from paddle_tpu.ops.pallas import grouped_mlp as gm  # noqa: E402
 
 #: cell: experts, width, hidden, gated, top-k, (tokens of a wave, tokens
-#: of one call of a chunk)
+#: of a chunk), tokens of a segment the parent cut a chunk into (None:
+#: one call)
 CONFIGS = {
-    "laguna": (256, 512, 2048, True, 8, (16, 512)),
-    "xing4": (64, 1024, 3584, True, 4, (16, 512)),
-    "kanana2": (128, 768, 2048, True, 6, (32, 341)),
-    "nemotron": (128, 1856, 2688, False, 6, (128, 128)),
+    "laguna": (256, 512, 2048, True, 8, (16, 512), None),
+    "xing4": (64, 1024, 3584, True, 4, (16, 512), None),
+    "kanana2": (128, 768, 2048, True, 6, (32, 512), 341),
+    "nemotron": (128, 1856, 2688, False, 6, (128, 128), None),
 }
 
 
@@ -58,11 +62,11 @@ def load_old(path):
 
 
 def make(experts, width, hidden, gated, k, tokens, seed):
-    """(x sorted by expert, up, gate or None, down, group sizes): each of
-    `tokens` tokens picks k distinct experts at random."""
+    """(picks [tokens k], rows x [tokens k, hidden], up, gate or None,
+    down): each of `tokens` tokens picks k distinct experts at random; a
+    pick's row is its own, in token order."""
     rng = np.random.default_rng(seed)
     picks = np.argsort(rng.random((tokens, experts)), axis=1)[:, :k]
-    sizes = np.bincount(picks.reshape(-1), minlength=experts)
     keys = jax.random.split(jax.random.PRNGKey(seed), 4)
 
     def mat(key):
@@ -70,8 +74,28 @@ def make(experts, width, hidden, gated, k, tokens, seed):
                 * 0.02).astype(jnp.bfloat16)
 
     x = jax.random.normal(keys[0], (tokens * k, hidden), jnp.bfloat16)
-    return (x, mat(keys[1]), mat(keys[2]) if gated else None, mat(keys[3]),
-            jnp.asarray(sizes, jnp.int32))
+    return (picks.reshape(-1), x, mat(keys[1]),
+            mat(keys[2]) if gated else None, mat(keys[3]))
+
+
+def sort(picks, x, experts):
+    """(x sorted by expert, group sizes, the sort's order)."""
+    order = np.argsort(picks, kind="stable")
+    return (x[order], jnp.asarray(np.bincount(picks, minlength=experts),
+                                  jnp.int32), order)
+
+
+def segments(picks, x, k, seg):
+    """The parent's calls for a chunk of picks: segments of `seg` tokens,
+    the last padded with tokens whose k picks are expert 0 and whose
+    rows are zeros; (picks, rows, real picks) each."""
+    out = []
+    for lo in range(0, len(picks), seg * k):
+        p, r = picks[lo:lo + seg * k], x[lo:lo + seg * k]
+        pad = seg * k - len(p)
+        out.append((np.concatenate([p, np.zeros(pad, p.dtype)]),
+                    jnp.pad(r, ((0, pad), (0, 0))), len(p)))
+    return out
 
 
 def timed(mod, x, up, gate, down, sizes):
@@ -98,6 +122,23 @@ def timed(mod, x, up, gate, down, sizes):
     return (once(10) - once(2)) / 8, None
 
 
+def calls_of(mod, calls, experts, up, gate, down):
+    """(seconds of the calls one after the other, their outputs in pick
+    order, error): `calls` are (picks, rows, real picks) each."""
+    total, outs = 0.0, []
+    for picks, rows, real in calls:
+        xs, sizes, order = sort(picks, rows, experts)
+        s, err = timed(mod, xs, up, gate, down, sizes)
+        if err is not None:
+            return None, None, err
+        total += s
+        out = np.empty((len(picks), rows.shape[1]), np.float32)
+        out[order] = np.asarray(mod.grouped_mlp(xs, up, down, sizes,
+                                                gate=gate))
+        outs.append(out[:real])
+    return total, np.concatenate(outs), None
+
+
 def main():
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--old", default=None, metavar="PATH",
@@ -108,28 +149,32 @@ def main():
     args = ap.parse_args()
     configs = CONFIGS
     if args.rehearse:
-        configs = {"tiny": (16, 256, 128, True, 4, (4, 32)),
-                   "tiny-plain": (16, 256, 128, False, 4, (4, 32))}
+        configs = {"tiny": (16, 256, 128, True, 4, (4, 32), 12),
+                   "tiny-plain": (16, 256, 128, False, 4, (4, 32), None)}
     elif jax.default_backend() != "tpu":
         sys.exit("needs a TPU (or --rehearse)")
     forms = [("change", gm)]
     if args.old:
         forms.append(("parent", load_old(args.old)))
-    for name, (e, w, h, gated, k, calls) in configs.items():
+    for name, (e, w, h, gated, k, shapes, seg) in configs.items():
         mats = 3 if gated else 2
-        for shape, tokens in zip(("wave", "chunk"), calls):
-            x, up, gate, down, sizes = make(e, w, h, gated, k, tokens,
+        for shape, tokens in zip(("wave", "chunk"), shapes):
+            picks, x, up, gate, down = make(e, w, h, gated, k, tokens,
                                             args.seed)
-            held = int(np.count_nonzero(np.asarray(sizes)))
-            nbytes = held * mats * w * h * 2
+            whole = [(picks, x, len(picks))]
             outs = []
             for form, mod in forms:
-                s, err = timed(mod, x, up, gate, down, sizes)
+                split = form == "parent" and shape == "chunk" and seg
+                calls = segments(picks, x, k, seg) if split else whole
+                s, out, err = calls_of(mod, calls, e, up, gate, down)
                 if err is None:
-                    outs.append(np.asarray(mod.grouped_mlp(x, up, down, sizes,
-                                                           gate=gate)))
+                    outs.append(out)
+                held = sum(np.count_nonzero(np.bincount(p, minlength=e))
+                           for p, _, _ in calls)
+                nbytes = held * mats * w * h * 2
                 emit(cell=name, shape=shape, form=form, picks=tokens * k,
-                     experts=e, held=held, touched_bytes=nbytes,
+                     calls=len(calls), experts=e, held=held,
+                     touched_bytes=nbytes,
                      ms=None if s is None else round(s * 1e3, 4),
                      touched_gb_s=None if not s else round(nbytes / s / 1e9,
                                                            1),

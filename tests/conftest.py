@@ -57,3 +57,35 @@ def chaos_train():
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
     return mod
+
+
+@pytest.fixture
+def vmem_holds(monkeypatch):
+    """`vmem_holds(rows, hidden, width, mats)`: the expert kernel's VMEM
+    budget shrunk to a float32 plan of `rows` resident rows at those
+    widths (an expert `_tiles` cannot slice) and not a block more, so
+    that a routed layer's picks past `rows` go through the kernel a
+    segment at a time (the calls are built afresh around it)."""
+    from paddle_tpu.ops.pallas import grouped_mlp as gm
+
+    def shrink(rows, hidden, width, mats):
+        monkeypatch.setattr(gm, "VMEM_BUDGET",
+                            gm._vmem(rows, hidden, width, 4, mats))
+        gm._call.cache_clear()
+    yield shrink
+    gm._call.cache_clear()
+
+
+@pytest.fixture
+def expert_calls():
+    """`expert_calls(moe, x)`: the kernel calls `moe.experts` makes on
+    x [T, hidden], read off its trace: the segments its `lax.map` walks,
+    or one."""
+    def calls(moe, x):
+        idx, w = moe.route(x)
+        eqns = jax.make_jaxpr(moe.experts)(x, idx, w).jaxpr.eqns
+        kernels = [e for e in eqns if e.primitive.name == "pallas_call"]
+        scans = [e for e in eqns if e.primitive.name == "scan"]
+        assert len(kernels) + len(scans) == 1
+        return scans[0].params["length"] if scans else 1
+    return calls

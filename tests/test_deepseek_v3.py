@@ -170,15 +170,21 @@ def test_gated_grouped_kernel_equals_a_loop_over_experts(sizes):
 
 @pytest.mark.parametrize("width,experts,tokens", [
     (32, 8, 18), (48, 4, 7), (32, 8, 1400)])
-def test_gated_experts_equal_the_expert_loop(width, experts, tokens):
+def test_gated_experts_equal_the_expert_loop(width, experts, tokens,
+                                            vmem_holds, expert_calls):
     """Sorted picks through the gated kernel (interpreted here) against
-    the reference's loop over every expert; 1,400 tokens x 3 picks pass
-    `MAX_ROWS` and go through it a segment at a time."""
+    the reference's loop over every expert; under a VMEM budget whose
+    plan holds 2,048 rows (682 tokens x 3 picks), 1,400 tokens pass it
+    and go through the kernel a segment at a time, in the calls
+    `kernel_calls` counts."""
+    vmem_holds(2048, 64, width, 3)
     model = _model(moe_intermediate_size=width, n_routed_experts=experts)
     moe = model.layers[1].mlp
     assert moe.gated and moe.experts_gate.shape == [experts, width, 64]
     x = jnp.asarray(np.random.default_rng(3).normal(0, 1, (tokens, 64)),
                     jnp.float32)
+    assert moe.kernel_calls(tokens) == expert_calls(moe, x) == \
+        -(-tokens // 682)
     lw = {"mlp." + n: p._data for n, p in moe.named_parameters()}
     with jax.default_matmul_precision("highest"):
         want = ref._experts(x[None], lw, 3,

@@ -368,6 +368,32 @@ def test_the_experts_a_wave_fetches_are_counted_on_the_device(model):
         assert compiled[key] == snap[key], key
 
 
+def test_the_kernel_calls_a_chunk_makes_are_counted(model, vmem_holds,
+                                                    expert_calls):
+    """`moe_chunk_calls` is the expert kernel's calls the chunks' sparse
+    layers make, by the rule `experts` cuts its picks by
+    (`kernel_calls`): one a layer where the plan holds a chunk's 64
+    picks, two under a budget whose plan holds 32 of them;
+    `moe_chunk_layers` is chunks x 4 sparse layers."""
+    moe = next(blk.mlp for blk in model.layers if not blk.dense)
+    x = jax.numpy.asarray(np.random.default_rng(1).normal(0, 1, (CHUNK, 64)),
+                          jax.numpy.float32)
+    assert moe.kernel_calls(CHUNK) == expert_calls(moe, x) == 1
+    vmem_holds(32, 64, 16, 3)
+    assert moe.kernel_calls(CHUNK) == expert_calls(moe, x) == 2
+    eng = PagedServingEngine(model, num_slots=3, max_len=MAX_LEN,
+                             block_size=BLOCK, prefill_chunk_len=CHUNK)
+    sched = Scheduler(eng)
+    for i in range(3):
+        sched.submit(prompt=_prompt(70 + i, 20 + 9 * i), max_tokens=3)
+    while sched.step():
+        pass
+    snap = sched.metrics.snapshot()
+    assert snap["prefill_chunks"] == 2 + 2 + 3
+    assert snap["moe_chunk_layers"] == snap["prefill_chunks"] * 4
+    assert snap["moe_chunk_calls"] == snap["prefill_chunks"] * 4 * 2
+
+
 def test_the_front_door_serves_it_and_a_hard_close_gives_both_pools_back(
         model):
     """`create_llm_predictor` -> the paged engine with a window pool and no

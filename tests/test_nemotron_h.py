@@ -183,11 +183,14 @@ def _moe(model):
 
 @pytest.mark.parametrize("width,experts,tokens", [
     (64, 8, 18), (48, 4, 7), (64, 8, 700), (128, 16, 345)])
-def test_grouped_experts_equal_the_expert_loop(width, experts, tokens):
+def test_grouped_experts_equal_the_expert_loop(width, experts, tokens,
+                                              vmem_holds, expert_calls):
     """Sorted picks through the grouped kernel (interpreted here) against
-    the reference's loop over every expert; 700 and 345 tokens x 3 picks
-    pass `MAX_ROWS` and go through it a segment at a time, the last one
-    padded."""
+    the reference's loop over every expert; under a VMEM budget whose
+    plan holds 1,024 rows (341 tokens x 3 picks), 700 and 345 tokens
+    pass it and go through the kernel a segment at a time, the last one
+    padded, in the calls `kernel_calls` counts."""
+    vmem_holds(1024, 64, width, 2)
     model = _model("E", moe_intermediate_size=width,
                    n_routed_experts=experts)
     moe = _moe(model)
@@ -195,6 +198,9 @@ def test_grouped_experts_equal_the_expert_loop(width, experts, tokens):
         [experts, width, 64]
     x = jnp.asarray(np.random.default_rng(3).normal(0, 1, (tokens, 64)),
                     jnp.float32)
+    assert moe.tokens_a_call() == 341
+    assert moe.kernel_calls(tokens) == expert_calls(moe, x) == \
+        -(-tokens // 341)
     lw = {n: p._data for n, p in moe.named_parameters()}
     with jax.default_matmul_precision("highest"):
         want, _ = ref._experts(x[None], lw, 3,
@@ -273,6 +279,41 @@ def test_grouped_kernel_walks_only_the_experts_that_hold_rows(
     hid = (hid * g / (1 + np.exp(-g)) if gated    # up(x) * silu(gate(x))
            else np.square(np.maximum(hid, 0)))
     _close(got, np.einsum("rw,rwh->rh", hid, down[owner]))
+
+
+#: what a routed cell's sparse layer hands the kernel (benchmark/configs,
+#: benchmark/workloads): hidden, width, matrices, top-k, lanes of a wave,
+#: tokens of a chunk; and a chunk's plan (MiB of VMEM) and slices of an
+#: expert's width
+CELL_CALLS = {
+    "kanana2": ((2048, 768, 3, 6, 32, 512), 98.0, 1),
+    "xing4": ((3584, 1024, 3, 4, 16, 512), 113.0, 2),
+    "laguna": ((2048, 512, 3, 8, 16, 512), 116.0, 1),
+    "nemotron": ((2688, 1856, 2, 6, 128, 128), 69.6875, 1),
+}
+
+
+@pytest.mark.parametrize("cell", list(CELL_CALLS))
+def test_a_cells_wave_and_chunk_are_one_call_of_the_plan(cell):
+    """`max_rows` is the most rows, a multiple of `ROWS`, whose plan
+    (the expert in as many slices as `_tiles` cuts it) fits the VMEM
+    budget, and a block more does not fit: each routed cell's wave and
+    whole chunk of bfloat16 picks are one call under it, and picks past
+    it are cut into segments."""
+    from paddle_tpu.ops.pallas import grouped_mlp as gm
+    (hidden, width, mats, k, lanes, chunk), mib, tiles = CELL_CALLS[cell]
+
+    def plan(rows):
+        return gm._vmem(rows, hidden,
+                        width // gm._tiles(rows, hidden, width, 2, mats),
+                        2, mats)
+
+    most = gm.max_rows(hidden, width, 2, mats)
+    assert most % gm.ROWS == 0
+    assert plan(most) <= gm.VMEM_BUDGET < plan(most + gm.ROWS)
+    assert lanes * k <= chunk * k <= most
+    assert gm._tiles(chunk * k, hidden, width, 2, mats) == tiles
+    assert plan(chunk * k) == mib * (1 << 20)
 
 
 def test_choice_by_biased_scores_weights_from_unbiased_ones():

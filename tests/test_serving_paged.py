@@ -30,6 +30,7 @@ from paddle_tpu.nlp import LlamaConfig, LlamaForCausalLM
 from paddle_tpu.serving import (BlockPool, BlockPoolExhausted,
                                 PagedServingEngine, Scheduler,
                                 ServingEngine)
+from paddle_tpu.serving.metrics import EXPERT_COUNTS
 from paddle_tpu.utils import chaos, telemetry
 
 VOCAB = 128
@@ -116,8 +117,7 @@ def test_a_dense_models_wave_counts_no_experts(model, paged):
     traced: the wave's read is the int32[2, S] of tokens and finite flags
     it was, and the snapshot carries no expert counter."""
     sched, _ = _stream(paged, [(_prompt(4, 6), 3)])
-    assert not {"moe_experts_fetched", "moe_experts_stored"} & set(
-        sched.metrics.snapshot())
+    assert not set(EXPERT_COUNTS) & set(sched.metrics.snapshot())
     eng = PagedServingEngine(model, num_slots=4, max_len=MAX_LEN,
                              block_size=BLOCK, num_blocks=33,
                              prefill_chunk_len=CHUNK)

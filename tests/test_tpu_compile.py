@@ -561,21 +561,24 @@ def test_grouped_expert_kernel_compiles_for_v5e(one_chip, as_on_tpu, name,
 
 
 @pytest.mark.parametrize("name,rows", [("wave-32-lanes", 192),
-                                       ("chunk-segment", 2048)])
+                                       ("chunk-512", 3072)])
 def test_gated_expert_kernel_compiles_for_v5e(one_chip, as_on_tpu, name,
                                               rows):
     """`moe_experts` in its gated form at kanana2-serve-doc's size (128
-    experts of three 768 x 2048 matrices; 192 picks a wave, 2,048 a
-    segment of a chunk): one kernel, under its name, no stack of
-    matrices copied on the way in."""
-    from paddle_tpu.ops.pallas.grouped_mlp import grouped_mlp
+    experts of three 768 x 2048 matrices; 192 picks a wave, 3,072 a
+    chunk of 512 tokens): a chunk's picks are one call, whose plan
+    (98 MiB) holds them with the expert whole; one kernel, under its
+    name, no stack of matrices copied on the way in."""
+    from paddle_tpu.ops.pallas import grouped_mlp as gm
 
     def sds(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
     def gated(x, up, gate, down, sizes):
-        return grouped_mlp(x, up, down, sizes, gate=gate)
+        return gm.grouped_mlp(x, up, down, sizes, gate=gate)
 
+    assert rows <= gm.max_rows(2048, 768, 2, 3)
+    assert gm._tiles(rows, 2048, 768, 2, 3) == 1
     w = sds((128, 768, 2048), jnp.bfloat16)
     shapes = (sds((rows, 2048), jnp.bfloat16), w, w, w,
               sds((128,), jnp.int32))
@@ -625,12 +628,11 @@ def test_small_gated_experts_compile_in_one_call_for_v5e(one_chip, as_on_tpu,
                                                          name, rows):
     """`moe_experts` in its gated form at laguna-xs2-serve-agent-mix's
     size (256 experts of three 512 x 2048 matrices, top-8: 128 picks a
-    wave of 16 lanes, 4,096 a chunk of 512 tokens, `LagunaMoE.MAX_ROWS`):
-    a chunk's picks are one call, whose 4,096 resident rows (101 MB)
-    beside an expert's matrices double-buffered (12.6 MB) fit the budget
-    with the expert whole (116 MiB of 120); one kernel, under its name,
-    no stack of matrices copied on the way in."""
-    from paddle_tpu.nlp.laguna import LagunaMoE
+    wave of 16 lanes, 4,096 a chunk of 512 tokens): a chunk's picks are
+    one call (`max_rows`), whose 4,096 resident rows (101 MB) beside an
+    expert's matrices double-buffered (12.6 MB) fit the budget with the
+    expert whole (116 MiB of 120); one kernel, under its name, no stack
+    of matrices copied on the way in."""
     from paddle_tpu.ops.pallas import grouped_mlp as gm
 
     def sds(shape, dtype):
@@ -639,7 +641,7 @@ def test_small_gated_experts_compile_in_one_call_for_v5e(one_chip, as_on_tpu,
     def gated(x, up, gate, down, sizes):
         return gm.grouped_mlp(x, up, down, sizes, gate=gate)
 
-    assert LagunaMoE.MAX_ROWS == 512 * 8
+    assert 512 * 8 <= gm.max_rows(2048, 512, 2, 3)
     assert gm._tiles(rows, 2048, 512, 2, 3) == 1
     assert gm._vmem(rows, 2048, 512, 2, 3) <= gm.VMEM_BUDGET
     w = sds((256, 512, 2048), jnp.bfloat16)
@@ -649,6 +651,40 @@ def test_small_gated_experts_compile_in_one_call_for_v5e(one_chip, as_on_tpu,
     txt = jax.jit(gated).lower(*shapes).compile().as_text()
     assert not re.search(r"bf16\[256,512,2048\][^ ]* copy\(", txt)
     _walks_the_experts(txt, rows, 256, 512, 2048, 3)
+
+
+@pytest.mark.parametrize("name,experts,width,hidden,mats,tiles", [
+    ("kanana2", 128, 768, 2048, 3, 2), ("xing4", 64, 1024, 3584, 3, 8),
+    ("laguna", 256, 512, 2048, 3, 4), ("nemotron", 128, 1856, 2688, 2, 1)])
+def test_a_long_forwards_segment_compiles_for_v5e(one_chip, as_on_tpu, name,
+                                                  experts, width, hidden,
+                                                  mats, tiles):
+    """A full-sequence forward past the plan goes through the kernel a
+    segment of `max_rows` picks at a time: at each routed cell's widths
+    that segment, its expert in as many slices as `_tiles` cuts it,
+    compiles inside the budget; one kernel, under its name, no stack of
+    matrices copied on the way in."""
+    from paddle_tpu.ops.pallas import grouped_mlp as gm
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def call(x, sizes, *mats_):
+        return gm.grouped_mlp(x, mats_[0], mats_[-1], sizes,
+                              gate=mats_[1] if mats == 3 else None)
+
+    rows = gm.max_rows(hidden, width, 2, mats)
+    assert gm._tiles(rows, hidden, width, 2, mats) == tiles
+    assert gm._vmem(rows, hidden, width // tiles, 2, mats) <= \
+        gm.VMEM_BUDGET
+    w = sds((experts, width, hidden), jnp.bfloat16)
+    shapes = (sds((rows, hidden), jnp.bfloat16), sds((experts,), jnp.int32),
+              *[w] * mats)
+    assert _kernel_names(call, *shapes) == ["moe_experts"]
+    txt = jax.jit(call).lower(*shapes).compile().as_text()
+    assert not re.search(rf"bf16\[{experts},{width},{hidden}\][^ ]* copy\(",
+                         txt)
+    _walks_the_experts(txt, rows, experts, width, hidden, mats)
 
 
 @pytest.mark.parametrize("program", ["decode_wave", "prefill_chunk"])
