@@ -53,9 +53,10 @@ import numpy as np
 from .. import nn
 from ..framework.tensor import Tensor
 from ..nn import initializer as I
+from ..nn.paged_layout import AttentionGroup, PagedLayout
 from .llama import (LlamaMLP, _rms_norm_raw, _rotate_pairs,
                     apply_rope_bshd, apply_rope_positions, rope_tables)
-from .moe import ParamBlock, RoutedExperts
+from .moe import ParamBlock, RoutedExperts, routed_layout
 
 _HIGHEST = jax.lax.Precision.HIGHEST
 
@@ -469,12 +470,8 @@ class DeepseekV3Block(nn.Layer):
 class DeepseekV3ForCausalLM(nn.Layer):
     """The stack, its embedding, final norm and untied head. The methods
     are the ones the trainer's forward and the paged engine call:
-    `forward`, `init_paged_cache`, `decode_step`, `prefill_chunk`."""
-
-    #: the paged cache holds latent rows (the paged engine reads this:
-    #: it counts the rows a wave attends and a chunk expands, and
-    #: refuses what moves K/V pages alone)
-    latent_cache = True
+    `forward`, `init_paged_cache`, `paged_layout`, `decode_step`,
+    `prefill_chunk`."""
 
     def __init__(self, cfg: DeepseekV3Config):
         super().__init__()
@@ -492,14 +489,17 @@ class DeepseekV3ForCausalLM(nn.Layer):
         self.lm_head = self.create_parameter(
             [cfg.hidden_size, cfg.vocab_size], dtype=cfg.param_dtype,
             default_initializer=init)
-        #: (token, expert) pairs one token makes on its way down the stack
-        self.moe_picks_per_token = (
-            sum(not blk.dense for blk in self.layers)
-            * cfg.num_experts_per_tok)
-        #: sub-layers whose maps a token's streams go through on its way
-        #: down the stack (0 with one stream: there are no maps)
-        self.mhc_mixes_per_token = (2 * cfg.num_layers if cfg.hc_mult > 1
-                                    else 0)
+
+    def paged_layout(self):
+        """Latent pages, routed picks and stream maps (nn/paged_layout.py):
+        two maps a layer with several streams, none with one."""
+        cfg = self.cfg
+        return PagedLayout(groups=(AttentionGroup(
+            cfg.num_layers, "latent", cfg.num_attention_heads,
+            latent=(cfg.kv_lora_rank, cfg.qk_rope_head_dim,
+                    cfg.qk_nope_head_dim, cfg.v_head_dim)),),
+            mhc_rows_mixed=2 * cfg.num_layers if cfg.hc_mult > 1 else 0,
+            **routed_layout(self))
 
     def _run(self, ids, caches, attend):
         """The residual stack over ids [B, L]; `attend(attention layer,
@@ -525,7 +525,7 @@ class DeepseekV3ForCausalLM(nn.Layer):
         return Tensor(x @ self.lm_head._data)
 
     def init_paged_cache(self, num_blocks, block_size, max_len,
-                         dtype=jnp.float32):
+                         dtype=jnp.float32, num_slots=1):
         """A latent pool a layer. max_len is held to the rotary table
         here: positions are traced inside the programs."""
         if max_len > self.cfg.max_position_embeddings:
@@ -542,17 +542,17 @@ class DeepseekV3ForCausalLM(nn.Layer):
         return self._run(ids, caches, lambda attn, cache, a: attn.paged_step(
             a, cache, tables, start, valid_len))
 
-    def decode_step(self, tok, caches, pos, block_tables):
-        """One token a lane: tok [B, 1], pos [B], tables [B, nblk].
-        Returns (logits [B, 1, V], caches)."""
+    def decode_step(self, tok, caches, pos, block_tables, active=None):
+        """One token a lane: tok [B, 1], pos [B], tables [B, nblk]
+        (`active` unused). Returns (logits [B, 1, V], caches)."""
         x, caches = self._paged(tok, caches, block_tables, pos, None)
         return x @ self.lm_head._data, caches
 
     def prefill_chunk(self, tok_chunk, caches, block_tables, chunk_start,
-                      valid_len, frontier):
+                      valid_len, frontier, slot=None):
         """One prompt chunk [1, C] at absolute positions chunk_start +
-        arange(C). Returns (logits [1, 1, V] at the chunk's `frontier`
-        row, caches)."""
+        arange(C); `slot` unused. Returns (logits [1, 1, V] at the
+        chunk's `frontier` row, caches)."""
         x, caches = self._paged(tok_chunk, caches, block_tables,
                                 chunk_start, _raw(valid_len))
         x = jax.lax.dynamic_slice_in_dim(x, frontier, 1, axis=1)

@@ -23,6 +23,7 @@ from ..framework.tensor import Tensor
 from ..nn import functional as F
 from ..nn import initializer as I
 from ..distributed import mesh as mesh_mod
+from ..nn.paged_layout import AttentionGroup, PagedLayout
 from ..nn.transformer import scaled_dot_product_attention
 
 
@@ -487,9 +488,16 @@ class GPTForPretraining(nn.Layer):
         return self.gpt.init_cache(batch, max_len, dtype)
 
     def init_paged_cache(self, num_blocks, block_size, max_len,
-                         dtype=jnp.float32):
+                         dtype=jnp.float32, num_slots=1):
         return self.gpt.init_paged_cache(num_blocks, block_size, max_len,
                                          dtype)
+
+    def paged_layout(self):
+        """K/V pages, every layer alike (nn/paged_layout.py)."""
+        cfg = self.cfg
+        return PagedLayout(groups=(AttentionGroup(
+            cfg.num_layers, "kv", cfg.num_heads, cfg.num_heads,
+            cfg.hidden_size // cfg.num_heads, window=cfg.attn_window),))
 
     def _logits(self, h, frontier=None):
         """Tied-head logits of h [B, S, H]; frontier (traced index): of
@@ -504,25 +512,25 @@ class GPTForPretraining(nn.Layer):
         from ..ops.math import matmul
         return matmul(h, w, transpose_y=True)
 
-    def decode_step(self, tok, caches, pos, block_tables=None):
+    def decode_step(self, tok, caches, pos, block_tables=None, active=None):
         """One token a row: tok [B, 1] at pos (a scalar, or [B]). On the
         dense caches; given block_tables [B, nblk] the caches are block
         POOLS and the step is a chunk of one (the paged decode wave).
-        The one place that chooses between the two."""
+        The one place that chooses between the two; `active` unused."""
         if block_tables is not None:
             return self.prefill_chunk(tok, caches, block_tables, pos, None)
         h, caches = self.gpt.decode_step(tok, caches, pos)
         return self._logits(h), caches
 
     def prefill_chunk(self, tok_chunk, caches, block_tables, chunk_start,
-                      valid_len, frontier=None):
+                      valid_len, frontier=None, slot=None):
         """[B, C] ids a lane against the block pools, at absolute
         positions chunk_start + arange(C) (chunk_start and valid_len:
         scalars or [B]): a prompt chunk of one lane, or every lane's
         k + 1 drafted tokens in the speculative verify wave, whose
         logits for ALL C positions ([S, C, V]) are the cost that program
         pays on purpose. frontier: see _logits; only the final chunk's
-        frontier is consumed by the serving engine."""
+        frontier is consumed by the serving engine. `slot` unused."""
         h, caches = self.gpt.paged_step(tok_chunk, caches, block_tables,
                                         chunk_start, valid_len)
         return self._logits(h, frontier), caches

@@ -26,8 +26,8 @@ from ..nn import initializer as I
 from .llama import _rms_norm_raw
 from .moe import ParamBlock
 from .nemotron_h import (Mamba2Mixer, NemotronHAttention, _raw, chunk_call,
-                         init_paged_caches, sequence_call, through,
-                         wave_call)
+                         hybrid_layout, init_paged_caches, sequence_call,
+                         through, wave_call)
 
 LAYER_TYPES = ("mamba", "attention")
 
@@ -158,10 +158,6 @@ class GraniteHybridForCausalLM(nn.Layer):
     `forward`, `init_paged_cache`, `decode_step`, `prefill_chunk`, with
     `NemotronHForCausalLM`'s signatures."""
 
-    #: per-slot records live beside the paged K/V (see
-    #: `NemotronHForCausalLM.slot_state`)
-    slot_state = True
-
     def __init__(self, cfg: GraniteHybridConfig):
         super().__init__()
         self.cfg = cfg
@@ -217,6 +213,8 @@ class GraniteHybridForCausalLM(nn.Layer):
         """`init_paged_caches` of this stack's mixers."""
         return init_paged_caches([lyr.mixer for lyr in self.layers],
                                  num_blocks, block_size, dtype, num_slots)
+
+    paged_layout = hybrid_layout
 
     def decode_step(self, tok, caches, pos, block_tables, active):
         """One token a lane: tok [B, 1], pos [B], tables [B, nblk],

@@ -25,15 +25,16 @@ Experts: the router is a softmax over `num_experts` logits, the top
 `shared_expert_intermediate_size`, added once.
 
 The paged cache. A layer keeps K/V pages in `nn/paged_attention.py`'s
-form. Full layers read theirs through the block tables; window layers
-through a second set of tables (`window_tables`) over a pool of their own,
-which the serving engine sizes to what a window attends and whose pages
-behind the window it gives back (serving/paged/engine.py). The model
-declares the window of each layer in `layer_windows`.
+form. Where window layers stand beside full ones, the window layers keep
+theirs in a pool of their own, which the serving engine sizes to what a
+window attends and whose pages behind the window it gives back: the
+model's `paged_layout()` names it, and a step's table row holds the full
+pool's entries, then the window pool's.
 
 Router arithmetic, the norms' statistics, the gate and the softmax are
 float32; everything else runs in the parameters' dtype.
 """
+import collections
 import math
 
 import jax
@@ -43,10 +44,11 @@ import numpy as np
 from .. import nn
 from ..framework.tensor import Tensor
 from ..nn import initializer as I
+from ..nn.paged_layout import AttentionGroup, PagedLayout
 from .deepseek_v3 import (_sequence_tables, _split_rows, _split_tables,
                           yarn_frequencies)
 from .llama import LlamaMLP, _gqa_flash_bshd, _rms_norm_raw, _rotate_pairs
-from .moe import ParamBlock, RoutedExperts
+from .moe import ParamBlock, RoutedExperts, routed_layout
 
 FULL, SLIDING = "full_attention", "sliding_attention"
 
@@ -302,17 +304,22 @@ class LagunaForCausalLM(nn.Layer):
         self.lm_head = self.create_parameter(
             [cfg.hidden_size, cfg.vocab_size], dtype=cfg.param_dtype,
             default_initializer=init)
-        #: the keys a layer's queries attend back from themselves (None:
-        #: all of them), layer by layer: the paged engine gives the
-        #: window layers a pool and tables of their own
-        self.layer_windows = tuple(blk.self_attn.window
-                                   for blk in self.layers)
-        #: query heads of each layer (the engine counts the attention
-        #: kernel's grid a kind of layer at a time)
-        self.layer_heads = tuple(blk.self_attn.nh for blk in self.layers)
-        #: (token, expert) pairs one token makes on its way down the stack
-        self.moe_picks_per_token = (sum(not blk.dense for blk in self.layers)
-                                    * cfg.num_experts_per_tok)
+        windows = [blk.self_attn.window for blk in self.layers]
+        # window layers beside full ones keep a pool of their own
+        self._window_pool = any(windows) and None in windows
+
+    def paged_layout(self):
+        """A group a (heads, window), window layers beside full ones on a
+        pool of their own, and the routed picks (nn/paged_layout.py)."""
+        cfg = self.cfg
+        kinds = collections.Counter((blk.self_attn.nh, blk.self_attn.window)
+                                    for blk in self.layers)
+        return PagedLayout(groups=tuple(
+            AttentionGroup(n, "kv", h, cfg.num_key_value_heads, cfg.head_dim,
+                           window=w, pool="window" if w and self._window_pool
+                           else "full")
+            for (h, w), n in sorted(kinds.items(), key=str)),
+            **routed_layout(self))
 
     def _run(self, ids, attend):
         x = self.embeddings._data[_raw(ids)]
@@ -326,7 +333,7 @@ class LagunaForCausalLM(nn.Layer):
                       @ self.lm_head._data)
 
     def init_paged_cache(self, num_blocks, block_size, max_len,
-                         dtype=jnp.float32, window_blocks=None):
+                         dtype=jnp.float32, num_slots=1, window_blocks=None):
         """A K/V pool a layer: `num_blocks` for a full layer, and
         `window_blocks` (default `num_blocks`) for a window layer. max_len
         is held to the rotary tables here: positions are traced inside the
@@ -341,10 +348,13 @@ class LagunaForCausalLM(nn.Layer):
             else window_blocks or num_blocks, block_size, dtype)
             for blk in self.layers]
 
-    def _paged(self, ids, caches, tables, window_tables, start, valid_len):
+    def _paged(self, ids, caches, tables, start, valid_len):
         tables, start = _raw(tables), _raw(start)
-        window_tables = tables if window_tables is None else _raw(
-            window_tables)
+        window_tables = tables
+        if self._window_pool:
+            # a row: the full pool's entries, then the window pool's
+            nblk = tables.shape[-1] // 2
+            tables, window_tables = tables[..., :nblk], tables[..., nblk:]
         caches, i = list(caches), iter(range(len(caches)))
 
         def attend(attn, a):
@@ -356,21 +366,18 @@ class LagunaForCausalLM(nn.Layer):
 
         return self._run(ids, attend), caches
 
-    def decode_step(self, tok, caches, pos, block_tables,
-                    window_tables=None):
-        """One token a lane: tok [B, 1], pos [B], tables [B, nblk] (the
-        window layers' `window_tables`, default the same). Returns
-        (logits [B, 1, V], caches)."""
-        x, caches = self._paged(tok, caches, block_tables, window_tables,
-                                pos, None)
+    def decode_step(self, tok, caches, pos, block_tables, active=None):
+        """One token a lane: tok [B, 1], pos [B], table rows as the layout
+        packs them (`active` unused). Returns (logits [B, 1, V], caches)."""
+        x, caches = self._paged(tok, caches, block_tables, pos, None)
         return x @ self.lm_head._data, caches
 
     def prefill_chunk(self, tok_chunk, caches, block_tables, chunk_start,
-                      valid_len, frontier, window_tables=None):
+                      valid_len, frontier, slot=None):
         """One prompt chunk [1, C] at absolute positions chunk_start +
-        arange(C). Returns (logits [1, 1, V] at the chunk's `frontier`
-        row, caches)."""
+        arange(C); `slot` unused. Returns (logits [1, 1, V] at the
+        chunk's `frontier` row, caches)."""
         x, caches = self._paged(tok_chunk, caches, block_tables,
-                                window_tables, chunk_start, _raw(valid_len))
+                                chunk_start, _raw(valid_len))
         x = jax.lax.dynamic_slice_in_dim(x, frontier, 1, axis=1)
         return x @ self.lm_head._data, caches

@@ -24,6 +24,7 @@ from .. import nn
 from ..nn import functional as F
 from ..nn import initializer as I
 from ..distributed import mesh as mesh_mod
+from ..nn.paged_layout import AttentionGroup, PagedLayout
 
 
 class LlamaConfig:
@@ -580,29 +581,36 @@ class LlamaForCausalLM(nn.Layer):
         return self.model.init_cache(batch, max_len, dtype)
 
     def init_paged_cache(self, num_blocks, block_size, max_len,
-                         dtype=jnp.float32):
+                         dtype=jnp.float32, num_slots=1):
         return self.model.init_paged_cache(num_blocks, block_size,
                                            max_len, dtype)
 
-    def decode_step(self, tok, caches, pos, block_tables=None):
+    def paged_layout(self):
+        """K/V pages of the kv-heads, layers alike (nn/paged_layout.py)."""
+        cfg = self.cfg
+        return PagedLayout(groups=(AttentionGroup(
+            cfg.num_layers, "kv", cfg.num_heads, cfg.num_kv_heads,
+            cfg.hidden_size // cfg.num_heads, window=cfg.attn_window),))
+
+    def decode_step(self, tok, caches, pos, block_tables=None, active=None):
         """One token a row: tok [B, 1] at pos (a scalar, or [B]). On the
         dense caches; given block_tables [B, nblk] the caches are block
         POOLS and the step is a chunk of one (the paged decode wave).
-        The one place that chooses between the two."""
+        The one place that chooses between the two; `active` unused."""
         if block_tables is not None:
             return self.prefill_chunk(tok, caches, block_tables, pos, None)
         h, caches = self.model.decode_step(tok, caches, pos)
         return self._logits(h), caches
 
     def prefill_chunk(self, tok_chunk, caches, block_tables, chunk_start,
-                      valid_len, frontier=None):
+                      valid_len, frontier=None, slot=None):
         """[B, C] ids a lane against the block pools, at absolute
         positions chunk_start + arange(C) (chunk_start and valid_len:
         scalars or [B]): a prompt chunk of one lane, or every lane's
         k + 1 drafted tokens in the speculative verify wave, whose
         logits for ALL C positions ([S, C, V]) are the cost that program
         pays on purpose. frontier: see _logits; only the final chunk's
-        frontier row is consumed by the serving engine."""
+        frontier row is consumed by the serving engine; `slot` unused."""
         h, caches = self.model.paged_step(tok_chunk, caches, block_tables,
                                           chunk_start, valid_len)
         return self._logits(h, frontier), caches

@@ -69,6 +69,15 @@ def route_softmax(logits, k, scale):
     return idx, w / jnp.sum(w, axis=-1, keepdims=True) * scale
 
 
+def routed_layout(model):
+    """`PagedLayout` fields of `model`'s routed layers: (token, expert)
+    pairs a token, experts stored, tokens a kernel call of each."""
+    routed = [m for m in model.sublayers() if isinstance(m, RoutedExperts)]
+    return {"moe_picks": sum(m.cfg.num_experts_per_tok for m in routed),
+            "experts_stored": sum(m.cfg.n_routed_experts for m in routed),
+            "expert_tokens": tuple(m.tokens_a_call() for m in routed)}
+
+
 class RoutedExperts(ParamBlock):
     """Dropless routed experts and one shared expert of the same form
     (`gated`: see the module's docstring), `shared_width` wide. Picks

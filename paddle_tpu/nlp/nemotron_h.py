@@ -7,8 +7,8 @@ One cache interface a mixer kind: attention keeps K/V in the paged pool
 (block tables, `nn/paged_attention.py`); a Mamba mixer keeps one fixed
 record a serving slot (`ssm` [slots, heads, head_dim, state] float32 and
 the last `conv_kernel - 1` conv inputs); an expert layer keeps nothing.
-`slot_state` tells the paged engine that such records exist, so it hands
-`prefill_chunk` the slot and `decode_step` the lanes that decode.
+The layout's `slot_record` tells the paged engine that such records
+exist, so it zeroes a slot's when it begins a prompt.
 
 State and router arithmetic are float32 (the state, dt, the decay, the
 sigmoid scores); everything else runs in the parameters' dtype.
@@ -21,8 +21,10 @@ import jax.numpy as jnp
 from .. import nn
 from ..framework.tensor import Tensor
 from ..nn import initializer as I
+from ..nn.paged_layout import AttentionGroup, PagedLayout
 from .llama import _gqa_flash_bshd, _rms_norm_raw
 from .moe import ParamBlock as _Block, RoutedExperts, route  # noqa: F401
+from .moe import routed_layout
 
 _HIGHEST = jax.lax.Precision.HIGHEST
 
@@ -338,6 +340,17 @@ def init_paged_caches(mixers, num_blocks, block_size, dtype, num_slots):
                       if cache_group(m) == "state"]}
 
 
+def hybrid_layout(model):
+    """`paged_layout()` of a stack of these mixers (nn/paged_layout.py):
+    its attention layers' K/V pages, a Mamba record a slot, routed picks."""
+    cfg = model.cfg
+    layers = sum(isinstance(lyr.mixer, NemotronHAttention)
+                 for lyr in model.layers)
+    return PagedLayout(groups=(AttentionGroup(
+        layers, "kv", cfg.num_attention_heads, cfg.num_key_value_heads,
+        cfg.head_dim),), slot_record=True, **routed_layout(model))
+
+
 def through(call, mixer, caches, i, x):
     """`call` on one layer's mixer with entry `i` of the list that holds
     its cache (if it keeps one), which the new entry replaces in
@@ -412,12 +425,8 @@ class NemotronHBlock(nn.Layer):
 class NemotronHForCausalLM(nn.Layer):
     """The stack, its embedding, final norm and untied head. The methods
     are the ones the trainer's forward and the paged engine call:
-    `forward`, `init_paged_cache`, `decode_step`, `prefill_chunk`."""
-
-    #: per-slot records live beside the paged K/V (the paged engine
-    #: reads this: it passes slots, zeroes a record when a slot begins a
-    #: prompt, and turns off what moves or shares pages alone)
-    slot_state = True
+    `forward`, `init_paged_cache`, `paged_layout`, `decode_step`,
+    `prefill_chunk`."""
 
     def __init__(self, cfg: NemotronHConfig):
         super().__init__()
@@ -438,8 +447,8 @@ class NemotronHForCausalLM(nn.Layer):
         kinds = cfg.hybrid_override_pattern
         # where each block's cache lies in its kind's list
         self._cache_index = [kinds[:i].count(k) for i, k in enumerate(kinds)]
-        #: (token, expert) pairs one token makes on its way down the stack
-        self.moe_picks_per_token = kinds.count("E") * cfg.num_experts_per_tok
+
+    paged_layout = hybrid_layout
 
     def _run(self, ids, caches, call):
         """The residual stack over ids [B, L]; `call` is one of the ways

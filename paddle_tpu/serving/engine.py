@@ -327,6 +327,9 @@ class ServingEngine:
     """
 
     pipelined = True
+    #: whether `take_model_counts` has counts of the model's own layers
+    #: (the paged engine's, from its model's layout)
+    counts_model_work = False
 
     def __init__(self, model, num_slots=4, max_len=256, prefill_len=None,
                  cache_dtype=None, jit_compile=True, seed=0):
@@ -526,27 +529,24 @@ class ServingEngine:
         self._program_donate_argnums = (2, 3, 4)
         self._prefill_donate_argnums = self._program_donate_argnums
 
-        if self._jit:
-            # donate the batched cache: the engine always replaces its
-            # cache reference with the program output, so XLA may update
-            # it in place — without this every wave would transiently
-            # hold 2x the [S, Hkv, L, D] pair in HBM. The two lane-state
-            # vectors beside it are replaced the same way.
-            # instrument_jit attributes XLA compile events to these
-            # labels (xla_compiles_total{function=...}) — the
-            # compile-once invariant as a live metric, not just the
-            # _cache_size() test assertion.
-            self._decode_wave = telemetry.instrument_jit(
-                jax.jit(decode_wave,
-                        donate_argnums=self._program_donate_argnums),
-                "serving_decode_wave")
-            self._prefill = telemetry.instrument_jit(
-                jax.jit(prefill,
-                        donate_argnums=self._prefill_donate_argnums),
-                "serving_prefill")
-        else:
-            self._decode_wave = decode_wave
-            self._prefill = prefill
+        # donate the batched cache: the engine always replaces its
+        # cache reference with the program output, so XLA may update it
+        # in place — without this every wave would transiently hold 2x
+        # the [S, Hkv, L, D] pair in HBM. The two lane-state vectors
+        # beside it are replaced the same way.
+        self._decode_wave = self.jit(decode_wave, "serving_decode_wave",
+                                     donate=self._program_donate_argnums)
+        self._prefill = self.jit(prefill, "serving_prefill",
+                                 donate=self._prefill_donate_argnums)
+
+    def jit(self, fn, label, donate=()):
+        """`fn` jitted with `donate` donated, its compiles counted as
+        xla_compiles_total{function=label} (the compile-once invariant as
+        a live metric); `fn` itself where nothing compiles."""
+        if not self._jit:
+            return fn
+        return telemetry.instrument_jit(jax.jit(fn, donate_argnums=donate),
+                                        label)
 
     @property
     def decode_compiles(self):
@@ -820,6 +820,11 @@ class ServingEngine:
             top_k=sampling["top_k"], temp=sampling["temp"],
             top_p=sampling["top_p"])
         return self._lane_tok, self._lane_pos, prompt, bias, self._key
+
+    def take_page_counts(self):
+        """(visited, spanned, run, visited steps) of the paged attention
+        core since the last call: none on dense rows."""
+        return 0, 0, 0, 0
 
     def take_bias_uploads(self):
         """Rows and matrices of bias sent to the device since the last

@@ -50,6 +50,8 @@ touch only the queue, never the pool.
 import collections
 import hashlib
 
+import numpy as np
+
 from ...utils import chaos
 from .. import metrics as serving_metrics
 
@@ -315,3 +317,49 @@ class WindowBlockPool(BlockPool):
 
     def _publish(self):
         pass
+
+
+class SlotPages:
+    """One pool's pages of every slot: its `[slots, blocks_per_slot]`
+    table, the programs' (no page: the scratch block), each slot's pages
+    in table order and the entry the first sits at (past those a window
+    left behind). The engine keeps one a pool of its layout."""
+
+    def __init__(self, pool, table):
+        self.pool, self.table = pool, table
+        self.blocks = [[] for _ in range(len(table))]
+        self.first = [0] * len(table)
+
+    def back(self, slot, last):
+        """Back the slot's entries up to `last` (BlockPoolExhausted)."""
+        blocks = self.blocks[slot]
+        have = self.first[slot] + len(blocks)
+        if last >= have:
+            fresh = self.pool.alloc(last + 1 - have)
+            self.table[slot, have:last + 1] = fresh
+            blocks.extend(fresh)
+
+    def behind(self, slots, starts, window):
+        """Of each of `slots`, its pages wholly below what a call whose
+        first query sits at `starts` attends through `window`."""
+        cuts = (np.maximum(np.asarray(starts, np.int64) - window + 1, 0)
+                // self.pool.block_size).tolist()
+        return [min(max(cut - self.first[s], 0), len(self.blocks[s]))
+                for s, cut in zip(slots, cuts)]
+
+    def release_behind(self, slots, counts):
+        """Give back the first `counts` pages of each of `slots`: a later
+        program may write one for another lane, never before the programs
+        already dispatched have read it (the device runs them in order)."""
+        for s, k in zip(slots, counts):
+            if k:
+                first, blocks = self.first[s], self.blocks[s]
+                self.pool.release(blocks[:k])
+                del blocks[:k]
+                self.table[s, first:first + k] = 0
+                self.first[s] = first + k
+
+    def retire(self, slot):
+        """Give back every page of the slot."""
+        self.release_behind([slot], [len(self.blocks[slot])])
+        self.first[slot] = 0

@@ -37,14 +37,12 @@ scheduler preempts it by recompute (requeue with prompt + generated
 tokens; the freed blocks' prefix hashes make the re-prefill mostly
 cache hits).
 """
-import collections
 import hashlib
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ...nlp.moe import RoutedExperts
 from ...nn import paged_attention
 from ...ops.pallas import grouped_mlp
 from ...utils import chaos, telemetry
@@ -54,7 +52,8 @@ from ..metrics import EXPERT_COUNTS, MODEL_COUNTS
 from ..engine import (ServingEngine, WaveTicket, _filter_top_k_top_p, _raw,
                       _select_first_token, _select_wave_tokens, arm_lane,
                       unpack_lanes, unpack_prompt, wave_read)
-from .block_pool import BlockPool, BlockPoolExhausted, WindowBlockPool
+from .block_pool import (BlockPool, BlockPoolExhausted, SlotPages,
+                         WindowBlockPool)
 
 #: block-level KV handoff payload schema version (export_slot_kv /
 #: import_handoff) — bumped when the payload layout changes so a
@@ -86,78 +85,37 @@ def _handoff_digest(layers, n_tokens, block_size):
     return h.hexdigest()
 
 
-#: why a model with slot state is refused what moves, shares or rolls
-#: back K/V pages alone (formatted with the operation's name)
-SLOT_STATE_REFUSAL = (
-    "{what} moves K/V pages only, and this model keeps a recurrent state "
-    "a slot beside them (model.slot_state) that no page holds: a snapshot "
-    "of that state at the boundary is needed first")
+def _copy_page(caches, src, dst):
+    return jax.tree_util.tree_map(lambda pool: pool.at[dst].set(pool[src]),
+                                  caches)
 
 
-#: why a model whose pages hold latent rows is refused the programs
-#: that were written for K/V pages (formatted with the operation's name)
-LATENT_REFUSAL = (
-    "{what} was written for pools of K/V pages ([blocks, kv_heads, "
-    "block, 2 x head_dim]); this model's pages hold latent rows "
-    "(model.latent_cache: [blocks, block, width], one row a position "
-    "shared by every head) and the program has not been taken through "
-    "that form yet")
+def _gather_pages(caches, idx):
+    return [leaf[idx] for leaf in jax.tree_util.tree_leaves(caches)]
 
 
-#: why a model whose window layers keep a pool of their own is refused
-#: what moves, shares or rolls back the pages of one pool (formatted with
-#: the operation's name)
-WINDOW_REFUSAL = (
-    "{what} moves the pages of one pool; this model's window layers keep "
-    "theirs in a second pool (model.layer_windows) that gives back the "
-    "pages behind the window as a lane advances, and the program has not "
-    "been taken through two pools yet")
+def _scatter_pages(caches, idx, data):
+    flat, treedef = jax.tree_util.tree_flatten(caches)
+    return jax.tree_util.tree_unflatten(
+        treedef, [leaf.at[idx].set(arr) for leaf, arr in zip(flat, data)])
 
 
-def pool_window(model):
-    """The window of a model that declares window layers of one size
-    beside full ones (`model.layer_windows`, None for a full layer): its
-    window layers get a pool and tables of their own. None for any other
-    model."""
-    windows = tuple(getattr(model, "layer_windows", None) or ())
-    sizes = {int(w) for w in windows if w}
-    if not sizes or None not in windows:
-        return None
-    if len(sizes) > 1:
-        raise ValueError(f"window layers of sizes {sorted(sizes)}: one "
-                         "window pool serves one window")
-    return sizes.pop()
+def _set_lane(lane_tok, lane_pos, row):
+    return (lane_tok.at[row[0]].set(row[1]),
+            lane_pos.at[row[0]].set(row[2]))
 
 
 class PagedServingEngine(ServingEngine):
     """Block-table batched decode executor.
 
-    model: a causal LM exposing init_paged_cache and the two paged
-        steps, decode_step(tok, caches, pos, block_tables=) and
+    model: a causal LM that declares its `paged_layout()`
+        (nn/paged_layout.py; docs/serving.md, "What a model declares to
+        be served") and exposes init_paged_cache(num_blocks, block_size,
+        max_len, dtype=, num_slots=) and the two paged steps,
+        decode_step(tok, caches, pos, block_tables=, active=) and
         prefill_chunk(tok_chunk, caches, block_tables, chunk_start,
-        valid_len, frontier=) (GPTForPretraining, LlamaForCausalLM). A
-        model that declares `slot_state` (NemotronHForCausalLM) keeps
-        a fixed record a slot beside the pages: its init_paged_cache
-        takes `num_slots` and returns
-        {"kv": pools, "state": arrays with leading dimension num_slots},
-        its prefill_chunk takes `slot`, its decode_step takes `active`.
-        The engine zeroes a slot's record when the slot begins a prompt,
-        serves such a model without prefix sharing (a shared page holds
-        no state, so a hit would be silently wrong) and refuses it
-        hand-off and speculation. A model that declares `latent_cache`
-        (DeepseekV3ForCausalLM) keeps one latent row a position in its
-        pages (nn.paged_attention's latent form): a page of rows is a
-        page, so tables, prefix sharing, copy-on-write and eviction are
-        the same; hand-off and speculation refuse it by name. A model
-        that declares window layers beside full ones (`layer_windows`,
-        LagunaForCausalLM) gets a second pool for them (`window_pool`)
-        and a second table a slot, as wide as the first: an entry below
-        what the lane attends points to the scratch block, and a page
-        wholly behind the window goes back to the pool as the lane
-        advances, so a lane holds at most `window_pages` of them. Its
-        init_paged_cache takes `window_blocks`, its two steps
-        `window_tables`. Such a model is served without prefix sharing;
-        hand-off and speculation refuse it by name.
+        valid_len, frontier=, slot=). A step's `block_tables` is the
+        slot's row of every pool the layout names, packed in its order.
     max_len: per-request horizon; must be a multiple of block_size
         (table width = max_len // block_size).
     num_blocks: pool size INCLUDING the scratch block (block 0).
@@ -192,176 +150,107 @@ class PagedServingEngine(ServingEngine):
             raise ValueError(
                 f"prefill_chunk_len {self.prefill_chunk_len} > max_len "
                 f"{max_len}")
-        self.slot_state = bool(getattr(model, "slot_state", False))
-        self.latent_cache = bool(getattr(model, "latent_cache", False))
-        self.window = pool_window(model)
-        self.prefix_sharing = (bool(prefix_sharing) and not self.slot_state
-                               and not self.window)
+        self.layout = layout = model.paged_layout()
+        self.window = layout.window
+        self.prefix_sharing = (bool(prefix_sharing)
+                               and layout.shares_prefixes)
         self.block_pool = BlockPool(num_blocks, self.block_size)
-        if self.window:
-            # what a lane attends at most: the window behind a chunk's
-            # first query and the chunk, on pages that need not align
-            self.window_pages = -(-(self.window - 1 + self.prefill_chunk_len)
-                                  // self.block_size) + 1
-            self.window_pool = WindowBlockPool(
-                int(num_slots) * self.window_pages + 1, self.block_size)
-        self._copy_fn = None
-        self._set_lane_fn = None
-        self._handoff_gather_fn = None
-        self._handoff_scatter_fn = None
+        # the full pool, then a window pool if the layout names one; a
+        # slot's row packs their tables in that order (what the programs
+        # take), and each pool's SlotPages keeps its part of the rows
+        pools = [self.block_pool] + [
+            WindowBlockPool(int(num_slots) * self._window_pages(w) + 1,
+                            self.block_size) for w in (self.window,) if w]
+        nblk = self.blocks_per_slot
+        self._rows = np.zeros((int(num_slots), len(pools) * nblk), np.int32)
+        self._pages = [SlotPages(pool, self._rows[:, i * nblk:(i + 1) * nblk])
+                       for i, pool in enumerate(pools)]
+        self._windowed = self._pages[1:]
+        self._tables = self._pages[0].table
+        self._slot_blocks = self._pages[0].blocks
         super().__init__(model, num_slots=num_slots, max_len=max_len,
                          prefill_len=self.prefill_chunk_len,
                          cache_dtype=cache_dtype, jit_compile=jit_compile,
                          seed=seed)
-        self._slot_blocks = [[] for _ in range(self.num_slots)]
-        self._tables = np.zeros((self.num_slots, self.blocks_per_slot),
-                                np.int32)
-        self._attn_window = getattr(model.cfg, "attn_window", None)
-        if self.window:
-            # a slot's pages of the window pool: table entries
-            # `_win_first[slot]` onwards, in order
-            self._win_tables = np.zeros_like(self._tables)
-            self._win_blocks = [[] for _ in range(self.num_slots)]
-            self._win_first = [0] * self.num_slots
+        # small programs, each compiled on its first call: the
+        # copy-on-write copy, a hand-off's gather and scatter over the
+        # whole bundle and the lane it arms where no chunk did
+        self._copy_fn = self.jit(_copy_page, "paged_cow_copy", donate=(0,))
+        # the gather reads the pools, which stay the engine's
+        self._handoff_gather_fn = self.jit(
+            _gather_pages, "paged_handoff_gather", donate=())
+        self._handoff_scatter_fn = self.jit(
+            _scatter_pages, "paged_handoff_scatter", donate=(0,))
+        self._set_lane_fn = self.jit(_set_lane, "paged_set_lane",
+                                     donate=(0, 1))
         # table entries the attention core visits in the decode waves
         # staged since the scheduler last took them (take_page_counts,
         # once a round), and the entries those waves' tables hold; the
         # grid steps its kernel runs, and those that fetch and score
-        # pages, in those waves and the prefill chunks, a layer (a model
-        # with a window pool: each kind of layer times its layers)
+        # pages, in those waves and the prefill chunks, a layer (a
+        # layout of several groups: each group times its layers)
         self._pages_visited = self._pages_spanned = 0
         self._steps_run = self._steps_visited = 0
-        self._attn_call = None if self.window else self._attn_call_shapes()
-        self._attn_kinds = self._attention_kinds()
+        self._groups = layout.weighted()
+        self._itemsize = np.dtype(self.cache_dtype).itemsize
         # what the model's own layers were staged since the scheduler
-        # last took them (take_model_counts, once a round): slot records
-        # zeroed, (token, expert) pairs routed
-        self._picks_per_token = int(getattr(model, "moe_picks_per_token",
-                                            0))
-        self._mixes_per_token = int(getattr(model, "mhc_mixes_per_token",
-                                            0))
-        self.counts_model_work = bool(self.slot_state or self.latent_cache
-                                      or self._picks_per_token
-                                      or self._mixes_per_token
-                                      or self.window)
+        # last took them (take_model_counts, once a round)
+        self.counts_model_work = layout.counts_work
         self._model_counts = dict.fromkeys(MODEL_COUNTS, 0)
         # of a model with routed experts, the experts its sparse layers
         # store, which every wave's kernel calls could fetch; the waves
         # read since the last take report those they did fetch. A chunk
-        # runs its sparse layers, each in the kernel calls its
-        # `kernel_calls` cuts the chunk's tokens into
-        cfg = model.cfg
-        self._experts_stored = (
-            self._picks_per_token // cfg.num_experts_per_tok
-            * cfg.n_routed_experts if self._picks_per_token else 0)
-        routed = [m for m in model.sublayers()
-                  if isinstance(m, RoutedExperts)]
-        self._chunk_layers = len(routed)
-        self._chunk_calls = sum(m.kernel_calls(self.prefill_chunk_len)
-                                for m in routed)
+        # runs its sparse layers, each in the kernel calls it cuts the
+        # chunk's tokens into
+        self._chunk_calls, self._chunk_layers = layout.chunk_calls(
+            self.prefill_chunk_len)
         self._expert_counts = dict.fromkeys(EXPERT_COUNTS, 0)
 
-    def _attn_call_shapes(self):
-        """What the engine's programs hand the attention core, read off
-        the model's sizes and the pool as it stands: of a K/V pool
-        (query heads a kv-head, kv-heads, head size, bytes a value); of
-        a latent pool (heads, stored width, bytes a value, and the rank,
-        rope, nope and v sizes `latent_path` chooses the way by)."""
-        cfg = self.model.cfg
-        heads = getattr(cfg, "num_attention_heads", None) or cfg.num_heads
-        latent = self.latent_cache
-        pool = next(a for a in jax.tree_util.tree_leaves(self._caches)
-                    if a.ndim == (3 if latent else 4)
-                    and a.shape[0] == self.block_pool.num_blocks
-                    and a.shape[1 if latent else 2] == self.block_size)
-        if latent:
-            return (heads, pool.shape[2], pool.dtype.itemsize,
-                    (cfg.kv_lora_rank, cfg.qk_rope_head_dim,
-                     cfg.qk_nope_head_dim, cfg.v_head_dim))
-        return (heads // pool.shape[1], pool.shape[1], pool.shape[3] // 2,
-                pool.dtype.itemsize)
+    def _window_pages(self, window):
+        """Window-pool pages a lane holds at most: the window behind a
+        chunk's first query and the chunk, on pages that need not align."""
+        return -(-(window - 1 + self.prefill_chunk_len)
+                 // self.block_size) + 1
 
-    def _attention_kinds(self):
-        """(layers, (query heads a kv-head, kv-heads, head size, bytes a
-        value), window) of each kind of layer whose pages and kernel grid
-        the engine counts: one kind, counted once, for a model with one
-        head count and window; for a model with a window pool each
-        distinct (heads, window) of `model.layer_heads` and
-        `layer_windows`, with the number of its layers."""
-        if not self.window:
-            return [(1, self._attn_call, self._attn_window)]
-        pool = next(a for a in jax.tree_util.tree_leaves(self._caches)
-                    if a.ndim == 4)
-        hkv, d, item = pool.shape[1], pool.shape[3] // 2, pool.dtype.itemsize
-        kinds = collections.Counter(zip(self.model.layer_heads,
-                                        self.model.layer_windows))
-        return [(n, (h // hkv, hkv, d, item), w)
-                for (h, w), n in sorted(kinds.items(), key=str)]
+    window_pages = property(lambda self: self._window_pages(self.window))
+    window_pool = property(lambda self: self._windowed[0].pool)
 
     def _count_steps(self, start, c):
-        """Count the kernel's grid for one staged call of `c` queries a
-        lane at `start`: the function the kernel builds its grid from
-        (none for a latent chunk on the expanded path: no kernel)."""
-        if self.latent_cache:
-            heads, width, itemsize, dims = self._attn_call
-            if paged_attention.latent_path(c, *dims) != "absorbed":
-                return
-            run, visited = paged_attention.count_latent_steps(
-                start, c, heads, width, self.block_size,
-                self.blocks_per_slot, itemsize)
-            self._steps_run += run
-            self._steps_visited += visited
-            return
-        for layers, (rep, hkv, d, itemsize), window in self._attn_kinds:
-            run, visited = paged_attention.count_steps(
-                start, c, rep, hkv, d, self.block_size, self.blocks_per_slot,
-                itemsize, window)
+        """Count each group's kernel grid for one staged call of `c`
+        queries a lane at `start` (`AttentionGroup.steps`)."""
+        for group, layers in self._groups:
+            run, visited = group.steps(start, c, self.block_size,
+                                       self.blocks_per_slot, self._itemsize)
             self._steps_run += layers * run
             self._steps_visited += layers * visited
 
     def _make_caches(self):
-        extra = {"num_slots": self.num_slots} if self.slot_state else {}
-        if self.window:
-            extra["window_blocks"] = self.window_pool.num_blocks
         caches = self.model.init_paged_cache(
             self.block_pool.num_blocks, self.block_size, self.max_len,
-            dtype=self.cache_dtype, **extra)
-        if self.slot_state:
-            # what the slots' records hold on the device (/healthz)
-            self._state_bytes = sum(
-                a.nbytes for a in jax.tree_util.tree_leaves(caches["state"]))
+            dtype=self.cache_dtype, num_slots=self.num_slots,
+            **{"window_blocks": p.pool.num_blocks for p in self._windowed})
+        # what the layout serves, with the bytes of slot records (/healthz)
+        self._layout_health = self.layout.health(caches)
         return caches
 
     # ---------------------------------------------------------- programs
     def _build_programs(self):
         model, kern = self.model, self.paged_kernel
-        slot_state = self.slot_state
-
         chunk_len = self.prefill_chunk_len
-        nblk, window = self.blocks_per_slot, self.window
-
-        def tables_of(t):
-            """The model's table keywords of packed table rows: with a
-            window pool, the full pool's entries and then the window
-            pool's."""
-            if not window:
-                return {"block_tables": t}
-            return {"block_tables": t[..., :nblk],
-                    "window_tables": t[..., nblk:]}
 
         def decode_wave(p, b, caches, lane_tok, lane_pos, lanes, bias, key):
             key, sub = jax.random.split(key)
             tables, a = unpack_lanes(lanes)
             # the scope pins this engine's kernel at TRACE time: the
-            # compiled wave keeps the core the engine was built with
-            live = {"active": a["active"]} if slot_state else {}
-            # a routed model's layers note the experts their kernel calls
+            # compiled wave keeps the core the engine was built with. A
+            # routed model's layers note the experts their kernel calls
             # fetch, which the wave's read carries (none for any other)
             with paged_attention.kernel_scope(kern), \
                     grouped_mlp.fetch_tally() as fetched:
                 out, _ = model.functional_call(
                     p, b, lane_tok[:, None], caches, lane_pos,
-                    method="decode_step", **tables_of(tables), **live)
+                    method="decode_step", block_tables=tables,
+                    active=a["active"])
             logits, new_caches = out
             lo = _raw(logits)[:, 0, :].astype(jnp.float32)
             nxt, new_pos, finite = _select_wave_tokens(
@@ -374,14 +263,12 @@ class PagedServingEngine(ServingEngine):
                           key):
             key, sub = jax.random.split(key)
             table, chunk, a = unpack_prompt(prompt, chunk_len)
-            # the request's slot goes to a model with slot state and to
-            # no other
-            where = {"slot": a["slot"]} if slot_state else {}
             with paged_attention.kernel_scope(kern):
                 out, _ = model.functional_call(
                     p, b, chunk[None, :], caches, method="prefill_chunk",
-                    **tables_of(table[None, :]), chunk_start=a["start"],
-                    valid_len=a["valid"], frontier=a["frontier"], **where)
+                    block_tables=table[None, :], chunk_start=a["start"],
+                    valid_len=a["valid"], frontier=a["frontier"],
+                    slot=a["slot"])
             logits, new_caches = out
             # frontier logits [1, 1, V]: only the FINAL chunk's value is
             # consumed (it arms the slot's lane, and the host reads it);
@@ -406,27 +293,15 @@ class PagedServingEngine(ServingEngine):
         self._prefill_fn = prefill_chunk
         self._program_donate_argnums = (2, 3, 4)
         self._prefill_donate_argnums = self._program_donate_argnums
-        if slot_state:
-            self._state_reset = (telemetry.instrument_jit(
-                jax.jit(state_reset, donate_argnums=(0,)),
-                "paged_state_reset") if self._jit else state_reset)
-
-        if self._jit:
-            # the block pools (and the lane state) are donated exactly
-            # like the dense cache: the engine always replaces its
-            # reference with the program output, so XLA updates the pool
-            # in place
-            self._decode_wave = telemetry.instrument_jit(
-                jax.jit(decode_wave,
-                        donate_argnums=self._program_donate_argnums),
-                "paged_decode_wave")
-            self._prefill = telemetry.instrument_jit(
-                jax.jit(prefill_chunk,
-                        donate_argnums=self._prefill_donate_argnums),
-                "paged_prefill_chunk")
-        else:
-            self._decode_wave = decode_wave
-            self._prefill = prefill_chunk
+        # compiled on the first admission of a layout with slot state
+        self._state_reset = self.jit(state_reset, "paged_state_reset",
+                                     donate=(0,))
+        # the block pools (and the lane state) are donated exactly like
+        # the dense cache: XLA updates the pool in place
+        self._decode_wave = self.jit(decode_wave, "paged_decode_wave",
+                                     donate=self._program_donate_argnums)
+        self._prefill = self.jit(prefill_chunk, "paged_prefill_chunk",
+                                 donate=self._prefill_donate_argnums)
 
     def describe(self):
         """Replay-relevant construction config (see ServingEngine
@@ -436,7 +311,7 @@ class PagedServingEngine(ServingEngine):
                   "num_blocks": self.block_pool.num_blocks,
                   "prefill_chunk_len": self.prefill_chunk_len,
                   "prefix_sharing": self.prefix_sharing,
-                  "slot_state": self.slot_state,
+                  "slot_state": self.layout.slot_record,
                   "paged_kernel": self.paged_kernel})
         return d
 
@@ -489,7 +364,7 @@ class PagedServingEngine(ServingEngine):
             # retries at the queue head must not inflate the rate
             self.block_pool.count_prefix(len(shared),
                                          n // bs - len(shared))
-        if self.slot_state:
+        if self.layout.slot_record:
             # the slot's last request left its record behind: this one
             # starts from zero, at token 0 (nothing was shared)
             with RecordEvent("serving/state/reset", pid=self.trace_pid,
@@ -548,28 +423,26 @@ class PagedServingEngine(ServingEngine):
             last = c0 + C >= n
             frontier = (n - 1) - c0 if last else 0
             sampling = st["sampling"]
-            if self.window:
-                self._release_window([slot], [c0])
-                self._back_window(slot, (c0 + valid - 1) // bs)
+            # a window pool's pages behind the chunk's window go back,
+            # and the chunk's are backed (the full pool's were at
+            # admission)
+            self._release_behind([slot], [c0])
+            for pages in self._windowed:
+                pages.back(slot, (c0 + valid - 1) // bs)
             args = (*self._prefill_chunk_args(slot),
                     *self._prompt_args(slot, chunk, c0, valid, frontier,
                                        sampling, self._slot_table(slot),
                                        last))
             counts = self._model_counts
-            counts["moe_picks"] += valid * self._picks_per_token
-            counts["mhc_rows_mixed"] += valid * self._mixes_per_token
             counts["prefill_tokens"] += valid
             counts["prefill_keys"] += valid * c0 + valid * (valid + 1) // 2
             counts["prefill_chunks"] += 1
+            for name, add in self.layout.chunk_counts(
+                    c0, valid, C, bs, self.blocks_per_slot).items():
+                counts[name] += add
             self._count_steps(np.int32([c0]), C)
-            if self.latent_cache:
-                counts["mla_rows_expanded"] += int(
-                    paged_attention.expanded_rows(c0, C, bs,
-                                                  self.blocks_per_slot))
-            if self._experts_stored:
-                self._expert_counts["moe_chunk_calls"] += self._chunk_calls
-                self._expert_counts["moe_chunk_layers"] += \
-                    self._chunk_layers
+            self._expert_counts["moe_chunk_calls"] += self._chunk_calls
+            self._expert_counts["moe_chunk_layers"] += self._chunk_layers
         self._acc("prefill.stage", ev)
         with RecordEvent("serving/prefill/dispatch", pid=pid) as ev:
             first, self._caches, self._lane_tok, self._lane_pos, \
@@ -635,13 +508,6 @@ class PagedServingEngine(ServingEngine):
             raise RuntimeError(f"slot {slot} is mid-prefill")
         blocks = list(self._slot_blocks[slot])
         manifest = self.block_pool.export_blocks(blocks)
-        if self._handoff_gather_fn is None:
-            def gather_fn(caches, idx):
-                return [leaf[idx]
-                        for leaf in jax.tree_util.tree_leaves(caches)]
-            self._handoff_gather_fn = (telemetry.instrument_jit(
-                jax.jit(gather_fn), "paged_handoff_gather")
-                if self._jit else gather_fn)
         idx = jnp.asarray(np.asarray(blocks, np.int32))
         layers = [np.asarray(x)
                   for x in self._handoff_gather_fn(self._caches, idx)]
@@ -665,14 +531,11 @@ class PagedServingEngine(ServingEngine):
         return payload
 
     def _refuse_handoff(self, name):
-        """Hand-off moves K/V pages alone: not a slot's record, and not
-        (yet) pages of latent rows or a second pool's."""
-        for flag, why in ((self.slot_state, SLOT_STATE_REFUSAL),
-                          (self.latent_cache, LATENT_REFUSAL),
-                          (self.window, WINDOW_REFUSAL)):
-            if flag:
-                raise HandoffRefused(why.format(
-                    what=f"{name} (block-level hand-off)"))
+        """Hand-off moves the K/V pages of one pool alone: what the
+        layout refuses it (`PagedLayout.refusal`)."""
+        why = self.layout.refusal(f"{name} (block-level hand-off)")
+        if why:
+            raise HandoffRefused(why)
 
     def import_handoff(self, slot, prompt, payload, do_sample=False,
                        temperature=1.0, top_k=0, top_p=1.0,
@@ -738,17 +601,6 @@ class PagedServingEngine(ServingEngine):
                 "handoff digest mismatch: payload content is corrupt")
         fresh = self.block_pool.import_blocks(payload["manifest"])
         try:
-            if self._handoff_scatter_fn is None:
-                def scatter_fn(caches, idx, data):
-                    flat, treedef = jax.tree_util.tree_flatten(caches)
-                    return jax.tree_util.tree_unflatten(
-                        treedef,
-                        [leaf.at[idx].set(arr)
-                         for leaf, arr in zip(flat, data)])
-                self._handoff_scatter_fn = (telemetry.instrument_jit(
-                    jax.jit(scatter_fn, donate_argnums=(0,)),
-                    "paged_handoff_scatter")
-                    if self._jit else scatter_fn)
             idx = jnp.asarray(np.asarray(fresh, np.int32))
             self._caches = self._handoff_scatter_fn(self._caches, idx,
                                                     layers)
@@ -781,15 +633,7 @@ class PagedServingEngine(ServingEngine):
 
     def _set_lane(self, slot, tok, pos):
         """Arm one lane on the device where no prefill chunk did (a
-        hand-off import): a tiny program of its own, compiled lazily
-        like the COW copy."""
-        if self._set_lane_fn is None:
-            def set_lane(lane_tok, lane_pos, row):
-                return (lane_tok.at[row[0]].set(row[1]),
-                        lane_pos.at[row[0]].set(row[2]))
-            self._set_lane_fn = (telemetry.instrument_jit(
-                jax.jit(set_lane, donate_argnums=(0, 1)), "paged_set_lane")
-                if self._jit else set_lane)
+        hand-off import)."""
         self._lane_tok, self._lane_pos = self._set_lane_fn(
             self._lane_tok, self._lane_pos, np.int32([slot, tok, pos]))
         self.slot_tok[slot] = tok
@@ -810,13 +654,11 @@ class PagedServingEngine(ServingEngine):
             blocks = self._slot_blocks[s]
             try:
                 if bi >= len(blocks):
-                    blk, = self.block_pool.alloc(1)
-                    blocks.append(blk)
-                    self._tables[s, bi] = blk
+                    self._pages[0].back(s, bi)
                 elif self.block_pool.refcount(blocks[bi]) > 1:
                     self._ensure_private(s, bi)
-                if self.window:
-                    self._back_window(s, bi)
+                for pages in self._windowed:
+                    pages.back(s, bi)
             except BlockPoolExhausted:
                 starved.append(s)
                 active_now[s] = False
@@ -830,85 +672,45 @@ class PagedServingEngine(ServingEngine):
         its table row into a live block — a mid-chunked-prefill slot's
         table is already populated, possibly with SHARED blocks. Those
         lanes get scratch rows, so the write lands in block 0 by
-        design. With a window pool a row is the full table and then the
-        window table."""
-        tables = self._tables
-        if self.window:
-            tables = np.concatenate([tables, self._win_tables], axis=1)
+        design. A row packs every pool's, in the layout's order."""
         return np.where(np.asarray(active_now, bool)[:, None],
-                        tables, np.int32(BlockPool.SCRATCH))
+                        self._rows, np.int32(BlockPool.SCRATCH))
 
     def _wave_args(self, active_now, poison, key):
-        if self.window:
-            lanes = np.flatnonzero(active_now)
-            self._release_window(lanes, self.slot_pos[lanes])
+        lanes = np.flatnonzero(active_now)
+        self._release_behind(lanes, self.slot_pos[lanes])
         tables = self._wave_tables(active_now)
         # every lane rides the wave at its `slot_pos`, the ones not in
         # it at a stale one over a scratch row: the core walks those too
-        for layers, _, window in self._attn_kinds:
-            lo, hi = paged_attention.attended_pages(
-                self.slot_pos, 1, self.block_size, self.blocks_per_slot,
-                window)
-            self._pages_visited += layers * int(np.sum(hi - lo))
+        for group, layers in self._groups:
+            self._pages_visited += layers * group.pages(
+                self.slot_pos, 1, self.block_size, self.blocks_per_slot)
             self._pages_spanned += layers * self._tables.size
         self._count_steps(self.slot_pos, 1)
-        lanes = int(np.count_nonzero(active_now))
-        self._model_counts["moe_picks"] += lanes * self._picks_per_token
-        self._model_counts["mhc_rows_mixed"] += lanes * self._mixes_per_token
-        self._expert_counts["moe_experts_stored"] += self._experts_stored
-        if self.slot_state:
-            # the wave's program steps every slot's record, whether its
-            # lane decodes or keeps what it had
-            self._model_counts["ssm_records_stepped"] += self.num_slots
-            self._model_counts["ssm_lanes_stepped"] += lanes
-        if self.latent_cache:
-            self._model_counts["mla_rows_attended"] += int(
-                np.sum(self.slot_pos[np.asarray(active_now, bool)] + 1))
+        for name, add in self.layout.wave_counts(self.slot_pos[lanes],
+                                                 self.num_slots).items():
+            self._model_counts[name] += add
+        self._expert_counts["moe_experts_stored"] += \
+            self.layout.experts_stored
         return (self._params, self._buffers, self._caches,
                 self._lane_tok, self._lane_pos,
                 *self._lane_args(active_now, poison, tables), key)
 
-    # -------------------------------------------------------- window pool
+    # ------------------------------------------------------ window pools
     def _slot_table(self, slot):
-        """The table row a prefill chunk of `slot` takes: the full pool's
-        entries, and with a window pool the window pool's after them."""
-        if not self.window:
-            return self._tables[slot]
-        return np.concatenate([self._tables[slot], self._win_tables[slot]])
+        """A prefill chunk's table row: every pool's, in the layout's order."""
+        return self._rows[slot]
 
-    def _back_window(self, slot, last):
-        """Back the slot's window table up to entry `last` with pages of
-        the window pool (BlockPoolExhausted as `alloc` raises it)."""
-        blocks = self._win_blocks[slot]
-        have = self._win_first[slot] + len(blocks)
-        if last >= have:
-            fresh = self.window_pool.alloc(last + 1 - have)
-            self._win_tables[slot, have:last + 1] = fresh
-            blocks.extend(fresh)
-
-    def _release_window(self, slots, starts):
-        """Give back the window pages of `slots` that lie wholly below what
-        their next call, whose first query sits at `starts`, attends: the
-        entries under `(start - window + 1) // block_size`. Their table
-        entries point to the scratch block again. A page given back here
-        may be written by a later program for another lane, never before
-        the programs already dispatched have read it: the device runs
-        them in order."""
-        cuts = (np.maximum(np.asarray(starts, np.int64) - self.window + 1, 0)
-                // self.block_size).tolist()
-        n = [min(max(cut - self._win_first[s], 0), len(self._win_blocks[s]))
-             for s, cut in zip(slots, cuts)]
-        with RecordEvent("serving/window/release", pid=self.trace_pid,
-                         pages=sum(n)) as ev:
-            for s, k in zip(slots, n):
-                if k:
-                    first, blocks = self._win_first[s], self._win_blocks[s]
-                    self.window_pool.release(blocks[:k])
-                    del blocks[:k]
-                    self._win_tables[s, first:first + k] = 0
-                    self._win_first[s] = first + k
-        self._acc("window.release", ev)
-        self._model_counts["window_pages_released"] += sum(n)
+    def _release_behind(self, slots, starts):
+        """Give back the window pools' pages of `slots` behind what a call
+        whose first query sits at `starts` attends (`SlotPages.behind`)."""
+        for pages in self._windowed:
+            counts = pages.behind(slots, starts, self.window)
+            with RecordEvent("serving/window/release", pid=self.trace_pid,
+                             pages=sum(counts)) as ev:
+                pages.release_behind(slots, counts)
+            self._acc("window.release", ev)
+            self._model_counts["window_pages_released"] += sum(counts)
 
     # ----------------------------------------------------- copy-on-write
     def _ensure_private(self, slot, bi):
@@ -932,13 +734,6 @@ class PagedServingEngine(ServingEngine):
         would desynchronize the draft cache from the tokens it claims
         to hold). A model with slot state shares no block, so its
         records never come here."""
-        if self._copy_fn is None:
-            def copy_fn(caches, src, dst):
-                return jax.tree_util.tree_map(
-                    lambda pool: pool.at[dst].set(pool[src]), caches)
-            self._copy_fn = (telemetry.instrument_jit(
-                jax.jit(copy_fn, donate_argnums=(0,)), "paged_cow_copy")
-                if self._jit else copy_fn)
         return self._copy_fn(caches, np.int32(src), np.int32(dst))
 
     def take_page_counts(self):
@@ -954,28 +749,19 @@ class PagedServingEngine(ServingEngine):
 
     def take_model_counts(self):
         """What the model's own layers were staged since the last call:
-        slot records zeroed at admission, records the waves stepped
-        (every slot's) and the lanes that decoded in them; (token,
-        expert) pairs of the tokens staged into chunks and waves; the
-        chunks, the prompt tokens they carried and the keys those tokens
-        attend in a full layer (position + 1 each); of a latent cache,
-        the rows the waves' lanes attend and the rows the chunks expand
-        (each a layer); of a residual path with several streams, those
-        tokens times the sub-layers whose maps each passes
-        (`model.mhc_mixes_per_token`); of a window pool, the pages given
-        back behind the window, and the pages in use and the lanes
-        holding any as the call finds them (once a round: summed, the
-        pool's occupancy a round); of a model with routed experts, the
-        experts the waves read since fetched and those they store, and
-        the kernel calls the chunks' sparse layers made and those layers
-        (`EXPERT_COUNTS`). Taken by the scheduler once a round, from an
-        engine whose `counts_model_work` is set."""
+        the chunks, their prompt tokens and the keys those attend in a
+        full layer (position + 1 each); what the layout counts of chunks
+        and waves (`PagedLayout.wave_counts`, `chunk_counts`); of a window
+        pool, the pages given back behind the window and the pages and
+        lanes holding any as the call finds them (summed once a round);
+        of routed experts, `EXPERT_COUNTS`. Taken by the scheduler once a
+        round, from an engine whose `counts_model_work` is set."""
         out = self._model_counts
         self._model_counts = dict.fromkeys(MODEL_COUNTS, 0)
-        if self.window:
-            out["window_pages_held"] += self.window_pool.used
-            out["window_lanes_held"] += sum(map(bool, self._win_blocks))
-        if self._experts_stored:
+        for pages in self._windowed:
+            out["window_pages_held"] += pages.pool.used
+            out["window_lanes_held"] += sum(map(bool, pages.blocks))
+        if self.layout.experts_stored:
             out.update(self._expert_counts)
             self._expert_counts = dict.fromkeys(EXPERT_COUNTS, 0)
         return out
@@ -990,16 +776,8 @@ class PagedServingEngine(ServingEngine):
         prompt — or this request re-admitted after preemption — re-hits
         the cache instead of recomputing."""
         super().retire_slot(slot)
-        blocks = self._slot_blocks[slot]
-        if blocks:
-            self.block_pool.release(blocks)
-        self._slot_blocks[slot] = []
-        self._tables[slot, :] = 0
-        if self.window:
-            self.window_pool.release(self._win_blocks[slot])
-            self._win_blocks[slot] = []
-            self._win_first[slot] = 0
-            self._win_tables[slot, :] = 0
+        for pages in self._pages:
+            pages.retire(slot)
 
     def _health(self):
         # cache_blocks_used/total mirror the gauges of the same name:
@@ -1012,15 +790,10 @@ class PagedServingEngine(ServingEngine):
                  cache_blocks_total=self.block_pool.usable,
                  prefix_cache_hits=self.block_pool.prefix_hits,
                  prefix_cache_misses=self.block_pool.prefix_misses,
-                 prefix_sharing=self.prefix_sharing)
-        if self.slot_state:
-            h.update(slot_state=True, state_bytes=self._state_bytes)
-        if self.latent_cache:
-            h.update(latent_cache=True)
-        if self.window:
-            h.update(window=self.window,
-                     window_blocks_used=self.window_pool.used,
-                     window_blocks_total=self.window_pool.usable)
+                 prefix_sharing=self.prefix_sharing, **self._layout_health)
+        for pages in self._windowed:
+            h.update(window_blocks_used=pages.pool.used,
+                     window_blocks_total=pages.pool.usable)
         return h
 
 
@@ -1155,19 +928,12 @@ class SpeculativePagedEngine(PagedServingEngine):
             raise ValueError("SpeculativePagedEngine needs a draft_model")
         if spec_k < 1:
             raise ValueError(f"spec_k must be >= 1, got {spec_k}")
-        if any(getattr(m, "slot_state", False)
-               for m in (model, draft_model)):
-            raise ValueError(SLOT_STATE_REFUSAL.format(
-                what="speculative decoding (the roll-back of a rejected "
-                     "draft)"))
-        if any(getattr(m, "latent_cache", False)
-               for m in (model, draft_model)):
-            raise ValueError(LATENT_REFUSAL.format(
-                what="speculative decoding (the draft and verify waves)"))
-        if any(pool_window(m) for m in (model, draft_model)):
-            raise ValueError(WINDOW_REFUSAL.format(
-                what="speculative decoding (the roll-back of a rejected "
-                     "draft)"))
+        for m in (model, draft_model):
+            why = m.paged_layout().refusal(
+                "speculative decoding (the draft and verify waves and the "
+                "roll-back of a rejected draft)")
+            if why:
+                raise ValueError(why)
         self.spec_k = int(spec_k)
         draft_model.eval()
         self.draft_model = draft_model
@@ -1303,24 +1069,12 @@ class SpeculativePagedEngine(PagedServingEngine):
         self._program_donate_argnums = (2, 3, 4)
         self._draft_donate_argnums = (2,)
         self._prefill_donate_argnums = (2, 5, 6)
-
-        if self._jit:
-            self._draft_wave = telemetry.instrument_jit(
-                jax.jit(draft_wave,
-                        donate_argnums=self._draft_donate_argnums),
-                "paged_spec_draft_wave")
-            self._decode_wave = telemetry.instrument_jit(
-                jax.jit(spec_verify,
-                        donate_argnums=self._program_donate_argnums),
-                "paged_spec_verify")
-            self._prefill = telemetry.instrument_jit(
-                jax.jit(prefill_chunk,
-                        donate_argnums=self._prefill_donate_argnums),
-                "paged_spec_prefill_chunk")
-        else:
-            self._draft_wave = draft_wave
-            self._decode_wave = spec_verify
-            self._prefill = prefill_chunk
+        self._draft_wave = self.jit(draft_wave, "paged_spec_draft_wave",
+                                    donate=self._draft_donate_argnums)
+        self._decode_wave = self.jit(spec_verify, "paged_spec_verify",
+                                     donate=self._program_donate_argnums)
+        self._prefill = self.jit(prefill_chunk, "paged_spec_prefill_chunk",
+                                 donate=self._prefill_donate_argnums)
 
     @property
     def draft_compiles(self):
@@ -1346,11 +1100,7 @@ class SpeculativePagedEngine(PagedServingEngine):
             last_bi = last_of[s]
             blocks = self._slot_blocks[s]
             try:
-                missing = last_bi + 1 - len(blocks)
-                if missing > 0:
-                    for blk in self.block_pool.alloc(missing):
-                        blocks.append(blk)
-                        self._tables[s, len(blocks) - 1] = blk
+                self._pages[0].back(s, last_bi)
                 for bi in range(first_of[s], last_bi + 1):
                     if self.block_pool.refcount(blocks[bi]) > 1:
                         self._ensure_private(s, bi)

@@ -186,10 +186,10 @@ class Scheduler:
         self._degraded = False
         self.last_error = None
         self.metrics = ServingMetrics(engine.num_slots)
-        # an engine whose model keeps slot state or routes to experts
-        # counts that work; the others are asked nothing a round
-        self._counts_model_work = getattr(engine, "counts_model_work",
-                                          False)
+        # an engine whose model's layout has work of its own to count
+        # (slot state, experts, a latent or window pool) reports it; the
+        # others are asked nothing a round
+        self._counts_model_work = engine.counts_model_work
         # /healthz carries this scheduler's queue depth (fleet routers
         # and LBs read load + pool pressure from one endpoint)
         engine.attach_queue_probe(self.queue_depth)
@@ -1072,8 +1072,7 @@ class Scheduler:
             if self._round_worked and ev.elapsed is not None:
                 phases["round"] = ev.elapsed
             self.metrics.on_phases(phases)
-            if hasattr(eng, "take_page_counts"):
-                self.metrics.on_pages(*eng.take_page_counts())
+            self.metrics.on_pages(*eng.take_page_counts())
             if self._counts_model_work:
                 self.metrics.on_model_counts(eng.take_model_counts())
             uploads = eng.take_bias_uploads()

@@ -485,7 +485,23 @@ def test_counters_of_the_latent_cache_and_the_experts(model, engine):
     # two expert layers, top-3
     assert snap["moe_picks"] - snap0["moe_picks"] == \
         (20 + len(out) - 1) * 2 * 3
-    assert model.moe_picks_per_token == 6 and snap["state_resets"] == 0
+    assert model.paged_layout().moe_picks == 6 and snap["state_resets"] == 0
+
+
+def test_the_paged_layout(model):
+    """One group of latent layers with the sizes `latent_path` takes, 2
+    sparse layers of top-3 over 8 experts, each a kernel call a chunk of
+    16, one stream; prefixes shared, hand-off refused for the form."""
+    from paddle_tpu.nn.paged_layout import AttentionGroup
+    layout = model.paged_layout()
+    assert layout.groups == (AttentionGroup(
+        3, "latent", HEADS, latent=(RANK, ROPE, NOPE, VD)),)
+    assert layout.window is None and not layout.slot_record and layout.latent
+    assert (layout.moe_picks, layout.mhc_rows_mixed,
+            layout.experts_stored) == (6, 0, 2 * 8)
+    assert layout.chunk_calls(CHUNK) == (2, 2)
+    assert layout.counts_work and layout.shares_prefixes
+    assert "latent rows" in layout.refusal("a hand-off")
 
 
 @pytest.mark.parametrize("path", ["rule", "expanded"], indirect=True)

@@ -154,7 +154,21 @@ def test_parameters_are_created_in_the_named_dtype():
         assert p._data.dtype == jnp.bfloat16, name
         if len(p.shape) > 1:
             assert not np.asarray(p._data, np.float32).any(), name
-    assert model.slot_state is True
+    assert model.paged_layout().slot_record is True
+
+
+def test_the_paged_layout(model):
+    """One group of K/V layers (the period's one attention layer), a
+    record a slot, no experts; no prefix sharing."""
+    from paddle_tpu.nn.paged_layout import AttentionGroup
+    layout = model.paged_layout()
+    assert layout.groups == (AttentionGroup(1, "kv", 4, 2, 16),)
+    assert layout.window is None and layout.slot_record
+    assert (layout.moe_picks, layout.mhc_rows_mixed,
+            layout.experts_stored) == (0, 0, 0)
+    assert layout.chunk_calls(CHUNK) == (0, 0)
+    assert layout.counts_work and not layout.shares_prefixes
+    assert "recurrent state a slot" in layout.refusal("a hand-off")
 
 
 @pytest.mark.parametrize("kw,what", [

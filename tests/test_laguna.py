@@ -218,6 +218,12 @@ def _engine(model, **kw):
                               **kw)
 
 
+@pytest.fixture(scope="module")
+def engine(model):
+    """One compiled engine for the tests that need no fresh one."""
+    return _engine(model)
+
+
 @pytest.mark.parametrize("n,kernel", [(3 * WINDOW, "reference"),
                                       (61, "reference"),
                                       (15 * WINDOW, "reference"),
@@ -263,7 +269,7 @@ def test_sixteen_lanes_that_pass_window_pages_on_serve_the_reference(model):
     reqs = [sched.submit(prompt=p, max_tokens=m) for p, m in jobs]
     owners = {}                                 # window page -> its slots
     while sched.step():
-        for s, blocks in enumerate(eng._win_blocks):
+        for s, blocks in enumerate(eng._windowed[0].blocks):
             for b in blocks:
                 owners.setdefault(b, set()).add(s)
     assert all(r.finish_reason == "max_tokens" for r in reqs)
@@ -278,14 +284,16 @@ def test_sixteen_lanes_that_pass_window_pages_on_serve_the_reference(model):
     assert eng.block_pool.used == 0 and eng.window_pool.used == 0
 
 
-def test_the_counters_of_a_window_pool(model):
+def test_the_counters_of_a_window_pool(model, engine):
     """`moe_picks` (4 expert layers x top-4 a token), the pages given back
     behind the window, and pages and lanes held summed a round; the
     visited pages are counted a kind of layer at a time, each times its
     layers."""
-    assert model.moe_picks_per_token == 16
-    assert model.layer_windows == (None, WINDOW, WINDOW, WINDOW, None)
-    eng = _engine(model)
+    layout = model.paged_layout()
+    assert layout.moe_picks == 16
+    assert [(g.layers, g.window) for g in layout.groups] == [
+        (2, None), (3, WINDOW)]
+    eng = engine
     sched = Scheduler(eng)
     prompt = _prompt(9, 50)
     out = sched.generate(prompt, max_tokens=5)
@@ -395,7 +403,7 @@ def test_the_kernel_calls_a_chunk_makes_are_counted(model, vmem_holds,
 
 
 def test_the_front_door_serves_it_and_a_hard_close_gives_both_pools_back(
-        model):
+        model, engine):
     """`create_llm_predictor` -> the paged engine with a window pool and no
     option for it; `/healthz` reports both pools; `close(drain=False)`
     deletes every pool's arrays."""
@@ -405,7 +413,7 @@ def test_the_front_door_serves_it_and_a_hard_close_gives_both_pools_back(
     pred = inference.create_llm_predictor(cfg, model=model)
     prompt = _prompt(8, 37)
     out = pred.generate(prompt, max_tokens=6)
-    assert out == Scheduler(_engine(model)).generate(prompt, max_tokens=6)
+    assert out == Scheduler(engine).generate(prompt, max_tokens=6)
     h = pred.health()
     assert h["window"] == WINDOW and h["prefix_sharing"] is False
     assert h["window_blocks_total"] == 2 * pred.engine.window_pages
@@ -416,6 +424,32 @@ def test_the_front_door_serves_it_and_a_hard_close_gives_both_pools_back(
         pred.engine.block_pool.num_blocks]
     pred.close(drain=False)
     assert all(p.is_deleted() for p in pools)
+
+
+def test_the_paged_layout(model):
+    """Two groups of K/V layers, by head count and window: the window
+    layers' on a pool of their own; 4 sparse layers of top-4 over 16
+    experts, each a kernel call a chunk of 16. A stack of window layers
+    alone keeps one pool, and two window sizes are refused one."""
+    from paddle_tpu.nn.paged_layout import AttentionGroup
+    layout = model.paged_layout()
+    assert layout.groups == (
+        AttentionGroup(2, "kv", 6, 1, 16),
+        AttentionGroup(3, "kv", 8, 1, 16, window=WINDOW, pool="window"))
+    assert layout.window == WINDOW and not layout.slot_record
+    assert (layout.moe_picks, layout.mhc_rows_mixed,
+            layout.experts_stored) == (16, 0, 4 * 16)
+    assert layout.chunk_calls(CHUNK) == (4, 4)
+    assert layout.counts_work and not layout.shares_prefixes
+    assert "second pool" in layout.refusal("a hand-off")
+    assert layout.weighted() == [(layout.groups[0], 2),
+                                 (layout.groups[1], 3)]
+    alone = _model(num_hidden_layers=2, layer_types=[SLIDING] * 2,
+                   mlp_layer_types=["dense"] * 2,
+                   num_attention_heads_per_layer=[8, 8]).paged_layout()
+    assert alone.groups == (AttentionGroup(2, "kv", 8, 1, 16, window=WINDOW),)
+    assert alone.window is None and alone.shares_prefixes
+    assert alone.refusal("a hand-off") is None
 
 
 def _lowered(engine):
@@ -431,11 +465,11 @@ def _lowered(engine):
     return wave, chunk
 
 
-def test_device_work_carries_its_scope_names(model):
+def test_device_work_carries_its_scope_names(model, engine):
     """`attn_gate`, `moe_route`, `moe_shared` and `moe_experts` name the
     instructions of a wave and a chunk; without `gating` there is no
     gate."""
-    for program in _lowered(_engine(model)):
+    for program in _lowered(engine):
         text = program.as_text(debug_info=True)
         for scope in ("attn_gate", "moe_route", "moe_shared",
                       "moe_experts"):

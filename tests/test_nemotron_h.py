@@ -522,7 +522,7 @@ def test_spans_phases_and_counters_appear(model, engine):
     assert snap["state_resets"] == 1
     # 20 prompt tokens and 3 decoded ones, two expert layers, top-3
     assert snap["moe_picks"] == (20 + len(out) - 1) * 2 * 3
-    assert model.moe_picks_per_token == 6
+    assert model.paged_layout().moe_picks == 6
 
 
 def test_device_work_carries_its_scope_names(model, engine):
@@ -596,15 +596,30 @@ def test_programs_of_models_without_slot_state_take_no_new_argument(model):
         max_seq_len=MAX_LEN))
     for plain in (llama, gpt):
         eng = PagedServingEngine(plain, **kw)
-        assert not eng.slot_state and not eng.counts_model_work
+        assert not eng.layout.slot_record and not eng.counts_model_work
         assert "state_bytes" not in eng._health()
         # parameters, buffers, caches, the lanes' tokens and positions,
         # the packed small arguments, the bias, the key: the slot and the
-        # active mask ride in the packed one for every model, and reach
-        # only a model with slot state (these two's `prefill_chunk` /
-        # `decode_step` have no such parameter and would refuse it)
+        # active mask ride in the packed one for every model and reach
+        # every model's steps (one call protocol), where only a model
+        # with slot state uses them
         assert _program_arity(eng) == (8, 8)
     assert _program_arity(PagedServingEngine(model, **kw)) == (8, 8)
+
+
+def test_the_paged_layout(model):
+    """One group of K/V layers (the one `*` of MEM*E), a record a slot,
+    2 sparse layers of top-3 over 8 experts, each a kernel call a chunk
+    of 16; no prefix sharing, hand-off refused for the record."""
+    from paddle_tpu.nn.paged_layout import AttentionGroup
+    layout = model.paged_layout()
+    assert layout.groups == (AttentionGroup(1, "kv", 4, 2, 16),)
+    assert layout.window is None and layout.slot_record
+    assert (layout.moe_picks, layout.mhc_rows_mixed,
+            layout.experts_stored) == (6, 0, 2 * 8)
+    assert layout.chunk_calls(CHUNK) == (2, 2)
+    assert layout.counts_work and not layout.shares_prefixes
+    assert "recurrent state a slot" in layout.refusal("a hand-off")
 
 
 def test_the_front_door_serves_it(model, engine):

@@ -891,16 +891,17 @@ def test_window_pool_holds_its_bound_and_reuses_what_it_gives_back(
     jobs = [(_prompt(90 + i, n=40 + 17 * i), 12) for i in range(4)]
     reqs = [sched.submit(prompt=p, max_tokens=m) for p, m in jobs]
     handed = set()
+    pages = eng._windowed[0]
     while sched.step():
         for s in range(eng.num_slots):
-            blocks = eng._win_blocks[s]
+            blocks = pages.blocks[s]
             assert len(blocks) <= eng.window_pages
-            first = eng._win_first[s]
-            row = eng._win_tables[s]
+            first = pages.first[s]
+            row = pages.table[s]
             assert not row[:first].any() and not row[first + len(blocks):].any()
             assert row[first:first + len(blocks)].tolist() == blocks
             handed.update(blocks)
-        assert eng.window_pool.used == sum(map(len, eng._win_blocks))
+        assert eng.window_pool.used == sum(map(len, pages.blocks))
     assert all(r.finish_reason == "max_tokens" for r in reqs)
     need = sum(-(-(len(p) + m) // WIN_BLOCK) for p, m in jobs)
     assert need > 3 * eng.window_pool.usable
@@ -942,11 +943,68 @@ def test_window_model_refuses_speculation_and_handoff_by_name(window_model):
     with pytest.raises(HandoffRefused, match="import_handoff.*second pool"):
         eng.import_handoff(0, [1, 2, 3], {})
     # a window pool serves window layers of one size beside full ones
-    import types
-    from paddle_tpu.serving.paged.engine import pool_window
-    for windows, want in (((None, 8, 8), 8), ((8, 8), None),
-                          ((None, None), None), ((), None)):
-        assert pool_window(types.SimpleNamespace(
-            layer_windows=windows)) == want
+    from paddle_tpu.nn.paged_layout import AttentionGroup, PagedLayout
+
+    def layout(*pools):
+        return PagedLayout(groups=tuple(
+            AttentionGroup(1, "kv", 2, 1, 8, window=w, pool=p)
+            for w, p in pools))
+    for pools, want in ((((None, "full"), (8, "window"), (8, "window")), 8),
+                        (((8, "full"), (8, "full")), None),
+                        (((None, "full"), (None, "full")), None),
+                        ((), None)):
+        assert layout(*pools).window == want
     with pytest.raises(ValueError, match="one window pool"):
-        pool_window(types.SimpleNamespace(layer_windows=(None, 8, 16)))
+        layout((None, "full"), (8, "window"), (16, "window")).window
+
+
+@pytest.mark.parametrize("window", [None, 32])
+def test_the_paged_layout_of_a_plain_model(model, window):
+    """LLaMA's and GPT's `paged_layout()`: one group of K/V layers a
+    model, the model's window on the one pool (no pool of its own), no
+    record a slot and nothing else to count; prefixes shared, nothing
+    refused."""
+    from paddle_tpu.nlp import GPTConfig, GPTForPretraining
+    from paddle_tpu.nn.paged_layout import AttentionGroup
+    llama = model.paged_layout()
+    assert llama.groups == (AttentionGroup(2, "kv", 4, 2, 16),)
+    gpt = GPTForPretraining(GPTConfig(
+        vocab_size=VOCAB, hidden_size=64, num_layers=3, num_heads=4,
+        max_seq_len=MAX_LEN, attn_window=window)).paged_layout()
+    assert gpt.groups == (AttentionGroup(3, "kv", 4, 4, 16, window=window),)
+    for layout in (llama, gpt):
+        assert layout.window is None
+        assert not layout.slot_record and not layout.latent
+        assert (layout.moe_picks, layout.mhc_rows_mixed,
+                layout.experts_stored) == (0, 0, 0)
+        assert layout.chunk_calls(CHUNK) == (0, 0)
+        assert layout.shares_prefixes and not layout.counts_work
+        assert layout.refusal("a hand-off") is None
+        assert layout.weighted() == [(layout.groups[0], 1)]
+
+
+def test_slot_pages_back_release_behind_and_retire():
+    """A pool's pages of every slot: `back` fills the table row up to an
+    entry, `behind` counts the pages wholly below what a window attends
+    from a position and `release_behind` gives them back (their entries
+    point to the scratch block again), `retire` gives back the rest."""
+    from paddle_tpu.serving.paged.block_pool import SlotPages
+    pages = SlotPages(BlockPool(9, 4), np.zeros((2, 6), np.int32))
+    pages.back(0, 3)
+    pages.back(1, 1)
+    pages.back(0, 2)                            # already backed
+    assert pages.blocks == [[1, 2, 3, 4], [5, 6]]
+    assert pages.table[0].tolist() == [1, 2, 3, 4, 0, 0]
+    # window 6 from position 13 attends keys 8..13: pages 2 and 3
+    counts = pages.behind([0, 1], [13, 5], 6)
+    assert counts == [2, 0]
+    pages.release_behind([0, 1], counts)
+    assert pages.first == [2, 0] and pages.blocks[0] == [3, 4]
+    assert pages.table[0].tolist() == [0, 0, 3, 4, 0, 0]
+    pages.back(0, 4)
+    assert pages.table[0].tolist() == [0, 0, 3, 4, 7, 0]   # oldest free
+    assert pages.behind([0], [13], 6) == [0]
+    pages.retire(0)
+    pages.retire(1)
+    assert pages.pool.used == 0 and not pages.table.any()
+    assert pages.first == [0, 0] and pages.blocks == [[], []]

@@ -222,6 +222,16 @@ def _engine(model, **kw):
                               **kw)
 
 
+@pytest.fixture(scope="module")
+def engine():
+    """One compiled engine for the tests that read counts, pools, scope
+    names and tokens, none of which the Sinkhorn iterations change: on
+    the file's model with 2 of them, where XLA:CPU compiles the 20
+    unrolled ones in about 90 s a program. The 20 are compiled for the
+    chip in `tests/test_tpu_compile.py` and held to the reference above."""
+    return _engine(_model(hc_sinkhorn_iters=2))
+
+
 @pytest.mark.parametrize("n", [5, 29, 37])
 def test_prefill_in_chunks_then_decode_equals_the_reference(model, n):
     """Prompts that end before the YaRN original length (32), that reach
@@ -266,11 +276,12 @@ def test_a_prefix_hit_gives_the_logits_of_the_reference(model):
         _close(lo[0, 0], want[n + i])
 
 
-def test_the_counter_of_mixed_rows(model):
+def test_the_counter_of_mixed_rows(model, engine):
     """`mhc_rows_mixed`: tokens staged into chunks and waves times the
-    2 x layers sub-layers whose maps each passes; 0 with one stream."""
-    assert model.mhc_mixes_per_token == 6
-    sched = Scheduler(_engine(model))
+    2 x layers sub-layers whose maps each passes; 0 with one stream (the
+    count is the host's: the one-stream engine runs uncompiled)."""
+    assert model.paged_layout().mhc_rows_mixed == 6
+    sched = Scheduler(engine)
     snap0 = sched.metrics.snapshot()
     out = sched.generate(_prompt(9, 20), max_tokens=4)
     snap = sched.metrics.snapshot()
@@ -279,14 +290,32 @@ def test_the_counter_of_mixed_rows(model):
     assert snap["mhc_rows_mixed"] == (20 + len(out) - 1) * 2 * 3
     assert snap["moe_picks"] == (20 + len(out) - 1) * 2 * 3
     plain = _model(**PLAIN)
-    assert plain.mhc_mixes_per_token == 0
-    sched = Scheduler(_engine(plain))
+    assert plain.paged_layout().mhc_rows_mixed == 0
+    sched = Scheduler(_engine(plain, jit_compile=False))
     sched.generate(_prompt(9, 20), max_tokens=4)
     snap = sched.metrics.snapshot()
     assert snap["mhc_rows_mixed"] == 0 and snap["prefill_tokens"] == 20
 
 
-def test_the_front_door_serves_it_and_a_hard_close_gives_the_pool_back(model):
+def test_the_paged_layout(model):
+    """One group of latent layers, 2 sparse layers of top-3 over 8
+    experts, each a kernel call a chunk of 16, and 2 x 3 sub-layers whose
+    stream maps a token passes; prefixes shared, hand-off refused for the
+    form."""
+    from paddle_tpu.nn.paged_layout import AttentionGroup
+    layout = model.paged_layout()
+    assert layout.groups == (AttentionGroup(3, "latent", 4,
+                                            latent=(32, 8, 16, 16)),)
+    assert layout.window is None and not layout.slot_record
+    assert (layout.moe_picks, layout.mhc_rows_mixed,
+            layout.experts_stored) == (6, 6, 2 * 8)
+    assert layout.chunk_calls(CHUNK) == (2, 2)
+    assert layout.counts_work and layout.shares_prefixes
+    assert "latent rows" in layout.refusal("a hand-off")
+
+
+def test_the_front_door_serves_it_and_a_hard_close_gives_the_pool_back(
+        engine):
     """`create_llm_predictor` -> the paged engine, with no option for the
     streams; `close(drain=False)` deletes the pool's arrays (a caller
     that keeps the process gets the device memory back at once) and
@@ -294,10 +323,10 @@ def test_the_front_door_serves_it_and_a_hard_close_gives_the_pool_back(model):
     cfg = inference.Config().enable_llm_engine(
         num_slots=2, max_len=MAX_LEN, prefill_len=CHUNK, paged=True,
         block_size=BLOCK)
-    pred = inference.create_llm_predictor(cfg, model=model)
+    pred = inference.create_llm_predictor(cfg, model=engine.model)
     prompt = _prompt(8, 37)
     out = pred.generate(prompt, max_tokens=6)
-    assert out == Scheduler(_engine(model)).generate(prompt, max_tokens=6)
+    assert out == Scheduler(engine).generate(prompt, max_tokens=6)
     pools = jax.tree_util.tree_leaves(pred.engine._caches)
     assert len(pools) == 3 and not any(p.is_deleted() for p in pools)
     pred.close(drain=False)
@@ -319,12 +348,12 @@ def _lowered(engine):
     return wave, chunk
 
 
-def test_device_work_carries_its_scope_names(model):
+def test_device_work_carries_its_scope_names(model, engine):
     """`mhc_map`, `mhc_mix` and `mla_q_lora` name the instructions of a
     wave and a chunk; a model with one stream and a full-rank query has
     none of them. The Sinkhorn iterations are unrolled elementwise work:
     no loop and no reduction, 32 divisions an iteration."""
-    for program in _lowered(_engine(model)):
+    for program in _lowered(engine):
         text = program.as_text(debug_info=True)
         for scope in ("mhc_map", "mhc_mix", "mla_q_lora", "mla_absorb",
                       "moe_experts"):
